@@ -22,9 +22,7 @@ import sys
 
 def main() -> int:
     # Same opt-in gate as quickstart.py: pin the virtual CPU slice
-    # unless the user explicitly asks for real hardware (probing
-    # jax.default_backend() here would initialize — and possibly fail
-    # on — whatever plugin the environment pre-selected).
+    # unless the user explicitly asks for real hardware.
     if not os.environ.get("KFT_PARALLELISM_TPU"):
         os.environ["JAX_PLATFORMS"] = "cpu"
         if "xla_force_host_platform_device_count" not in \

@@ -125,7 +125,6 @@ def _native_lib():
                 # path without blocking.
                 # kft: allow=blocking-under-lock
                 subprocess.run(cmd, check=True, capture_output=True)
-                log.info("built native data core -> %s", so_path)
             lib = ctypes.CDLL(str(so_path))
             lib.kft_loader_create.restype = ctypes.c_void_p
             lib.kft_loader_create.argtypes = [
@@ -160,9 +159,14 @@ def _native_lib():
             lib.kft_loader_destroy.argtypes = [ctypes.c_void_p]
             lib.kft_free.argtypes = [ctypes.c_void_p]
             _lib = lib
+            # WARNING on both sides, once per process: which reader
+            # feeds a job decides its input rate, and the python one is
+            # several times slower.
+            log.warning("data reader in use: native C++ core (%s)",
+                        so_path)
         except Exception as e:  # no g++ / unwritable cache
-            log.warning("native data core unavailable (%s); "
-                        "using python reader", e)
+            log.warning("data reader in use: python (native C++ core "
+                        "unavailable: %s)", e)
             _lib_failed = True
     return _lib
 

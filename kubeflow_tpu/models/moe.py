@@ -95,13 +95,11 @@ class MoEMLP(nn.Module):
     #     batch (O(E*C*d) bytes moved, no MACs), and a per-choice row
     #     gather back out (O(g*top_k*d)).  Identical numerics and drop
     #     semantics; the g-fold reduction dimension disappears.
-    # Swept on-chip at the bench config (v5e, 4 experts, top-2,
-    # artifacts/r4_onchip_sweeps.log): einsum 38.8k tok/s (MFU 0.404,
-    # E-major rank-3 form, group 128) vs gather 31.0k (0.322, at its
-    # own best group 256 — each impl runs its optimum via the
-    # group_size=0 sentinel).  The asymptotic-MAC win loses to XLA's
-    # dynamic-gather lowering (vector-unit + HBM bound); the one-hot
-    # contractions ride the MXU.  Default follows the measurement.
+    # An on-chip sweep before PR 1 (v5e, 4 experts, top-2; its record is
+    # gone, so not re-measured since) had einsum ahead of gather, each
+    # at its own best group size (the group_size=0 sentinel).  The
+    # asymptotic-MAC win loses to XLA's dynamic-gather lowering
+    # (vector-unit + HBM bound); the one-hot contractions ride the MXU.
     impl: str = "einsum"
 
     @nn.compact

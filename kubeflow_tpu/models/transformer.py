@@ -367,7 +367,7 @@ def _remat_policy(cfg: TransformerConfig):
         # every matmul recomputes in the bwd.  The long-context
         # policy: at seq 32k the nobatch-saved MLP activations alone
         # are 2 x 2.06 GB and the program OOMs a 16 GB v5e; minimal
-        # fits (measured in BASELINE.md's long-context ladder).
+        # fits.
         "minimal": jax.checkpoint_policies.nothing_saveable,
     }
     if cfg.remat_policy not in policies:

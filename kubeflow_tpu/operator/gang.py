@@ -8,8 +8,7 @@ until timeout.  A TPU pod slice makes partial placement *meaningless*:
 the slice is one indivisible machine.  This scheduler therefore admits a
 job only when its full slice demand is free, holds FIFO order per queue
 (no starvation by smaller later jobs), and records the
-gang-schedule-to-running latency that BASELINE.md tracks as a north-star
-metric.
+gang-schedule-to-running latency, a north-star metric (BASELINE.json).
 """
 
 from __future__ import annotations

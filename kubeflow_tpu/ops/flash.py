@@ -224,8 +224,8 @@ def _flash_fwd_bhsd(
 #
 # The single-pass causal kernel pays full BQ x BK MACs on every block
 # that straddles the diagonal — at (512, 1024) on seq 2048 that is ~33%
-# of all MACs on masked pairs (XProf accounting, BASELINE.md headroom
-# #1).  Split the work by mask structure instead:
+# of all MACs on masked pairs, counted from the block shapes.  Split the
+# work by mask structure instead:
 #   pass A — only blocks FULLY below the diagonal, at the big
 #     (block_q, block_k) tiling, zero masking code;
 #   pass B — the diagonal band (everything pass A skipped), retiled at
@@ -234,7 +234,7 @@ def _flash_fwd_bhsd(
 # Each pass emits normalized (o, lse); one fused elementwise merge in
 # log space (the ring-attention hop merge, parallel/ring.py _merge)
 # combines them exactly.  At (512, 1024, 256) on seq 2048 the MAC count
-# drops ~24%; the sweep lives in BASELINE.md.
+# drops ~24%.
 # ---------------------------------------------------------------------------
 
 
@@ -867,7 +867,9 @@ def flash_attention(
     kernels — long-context training memory is O(s).  GQA is handled by
     repeating kv heads before the kernel (the cotangent sum over the head
     group is what jnp.repeat's autodiff gives back).  Segment masking is
-    not yet in the kernel: segmented calls fall back to the XLA path.
+    not yet in the kernel: segmented calls take the XLA path.  Anywhere
+    but on a TPU the kernel cannot run: the call raises, naming the
+    backend, unless ``interpret=True`` asks for the Pallas interpreter.
 
     block_diag > 0 selects the two-pass causal forward: full blocks at
     (block_q, block_k) with no masking, the diagonal band at
@@ -881,12 +883,20 @@ def flash_attention(
     custom-vjp kernels (inference has no cotangents; differentiating it
     raises).
     """
-    on_tpu = jax.default_backend() == "tpu"
-    if segment_ids is not None or (not on_tpu and not interpret):
+    if segment_ids is not None:
         return dot_product_attention(
             q, k, v, causal=causal, segment_ids=segment_ids,
             kv_valid_start=kv_valid_start,
         )
+    backend = jax.default_backend()
+    if backend != "tpu" and not interpret:
+        # The caller asked for the kernel by name.  Answering with the
+        # XLA path would let a job that landed on the wrong hardware
+        # train, print a loss and exit 0.
+        raise RuntimeError(
+            f"flash_attention needs a TPU backend; this process runs on "
+            f"{backend!r}.  Use attention='dot' there, or pass "
+            f"interpret=True to run the kernel in the Pallas interpreter")
     b, sq, h, d = q.shape
     k, v = repeat_kv(k, v, h)
     if kv_valid_start is not None:

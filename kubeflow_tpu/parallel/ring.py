@@ -33,6 +33,7 @@ single-device reference in tests/test_ring.py.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -40,6 +41,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
 from kubeflow_tpu.parallel.mesh import DATA, FSDP, SEQUENCE, TENSOR
+
+log = logging.getLogger(__name__)
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -102,9 +105,20 @@ def _xla_block_bwd(
     return dq, dk, dv
 
 
+@functools.lru_cache(maxsize=None)
+def _flash_by_backend(backend: str) -> bool:
+    """The block kernel when the caller named none: the Pallas kernel on
+    a TPU, the XLA block elsewhere.  Cached per backend so the choice is
+    logged once, not per traced block."""
+    use = backend == "tpu"
+    log.info("ring attention block kernel on backend %r: %s", backend,
+             "Pallas flash" if use else "XLA einsum")
+    return use
+
+
 def _use_flash(use_flash: Optional[bool]) -> bool:
     if use_flash is None:
-        return jax.default_backend() == "tpu"
+        return _flash_by_backend(jax.default_backend())
     return use_flash
 
 

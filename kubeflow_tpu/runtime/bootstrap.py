@@ -21,14 +21,21 @@ Heir of the reference's rendezvous machinery, with the daemons deleted:
 from __future__ import annotations
 
 import dataclasses
+import json
 import logging
 import os
+import pathlib
 import re
 import socket
+import sys
 import time
 from typing import Optional
 
 log = logging.getLogger(__name__)
+
+# The checkout this package was imported from: the compile cache lives
+# beside it when nobody placed it from outside.
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
 
 # Env contract injected by the operator (manifests/tpujob.py) into every
 # worker pod.  Names are the framework's own — TF_CONFIG is not emulated.
@@ -116,30 +123,6 @@ def initialize(
     (kubeflow/openmpi/prototypes/openmpi.jsonnet:21).
     """
     env = env or worker_env()
-    # A JAX_PLATFORMS env var is the operator's explicit platform
-    # choice; honor it even on images whose sitecustomize pre-registers
-    # a hardware plugin and pins jax.config.jax_platforms at interpreter
-    # start (which silently overrides the env var — a CPU fake-slice
-    # run of any tool entrypoint would land on the real chip instead).
-    platforms = os.environ.get("JAX_PLATFORMS")
-    if platforms:
-        import jax
-
-        if _backends_already_initialized():
-            # The override below is a no-op once backends exist (e.g.
-            # a tool touched jax.devices() before initialize()) — the
-            # CPU fake-slice run this defends against would silently
-            # land on the real chip.  Loud, because the symptom at
-            # train time (wrong device kind) is far from the cause.
-            log.warning(
-                "JAX backends were already initialized before "
-                "bootstrap.initialize(); JAX_PLATFORMS=%r cannot take "
-                "effect — set it before the first jax.devices()/jit "
-                "call (platform now: %s)",
-                platforms,
-                ",".join(sorted({d.platform for d in jax.devices()})),
-            )
-        jax.config.update("jax_platforms", platforms)
     if not env.is_distributed:
         log.info("single-process job; skipping jax.distributed")
         return env
@@ -159,21 +142,53 @@ def initialize(
     return env
 
 
-def _backends_already_initialized() -> bool:
-    """True when JAX has materialized its backends (after which
-    ``jax_platforms`` updates are silently ignored).  Best-effort
-    across jax versions: the check lives in a private module, so an
-    API move degrades to 'unknown' (False) rather than breaking
-    initialize()."""
-    try:
-        from jax._src import xla_bridge
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns the directory.
 
-        probe = getattr(xla_bridge, "backends_are_initialized", None)
-        if probe is not None:
-            return bool(probe())
-        return bool(getattr(xla_bridge, "_backends", None))
-    except Exception:
-        return False
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    this sets nothing.  Otherwise the cache goes to ``<checkout>/.jax_cache``
+    — a fixed path, because the directory is part of the cache key: one
+    that moved between two processes would never hit.  Call before the
+    first compile; every entrypoint does so first thing.
+    """
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def report_devices() -> dict:
+    """Name the devices this process computes on, on one parseable
+    stderr line (``KFT_DEVICE {json}``), so a caller that started the
+    process can check it landed on the hardware it was meant for."""
+    import jax
+
+    devices = jax.devices()
+    found = {"platform": devices[0].platform,
+             "kind": devices[0].device_kind, "count": len(devices)}
+    print(f"KFT_DEVICE {json.dumps(found)}", file=sys.stderr, flush=True)
+    return found
+
+
+def report_memory() -> list:
+    """Bytes in use and at peak on each local device, on one parseable
+    stderr line (``KFT_MEMORY [json]``) — only the process that holds a
+    chip can read these, so entrypoints print them on their way out.
+    Backends that keep no such statistics (CPU) report nulls."""
+    import jax
+
+    found = []
+    for device in jax.local_devices():
+        stats = device.memory_stats() or {}
+        found.append({"id": device.id,
+                      "bytes_in_use": stats.get("bytes_in_use"),
+                      "peak_bytes_in_use": stats.get("peak_bytes_in_use")})
+    print(f"KFT_MEMORY {json.dumps(found)}", file=sys.stderr, flush=True)
+    return found
 
 
 def _wait_dns(host: str, timeout_s: float, poll_s: float = 2.0) -> None:

@@ -2,9 +2,9 @@
 
 The reference had *no* metrics subsystem (SURVEY.md §5: "No Prometheus, no
 metrics endpoints"); observability was TensorBoard-or-nothing.  Here the
-north-star metrics from BASELINE.md — images(or tokens)/sec/chip, MFU, and
+north-star metrics (BASELINE.json) — images(or tokens)/sec/chip, MFU, and
 gang-schedule-to-running p50 — are first-party, emitted as structured JSON
-lines any scraper (or the bench driver) can consume.
+lines any scraper can consume.
 """
 
 from __future__ import annotations
@@ -60,6 +60,36 @@ class Timer:
     @property
     def steady_samples(self) -> int:
         return len(self._samples)
+
+
+# Peak dense bf16 FLOP/s of one chip, keyed by ``jax.Device.device_kind``.
+# Sources: Google Cloud TPU documentation, the "TPU v4", "TPU v5e",
+# "TPU v5p" and "TPU v6e" system-architecture pages (v5e: 197 TFLOP/s).
+PEAK_BF16_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5": 459e12,       # v5p
+    "TPU v6 lite": 918e12,  # v6e
+}
+
+
+def peak_flops(device) -> Optional[float]:
+    """Per-chip peak bf16 FLOP/s of ``device`` for MFU.
+
+    None off-TPU: a CPU has no peak worth dividing by, so no MFU is
+    reported there.  A TPU whose kind is not in the table raises — a
+    guessed peak would print a plausible, wrong utilization.
+    """
+    if device.platform != "tpu":
+        return None
+    try:
+        return PEAK_BF16_FLOPS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s on record for device kind "
+            f"{device.device_kind!r}; add it to "
+            f"runtime.metrics.PEAK_BF16_FLOPS with its source "
+            f"(known: {sorted(PEAK_BF16_FLOPS)})") from None
 
 
 def mfu(

@@ -136,9 +136,10 @@ class Trainer:
     checkpoints: Optional[CheckpointManager] = None
     checkpoint_every: int = 1000
     metrics: MetricsLogger = dataclasses.field(default_factory=MetricsLogger)
-    # Useful-FLOPs per example for MFU reporting (0 = skip MFU).
+    # Useful-FLOPs per example and the chip's peak, for MFU reporting
+    # (either missing = no MFU; runtime.metrics.peak_flops is None off-TPU).
     flops_per_example: float = 0.0
-    peak_flops_per_chip: float = 0.0
+    peak_flops_per_chip: Optional[float] = None
 
     def __post_init__(self) -> None:
         self._train_step = None
@@ -195,7 +196,15 @@ class Trainer:
         )
         self._state_shardings = state_shardings
         init_jit = jax.jit(init, out_shardings=state_shardings)
-        return init_jit(rng)
+        state = init_jit(rng)
+        # Where the params actually landed — a mesh that did not take
+        # effect shows here as every leaf on one device.
+        spans = [len(leaf.sharding.device_set)
+                 for leaf in jax.tree_util.tree_leaves(state.params)]
+        log.info("train state placed: %d param leaves, each on %d..%d of "
+                 "the mesh's %d device(s)", len(spans), min(spans),
+                 max(spans), self.mesh.devices.size)
+        return state
 
     # -- step -------------------------------------------------------------
 
@@ -241,10 +250,9 @@ class Trainer:
 
         ``lax.scan`` over batches stacked on a leading [k, ...] axis: one
         dispatch, one readiness check, and one metrics read amortize over
-        k steps.  For short step times — or high-latency dispatch paths
-        (a remote/tunneled chip, a busy host) — per-step host overhead is
-        what separates the measured step from the device step; fusing
-        divides it by k.  Returned metrics are the last step's (losses of
+        k steps.  For short step times, or a busy host, per-step host
+        overhead is what separates the measured step from the device
+        step; fusing divides it by k.  Returned metrics are the last step's (losses of
         the k steps differ only by one step of optimizer progress).
         """
         if k in self._multi_steps:
@@ -312,7 +320,7 @@ class Trainer:
 
         # The jit wrapper must be cached: a fresh jax.jit per call is a
         # fresh trace cache, i.e. a recompile of the (trivial) stack
-        # program on every chunk — ruinous on remote-compile backends.
+        # program on every chunk.
         key = (len(batches),
                jax.tree_util.tree_structure(batches[0]),
                tuple((getattr(x, "shape", None), str(getattr(x, "dtype",
@@ -466,7 +474,7 @@ class Trainer:
                     flops_per_step=self.flops_per_example * examples_per_step * 3
                     if self.flops_per_example else None,
                     n_chips=n_chips,
-                    peak_flops_per_chip=self.peak_flops_per_chip or None,
+                    peak_flops_per_chip=self.peak_flops_per_chip,
                     loss=loss,
                 )
             if (

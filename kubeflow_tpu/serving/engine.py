@@ -118,7 +118,7 @@ class _SpillShed(Exception):
 
 
 # Step-duration histogram buckets: decode steps run ~0.1 ms (tiny CPU
-# smoke models) to ~100 ms (big models over a slow tunnel).
+# smoke models) to ~100 ms (big models, wide fused rounds).
 _STEP_BUCKETS = (.0005, .001, .0025, .005, .01, .025, .05, .1, .25, .5,
                  1.0, 2.5)
 
@@ -2283,11 +2283,10 @@ class DecodeEngine:
         compute; a table mutation after that point (admission row
         reset, expiry parking, speculative trim) re-marks dirty and
         the next dispatch re-uploads before launching.  Under a mesh
-        whose table placement could not be introspected from the
-        compiled executable, keep passing the host array instead — the
-        runtime then transfers per dispatch, exactly as the unfused
-        ``decode_step`` path always has (correctness first, the
-        overlap win is opt-in)."""
+        whose executable is not compiled yet the table placement is
+        unknown: keep passing the host array — the runtime then
+        transfers per dispatch, exactly as the unfused ``decode_step``
+        path always has."""
         import jax
 
         with self._lock:
@@ -2428,15 +2427,9 @@ class DecodeEngine:
                 self._tables, np.int32(kmax)).compile()
             if self.mesh is not None:
                 # The double-buffered upload must land the tables
-                # exactly where the SPMD executable expects them;
-                # when that sharding is not introspectable, fall back
-                # to passing the host array per dispatch (see
-                # _refresh_tables_dev).
-                try:
-                    self._tables_sharding = \
-                        self._rounds_exec.input_shardings[0][2]
-                except Exception:
-                    self._tables_sharding = None
+                # exactly where the SPMD executable expects them.
+                self._tables_sharding = \
+                    self._rounds_exec.input_shardings[0][2]
         if self._tables_dirty:
             self._refresh_tables_dev()
         tables = (self._tables_dev if self._tables_dev is not None

@@ -141,9 +141,8 @@ def lm_generate(config: Dict[str, Any]) -> Callable:
     def make_predict(variables):
         # Stage weights into HBM ONCE at load.  They are an argument to
         # the jitted generate (not a closure constant), and jit
-        # re-transfers host-numpy arguments on every call — measured as
-        # ~40 s/request for a 188M model through the bench harness's
-        # slow host link vs ~0.1 ms/token with resident params.
+        # re-transfers host-numpy arguments on every call: the whole
+        # model would cross the host link once per request.
         # Weight-only int8 quantization happens host-side BEFORE the
         # staging transfer (fewer bytes over the link, fewer HBM reads
         # per decoded token; ops/quantize.py).  Without it, matmul

@@ -358,6 +358,10 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO, stream=sys.stderr)
+    from kubeflow_tpu.runtime import bootstrap
+
+    bootstrap.configure_compile_cache()
+    bootstrap.report_devices()
     if tracing.enable_from_args(args) is not None:
         logging.info("request tracing on (sample rate %g, store %d "
                      "traces) — GET /debug/traces",
@@ -463,6 +467,7 @@ def main(argv=None) -> int:
     if grpc_server is not None:
         grpc_server.stop(grace=1)
     server.stop()
+    bootstrap.report_memory()
     return 0
 
 

@@ -1791,10 +1791,12 @@ def multichip_serving_smoke(namespace: str = "kubeflow-test") -> None:
          replica dead, a tiered :generate sheds typed 429 Overloaded
          (Retry-After set) instead of hanging or 502ing.
 
-    Needs >= 4 local devices; when the current process initialized
-    JAX single-device (standalone CI runs), it re-execs itself in a
-    subprocess with ``--xla_force_host_platform_device_count=4`` —
-    the same trick the test conftest and MULTICHIP dryruns use.
+    Needs >= 4 local devices; when the current process sees fewer
+    (standalone CI runs, a one-chip machine), it re-execs itself in a
+    subprocess pinned to four VIRTUAL CPU devices
+    (``--xla_force_host_platform_device_count=4``, the test conftest's
+    trick) and says so: that run proves sharding and control flow, and
+    nothing about chips.
     """
     import os
     import sys
@@ -1804,6 +1806,10 @@ def multichip_serving_smoke(namespace: str = "kubeflow-test") -> None:
     if jax.device_count() < 4:
         import subprocess
 
+        print(f"multichip_serving: {jax.device_count()} "
+              f"{jax.devices()[0].platform} device(s) here, need 4 — "
+              "running the multichip leg on 4 virtual CPU devices in a "
+              "child process, NOT on accelerator chips", flush=True)
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
         flags = env.get("XLA_FLAGS", "")

@@ -3,9 +3,13 @@
 Heir of tf-controller-examples/tf-cnn/launcher.py: where that script
 translated operator-injected TF_CONFIG JSON into tf_cnn_benchmarks flags
 and streamed the subprocess (launcher.py:29-90), this one consumes the
-KFT_* env contract (runtime/bootstrap.py), initializes jax.distributed,
-and then either ``exec``s the user command or imports a python entrypoint
-in-process (so the initialized JAX runtime is shared).
+KFT_* env contract (runtime/bootstrap.py) and then either imports a python
+entrypoint in-process — after initializing jax.distributed, so the
+initialized JAX runtime is shared — or runs the user command as a child.
+In the command form the launcher itself never touches JAX: an accelerator
+belongs to one process at a time, so the child, which inherits the env
+contract, does its own ``bootstrap.initialize()`` (the train entrypoints
+do) and gets the chip.
 
 Deliberately absent: the reference's sleep-forever-on-success hack
 (launcher.py:86-90) — gang restart policy lives in the operator, pods use
@@ -28,7 +32,8 @@ def main(argv=None) -> int:
                     help="python entrypoint 'module:function' run in-process "
                          "after jax.distributed init")
     ap.add_argument("--no-distributed", action="store_true",
-                    help="skip jax.distributed (single-process debug)")
+                    help="--entrypoint form: skip jax.distributed "
+                         "(single-process debug)")
     ap.add_argument("command", nargs=argparse.REMAINDER,
                     help="command to exec (after '--')")
     args = ap.parse_args(argv)
@@ -45,10 +50,9 @@ def main(argv=None) -> int:
         env.process_id, env.num_processes, env.job_name or "-",
         env.slice_type or "-", env.coordinator_address or "-",
     )
-    if not args.no_distributed:
-        bootstrap.initialize(env)
-
     if args.entrypoint:
+        if not args.no_distributed:
+            bootstrap.initialize(env)
         mod_name, _, fn_name = args.entrypoint.partition(":")
         fn = getattr(importlib.import_module(mod_name), fn_name or "main")
         result = fn()

@@ -60,7 +60,9 @@ def main(argv=None) -> int:
     # (train.step/checkpoint.*/data.next) drives a deployed training
     # container, the e2e harness, and in-process tests.
     faults.install_from_env()
+    bootstrap.configure_compile_cache()
     env = bootstrap.initialize()
+    bootstrap.report_devices()
 
     import jax
     import jax.numpy as jnp
@@ -71,7 +73,7 @@ def main(argv=None) -> int:
     from kubeflow_tpu.models.resnet import ResNetConfig
     from kubeflow_tpu.parallel import MeshSpec
     from kubeflow_tpu.runtime.checkpoint import CheckpointManager
-    from kubeflow_tpu.runtime.metrics import MetricsLogger
+    from kubeflow_tpu.runtime.metrics import MetricsLogger, peak_flops
     from kubeflow_tpu.runtime.train import Trainer
     from kubeflow_tpu.runtime.topology import parse_slice_type
 
@@ -87,9 +89,8 @@ def main(argv=None) -> int:
     init_fn, loss_fn = classification_task(
         cfg.build(), (1, size, size, 3))
     mesh = MeshSpec(data=n).build()
-    peak = 0.0
-    if env.slice_type:
-        peak = parse_slice_type(env.slice_type).bf16_tflops_per_chip * 1e12
+    peak = (parse_slice_type(env.slice_type).bf16_tflops_per_chip * 1e12
+            if env.slice_type else peak_flops(jax.devices()[0]))
     ckpt = (CheckpointManager(args.checkpoint_dir)
             if args.checkpoint_dir else None)
     trainer = Trainer(
@@ -141,6 +142,7 @@ def main(argv=None) -> int:
                    examples_per_step=global_batch,
                    log_every=args.log_every)
     logging.info("training done: %s", trainer._last_metrics)
+    bootstrap.report_memory()
     return 0
 
 

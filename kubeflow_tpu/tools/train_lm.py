@@ -79,7 +79,9 @@ def main(argv=None) -> int:
     # (train.step/checkpoint.*/data.next) drives a deployed training
     # container, the e2e harness, and in-process tests.
     faults.install_from_env()
+    bootstrap.configure_compile_cache()
     env = bootstrap.initialize()
+    bootstrap.report_devices()
 
     import jax
     import numpy as np
@@ -88,7 +90,7 @@ def main(argv=None) -> int:
     from kubeflow_tpu.models.transformer import TransformerConfig, lm_task
     from kubeflow_tpu.parallel import MeshSpec
     from kubeflow_tpu.runtime.checkpoint import CheckpointManager
-    from kubeflow_tpu.runtime.metrics import MetricsLogger
+    from kubeflow_tpu.runtime.metrics import MetricsLogger, peak_flops
     from kubeflow_tpu.runtime.topology import parse_slice_type
     from kubeflow_tpu.runtime.train import Trainer
 
@@ -135,7 +137,7 @@ def main(argv=None) -> int:
     init_fn, loss_fn = lm_task(cfg, mesh=mesh)
     batch = args.batch_size_per_device * jax.device_count()
     peak = (parse_slice_type(env.slice_type).bf16_tflops_per_chip * 1e12
-            if env.slice_type else 0.0)
+            if env.slice_type else peak_flops(jax.devices()[0]))
     if args.warmup_steps > 0:
         lr = optax.warmup_cosine_decay_schedule(
             init_value=0.0, peak_value=args.learning_rate,
@@ -184,6 +186,7 @@ def main(argv=None) -> int:
                    log_every=args.log_every,
                    steps_per_call=args.steps_per_call)
     logging.info("training done: %s", trainer._last_metrics)
+    bootstrap.report_memory()
     if args.metrics_out:
         import json as _json
 

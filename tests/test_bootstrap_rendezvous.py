@@ -20,8 +20,6 @@ _WORKER = r"""
 import os, sys
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-
 from kubeflow_tpu.runtime import bootstrap
 
 env = bootstrap.worker_env()
@@ -50,8 +48,6 @@ print(f"RENDEZVOUS process={env.process_id} sum={float(total)}", flush=True)
 _TRAIN_WORKER = r"""
 import os, sys
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 from kubeflow_tpu.runtime import bootstrap
 
@@ -147,8 +143,6 @@ def _run_two_workers(worker_src: str, job_name: str, timeout_s: float,
 _SHARDED_TRAIN_WORKER = r"""
 import os, sys
 import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 from kubeflow_tpu.runtime import bootstrap
 
@@ -252,25 +246,3 @@ def test_two_process_two_device_sharded_training():
     loss1 = lines[1].split("loss=")[1].split()[0]
     assert loss0 == loss1, lines
     assert "step=3" in lines[0], lines
-
-
-def test_late_jax_platforms_override_warns(monkeypatch, caplog):
-    """ADVICE r5: once JAX backends are materialized, the
-    `jax_platforms` update in initialize() is silently a no-op — the
-    CPU fake-slice run it defends against would land on the real chip
-    with zero signal.  initialize() must detect the already-built
-    backends and warn loudly."""
-    import logging
-
-    import jax
-
-    from kubeflow_tpu.runtime import bootstrap
-
-    jax.devices()  # materialize backends before initialize() runs
-    assert bootstrap._backends_already_initialized()
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    with caplog.at_level(logging.WARNING,
-                         logger="kubeflow_tpu.runtime.bootstrap"):
-        bootstrap.initialize(bootstrap.worker_env({}))
-    assert any("cannot take effect" in r.getMessage()
-               for r in caplog.records), caplog.records

@@ -12,8 +12,8 @@ real actors:
     operator/main.py constructs), running on its own thread;
   * a fake kubelet driving created pods Pending -> Running -> Succeeded,
     standing in for the containers a kind/GKE cluster would run —
-    docker/kind are unavailable in this build environment (see
-    BASELINE.md), so container execution is the one simulated piece;
+    docker/kind are unavailable in this build environment, so
+    container execution is the one simulated piece;
   * the unmodified e2e.py drivers, whose kubectl shell-outs are routed
     onto the same FakeKube by a translating stub.
 """

@@ -14,8 +14,6 @@ REPO = pathlib.Path(__file__).parents[1]
 def test_quickstart_end_to_end():
     env = dict(
         os.environ,
-        # Hermetic spawn: CPU fake slice, no environment-injected jax
-        # plugin paths (same rationale as test_serving_process.py).
         PYTHONPATH=str(REPO),
     )
     env.pop("JAX_PLATFORMS", None)       # the script pins cpu itself

@@ -328,13 +328,28 @@ def test_bucketed_batcher_rejects_multi_row_submit():
         mb.close()
 
 
-def test_flash_prefill_matches_dot_decode():
+def test_flash_prefill_matches_dot_decode(monkeypatch):
     """A flash-configured model's generate() (flash prefill, cached dot
-    decode) must produce exactly the dot-configured model's tokens.
-    This suite runs on the CPU fake slice (conftest pins the platform)
-    where flash falls back to the XLA path, so exact equality pins the
-    GATE logic and shapes; flash-kernel-vs-dot numerics are pinned
-    separately with tolerances in tests/test_ops.py."""
+    decode) must produce the dot-configured model's tokens.  The kernel
+    cannot run on this suite's CPU backend (it raises rather than fall
+    back), so the test itself steers it into the Pallas interpreter —
+    that also proves the gate really reaches the kernel, with the
+    per-row key-start mask.  Kernel-vs-dot numerics are pinned with
+    tolerances in tests/test_ops.py; here the float32 toy model's
+    greedy tokens survive the kernel's blockwise summation order."""
+    import functools
+
+    from kubeflow_tpu.ops import flash
+
+    calls = []
+    real = flash.flash_attention
+
+    def interpreted(*args, **kwargs):
+        calls.append(kwargs.get("kv_valid_start") is not None)
+        return real(*args, **{**kwargs, "interpret": True})
+
+    monkeypatch.setattr(flash, "flash_attention",
+                        functools.wraps(real)(interpreted))
     _, params, prompt = setup()
     dc = DecodeConfig(max_new_tokens=5)
     ref, _ = generate(CFG, params, prompt, dc)
@@ -344,8 +359,8 @@ def test_flash_prefill_matches_dot_decode():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
     # Left-padded rows ride flash prefill via the kernel's per-row
-    # key-start mask (CPU fallback applies the same mask in the dot
-    # path) and must decode identically to the unpadded reference;
+    # key-start mask and must decode identically to the unpadded
+    # reference;
     # int8 caches keep the dot path (goldens pin that rounding) and
     # must still decode at the right shape.
     padded = jnp.concatenate(
@@ -359,6 +374,9 @@ def test_flash_prefill_matches_dot_decode():
         params, prompt,
         DecodeConfig(max_new_tokens=5, kv_cache_dtype="int8"))
     assert out_q.shape == ref.shape
+    # Both fp prefills went through the kernel — the padded one with its
+    # key-start mask — and the int8-cache one stayed on the dot path.
+    assert calls == [False, True]
 
 
 def test_eos_while_loop_matches_scan_when_eos_never_fires():

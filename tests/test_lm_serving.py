@@ -737,33 +737,70 @@ class TestDecodeEngine:
         finally:
             server.enable_batching("lm", lambda model: None)
 
-    @pytest.mark.slow
-    def test_throughput_beats_static_batcher(self):
-        """Mixed-length open-loop workload: the continuous engine's
-        delivered tokens/sec must beat the static BucketedLMBatcher.
+    def test_engine_and_static_batcher_deliver_the_same_tokens(
+            self, engine_model):
+        """The continuous engine against the static BucketedLMBatcher on
+        one mixed-length, mixed-budget request set: what a CPU run can
+        say.  Both paths deliver every token that was asked for, the
+        tokens are identical between them, and the engine compiles its
+        two programs once.  Which path is FASTER is a device number:
+        a CPU wall-clock ratio says nothing about the chip, so no rate
+        is asserted here (it was; it flipped with the box's load)."""
+        import threading
 
-        Drives bench.py's lm_engine section directly — same request
-        set, same arrival schedule on both sides, stall-resistant
-        interleaved windows with max-window capability estimates — so
-        this test and the recorded BENCH number are one measurement.
-        (A smaller hand-rolled version of this comparison flaked: on
-        the CPU smoke model the engine's host-loop overhead and the
-        box's scheduling noise are the same order as the structural
-        win, and only the bench's windowing rides that out.)"""
-        import bench
+        from kubeflow_tpu.serving.engine import DecodeEngine
+        from kubeflow_tpu.serving.model_server import BucketedLMBatcher
 
-        import jax
+        spec, server = engine_model
+        rng = np.random.RandomState(SEED)
+        lens = [3, 7, 11, 16, 5, 16, 9, 2]
+        news = [4, 12, 6, 3, 12, 8, 5, 10]
+        prompts = [rng.randint(1, VOCAB, size=(n,)).astype(np.int32)
+                   for n in lens]
 
-        devices = jax.devices()
-        record = bench.bench_lm_engine(None, devices, len(devices),
-                                       on_tpu=False)
-        detail = record["detail"]
-        assert detail["compiled_programs"] == {
-            "chunked_prefill": 1, "step": 1, "verify": 0}
-        assert detail["engine_vs_batcher"] > 1.0, (
-            f"engine {detail['engine_tokens_per_sec']} tok/s did not "
-            f"beat static batcher {detail['batcher_tokens_per_sec']} "
-            "tok/s on the bench's mixed-length open-loop workload")
+        def run(submit):
+            outs = [None] * len(prompts)
+
+            def client(i):
+                outs[i] = np.asarray(submit(i)["tokens"])[0].tolist()
+
+            threads = [threading.Thread(target=client, args=(i,))
+                       for i in range(len(prompts))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=300)
+            assert all(o is not None for o in outs)
+            return outs
+
+        engine = DecodeEngine(spec["cfg"], spec["params"], spec["decode"],
+                              slots=4, prefill_len=16, name="test-vs-static")
+        try:
+            got = run(lambda i: engine.submit(
+                {"tokens": prompts[i][None], "max_new_tokens": news[i]}))
+            assert engine.stats()["tokens"] == sum(news)
+            assert engine.compiled_programs() == {
+                "chunked_prefill": 1, "step": 1, "verify": 0}
+        finally:
+            engine.close()
+        # The static path bakes the export's budget into its programs:
+        # every request decodes NEW_TOKENS, and the client's budget is a
+        # prefix of that (greedy is prefix-stable).
+        batcher = BucketedLMBatcher(
+            server.get("lm").predict, buckets=[8, 16], max_batch_size=4,
+            batch_timeout_s=0.02, allowed_batch_sizes=[1, 2, 4],
+            name="test-static")
+        try:
+            static = run(lambda i: batcher.submit(
+                {"tokens": prompts[i][None]}))
+        finally:
+            batcher.close()
+        for i, (n, new) in enumerate(zip(lens, news)):
+            assert len(got[i]) == n + new
+            assert len(static[i]) == n + NEW_TOKENS
+            assert got[i] == static[i][:n + new], (
+                f"request {i} (len {n}, budget {new}): the engine and "
+                "the static batcher decoded different tokens")
 
 
 class TestSpeculativeDecoding:

@@ -82,7 +82,7 @@ class TestTPUJobPrototypes:
         assert crd_obj["metadata"]["name"] == "tpujobs.kubeflow-tpu.org"
 
     def test_no_nvidia_gpu_anywhere(self):
-        """North-star: zero nvidia.com/gpu requests cluster-wide (BASELINE.md)."""
+        """North-star: zero nvidia.com/gpu requests cluster-wide (BASELINE.json)."""
         import json
 
         app = App()

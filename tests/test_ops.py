@@ -105,11 +105,18 @@ class TestFlashKernel:
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
-    def test_cpu_fallback_without_interpret(self):
+    def test_cpu_without_interpret_raises_naming_the_backend(self):
+        """The kernel was asked for by name: on a backend that cannot
+        run it the call fails instead of answering with the XLA path (a
+        job that landed on the CPU would train, print a loss and exit
+        0).  Segmented calls keep their documented XLA path."""
         rng = np.random.RandomState(7)
         q, k, v = rand_qkv(rng, s=16)
-        ref = dot_product_attention(q, k, v)
-        out = flash_attention(q, k, v)  # backend=cpu -> XLA fallback
+        with pytest.raises(RuntimeError, match=r"'cpu'"):
+            flash_attention(q, k, v)
+        seg = jnp.zeros(q.shape[:2], jnp.int32)
+        ref = dot_product_attention(q, k, v, segment_ids=seg)
+        out = flash_attention(q, k, v, segment_ids=seg)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
 
 

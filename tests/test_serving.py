@@ -315,11 +315,10 @@ class TestMicroBatcher:
         mb.close()
 
     def test_pipelined_dispatch_overlaps_slow_predict(self):
-        """With a high-latency predict (the driver-tunnel regime), two
-        executors must keep two batches in flight: wall time for two
-        batches' worth of load ~= one latency, not two (the round-2
-        failure: one runner thread => one batch in flight => throughput
-        collapse)."""
+        """With a high-latency predict, two executors must keep two
+        batches in flight: wall time for two batches' worth of load ~=
+        one latency, not two (one runner thread => one batch in flight
+        => throughput collapse)."""
         import concurrent.futures as cf
         import time as _t
 

@@ -13,7 +13,8 @@ REPO = pathlib.Path(__file__).parents[1]
 
 
 def _env():
-    # Same hermetic-spawn rationale as test_serving_process.py.
+    # CPU fake slice for the spawned entrypoints, package importable
+    # from any cwd.
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=str(REPO),
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
