@@ -456,42 +456,9 @@ def bench_serving(args, devices, n_chips, on_tpu):
         par_s = time.perf_counter() - t0
         rpc_parallelism = n_par * one_rt_s / max(par_s, 1e-9)
 
-        # --- device-side truth: XProf the pipelined batch-16 predict
-        # and sum leaf-op device time.  Wall-clock cannot isolate the
-        # device on a high-latency transport; the trace can — this is
-        # the un-foolable "what could the chip itself sustain" number
-        # the capacity ratio is judged against.
+        # No device-side probe here: benchmark/lib/trace_reduce.py reads
+        # a trace; on every CPU run this was None already.
         device_ms_per_batch = None
-        if on_tpu:
-            try:
-                import glob as _glob
-
-                from kubeflow_tpu.runtime.profiling import trace as \
-                    xprof_trace
-                from kubeflow_tpu.tools.xplane_summary import \
-                    device_busy_ms
-
-                probe_reps = 5
-                with xprof_trace(f"{tmp}/xprof"):
-                    outs = [server.predict(
-                        family, {"image": dev_big})["scores"]
-                        for _ in range(probe_reps)]
-                    for o in outs:
-                        np.asarray(o)
-                pbs = _glob.glob(
-                    f"{tmp}/xprof/**/*.xplane.pb", recursive=True)
-                if pbs:
-                    # Newest by mtime, NOT lexicographic max: the
-                    # profiler can emit several xplane files (multi-
-                    # host) and a leftover trace in the same dir would
-                    # silently mis-measure the device ceiling.
-                    import os as _os
-
-                    device_ms_per_batch = device_busy_ms(
-                        max(pbs, key=_os.path.getmtime)) / probe_reps
-            except Exception as e:
-                print(f"device xprof probe unavailable: {e}",
-                      file=sys.stderr)
 
         # --- single-request sync latency (full round trip per call).
         lat = []

@@ -124,27 +124,28 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
         return (normed * scale).astype(dt)
 
-    y = norm(x, layer_params["attn_norm"]["scale"])
-    # qeinsum keeps int8 serving weights quantized through the dot
-    # (per-output-channel scales applied after; ops/quantize.py).
-    q = qeinsum("bse,ehd->bshd", y, attn["wq"], dt)
-    k = qeinsum("bse,ehd->bshd", y, attn["wkv"][0], dt)
-    v = qeinsum("bse,ehd->bshd", y, attn["wkv"][1], dt)
-    if adapters is not None:
-        # Adapter-array serving (§5.11): each row adds ITS adapter's
-        # low-rank delta to every projection, pre-rope so the delta is
-        # part of the projection itself.  Row 0 of the stack is the
-        # all-zero base delta, so base traffic co-batches with tenant
-        # traffic at identical math.
-        ad = adapters["attn"]
-        q = q + _lora(y, ad["wq_a"], ad["wq_b"],
-                      "bse,ber->bsr", "bsr,brhd->bshd")
-        k = k + _lora(y, ad["wkv_a"][:, 0], ad["wkv_b"][:, 0],
-                      "bse,ber->bsr", "bsr,brhd->bshd")
-        v = v + _lora(y, ad["wkv_a"][:, 1], ad["wkv_b"][:, 1],
-                      "bse,ber->bsr", "bsr,brhd->bshd")
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("kft.qkv_proj"):
+        y = norm(x, layer_params["attn_norm"]["scale"])
+        # qeinsum keeps int8 serving weights quantized through the dot
+        # (per-output-channel scales applied after; ops/quantize.py).
+        q = qeinsum("bse,ehd->bshd", y, attn["wq"], dt)
+        k = qeinsum("bse,ehd->bshd", y, attn["wkv"][0], dt)
+        v = qeinsum("bse,ehd->bshd", y, attn["wkv"][1], dt)
+        if adapters is not None:
+            # Adapter-array serving (§5.11): each row adds ITS adapter's
+            # low-rank delta to every projection, pre-rope so the delta
+            # is part of the projection itself.  Row 0 of the stack is
+            # the all-zero base delta, so base traffic co-batches with
+            # tenant traffic at identical math.
+            ad = adapters["attn"]
+            q = q + _lora(y, ad["wq_a"], ad["wq_b"],
+                          "bse,ber->bsr", "bsr,brhd->bshd")
+            k = k + _lora(y, ad["wkv_a"][:, 0], ad["wkv_b"][:, 0],
+                          "bse,ber->bsr", "bsr,brhd->bshd")
+            v = v + _lora(y, ad["wkv_a"][:, 1], ad["wkv_b"][:, 1],
+                          "bse,ber->bsr", "bsr,brhd->bshd")
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
 
     ck, cv = cache_kv
     t = x.shape[1]
@@ -153,20 +154,6 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
         vals = ck.values if isinstance(ck, QTensor) else ck
         nb, bt = vals.shape[0], vals.shape[1]
         mb = tables.shape[1]
-        if per_row:
-            base = cache_len if write_cols is None else write_cols
-            pos = base[:, None] + jnp.arange(t)[None, :]
-        else:
-            pos = cache_len + jnp.arange(t)[None, :]
-            pos = jnp.broadcast_to(pos, (x.shape[0], t))
-        blk_slot = pos // bt
-        # Physical block per position: sentinel table entries (== nb)
-        # and logical indices past the table both park the write out
-        # of the pool's range — the scatter drops them.
-        blk = jnp.take_along_axis(
-            tables, jnp.clip(blk_slot, 0, mb - 1), axis=1)
-        blk = jnp.where(blk_slot < mb, blk, nb)
-        off = pos % bt
 
         def store(c, new):  # new: [b, t, hk, d]
             if isinstance(c, QTensor):
@@ -178,8 +165,23 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                 )
             return c.at[blk, off].set(new.astype(c.dtype), mode="drop")
 
-        ck = store(ck, k)
-        cv = store(cv, v)
+        with jax.named_scope("kft.kv_write"):
+            if per_row:
+                base = cache_len if write_cols is None else write_cols
+                pos = base[:, None] + jnp.arange(t)[None, :]
+            else:
+                pos = cache_len + jnp.arange(t)[None, :]
+                pos = jnp.broadcast_to(pos, (x.shape[0], t))
+            blk_slot = pos // bt
+            # Physical block per position: sentinel table entries
+            # (== nb) and logical indices past the table both park the
+            # write out of the pool's range — the scatter drops them.
+            blk = jnp.take_along_axis(
+                tables, jnp.clip(blk_slot, 0, mb - 1), axis=1)
+            blk = jnp.where(blk_slot < mb, blk, nb)
+            off = pos % bt
+            ck = store(ck, k)
+            cv = store(cv, v)
 
         def paged_view(c):
             # Row view of the (just-updated) pool: OOB sentinel
@@ -195,10 +197,13 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                                c.axes)
             return gather(c)
 
-        out = dot_product_attention(
-            q, paged_view(ck), paged_view(cv), causal=True,
-            kv_offset=cache_len, kv_valid_start=pad_amount,
-        )
+        with jax.named_scope("kft.kv_view"):
+            view_k, view_v = paged_view(ck), paged_view(cv)
+        with jax.named_scope("kft.attention"):
+            out = dot_product_attention(
+                q, view_k, view_v, causal=True,
+                kv_offset=cache_len, kv_valid_start=pad_amount,
+            )
     elif per_row:
         # Slot-based decode/verify: t new tokens per row, scattered to
         # each row's own columns [base, base + t).  mode="drop" makes
@@ -221,8 +226,9 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
             return c.at[rows, cols].set(
                 new.astype(c.dtype), mode="drop")
 
-        ck = store(ck, k)
-        cv = store(cv, v)
+        with jax.named_scope("kft.kv_write"):
+            ck = store(ck, k)
+            cv = store(cv, v)
     elif isinstance(ck, QTensor):
         def store(c, new):
             vals, s = quantize_array(new, (-1,))    # [b, t, hk, d]
@@ -234,13 +240,15 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                 c.axes,
             )
 
-        ck = store(ck, k)
-        cv = store(cv, v)
+        with jax.named_scope("kft.kv_write"):
+            ck = store(ck, k)
+            cv = store(cv, v)
     else:
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype),
-                                                 cache_len, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype),
-                                                 cache_len, axis=1)
+        with jax.named_scope("kft.kv_write"):
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                ck, k.astype(ck.dtype), cache_len, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                cv, v.astype(cv.dtype), cache_len, axis=1)
     # Attend over the whole buffer; positions beyond cache_len + t are
     # masked by the causal rule (their k_pos > any live q_pos... they are
     # zeros at positions >= cache_len+t, masked via kv_offset arithmetic).
@@ -264,39 +272,44 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
             and not isinstance(ck, QTensor)):
         from kubeflow_tpu.ops.flash import flash_attention
 
-        out = flash_attention(
-            q, k, v, causal=True,
-            block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-            kv_valid_start=pad_amount,
-        )
+        with jax.named_scope("kft.attention"):
+            out = flash_attention(
+                q, k, v, causal=True,
+                block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
+                kv_valid_start=pad_amount,
+            )
     elif tables is None:
-        out = dot_product_attention(
-            q, ck, cv, causal=True, kv_offset=cache_len,
-            kv_valid_start=pad_amount,
-        )
-    y = qeinsum("bshd,hde->bse", out, attn["wo"], dt)
-    if adapters is not None:
-        ad = adapters["attn"]
-        y = y + _lora(out, ad["wo_a"], ad["wo_b"],
-                      "bshd,bhdr->bsr", "bsr,bre->bse")
-    x = x + y
-    y = norm(x, layer_params["mlp_norm"]["scale"])
-    mlp = layer_params["mlp"]
-    gate = qeinsum("bse,ef->bsf", y, mlp["wi"][0], dt)
-    up = qeinsum("bse,ef->bsf", y, mlp["wi"][1], dt)
-    if adapters is not None:
-        ad = adapters["mlp"]
-        gate = gate + _lora(y, ad["wi_a"][:, 0], ad["wi_b"][:, 0],
+        with jax.named_scope("kft.attention"):
+            out = dot_product_attention(
+                q, ck, cv, causal=True, kv_offset=cache_len,
+                kv_valid_start=pad_amount,
+            )
+    with jax.named_scope("kft.attn_out"):
+        y = qeinsum("bshd,hde->bse", out, attn["wo"], dt)
+        if adapters is not None:
+            ad = adapters["attn"]
+            y = y + _lora(out, ad["wo_a"], ad["wo_b"],
+                          "bshd,bhdr->bsr", "bsr,bre->bse")
+        x = x + y
+    with jax.named_scope("kft.mlp"):
+        y = norm(x, layer_params["mlp_norm"]["scale"])
+        mlp = layer_params["mlp"]
+        gate = qeinsum("bse,ef->bsf", y, mlp["wi"][0], dt)
+        up = qeinsum("bse,ef->bsf", y, mlp["wi"][1], dt)
+        if adapters is not None:
+            ad = adapters["mlp"]
+            gate = gate + _lora(y, ad["wi_a"][:, 0], ad["wi_b"][:, 0],
+                                "bse,ber->bsr", "bsr,brf->bsf")
+            up = up + _lora(y, ad["wi_a"][:, 1], ad["wi_b"][:, 1],
                             "bse,ber->bsr", "bsr,brf->bsf")
-        up = up + _lora(y, ad["wi_a"][:, 1], ad["wi_b"][:, 1],
-                        "bse,ber->bsr", "bsr,brf->bsf")
-    h = jax.nn.silu(gate) * up
-    y = qeinsum("bsf,fe->bse", h, mlp["wo"], dt)
-    if adapters is not None:
-        ad = adapters["mlp"]
-        y = y + _lora(h, ad["wo_a"], ad["wo_b"],
-                      "bsf,bfr->bsr", "bsr,bre->bse")
-    return x + y, (ck, cv)
+        h = jax.nn.silu(gate) * up
+        y = qeinsum("bsf,fe->bse", h, mlp["wo"], dt)
+        if adapters is not None:
+            ad = adapters["mlp"]
+            y = y + _lora(h, ad["wo_a"], ad["wo_b"],
+                          "bsf,bfr->bsr", "bsr,bre->bse")
+        x = x + y
+    return x, (ck, cv)
 
 
 def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
@@ -323,7 +336,8 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
     params = nn.unbox(params)  # accept raw model.init output
     dt = cfg.dtype
     embed = params["embed"]
-    x = embed_lookup(embed, tokens, dt)  # int8-aware row gather
+    with jax.named_scope("kft.embed"):
+        x = embed_lookup(embed, tokens, dt)  # int8-aware row gather
     per_row = not isinstance(cache_len, int) and cache_len.ndim == 1
     if per_row:
         positions = (cache_len[:, None]
@@ -346,10 +360,11 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
         # axis moves out front so the factors ride the scan xs beside
         # the base layer stack — one gather per forward, ONE SPMD
         # program for every mix of co-batched variants.
-        adapter_stack = jax.tree_util.tree_map(
-            lambda arr: jnp.moveaxis(
-                jnp.asarray(arr, dt)[adapter_ids], 1, 0),
-            dict(params["adapters"]))
+        with jax.named_scope("kft.embed"):  # the rows' other lookup
+            adapter_stack = jax.tree_util.tree_map(
+                lambda arr: jnp.moveaxis(
+                    jnp.asarray(arr, dt)[adapter_ids], 1, 0),
+                dict(params["adapters"]))
 
     # The caches ride the scan as xs/ys (sliced per layer on the leading
     # axis, re-stacked from the per-layer outputs) — NOT as carry with
@@ -377,16 +392,18 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
         xs = xs + (adapter_stack,)
     x, (cache_k, cache_v) = jax.lax.scan(body, x, xs)
 
-    scale = params["final_norm"]["scale"]
-    x32 = x.astype(jnp.float32)
-    x = (x32 * jax.lax.rsqrt(
-        jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6) * scale
-    ).astype(dt)
-    if cfg.tied_embeddings:
-        logits = qeinsum("bse,ve->bsv", x, embed, dt)
-    else:
-        logits = qeinsum("bse,ev->bsv", x, params["w_out"], dt)
-    return logits.astype(jnp.float32), (cache_k, cache_v)
+    with jax.named_scope("kft.logits"):
+        scale = params["final_norm"]["scale"]
+        x32 = x.astype(jnp.float32)
+        x = (x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6) * scale
+        ).astype(dt)
+        if cfg.tied_embeddings:
+            logits = qeinsum("bse,ve->bsv", x, embed, dt)
+        else:
+            logits = qeinsum("bse,ev->bsv", x, params["w_out"], dt)
+        logits = logits.astype(jnp.float32)
+    return logits, (cache_k, cache_v)
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
@@ -688,23 +705,24 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
         (state["cache_k"], state["cache_v"]), lengths,
         write_cols=write_cols, tables=tables,
         adapter_ids=state.get("adapter_ids"))
-    last = logits[:, -1]
-    if decode.temperature <= 0.0:
-        nxt = jnp.argmax(last, axis=-1)
-        keys = state["keys"]
-    else:
-        # Per-slot keys, split per step: slot r's sample stream
-        # depends only on its own seed and step index, never on
-        # which other requests happen to share the batch.
-        split = jax.vmap(jax.random.split)(state["keys"])
-        keys, subs = split[:, 0], split[:, 1]
-        nxt = jax.vmap(jax.random.categorical)(
-            subs, _filter_logits(decode, last))
-    nxt = jnp.where(advance, nxt.astype(jnp.int32), 0)
-    new_lengths = lengths + advance.astype(jnp.int32)
-    new_done = done | (new_lengths >= state["stop_len"])
-    if decode.eos_token >= 0:
-        new_done = new_done | (advance & (nxt == decode.eos_token))
+    with jax.named_scope("kft.sample"):
+        last = logits[:, -1]
+        if decode.temperature <= 0.0:
+            nxt = jnp.argmax(last, axis=-1)
+            keys = state["keys"]
+        else:
+            # Per-slot keys, split per step: slot r's sample stream
+            # depends only on its own seed and step index, never on
+            # which other requests happen to share the batch.
+            split = jax.vmap(jax.random.split)(state["keys"])
+            keys, subs = split[:, 0], split[:, 1]
+            nxt = jax.vmap(jax.random.categorical)(
+                subs, _filter_logits(decode, last))
+        nxt = jnp.where(advance, nxt.astype(jnp.int32), 0)
+        new_lengths = lengths + advance.astype(jnp.int32)
+        new_done = done | (new_lengths >= state["stop_len"])
+        if decode.eos_token >= 0:
+            new_done = new_done | (advance & (nxt == decode.eos_token))
     state = dict(state)
     state["cache_k"], state["cache_v"] = ck, cv
     state["lengths"] = new_lengths
@@ -850,33 +868,34 @@ def verify_step(cfg: TransformerConfig, params, state,
         cfg, params, tokens, (state["cache_k"], state["cache_v"]),
         lengths, write_cols=write_cols, tables=tables,
         adapter_ids=state.get("adapter_ids"))
-    targets = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, k+1]
-    # Longest accepted draft prefix (positions beyond draft_len never
-    # match), then +1 free token, clipped to the per-slot budget: a
-    # live slot always has stop_len - lengths >= 1 emission of room,
-    # so every advancing slot nets at least one token per call — a
-    # verify call never delivers less than a decode step would.
-    pos = jnp.arange(k)[None, :]
-    match = (draft.astype(jnp.int32) == targets[:, :k]) \
-        & (pos < draft_len[:, None])
-    accepted = jnp.sum(
-        jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
-    emit = jnp.minimum(accepted + 1,
-                       jnp.maximum(state["stop_len"] - lengths, 0))
-    if decode.eos_token >= 0:
-        is_eos = targets == decode.eos_token
-        eos_cut = jnp.where(jnp.any(is_eos, axis=1),
-                            jnp.argmax(is_eos, axis=1) + 1, k + 2)
-        done_eos = advance & (eos_cut <= emit)
-        emit = jnp.minimum(emit, eos_cut)
-    else:
-        done_eos = jnp.zeros_like(done)
-    emit = jnp.where(advance, emit, 0)
-    out = jnp.where(jnp.arange(k + 1)[None, :] < emit[:, None],
-                    targets, 0)
-    new_lengths = lengths + emit
-    last_tok = jnp.take_along_axis(
-        targets, jnp.maximum(emit - 1, 0)[:, None], axis=1)[:, 0]
+    with jax.named_scope("kft.sample"):
+        targets = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, k+1]
+        # Longest accepted draft prefix (positions beyond draft_len never
+        # match), then +1 free token, clipped to the per-slot budget: a
+        # live slot always has stop_len - lengths >= 1 emission of room,
+        # so every advancing slot nets at least one token per call — a
+        # verify call never delivers less than a decode step would.
+        pos = jnp.arange(k)[None, :]
+        match = (draft.astype(jnp.int32) == targets[:, :k]) \
+            & (pos < draft_len[:, None])
+        accepted = jnp.sum(
+            jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
+        emit = jnp.minimum(accepted + 1,
+                           jnp.maximum(state["stop_len"] - lengths, 0))
+        if decode.eos_token >= 0:
+            is_eos = targets == decode.eos_token
+            eos_cut = jnp.where(jnp.any(is_eos, axis=1),
+                                jnp.argmax(is_eos, axis=1) + 1, k + 2)
+            done_eos = advance & (eos_cut <= emit)
+            emit = jnp.minimum(emit, eos_cut)
+        else:
+            done_eos = jnp.zeros_like(done)
+        emit = jnp.where(advance, emit, 0)
+        out = jnp.where(jnp.arange(k + 1)[None, :] < emit[:, None],
+                        targets, 0)
+        new_lengths = lengths + emit
+        last_tok = jnp.take_along_axis(
+            targets, jnp.maximum(emit - 1, 0)[:, None], axis=1)[:, 0]
     state = dict(state)
     state["cache_k"], state["cache_v"] = ck, cv
     state["lengths"] = new_lengths
@@ -955,42 +974,43 @@ def prefill_chunk_into_slot(
     logits, (ck, cv) = _forward_with_cache(
         cfg, params, tokens, (state["cache_k"], state["cache_v"]),
         start, tables=table_row, adapter_ids=aid[None])
-    # First-token sampling from the last REAL prompt position of this
-    # chunk (only meaningful on the final chunk; clamped otherwise).
-    idx = jnp.clip(prompt_len - 1 - start, 0, w - 1)
-    last = jnp.take_along_axis(
-        logits, jnp.reshape(idx, (1, 1, 1)), axis=1)[:, 0]  # [1, V]
-    useed = jnp.reshape(seed, (1,)).astype(jnp.uint32)
-    keys = jnp.stack([jnp.zeros_like(useed), useed], axis=-1)
-    split = jax.vmap(jax.random.split)(keys)
-    keys, subs = split[:, 0], split[:, 1]
-    if decode.temperature <= 0.0:
-        tok = jnp.argmax(last, axis=-1)
-    else:
-        tok = jax.vmap(jax.random.categorical)(
-            subs, _filter_logits(decode, last))
-    tok = tok.astype(jnp.int32)
+    with jax.named_scope("kft.sample"):
+        # First-token sampling from the last REAL prompt position of this
+        # chunk (only meaningful on the final chunk; clamped otherwise).
+        idx = jnp.clip(prompt_len - 1 - start, 0, w - 1)
+        last = jnp.take_along_axis(
+            logits, jnp.reshape(idx, (1, 1, 1)), axis=1)[:, 0]  # [1, V]
+        useed = jnp.reshape(seed, (1,)).astype(jnp.uint32)
+        keys = jnp.stack([jnp.zeros_like(useed), useed], axis=-1)
+        split = jax.vmap(jax.random.split)(keys)
+        keys, subs = split[:, 0], split[:, 1]
+        if decode.temperature <= 0.0:
+            tok = jnp.argmax(last, axis=-1)
+        else:
+            tok = jax.vmap(jax.random.categorical)(
+                subs, _filter_logits(decode, last))
+        tok = tok.astype(jnp.int32)
 
-    is_last = (start + w) >= prompt_len
-    final_slot = jnp.where(is_last, slot, slots_n)  # OOB mid-prefill
-    stop = prompt_len + jnp.maximum(new_tokens, 1) - 1
-    done_final = new_tokens <= 1
-    if decode.eos_token >= 0:
-        done_final = done_final | (tok[0] == decode.eos_token)
+        is_last = (start + w) >= prompt_len
+        final_slot = jnp.where(is_last, slot, slots_n)  # OOB mid-prefill
+        stop = prompt_len + jnp.maximum(new_tokens, 1) - 1
+        done_final = new_tokens <= 1
+        if decode.eos_token >= 0:
+            done_final = done_final | (tok[0] == decode.eos_token)
 
-    state = dict(state)
-    state["cache_k"], state["cache_v"] = ck, cv
-    if "adapter_ids" in state:
-        state["adapter_ids"] = state["adapter_ids"].at[slot].set(aid)
-    state["done"] = state["done"].at[slot].set(True)
-    state["done"] = state["done"].at[final_slot].set(
-        done_final, mode="drop")
-    state["lengths"] = state["lengths"].at[final_slot].set(
-        prompt_len, mode="drop")
-    state["stop_len"] = state["stop_len"].at[final_slot].set(
-        stop, mode="drop")
-    state["last_token"] = state["last_token"].at[final_slot].set(
-        tok[0], mode="drop")
-    state["keys"] = state["keys"].at[final_slot].set(
-        keys[0], mode="drop")
+        state = dict(state)
+        state["cache_k"], state["cache_v"] = ck, cv
+        if "adapter_ids" in state:
+            state["adapter_ids"] = state["adapter_ids"].at[slot].set(aid)
+        state["done"] = state["done"].at[slot].set(True)
+        state["done"] = state["done"].at[final_slot].set(
+            done_final, mode="drop")
+        state["lengths"] = state["lengths"].at[final_slot].set(
+            prompt_len, mode="drop")
+        state["stop_len"] = state["stop_len"].at[final_slot].set(
+            stop, mode="drop")
+        state["last_token"] = state["last_token"].at[final_slot].set(
+            tok[0], mode="drop")
+        state["keys"] = state["keys"].at[final_slot].set(
+            keys[0], mode="drop")
     return state, tok

@@ -195,6 +195,7 @@ def _flash_fwd_bhsd(
         inputs.append(kv_start.astype(jnp.int32))
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype, vma=vma),
             # lse kept as a trailing-singleton column so every kernel
@@ -409,6 +410,7 @@ def _flash_fwd_two_pass(
                 _flash_fwd_full_kernel, scale=scale,
                 block_q=block_q, block_k=block_k,
             ),
+            name="flash_fwd_full",
             out_shape=out_shape,
             grid=(bh, nq, n_full_max),
             in_specs=[
@@ -466,6 +468,7 @@ def _flash_fwd_two_pass(
             _flash_fwd_diag_kernel, scale=scale, block_q=block_q,
             block_k=block_k, block_diag=block_diag,
         ),
+        name="flash_fwd_diag",
         out_shape=out_shape,
         grid=(bh, sq // block_diag, nband),
         in_specs=[
@@ -639,6 +642,7 @@ def _flash_bwd_bhsd(
             _flash_dq_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k,
         ),
+        name="flash_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype, vma=vma),
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[
@@ -659,6 +663,7 @@ def _flash_bwd_bhsd(
             _flash_dkv_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k,
         ),
+        name="flash_bwd_dkv",
         out_shape=[
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype, vma=vma),

@@ -146,16 +146,26 @@ def configure_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns the directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
-    this sets nothing.  Otherwise the cache goes to ``<checkout>/.jax_cache``
-    — a fixed path, because the directory is part of the cache key: one
-    that moved between two processes would never hit.  Call before the
-    first compile; every entrypoint does so first thing.
+    this places nothing.  Otherwise the cache goes to
+    ``<checkout>/.jax_cache`` — a fixed path, because the directory is
+    part of the cache key: one that moved between two processes would
+    never hit.  Call before the first compile; every entrypoint does so
+    first thing.
+
+    Either way the key takes the programs' METADATA in (source lines,
+    ``jax.named_scope`` names).  By default JAX leaves it out, and an
+    executable compiled by another commit is then served with that
+    commit's metadata: a profile of this commit's ``kft.*`` scopes showed
+    none, because the parent commit had filled the cache (found on the
+    chip, PR 24).  The price is a cold compile per checkout and per
+    change to a traced source line.
     """
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     path = str(_CHECKOUT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

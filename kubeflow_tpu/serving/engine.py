@@ -174,6 +174,23 @@ KV_SPILL_HELP = \
     "paged-KV pages crossing the host spill tier, by engine and " \
     "direction (out = device pages evacuated to host, in = host " \
     "pages re-imported at admission)"
+LOOP_SECONDS_TOTAL = "kft_engine_loop_seconds_total"
+LOOP_SECONDS_HELP = \
+    "wall seconds of the engine's loop thread, by engine and phase " \
+    "of an iteration (the phases tile it; round_wait is the host " \
+    "blocked on the device)"
+QUEUE_WAIT_TOTAL = "kft_engine_queue_wait_seconds_total"
+QUEUE_WAIT_HELP = \
+    "seconds admitted requests waited from submit to slot claim, " \
+    "summed, by engine"
+PREFILL_SPAN_TOTAL = "kft_engine_prefill_span_seconds_total"
+PREFILL_SPAN_HELP = \
+    "seconds from slot claim to a request's first token, summed, " \
+    "by engine"
+COMPILE_SECONDS_TOTAL = "kft_engine_compile_seconds_total"
+COMPILE_SECONDS_HELP = \
+    "wall seconds spent lowering and compiling the engine's AOT " \
+    "programs, by engine"
 ADAPTER_REQUESTS_TOTAL = "kft_engine_adapter_requests_total"
 ADAPTER_REQUESTS_HELP = \
     "requests admitted naming an adapter variant, by engine and " \
@@ -223,6 +240,67 @@ _ROUND_PACE_ALPHA = 0.2
 
 
 _NO_DRAFT = np.empty((0,), np.int32)
+
+# The phases that tile one iteration of DecodeEngine._run (see _Phase).
+_PHASES = ("wait_work", "admit", "housekeeping", "prefill_dispatch",
+           "round_prepare", "round_dispatch", "overlap", "round_wait",
+           "drain", "account")
+_PHASE_KEY = {p: f"loop_{p}_s" for p in _PHASES}
+_PHASE_NOTE = {p: f"kft.engine.{p}" for p in _PHASES}
+# Cumulative counters that stats() hands out under their own names (a
+# window is two readings subtracted).  Where the loop thread's wall time
+# went, by phase (_Phase), and its iterations; per request, submit ->
+# slot claim over the requests admitted, slot claim -> first token over
+# the first tokens delivered, and the latter again over the requests
+# that resumed a cached prefix; wall seconds of every AOT compile and
+# the largest program by the compiler's own account (arguments + outputs
+# + temporaries - aliased: memory_stats() leaves temporaries out).
+_SUM_KEYS = ("loop_rounds", *_PHASE_KEY.values(),
+             "queue_wait_s_sum", "admitted",
+             "prefill_span_s_sum", "first_tokens",
+             "prefill_span_hit_s_sum", "first_tokens_hit",
+             "compile_s", "compiled_peak_bytes")
+
+
+class _Phase:
+    """One phase of a loop iteration, on the loop thread only: a
+    ``jax.profiler`` host annotation ``kft.engine.<name>`` (inert while
+    no trace records; it puts the phase on the device trace's clock, so
+    an idle gap of the chip has an owner) and, on exit, the phase's OWN
+    wall time added to ``stats()``'s cumulative ``loop_<name>_s``.  A
+    phase entered inside another is subtracted from it, so the ten sums
+    tile the thread's wall time; a window is two readings subtracted."""
+
+    __slots__ = ("_engine", "_key", "_note", "_t0", "_inner")
+
+    def __init__(self, engine, name, facts):
+        self._engine = engine
+        self._key = _PHASE_KEY[name]
+        self._note = engine._annotate(
+            _PHASE_NOTE[name], round=engine._counters["loop_rounds"],
+            **facts)
+
+    def __enter__(self):
+        self._note.__enter__()
+        self._inner = 0.0
+        self._engine._phases.append(self)
+        self._t0 = time.perf_counter()
+        return self
+
+    def facts(self, **facts):
+        """Facts known only at the phase's end."""
+        self._note.set_metadata(**facts)
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        stack = self._engine._phases
+        stack.pop()
+        if stack:
+            stack[-1]._inner += dt
+        # Loop-thread-owned keys: one writer, stats() copies the dict.
+        self._engine._counters[self._key] += dt - self._inner
+        self._note.__exit__(*exc)
+        return False
 
 
 def _ngram_propose(history: np.ndarray, k: int,
@@ -609,7 +687,13 @@ class DecodeEngine:
             "fused_rounds": 0, "fused_steps_wasted": 0,
             "spill_pages_out": 0, "spill_pages_in": 0,
             "parked_sessions": 0, "fetches": 0,
+            **dict.fromkeys(_SUM_KEYS, 0),
         }
+        import jax
+
+        self._annotate = jax.profiler.TraceAnnotation
+        self._phases: List[_Phase] = []  # loop-thread-owned stack
+        self._loop_pushed = dict.fromkeys(_PHASE_KEY.values(), 0.0)
         self._step_times: List[float] = []   # bounded reservoirs
         self._chunk_times: List[float] = []
         self._gap_times: List[float] = []
@@ -666,6 +750,14 @@ class DecodeEngine:
             KV_SPILL_TOTAL, KV_SPILL_HELP)
         self._adapter_req_ctr = REGISTRY.counter(
             ADAPTER_REQUESTS_TOTAL, ADAPTER_REQUESTS_HELP)
+        self._loop_ctr = REGISTRY.counter(
+            LOOP_SECONDS_TOTAL, LOOP_SECONDS_HELP)
+        self._queue_wait_ctr = REGISTRY.counter(
+            QUEUE_WAIT_TOTAL, QUEUE_WAIT_HELP)
+        self._prefill_span_ctr = REGISTRY.counter(
+            PREFILL_SPAN_TOTAL, PREFILL_SPAN_HELP)
+        self._compile_ctr = REGISTRY.counter(
+            COMPILE_SECONDS_TOTAL, COMPILE_SECONDS_HELP)
         # Fault-layer series: same names as the static batchers', so
         # shed/expired rates read uniformly across batching planes.
         self._shed_ctr = REGISTRY.counter(SHED_TOTAL, SHED_HELP)
@@ -921,7 +1013,10 @@ class DecodeEngine:
         # Trace context captured on the transport thread; the loop
         # thread stamps spans from perf readings at drain time (never
         # per token), so the hot step loop stays untouched and a
-        # disabled tracer costs one None check per site.
+        # disabled tracer costs one None check per site.  The perf
+        # readings themselves are always taken (submit, slot claim,
+        # first token, delivery): the same stamps feed the spans and
+        # the always-kept queue-wait / prefill-span sums of stats().
         # Worst-case paged-KV reservation: every position the request
         # could ever write (prompt + full budget) in whole pages.
         # Reserving it at admission is what makes block exhaustion a
@@ -932,8 +1027,7 @@ class DecodeEngine:
             "tokens": tokens, "new": new, "seed": seed,
             "emitted": [], "scheduled": 0, "slot": None,
             "trace": trace_ctx,
-            "t_perf": time.perf_counter()
-            if trace_ctx is not None else 0.0,
+            "t_perf": time.perf_counter(), "t_claim_perf": None,
             "t_first_perf": None, "spec_acc": 0,
             "prefilling": False, "pos": 0, "cached": 0,
             "res_blocks": res_blocks, "res_left": 0, "blocks": [],
@@ -1210,11 +1304,16 @@ class DecodeEngine:
             # observable over the :stats route (the hermetic engine
             # e2e asserts it end to end).
             "compiled_programs": self.compiled_programs(),
-            # Chunked prefill: calls made and their latency — one chunk
-            # is the most an arriving prompt may stall in-flight decode
-            # per scheduling turn.
+            # Chunked prefill: calls made and how long the host took
+            # to DISPATCH one (the call returns at enqueue; the chunk's
+            # compute is in the next round's wait) — one chunk is the
+            # most an arriving prompt may stall in-flight decode per
+            # scheduling turn.
             "prefill_chunks": c["prefill_chunks"],
             "prefill_chunk_p95_ms": pct(chunks, 0.95),
+            # Where the time goes, cumulative (see _SUM_KEYS): the
+            # loop's phases, queue wait, prefill span, compiles.
+            **{key: c[key] for key in _SUM_KEYS},
             "mean_occupancy": round(c["occupancy_sum"] / steps, 2)
             if steps else 0.0,
             "tokens_per_sec": round(c["tokens"] / c["busy_s"], 1)
@@ -1282,6 +1381,39 @@ class DecodeEngine:
         return mesh_devices(self.mesh)
 
     # -- step loop --------------------------------------------------------
+
+    def _phase(self, name: str, **facts) -> _Phase:
+        """``with self._phase("drain"):`` — loop thread only."""
+        return _Phase(self, name, facts)
+
+    def _aot(self, fn, *args):
+        """``fn.lower(*args).compile()`` for every AOT program of the
+        engine, with the wall time it took added to ``compile_s`` and
+        the compiler's own account of the program's memory kept as
+        ``compiled_peak_bytes`` (the largest over the programs)."""
+        t0 = time.perf_counter()
+        compiled = fn.lower(*args).compile()
+        dt = time.perf_counter() - t0
+        mem = compiled.memory_analysis()
+        peak = 0 if mem is None else int(
+            mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+        with self._lock:
+            self._counters["compile_s"] += dt
+            self._counters["compiled_peak_bytes"] = max(
+                self._counters["compiled_peak_bytes"], peak)
+        self._compile_ctr.inc(dt, engine=self._metric_name)
+        return compiled
+
+    def _push_loop_seconds(self) -> None:
+        """The phase sums, to the registry ``/metrics`` serves: once
+        an iteration, each phase's growth since the last push."""
+        for phase, key in _PHASE_KEY.items():
+            total = self._counters[key]
+            if total > self._loop_pushed[key]:
+                self._loop_ctr.inc(total - self._loop_pushed[key],
+                                   engine=self._metric_name, phase=phase)
+                self._loop_pushed[key] = total
 
     def _free_slots_locked(self) -> List[int]:
         return [i for i, r in enumerate(self._slot_req) if r is None]
@@ -1597,8 +1729,8 @@ class DecodeEngine:
         pages_k = self._pad_pages(pages["k"], span)
         pages_v = self._pad_pages(pages["v"], span)
         if self._import_exec is None:
-            self._import_exec = import_kv_pages.lower(
-                self._state, pages_k, pages_v, ids).compile()
+            self._import_exec = self._aot(
+                import_kv_pages, self._state, pages_k, pages_v, ids)
         self._state = self._import_exec(
             self._state, pages_k, pages_v, ids)
         entry["pos"] = pages["covered"]
@@ -1956,7 +2088,13 @@ class DecodeEngine:
         # Chaos hook: sleep = slow admission; raise = device death at
         # admission (propagates to _abort, every waiter resolved).
         faults.fire("engine.admit")
+        # Queue wait (submit -> slot claim), stamped once: the sum
+        # every request feeds and the admission span of a traced one.
+        claimed = entry["t_claim_perf"] = time.perf_counter()
+        waited = claimed - entry["t_perf"]
         with self._lock:
+            self._counters["queue_wait_s_sum"] += waited
+            self._counters["admitted"] += 1
             self._counters["prompt_tokens"] += true_len
             if self.prefix_caching:
                 # Hit/miss accounting only when caching is ON — with
@@ -1967,6 +2105,7 @@ class DecodeEngine:
                     self._counters["cached_tokens"] += cached
                 else:
                     self._counters["prefix_misses"] += 1
+        self._queue_wait_ctr.inc(waited, engine=self._metric_name)
         if self.prefix_caching:
             (self._hits_ctr if cached else self._misses_ctr).inc(
                 engine=self._metric_name)
@@ -1978,7 +2117,7 @@ class DecodeEngine:
             # is no copy_ms to report.
             tracing.record_span(
                 "engine.admission", entry["trace"], entry["t_perf"],
-                time.perf_counter(),
+                claimed,
                 attrs={"engine": self._metric_name, "slot": slot,
                        "prompt_tokens": true_len,
                        "cached_tokens": cached,
@@ -2002,7 +2141,14 @@ class DecodeEngine:
     def _prefill_chunk(self, entry: dict) -> None:
         """One static-width chunk of one entry's prompt into its slot
         (dispatch only — the final chunk's first sampled token joins
-        the lagged pending stream)."""
+        the lagged pending stream).  The program call returns when the
+        chunk is ENQUEUED, so what is timed here (``_chunk_times``,
+        ``stats()["prefill_chunk_p95_ms"]``, the ``engine.prefill_chunk``
+        span) is the host's dispatch, not the chunk's compute: the
+        device runs the chunk ahead of the next round, whose wait
+        (``round_wait``, and ``busy_s`` through the round's timing)
+        covers it, and ``prefill_span_s_sum`` holds a request's whole
+        prefill from slot claim to first token."""
         from kubeflow_tpu.models.generate import prefill_chunk_into_slot
 
         w = self.chunk_w
@@ -2030,13 +2176,13 @@ class DecodeEngine:
                 # = base), so compiled_programs() never grows a
                 # per-adapter entry.
                 lower_args.append(np.int32(0))
-            self._chunk_exec = prefill_chunk_into_slot.lower(
-                *lower_args).compile()
+            self._chunk_exec = self._aot(
+                prefill_chunk_into_slot, *lower_args)
         call_args = [
             self.params, self._state, chunk,
             np.int32(start), np.int32(true_len), np.int32(entry["new"]),
             np.int32(entry["slot"]), np.int32(entry["seed"]),
-            self._tables[entry["slot"]:entry["slot"] + 1]]
+            self._tables[entry["slot"]:entry["slot"] + 1].copy()]
         if self._registry is not None:
             call_args.append(np.int32(entry.get("adapter", 0)))
         t0 = time.perf_counter()
@@ -2058,11 +2204,9 @@ class DecodeEngine:
                         salt=entry.get("adapter_salt", b""))
         with self._lock:
             self._counters["prefill_chunks"] += 1
-            # Prefill compute belongs in busy_s — tokens_per_sec must
-            # not count tokens whose cost was never measured (short-
-            # completion workloads would otherwise read up to ~2x the
-            # real rate).
-            self._counters["busy_s"] += dt
+            # NOT added to busy_s: dt is a dispatch.  The chunk's
+            # compute is inside the next round's timed wait, so
+            # tokens_per_sec still pays for it.
             self._chunk_times.append(dt)
             if len(self._chunk_times) > 4096:
                 del self._chunk_times[:2048]
@@ -2128,13 +2272,22 @@ class DecodeEngine:
         at EOS/budget on device, so row s carries counts[s] real
         tokens)."""
         arr, snapshot, counts = self._pending.pop(0)
-        host = np.asarray(arr)
+        if isinstance(arr, np.ndarray):
+            host = arr  # the round already waited for it
+        else:
+            # The blocking read of the device's tokens (the unfused
+            # step, a prefill's first token): the host waits on the
+            # chip here, not in the drain around it.
+            with self._phase("round_wait"):
+                host = np.asarray(arr)
+                if counts is not None:
+                    counts = np.asarray(counts)
         emitted = 0
         finished = 0
         finished_entries: List[dict] = []
         ttfts: List[float] = []
-        if counts is not None:
-            counts = np.asarray(counts)
+        span_s = span_hit_s = 0.0
+        firsts = firsts_hit = 0
         for col, entry in snapshot:
             if counts is not None:       # verify: row per slot
                 toks = host[col, :int(counts[col])]
@@ -2149,8 +2302,14 @@ class DecodeEngine:
                 tok = int(tok)
                 if entry["t_first"] is None:
                     entry["t_first"] = faults.monotonic()
-                    if entry["trace"] is not None:
-                        entry["t_first_perf"] = time.perf_counter()
+                    now = entry["t_first_perf"] = time.perf_counter()
+                    # Slot claim -> first token: the request's prefill.
+                    span = now - entry["t_claim_perf"]
+                    span_s += span
+                    firsts += 1
+                    if entry["cached"] > 0:
+                        span_hit_s += span
+                        firsts_hit += 1
                 entry["emitted"].append(tok)
                 if entry["hist"] is not None:
                     entry["hist"][entry["hist_len"]] = tok
@@ -2178,6 +2337,11 @@ class DecodeEngine:
             self._counters["tokens"] += emitted
             self._counters["requests"] += finished
             self._counters["in_flight"] -= finished
+            if firsts:
+                self._counters["prefill_span_s_sum"] += span_s
+                self._counters["first_tokens"] += firsts
+                self._counters["prefill_span_hit_s_sum"] += span_hit_s
+                self._counters["first_tokens_hit"] += firsts_hit
             # Delivered requests return their private KV pages to the
             # pool; published prefix pages stay resident as evictable
             # cache until LRU eviction needs them.
@@ -2190,6 +2354,8 @@ class DecodeEngine:
             self._emit.notify_all()
         if emitted:
             self._tok_counter.inc(emitted, engine=self._metric_name)
+        if firsts:
+            self._prefill_span_ctr.inc(span_s, engine=self._metric_name)
 
     @staticmethod
     def _blend_rate(ema, rate):
@@ -2406,103 +2572,112 @@ class DecodeEngine:
         from kubeflow_tpu.models.generate import decode_rounds
 
         kmax = self.decode_rounds
-        width = self._round_width()
-        snapshot = [(i, r) for i, r in enumerate(self._slot_req)
-                    if r is not None and not r["prefilling"]]
-        # Worst-case cover for the WHOLE round before dispatch: the
-        # device may write `width` new positions per slot and the
-        # block tables ride in as one host-owned snapshot.  The
-        # admission reservation guarantees the pages, so this never
-        # blocks.
-        for _, r in snapshot:
-            self._ensure_cover(
-                r, r["tokens"].shape[1] + r["scheduled"] + width - 1)
-        if self._rounds_exec is None:
-            # One executable serves EVERY adaptive width: the buffer
-            # size k is static, the per-round step cap is a traced
-            # operand.  Built outside the timed window (compile must
-            # not pollute the step percentiles).
-            self._rounds_exec = decode_rounds.lower(
-                self.cfg, self.params, self._state, self.decode, kmax,
-                self._tables, np.int32(kmax)).compile()
-            if self.mesh is not None:
-                # The double-buffered upload must land the tables
-                # exactly where the SPMD executable expects them.
-                self._tables_sharding = \
-                    self._rounds_exec.input_shardings[0][2]
-        if self._tables_dirty:
-            self._refresh_tables_dev()
-        tables = (self._tables_dev if self._tables_dev is not None
-                  else self._tables)
-        # Chaos hook: the same site as the unfused step — injected
-        # stalls/deaths hit fused rounds identically (deadlines expire
-        # mid-round, _abort resolves waiters).
-        faults.fire("engine.step")
-        tok_before = self._counters["tokens"]
+        with self._phase("round_prepare"):
+            width = self._round_width()
+            snapshot = [(i, r) for i, r in enumerate(self._slot_req)
+                        if r is not None and not r["prefilling"]]
+            # Worst-case cover for the WHOLE round before dispatch: the
+            # device may write `width` new positions per slot and the
+            # block tables ride in as one host-owned snapshot.  The
+            # admission reservation guarantees the pages, so this never
+            # blocks.
+            for _, r in snapshot:
+                self._ensure_cover(
+                    r, r["tokens"].shape[1] + r["scheduled"] + width - 1)
+            if self._rounds_exec is None:
+                # One executable serves EVERY adaptive width: the buffer
+                # size k is static, the per-round step cap is a traced
+                # operand.  Built outside the timed window (compile must
+                # not pollute the step percentiles).
+                self._rounds_exec = self._aot(
+                    decode_rounds, self.cfg, self.params, self._state,
+                    self.decode, kmax, self._tables, np.int32(kmax))
+                if self.mesh is not None:
+                    # The double-buffered upload must land the tables
+                    # exactly where the SPMD executable expects them.
+                    self._tables_sharding = \
+                        self._rounds_exec.input_shardings[0][2]
+            if self._tables_dirty:
+                self._refresh_tables_dev()
+            tables = (self._tables_dev if self._tables_dev is not None
+                      else self._tables)
+            # Chaos hook: the same site as the unfused step — injected
+            # stalls/deaths hit fused rounds identically (deadlines
+            # expire mid-round, _abort resolves waiters).
+            faults.fire("engine.step")
+            tok_before = self._counters["tokens"]
         t0 = time.perf_counter()
-        self._state, toks, counts, steps_run = self._rounds_exec(
-            self.params, self._state, tables, np.int32(width))
+        with self._phase("round_dispatch", width=width, live=live):
+            self._state, toks, counts, steps_run = self._rounds_exec(
+                self.params, self._state, tables, np.int32(width))
         # ---- overlap window: the dispatch returned as soon as the
         # round was enqueued; everything until the np.asarray below
         # runs while the device computes.
-        # Deterministic retirement at dispatch: with no EOS a slot
-        # whose remaining budget fits this round is KNOWN to finish —
-        # the loop early-exits only when EVERY slot is done, so it can
-        # never stop short of a still-advancing slot's budget.
-        for i, r in snapshot:
-            r["scheduled"] = min(r["new"], r["scheduled"] + width)
-            if not self._eos and r["scheduled"] >= r["new"]:
-                # Loop-thread-owned (see _drain_one).
-                # kft: allow=lock-guard
-                self._slot_req[i] = None
-        # Double buffer: grow the NEXT round's covers and start their
-        # table upload now, so the next dispatch finds the transfer
-        # already done (or at least in flight) instead of paying it on
-        # the critical path.
-        for i, r in snapshot:
-            if self._slot_req[i] is r:
-                self._ensure_cover(
-                    r, r["tokens"].shape[1] + r["scheduled"] + kmax - 1)
-        if self._tables_dirty:
-            self._refresh_tables_dev()
-        # Overlapped drafting for the next boundary's verify round.
-        if self.speculative_tokens:
-            self._draft_ahead(snapshot, width)
-        # Overlapped spill (§5.10): evacuate one cold record while the
-        # round computes — the gather is enqueued behind the in-flight
-        # round, so the host blocks at most where it would block on
-        # the round's tokens anyway, and pool pressure drains in the
-        # window PR 16 opened instead of on the admission path.
-        if self.host_spill_blocks:
-            self._spill_tick(1)
+        with self._phase("overlap"):
+            # Deterministic retirement at dispatch: with no EOS a slot
+            # whose remaining budget fits this round is KNOWN to finish
+            # — the loop early-exits only when EVERY slot is done, so it
+            # can never stop short of a still-advancing slot's budget.
+            for i, r in snapshot:
+                r["scheduled"] = min(r["new"], r["scheduled"] + width)
+                if not self._eos and r["scheduled"] >= r["new"]:
+                    # Loop-thread-owned (see _drain_one).
+                    # kft: allow=lock-guard
+                    self._slot_req[i] = None
+            # Double buffer: grow the NEXT round's covers and start
+            # their table upload now, so the next dispatch finds the
+            # transfer already done (or at least in flight) instead of
+            # paying it on the critical path.
+            for i, r in snapshot:
+                if self._slot_req[i] is r:
+                    self._ensure_cover(
+                        r, r["tokens"].shape[1] + r["scheduled"]
+                        + kmax - 1)
+            if self._tables_dirty:
+                self._refresh_tables_dev()
+            # Overlapped drafting for the next boundary's verify round.
+            if self.speculative_tokens:
+                self._draft_ahead(snapshot, width)
+            # Overlapped spill (§5.10): evacuate one cold record while
+            # the round computes — the gather is enqueued behind the
+            # in-flight round, so the host blocks at most where it
+            # would block on the round's tokens anyway, and pool
+            # pressure drains in the window PR 16 opened instead of on
+            # the admission path.
+            if self.host_spill_blocks:
+                self._spill_tick(1)
         # ---- round boundary: materialize ONCE, deliver, account.
-        toks_np = np.asarray(toks)
-        counts_np = np.asarray(counts)
-        steps = int(steps_run)
-        self._pending.append((toks_np, snapshot, counts_np))
-        while self._pending:
-            self._drain_one()
+        with self._phase("round_wait"):
+            toks_np = np.asarray(toks)
+            counts_np = np.asarray(counts)
+            steps = int(steps_run)
+            del toks, counts, steps_run  # freed here, inside a phase
+        with self._phase("drain"):
+            self._pending.append((toks_np, snapshot, counts_np))
+            while self._pending:
+                self._drain_one()
         end = time.perf_counter()
-        delivered = self._counters["tokens"] - tok_before
-        dispatched = steps * len(snapshot)
-        wasted = max(0, dispatched - delivered)
-        # Adaptive width (the PR 7 discipline on the round dimension):
-        # shrink on early-exit waste or a waiting admission, grow one
-        # step per full, waste-free round.
-        if dispatched and (self._queue
-                           or wasted > _ROUND_WASTE_FRAC * dispatched):
-            self._round_k = max(1, self._round_k // 2)
-        elif steps >= width and not wasted:
-            self._round_k = min(kmax, self._round_k + 1)
-        norm = max(1, steps)
-        self._record_step_timing(
-            t0, end, norm, steps=norm, occupancy=live * norm,
-            extra={"fused_rounds": 1, "fused_steps_wasted": wasted},
-            delivered=delivered, round_steps=steps)
-        self._fused_rounds_ctr.inc(1, engine=self._metric_name)
-        if wasted:
-            self._fused_wasted_ctr.inc(wasted,
-                                       engine=self._metric_name)
+        with self._phase("account"):
+            delivered = self._counters["tokens"] - tok_before
+            dispatched = steps * len(snapshot)
+            wasted = max(0, dispatched - delivered)
+            # Adaptive width (the PR 7 discipline on the round
+            # dimension): shrink on early-exit waste or a waiting
+            # admission, grow one step per full, waste-free round.
+            if dispatched and (self._queue
+                               or wasted > _ROUND_WASTE_FRAC * dispatched):
+                self._round_k = max(1, self._round_k // 2)
+            elif steps >= width and not wasted:
+                self._round_k = min(kmax, self._round_k + 1)
+            norm = max(1, steps)
+            self._record_step_timing(
+                t0, end, norm, steps=norm, occupancy=live * norm,
+                extra={"fused_rounds": 1, "fused_steps_wasted": wasted},
+                delivered=delivered, round_steps=steps)
+            self._fused_rounds_ctr.inc(1, engine=self._metric_name)
+            if wasted:
+                self._fused_wasted_ctr.inc(wasted,
+                                           engine=self._metric_name)
 
     def _collect_drafts(self):
         """Host-side n-gram drafting pass over the live slots.
@@ -2598,35 +2773,50 @@ class DecodeEngine:
         but-rejected token can never enter a published prefix page."""
         from kubeflow_tpu.models.generate import verify_step
 
-        # Cover every slot's verify window [len, len + k] with pages
-        # from its reservation BEFORE dispatch (accepted positions
-        # must land in real pages; positions past the reservation can
-        # only be rejected/past-budget and park on the sentinel).
-        for _, entry in snapshot:
-            self._ensure_cover(
-                entry, entry["tokens"].shape[1] + len(entry["emitted"])
-                + self.speculative_tokens)
-        if self._verify_exec is None:
-            self._verify_exec = verify_step.lower(
-                self.cfg, self.params, self._state, self.decode,
-                self.speculative_tokens, draft, draft_len,
-                self._tables).compile()
-        # Chaos hook: the same site as the decode step — injected
-        # stalls/deaths must hit speculative rounds identically
-        # (deadlines expire mid-verify, _abort resolves waiters).
-        faults.fire("engine.step")
+        with self._phase("round_prepare"):
+            # Cover every slot's verify window [len, len + k] with
+            # pages from its reservation BEFORE dispatch (accepted
+            # positions must land in real pages; positions past the
+            # reservation can only be rejected/past-budget and park on
+            # the sentinel).
+            for _, entry in snapshot:
+                self._ensure_cover(
+                    entry, entry["tokens"].shape[1]
+                    + len(entry["emitted"]) + self.speculative_tokens)
+            if self._verify_exec is None:
+                self._verify_exec = self._aot(
+                    verify_step, self.cfg, self.params, self._state,
+                    self.decode, self.speculative_tokens, draft,
+                    draft_len, self._tables)
+            # Chaos hook: the same site as the decode step — injected
+            # stalls/deaths must hit speculative rounds identically
+            # (deadlines expire mid-verify, _abort resolves waiters).
+            faults.fire("engine.step")
         t0 = time.perf_counter()
-        self._state, toks, counts = self._verify_exec(
-            self.params, self._state, draft, draft_len, self._tables)
+        with self._phase("round_dispatch",
+                         width=self.speculative_tokens + 1, live=live):
+            self._state, toks, counts = self._verify_exec(
+                self.params, self._state, draft, draft_len, self._tables)
         # Materialize ONCE and share the host copies with the drain —
         # a second device->host transfer per round would show up at
         # this call rate.
-        toks_np = np.asarray(toks)
-        counts_np = np.asarray(counts)
-        self._pending.append((toks_np, snapshot, counts_np))
-        while len(self._pending) > self.sync_lag:  # sync: drains all
-            self._drain_one()
+        with self._phase("round_wait"):
+            toks_np = np.asarray(toks)
+            counts_np = np.asarray(counts)
+            del toks, counts  # freed here, inside a phase
+        with self._phase("drain"):
+            self._pending.append((toks_np, snapshot, counts_np))
+            while len(self._pending) > self.sync_lag:  # sync: drains all
+                self._drain_one()
         end = time.perf_counter()
+        with self._phase("account"):
+            self._account_verify(snapshot, draft, draft_len, toks_np,
+                                 counts_np, t0, end, live)
+
+    def _account_verify(self, snapshot, draft, draft_len, toks_np,
+                        counts_np, t0, end, live: int) -> None:
+        """What a verify round's outcome does to the adaptive widths,
+        the page covers and the counters."""
         drafted = int(draft_len.sum())
         accepted = 0
         for col, entry in snapshot:
@@ -2689,282 +2879,309 @@ class DecodeEngine:
                                         engine=self._metric_name)
 
     def _run(self) -> None:
-        from kubeflow_tpu.models.generate import decode_step
-
+        """The loop thread.  Every statement of an iteration lies in
+        one ``_phase`` (admit with wait_work inside it, housekeeping,
+        prefill_dispatch, round_prepare, then one of the three step
+        paths' round_dispatch / overlap / round_wait / drain, account),
+        so the ``loop_*_s`` sums tile the thread's wall time and every
+        idle gap of the device falls into a named phase."""
         try:
             while True:
-                with self._lock:
-                    while (not self._queue
-                           and all(r is None for r in self._slot_req)
-                           and not self._pending and not self._stopped):
-                        self._work.wait()
-                    if self._stopped and not self._queue \
-                            and all(r is None for r in self._slot_req) \
-                            and not self._pending:
-                        return
-                    stopping = self._stopped
-                    past_drain = (stopping and self._drain_deadline
-                                  is not None and faults.monotonic()
-                                  > self._drain_deadline)
-                    expired = self._sweep_expired_locked()
-                    admissions = []
-                    if not stopping:
-                        free = self._free_slots_locked()
-                        while (free and self._queue
-                               and len(self._prefilling)
-                               + len(admissions) < self.admit_width):
-                            pick = self._fair_pick_locked()
-                            entry = self._queue[pick]
-                            plan = self._plan_blocks_locked(entry)
-                            if plan is None:
-                                # Tokens-resident admission bound: the
-                                # pool cannot reserve this request's
-                                # worst case yet.  It HOLDS its queue
-                                # position (no starvation — the pick
-                                # is stable until pages free) until
-                                # retirements free pages; submit sheds
-                                # new arrivals past the queue cap.
-                                break
-                            self._queue.pop(pick)
-                            self._fair_seq += 1
-                            self._fair_last[
-                                entry.get("adapter_name") or ""] = \
-                                self._fair_seq
-                            slot = free.pop(0)
-                            shared, cached = plan
-                            # Claim the slot and bump in_flight in the
-                            # same locked section that pops the queue:
-                            # stats() must never see queue_depth==0 AND
-                            # in_flight_requests==0 while a request is
-                            # live (monitors treat that as "drained"),
-                            # and an entry registered here is reachable
-                            # by _abort even if its prefill dispatch
-                            # dies.
-                            entry["slot"] = slot
-                            entry["cached"] = cached
-                            entry["pos"] = cached
-                            entry["blocks"] = list(shared)
-                            entry["res_left"] = \
-                                entry["res_blocks"] - len(shared)
-                            # Zero-copy prefix resume: the cached
-                            # blocks slide into the table's leading
-                            # entries; prefill starts at the cached
-                            # offset.
-                            row = self._tables[slot]
-                            row[:] = self.kv_pool_blocks
-                            row[:len(shared)] = shared
-                            self._tables_dirty = True
-                            self._slot_req[slot] = entry
-                            self._counters["in_flight"] += 1
-                            admissions.append((entry, slot))
-                        self._set_queue_gauge(len(self._queue))
-                self._fail_expired(expired)
-                if expired and self._prefilling:
-                    # Mid-prefill expiries leave the chunk schedule
-                    # (the sweep already released their pages and
-                    # parked their table rows); their frozen slots are
-                    # safe to reclaim (claim-time first-chunk freeze).
-                    self._prefilling = [
-                        p for p in self._prefilling
-                        if not any(p is e for e in expired)]
-                if past_drain:
-                    self._abort(RuntimeError(
-                        f"engine {self._metric_name!r} drain deadline "
-                        "exceeded at close"))
+                if not self._iterate():
                     return
-                if stopping:
-                    # Refuse queued work immediately; keep stepping only
-                    # to drain in-flight slots.
-                    self._fail_queue(BatcherClosed(
-                        f"engine {self._metric_name!r} is closed"))
-                if self._registry is not None:
-                    # Hot adapter load/evict (§5.11): fold any pending
-                    # stack version into params between dispatches —
-                    # live traffic never waits, in-flight rows are
-                    # never torn, and no program recompiles.
-                    self._apply_adapter_updates()
-                if self.host_spill_blocks:
-                    # Spill-then-admit (§5.10): evacuate LRU-cold idle
-                    # records to the host tier BEFORE this round's
-                    # take() calls (admission prefills below, chunk
-                    # budget, decode covers) can destroy-evict them —
-                    # pool pressure degrades to a host copy, not to
-                    # recompute.
-                    self._spill_tick()
-                for entry, slot in admissions:
-                    try:
-                        self._begin_prefill(entry, slot)
-                    except _SpillShed as exc:
-                        self._shed_admitted(entry, slot, str(exc))
-                # Chunked prefill BETWEEN decode steps, under the
-                # per-step token budget: the head admission (FIFO —
-                # oldest finishes first, best TTFT) gets chunks until
-                # the budget is spent, then the loop returns to
-                # decoding.  In-flight slots therefore stall at most
-                # ~budget prompt-tokens of prefill per step, no matter
-                # how long the arriving prompts are.
-                budget = self.prefill_chunk_tokens
-                while budget > 0 and self._prefilling:
-                    entry = self._prefilling[0]
-                    self._prefill_chunk(entry)
-                    budget -= self.chunk_w
-                    if not entry["prefilling"]:
-                        self._prefilling.pop(0)
-                self._set_occ_gauge(
-                    sum(r is not None for r in self._slot_req))
-                live = sum(1 for r in self._slot_req
-                           if r is not None and not r["prefilling"])
-                if live and self.speculative_tokens \
-                        and self.decode_rounds > 1:
-                    # Fused mode: the drafting scan already ran in the
-                    # PREVIOUS round's overlap window (_draft_ahead
-                    # owns the stride backoff there); harvest the
-                    # proposals that survived the in-flight round and
-                    # dispatch verify with no drafting stall on the
-                    # critical path.  Nothing harvested => plain fused
-                    # round below, which re-drafts while it computes.
-                    if any(e.get("spec_seed") for e, _ in admissions):
-                        self._spec_stride = 1
-                        self._spec_tick = self._spec_stride
-                        self._spec_probe = _SPEC_PROBE_EVERY
-                    drafts = self._harvest_ahead_drafts()
-                    if drafts is not None \
-                            and self._spec_gates_pass(drafts[2]):
-                        self._verify_round(*drafts, live)
-                        self._set_occ_gauge(sum(
-                            r is not None for r in self._slot_req))
-                        continue
-                elif live and self.speculative_tokens:
-                    # Speculation: draft host-side; when at least one
-                    # slot proposed, one verify call replaces this
-                    # round's decode step (undrafted slots ride along
-                    # at draft_len 0 and still net their one token).
-                    # No drafts => fall through to the plain decode
-                    # program — the adaptive backoff's no-regression
-                    # guarantee for low-acceptance traffic — and
-                    # stretch the scan stride so persistent
-                    # unrepetitive traffic stops paying even the scan.
-                    self._spec_tick += 1
-                    if any(e.get("spec_seed") for e, _ in admissions):
-                        # A draftable prompt arrived: scan next round
-                        # and let the first drafted round probe even
-                        # if earlier traffic measured speculation
-                        # unprofitable — a new request is a new
-                        # regime.
-                        self._spec_stride = 1
-                        self._spec_tick = self._spec_stride
-                        self._spec_probe = _SPEC_PROBE_EVERY
-                    if self._spec_tick >= self._spec_stride:
-                        self._spec_tick = 0
-                        drafts = self._collect_drafts()
-                        if drafts is None:
-                            # Truly EMPTY scan (nothing proposed):
-                            # stretch the scan period.  Gate-blocked
-                            # rounds below do NOT — proposals exist,
-                            # so the scan stays productive and the
-                            # probe cadence stays honest.
-                            self._spec_stride = min(
-                                self._spec_stride * 2,
-                                _SPEC_SCAN_STRIDE_MAX)
-                        else:
-                            self._spec_stride = 1
-                            if self._spec_gates_pass(drafts[2]):
-                                self._verify_round(*drafts, live)
-                                self._set_occ_gauge(sum(
-                                    r is not None
-                                    for r in self._slot_req))
-                                continue
-                if live and self.decode_rounds > 1:
-                    self._fused_round(live)
-                elif live:
-                    k = self.steps_per_call
-                    # Cover every advancing slot's next k write
-                    # positions with pages from its admission
-                    # reservation BEFORE dispatch (the reservation
-                    # guarantees them, so this can never block); slots
-                    # already done on device write nothing, and the
-                    # cover cap at res_blocks bounds what an EOS-lagged
-                    # slot can take to pages it had reserved anyway.
-                    for r in self._slot_req:
-                        if r is None or r["prefilling"]:
-                            continue
-                        self._ensure_cover(
-                            r, r["tokens"].shape[1]
-                            + r["scheduled"] + k - 1)
-                    # Build (one-time) OUTSIDE the timed window: the
-                    # first per-token latency sample must not carry
-                    # seconds of XLA compile into the p50/p95 stats and
-                    # the step histogram.
-                    if self._step_exec is None:
-                        self._step_exec = decode_step.lower(
-                            self.cfg, self.params, self._state,
-                            self.decode, k, self._tables).compile()
-                    # Chaos hook: sleep = slow/wedged step (deadlines
-                    # expire mid-generation); raise = device death.
-                    # Outside the timed window so the injected stall
-                    # does not masquerade as device latency in the
-                    # step histogram.
-                    faults.fire("engine.step")
-                    # Counter read is loop-thread-local (the sync
-                    # drain below merges into it on this same thread):
-                    # the delta across the drain is the tokens this
-                    # round actually DELIVERED — post-EOS/post-budget
-                    # fused steps emit nothing, so live*k would
-                    # overstate the decode rate and the throughput
-                    # gate would suppress profitable speculation.
-                    tok_before = (self._counters["tokens"]
-                                  if self.speculative_tokens else 0)
-                    t0 = time.perf_counter()
-                    self._state, sampled = self._step_exec(
-                        self.params, self._state, self._tables)
-                    self._pending.append((sampled, [
-                        (i, r) for i, r in enumerate(self._slot_req)
-                        if r is not None and not r["prefilling"]], None))
-                    # Deterministic retirement: with no EOS in play a
-                    # request's completion step is known at dispatch —
-                    # free the slot NOW so the next admission overlaps
-                    # the lagged read instead of waiting for it.  The
-                    # request stays visible in in_flight until its
-                    # lagged emission is delivered.
-                    for i, r in enumerate(self._slot_req):
-                        if r is None or r["prefilling"]:
-                            continue
-                        r["scheduled"] = min(r["new"],
-                                             r["scheduled"] + k)
-                        if not self._eos and r["scheduled"] >= r["new"]:
-                            # Loop-thread-owned (see _drain_one).
-                            # kft: allow=lock-guard
-                            self._slot_req[i] = None
-                    while len(self._pending) > self.sync_lag:
-                        self._drain_one()
-                    end = time.perf_counter()
-                    # Per-call latency and gap normalized by fused
-                    # steps: what a client streaming tokens would see
-                    # between tokens, including interleaved
-                    # admission/prefill work.  The delivered-token
-                    # delta feeds the speculation throughput gate its
-                    # decode-side comparison rate (same currency as
-                    # the verify side's counts sum).
-                    self._record_step_timing(
-                        t0, end, k, steps=k, occupancy=live * k,
-                        delivered=(self._counters["tokens"] - tok_before
-                                   if self.speculative_tokens else None))
-                else:
-                    self._last_step_end = None
-                    if not self._prefilling:
-                        while self._pending:
-                            self._drain_one()
-                self._set_occ_gauge(
-                    sum(r is not None for r in self._slot_req))
-                # Pages resident (loop thread is the pool's only
-                # mutator; the guarded setter only touches the locked
-                # registry on change).
-                self._set_kv_used_gauge(self._mgr.used_blocks())
-                if self.host_spill_blocks:
-                    self._set_kv_spilled_gauge(
-                        self._mgr.host_used_blocks())
         except BaseException as exc:  # noqa: BLE001 — fail loudly to waiters
             self._abort(exc)
+
+    def _iterate(self) -> bool:
+        """One iteration of the loop; False once the engine is closed
+        and drained."""
+        # Loop-thread-owned key (one writer; stats() copies the dict),
+        # bumped before the first phase so that it can name the round.
+        # kft: allow=lock-guard
+        self._counters["loop_rounds"] += 1
+        with self._phase("admit"), self._lock:
+            with self._phase("wait_work"):
+                while (not self._queue
+                       and all(r is None for r in self._slot_req)
+                       and not self._pending and not self._stopped):
+                    self._work.wait()
+            if self._stopped and not self._queue \
+                    and all(r is None for r in self._slot_req) \
+                    and not self._pending:
+                return False
+            stopping = self._stopped
+            past_drain = (stopping and self._drain_deadline
+                          is not None and faults.monotonic()
+                          > self._drain_deadline)
+            expired = self._sweep_expired_locked()
+            admissions = []
+            if not stopping:
+                free = self._free_slots_locked()
+                while (free and self._queue
+                       and len(self._prefilling)
+                       + len(admissions) < self.admit_width):
+                    pick = self._fair_pick_locked()
+                    entry = self._queue[pick]
+                    plan = self._plan_blocks_locked(entry)
+                    if plan is None:
+                        # Tokens-resident admission bound: the pool
+                        # cannot reserve this request's worst case yet.
+                        # It HOLDS its queue position (no starvation —
+                        # the pick is stable until pages free) until
+                        # retirements free pages; submit sheds new
+                        # arrivals past the queue cap.
+                        break
+                    self._queue.pop(pick)
+                    self._fair_seq += 1
+                    self._fair_last[
+                        entry.get("adapter_name") or ""] = \
+                        self._fair_seq
+                    slot = free.pop(0)
+                    shared, cached = plan
+                    # Claim the slot and bump in_flight in the same
+                    # locked section that pops the queue: stats() must
+                    # never see queue_depth==0 AND
+                    # in_flight_requests==0 while a request is live
+                    # (monitors treat that as "drained"), and an entry
+                    # registered here is reachable by _abort even if
+                    # its prefill dispatch dies.
+                    entry["slot"] = slot
+                    entry["cached"] = cached
+                    entry["pos"] = cached
+                    entry["blocks"] = list(shared)
+                    entry["res_left"] = \
+                        entry["res_blocks"] - len(shared)
+                    # Zero-copy prefix resume: the cached blocks slide
+                    # into the table's leading entries; prefill starts
+                    # at the cached offset.
+                    row = self._tables[slot]
+                    row[:] = self.kv_pool_blocks
+                    row[:len(shared)] = shared
+                    self._tables_dirty = True
+                    self._slot_req[slot] = entry
+                    self._counters["in_flight"] += 1
+                    admissions.append((entry, slot))
+                self._set_queue_gauge(len(self._queue))
+        with self._phase("housekeeping"):
+            self._fail_expired(expired)
+            if expired and self._prefilling:
+                # Mid-prefill expiries leave the chunk schedule (the
+                # sweep already released their pages and parked their
+                # table rows); their frozen slots are safe to reclaim
+                # (claim-time first-chunk freeze).
+                self._prefilling = [
+                    p for p in self._prefilling
+                    if not any(p is e for e in expired)]
+            if past_drain:
+                self._abort(RuntimeError(
+                    f"engine {self._metric_name!r} drain deadline "
+                    "exceeded at close"))
+                return False
+            if stopping:
+                # Refuse queued work immediately; keep stepping only
+                # to drain in-flight slots.
+                self._fail_queue(BatcherClosed(
+                    f"engine {self._metric_name!r} is closed"))
+            if self._registry is not None:
+                # Hot adapter load/evict (§5.11): fold any pending
+                # stack version into params between dispatches — live
+                # traffic never waits, in-flight rows are never torn,
+                # and no program recompiles.
+                self._apply_adapter_updates()
+            if self.host_spill_blocks:
+                # Spill-then-admit (§5.10): evacuate LRU-cold idle
+                # records to the host tier BEFORE this round's take()
+                # calls (admission prefills below, chunk budget, decode
+                # covers) can destroy-evict them — pool pressure
+                # degrades to a host copy, not to recompute.
+                self._spill_tick()
+        with self._phase("prefill_dispatch") as phase:
+            chunks_before = self._counters["prefill_chunks"]
+            for entry, slot in admissions:
+                try:
+                    self._begin_prefill(entry, slot)
+                except _SpillShed as exc:
+                    self._shed_admitted(entry, slot, str(exc))
+            # Chunked prefill BETWEEN decode steps, under the per-step
+            # token budget: the head admission (FIFO — oldest finishes
+            # first, best TTFT) gets chunks until the budget is spent,
+            # then the loop returns to decoding.  In-flight slots
+            # therefore stall at most ~budget prompt-tokens of prefill
+            # per step, no matter how long the arriving prompts are.
+            budget = self.prefill_chunk_tokens
+            while budget > 0 and self._prefilling:
+                entry = self._prefilling[0]
+                self._prefill_chunk(entry)
+                budget -= self.chunk_w
+                if not entry["prefilling"]:
+                    self._prefilling.pop(0)
+            phase.facts(
+                admitted=len(admissions),
+                chunks=self._counters["prefill_chunks"] - chunks_before)
+        with self._phase("round_prepare"):
+            self._set_occ_gauge(
+                sum(r is not None for r in self._slot_req))
+            live = sum(1 for r in self._slot_req
+                       if r is not None and not r["prefilling"])
+            drafts = self._drafts_for_round(live, admissions) \
+                if live and self.speculative_tokens else None
+        if drafts is not None:
+            self._verify_round(*drafts, live)
+        elif live and self.decode_rounds > 1:
+            self._fused_round(live)
+        elif live:
+            self._step_round(live)
+        else:
+            with self._phase("drain"):
+                self._last_step_end = None
+                if not self._prefilling:
+                    while self._pending:
+                        self._drain_one()
+        with self._phase("account"):
+            self._set_occ_gauge(
+                sum(r is not None for r in self._slot_req))
+            # Pages resident (loop thread is the pool's only mutator;
+            # the guarded setter only touches the locked registry on
+            # change).
+            self._set_kv_used_gauge(self._mgr.used_blocks())
+            if self.host_spill_blocks:
+                self._set_kv_spilled_gauge(
+                    self._mgr.host_used_blocks())
+            self._push_loop_seconds()
+        return True
+
+    def _drafts_for_round(self, live: int, admissions):
+        """Speculation's verdict for this round: (snapshot, draft,
+        draft_len) when a verify call should replace the decode step,
+        else None (the plain decode program runs — the adaptive
+        backoff's no-regression guarantee for low-acceptance
+        traffic)."""
+        reseed = any(e.get("spec_seed") for e, _ in admissions)
+        if self.decode_rounds > 1:
+            # Fused mode: the drafting scan already ran in the PREVIOUS
+            # round's overlap window (_draft_ahead owns the stride
+            # backoff there); harvest the proposals that survived the
+            # in-flight round and dispatch verify with no drafting
+            # stall on the critical path.  Nothing harvested => plain
+            # fused round, which re-drafts while it computes.
+            if reseed:
+                self._spec_stride = 1
+                self._spec_tick = self._spec_stride
+                self._spec_probe = _SPEC_PROBE_EVERY
+            drafts = self._harvest_ahead_drafts()
+            if drafts is not None and self._spec_gates_pass(drafts[2]):
+                return drafts
+            return None
+        # Draft host-side; when at least one slot proposed, one verify
+        # call replaces this round's decode step (undrafted slots ride
+        # along at draft_len 0 and still net their one token).  No
+        # drafts => the plain decode program, and the scan stride
+        # stretches so persistent unrepetitive traffic stops paying
+        # even the scan.
+        self._spec_tick += 1
+        if reseed:
+            # A draftable prompt arrived: scan next round and let the
+            # first drafted round probe even if earlier traffic
+            # measured speculation unprofitable — a new request is a
+            # new regime.
+            self._spec_stride = 1
+            self._spec_tick = self._spec_stride
+            self._spec_probe = _SPEC_PROBE_EVERY
+        if self._spec_tick < self._spec_stride:
+            return None
+        self._spec_tick = 0
+        drafts = self._collect_drafts()
+        if drafts is None:
+            # Truly EMPTY scan (nothing proposed): stretch the scan
+            # period.  Gate-blocked rounds below do NOT — proposals
+            # exist, so the scan stays productive and the probe
+            # cadence stays honest.
+            self._spec_stride = min(self._spec_stride * 2,
+                                    _SPEC_SCAN_STRIDE_MAX)
+            return None
+        self._spec_stride = 1
+        return drafts if self._spec_gates_pass(drafts[2]) else None
+
+    def _step_round(self, live: int) -> None:
+        """One unfused decode call (decode_rounds == 1): every live
+        slot advances ``steps_per_call`` tokens; the host may run
+        ``sync_lag`` calls ahead of reading them."""
+        from kubeflow_tpu.models.generate import decode_step
+
+        k = self.steps_per_call
+        with self._phase("round_prepare"):
+            # Cover every advancing slot's next k write positions with
+            # pages from its admission reservation BEFORE dispatch (the
+            # reservation guarantees them, so this can never block);
+            # slots already done on device write nothing, and the cover
+            # cap at res_blocks bounds what an EOS-lagged slot can take
+            # to pages it had reserved anyway.
+            for r in self._slot_req:
+                if r is None or r["prefilling"]:
+                    continue
+                self._ensure_cover(
+                    r, r["tokens"].shape[1] + r["scheduled"] + k - 1)
+            # Build (one-time) OUTSIDE the timed window: the first
+            # per-token latency sample must not carry seconds of XLA
+            # compile into the p50/p95 stats and the step histogram.
+            if self._step_exec is None:
+                self._step_exec = self._aot(
+                    decode_step, self.cfg, self.params, self._state,
+                    self.decode, k, self._tables)
+            # Chaos hook: sleep = slow/wedged step (deadlines expire
+            # mid-generation); raise = device death.  Outside the timed
+            # window so the injected stall does not masquerade as
+            # device latency in the step histogram.
+            faults.fire("engine.step")
+            # Counter read is loop-thread-local (the sync drain below
+            # merges into it on this same thread): the delta across the
+            # drain is the tokens this round actually DELIVERED —
+            # post-EOS/post-budget fused steps emit nothing, so live*k
+            # would overstate the decode rate and the throughput gate
+            # would suppress profitable speculation.
+            tok_before = (self._counters["tokens"]
+                          if self.speculative_tokens else 0)
+        t0 = time.perf_counter()
+        with self._phase("round_dispatch", width=k, live=live):
+            # A COPY of the host tables: the call returns at enqueue,
+            # and on a backend that reads a numpy argument in place
+            # (XLA:CPU) the next admission's row reset would otherwise
+            # reach a step that has not run yet — the retired slot's
+            # last token then attends through a parked table.
+            self._state, sampled = self._step_exec(
+                self.params, self._state, self._tables.copy())
+        with self._phase("overlap"):
+            self._pending.append((sampled, [
+                (i, r) for i, r in enumerate(self._slot_req)
+                if r is not None and not r["prefilling"]], None))
+            # Deterministic retirement: with no EOS in play a request's
+            # completion step is known at dispatch — free the slot NOW
+            # so the next admission overlaps the lagged read instead of
+            # waiting for it.  The request stays visible in in_flight
+            # until its lagged emission is delivered.
+            for i, r in enumerate(self._slot_req):
+                if r is None or r["prefilling"]:
+                    continue
+                r["scheduled"] = min(r["new"], r["scheduled"] + k)
+                if not self._eos and r["scheduled"] >= r["new"]:
+                    # Loop-thread-owned (see _drain_one).
+                    # kft: allow=lock-guard
+                    self._slot_req[i] = None
+        with self._phase("drain"):
+            while len(self._pending) > self.sync_lag:
+                self._drain_one()
+        end = time.perf_counter()
+        with self._phase("account"):
+            # Per-call latency and gap normalized by fused steps: what
+            # a client streaming tokens would see between tokens,
+            # including interleaved admission/prefill work.  The
+            # delivered-token delta feeds the speculation throughput
+            # gate its decode-side comparison rate (same currency as
+            # the verify side's counts sum).
+            self._record_step_timing(
+                t0, end, k, steps=k, occupancy=live * k,
+                delivered=(self._counters["tokens"] - tok_before
+                           if self.speculative_tokens else None))
 
     def _fail_queue(self, exc: Exception) -> None:
         with self._lock:

@@ -28,15 +28,21 @@ def _env(**extra):
 
 
 class TestCompileCache:
-    def test_placed_from_outside_the_code_sets_nothing(self, monkeypatch):
+    METADATA_IN_KEY = ("jax_compilation_cache_include_metadata_in_key", True)
+
+    def test_placed_from_outside_the_code_sets_no_directory(
+            self, monkeypatch):
         """JAX reads JAX_COMPILATION_CACHE_DIR itself; the helper must not
-        set any directory on top of it."""
+        set any directory on top of it.  What it does set, here too: the
+        programs' metadata (named scopes, source lines) is part of the key,
+        so that no other commit's executable is served with that commit's
+        scopes to a profile of this one."""
         updates = []
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
         monkeypatch.setattr(jax.config, "update",
                             lambda *a: updates.append(a))
         assert bootstrap.configure_compile_cache() == "/some/dir"
-        assert updates == []
+        assert updates == [self.METADATA_IN_KEY]
 
     def test_default_is_the_checkout_and_never_moves(self, monkeypatch):
         """Unset: <checkout>/.jax_cache — a fixed path, the same across
@@ -49,7 +55,8 @@ class TestCompileCache:
                             lambda *a: updates.append(a))
         assert bootstrap.configure_compile_cache() == want
         assert bootstrap.configure_compile_cache() == want
-        assert updates == [("jax_compilation_cache_dir", want)] * 2
+        assert updates == [self.METADATA_IN_KEY,
+                           ("jax_compilation_cache_dir", want)] * 2
 
         env = _env()
         env.pop("JAX_COMPILATION_CACHE_DIR", None)

@@ -1,0 +1,353 @@
+"""What the serving path says about itself (PR 24): ``kft.*`` named scopes
+in the AOT programs (models/generate.py), ``kft.engine.*`` phase
+annotations and the cumulative ``loop_*_s`` / queue-wait / prefill-span /
+compile counters of ``DecodeEngine`` (serving/engine.py).  CPU, the tiny LM
+the other engine tests run."""
+
+import re
+import threading
+import time
+
+import numpy as np
+import pytest
+
+SEED = 20260927
+VOCAB, NEW_TOKENS = 128, 12
+SCOPES = {"kft.embed", "kft.qkv_proj", "kft.kv_write", "kft.kv_view",
+          "kft.attention", "kft.attn_out", "kft.mlp", "kft.logits",
+          "kft.sample"}
+# Top-level phases of one iteration in the order they may be entered;
+# wait_work lies inside admit, and a blocking token read inside drain is
+# a round_wait of its own.
+RANK = {"admit": 0, "housekeeping": 1, "prefill_dispatch": 2,
+        "round_prepare": 3, "round_dispatch": 4, "overlap": 5,
+        "round_wait": 6, "drain": 7, "account": 8}
+NESTED = {"wait_work": "admit", "round_wait": "drain"}
+
+
+@pytest.fixture(scope="module")
+def lm():
+    import jax
+    from flax import linen as nn
+
+    from kubeflow_tpu.models.generate import DecodeConfig
+    from kubeflow_tpu.models.transformer import Transformer
+    from kubeflow_tpu.serving.loaders import _model_config
+
+    cfg = _model_config({
+        "vocab_size": VOCAB, "d_model": 32, "n_layers": 2, "n_heads": 4,
+        "n_kv_heads": 2, "d_ff": 64, "head_dim": 8, "max_seq_len": 64,
+        "dtype": "float32"})
+    params = nn.unbox(Transformer(cfg).init(
+        jax.random.key(SEED), np.zeros((1, 8), np.int32))["params"])
+    return cfg, params, DecodeConfig(max_new_tokens=NEW_TOKENS,
+                                     temperature=0.0)
+
+
+def _programs(lm):
+    """{name: (jitted function, arguments)} of the engine's programs at
+    the tiny shapes."""
+    from kubeflow_tpu.models import generate as g
+
+    cfg, params, decode = lm
+    state = g.init_paged_state(cfg, 4, 16, 8)
+    tables = np.zeros((4, 4), np.int32)
+    chunk = np.zeros((1, 8), np.int32)
+    i32 = np.int32
+    return {
+        "decode_rounds": (g.decode_rounds, (
+            cfg, params, state, decode, 4, tables, i32(4))),
+        "decode_step": (g.decode_step, (
+            cfg, params, state, decode, 1, tables)),
+        "prefill_chunk_into_slot": (g.prefill_chunk_into_slot, (
+            cfg, params, state, decode, chunk, i32(0), i32(1), i32(1),
+            i32(0), i32(0), tables[:1])),
+    }
+
+
+PROGRAMS = ["decode_rounds", "decode_step", "prefill_chunk_into_slot"]
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_scope_names_are_in_the_lowered_text(lm, program):
+    fn, args = _programs(lm)[program]
+    text = fn.lower(*args).as_text(debug_info=True)
+    assert set(re.findall(r"kft\.[a-z_]+", text)) == SCOPES
+
+
+def _unowned(jaxpr, stack=(), in_layers=False):
+    """Dots, gathers and scatters of the layer scan's body whose name
+    stack holds no ``kft.`` scope."""
+    import jax
+
+    out = []
+    for eqn in jaxpr.eqns:
+        here = stack + (str(eqn.source_info.name_stack),)
+        name = eqn.primitive.name
+        if in_layers and (name in ("dot_general", "gather")
+                          or name.startswith("scatter")
+                          or name == "dynamic_update_slice") \
+                and "kft." not in "/".join(here):
+            out.append((name, here))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _unowned(sub, here, in_layers or name == "scan")
+    return out
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_every_dot_gather_and_scatter_of_the_layer_body_has_a_scope(
+        lm, program):
+    import jax
+
+    fn, args = _programs(lm)[program]
+    static = {"decode_rounds": (0, 3, 4), "decode_step": (0, 3, 4),
+              "prefill_chunk_into_slot": (0, 3)}[program]
+    jaxpr = jax.make_jaxpr(fn, static_argnums=static)(*args)
+    counted = [e.primitive.name for e in _all_eqns(jaxpr.jaxpr)]
+    assert "scan" in counted and "dot_general" in counted
+    assert _unowned(jaxpr.jaxpr) == []
+
+
+def _all_eqns(jaxpr):
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_eqns(sub)
+
+
+class Recorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps every
+    annotation of every thread with its enter and exit times."""
+
+    events = []
+
+    def __init__(self, name, **facts):
+        self.event = {"name": name, "facts": dict(facts),
+                      "thread": threading.get_ident()}
+
+    def __enter__(self):
+        self.event["t0"] = time.perf_counter()
+        Recorder.events.append(self.event)
+        return self
+
+    def set_metadata(self, **facts):
+        self.event["facts"].update(facts)
+
+    def __exit__(self, *exc):
+        self.event["t1"] = time.perf_counter()
+        return False
+
+
+def _engine(lm, **kw):
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    cfg, params, decode = lm
+    kw.setdefault("slots", 3)
+    kw.setdefault("prefill_len", 32)
+    kw.setdefault("prefill_chunk_tokens", 8)
+    kw.setdefault("kv_block_tokens", 4)
+    return DecodeEngine(cfg, params, decode, **kw)
+
+
+def _serve(engine, prompts, new=NEW_TOKENS):
+    outs = [None] * len(prompts)
+
+    def client(i):
+        outs[i] = engine.submit({"tokens": np.asarray(prompts[i], np.int32),
+                                 "max_new_tokens": new})
+
+    threads = [threading.Thread(target=client, args=(i,))
+               for i in range(len(prompts))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return outs
+
+
+def _prompts(n, length=9, seed=SEED):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(1, VOCAB, size=(length,)).tolist() for _ in range(n)]
+
+
+PATHS = {
+    "fused": ({"decode_rounds": 4}, "decode_rounds"),
+    "unfused": ({"decode_rounds": 1}, "step"),
+    # A prompt that repeats itself, so the n-gram drafter proposes.
+    "verify": ({"decode_rounds": 1, "speculative_tokens": 3}, "verify"),
+}
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_phases_tile_every_iteration_in_order(lm, path, monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    Recorder.events = []
+    kw, program = PATHS[path]
+    engine = _engine(lm, name=f"phases-{path}", **kw)
+    try:
+        prompts = _prompts(4)
+        if path == "verify":
+            prompts = [[7, 8, 9] * 5 for _ in range(4)]
+        _serve(engine, prompts)
+        assert engine.compiled_programs()[program] == 1
+    finally:
+        engine.close()
+    events = [e for e in Recorder.events if "t1" in e]
+    assert {e["thread"] for e in events} == {engine._thread.ident}
+    assert {e["name"] for e in events} <= {
+        "kft.engine." + p for p in list(RANK) + list(NESTED)}
+    rounds = {}
+    for e in events:
+        rounds.setdefault(e["facts"]["round"], []).append(e)
+    assert sorted(rounds) == list(range(min(rounds), max(rounds) + 1))
+    stepped, covered, span = 0, 0.0, 0.0
+    for number, group in sorted(rounds.items()):
+        # Enter order is time order, and the round number never falls.
+        assert [e["t0"] for e in group] == sorted(e["t0"] for e in group)
+        top, rank = [], -1
+        for e in group:
+            name = e["name"][len("kft.engine."):]
+            if top and e["t0"] < top[-1]["t1"]:  # inside the one before
+                assert NESTED[name] == top[-1]["name"][len("kft.engine."):]
+                assert e["t1"] <= top[-1]["t1"]
+                continue
+            assert RANK[name] >= rank, (number, name)
+            rank = RANK[name]
+            top.append(e)
+        names = [e["name"][len("kft.engine."):] for e in top]
+        if number == max(rounds):  # closed and drained: the loop left
+            assert names == ["admit"]
+            continue
+        assert names[0] == "admit" and names[-1] == "account"
+        if "round_dispatch" in names:
+            stepped += 1
+            facts = top[names.index("round_dispatch")]["facts"]
+            assert facts["live"] >= 1 and facts["width"] >= 1
+        # Tiling: the top-level phases never overlap, and over the run
+        # (below) they leave no hole worth a name.
+        for a, b in zip(top, top[1:]):
+            assert a["t1"] <= b["t0"]
+        covered += sum(e["t1"] - e["t0"] for e in top)
+        span += top[-1]["t1"] - top[0]["t0"]
+    assert 0.9 * span <= covered <= span
+    assert stepped >= 3
+    chunks = sum(e["facts"].get("chunks", 0) for e in events
+                 if e["name"].endswith("prefill_dispatch"))
+    assert chunks == engine.stats()["prefill_chunks"]
+
+
+def _loop_sums(stats):
+    return {k: v for k, v in stats.items()
+            if k.startswith("loop_") and k.endswith("_s")}
+
+
+def test_loop_sums_are_monotone_and_add_up_to_the_wall_time(lm):
+    engine = _engine(lm, decode_rounds=4, name="phases-sums")
+    try:
+        _serve(engine, _prompts(2))  # compiles; the loop goes idle
+        time.sleep(0.05)
+        a, t_a = engine.stats(), time.perf_counter()
+        readings = [a]
+        for batch in range(3):
+            _serve(engine, _prompts(5, seed=batch))
+            readings.append(engine.stats())
+        time.sleep(0.05)  # idle again: the last wait_work is open
+        b, t_b = engine.stats(), time.perf_counter()
+        readings.append(b)
+    finally:
+        engine.close()
+    assert len(_loop_sums(a)) == 10
+    for before, after in zip(readings, readings[1:]):
+        for key, value in _loop_sums(before).items():
+            assert after[key] >= value
+        assert after["loop_rounds"] >= before["loop_rounds"]
+    assert b["loop_rounds"] > a["loop_rounds"]
+    grown = sum(_loop_sums(b).values()) - sum(_loop_sums(a).values())
+    # The idle stretch before ``a`` is added when its wait ends (inside
+    # the window) and the one before ``b`` is still open: 50 ms each way.
+    assert grown == pytest.approx(t_b - t_a, rel=0.10, abs=0.06)
+    assert b["loop_round_wait_s"] > a["loop_round_wait_s"]
+
+
+@pytest.mark.parametrize("prefix_hit", [False, True])
+def test_queue_wait_and_prefill_span_equal_the_traced_spans(
+        lm, prefix_hit, monkeypatch):
+    from kubeflow_tpu.runtime import tracing
+
+    spans = []
+    tracing.enable(sample_rate=1.0)
+    monkeypatch.setattr(
+        tracing, "record_span",
+        lambda name, ctx, start, end, **kw: spans.append(
+            (name, ctx.trace_id, start, end)))
+    engine = _engine(lm, decode_rounds=4, slots=2,
+                     name=f"phases-spans-{int(prefix_hit)}")
+    try:
+        shared = _prompts(1, length=16)[0]
+        prompts = [shared + tail for tail in _prompts(5, length=3)] \
+            if prefix_hit else _prompts(5, length=19)
+        before = engine.stats()
+        if prefix_hit:  # make the shared pages resident first
+            _serve(engine, [shared + [1, 2, 3]])
+
+        def client(prompt):
+            root = tracing.start_span("client")
+            with tracing.use_span(root):
+                engine.submit({"tokens": np.asarray(prompt, np.int32),
+                               "max_new_tokens": 6})
+            root.end()
+
+        warm = engine.stats()
+        threads = [threading.Thread(target=client, args=(p,))
+                   for p in prompts]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        after = engine.stats()
+    finally:
+        engine.close()
+        tracing.disable()
+    sent = len(prompts)
+    assert after["admitted"] - warm["admitted"] == sent
+    assert after["first_tokens"] - warm["first_tokens"] == sent
+    assert after["first_tokens_hit"] - warm["first_tokens_hit"] == (
+        sent if prefix_hit else 0)
+    assert before["admitted"] == before["first_tokens"] == 0
+    admission = {t: (s, e) for n, t, s, e in spans
+                 if n == "engine.admission"}
+    decode = {t: (s, e) for n, t, s, e in spans if n == "engine.decode"}
+    assert len(admission) == len(decode) == sent
+    waited = sum(e - s for s, e in admission.values())
+    prefilled = sum(decode[t][0] - admission[t][1] for t in admission)
+    assert after["queue_wait_s_sum"] - warm["queue_wait_s_sum"] == \
+        pytest.approx(waited, abs=1e-9)
+    assert after["prefill_span_s_sum"] - warm["prefill_span_s_sum"] == \
+        pytest.approx(prefilled, abs=1e-9)
+    hit = after["prefill_span_hit_s_sum"] - warm["prefill_span_hit_s_sum"]
+    assert hit == pytest.approx(prefilled if prefix_hit else 0.0, abs=1e-9)
+    # With two slots for five requests some of them queued.
+    assert waited > 0 and prefilled > 0
+
+
+def test_compile_counters_are_set_once(lm):
+    engine = _engine(lm, decode_rounds=4, name="phases-compile")
+    try:
+        assert engine.stats()["compile_s"] == 0.0
+        assert engine.stats()["compiled_peak_bytes"] == 0
+        _serve(engine, _prompts(1))
+        first = engine.stats()
+        _serve(engine, _prompts(3, seed=1))
+        second = engine.stats()
+    finally:
+        engine.close()
+    assert first["compile_s"] > 0.0
+    assert first["compiled_peak_bytes"] > 0
+    assert second["compile_s"] == first["compile_s"]
+    assert second["compiled_peak_bytes"] == first["compiled_peak_bytes"]
+    assert second["compiled_programs"] == first["compiled_programs"] == {
+        "chunked_prefill": 1, "step": 0, "verify": 0, "decode_rounds": 1}

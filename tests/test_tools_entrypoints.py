@@ -108,25 +108,3 @@ class TestProfiling:
         sched.close()
         assert list(tmp_path.rglob("*.xplane.pb")), \
             list(tmp_path.rglob("*"))
-
-
-class TestXplaneSummary:
-    @pytest.mark.slow  # ~19s real-trace capture; trace-writing stays tier-1
-    def test_summarizes_a_real_trace(self, tmp_path):
-        import jax
-        import jax.numpy as jnp
-
-        from kubeflow_tpu.runtime import profiling
-
-        with profiling.trace(str(tmp_path)):
-            jax.block_until_ready(
-                jnp.ones((64, 64)) @ jnp.ones((64, 64)))
-        traces = list(tmp_path.rglob("*.xplane.pb"))
-        assert traces
-        proc = subprocess.run(
-            [sys.executable, "-m", "kubeflow_tpu.tools.xplane_summary",
-             str(traces[0]), "5", "--steps", "1"],
-            capture_output=True, text=True, timeout=240, env=_env(),
-        )
-        assert proc.returncode == 0, proc.stderr[-1500:]
-        assert "busy (leaf ops)" in proc.stdout or "plane:" in proc.stderr
