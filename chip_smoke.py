@@ -468,6 +468,13 @@ def phase_serve(run, export_dir):
               and (programs["step"] == 1
                    or programs.get("decode_rounds") == 1),
               f"engine programs not compiled once each: {programs}")
+        # No hidden fallback: on the chip every decode step goes through
+        # ops/paged_attention.py; off it (the rehearsal) none does.
+        kernel_steps = stats["steps"] if run.expect_platform == "tpu" else 0
+        check(stats["steps"] > 0
+              and stats["decode_kernel_steps"] == kernel_steps,
+              f"decode_kernel_steps {stats['decode_kernel_steps']} of "
+              f"{stats['steps']} steps on {run.expect_platform}")
         stop_server(run, server)
     finally:
         server.kill()
@@ -478,6 +485,8 @@ def phase_serve(run, export_dir):
          requests=len(wave) + 2, tokens_returned=returned,
          max_in_flight=in_flight, prefix_hits=hits,
          compiled_programs=programs,
+         steps=stats["steps"],
+         decode_kernel_steps=stats["decode_kernel_steps"],
          mean_occupancy=stats.get("mean_occupancy"),
          memory=server.memory,
          cache_entries_written=run.cache_entries() - before)

@@ -82,7 +82,7 @@ def _lora(x, a, b, spec_a, spec_b):
 
 def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                 cache_len, positions, pad_amount=None, write_cols=None,
-                tables=None, adapters=None):
+                tables=None, adapters=None, paged_kernel=False):
     """One decoder block against the KV cache.
 
     x: [b, t, e] new activations (t = prompt len at prefill, 1 at decode);
@@ -111,6 +111,14 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
     [max_blocks * block_tokens] view of the pool (sentinel entries
     clamp onto an arbitrary block whose columns all sit beyond the
     causal frontier, so the garbage they contribute is masked).
+    paged_kernel (static; the serving engine sets it when its pool
+    lives on a TPU): a step with ONE query position per row (t == 1:
+    decode_step, decode_rounds) over a plain-array pool gathers no
+    view — ops/paged_attention.py reads each row's resident pages from
+    the pool in place (a row whose write is parked attends nothing).
+    Wider steps (the prefill chunk, speculative verify), an int8
+    ``QTensor`` pool and every other backend keep the view and
+    ``dot_product_attention``.
     Mirrors models/transformer.py Block but with explicit cache state.
     """
     from kubeflow_tpu.models.transformer import MLP, RMSNorm
@@ -197,13 +205,25 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                                c.axes)
             return gather(c)
 
-        with jax.named_scope("kft.kv_view"):
-            view_k, view_v = paged_view(ck), paged_view(cv)
-        with jax.named_scope("kft.attention"):
-            out = dot_product_attention(
-                q, view_k, view_v, causal=True,
-                kv_offset=cache_len, kv_valid_start=pad_amount,
-            )
+        if (paged_kernel and t == 1 and per_row and pad_amount is None
+                and not isinstance(ck, QTensor)):
+            from kubeflow_tpu.ops import paged_attention
+
+            with jax.named_scope("kft.attention"):
+                # The step's own k/v are in the pool already (above), so
+                # a live row attends its cache_len + 1 positions; a
+                # parked write marks a retired row, which reads nothing.
+                attend = jnp.where(base < mb * bt, cache_len + 1, 0)
+                out = paged_attention.paged_decode_attention(
+                    q[:, 0], ck, cv, tables, attend)[:, None]
+        else:
+            with jax.named_scope("kft.kv_view"):
+                view_k, view_v = paged_view(ck), paged_view(cv)
+            with jax.named_scope("kft.attention"):
+                out = dot_product_attention(
+                    q, view_k, view_v, causal=True,
+                    kv_offset=cache_len, kv_valid_start=pad_amount,
+                )
     elif per_row:
         # Slot-based decode/verify: t new tokens per row, scattered to
         # each row's own columns [base, base + t).  mode="drop" makes
@@ -314,7 +334,7 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
 
 def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
                         cache_len, pad_amount=None, write_cols=None,
-                        tables=None, adapter_ids=None):
+                        tables=None, adapter_ids=None, paged_kernel=False):
     """tokens [b, t] -> (logits [b, t, v], new cache).
 
     cache_len scalar: the whole batch sits at one length (generate()).
@@ -330,6 +350,7 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
     ``params["adapters"]`` low-rank delta arrays (multi-model adapter
     serving, §5.11) — ignored when the params tree carries no adapter
     stack, so the base model's programs are untouched.
+    paged_kernel: see _layer_step (static).
     """
     from flax import linen as nn
 
@@ -382,7 +403,7 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
         x, (ck, cv) = _layer_step(
             cfg, layer_params, x, (ck, cv), cache_len, positions,
             pad_amount=pad_amount, write_cols=write_cols,
-            tables=tables, adapters=ad,
+            tables=tables, adapters=ad, paged_kernel=paged_kernel,
         )
         return x, (ck, cv)
 
@@ -689,7 +710,7 @@ def gather_kv_pages(state, ids):
 
 
 def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
-                   tables: jax.Array, park, state):
+                   tables: jax.Array, park, state, paged_kernel=False):
     """One batched decode step over every slot: the shared body of
     ``decode_step`` and ``decode_rounds``.  Returns (state, nxt [S])
     where ``nxt`` is the sampled token per slot (0 for frozen slots).
@@ -704,7 +725,7 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
         cfg, params, state["last_token"][:, None],
         (state["cache_k"], state["cache_v"]), lengths,
         write_cols=write_cols, tables=tables,
-        adapter_ids=state.get("adapter_ids"))
+        adapter_ids=state.get("adapter_ids"), paged_kernel=paged_kernel)
     with jax.named_scope("kft.sample"):
         last = logits[:, -1]
         if decode.temperature <= 0.0:
@@ -732,9 +753,11 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
     return state, nxt
 
 
-@partial(jax.jit, static_argnums=(0, 3, 4), donate_argnums=(2,))
+@partial(jax.jit, static_argnums=(0, 3, 4),
+         static_argnames=("paged_kernel",), donate_argnums=(2,))
 def decode_step(cfg: TransformerConfig, params, state,
-                decode: DecodeConfig, steps: int, tables: jax.Array):
+                decode: DecodeConfig, steps: int, tables: jax.Array,
+                *, paged_kernel: bool = False):
     """Advance every live slot; returns (state, sampled [steps, S]).
 
     One batched forward at t=1 per step: each slot ropes at its own
@@ -751,11 +774,16 @@ def decode_step(cfg: TransformerConfig, params, state,
     the cost of k-token admission granularity (slots finishing mid-call
     freeze via `done` on device, so at most k-1 slot-steps idle).  One
     engine uses ONE value, so the three-program guarantee holds.
+
+    ``paged_kernel`` (static, chosen once by the engine from the
+    platform its pool lives on): attention reads the pool in place
+    through ops/paged_attention.py instead of the gathered view.
     """
     park = tables.shape[1] * _pool_block_tokens(state["cache_k"])
 
     def one(state, _):
-        return _advance_slots(cfg, params, decode, tables, park, state)
+        return _advance_slots(cfg, params, decode, tables, park, state,
+                              paged_kernel)
 
     if steps == 1:  # skip the scan wrapper on the canonical path
         state, toks = one(state, None)
@@ -764,10 +792,11 @@ def decode_step(cfg: TransformerConfig, params, state,
     return state, toks
 
 
-@partial(jax.jit, static_argnums=(0, 3, 4), donate_argnums=(2,))
+@partial(jax.jit, static_argnums=(0, 3, 4),
+         static_argnames=("paged_kernel",), donate_argnums=(2,))
 def decode_rounds(cfg: TransformerConfig, params, state,
                   decode: DecodeConfig, k: int, tables: jax.Array,
-                  max_steps: jax.Array):
+                  max_steps: jax.Array, *, paged_kernel: bool = False):
     """Device-resident multi-step decode: up to ``k`` decode steps in
     ONE dispatch via ``lax.while_loop``, with device-side early exit
     the moment every slot is done (EOS/budget) — the host never pays
@@ -793,7 +822,7 @@ def decode_rounds(cfg: TransformerConfig, params, state,
     before dispatch.  Per-step math is ``_advance_slots``, the same
     body ``decode_step`` runs, so greedy tokens are bit-identical to
     k single-step dispatches; under a mesh the loop body partitions
-    exactly like ``decode_step`` does.
+    exactly like ``decode_step`` does.  ``paged_kernel``: as there.
     """
     park = tables.shape[1] * _pool_block_tokens(state["cache_k"])
     slots = state["done"].shape[0]
@@ -808,7 +837,7 @@ def decode_rounds(cfg: TransformerConfig, params, state,
     def body(carry):
         i, state, out = carry
         state, nxt = _advance_slots(cfg, params, decode, tables, park,
-                                    state)
+                                    state, paged_kernel)
         return i + 1, state, out.at[:, i].set(nxt)
 
     steps_run, state, toks = jax.lax.while_loop(

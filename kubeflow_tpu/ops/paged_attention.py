@@ -1,0 +1,173 @@
+"""Decode attention over the paged KV pool, read in place (Pallas TPU).
+
+The step programs of the serving engine (``models/generate.py``
+``decode_step`` / ``decode_rounds``) attend ONE query position per slot
+against a pool ``[num_blocks, block_tokens, hkv, d]`` that every slot
+shares through its block table.  The plain path gathers each slot's
+whole ``[max_blocks * block_tokens]`` view, repeats it over the GQA
+group and multiplies in float32: tens of gigabytes of HBM traffic a
+step for a gigabyte of resident keys and values.  This kernel leaves
+the pool in HBM and, per slot, copies only the pages below the slot's
+frontier into VMEM, several pages a block, the next block's copies in
+flight while this one is computed.
+
+Layout.  A page is ``[bt, hkv, d]``; with the two leading axes merged
+it is ``bt * hkv`` rows of ``d``: one row per (position, kv head).  The
+kernel treats those rows as the keys of a plain single-query flash
+step: ``q [h, d] @ rows^T`` scores every query head against every
+(position, kv head) row on the MXU, the mask keeps for query head ``i``
+the rows of ITS kv head (``row % hkv == i // g``) below the frontier,
+and ``p [h, rows] @ v_rows [rows, d]`` sums exactly those.  So each page
+is loaded once for all ``h / hkv`` query heads of a group, nothing is
+transposed or repeated, and the MXU does ``hkv`` times the needed work
+on a step that memory bounds.
+
+Arithmetic: operands in the pool's dtype (bf16 on the chip) with
+float32 accumulation, float32 running max / sum / output (online
+softmax), weights cast to the pool's dtype before the value product, as
+``ops.attention.dot_product_attention`` does: the same products in
+another order of summation.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Finite, so that exp(masked - max) is 0 and never inf - inf.
+_MASKED = -1e30
+
+
+def _kernel(tables_ref, ntok_ref, q_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sems, *, mb, bt, hkv, g, pages, nb, scale):
+    """One slot: walk its resident pages ``pages`` at a time."""
+    s = pl.program_id(0)
+    n = ntok_ref[s]                       # positions to attend (0: none)
+    n_pages = (n + bt - 1) // bt
+    n_blocks = (n_pages + pages - 1) // pages
+    rows = bt * hkv                       # (position, kv head) rows a page
+
+    @pl.when(s == 0)
+    def _():
+        # A page the walk never copied is multiplied by a zero weight:
+        # it has to hold numbers, and fresh VMEM need not.
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def copies(blk, buf, p):
+        # Entries below the frontier are real pages; the clamp only
+        # keeps a sentinel (== nb) that a wrong table would hold inside
+        # the pool.
+        page = jnp.minimum(tables_ref[s * mb + blk * pages + p], nb - 1)
+        dst = pl.ds(p * rows, rows)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[page], kbuf.at[buf, dst], sems.at[0, buf]),
+            pltpu.make_async_copy(
+                v_hbm.at[page], vbuf.at[buf, dst], sems.at[1, buf]),
+        )
+
+    def for_pages(blk, buf, act):
+        for p in range(pages):
+            @pl.when(blk * pages + p < n_pages)
+            def _(p=p):
+                for c in copies(blk, buf, p):
+                    act(c)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        for_pages(0, 0, lambda c: c.start())
+
+    q = q_ref[0]                                            # [h, d]
+    h = q.shape[0]
+    shape = (h, pages * rows)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    own = (col % hkv) == (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                          // g)
+    pos = col // hkv
+
+    def body(i, carry):
+        m, l, acc = carry
+        buf = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _():
+            for_pages(i + 1, 1 - buf, lambda c: c.start())
+
+        for_pages(i, buf, lambda c: c.wait())
+        k = kbuf[buf]                                       # [rows*, d]
+        v = vbuf[buf]
+        sc = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # [h, rows*]
+        keep = own & (pos + i * (pages * bt) < n)
+        sc = jnp.where(keep, sc, _MASKED)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        acc = alpha * acc + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((h, 1), _MASKED, jnp.float32)
+    l0 = jnp.zeros((h, 1), jnp.float32)
+    acc0 = jnp.zeros((h, q.shape[1]), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
+    # A slot with nothing to attend (retired) returns zeros.
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_block",
+                                             "interpret"))
+def paged_decode_attention(q, k_pool, v_pool, tables, n_tokens, *,
+                           pages_per_block: int = 16,
+                           interpret: bool = False):
+    """``q [S, h, d]`` against each slot's resident pages -> ``[S, h, d]``.
+
+    k_pool / v_pool: ``[num_blocks, block_tokens, hkv, d]``, left in HBM.
+    tables ``[S, max_blocks]`` int32: slot s's logical page i lives in
+    physical page ``tables[s, i]``; entries at and above the slot's
+    frontier are never read (they may hold the sentinel ``num_blocks``).
+    n_tokens ``[S]`` int32: how many positions slot s attends, counted
+    from 0 and INCLUDING the step's own (already written to the pool);
+    0 does no page and returns zeros (a retired slot).  Slots may share
+    physical pages (the prefix cache's aliasing).
+    """
+    S, h, d = q.shape
+    nb, bt, hkv, _ = k_pool.shape
+    mb = tables.shape[1]
+    assert h % hkv == 0, (h, hkv)
+    pages = max(1, min(pages_per_block, mb))
+    rows = bt * hkv
+    kernel = functools.partial(
+        _kernel, mb=mb, bt=bt, hkv=hkv, g=h // hkv, pages=pages, nb=nb,
+        scale=d ** -0.5)
+    return pl.pallas_call(
+        kernel,
+        name="paged_decode_attention",
+        out_shape=jax.ShapeDtypeStruct((S, h, d), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[
+                pl.BlockSpec((1, h, d), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, h, d), lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * rows, d), k_pool.dtype),
+                pltpu.VMEM((2, pages * rows, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(tables.reshape(-1).astype(jnp.int32), n_tokens.astype(jnp.int32),
+      q, k_pool.reshape(nb, rows, d), v_pool.reshape(nb, rows, d))

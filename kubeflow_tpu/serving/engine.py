@@ -162,6 +162,11 @@ FUSED_WASTED_TOTAL = "kft_engine_fused_steps_wasted_total"
 FUSED_WASTED_HELP = \
     "fused-round slot-steps dispatched but not delivered (early-exit " \
     "waste past a slot's EOS/budget/deadline), by engine"
+DECODE_KERNEL_STEPS_TOTAL = "kft_engine_decode_kernel_steps_total"
+DECODE_KERNEL_STEPS_HELP = \
+    "decode steps run by a step program that holds the paged " \
+    "attention kernel (ops/paged_attention.py), by engine; over " \
+    "steps: the share the kernel served"
 KV_SPILLED_GAUGE = "kft_engine_kv_spilled_blocks"
 KV_SPILLED_HELP = \
     "paged-KV pages currently resident in the host spill tier, " \
@@ -369,6 +374,18 @@ def _true_token_len(row: np.ndarray) -> int:
     keeps its full width — there is no basis to trim it."""
     nz = np.flatnonzero(row)
     return int(nz[-1]) + 1 if nz.size else int(row.shape[0])
+
+
+def _plain_pool_platform(pool) -> Optional[str]:
+    """Platform of the device a paged pool lives on, None for an int8
+    ``QTensor`` pool (its attention stays on the gathered view).  Reads
+    the sharding, so a described shape answers like a placed array
+    (tests/test_tpu_compile.py asks for a chip that is not attached)."""
+    from kubeflow_tpu.ops.quantize import QTensor
+
+    if isinstance(pool, QTensor):
+        return None
+    return next(iter(pool.sharding.device_set)).platform
 
 
 class DecodeEngine:
@@ -595,6 +612,22 @@ class DecodeEngine:
             from kubeflow_tpu.serving import sharding
 
             self._state = sharding.shard_paged_state(self._state, mesh)
+        # Decided ONCE, from what the engine holds: decode_step and
+        # decode_rounds attend through ops/paged_attention.py when the
+        # pool is a plain array on a TPU, a page's rows fill whole
+        # 128-lane tiles (the chip's compiler refuses the kernel's page
+        # copies otherwise) and no mesh shards the kv heads (a
+        # shard_map over that axis is the sound form there; until it
+        # exists a mesh keeps the gathered view).  Static for the two
+        # programs; stats()["decode_kernel_steps"] over "steps" is the
+        # share of decode steps the kernel served.
+        self._paged_kernel = (
+            mesh is None and cfg.head_dim % 128 == 0
+            and _plain_pool_platform(self._state["cache_k"]) == "tpu")
+        if self._paged_kernel:
+            # Load Pallas here, not inside the first trace: its import
+            # took ~3 s on the chip's host and read as compile_s.
+            from kubeflow_tpu.ops import paged_attention  # noqa: F401
         # Host-owned per-slot block tables, passed into every program
         # call; the sentinel value (== pool size) parks writes and
         # reads of unallocated logical pages.  Loop-thread-owned.
@@ -685,6 +718,7 @@ class DecodeEngine:
             "kv_evictions": 0, "kv_shed_no_blocks": 0,
             "handoff_pages_out": 0, "handoff_pages_in": 0,
             "fused_rounds": 0, "fused_steps_wasted": 0,
+            "decode_kernel_steps": 0,
             "spill_pages_out": 0, "spill_pages_in": 0,
             "parked_sessions": 0, "fetches": 0,
             **dict.fromkeys(_SUM_KEYS, 0),
@@ -742,6 +776,8 @@ class DecodeEngine:
             FUSED_ROUNDS_TOTAL, FUSED_ROUNDS_HELP)
         self._fused_wasted_ctr = REGISTRY.counter(
             FUSED_WASTED_TOTAL, FUSED_WASTED_HELP)
+        self._kernel_steps_ctr = REGISTRY.counter(
+            DECODE_KERNEL_STEPS_TOTAL, DECODE_KERNEL_STEPS_HELP)
         self._kv_spilled_gauge = REGISTRY.gauge(
             KV_SPILLED_GAUGE, KV_SPILLED_HELP)
         self._host_tier_gauge = REGISTRY.gauge(
@@ -1298,6 +1334,10 @@ class DecodeEngine:
             "decode_rounds": self.decode_rounds,
             "fused_rounds": c["fused_rounds"],
             "fused_steps_wasted": c["fused_steps_wasted"],
+            # Steps whose program held the paged attention kernel
+            # (decode_step / decode_rounds on a TPU pool): over "steps"
+            # it is the share the kernel served, 0 off the chip.
+            "decode_kernel_steps": c["decode_kernel_steps"],
             "steps_per_round_p50": pct_raw(rounds, 0.50),
             "steps_per_round_p99": pct_raw(rounds, 0.99),
             # Which AOT programs exist — the four-program guarantee,
@@ -1386,13 +1426,13 @@ class DecodeEngine:
         """``with self._phase("drain"):`` — loop thread only."""
         return _Phase(self, name, facts)
 
-    def _aot(self, fn, *args):
+    def _aot(self, fn, *args, **static):
         """``fn.lower(*args).compile()`` for every AOT program of the
         engine, with the wall time it took added to ``compile_s`` and
         the compiler's own account of the program's memory kept as
         ``compiled_peak_bytes`` (the largest over the programs)."""
         t0 = time.perf_counter()
-        compiled = fn.lower(*args).compile()
+        compiled = fn.lower(*args, **static).compile()
         dt = time.perf_counter() - t0
         mem = compiled.memory_analysis()
         peak = 0 if mem is None else int(
@@ -2392,8 +2432,11 @@ class DecodeEngine:
         self._step_pace_ema = per_tok if self._step_pace_ema is None \
             else ((1 - _ROUND_PACE_ALPHA) * self._step_pace_ema
                   + _ROUND_PACE_ALPHA * per_tok)
+        kernel_steps = steps if (
+            self._paged_kernel and program == "step") else 0
         with self._lock:
             self._counters["steps"] += steps
+            self._counters["decode_kernel_steps"] += kernel_steps
             self._counters["occupancy_sum"] += occupancy
             self._counters["busy_s"] += dt
             if extra:
@@ -2411,6 +2454,9 @@ class DecodeEngine:
                 if len(self._round_steps) > 4096:
                     del self._round_steps[:2048]
         self._step_hist.observe(per_tok, engine=self._metric_name)
+        if kernel_steps:
+            self._kernel_steps_ctr.inc(kernel_steps,
+                                       engine=self._metric_name)
         if delivered is not None and delivered > 0 and dt > 0:
             rate = delivered / dt
             if program == "verify":
@@ -2591,7 +2637,8 @@ class DecodeEngine:
                 # not pollute the step percentiles).
                 self._rounds_exec = self._aot(
                     decode_rounds, self.cfg, self.params, self._state,
-                    self.decode, kmax, self._tables, np.int32(kmax))
+                    self.decode, kmax, self._tables, np.int32(kmax),
+                    paged_kernel=self._paged_kernel)
                 if self.mesh is not None:
                     # The double-buffered upload must land the tables
                     # exactly where the SPMD executable expects them.
@@ -3127,7 +3174,8 @@ class DecodeEngine:
             if self._step_exec is None:
                 self._step_exec = self._aot(
                     decode_step, self.cfg, self.params, self._state,
-                    self.decode, k, self._tables)
+                    self.decode, k, self._tables,
+                    paged_kernel=self._paged_kernel)
             # Chaos hook: sleep = slow/wedged step (deadlines expire
             # mid-generation); raise = device death.  Outside the timed
             # window so the injected stall does not masquerade as

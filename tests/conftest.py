@@ -77,3 +77,18 @@ def mesh8(devices):
     from jax.sharding import Mesh
 
     return Mesh(np.array(devices).reshape(2, 4), ("data", "model"))
+
+
+@pytest.fixture()
+def interpreted_paged_kernel(monkeypatch):
+    """ops/paged_attention.py in the Pallas interpreter: this process has
+    no chip to lower the kernel for, and product code reaches it through
+    the module attribute (models/generate.py)."""
+    import functools
+
+    from kubeflow_tpu.ops import paged_attention
+
+    monkeypatch.setattr(
+        paged_attention, "paged_decode_attention",
+        functools.partial(paged_attention.paged_decode_attention,
+                          interpret=True))

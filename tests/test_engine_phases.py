@@ -75,6 +75,27 @@ def test_scope_names_are_in_the_lowered_text(lm, program):
     assert set(re.findall(r"kft\.[a-z_]+", text)) == SCOPES
 
 
+@pytest.mark.parametrize("program", ["decode_rounds", "decode_step"])
+def test_kernel_step_programs_keep_their_scopes(
+        lm, program, interpreted_paged_kernel):
+    """With the paged attention kernel chosen the step programs gather
+    no view (no ``kft.kv_view``) and the kernel's own operations lie
+    under ``kft.attention``, which ``programs.decode_attention_share``
+    reads."""
+    import jax
+
+    fn, args = _programs(lm)[program]
+    text = fn.lower(*args, paged_kernel=True).as_text(debug_info=True)
+    assert set(re.findall(r"kft\.[a-z_]+", text)) \
+        == SCOPES - {"kft.kv_view"}
+    jaxpr = jax.make_jaxpr(
+        lambda *a: fn(*a, paged_kernel=True),
+        static_argnums=(0, 3, 4))(*args)
+    names = [e.primitive.name for e in _all_eqns(jaxpr.jaxpr)]
+    assert "pallas_call" in names
+    assert _unowned(jaxpr.jaxpr) == []
+
+
 def _unowned(jaxpr, stack=(), in_layers=False):
     """Dots, gathers and scatters of the layer scan's body whose name
     stack holds no ``kft.`` scope."""

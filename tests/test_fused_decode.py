@@ -412,3 +412,97 @@ class TestFusedDecode:
         assert len(outs) == 2  # every waiter resolved (result or error)
         assert any(isinstance(v, Exception) for v in outs.values())
         engine.close()
+
+
+class TestPagedKernelChoice:
+    """serving/engine.py decides ONCE, from the platform its pool lives
+    on, whether decode_step / decode_rounds attend through
+    ops/paged_attention.py; ``decode_kernel_steps`` over ``steps`` is
+    the share of decode steps the kernel served."""
+
+    @staticmethod
+    def _lane_wide(spec):
+        """The tiny LM with heads of 128: the engine takes the kernel
+        only where a page's rows fill whole 128-lane tiles."""
+        import jax
+        from flax import linen as nn
+
+        from kubeflow_tpu.models.transformer import Transformer
+
+        cfg = dataclasses.replace(spec["cfg"], head_dim=128)
+        params = nn.unbox(Transformer(cfg).init(
+            jax.random.key(SEED), np.zeros((1, PROMPT_LEN), np.int32))
+            ["params"])
+        return {"cfg": cfg, "params": params, "decode": spec["decode"]}
+
+    @pytest.mark.parametrize("decode_rounds", [1, 8])
+    def test_kernel_engine_matches_plain_engine_and_counts_its_steps(
+            self, engine_model, monkeypatch, interpreted_paged_kernel,
+            decode_rounds):
+        from kubeflow_tpu.runtime.prom import REGISTRY, parse_metrics, \
+            sample_value
+        from kubeflow_tpu.serving import engine as engine_mod
+
+        spec = self._lane_wide(engine_model[0])
+        rng = np.random.RandomState(SEED + 25)
+        lens = [3, 9, 16, 2, 9, 16, 3]
+        news = [12, 6, 3, 8, 12, 4, 10]
+        prompts = [rng.randint(1, VOCAB, size=(n,)).tolist()
+                   for n in lens]
+        want = _reference_rows(spec, prompts, news)
+
+        # On the CPU the pool's platform says "cpu": the plain path.
+        plain_outs, plain_stats, _ = _run_engine(
+            spec, prompts, news, decode_rounds=decode_rounds,
+            name="test-plain-path")
+        assert plain_stats["steps"] > 0
+        assert plain_stats["decode_kernel_steps"] == 0
+
+        # The same engine told its pool lives on a TPU (the kernel in
+        # the Pallas interpreter: this process has no chip).
+        monkeypatch.setattr(engine_mod, "_plain_pool_platform",
+                            lambda pool: "tpu")
+        name = "test-kernel-path"
+        outs, stats, programs = _run_engine(
+            spec, prompts, news, decode_rounds=decode_rounds, name=name)
+        assert stats["steps"] > 0
+        assert stats["decode_kernel_steps"] == stats["steps"]
+        assert sample_value(
+            parse_metrics(REGISTRY.render()),
+            "kft_engine_decode_kernel_steps_total",
+            engine=f"{name}-k{decode_rounds}") == stats["steps"]
+        assert programs["chunked_prefill"] == 1
+        assert programs["step"] == int(decode_rounds == 1)
+        assert programs.get("decode_rounds", 0) == int(decode_rounds > 1)
+        for i in range(len(prompts)):
+            got = np.asarray(outs[i]["tokens"])[0].tolist()
+            assert got == np.asarray(
+                plain_outs[i]["tokens"])[0].tolist() == want[i], i
+
+    def test_narrow_heads_keep_the_view_on_a_tpu(self, engine_model,
+                                                 monkeypatch):
+        """Heads of 8 (the suite's tiny LM): the chip's compiler would
+        refuse the kernel's page copies, so the engine does not choose
+        it, whatever the platform."""
+        from kubeflow_tpu.serving import engine as engine_mod
+
+        monkeypatch.setattr(engine_mod, "_plain_pool_platform",
+                            lambda pool: "tpu")
+        spec, _ = engine_model
+        _, stats, _ = _run_engine(spec, [[5, 6, 7]], [4], decode_rounds=8,
+                                  name="test-narrow-heads")
+        assert stats["steps"] > 0 and stats["decode_kernel_steps"] == 0
+
+    def test_int8_pool_keeps_the_view_on_a_tpu(self):
+        """An int8 ``QTensor`` pool is not the kernel's: the engine says
+        so itself, whatever the platform."""
+        import jax.numpy as jnp
+
+        from kubeflow_tpu.ops.quantize import QTensor
+        from kubeflow_tpu.serving import engine as engine_mod
+
+        pool = QTensor(jnp.zeros((4, 4, 2, 8), jnp.int8),
+                       jnp.zeros((4, 4, 2), jnp.float32), (-1,))
+        assert engine_mod._plain_pool_platform(pool) is None
+        assert engine_mod._plain_pool_platform(
+            jnp.zeros((4, 4, 2, 8))) == "cpu"

@@ -376,3 +376,71 @@ class TestFlashKeyStartMask:
         # Pad-row outputs are exactly zero (l == 0 guard).
         np.testing.assert_array_equal(
             np.asarray(out[0, :32]), np.zeros_like(out[0, :32]))
+
+
+_PAGE = 8          # block_tokens of the test pools
+_TABLE = 5         # pages a slot's table spans
+
+
+class TestPagedDecodeKernel:
+    """ops/paged_attention.py in the Pallas interpreter against
+    ``dot_product_attention`` over each slot's gathered view of the pool
+    (what the step programs do off the chip)."""
+
+    @staticmethod
+    def _reference(q, k_pool, v_pool, tables, n_tokens):
+        nb = k_pool.shape[0]
+        t = jnp.minimum(tables, nb - 1)      # sentinels clamp, masked
+        s, mb = tables.shape
+
+        def view(p):
+            return p[t].reshape((s, mb * _PAGE) + p.shape[2:])
+
+        return dot_product_attention(
+            q[:, None], view(k_pool), view(v_pool), causal=True,
+            kv_offset=n_tokens - 1)[:, 0]
+
+    @pytest.mark.parametrize("group", [1, 2, 4])
+    @pytest.mark.parametrize("case", [
+        "retired", "one", "page_minus_one", "page", "page_plus_one",
+        "full_span", "shuffled", "sentinel_above_frontier", "aliased"])
+    def test_matches_gathered_view(self, case, group):
+        from kubeflow_tpu.ops.paged_attention import paged_decode_attention
+
+        rng = np.random.RandomState(40 + group)
+        slots, hkv, d = 4, 2, 128
+        h = hkv * group
+        nb = slots * _TABLE + 3
+        q = jnp.asarray(rng.randn(slots, h, d), jnp.float32)
+        k_pool = jnp.asarray(rng.randn(nb, _PAGE, hkv, d), jnp.float32)
+        v_pool = jnp.asarray(rng.randn(nb, _PAGE, hkv, d), jnp.float32)
+        # Physical pages in no order, other slots at lengths of their own.
+        tables = rng.permutation(nb)[:slots * _TABLE].reshape(
+            slots, _TABLE).astype(np.int32)
+        n = np.array([13, 2 * _PAGE, 29, 5], np.int32)
+        first = {"retired": 0, "one": 1, "page_minus_one": _PAGE - 1,
+                 "page": _PAGE, "page_plus_one": _PAGE + 1,
+                 "full_span": _TABLE * _PAGE}
+        if case in first:
+            n[0] = first[case]
+        elif case == "sentinel_above_frontier":
+            # Unallocated logical pages hold the pool size, as the
+            # engine's tables do; slot 2's frontier is its page's end.
+            n[2] = 2 * _PAGE
+            for s in range(slots):
+                tables[s, -(-int(n[s]) // _PAGE):] = nb
+        elif case == "aliased":
+            # Two slots share their first two physical pages (a cached
+            # prefix) and differ after them.
+            tables[1, :2] = tables[0, :2]
+            n[0], n[1] = 2 * _PAGE + 3, 3 * _PAGE + 1
+        tables, n = jnp.asarray(tables), jnp.asarray(n)
+        out = paged_decode_attention(
+            q, k_pool, v_pool, tables, n, pages_per_block=2,
+            interpret=True)
+        ref = self._reference(q, k_pool, v_pool, tables, n)
+        live = np.asarray(n) > 0
+        np.testing.assert_allclose(
+            np.asarray(out)[live], np.asarray(ref)[live], atol=2e-5)
+        # A slot with nothing to attend reads no page and returns zeros.
+        assert not np.asarray(out)[~live].any()

@@ -18,6 +18,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -109,10 +110,9 @@ def test_flash_kv_start_forward_compiles(chip, s):
     assert _has_kernel(compiled)
 
 
-@pytest.fixture(scope="module")
-def engine_shapes(chip):
-    """Abstract params and paged state of the 188M engine: 16 slots,
-    chunk 64, 16-token pages, 256-token prompts + 128 new."""
+def _engine_shapes(chip, widths, slots, max_len, block=16,
+                   max_new_tokens=128):
+    """Abstract params and paged state of an engine on ``chip``."""
     from flax import linen as nn
 
     from kubeflow_tpu.models import generate
@@ -120,8 +120,7 @@ def engine_shapes(chip):
     from kubeflow_tpu.ops.quantize import narrow_params
     from kubeflow_tpu.serving.loaders import _model_config
 
-    cfg = _model_config(LM)
-    slots, block, max_len = 16, 16, 256 + 128
+    cfg = _model_config(widths)
     table_blocks = -(-max_len // block)
 
     def on_chip(tree):
@@ -140,8 +139,15 @@ def engine_shapes(chip):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
 
     return {"cfg": cfg, "params": params, "state": state, "arg": arg,
-            "decode": generate.DecodeConfig(max_new_tokens=128),
+            "decode": generate.DecodeConfig(max_new_tokens=max_new_tokens),
             "slots": slots, "table_blocks": table_blocks}
+
+
+@pytest.fixture(scope="module")
+def engine_shapes(chip):
+    """The 188M engine: 16 slots, chunk 64, 16-token pages, 256-token
+    prompts + 128 new."""
+    return _engine_shapes(chip, LM, 16, 256 + 128)
 
 
 def _fits(compiled, gib=16):
@@ -170,4 +176,45 @@ def test_engine_prefill_chunk_compiles(engine_shapes):
         e["cfg"], e["params"], e["state"], e["decode"], e["arg"](1, 64),
         scalar, scalar, scalar, scalar, scalar,
         e["arg"](1, e["table_blocks"])).compile()
+    assert _fits(compiled)
+
+
+# The serving cells' widths (benchmark/configs/, benchmark/cells/): the
+# engine's decode program has to come out WITH the paged attention kernel
+# when its pool lives on a TPU, and without the gathered float32 view.
+CELLS = {
+    "internlm2-1.8b": (
+        {"vocab_size": 92_544, "d_model": 2048, "n_layers": 24,
+         "n_heads": 16, "n_kv_heads": 8, "d_ff": 8192, "head_dim": 128,
+         "max_seq_len": 32_768, "dtype": "bfloat16"}, 16, 2560),
+    "mistral-7b-v0.3-l16": (
+        {"vocab_size": 32_768, "d_model": 4096, "n_layers": 16,
+         "n_heads": 32, "n_kv_heads": 8, "d_ff": 14_336, "head_dim": 128,
+         "max_seq_len": 32_768, "dtype": "bfloat16"}, 6, 6400),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_engine_decode_rounds_compiles_with_paged_kernel(chip, name):
+    import re
+
+    from kubeflow_tpu.models.generate import decode_rounds
+    from kubeflow_tpu.serving.engine import _plain_pool_platform
+
+    widths, slots, max_len = CELLS[name]
+    e = _engine_shapes(chip, widths, slots, max_len)
+    # What DecodeEngine decides from: the platform of the pool's device.
+    assert _plain_pool_platform(e["state"]["cache_k"]) == "tpu"
+    compiled = decode_rounds.lower(
+        e["cfg"], e["params"], e["state"], e["decode"], 8,
+        e["arg"](slots, e["table_blocks"]), e["arg"](),
+        paged_kernel=True).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    # No float32 array of a slot's whole view, repeated over the group
+    # or not, and no float32 copy of a layer's pool.
+    view = slots * max_len * widths["n_kv_heads"] * widths["head_dim"]
+    sizes = [int(np.prod([int(n) for n in dims.split(",")]))
+             for dims in re.findall(r"f32\[([\d,]+)\]", text)]
+    assert max(sizes) < view, max(sizes)
     assert _fits(compiled)
