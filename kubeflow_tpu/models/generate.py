@@ -80,6 +80,15 @@ def _lora(x, a, b, spec_a, spec_b):
     return jnp.einsum(spec_b, mid, b).astype(x.dtype)
 
 
+def _rms_norm(x, scale, eps, dtype):
+    """RMSNorm in float32, cast to ``dtype`` (models/transformer.py
+    RMSNorm on plain arrays)."""
+    x32 = x.astype(jnp.float32)
+    normed = x32 * jax.lax.rsqrt(
+        jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (normed * scale).astype(dtype)
+
+
 def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                 cache_len, positions, pad_amount=None, write_cols=None,
                 tables=None, adapters=None, paged_kernel=False):
@@ -121,16 +130,11 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
     ``dot_product_attention``.
     Mirrors models/transformer.py Block but with explicit cache state.
     """
-    from kubeflow_tpu.models.transformer import MLP, RMSNorm
-
     attn = layer_params["attn"]
     dt = cfg.dtype
 
     def norm(x, scale):
-        x32 = x.astype(jnp.float32)
-        normed = x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
-        return (normed * scale).astype(dt)
+        return _rms_norm(x, scale, cfg.norm_eps, dt)
 
     with jax.named_scope("kft.qkv_proj"):
         y = norm(x, layer_params["attn_norm"]["scale"])
@@ -310,6 +314,9 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
             ad = adapters["attn"]
             y = y + _lora(out, ad["wo_a"], ad["wo_b"],
                           "bshd,bhdr->bsr", "bsr,bre->bse")
+        if cfg.sandwich_norm:
+            with jax.named_scope("kft.loop_norm"):
+                y = norm(y, layer_params["attn_out_norm"]["scale"])
         x = x + y
     with jax.named_scope("kft.mlp"):
         y = norm(x, layer_params["mlp_norm"]["scale"])
@@ -328,6 +335,9 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
             ad = adapters["mlp"]
             y = y + _lora(h, ad["wo_a"], ad["wo_b"],
                           "bsf,bfr->bsr", "bsr,bre->bse")
+        if cfg.sandwich_norm:
+            with jax.named_scope("kft.loop_norm"):
+                y = norm(y, layer_params["mlp_out_norm"]["scale"])
         x = x + y
     return x, (ck, cv)
 
@@ -378,47 +388,57 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
         # Per-row adapter gather (§5.11): each row pulls ITS adapter's
         # low-rank factors out of the stacked [n_adapters, layers, ...]
         # arrays (row 0 is the all-zero base delta), then the layer
-        # axis moves out front so the factors ride the scan xs beside
-        # the base layer stack — one gather per forward, ONE SPMD
-        # program for every mix of co-batched variants.
+        # axis moves out front so the scan body indexes the factors by
+        # layer beside the base layer stack — one gather per forward,
+        # ONE SPMD program for every mix of co-batched variants.
         with jax.named_scope("kft.embed"):  # the rows' other lookup
             adapter_stack = jax.tree_util.tree_map(
                 lambda arr: jnp.moveaxis(
                     jnp.asarray(arr, dt)[adapter_ids], 1, 0),
                 dict(params["adapters"]))
 
-    # The caches ride the scan as xs/ys (sliced per layer on the leading
-    # axis, re-stacked from the per-layer outputs) — NOT as carry with
+    def final_norm(x):
+        return _rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps, dt)
+
+    # The caches ride the scan as xs/ys (sliced per plane on the leading
+    # axis, re-stacked from the per-plane outputs) — NOT as carry with
     # `cache.at[idx].set(...)`.  Indexed whole-cache updates in the body
     # compile to a copy of the full [L, b, s, h, d] buffer per layer per
     # token (measured 235 ms/token for a 188M model on v5e — ~20 GB of
-    # HBM traffic per 128-token request); scan ys write each layer's
+    # HBM traffic per 128-token request); scan ys write each plane's
     # slice in place.
+    #
+    # ONE scan over the cfg.kv_planes cache planes, step-major: plane p
+    # is layer p % n_layers of loop step p // n_layers, and the body
+    # indexes the stacked weights (and adapters) by that layer, which is
+    # what riding them as xs compiles to.  A scan per loop step would
+    # slice a quarter of the pool out and write it back on top.
     def body(x, inputs):
-        if adapter_stack is None:
-            layer_params, ck, cv = inputs
-            ad = None
-        else:
-            layer_params, ck, cv, ad = inputs
+        plane, ck, cv = inputs
+        layer = plane % cfg.n_layers
+        layer_params, ad = jax.tree_util.tree_map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, layer, keepdims=False),
+            (layer_stack, adapter_stack))
         x, (ck, cv) = _layer_step(
             cfg, layer_params, x, (ck, cv), cache_len, positions,
             pad_amount=pad_amount, write_cols=write_cols,
             tables=tables, adapters=ad, paged_kernel=paged_kernel,
         )
+        if cfg.loop_steps > 1:
+            # Step t + 1 reads the NORMED output of step t's last
+            # layer; the last step's norm is the one before the logits.
+            with jax.named_scope("kft.loop_norm"):
+                hand_over = (layer == cfg.n_layers - 1) \
+                    & (plane < cfg.kv_planes - 1)
+                x = jnp.where(hand_over, final_norm(x), x)
         return x, (ck, cv)
 
     cache_k, cache_v = cache
-    xs = (layer_stack, cache_k, cache_v)
-    if adapter_stack is not None:
-        xs = xs + (adapter_stack,)
+    xs = (jnp.arange(cfg.kv_planes), cache_k, cache_v)
     x, (cache_k, cache_v) = jax.lax.scan(body, x, xs)
 
     with jax.named_scope("kft.logits"):
-        scale = params["final_norm"]["scale"]
-        x32 = x.astype(jnp.float32)
-        x = (x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6) * scale
-        ).astype(dt)
+        x = final_norm(x)
         if cfg.tied_embeddings:
             logits = qeinsum("bse,ve->bsv", x, embed, dt)
         else:
@@ -429,7 +449,8 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                kv_cache_dtype: str = "model"):
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    # One plane per (loop step, layer): cfg.kv_planes on the leading axis.
+    shape = (cfg.kv_planes, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     if kv_cache_dtype == "int8":
         def buf():
             return QTensor(
@@ -569,7 +590,8 @@ def generate(
 # whole program, and every row pays the batch bucket's padded KV span.
 # These entry points split that lifecycle so a serving loop can interleave
 # admission with decode.  The unified KV store is a device-side BLOCK
-# POOL — [layers, num_blocks, block_tokens, hkv, d], fp or int8 QTensor
+# POOL — [planes, num_blocks, block_tokens, hkv, d] (one plane per layer;
+# per (loop step, layer) in a looped stack: cfg.kv_planes), fp or int8 QTensor
 # alike — and every program takes the current per-slot block tables as
 # a plain argument: which pool block backs which logical block of which
 # slot is HOST bookkeeping (serving/prefix_cache.py BlockManager), so
@@ -621,7 +643,7 @@ def init_paged_state(cfg: TransformerConfig, slots: int,
     """Fresh paged engine state: every slot retired, block pool zeroed.
 
     The state dict is the carry the jitted entry points thread (and
-    donate): the [layers, num_blocks, block_tokens, hkv, d] KV block
+    donate): the [cfg.kv_planes, num_blocks, block_tokens, hkv, d] KV block
     pool plus per-slot scalars — lengths (valid cache positions),
     stop_len (length at which the slot stops sampling), last_token
     (sampled but not yet in cache), done, a per-slot PRNG key
@@ -659,7 +681,7 @@ def import_kv_pages(state, pages_k, pages_v, ids):
     of transferred block PAGES into this engine's pool at physical
     blocks ``ids`` ([n] int32; entries holding the pool-size sentinel
     are padding and drop).  ``pages_k``/``pages_v`` are
-    [layers, n, block_tokens, hkv, d] page stacks (QTensor values +
+    [planes, n, block_tokens, hkv, d] page stacks (QTensor values +
     scale for int8 pools) — exactly the prefill replica's pool rows,
     so after the scatter the decode replica's pool holds bit-identical
     k/v and the slot resumes through the ordinary cached-prefix path
@@ -689,7 +711,7 @@ def import_kv_pages(state, pages_k, pages_v, ids):
 def gather_kv_pages(state, ids):
     """The inverse of ``import_kv_pages``, host side: pull physical
     blocks ``ids`` out of the pool as HOST page stacks — one batched
-    fancy index per pool side ([layers, n, block_tokens, hkv, d] in a
+    fancy index per pool side ([planes, n, block_tokens, hkv, d] in a
     single transfer, never a per-block loop).  Returns
     ``((k_vals, k_scale), (v_vals, v_scale))`` as numpy arrays (scale
     is None for fp pools).  Deliberately NOT jitted: ``n`` varies per

@@ -130,9 +130,31 @@ class TransformerConfig:
     # rule; embed / final norm / logits stay replicated across stages.
     # 0 (or a pipeline-less mesh) runs the plain sequential scan.
     pipeline_microbatches: int = 0
+    # Epsilon of every RMSNorm, in training and in the serving programs.
+    norm_eps: float = 1e-6
+    # Looped stack (Ouro's ``total_ut_steps``): the n_layers blocks run
+    # loop_steps times a token over ONE set of weights.  Every (step,
+    # layer) pair attends keys and values of its own, so a KV cache has
+    # loop_steps * n_layers planes (``kv_planes``) where the weights have
+    # n_layers.  With more than one step the tree also holds the exit
+    # gate (``exit_gate_w`` / ``exit_gate_b``), which no program reads:
+    # every token takes every step, the published early-exit threshold
+    # of 1.  The final norm also runs BETWEEN loop steps: step t+1 reads
+    # the normed output of step t.
+    loop_steps: int = 1
+    # Sandwich norms: a second RMSNorm on the OUTPUT of each branch
+    # (``attn_out_norm``, ``mlp_out_norm``), before the residual add.
+    sandwich_norm: bool = False
 
     def __post_init__(self):
         assert self.n_heads % self.n_kv_heads == 0
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps={self.loop_steps} must be >= 1")
+        if self.loop_steps > 1 and self.pipeline_microbatches:
+            raise ValueError(
+                "pipeline_microbatches requires loop_steps=1 (a looped "
+                "stack would send every microbatch round the stage ring "
+                "loop_steps times; not built)")
         if self.ce_dtype not in ("f32", "compute"):
             raise ValueError(
                 f"ce_dtype={self.ce_dtype!r} not in ('f32', 'compute')")
@@ -162,6 +184,11 @@ class TransformerConfig:
 
         return default_group_size(self.moe_impl)
 
+    @property
+    def kv_planes(self) -> int:
+        """Leading axis of a KV cache: one plane per (loop step, layer)."""
+        return self.loop_steps * self.n_layers
+
     def flops_per_token(self) -> float:
         """Forward useful FLOPs per token (2*params matmul convention +
         attention term) — the MFU numerator, bwd counted as 2x by caller."""
@@ -175,8 +202,8 @@ class TransformerConfig:
             p_mlp = self.moe_top_k * p_mlp \
                 + self.d_model * self.moe_experts
         p_embed = self.vocab_size * self.d_model
-        matmul = 2 * (self.n_layers * (p_attn + p_mlp) + p_embed)
-        attn = 2 * 2 * self.n_layers * self.n_heads * self.head_dim \
+        matmul = 2 * (self.kv_planes * (p_attn + p_mlp) + p_embed)
+        attn = 2 * 2 * self.kv_planes * self.n_heads * self.head_dim \
             * self.max_seq_len  # qk^T + av, causal halving ignored
         return float(matmul + attn)
 
@@ -326,14 +353,21 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions, segment_ids):
         cfg = self.cfg
-        y = RMSNorm(dtype=cfg.dtype, name="attn_norm")(x)
+
+        def norm(name, y):
+            return RMSNorm(dtype=cfg.dtype, eps=cfg.norm_eps, name=name)(y)
+
+        y = norm("attn_norm", x)
         y = Attention(cfg, mesh=self.mesh, ring_manual=self.ring_manual,
                       name="attn")(y, positions, segment_ids)
+        if cfg.sandwich_norm:
+            with jax.named_scope("kft.loop_norm"):
+                y = norm("attn_out_norm", y)
         if cfg.dropout_rate:
             y = nn.Dropout(cfg.dropout_rate,
                            deterministic=self.deterministic)(y)
         x = x + y
-        y = RMSNorm(dtype=cfg.dtype, name="mlp_norm")(x)
+        y = norm("mlp_norm", x)
         if cfg.moe_experts > 0:
             from kubeflow_tpu.models.moe import MoEMLP
 
@@ -348,6 +382,9 @@ class Block(nn.Module):
             )(y)
         else:
             y = MLP(cfg, name="mlp")(y)
+        if cfg.sandwich_norm:
+            with jax.named_scope("kft.loop_norm"):
+                y = norm("mlp_out_norm", y)
         if cfg.dropout_rate:
             y = nn.Dropout(cfg.dropout_rate,
                            deterministic=self.deterministic)(y)
@@ -426,6 +463,8 @@ class Transformer(nn.Module):
             and self.mesh.shape.get("pipeline", 1) > 1
             and not self.is_initializing()
         )
+        final_norm = RMSNorm(dtype=cfg.dtype, eps=cfg.norm_eps,
+                             name="final_norm")
         if use_pipeline:
             if not default_positions or segment_ids is not None:
                 raise ValueError(
@@ -438,17 +477,33 @@ class Transformer(nn.Module):
             # One compiled body for all layers; params gain a leading
             # 'layers' dim, sharded over the `pipeline` mesh axis by the
             # rule table (a no-op at pipeline=1).
-            x, _ = nn.scan(
+            layers = nn.scan(
                 block,
                 variable_axes={"params": 0, "losses": 0},
                 split_rngs={"params": True, "dropout": True},
                 length=cfg.n_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
                 in_axes=(nn.broadcast, nn.broadcast),
-            )(cfg, deterministic, self.mesh, name="layers")(
-                x, positions, segment_ids)
+            )(cfg, deterministic, self.mesh, name="layers")
+            # A looped stack calls the ONE scanned module loop_steps
+            # times: the same parameters every time.
+            for step in range(cfg.loop_steps):
+                x, _ = layers(x, positions, segment_ids)
+                if step < cfg.loop_steps - 1:
+                    with jax.named_scope("kft.loop_norm"):
+                        x = final_norm(x)
 
-        x = RMSNorm(dtype=cfg.dtype, name="final_norm")(x)
+        x = final_norm(x)
+        if cfg.loop_steps > 1:
+            # Held, not read (TransformerConfig.loop_steps).
+            self.param(
+                "exit_gate_w",
+                nn.with_logical_partitioning(kernel_init, ("embed", None)),
+                (cfg.d_model, 1), jnp.float32)
+            self.param(
+                "exit_gate_b",
+                nn.with_logical_partitioning(init.zeros_init(), (None,)),
+                (1,), jnp.float32)
         if cfg.tied_embeddings:
             unembed = embed
         else:

@@ -12,7 +12,8 @@ This engine runs the slot entry points instead (models/generate.py)
 over ONE persistent PAGED KV block pool shared by ``slots`` sequences:
 
   - the unified KV store is a device-side block pool
-    ([layers, kv_pool_blocks, kv_block_tokens, hkv, d], fp and int8
+    ([planes, kv_pool_blocks, kv_block_tokens, hkv, d], one plane per
+    (loop step, layer): cfg.kv_planes; fp and int8
     QTensor alike) with host-owned per-slot block tables passed into
     every program call — a slot holds pages for the tokens it has
     actually produced, so serving capacity is bounded by **tokens
@@ -504,6 +505,8 @@ class DecodeEngine:
         adapters=None,
         name: str = "engine",
     ):
+        import jax
+
         from kubeflow_tpu.models.generate import init_paged_state
         from kubeflow_tpu.runtime.prom import REGISTRY
 
@@ -608,6 +611,12 @@ class DecodeEngine:
         self._state = init_paged_state(cfg, slots, self.kv_pool_blocks,
                                        self.kv_block_tokens,
                                        decode.kv_cache_dtype)
+        # What one resident position costs: keys and values over every
+        # plane of the pool (an int8 pool's scales included).
+        self.kv_bytes_per_token = sum(
+            leaf.nbytes for side in ("cache_k", "cache_v")
+            for leaf in jax.tree_util.tree_leaves(self._state[side])
+        ) // (self.kv_pool_blocks * self.kv_block_tokens)
         if mesh is not None:
             from kubeflow_tpu.serving import sharding
 
@@ -1278,6 +1287,12 @@ class DecodeEngine:
             "kv_blocks": self.kv_pool_blocks,
             "kv_blocks_used": extra["kv_used"],
             "kv_block_tokens": self.kv_block_tokens,
+            # The pool's leading axis, one plane per (loop step,
+            # layer), and what a resident position costs over all of
+            # them: static facts of the model, not serving knobs.
+            "loop_steps": self.cfg.loop_steps,
+            "kv_planes": self.cfg.kv_planes,
+            "kv_bytes_per_token": self.kv_bytes_per_token,
             "kv_block_evictions": c["kv_evictions"],
             "kv_shed_no_blocks": c["kv_shed_no_blocks"],
             "tokens_resident": extra["kv_used"] * self.kv_block_tokens,
@@ -1681,7 +1696,7 @@ class DecodeEngine:
                 f"kv_handoff block_tokens {bt} != engine page size "
                 f"{self.kv_block_tokens}")
         int8 = self.decode.kv_cache_dtype == "int8"
-        page_shape = (self.cfg.n_layers, self.kv_block_tokens,
+        page_shape = (self.cfg.kv_planes, self.kv_block_tokens,
                       self.cfg.n_kv_heads, self.cfg.head_dim)
 
         def norm(side, raw):
@@ -1712,7 +1727,7 @@ class DecodeEngine:
                     != page_shape:
                 raise ValueError(
                     f"kv_handoff {side} pages {vals.shape} do not "
-                    f"match pool pages [layers={page_shape[0]}, n, "
+                    f"match pool pages [planes={page_shape[0]}, n, "
                     f"block_tokens={page_shape[1]}, "
                     f"hkv={page_shape[2]}, d={page_shape[3]}]")
         if k_vals.shape[1] != v_vals.shape[1]:
@@ -2627,9 +2642,10 @@ class DecodeEngine:
             # block tables ride in as one host-owned snapshot.  The
             # admission reservation guarantees the pages, so this never
             # blocks.
+            lengths = []  # per snapshot row: cache positions before the round
             for _, r in snapshot:
-                self._ensure_cover(
-                    r, r["tokens"].shape[1] + r["scheduled"] + width - 1)
+                lengths.append(r["tokens"].shape[1] + r["scheduled"])
+                self._ensure_cover(r, lengths[-1] + width - 1)
             if self._rounds_exec is None:
                 # One executable serves EVERY adaptive width: the buffer
                 # size k is static, the per-round step cap is a traced
@@ -2694,10 +2710,20 @@ class DecodeEngine:
             if self.host_spill_blocks:
                 self._spill_tick(1)
         # ---- round boundary: materialize ONCE, deliver, account.
-        with self._phase("round_wait"):
+        with self._phase("round_wait") as phase:
             toks_np = np.asarray(toks)
             counts_np = np.asarray(counts)
             steps = int(steps_run)
+            # The device's own counts: the steps it ran of ``width``, and
+            # the cache positions those steps attended, summed over slots
+            # and steps (a slot that emitted n tokens from length l
+            # attended l, l + 1, ... l + n - 1): the round's least cache
+            # traffic, whatever stopped a slot.
+            attended = 0
+            for (i, _), at in zip(snapshot, lengths):
+                n = int(counts_np[i])
+                attended += n * at + n * (n - 1) // 2
+            phase.facts(steps=steps, attended=attended)
             del toks, counts, steps_run  # freed here, inside a phase
         with self._phase("drain"):
             self._pending.append((toks_np, snapshot, counts_np))

@@ -2,7 +2,9 @@
 in the AOT programs (models/generate.py), ``kft.engine.*`` phase
 annotations and the cumulative ``loop_*_s`` / queue-wait / prefill-span /
 compile counters of ``DecodeEngine`` (serving/engine.py).  CPU, the tiny LM
-the other engine tests run."""
+the other engine tests run, and (PR 26) the same LM as a looped stack:
+two loop steps over its two layers, sandwich norms, so that every path the
+engine sizes by the pool's leading axis runs with 4 planes for 2 layers."""
 
 import re
 import threading
@@ -25,8 +27,11 @@ RANK = {"admit": 0, "housekeeping": 1, "prefill_dispatch": 2,
 NESTED = {"wait_work": "admit", "round_wait": "drain"}
 
 
-@pytest.fixture(scope="module")
-def lm():
+LOOPED = {"loop_steps": 2, "sandwich_norm": True}
+
+
+@pytest.fixture(scope="module", params=["dense", "looped"])
+def lm(request):
     import jax
     from flax import linen as nn
 
@@ -37,9 +42,13 @@ def lm():
     cfg = _model_config({
         "vocab_size": VOCAB, "d_model": 32, "n_layers": 2, "n_heads": 4,
         "n_kv_heads": 2, "d_ff": 64, "head_dim": 8, "max_seq_len": 64,
-        "dtype": "float32"})
+        "dtype": "float32", **(LOOPED if request.param == "looped" else {})})
     params = nn.unbox(Transformer(cfg).init(
         jax.random.key(SEED), np.zeros((1, 8), np.int32))["params"])
+    # Norm scales away from 1: a norm read with the wrong scale shows.
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: a * (1.0 + 0.1 * np.sin(np.arange(a.size)).reshape(
+            a.shape)) if path[-1].key == "scale" else a, params)
     return cfg, params, DecodeConfig(max_new_tokens=NEW_TOKENS,
                                      temperature=0.0)
 
@@ -68,11 +77,17 @@ def _programs(lm):
 PROGRAMS = ["decode_rounds", "decode_step", "prefill_chunk_into_slot"]
 
 
+def _scopes(lm):
+    """A looped stack's norm between steps and its branch-output norms
+    lie under a scope of their own."""
+    return SCOPES | ({"kft.loop_norm"} if lm[0].loop_steps > 1 else set())
+
+
 @pytest.mark.parametrize("program", PROGRAMS)
 def test_scope_names_are_in_the_lowered_text(lm, program):
     fn, args = _programs(lm)[program]
     text = fn.lower(*args).as_text(debug_info=True)
-    assert set(re.findall(r"kft\.[a-z_]+", text)) == SCOPES
+    assert set(re.findall(r"kft\.[a-z_]+", text)) == _scopes(lm)
 
 
 @pytest.mark.parametrize("program", ["decode_rounds", "decode_step"])
@@ -87,7 +102,7 @@ def test_kernel_step_programs_keep_their_scopes(
     fn, args = _programs(lm)[program]
     text = fn.lower(*args, paged_kernel=True).as_text(debug_info=True)
     assert set(re.findall(r"kft\.[a-z_]+", text)) \
-        == SCOPES - {"kft.kv_view"}
+        == _scopes(lm) - {"kft.kv_view"}
     jaxpr = jax.make_jaxpr(
         lambda *a: fn(*a, paged_kernel=True),
         static_argnums=(0, 3, 4))(*args)
@@ -261,6 +276,51 @@ def test_phases_tile_every_iteration_in_order(lm, path, monkeypatch):
     assert chunks == engine.stats()["prefill_chunks"]
 
 
+@pytest.mark.parametrize("stop", ["budget", "eos"])
+def test_round_wait_states_the_steps_and_positions_the_device_ran(
+        lm, stop, monkeypatch):
+    """``steps`` and ``attended`` on ``round_wait`` are the device's own
+    counts: over a run they add up to the tokens the rounds emitted and
+    to the cache positions those tokens' steps read, also where an EOS
+    stops a slot before its budget (which no dispatch can know)."""
+    import dataclasses
+
+    import jax
+
+    cfg, params, decode = lm
+    prompts = _prompts(3)
+    if stop == "eos":
+        engine = _engine(lm, decode_rounds=4, name="facts-probe")
+        try:
+            probe = _serve(engine, prompts)
+        finally:
+            engine.close()
+        # A token that request 0 emits in the middle of its answer.
+        decode = dataclasses.replace(
+            decode, eos_token=int(probe[0]["tokens"][0][len(prompts[0]) + 4]))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    Recorder.events = []
+    engine = _engine((cfg, params, decode), decode_rounds=4,
+                     name=f"facts-{stop}")
+    try:
+        outs = _serve(engine, prompts)
+    finally:
+        engine.close()
+    emitted = [len(o["tokens"][0]) - len(p) for o, p in zip(outs, prompts)]
+    if stop == "eos":
+        assert min(emitted) < NEW_TOKENS
+    waits = [e["facts"] for e in Recorder.events
+             if e["name"] == "kft.engine.round_wait" and "steps" in e["facts"]]
+    dispatches = {e["facts"]["round"]: e["facts"] for e in Recorder.events
+                  if e["name"] == "kft.engine.round_dispatch"}
+    assert all(0 <= w["steps"] <= dispatches[w["round"]]["width"]
+               for w in waits)
+    # The first token of a request is its prefill's; token j (from 1) of
+    # an answer was made by a step that read the prompt and j tokens.
+    assert sum(w["attended"] for w in waits) == sum(
+        len(p) + j for p, n in zip(prompts, emitted) for j in range(1, n))
+
+
 def _loop_sums(stats):
     return {k: v for k, v in stats.items()
             if k.startswith("loop_") and k.endswith("_s")}
@@ -372,3 +432,41 @@ def test_compile_counters_are_set_once(lm):
     assert second["compiled_peak_bytes"] == first["compiled_peak_bytes"]
     assert second["compiled_programs"] == first["compiled_programs"] == {
         "chunked_prefill": 1, "step": 0, "verify": 0, "decode_rounds": 1}
+
+
+def test_pool_hand_off_and_stats_are_sized_by_planes(lm):
+    """What the engine sizes by the pool's leading axis: ``stats()``, the
+    pages a prefill replica exports (``gather_kv_pages``), the shape a
+    decode replica accepts and scatters (``import_kv_pages``), and pages
+    shared through the prefix cache.  A looped stack has more planes than
+    layers; tokens after a hand-off and after a cache hit are the local
+    run's."""
+    cfg, _, _ = lm
+    prompt = _prompts(1, length=19)[0]
+    pre = _engine(lm, decode_rounds=4, name="phases-planes-pre")
+    dec = _engine(lm, decode_rounds=4, prefix_caching=False,
+                  name="phases-planes-dec")
+    try:
+        stats = pre.stats()
+        assert stats["loop_steps"] == cfg.loop_steps
+        assert stats["kv_planes"] == cfg.loop_steps * cfg.n_layers
+        # keys + values, float32, 2 kv heads of 8: 128 B a plane.
+        assert stats["kv_bytes_per_token"] == 128 * stats["kv_planes"]
+        assert pre._state["cache_k"].shape[0] == stats["kv_planes"]
+        local = _serve(pre, [prompt])[0]["tokens"][0].tolist()
+        again = _serve(pre, [prompt])[0]["tokens"][0].tolist()
+        assert again == local and pre.stats()["prefix_hits"] == 1
+        hand = pre.prefill_export({"tokens": prompt})["kv_handoff"]
+        assert hand["k"].shape == (stats["kv_planes"], 4, 4, 2, 8)
+        got = dec.submit({"tokens": np.asarray(prompt, np.int32),
+                          "max_new_tokens": NEW_TOKENS,
+                          "kv_handoff": hand})
+        assert got["tokens"][0].tolist() == local
+        assert dec.stats()["handoff_pages_in"] == 4
+        with pytest.raises(ValueError, match="planes="):
+            dec.submit({"tokens": np.asarray(prompt, np.int32),
+                        "kv_handoff": dict(hand, k=hand["k"][:-1],
+                                           v=hand["v"][:-1])})
+    finally:
+        pre.close()
+        dec.close()

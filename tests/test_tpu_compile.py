@@ -191,6 +191,14 @@ CELLS = {
         {"vocab_size": 32_768, "d_model": 4096, "n_layers": 16,
          "n_heads": 32, "n_kv_heads": 8, "d_ff": 14_336, "head_dim": 128,
          "max_seq_len": 32_768, "dtype": "bfloat16"}, 6, 6400),
+    # A looped stack: 192 planes over 48 layers' weights, kv heads = heads
+    # (a page is 256 rows of the kernel's block, twice the others').
+    "ouro-2.6b": (
+        {"vocab_size": 49_152, "d_model": 2048, "n_layers": 48,
+         "n_heads": 16, "n_kv_heads": 16, "d_ff": 5632, "head_dim": 128,
+         "max_seq_len": 65_536, "rope_theta": 1e6, "tied_embeddings": False,
+         "loop_steps": 4, "sandwich_norm": True, "dtype": "bfloat16"},
+        5, 512),
 }
 
 
