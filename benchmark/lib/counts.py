@@ -53,14 +53,16 @@ def train_flops_per_token(c, seq_len: int) -> float:
     return 3.0 * forward_flops_per_token(c, seq_len / 2.0)
 
 
-def decode_step_bytes(c, resident_tokens: float,
+def decode_step_bytes(c, attended_tokens: float,
                       bytes_per_param: int = 2,
                       bytes_per_value: int = 2) -> float:
     """Least bytes one decode step must read: every matmul weight once
-    (the embedding only a row per sequence) and the keys and values that
-    are resident for the sequences in the batch."""
+    (the embedding only a row per sequence) and the keys and values of the
+    ``attended_tokens`` positions the step's sequences attend, summed over
+    the sequences.  Pages a prefix cache keeps for nobody in the batch are
+    not read, and counting them would break this file's promise."""
     w = matmul_params(c) * bytes_per_param
-    return w + resident_tokens * kv_bytes_per_token(c, bytes_per_value)
+    return w + attended_tokens * kv_bytes_per_token(c, bytes_per_value)
 
 
 def roofline_seconds(flops: float, bytes_: float, peak_flops: float,

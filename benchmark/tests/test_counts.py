@@ -5,7 +5,7 @@ import pathlib
 
 import pytest
 
-from benchmark.lib import counts, peaks
+from benchmark.lib import counts, counts_looped, peaks
 
 CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "configs"
 
@@ -55,13 +55,34 @@ def test_flops_internlm2_by_hand():
 def test_decode_step_bytes_and_roofline():
     c = load("mistral-7b-v0.3-l16")
     # 16 * (218,112,000 - 8,192) + 32,768*4096 = 3,623,878,656 weights
-    # read, 2 bytes each, + 10,000 resident tokens * 65,536 B.
+    # read, 2 bytes each, + 10,000 attended positions * 65,536 B.
     assert counts.decode_step_bytes(c, 10_000) == \
         2 * 3_623_878_656 + 10_000 * 65_536
     seconds, bound = counts.roofline_seconds(
         1e9, 819e9, peaks.peak("TPU v5 lite", "bf16_flops_per_s"),
         peaks.peak("TPU v5 lite", "hbm_bytes_per_s"))
     assert bound == "memory" and seconds == pytest.approx(1.0)
+
+
+def test_one_mistral_round_by_hand():
+    """A fused round of 3 steps whose sequences attended 12,000 positions in
+    all: 3 * 7,247,757,312 B of weights + 12,000 * 65,536 B of keys and
+    values = 22,529,703,936 B, 27.51 ms at 819 GB/s; one sequence's FLOPs a
+    step, 3 * (7,247,757,312 + 4 * 16 * 32 * 128 * 4,000) = 24,889,...: 0.13
+    ms at 197 TFLOP/s.  Memory bounds it.  ``decode_rounds_roofline`` holds
+    every traced call to this (``lib/traced_rounds.py``)."""
+    c = load("mistral-7b-v0.3-l16")
+    seconds, bound = counts_looped.decode_round_seconds(
+        c, 3, 12_000, 197e12, 819e9)
+    assert bound == "memory"
+    assert seconds == pytest.approx(22_529_703_936 / 819e9)
+    assert round(seconds * 1e3, 2) == 27.51
+    # The same from this file's counts: a dense stack is a loop of one.
+    assert (seconds, bound) == counts.roofline_seconds(
+        3 * counts.forward_flops_per_token(c, 4_000),
+        3 * counts.decode_step_bytes(c, 4_000), 197e12, 819e9)
+    assert 3 * counts.forward_flops_per_token(c, 4_000) == \
+        3 * (7_247_757_312 + 4 * 16 * 32 * 128 * 4_000)
 
 
 def test_unknown_device_is_an_error():

@@ -1,7 +1,9 @@
 """What the looped configuration (``configs/ouro-2.6b.json``) brings to the
 benchmark: its counts against sizes worked out by hand, its reference against
 the dense one and against itself with a part left out, the traced rounds'
-reduction on rounds set by hand, a run held against a reference that
+reduction on rounds set by hand (under both names the decode program's
+roofline has, on a looped stack and on a dense one), a run held against a
+reference that
 leaves the output norms out, which has to come out as not correct, and the
 looped runner's exits, which leave no process behind."""
 
@@ -127,6 +129,20 @@ def test_control_in_lower_precision_reads_worse(seed):
 
 
 # -- traced rounds ------------------------------------------------------------
+# Both rooflines of the fused decode program, the looped cell's and the dense
+# cells', are one computation (``traced_rounds.roofline_share``): every case
+# below runs under both metric names, each on a stack of its kind.
+
+MISTRAL = json.loads((ROOT / "benchmark" / "configs"
+                      / "mistral-7b-v0.3-l16.json").read_text())
+# Mistral-7B at 16 layers, by hand (``test_counts.py``): 3,623,878,656
+# matmul parameters read once a step in bf16; keys and values a token
+# 2 * 16 * 8 * 128 * 2 B.
+DENSE = dict(step_bytes=2 * 3_623_878_656, kv=65_536)
+ROOFLINES = [
+    pytest.param("loop.decode_rounds_roofline", OURO, HAND, id="looped"),
+    pytest.param("decode_rounds_roofline", MISTRAL, DENSE, id="dense")]
+
 
 def _reader(name):
     spec = importlib.util.spec_from_file_location(
@@ -136,14 +152,20 @@ def _reader(name):
     return module
 
 
-def _traced_run(monkeypatch, phases, modules, t0=1_000, t1=1_000_000_000):
+def _traced_run(monkeypatch, phases, modules, t0=1_000, t1=1_000_000_000,
+                config=OURO, **more):
     run = {"trace": {"t0": t0, "t1": t1, "planes": {"/device:TPU:0": {
         "busy_s": 1.0, "modules": modules, "ops": []}}},
-        "config": OURO, "device": {"kind": "TPU v5 lite"},
-        "counters": {"at_close": {"kv_planes": 192}}}
+        "config": config, "device": {"kind": "TPU v5 lite"},
+        "counters": {"at_close": {"kv_planes": 192}}, **more}
     monkeypatch.setattr(trace_spans, "_LOADED",
                         {(t0, t1): {"phases": phases, "ops": {}}})
     return run
+
+
+def _least(hand, steps, attended):
+    """Memory bounds a decode round at these sizes (819 GB/s)."""
+    return (steps * hand["step_bytes"] + attended * hand["kv"]) / 819e9
 
 
 MS = 1_000_000
@@ -173,48 +195,164 @@ MODULES = [
 ]
 
 
-def test_whole_calls_are_matched_to_their_rounds(monkeypatch):
-    run = _traced_run(monkeypatch, PHASES, MODULES)
+@pytest.mark.parametrize("name, config, hand", ROOFLINES)
+def test_whole_calls_are_matched_to_their_rounds(monkeypatch, name, config,
+                                                 hand):
+    run = _traced_run(monkeypatch, PHASES, MODULES, config=config)
     calls = traced_rounds.whole_calls(run)
     assert [(c["seconds"], c["steps"], c["attended"])
             for c in calls] == [(0.3, 3, 900), (0.22, 2, 401)]
     assert _reader("loop.layer_pass_ms").read(run) == pytest.approx(
         1e3 * 0.52 / 5 / 192)
-    least = (5 * HAND["step_bytes"] + 1301 * HAND["kv"]) / 819e9
-    share = _reader("loop.decode_rounds_roofline").read(run)
-    assert share == pytest.approx(100 * least / 0.52)
-    assert 20 < share < 30
+    share = _reader(name).read(run)
+    assert share == pytest.approx(100 * _least(hand, 5, 1301) / 0.52)
+    # 19.9 GB a step (looped) and 7.25 (dense): 23.9 % and 8.5 % of 0.52 s.
+    assert 8 < share < 30
 
 
-def test_readers_leave_the_metric_out_where_nothing_is_stated(monkeypatch):
+@pytest.mark.parametrize("name", ["loop.decode_rounds_roofline",
+                                  "decode_rounds_roofline"])
+def test_readers_leave_the_metric_out_where_nothing_is_stated(monkeypatch,
+                                                              name):
     """A program from before PR 26 states no ``steps``: no number, no
-    error.  Nor from an untraced run."""
+    error.  Nor one that states ``steps`` and no ``attended``, nor an
+    untraced run."""
     bare = [(p, s, d, {k: v for k, v in f.items()
                        if k not in ("steps", "attended")})
             for p, s, d, f in PHASES]
     run = _traced_run(monkeypatch, bare, MODULES)
     assert traced_rounds.whole_calls(run) is None
     assert _reader("loop.layer_pass_ms").read(run) is None
-    assert _reader("loop.decode_rounds_roofline").read(run) is None
+    assert _reader(name).read(run) is None
+    no_attended = [(p, s, d, {k: v for k, v in f.items() if k != "attended"})
+                   for p, s, d, f in PHASES]
+    run = _traced_run(monkeypatch, no_attended, MODULES)
+    assert len(traced_rounds.whole_calls(run)) == 2
+    assert _reader(name).read(run) is None
     untraced = {"trace": None, "counters": {"at_close": {}}}
     assert _reader("loop.layer_pass_ms").read(untraced) is None
-    assert _reader("loop.decode_rounds_roofline").read(untraced) is None
+    assert _reader(name).read(untraced) is None
 
 
-def test_the_roofline_counts_the_steps_that_ran_not_the_width(monkeypatch):
-    """Had the reader taken the window's mean steps a call, or the round's
-    width, a short round would read over 100 %: 8 steps' least time is
-    195 ms, the round took 80 ms for the 3 it ran."""
+@pytest.mark.parametrize("name, config, hand", ROOFLINES)
+def test_the_roofline_counts_the_steps_that_ran_not_the_width(
+        monkeypatch, name, config, hand):
+    """A round of a width of 8 that ran 3 steps in 1.1 times their least
+    time reads 90.9 %; held to its width it would read 242 %."""
+    least = _least(hand, 3, 300)
+    dur = round(1.1 * least * 1e9)
     phases = [("round_dispatch", 100 * MS, MS,
                {"round": 1, "width": 8, "live": 1}),
-              ("round_wait", 101 * MS, 90 * MS,
+              ("round_wait", 101 * MS, dur + 10 * MS,
                {"round": 1, "steps": 3, "attended": 300})]
-    modules = [("jit_decode_rounds(5)", 101 * MS, 80 * MS)]
-    run = _traced_run(monkeypatch, phases, modules)
-    share = _reader("loop.decode_rounds_roofline").read(run)
-    assert share == pytest.approx(
-        100 * (3 * HAND["step_bytes"] + 300 * HAND["kv"]) / 819e9 / 0.08)
-    assert share < 100
+    modules = [("jit_decode_rounds(5)", 101 * MS, dur)]
+    run = _traced_run(monkeypatch, phases, modules, config=config)
+    share = _reader(name).read(run)
+    assert share == pytest.approx(100 * least / (dur / 1e9))
+    assert 90 < share < 91 and 8 / 3 * share > 100
+
+
+def _window_mean_share(run):
+    """What ``decode_rounds_roofline`` computed until PR 28, kept here as
+    the fault the reader must not have again: the WINDOW's mean steps a
+    call (``steps`` / ``fused_rounds``) times one step's least time, with
+    every page the pool had in use counted as read, over the mean time of
+    the TRACED calls."""
+    from benchmark.lib import peaks, stats, trace_reduce
+
+    plane = run["trace"]["planes"]["/device:TPU:0"]
+    seconds, calls = trace_reduce.module_times(plane["modules"])[
+        "jit_decode_rounds"]
+    steps = stats.delta(run, "steps")
+    used = [u for _, u, _, _ in run["samples"]]
+    resident = sum(used) / len(used) * \
+        run["counters"]["at_close"]["kv_block_tokens"]
+    live = stats.delta(run, "tokens") / steps
+    least, _ = counts.roofline_seconds(
+        live * counts.forward_flops_per_token(run["config"],
+                                              resident / max(live, 1.0)),
+        counts.decode_step_bytes(run["config"], resident),
+        peaks.peak("TPU v5 lite", "bf16_flops_per_s"),
+        peaks.peak("TPU v5 lite", "hbm_bytes_per_s"))
+    return 100.0 * steps / stats.delta(run, "fused_rounds") * least \
+        / (seconds / calls)
+
+
+def _one_step_calls(hand, n, attended, slack=3.0):
+    """``n`` rounds of one step each (an admission waits), every call
+    ``slack`` times its least time long."""
+    dur = round(slack * _least(hand, 1, attended) * 1e9)
+    phases, modules = [], []
+    for i in range(n):
+        at = (100 + 100 * i) * MS
+        phases += [("round_dispatch", at, MS,
+                    {"round": i, "width": 1, "live": 1}),
+                   ("round_wait", at + MS, dur + 5 * MS,
+                    {"round": i, "steps": 1, "attended": attended})]
+        modules.append(("jit_decode_rounds(5)", at + MS, dur))
+    return phases, modules, dur / 1e9
+
+
+def _window(steps, rounds, tokens, pages_in_use, pages=2400):
+    before = dict(steps=1000, fused_rounds=100, tokens=5000)
+    at_close = dict(steps=1000 + steps, fused_rounds=100 + rounds,
+                    tokens=5000 + tokens, kv_block_tokens=16, kv_planes=16)
+    return dict(counters={"before": before, "at_close": at_close},
+                samples=[(float(t), pages_in_use, pages, 0)
+                         for t in range(50)])
+
+
+def test_a_wide_window_over_a_narrow_trace_stays_under_100(monkeypatch):
+    """The window's mean round is 6 steps wide; the traced 5 s hold only
+    rounds of one step, each three times its least time long.  The window's
+    mean times the trace's mean reads 200 %; the calls' own steps read the
+    33 % that is there."""
+    phases, modules, seconds = _one_step_calls(DENSE, 4, 0)
+    run = _traced_run(monkeypatch, phases, modules, config=MISTRAL,
+                      **_window(steps=600, rounds=100, tokens=600,
+                                pages_in_use=0))
+    assert _window_mean_share(run) == pytest.approx(200.0, rel=1e-6)
+    share = _reader("decode_rounds_roofline").read(run)
+    assert share == pytest.approx(100 * _least(DENSE, 1, 0) / seconds)
+    assert share == pytest.approx(100 / 3, rel=1e-6)
+    # A prefix cache that keeps the pool 90 % in use (2,160 pages of 16
+    # positions: 2.26 GB a step on top of 7.25 GB of weights) adds a third
+    # to that formula and nothing to the reader.
+    cached = dict(run, **_window(steps=600, rounds=100, tokens=600,
+                                 pages_in_use=2160))
+    assert _window_mean_share(cached) > 260
+    assert _reader("decode_rounds_roofline").read(cached) == share
+
+
+@pytest.mark.parametrize("name, config, hand", ROOFLINES)
+def test_the_roofline_follows_the_attended_positions_not_the_pool(
+        monkeypatch, name, config, hand):
+    """A prefix cache keeps the pool 90 % in use (2,160 pages of 16
+    positions) while the live sequences attend 300 positions: the reading is
+    by the 300, moves with them, and is the same whatever the pool's
+    samples and the window's counters say, or without any."""
+    phases, modules, seconds = _one_step_calls(hand, 3, 300)
+    full = _window(steps=3, rounds=3, tokens=3, pages_in_use=2160)
+    empty = _window(steps=900, rounds=7, tokens=4000, pages_in_use=0)
+    reads = [_reader(name).read(_traced_run(
+        monkeypatch, phases, modules, config=config, **more))
+        for more in (full, empty, {})]
+    assert reads[0] == reads[1] == reads[2] == pytest.approx(
+        100 * _least(hand, 1, 300) / seconds)
+    more_attended, _, _ = _one_step_calls(hand, 3, 600)
+    moved = _reader(name).read(_traced_run(
+        monkeypatch, more_attended, modules, config=config, **full))
+    assert moved == pytest.approx(100 * _least(hand, 1, 600) / seconds)
+    assert moved > reads[0]
+
+
+def test_both_metric_names_read_one_number(monkeypatch):
+    """To the last digit, on a looped run and on a dense one."""
+    for config in (OURO, MISTRAL):
+        run = _traced_run(monkeypatch, PHASES, MODULES, config=config)
+        assert _reader("decode_rounds_roofline").read(run) == \
+            _reader("loop.decode_rounds_roofline").read(run) == \
+            traced_rounds.roofline_share(run)
 
 
 # -- a broken program through the whole run -----------------------------------

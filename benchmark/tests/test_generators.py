@@ -13,6 +13,13 @@ MIXES = sorted(p.stem for p in (BENCH / "traffic").glob("*.json"))
 BIG = 2**31 + 12345
 
 
+def states_order(mix_name):
+    """Whether the mix draws the order of its sizes from a number of its
+    own (``order_seed``) and not from the run's seed."""
+    mix = json.loads((BENCH / "traffic" / f"{mix_name}.json").read_text())
+    return "order_seed" in mix["params"]
+
+
 def plan_of(mix_name, seed, seconds=6.0):
     mix = json.loads((BENCH / "traffic" / f"{mix_name}.json").read_text())
     spec = importlib.util.spec_from_file_location(
@@ -68,10 +75,38 @@ def test_every_seed_gets_the_same_work(mix):
 
 
 @pytest.mark.parametrize("mix", MIXES)
-def test_the_seed_draws_the_order(mix):
+def test_the_seed_draws_the_order_unless_the_mix_states_it(mix):
     a, b = requests_of(plan_of(mix, 7)), requests_of(plan_of(mix, BIG))
-    assert [(len(r["prompt"]), r["max_new"]) for r in a] != \
-        [(len(r["prompt"]), r["max_new"]) for r in b]
+    sizes = [[(len(r["prompt"]), r["max_new"]) for r in rs] for rs in (a, b)]
+    if states_order(mix):
+        # One schedule for every seed; the seed still draws every token.
+        assert sizes[0] == sizes[1]
+        assert not any(np.array_equal(x["prompt"], y["prompt"])
+                       for x, y in zip(a, b))
+    else:
+        assert sizes[0] != sizes[1]
+
+
+def test_order_seed_alone_draws_the_order_of_a_closed_loop():
+    mix = json.loads((BENCH / "traffic" / "docqa.json").read_text())
+    spec = importlib.util.spec_from_file_location(
+        "gen", BENCH / "generators" / "closed_loop_docs.py")
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    params = dict(mix["params"], **mix["rehearse"]["params"])
+
+    def sizes(p, seed):
+        return [(len(r["prompt"]), r["max_new"])
+                for r in requests_of(gen.plan(p, seed, 6.0, 512))]
+
+    stated = dict(params, order_seed=3)
+    assert sizes(stated, 1) == sizes(stated, BIG)
+    assert sizes(stated, 1) != sizes(dict(params, order_seed=4), 1)
+    free = {k: v for k, v in params.items() if k != "order_seed"}
+    assert sizes(free, 1) != sizes(free, BIG)
+    assert sorted(sizes(free, 1)) != [] and \
+        sorted(m for _, m in sizes(free, 1)) == \
+        sorted(m for _, m in sizes(stated, 1))
 
 
 def test_open_loop_ramp_is_set_up_traffic_of_the_same_rate():
