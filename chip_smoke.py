@@ -487,6 +487,12 @@ def phase_serve(run, export_dir):
          compiled_programs=programs,
          steps=stats["steps"],
          decode_kernel_steps=stats["decode_kernel_steps"],
+         # The engine's largest program by the compiler's account beside
+         # both sides of its paged pool: a program that copies the pool
+         # holds a second one among its temporaries.
+         compiled_peak_bytes=stats["compiled_peak_bytes"],
+         kv_pool_bytes=stats["kv_blocks"] * stats["kv_block_tokens"]
+         * stats["kv_bytes_per_token"],
          mean_occupancy=stats.get("mean_occupancy"),
          memory=server.memory,
          cache_entries_written=run.cache_entries() - before)

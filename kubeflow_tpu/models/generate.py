@@ -91,13 +91,15 @@ def _rms_norm(x, scale, eps, dtype):
 
 def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                 cache_len, positions, pad_amount=None, write_cols=None,
-                tables=None, adapters=None, paged_kernel=False):
+                tables=None, adapters=None, paged_kernel=False, plane=None):
     """One decoder block against the KV cache.
 
     x: [b, t, e] new activations (t = prompt len at prefill, 1 at decode);
-    cache_kv: (k, v) each [b, max_len, hkv, d] — or, when ``tables`` is
-    given, a paged block POOL [num_blocks, block_tokens, hkv, d] shared
-    by every slot;
+    cache_kv: (k, v) each [b, max_len, hkv, d], this layer's own — or,
+    when ``tables`` is given, the paged block POOL of EVERY plane,
+    stacked [kv_planes, num_blocks, block_tokens, hkv, d] and shared by
+    every slot, of which this call writes and reads plane ``plane`` (a
+    traced scalar) and hands the whole pool back;
     cache_len: number of valid cache positions before this call — a
     scalar (whole batch at one length, the generate() path) or a [b]
     array (per-row lengths, the slot-based decode_step / verify_step
@@ -113,18 +115,21 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
     drops it.
     tables: [b, max_blocks] int32 per-row block tables mapping each
     row's LOGICAL block index (position // block_tokens) to a physical
-    pool block.  Fresh k/v scatter straight into the pool at their
-    (block, offset) coordinates — a logical index past the table span,
-    or a table entry holding the sentinel ``num_blocks`` (unallocated),
-    drops the write — and attention runs over the row's gathered
-    [max_blocks * block_tokens] view of the pool (sentinel entries
-    clamp onto an arbitrary block whose columns all sit beyond the
-    causal frontier, so the garbage they contribute is masked).
+    pool block.  Fresh k/v scatter straight into the stacked pool at
+    their (plane, block, offset) coordinates: t columns a row, never a
+    plane sliced out and put back — a logical index past the table
+    span, or a table entry holding the sentinel ``num_blocks``
+    (unallocated), drops the write — and attention runs over the row's
+    [max_blocks * block_tokens] view, ONE gather ``pool[plane, tables]``
+    of the row's own pages (sentinel entries clamp onto an arbitrary
+    block whose columns all sit beyond the causal frontier, so the
+    garbage they contribute is masked).
     paged_kernel (static; the serving engine sets it when its pool
     lives on a TPU): a step with ONE query position per row (t == 1:
     decode_step, decode_rounds) over a plain-array pool gathers no
-    view — ops/paged_attention.py reads each row's resident pages from
-    the pool in place (a row whose write is parked attends nothing).
+    view — ops/paged_attention.py is handed the stacked pool and the
+    plane and reads each row's resident pages in place (a row whose
+    write is parked attends nothing).
     Wider steps (the prefill chunk, speculative verify), an int8
     ``QTensor`` pool and every other backend keep the view and
     ``dot_product_attention``.
@@ -164,18 +169,19 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
     per_row = not isinstance(cache_len, int) and cache_len.ndim == 1
     if tables is not None:
         vals = ck.values if isinstance(ck, QTensor) else ck
-        nb, bt = vals.shape[0], vals.shape[1]
+        nb, bt = vals.shape[1], vals.shape[2]
         mb = tables.shape[1]
 
         def store(c, new):  # new: [b, t, hk, d]
             if isinstance(c, QTensor):
                 qvals, s = quantize_array(new, (-1,))
                 return QTensor(
-                    c.values.at[blk, off].set(qvals, mode="drop"),
-                    c.scale.at[blk, off].set(s, mode="drop"),
+                    c.values.at[plane, blk, off].set(qvals, mode="drop"),
+                    c.scale.at[plane, blk, off].set(s, mode="drop"),
                     c.axes,
                 )
-            return c.at[blk, off].set(new.astype(c.dtype), mode="drop")
+            return c.at[plane, blk, off].set(new.astype(c.dtype),
+                                             mode="drop")
 
         with jax.named_scope("kft.kv_write"):
             if per_row:
@@ -196,13 +202,14 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
             cv = store(cv, v)
 
         def paged_view(c):
-            # Row view of the (just-updated) pool: OOB sentinel
-            # entries clamp, contributing finite garbage that the
-            # kv_offset mask discards.
+            # Row view of the (just-updated) pool, the row's pages of
+            # this plane in ONE gather (p[plane] first would copy the
+            # plane): OOB sentinel entries clamp, contributing finite
+            # garbage that the kv_offset mask discards.
             def gather(p):
-                g = p[tables]
+                g = p[plane, tables]
                 return g.reshape(
-                    (tables.shape[0], mb * bt) + p.shape[2:])
+                    (tables.shape[0], mb * bt) + p.shape[3:])
 
             if isinstance(c, QTensor):
                 return QTensor(gather(c.values), gather(c.scale),
@@ -219,7 +226,7 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                 # parked write marks a retired row, which reads nothing.
                 attend = jnp.where(base < mb * bt, cache_len + 1, 0)
                 out = paged_attention.paged_decode_attention(
-                    q[:, 0], ck, cv, tables, attend)[:, None]
+                    q[:, 0], ck, cv, plane, tables, attend)[:, None]
         else:
             with jax.named_scope("kft.kv_view"):
                 view_k, view_v = paged_view(ck), paged_view(cv)
@@ -355,7 +362,11 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
     frontier (t = 1 at decode, k+1 at speculative verify).
     tables: per-row block tables for the paged block-pool cache (the
     serving engine's unified KV store — see _layer_step); None keeps
-    the contiguous per-row layout generate() uses.
+    the contiguous per-row layout generate() uses.  With tables,
+    ``cache`` is the stacked pool [kv_planes, num_blocks, block_tokens,
+    hkv, d] a side and the layer scan CARRIES it: each plane's layer
+    scatters its t new columns at (plane, block, offset) and reads its
+    pages by plane, so a donated pool is updated in place and returned.
     adapter_ids ([b] int32, optional): per-row index into the stacked
     ``params["adapters"]`` low-rank delta arrays (multi-model adapter
     serving, §5.11) — ignored when the params tree carries no adapter
@@ -400,29 +411,21 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
     def final_norm(x):
         return _rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps, dt)
 
-    # The caches ride the scan as xs/ys (sliced per plane on the leading
-    # axis, re-stacked from the per-plane outputs) — NOT as carry with
-    # `cache.at[idx].set(...)`.  Indexed whole-cache updates in the body
-    # compile to a copy of the full [L, b, s, h, d] buffer per layer per
-    # token (measured 235 ms/token for a 188M model on v5e — ~20 GB of
-    # HBM traffic per 128-token request); scan ys write each plane's
-    # slice in place.
-    #
     # ONE scan over the cfg.kv_planes cache planes, step-major: plane p
     # is layer p % n_layers of loop step p // n_layers, and the body
     # indexes the stacked weights (and adapters) by that layer, which is
     # what riding them as xs compiles to.  A scan per loop step would
     # slice a quarter of the pool out and write it back on top.
-    def body(x, inputs):
-        plane, ck, cv = inputs
+    def step(x, cache_kv, plane):
         layer = plane % cfg.n_layers
         layer_params, ad = jax.tree_util.tree_map(
             lambda w: jax.lax.dynamic_index_in_dim(w, layer, keepdims=False),
             (layer_stack, adapter_stack))
-        x, (ck, cv) = _layer_step(
-            cfg, layer_params, x, (ck, cv), cache_len, positions,
+        x, cache_kv = _layer_step(
+            cfg, layer_params, x, cache_kv, cache_len, positions,
             pad_amount=pad_amount, write_cols=write_cols,
             tables=tables, adapters=ad, paged_kernel=paged_kernel,
+            plane=plane,
         )
         if cfg.loop_steps > 1:
             # Step t + 1 reads the NORMED output of step t's last
@@ -431,11 +434,37 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
                 hand_over = (layer == cfg.n_layers - 1) \
                     & (plane < cfg.kv_planes - 1)
                 x = jnp.where(hand_over, final_norm(x), x)
-        return x, (ck, cv)
+        return x, cache_kv
 
-    cache_k, cache_v = cache
-    xs = (jnp.arange(cfg.kv_planes), cache_k, cache_v)
-    x, (cache_k, cache_v) = jax.lax.scan(body, x, xs)
+    planes = jnp.arange(cfg.kv_planes)
+    if tables is not None:
+        # The paged pool is the scan's CARRY, whole: a layer scatters its
+        # t columns into the stacked, donated array at (plane, block,
+        # offset) and reads its pages by plane, so the pool that comes in
+        # is the pool that goes out.  As xs / ys each plane was sliced
+        # out, written and restacked, and the step programs' while_loop
+        # and donation could not alias through that: two copies of the
+        # whole pool a call and a second pool of temporaries (v5e traces,
+        # PRs 25-28: 30 of a 1.9B model's 37 ms a token).
+        def body(carry, plane):
+            x, cache_kv = step(carry[0], carry[1:], plane)
+            return (x, *cache_kv), None
+
+        (x, *cache), _ = jax.lax.scan(body, (x, *cache), planes)
+    else:
+        # generate()'s contiguous cache rides as xs / ys (sliced per
+        # plane, re-stacked from the per-plane outputs).  Its layers
+        # write and attend WHOLE planes ([b, max_len, h, d]), and as
+        # carry with an indexed update the compiler still copies the
+        # whole cache around the loops (described-v5e compile of
+        # generate(), 32 rows x 1280 positions: temporaries of 6.05 GB
+        # as carry against 6.29 GB so, six times one side of the cache;
+        # the first such form measured 235 ms/token for a 188M model on
+        # v5e): a second form would buy nothing here.
+        def body(x, inputs):
+            return step(x, inputs[1:], inputs[0])
+
+        x, cache = jax.lax.scan(body, x, (planes, *cache))
 
     with jax.named_scope("kft.logits"):
         x = final_norm(x)
@@ -444,7 +473,7 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
         else:
             logits = qeinsum("bse,ev->bsv", x, params["w_out"], dt)
         logits = logits.astype(jnp.float32)
-    return logits, (cache_k, cache_v)
+    return logits, tuple(cache)
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,

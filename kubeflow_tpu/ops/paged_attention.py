@@ -2,16 +2,23 @@
 
 The step programs of the serving engine (``models/generate.py``
 ``decode_step`` / ``decode_rounds``) attend ONE query position per slot
-against a pool ``[num_blocks, block_tokens, hkv, d]`` that every slot
-shares through its block table.  The plain path gathers each slot's
-whole ``[max_blocks * block_tokens]`` view, repeats it over the GQA
-group and multiplies in float32: tens of gigabytes of HBM traffic a
-step for a gigabyte of resident keys and values.  This kernel leaves
-the pool in HBM and, per slot, copies only the pages below the slot's
-frontier into VMEM, several pages a block, the next block's copies in
-flight while this one is computed.
+against a pool that every slot shares through its block table.  The
+pool is STACKED, ``[kv_planes, num_blocks, block_tokens, hkv, d]``: one
+plane per layer (per loop step and layer in a looped stack), and the
+layer scan carries it whole, so a layer hands the kernel the whole pool
+and its plane, never a slice.  The plain path gathers each slot's whole
+``[max_blocks * block_tokens]`` view, repeats it over the GQA group and
+multiplies in float32: tens of gigabytes of HBM traffic a step for a
+gigabyte of resident keys and values.  This kernel leaves the pool in
+HBM and, per slot, copies only the pages below the slot's frontier into
+VMEM, several pages a block, the next block's copies in flight while
+this one is computed.
 
-Layout.  A page is ``[bt, hkv, d]``; with the two leading axes merged
+Layout.  The pool is viewed ``[kv_planes * num_blocks, bt * hkv, d]``
+(a bitcast: the leading axes merged, and a page's two), so physical
+page ``page`` of plane ``plane`` is row ``plane * num_blocks + page`` of
+the view, and the plane rides in as a scalar beside the tables.  A page
+is ``[bt, hkv, d]``; with its two leading axes merged
 it is ``bt * hkv`` rows of ``d``: one row per (position, kv head).  The
 kernel treats those rows as the keys of a plain single-query flash
 step: ``q [h, d] @ rows^T`` scores every query head against every
@@ -42,10 +49,11 @@ from jax.experimental.pallas import tpu as pltpu
 _MASKED = -1e30
 
 
-def _kernel(tables_ref, ntok_ref, q_ref, k_hbm, v_hbm, o_ref,
+def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, k_hbm, v_hbm, o_ref,
             kbuf, vbuf, sems, *, mb, bt, hkv, g, pages, nb, scale):
     """One slot: walk its resident pages ``pages`` at a time."""
     s = pl.program_id(0)
+    first = plane_ref[0] * nb             # the plane's page 0 in the view
     n = ntok_ref[s]                       # positions to attend (0: none)
     n_pages = (n + bt - 1) // bt
     n_blocks = (n_pages + pages - 1) // pages
@@ -61,8 +69,9 @@ def _kernel(tables_ref, ntok_ref, q_ref, k_hbm, v_hbm, o_ref,
     def copies(blk, buf, p):
         # Entries below the frontier are real pages; the clamp only
         # keeps a sentinel (== nb) that a wrong table would hold inside
-        # the pool.
-        page = jnp.minimum(tables_ref[s * mb + blk * pages + p], nb - 1)
+        # the plane.
+        page = first + jnp.minimum(
+            tables_ref[s * mb + blk * pages + p], nb - 1)
         dst = pl.ds(p * rows, rows)
         return (
             pltpu.make_async_copy(
@@ -124,22 +133,25 @@ def _kernel(tables_ref, ntok_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("pages_per_block",
                                              "interpret"))
-def paged_decode_attention(q, k_pool, v_pool, tables, n_tokens, *,
+def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
                            pages_per_block: int = 16,
                            interpret: bool = False):
     """``q [S, h, d]`` against each slot's resident pages -> ``[S, h, d]``.
 
-    k_pool / v_pool: ``[num_blocks, block_tokens, hkv, d]``, left in HBM.
+    k_pool / v_pool: ``[kv_planes, num_blocks, block_tokens, hkv, d]``,
+    the stacked pools, left in HBM.
+    plane: int32 scalar (traced in the layer scan): the plane to read.
     tables ``[S, max_blocks]`` int32: slot s's logical page i lives in
-    physical page ``tables[s, i]``; entries at and above the slot's
-    frontier are never read (they may hold the sentinel ``num_blocks``).
+    physical page ``tables[s, i]`` of that plane; entries at and above
+    the slot's frontier are never read (they may hold the sentinel
+    ``num_blocks``).
     n_tokens ``[S]`` int32: how many positions slot s attends, counted
     from 0 and INCLUDING the step's own (already written to the pool);
     0 does no page and returns zeros (a retired slot).  Slots may share
     physical pages (the prefix cache's aliasing).
     """
     S, h, d = q.shape
-    nb, bt, hkv, _ = k_pool.shape
+    planes, nb, bt, hkv, _ = k_pool.shape
     mb = tables.shape[1]
     assert h % hkv == 0, (h, hkv)
     pages = max(1, min(pages_per_block, mb))
@@ -152,7 +164,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, n_tokens, *,
         name="paged_decode_attention",
         out_shape=jax.ShapeDtypeStruct((S, h, d), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(S,),
             in_specs=[
                 pl.BlockSpec((1, h, d), lambda s, *_: (s, 0, 0)),
@@ -170,4 +182,6 @@ def paged_decode_attention(q, k_pool, v_pool, tables, n_tokens, *,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(tables.reshape(-1).astype(jnp.int32), n_tokens.astype(jnp.int32),
-      q, k_pool.reshape(nb, rows, d), v_pool.reshape(nb, rows, d))
+      jnp.reshape(plane, (1,)).astype(jnp.int32), q,
+      k_pool.reshape(planes * nb, rows, d),
+      v_pool.reshape(planes * nb, rows, d))

@@ -414,6 +414,21 @@ class TestFusedDecode:
         engine.close()
 
 
+def _with_config(spec, **over):
+    """The tiny LM with some fields of its config changed, and weights
+    initialised anew for it."""
+    import jax
+    from flax import linen as nn
+
+    from kubeflow_tpu.models.transformer import Transformer
+
+    cfg = dataclasses.replace(spec["cfg"], **over)
+    params = nn.unbox(Transformer(cfg).init(
+        jax.random.key(SEED), np.zeros((1, PROMPT_LEN), np.int32))
+        ["params"])
+    return {"cfg": cfg, "params": params, "decode": spec["decode"]}
+
+
 class TestPagedKernelChoice:
     """serving/engine.py decides ONCE, from the platform its pool lives
     on, whether decode_step / decode_rounds attend through
@@ -424,16 +439,7 @@ class TestPagedKernelChoice:
     def _lane_wide(spec):
         """The tiny LM with heads of 128: the engine takes the kernel
         only where a page's rows fill whole 128-lane tiles."""
-        import jax
-        from flax import linen as nn
-
-        from kubeflow_tpu.models.transformer import Transformer
-
-        cfg = dataclasses.replace(spec["cfg"], head_dim=128)
-        params = nn.unbox(Transformer(cfg).init(
-            jax.random.key(SEED), np.zeros((1, PROMPT_LEN), np.int32))
-            ["params"])
-        return {"cfg": cfg, "params": params, "decode": spec["decode"]}
+        return _with_config(spec, head_dim=128)
 
     @pytest.mark.parametrize("decode_rounds", [1, 8])
     def test_kernel_engine_matches_plain_engine_and_counts_its_steps(
@@ -506,3 +512,231 @@ class TestPagedKernelChoice:
         assert engine_mod._plain_pool_platform(pool) is None
         assert engine_mod._plain_pool_platform(
             jnp.zeros((4, 4, 2, 8))) == "cpu"
+
+
+# Greedy tokens of the cases below from the PARENT's programs (commit
+# c94825e: the pool rode the layer scan as xs / ys), new tokens only, on
+# this suite's CPU backend.  The carry form computes the same products in
+# the same order, so the tokens are these, bit for bit.
+_PARENT_TOKENS = {
+    "int8-k1": [
+        [98, 98, 98, 98, 98, 98, 27, 27, 27, 27, 27, 27],
+        [16, 16, 16, 16, 16, 16],
+        [23, 92, 88],
+        [2, 73, 43, 43, 25, 113, 113, 102],
+        [99, 16, 16, 16, 46, 46, 46, 30, 30, 102, 102, 65],
+        [123, 123, 123, 123],
+        [126, 126, 102, 102, 98, 48, 102, 98, 98, 98],
+    ],
+    "int8-k8": [
+        [98, 98, 98, 98, 98, 98, 27, 27, 27, 27, 27, 27],
+        [16, 16, 16, 16, 16, 16],
+        [23, 92, 88],
+        [2, 73, 43, 43, 25, 113, 113, 102],
+        [99, 16, 16, 16, 46, 46, 46, 30, 30, 102, 102, 65],
+        [123, 123, 123, 123],
+        [126, 126, 102, 102, 98, 48, 102, 98, 98, 98],
+    ],
+    "looped": [
+        [36, 36, 102, 36, 102, 27, 27, 36, 36, 36, 36, 36],
+        [16, 16, 16, 16, 16, 16],
+        [53, 123, 9],
+        [2, 80, 53, 74, 71, 53, 74, 71],
+        [9, 5, 17, 46, 17, 46, 46, 5, 95, 46, 46, 46],
+        [45, 45, 45, 45],
+        [57, 57, 35, 57, 26, 57, 57, 26, 57, 26],
+    ],
+    "mesh4": [
+        [39, 39, 63, 63, 63, 63, 46, 46, 42, 42, 42, 42],
+        [66, 66, 66, 66, 66, 66],
+        [108, 108, 57],
+        [15, 19, 19, 90, 90, 90, 105, 105],
+        [48, 48, 64, 64, 64, 64, 64, 1, 107, 107, 107, 107],
+        [114, 114, 114, 2],
+        [50, 47, 47, 47, 47, 47, 42, 42, 42, 42],
+    ],
+    "plain-k1": [
+        [98, 98, 98, 98, 98, 98, 27, 27, 27, 27, 27, 27],
+        [16, 16, 16, 16, 16, 16],
+        [23, 92, 88],
+        [2, 73, 43, 43, 25, 113, 113, 102],
+        [99, 16, 16, 16, 46, 46, 46, 30, 30, 102, 102, 65],
+        [123, 123, 123, 123],
+        [126, 126, 102, 102, 98, 48, 102, 98, 98, 98],
+    ],
+    "plain-k8": [
+        [98, 98, 98, 98, 98, 98, 27, 27, 27, 27, 27, 27],
+        [16, 16, 16, 16, 16, 16],
+        [23, 92, 88],
+        [2, 73, 43, 43, 25, 113, 113, 102],
+        [99, 16, 16, 16, 46, 46, 46, 30, 30, 102, 102, 65],
+        [123, 123, 123, 123],
+        [126, 126, 102, 102, 98, 48, 102, 98, 98, 98],
+    ],
+    "verify": [
+        [98, 98, 98, 98, 98, 98, 98, 98, 98, 98, 98, 98],
+        [16, 16, 16, 16, 16, 16],
+        [23, 23, 23],
+        [2, 73, 43, 43, 25, 113, 113, 102],
+        [102, 102, 16, 16, 16, 16, 16, 16, 16, 16, 16, 16],
+        [123, 123, 123, 123],
+        [62, 62, 62, 62, 62, 86, 8, 8, 59, 59],
+    ],
+}
+
+
+class TestPoolCarriedInPlace:
+    """models/generate.py carries the STACKED paged pool through the
+    layer scan: a layer scatters its columns at (plane, block, offset)
+    and reads its pages by plane.  Invisible in the tokens, for every
+    stack that runs the paged programs: plain and int8 pools, single
+    and fused steps, a verify round, a looped stack, a mesh."""
+
+    CASES = {
+        "plain-k1": {"decode_rounds": 1},
+        "plain-k8": {"decode_rounds": 8},
+        "int8-k1": {"decode_rounds": 1, "kv": "int8"},
+        "int8-k8": {"decode_rounds": 8, "kv": "int8"},
+        "verify": {"decode_rounds": 1, "speculative_tokens": 4},
+        "looped": {"decode_rounds": 8,
+                   "cfg": {"loop_steps": 2, "sandwich_norm": True}},
+        "mesh4": {"decode_rounds": 8, "tensor": 4,
+                  "cfg": {"n_kv_heads": 4}},
+    }
+
+    @classmethod
+    def serve(cls, spec, case, monkeypatch):
+        """(new tokens per request, generate()'s, stats) of one case."""
+        import kubeflow_tpu.serving.engine as eng_mod
+        from kubeflow_tpu.serving import sharding
+
+        case = dict(cls.CASES[case])
+        if "cfg" in case:
+            spec = _with_config(spec, **case.pop("cfg"))
+        decode = dataclasses.replace(
+            spec["decode"], kv_cache_dtype=case.pop("kv", "model"))
+        rng = np.random.RandomState(SEED + 29)
+        lens = [3, 9, 16, 2, 12, 16, 5]
+        news = [12, 6, 3, 8, 12, 4, 10]
+        prompts = [rng.randint(1, VOCAB, size=(n,)).tolist() for n in lens]
+        if "speculative_tokens" in case:
+            # Repeating prompts, so that the drafter has something to
+            # propose, and no throughput veto of the verify rounds.
+            prompts = [np.tile(p[:4], 3).tolist() if i % 2 == 0 else p
+                       for i, p in enumerate(prompts)]
+            monkeypatch.setattr(eng_mod, "_SPEC_RATE_MARGIN", 0.0)
+        tensor = case.pop("tensor", 0)
+        if tensor:
+            case["mesh"] = sharding.build_mesh({"tensor": tensor})
+        want = _reference_rows(spec, prompts, news, decode)
+        outs, stats, _ = _run_engine(
+            spec, prompts, news, decode=decode, name="test-in-place",
+            **case)
+        got = [np.asarray(o["tokens"])[0].tolist() for o in outs]
+        assert [g[:len(p)] for g, p in zip(got, prompts)] == prompts
+        return ([g[len(p):] for g, p in zip(got, prompts)],
+                [w[len(p):] for w, p in zip(want, prompts)], stats)
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_tokens_are_generates_and_the_parents(self, engine_model,
+                                                  monkeypatch, case):
+        got, want, stats = self.serve(engine_model[0], case, monkeypatch)
+        assert got == want, "drifted from single-request generate()"
+        assert got == _PARENT_TOKENS[case], "drifted from the parent's"
+        assert stats["steps"] > 0
+        if case == "verify":
+            assert stats["spec_steps"] > 0
+        if case == "looped":
+            assert stats["kv_planes"] == 4 and stats["fused_rounds"] > 0
+        if case == "mesh4":
+            assert stats["mesh_devices"] == 4
+
+    @pytest.mark.parametrize("kv", ["model", "int8"])
+    def test_parked_and_sentinel_writes_leave_every_plane_as_it_was(
+            self, engine_model, kv):
+        """A retired slot parks its write past the table span, an
+        unallocated logical page holds the sentinel (== pool size): the
+        scatter into the stacked pool drops both, in every plane, in
+        every program."""
+        import jax
+        import jax.numpy as jnp
+
+        from kubeflow_tpu.models import generate as gen
+
+        spec, _ = engine_model
+        cfg, params = spec["cfg"], spec["params"]
+        decode = dataclasses.replace(spec["decode"], kv_cache_dtype=kv)
+        slots, nb, bt, mb = 3, 10, 4, 3
+        rng = np.random.RandomState(SEED + 33)
+
+        def noise(leaf):
+            return jnp.asarray(
+                rng.randint(-100, 100, leaf.shape).astype(leaf.dtype))
+
+        def state(**over):
+            s = gen.init_paged_state(cfg, slots, nb, bt, kv)
+            for side in ("cache_k", "cache_v"):
+                s[side] = jax.tree_util.tree_map(noise, s[side])
+            return {**s, **{k: jnp.asarray(v) for k, v in over.items()}}
+
+        def pool(s):
+            return [np.asarray(leaf) for side in ("cache_k", "cache_v")
+                    for leaf in jax.tree_util.tree_leaves(s[side])]
+
+        def unchanged(before, s):
+            after = pool(s)
+            assert before[0].shape[0] == cfg.kv_planes
+            assert all(np.array_equal(a, b)
+                       for a, b in zip(before, after))
+
+        tables = jnp.asarray(
+            rng.permutation(nb)[:slots * mb].reshape(slots, mb), jnp.int32)
+        # Every slot retired: each step parks its write.
+        s = state()
+        before = pool(s)
+        s, _ = gen.decode_step(cfg, params, s, decode, 2, tables)
+        unchanged(before, s)
+        s = state()
+        before = pool(s)
+        s, _, _ = gen.verify_step(
+            cfg, params, s, decode, 2, jnp.ones((slots, 2), jnp.int32),
+            jnp.full((slots,), 2, jnp.int32), tables)
+        unchanged(before, s)
+        # Live slots whose next position falls on a page the table does
+        # not hold (the sentinel), single and fused steps.
+        live = {"done": np.zeros(slots, bool),
+                "lengths": np.full(slots, bt, np.int32),
+                "stop_len": np.full(slots, 2 * bt, np.int32)}
+        holes = tables.at[:, 1:].set(nb)
+        s = state(**live)
+        before = pool(s)
+        s, _ = gen.decode_step(cfg, params, s, decode, 1, holes)
+        unchanged(before, s)
+        s = state(**live)
+        before = pool(s)
+        s, _, _, steps = gen.decode_rounds(
+            cfg, params, s, decode, 4, holes, jnp.int32(3))
+        assert int(steps) == 3
+        unchanged(before, s)
+        # A chunk whose table row holds no page at all.
+        s = state()
+        before = pool(s)
+        s, _ = gen.prefill_chunk_into_slot(
+            cfg, params, s, decode, jnp.ones((1, 8), jnp.int32),
+            jnp.int32(0), jnp.int32(8), jnp.int32(4), jnp.int32(1),
+            jnp.int32(7), jnp.full((1, mb), nb, jnp.int32))
+        unchanged(before, s)
+        # The control: the same chunk through a real row writes its 8
+        # columns into EVERY plane, and nowhere else.
+        s = state()
+        before = pool(s)
+        s, _ = gen.prefill_chunk_into_slot(
+            cfg, params, s, decode, jnp.ones((1, 8), jnp.int32),
+            jnp.int32(0), jnp.int32(8), jnp.int32(4), jnp.int32(1),
+            jnp.int32(7), tables[1:2])
+        row = np.asarray(tables[1, :2])
+        for b, a in zip(before, pool(s)):
+            changed = np.any(
+                (a != b).reshape(a.shape[0], a.shape[1], -1), axis=-1)
+            assert changed[:, row].all() and changed.sum() \
+                == cfg.kv_planes * 2

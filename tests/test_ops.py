@@ -412,8 +412,11 @@ class TestPagedDecodeKernel:
         h = hkv * group
         nb = slots * _TABLE + 3
         q = jnp.asarray(rng.randn(slots, h, d), jnp.float32)
-        k_pool = jnp.asarray(rng.randn(nb, _PAGE, hkv, d), jnp.float32)
-        v_pool = jnp.asarray(rng.randn(nb, _PAGE, hkv, d), jnp.float32)
+        # The engine's stacked pool: three planes, the kernel reads the
+        # middle one; its neighbours hold other numbers, so a wrong plane
+        # offset cannot pass.
+        k_pools = jnp.asarray(rng.randn(3, nb, _PAGE, hkv, d), jnp.float32)
+        v_pools = jnp.asarray(rng.randn(3, nb, _PAGE, hkv, d), jnp.float32)
         # Physical pages in no order, other slots at lengths of their own.
         tables = rng.permutation(nb)[:slots * _TABLE].reshape(
             slots, _TABLE).astype(np.int32)
@@ -436,9 +439,9 @@ class TestPagedDecodeKernel:
             n[0], n[1] = 2 * _PAGE + 3, 3 * _PAGE + 1
         tables, n = jnp.asarray(tables), jnp.asarray(n)
         out = paged_decode_attention(
-            q, k_pool, v_pool, tables, n, pages_per_block=2,
-            interpret=True)
-        ref = self._reference(q, k_pool, v_pool, tables, n)
+            q, k_pools, v_pools, jnp.int32(1), tables, n,
+            pages_per_block=2, interpret=True)
+        ref = self._reference(q, k_pools[1], v_pools[1], tables, n)
         live = np.asarray(n) > 0
         np.testing.assert_allclose(
             np.asarray(out)[live], np.asarray(ref)[live], atol=2e-5)

@@ -186,11 +186,13 @@ CELLS = {
     "internlm2-1.8b": (
         {"vocab_size": 92_544, "d_model": 2048, "n_layers": 24,
          "n_heads": 16, "n_kv_heads": 8, "d_ff": 8192, "head_dim": 128,
-         "max_seq_len": 32_768, "dtype": "bfloat16"}, 16, 2560),
+         "max_seq_len": 32_768, "tied_embeddings": False,
+         "dtype": "bfloat16"}, 16, 2560),
     "mistral-7b-v0.3-l16": (
         {"vocab_size": 32_768, "d_model": 4096, "n_layers": 16,
          "n_heads": 32, "n_kv_heads": 8, "d_ff": 14_336, "head_dim": 128,
-         "max_seq_len": 32_768, "dtype": "bfloat16"}, 6, 6400),
+         "max_seq_len": 32_768, "tied_embeddings": False,
+         "dtype": "bfloat16"}, 6, 6400),
     # A looped stack: 192 planes over 48 layers' weights, kv heads = heads
     # (a page is 256 rows of the kernel's block, twice the others').
     "ouro-2.6b": (
@@ -202,21 +204,44 @@ CELLS = {
 }
 
 
+@pytest.fixture(scope="module")
+def cell_program(chip):
+    """``(engine shapes, compiled program)`` of a cell's engine, each
+    program compiled once for the tests below: ``decode_rounds`` (8 steps
+    wide, with the paged kernel) or ``prefill_chunk_into_slot`` (64
+    columns)."""
+    import functools
+
+    from kubeflow_tpu.models import generate
+
+    @functools.cache
+    def compiled(name, program):
+        widths, slots, max_len = CELLS[name]
+        e = _engine_shapes(chip, widths, slots, max_len)
+        if program == "decode_rounds":
+            return e, generate.decode_rounds.lower(
+                e["cfg"], e["params"], e["state"], e["decode"], 8,
+                e["arg"](slots, e["table_blocks"]), e["arg"](),
+                paged_kernel=True).compile()
+        scalar = e["arg"]()
+        return e, generate.prefill_chunk_into_slot.lower(
+            e["cfg"], e["params"], e["state"], e["decode"], e["arg"](1, 64),
+            scalar, scalar, scalar, scalar, scalar,
+            e["arg"](1, e["table_blocks"])).compile()
+
+    return compiled
+
+
 @pytest.mark.parametrize("name", sorted(CELLS))
-def test_engine_decode_rounds_compiles_with_paged_kernel(chip, name):
+def test_engine_decode_rounds_compiles_with_paged_kernel(cell_program, name):
     import re
 
-    from kubeflow_tpu.models.generate import decode_rounds
     from kubeflow_tpu.serving.engine import _plain_pool_platform
 
     widths, slots, max_len = CELLS[name]
-    e = _engine_shapes(chip, widths, slots, max_len)
+    e, compiled = cell_program(name, "decode_rounds")
     # What DecodeEngine decides from: the platform of the pool's device.
     assert _plain_pool_platform(e["state"]["cache_k"]) == "tpu"
-    compiled = decode_rounds.lower(
-        e["cfg"], e["params"], e["state"], e["decode"], 8,
-        e["arg"](slots, e["table_blocks"]), e["arg"](),
-        paged_kernel=True).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_decode_attention" in text
     # No float32 array of a slot's whole view, repeated over the group
@@ -225,4 +250,62 @@ def test_engine_decode_rounds_compiles_with_paged_kernel(chip, name):
     sizes = [int(np.prod([int(n) for n in dims.split(",")]))
              for dims in re.findall(r"f32\[([\d,]+)\]", text)]
     assert max(sizes) < view, max(sizes)
+    assert _fits(compiled)
+
+
+def _pool_moves(text, pool):
+    """Instructions of an HLO module that COPY the paged pool: a ``copy``,
+    ``copy-start`` / ``copy-done``, ``dynamic-slice`` or
+    ``dynamic-update-slice`` (or a fusion the compiler named for one)
+    whose result has the shape of one side of the pool or of one plane of
+    it.  A scatter into the pool and the loops' own tuples have that shape
+    too and move nothing."""
+    import re
+
+    whole = ",".join(str(n) for n in pool)
+    plane = ",".join(str(n) for n in pool[1:])
+    shaped = re.compile(
+        rf"%(\S+) = \(?\w+\[(?:{whole}|1,{plane}|{plane})\].*? ([\w-]+)\(")
+    moving = ("copy", "dynamic-slice", "dynamic-update-slice")
+    return [m.group(1) for m in map(shaped.search, text.splitlines())
+            if m and any(w in m.group(1) or m.group(2).startswith(w)
+                         for w in moving)]
+
+
+def test_pool_moves_finds_the_scans_slices_and_copies():
+    """The texts are the parent's (PR 28's traces and compiles)."""
+    pool = (24, 2560, 16, 8, 128)
+    whole, plane = "bf16[24,2560,16,8,128]{4,3,2,1,0}", "[2560,16,8,128]"
+    text = f"""
+  %copy.68 = {whole} copy(%gte.1)
+  %bitcast_dynamic-update-slice_fusion.7 = {whole} fusion(%a, %b), kind=kLoop
+  %dynamic-slice_bitcast_fusion.25 = bf16{plane}{{3,2,1,0}} fusion(%a, %i)
+  %copy-start.1 = ({whole}, {whole}, u32[]) copy-start(%p)
+  %slice.3 = bf16[1,2560,16,8,128]{{4,3,2,1,0}} dynamic-slice(%p, %i, %z)
+  ROOT %scatter.26 = {whole} scatter(%p, %i, %u)
+  %get-tuple-element.9 = {whole} get-tuple-element(%while.3), index=2
+  %copy.14 = bf16[24,2,2048,8,128]{{4,2,3,1,0}} copy(%wkv)
+"""
+    assert _pool_moves(text, pool) == [
+        "copy.68", "bitcast_dynamic-update-slice_fusion.7",
+        "dynamic-slice_bitcast_fusion.25", "copy-start.1", "slice.3"]
+
+
+@pytest.mark.parametrize("program", ["decode_rounds",
+                                     "prefill_chunk_into_slot"])
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_engine_programs_update_the_pool_in_place(cell_program, name,
+                                                  program):
+    """The layer scan carries the stacked pool: neither engine program
+    slices a plane out, restacks it or copies the pool, and the
+    temporaries of a call are smaller than ONE side of the pool (with the
+    pool as the scan's xs / ys they were larger than both: 5.2-5.8 GB)."""
+    e, compiled = cell_program(name, program)
+    pool = e["state"]["cache_k"]
+    assert _pool_moves(compiled.as_text(), pool.shape) == []
+    m = compiled.memory_analysis()
+    side = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    assert m.temp_size_in_bytes < side, (m.temp_size_in_bytes, side)
+    # Donated and aliased: the pool that comes in is the pool that goes out.
+    assert m.alias_size_in_bytes >= 2 * side
     assert _fits(compiled)
