@@ -50,7 +50,7 @@ import time
 
 ROOT = pathlib.Path(__file__).resolve().parent
 
-# The 188M LM at full width (bench.py's lm preset): nothing is cut.
+# The 188M LM at full width: nothing is cut.
 FULL = {
     "model": {"vocab_size": 32_000, "d_model": 1024, "n_layers": 12,
               "n_heads": 8, "n_kv_heads": 8, "d_ff": 2816, "head_dim": 128,
@@ -465,8 +465,7 @@ def phase_serve(run, export_dir):
         stats = json.loads(http_get(port, "/model/lm:stats"))["batcher"]
         programs = stats["compiled_programs"]
         check(programs["chunked_prefill"] == 1
-              and (programs["step"] == 1
-                   or programs.get("decode_rounds") == 1),
+              and programs["decode_rounds"] == 1,
               f"engine programs not compiled once each: {programs}")
         # No hidden fallback: on the chip every decode step goes through
         # ops/paged_attention.py; off it (the rehearsal) none does.

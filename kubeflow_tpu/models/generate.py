@@ -102,8 +102,9 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
     traced scalar) and hands the whole pool back;
     cache_len: number of valid cache positions before this call — a
     scalar (whole batch at one length, the generate() path) or a [b]
-    array (per-row lengths, the slot-based decode_step / verify_step
-    paths; each row writes its t new k/v columns starting at its OWN
+    array (per-row lengths, the slot-based decode_rounds / verify_step
+    paths, always with ``tables``; each row writes its t new k/v
+    columns starting at its OWN
     frontier and attends under its own causal mask via the per-row
     kv_offset — t is 1 at decode and k+1 at speculative verify);
     pad_amount: per-row [b] left-pad width (bucketed mixed-length
@@ -126,7 +127,7 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
     garbage they contribute is masked).
     paged_kernel (static; the serving engine sets it when its pool
     lives on a TPU): a step with ONE query position per row (t == 1:
-    decode_step, decode_rounds) over a plain-array pool gathers no
+    decode_rounds) over a plain-array pool gathers no
     view — ops/paged_attention.py is handed the stacked pool and the
     plane and reads each row's resident pages in place (a row whose
     write is parked attends nothing).
@@ -235,31 +236,6 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                     q, view_k, view_v, causal=True,
                     kv_offset=cache_len, kv_valid_start=pad_amount,
                 )
-    elif per_row:
-        # Slot-based decode/verify: t new tokens per row, scattered to
-        # each row's own columns [base, base + t).  mode="drop" makes
-        # an out-of-range column a no-op — that is how retired slots
-        # skip the write without a separate program, and how a verify
-        # window overhanging the cache end drops only its unreachable
-        # tail columns.
-        rows = jnp.arange(x.shape[0])[:, None]
-        base = cache_len if write_cols is None else write_cols
-        cols = base[:, None] + jnp.arange(t)[None, :]
-
-        def store(c, new):  # new: [b, t, hk, d]
-            if isinstance(c, QTensor):
-                vals, s = quantize_array(new, (-1,))
-                return QTensor(
-                    c.values.at[rows, cols].set(vals, mode="drop"),
-                    c.scale.at[rows, cols].set(s, mode="drop"),
-                    c.axes,
-                )
-            return c.at[rows, cols].set(
-                new.astype(c.dtype), mode="drop")
-
-        with jax.named_scope("kft.kv_write"):
-            ck = store(ck, k)
-            cv = store(cv, v)
     elif isinstance(ck, QTensor):
         def store(c, new):
             vals, s = quantize_array(new, (-1,))    # [b, t, hk, d]
@@ -355,7 +331,7 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
     """tokens [b, t] -> (logits [b, t, v], new cache).
 
     cache_len scalar: the whole batch sits at one length (generate()).
-    cache_len [b] array: per-row lengths (slot-based decode_step /
+    cache_len [b] array: per-row lengths (slot-based decode_rounds /
     verify_step) — each row ropes its t tokens at its own positions
     [len, len + t), writes its own cache columns (write_cols,
     defaulting to cache_len), and attends under its own causal
@@ -639,10 +615,12 @@ def generate(
 #                            the first chunk at claim time, which is
 #                            what makes reusing a deadline-expired
 #                            slot safe
-#   decode_step              ALL live slots advance one token, each at
-#                            its OWN length (per-row rope position,
-#                            per-row causal frontier, per-row block-
-#                            scatter through its table)
+#   decode_rounds            ALL live slots advance up to k tokens in
+#                            one dispatch, each at its OWN length
+#                            (per-row rope position, per-row causal
+#                            frontier, per-row block-scatter through
+#                            its table), stopping early once every
+#                            slot is done
 #   verify_step              speculative decoding: score k host-drafted
 #                            candidate tokens per slot in ONE forward
 #                            pass at each slot's frontier, accept the
@@ -658,8 +636,8 @@ def generate(
 # Static shapes throughout: slot count, chunk width, pool geometry,
 # draft width, and the per-slot table span are fixed at engine
 # construction, so the whole serving lifetime compiles at most THREE
-# programs (chunked prefill, step, verify — the third only when
-# speculation is enabled).  Retirement is a device-side `done` flag (a
+# programs (chunked prefill, decode rounds, verify — the third only
+# when speculation is enabled).  Retirement is a device-side `done` flag (a
 # slot that hits its stop length or EOS stops advancing and drops its
 # block writes), so freeing + reusing a slot needs no extra program —
 # the next admission's first chunk freezes and overwrites it.
@@ -762,11 +740,19 @@ def gather_kv_pages(state, ids):
 
 def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
                    tables: jax.Array, park, state, paged_kernel=False):
-    """One batched decode step over every slot: the shared body of
-    ``decode_step`` and ``decode_rounds``.  Returns (state, nxt [S])
-    where ``nxt`` is the sampled token per slot (0 for frozen slots).
+    """One batched decode step over every slot, ``decode_rounds``'s
+    loop body: one forward at t=1 in which each slot ropes at its own
+    length, attends under its own causal frontier over its pages of
+    the pool, and scatters its new k/v to its own (block, offset)
+    through ``tables`` ([S, max_blocks] int32, host-owned).  Retired
+    slots ride along with dropped writes and zero emissions, so the
+    static shape never changes.  Returns (state, nxt [S]) where
+    ``nxt`` is the sampled token per slot (0 for frozen slots).
     ``park`` is the column past the table span where retired slots
-    aim their dropped cache writes."""
+    aim their dropped cache writes.  ``paged_kernel`` (static, chosen
+    once by the engine from the platform its pool lives on):
+    attention reads the pool in place through ops/paged_attention.py
+    instead of the gathered view."""
     lengths, done = state["lengths"], state["done"]
     advance = ~done
     # Retired slots park their write past the table span; the
@@ -806,45 +792,6 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
 
 @partial(jax.jit, static_argnums=(0, 3, 4),
          static_argnames=("paged_kernel",), donate_argnums=(2,))
-def decode_step(cfg: TransformerConfig, params, state,
-                decode: DecodeConfig, steps: int, tables: jax.Array,
-                *, paged_kernel: bool = False):
-    """Advance every live slot; returns (state, sampled [steps, S]).
-
-    One batched forward at t=1 per step: each slot ropes at its own
-    length, attends under its own causal frontier (vector kv_offset)
-    over its block-table-gathered view of the pool, and scatters its
-    new k/v to its own (block, offset) through ``tables``
-    ([S, max_blocks] int32, host-owned).  Retired slots ride along
-    with dropped writes and zero emissions — the static shape never
-    changes, so this is the engine's single step program for its
-    whole lifetime.
-
-    ``steps`` (static) fuses that many steps into one program via scan:
-    per-call dispatch and runtime overhead amortize over k tokens at
-    the cost of k-token admission granularity (slots finishing mid-call
-    freeze via `done` on device, so at most k-1 slot-steps idle).  One
-    engine uses ONE value, so the three-program guarantee holds.
-
-    ``paged_kernel`` (static, chosen once by the engine from the
-    platform its pool lives on): attention reads the pool in place
-    through ops/paged_attention.py instead of the gathered view.
-    """
-    park = tables.shape[1] * _pool_block_tokens(state["cache_k"])
-
-    def one(state, _):
-        return _advance_slots(cfg, params, decode, tables, park, state,
-                              paged_kernel)
-
-    if steps == 1:  # skip the scan wrapper on the canonical path
-        state, toks = one(state, None)
-        return state, toks[None]
-    state, toks = jax.lax.scan(one, state, None, length=steps)
-    return state, toks
-
-
-@partial(jax.jit, static_argnums=(0, 3, 4),
-         static_argnames=("paged_kernel",), donate_argnums=(2,))
 def decode_rounds(cfg: TransformerConfig, params, state,
                   decode: DecodeConfig, k: int, tables: jax.Array,
                   max_steps: jax.Array, *, paged_kernel: bool = False):
@@ -870,10 +817,9 @@ def decode_rounds(cfg: TransformerConfig, params, state,
     executable instead of compiling one program per width.  Block
     tables ride in unchanged as the host-owned snapshot — the host
     must pre-cover every slot for the worst case (``k`` new positions)
-    before dispatch.  Per-step math is ``_advance_slots``, the same
-    body ``decode_step`` runs, so greedy tokens are bit-identical to
-    k single-step dispatches; under a mesh the loop body partitions
-    exactly like ``decode_step`` does.  ``paged_kernel``: as there.
+    before dispatch.  Per-step math is ``_advance_slots``, so greedy
+    tokens do not depend on how the steps are cut into rounds.
+    ``paged_kernel``: as there.
     """
     park = tables.shape[1] * _pool_block_tokens(state["cache_k"])
     slots = state["done"].shape[0]
@@ -913,9 +859,9 @@ def verify_step(cfg: TransformerConfig, params, state,
     rides along undrafted, a mixed batch).  The forward runs at t =
     k+1 — column 0 is the slot's pending ``last_token``, columns 1..k
     the draft — with per-row rope positions, per-row causal frontiers,
-    and per-row cache-column scatters, i.e. decode_step's math widened
+    and per-row cache-column scatters, i.e. a decode step's math widened
     to a k+1 window, so position j's logits are bit-for-bit the logits
-    the (j+1)-th sequential decode_step would have produced whenever
+    the (j+1)-th sequential decode step would have produced whenever
     the first j draft tokens match greedy decode.
 
     Acceptance is exact-match greedy (the engine only speculates at
@@ -936,7 +882,7 @@ def verify_step(cfg: TransformerConfig, params, state,
     never a scatter-erase (the engine additionally trims whole
     rejected-tail BLOCKS back to the pool host-side).  Retired slots
     park their writes out of range and emit 0 tokens, exactly like
-    decode_step.
+    a decode step.
     """
     lengths, done = state["lengths"], state["done"]
     park = tables.shape[1] * _pool_block_tokens(state["cache_k"])
@@ -1035,13 +981,13 @@ def prefill_chunk_into_slot(
     On the final chunk (start + chunk_w >= prompt_len, decided on
     device) the program samples the request's first token from the
     last real prompt position and arms the slot's scalars (lengths /
-    stop_len / last_token / done / keys — what decode_step needs to
+    stop_len / last_token / done / keys — what decode_rounds needs to
     advance the slot); intermediate chunks leave the slot frozen and
     park the scalar writes out of range.
 
     The unconditional ``done`` = True FREEZE is load-bearing: a slot
     freed by mid-generation deadline expiry still has ``done`` = False
-    on device, so without it an interleaved decode_step would keep
+    on device, so without it an interleaved decode round would keep
     advancing the dead occupant and scatter garbage through the NEW
     request's block table.  The engine therefore dispatches the first
     chunk of every admission at claim time, before any step program

@@ -1,7 +1,7 @@
 """Decode attention over the paged KV pool, read in place (Pallas TPU).
 
-The step programs of the serving engine (``models/generate.py``
-``decode_step`` / ``decode_rounds``) attend ONE query position per slot
+The decode program of the serving engine (``models/generate.py``
+``decode_rounds``) attends ONE query position per slot
 against a pool that every slot shares through its block table.  The
 pool is STACKED, ``[kv_planes, num_blocks, block_tokens, hkv, d]``: one
 plane per layer (per loop step and layer in a looped stack), and the

@@ -7,9 +7,9 @@ module applies HFTA's model-array trick (PAPERS.md, arXiv 2102.02344)
 to INFERENCE: every variant is a LoRA-style low-rank delta over the
 attention/MLP projections named by the PR 15 partition rules, and all
 variants live in ONE stacked ``[n_adapters, layers, ...]`` array
-resident beside the base params.  The step programs gather each slot's
-delta by a per-slot int32 index (``state["adapter_ids"]``, armed at
-prefill) — so requests for different variants ride ONE continuous
+resident beside the base params.  The engine's programs gather each
+slot's delta by a per-slot int32 index (``state["adapter_ids"]``, armed
+at prefill) — so requests for different variants ride ONE continuous
 batch and ONE SPMD executable, and ``compiled_programs()`` never grows
 a per-adapter entry.  Row 0 of the stack is the all-zero base delta:
 base traffic co-batches with tenant traffic at identical math.

@@ -21,8 +21,10 @@ over ONE persistent PAGED KV block pool shared by ``slots`` sequences:
     sheds typed ``Overloaded`` when the pool is exhausted instead of
     deadlocking (each admission reserves its worst-case page count up
     front; see serving/prefix_cache.py BlockManager);
-  - a dedicated step loop advances all live slots one token per
-    ``decode_step`` call;
+  - a dedicated loop advances all live slots in ROUNDS: one
+    ``decode_rounds`` call runs up to ``decode_rounds`` steps on the
+    device (the width adapts under that cap, see ``_round_width``) and
+    stops early once every slot is done;
   - new requests are admitted into free slots BETWEEN steps, and their
     prompts prefill in **static-width chunks scheduled between decode
     steps** under a per-step token budget (``prefill_chunk_tokens``) —
@@ -55,8 +57,8 @@ over ONE persistent PAGED KV block pool shared by ``slots`` sequences:
     and a round in which no slot drafts runs the plain decode
     program, so low-acceptance traffic never pays the verify window;
   - every shape is static, so the engine's whole lifetime compiles at
-    most THREE programs (chunked prefill, step, verify — the third
-    only when speculation is enabled; prefix reuse needs no copy
+    most THREE programs (chunked prefill, decode rounds, verify — the
+    third only when speculation is enabled; prefix reuse needs no copy
     program at all; a decode-tier engine that imports disaggregated
     KV handoffs adds a fourth, ``kv_import``, run once per imported
     request);
@@ -73,13 +75,12 @@ over ONE persistent PAGED KV block pool shared by ``slots`` sequences:
     scatters transferred pages into reserved blocks and resumes
     through the ordinary cached-prefix chunked-prefill path.
 
-The host loop reads sampled tokens with a small LAG (``sync_lag``
-steps): step N+lag is dispatched before step N's tokens are
-materialized, so host bookkeeping overlaps device compute instead of
-serializing on it.  Completion is detected deterministically from the
-per-request budget (and, when EOS is configured, from the lagged token
-stream — the device flag has already frozen the slot by then, so the
-lag costs at most ``sync_lag`` idle slot-steps).
+The host's work for the NEXT round (page covers, the block-table
+upload, drafting, spill) runs while the device computes the current
+one, and the round's tokens are read once, at its end.  Completion is
+detected deterministically from the per-request budget (and, when EOS
+is configured, from the round's tokens — the device flag has already
+frozen the slot by then).
 
 Interface-compatible with the batchers (submit/accepts/stats/close), so
 ModelServer.enable_batching wires it behind the REST and gRPC surfaces
@@ -157,8 +158,7 @@ HANDOFF_PAGES_HELP = \
     "handoff, by engine and direction (export/import)"
 FUSED_ROUNDS_TOTAL = "kft_engine_fused_rounds_total"
 FUSED_ROUNDS_HELP = \
-    "fused multi-step decode rounds dispatched (decode_rounds > 1), " \
-    "by engine"
+    "decode rounds dispatched, by engine"
 FUSED_WASTED_TOTAL = "kft_engine_fused_steps_wasted_total"
 FUSED_WASTED_HELP = \
     "fused-round slot-steps dispatched but not delivered (early-exit " \
@@ -233,7 +233,7 @@ _SPEC_RATE_MARGIN = 0.95
 _SPEC_PROBE_EVERY = 4
 _SPEC_RATE_ALPHA = 0.3
 
-# Fused decode rounds (decode_rounds > 1): shrink the adaptive round
+# Decode rounds: shrink the adaptive round
 # width when more than this fraction of a round's dispatched slot-steps
 # delivered nothing (early-exit waste: slots frozen at EOS/budget while
 # co-resident slots keep stepping), or when an admission is queued
@@ -401,13 +401,11 @@ class DecodeEngine:
         generate() path.
       max_len: cache columns per slot (default prefill_len +
         decode.max_new_tokens).
-      sync_lag: how many step calls the host may run ahead of token
-        materialization (0 = fully synchronous loop).
-      steps_per_call: decode steps fused into one step-program call
-        (models/generate.py decode_step's static ``steps``): per-call
-        dispatch overhead amortizes over k tokens, admission waits at
-        most k steps.  One engine uses one value, so the three-program
-        guarantee holds either way.
+      decode_rounds: the most decode steps one ``decode_rounds``
+        dispatch may run (the token buffer's static width, docs
+        §5.2e).  The width of a round adapts under it
+        (``_round_width``); admissions and expiries join between
+        rounds.  1 is the same program run one step a dispatch.
       admit_width: how many admissions may be MID-PREFILL concurrently
         — further queued requests wait even when slots are free, so a
         burst of long prompts cannot hoard every slot in a half-filled
@@ -448,10 +446,7 @@ class DecodeEngine:
         decode (0 disables).  Requires a greedy export (temperature
         0) — sampling exports silently fall back to plain decode,
         because drafting would perturb the per-request sample
-        streams.  Speculation forces a synchronous host loop
-        (sync_lag 0): the drafter reads each slot's materialized
-        history, and the k-token verify window amortizes dispatch
-        the way the read lag otherwise would.
+        streams.
       mesh: a ``jax.sharding.Mesh`` (serving/sharding.py build_mesh)
         to run tensor-parallel over: params and the paged KV block
         pool are placed with NamedShardings at construction (heads /
@@ -488,9 +483,7 @@ class DecodeEngine:
         slots: int = 8,
         prefill_len: int = 256,
         max_len: Optional[int] = None,
-        sync_lag: int = 2,
-        steps_per_call: int = 1,
-        decode_rounds: int = 1,
+        decode_rounds: int = 8,
         admit_width: int = 4,
         prefill_chunk_tokens: int = 64,
         kv_block_tokens: int = 16,
@@ -555,15 +548,6 @@ class DecodeEngine:
             raise ValueError(
                 f"max_len {self.max_len} exceeds model max_seq_len "
                 f"{cfg.max_seq_len}")
-        self.sync_lag = max(0, int(sync_lag))
-        self.steps_per_call = max(1, int(steps_per_call))
-        # Fused multi-step decode (docs §5.2e): > 1 replaces the
-        # per-step dispatch loop with ONE decode_rounds program call
-        # advancing every slot up to decode_rounds steps, draining
-        # synchronously at each round boundary (sync_lag applies only
-        # to the k=1 path — the round's overlap window supersedes the
-        # lagged read).  1 keeps the classic loop bit-for-bit and
-        # compiles no new program.
         self.decode_rounds = max(1, int(decode_rounds))
         self.admit_width = max(1, min(int(admit_width), slots))
         self.prefill_chunk_tokens = max(1, int(prefill_chunk_tokens))
@@ -602,12 +586,6 @@ class DecodeEngine:
                 "only", name, spec, decode.temperature)
             spec = 0
         self.speculative_tokens = spec
-        if spec:
-            # The drafter proposes from each slot's materialized
-            # history, so the loop must drain emissions every round;
-            # the k-token verify window is what amortizes dispatch
-            # instead of the read lag.
-            self.sync_lag = 0
         self._state = init_paged_state(cfg, slots, self.kv_pool_blocks,
                                        self.kv_block_tokens,
                                        decode.kv_cache_dtype)
@@ -621,14 +599,14 @@ class DecodeEngine:
             from kubeflow_tpu.serving import sharding
 
             self._state = sharding.shard_paged_state(self._state, mesh)
-        # Decided ONCE, from what the engine holds: decode_step and
-        # decode_rounds attend through ops/paged_attention.py when the
+        # Decided ONCE, from what the engine holds: decode_rounds
+        # attends through ops/paged_attention.py when the
         # pool is a plain array on a TPU, a page's rows fill whole
         # 128-lane tiles (the chip's compiler refuses the kernel's page
         # copies otherwise) and no mesh shards the kv heads (a
         # shard_map over that axis is the sound form there; until it
-        # exists a mesh keeps the gathered view).  Static for the two
-        # programs; stats()["decode_kernel_steps"] over "steps" is the
+        # exists a mesh keeps the gathered view).  Static for the
+        # program; stats()["decode_kernel_steps"] over "steps" is the
         # share of decode steps the kernel served.
         self._paged_kernel = (
             mesh is None and cfg.head_dim % 128 == 0
@@ -652,22 +630,21 @@ class DecodeEngine:
                                  host_blocks=self.host_spill_blocks)
         self._evict_rec_seen = 0
         self._evict_blk_seen = 0
-        # AOT executables, built lazily by the loop thread: the step
-        # loop calls its programs thousands of times per second, and
+        # AOT executables, built lazily by the loop thread: the loop
+        # calls its programs hundreds of times per second, and
         # the jitted wrapper re-hashes the whole params pytree
         # signature per call (~0.4 ms on the smoke config — comparable
         # to the step itself).  lower().compile() once, then call the
         # executable.  This is also the three-program guarantee made
-        # literal: these three fields ARE the engine's compiled
-        # programs.
+        # literal: these fields and _rounds_exec below ARE the
+        # engine's compiled programs.
         self._chunk_exec = None
-        self._step_exec = None
         self._verify_exec = None
         # Disaggregated-serving KV import program (kv_import): built
         # the first time a handoff payload arrives; runs once per
         # imported request, never in the step loop.
         self._import_exec = None
-        # Fused decode rounds (decode_rounds > 1): the while_loop
+        # Decode rounds: the while_loop
         # executable, the double-buffered device-side block-table
         # snapshot (re-uploaded in the overlap window; any host-table
         # mutation marks it dirty), the table sharding the SPMD
@@ -713,8 +690,10 @@ class DecodeEngine:
         # (FIFO — the oldest admission finishes first, best TTFT).
         # Loop-thread-owned; the admission pop reads only its length.
         self._prefilling: List[dict] = []
-        # (tokens_array, [(slot, entry), ...]) emissions not yet read.
+        # (tokens_array, [(slot, entry), ...], counts) emissions not
+        # yet delivered, and the entries the current round advances.
         self._pending: List[tuple] = []
+        self._advancing: List[dict] = []
         # Counters (mutated by the loop thread, snapshotted under the
         # lock — the same locked-snapshot discipline MicroBatcher uses).
         self._counters = {
@@ -1173,28 +1152,24 @@ class DecodeEngine:
 
     def compiled_programs(self) -> Dict[str, int]:
         """How many device programs this engine has compiled — by
-        construction at most one chunked-prefill, one step, and one
-        speculative-verify executable (the build sites are
+        construction at most one chunked-prefill, one decode-rounds
+        and one speculative-verify executable (the build sites are
         None-guarded), so a healthy engine reports at most
-        {"chunked_prefill": 1, "step": 1, "verify": 1} for its whole
-        lifetime ("verify" stays 0 unless speculation is enabled AND a
+        {"chunked_prefill": 1, "decode_rounds": 1, "verify": 1} for
+        its whole lifetime (ONE ``decode_rounds`` executable serves
+        every round width — the per-round step cap is a traced
+        operand; "verify" stays 0 unless speculation is enabled AND a
         slot actually drafted).  There is no prefix-copy program:
         shared-prefix reuse is host-side block-table aliasing.  A
         decode-tier engine that has imported a disaggregated KV
         handoff additionally reports ``kv_import`` (once compiled) —
         the one-per-request page-scatter program; engines that never
-        see a handoff keep the exact three-key shape.  An engine built
-        with ``decode_rounds > 1`` reports ``decode_rounds`` once the
-        fused while_loop program compiles (ONE executable serves every
-        adaptive width — the per-round step cap is a traced operand);
-        the k=1 path never compiles it."""
+        see a handoff keep the exact three-key shape."""
         out = {"chunked_prefill": int(self._chunk_exec is not None),
-               "step": int(self._step_exec is not None),
+               "decode_rounds": int(self._rounds_exec is not None),
                "verify": int(self._verify_exec is not None)}
         if self._import_exec is not None:
             out["kv_import"] = 1
-        if self._rounds_exec is not None:
-            out["decode_rounds"] = 1
         return out
 
     def adapter_info(self) -> List[Dict[str, Any]]:
@@ -1344,13 +1319,12 @@ class DecodeEngine:
             # early-exit slot-steps that delivered nothing, and the
             # realized steps-per-round distribution — how much of the
             # configured width the device actually ran before every
-            # slot froze.  decode_rounds == 1 is the classic per-step
-            # dispatch loop (all three stay at zero).
+            # slot froze.
             "decode_rounds": self.decode_rounds,
             "fused_rounds": c["fused_rounds"],
             "fused_steps_wasted": c["fused_steps_wasted"],
             # Steps whose program held the paged attention kernel
-            # (decode_step / decode_rounds on a TPU pool): over "steps"
+            # (decode_rounds on a TPU pool): over "steps"
             # it is the share the kernel served, 0 off the chip.
             "decode_kernel_steps": c["decode_kernel_steps"],
             "steps_per_round_p50": pct_raw(rounds, 0.50),
@@ -1530,12 +1504,15 @@ class DecodeEngine:
         live slot table (caller fails them outside the lock).
 
         In-flight expiry rides the deterministic-retirement path: the
-        slot is freed NOW — the next admission's prefix-copy program
-        freezes it on device, which is the device-side abort — and the
-        request's lagged emissions still in _pending are dropped by
-        _drain_one's event-set check, exactly like a normally-retired
-        slot's.  No other slot's state is touched, so co-resident
-        generations are unaffected."""
+        slot is freed NOW — the next admission's first prefill chunk
+        freezes it on device, which is the device-side abort — and a
+        first token of the request still in _pending is dropped by
+        _drain_one's event-set check.  No other slot's state is
+        touched, so co-resident generations are unaffected.  The
+        sweep runs between rounds, so expiry granularity is the round
+        (``_round_width`` keeps a round under the tightest deadline):
+        tokens that complete a request before the next sweep are
+        delivered."""
         pnow = faults.monotonic()
         expired: List[dict] = []
         live = []
@@ -1560,31 +1537,6 @@ class DecodeEngine:
                 # its freed pages can be reallocated immediately.
                 self._tables[i][:] = self.kv_pool_blocks
                 self._tables_dirty = True
-                self._release_entry_locked(entry)
-                self._counters["in_flight"] -= 1
-                expired.append(entry)
-        # Deterministically-retired requests live in NEITHER the queue
-        # nor the slot table while their lagged emissions sit in
-        # _pending — a request is in_flight until delivery, so its
-        # deadline is enforced on this tail too (under wedged steps the
-        # lag is unbounded; the client must get its 504, not a late
-        # 200).  A snapshot entry still slot-resident cannot reach the
-        # append: the slot scan above already moved every expired slot
-        # entry into `expired`, and the identity dedup skips those (and
-        # entries recurring across snapshots).
-        for _, snapshot, _ in self._pending:
-            for _, entry in snapshot:
-                if entry["event"].is_set():
-                    continue
-                d = entry["deadline"]
-                if d is None or d > pnow:
-                    continue
-                if any(entry is e for e in expired):
-                    continue
-                # Deterministically retired: the slot (and possibly
-                # its table row) already belongs to a successor, but
-                # the entry still owns its physical pages until
-                # delivery — release them now with the failure.
                 self._release_entry_locked(entry)
                 self._counters["in_flight"] -= 1
                 expired.append(entry)
@@ -2135,7 +2087,7 @@ class DecodeEngine:
         accounting and the FIRST prefill chunk, dispatched at claim
         time: its unconditional device-side ``done`` freeze is what
         makes reusing a deadline-expired slot safe — without it an
-        interleaved decode_step would advance the dead occupant and
+        interleaved decode round would advance the dead occupant and
         scatter through the NEW request's table."""
         prompt = entry["tokens"][0]
         true_len = int(prompt.shape[0])
@@ -2317,26 +2269,20 @@ class DecodeEngine:
         Counter merges are batched: one locked update per drained call,
         not per token.
 
-        Three emission shapes ride the one stream: a prefill's [1]
-        first token (counts None, col 0), a decode call's
-        [steps, slots] grid (counts None — every live slot emitted one
-        token per fused step), and a slot-major grid with a per-slot
-        ``counts`` vector for the VARIABLE-count programs — a verify
-        call's [slots, k+1] accepted prefixes plus free token, and a
-        fused decode round's [slots, k] per-step emissions (both cut
-        at EOS/budget on device, so row s carries counts[s] real
-        tokens)."""
+        Two emission shapes ride the one stream: a prefill's [1]
+        first token (counts None, col 0), and a slot-major grid with
+        a per-slot ``counts`` vector — a verify call's [slots, k+1]
+        accepted prefixes plus free token, and a decode round's
+        [slots, k] per-step emissions (both cut at EOS/budget on
+        device, so row s carries counts[s] real tokens)."""
         arr, snapshot, counts = self._pending.pop(0)
         if isinstance(arr, np.ndarray):
             host = arr  # the round already waited for it
         else:
-            # The blocking read of the device's tokens (the unfused
-            # step, a prefill's first token): the host waits on the
-            # chip here, not in the drain around it.
+            # The blocking read of a prefill's first token: the host
+            # waits on the chip here, not in the drain around it.
             with self._phase("round_wait"):
                 host = np.asarray(arr)
-                if counts is not None:
-                    counts = np.asarray(counts)
         emitted = 0
         finished = 0
         finished_entries: List[dict] = []
@@ -2344,10 +2290,8 @@ class DecodeEngine:
         span_s = span_hit_s = 0.0
         firsts = firsts_hit = 0
         for col, entry in snapshot:
-            if counts is not None:       # verify: row per slot
+            if counts is not None:       # a round: row per slot
                 toks = host[col, :int(counts[col])]
-            elif host.ndim >= 2:         # decode: [steps, slots]
-                toks = host[:, col]
             else:                        # prefill first token: [1]
                 toks = host
             for tok in toks:
@@ -2374,8 +2318,8 @@ class DecodeEngine:
                     self._eos and tok == self.decode.eos_token)
                 if complete:
                     # The device `done` flag froze this slot at the
-                    # same step, so freeing it here (possibly sync_lag
-                    # calls late on the EOS path) never races the cache.
+                    # same step, so freeing it here never races the
+                    # cache.
                     if self._slot_req[entry["slot"]] is entry:
                         # Slot table is loop-thread-owned: only _run/
                         # _drain_one rebind entries; stats() reads a
@@ -2418,14 +2362,14 @@ class DecodeEngine:
             (1 - _SPEC_RATE_ALPHA) * ema + _SPEC_RATE_ALPHA * rate)
 
     def _record_step_timing(self, t0, end, norm, steps, occupancy,
-                            extra=None, delivered=None, program="step",
+                            extra=None, delivered=None, program="decode",
                             round_steps=None):
-        """Shared per-round accounting for ALL step programs (decode,
-        fused decode rounds, and verify): busy time, step/occupancy
+        """Shared per-round accounting for BOTH step programs (decode
+        rounds and verify): busy time, step/occupancy
         counters, the per-token latency and inter-token-gap
         reservoirs, the step histogram, AND the throughput-gate EMAs —
-        one discipline, so the percentiles the bench and e2e assert on
-        mean the same thing on every path and the speculation gate
+        one discipline, so the percentiles the benchmark and e2e read
+        mean the same thing on either path and the speculation gate
         compares decode and verify in the same currency.  ``norm`` is
         tokens-per-slot-stream this call (fused steps for decode, mean
         emissions of advancing slots for verify); ``extra`` merges
@@ -2448,7 +2392,7 @@ class DecodeEngine:
             else ((1 - _ROUND_PACE_ALPHA) * self._step_pace_ema
                   + _ROUND_PACE_ALPHA * per_tok)
         kernel_steps = steps if (
-            self._paged_kernel and program == "step") else 0
+            self._paged_kernel and program == "decode") else 0
         with self._lock:
             self._counters["steps"] += steps
             self._counters["decode_kernel_steps"] += kernel_steps
@@ -2512,8 +2456,7 @@ class DecodeEngine:
         the next dispatch re-uploads before launching.  Under a mesh
         whose executable is not compiled yet the table placement is
         unknown: keep passing the host array — the runtime then
-        transfers per dispatch, exactly as the unfused ``decode_step``
-        path always has."""
+        transfers per dispatch."""
         import jax
 
         with self._lock:
@@ -2540,7 +2483,7 @@ class DecodeEngine:
         it (the next fused round simply runs undrafted).  Either way a
         verify dispatch never waits on a drafting scan.  Scan-stride
         backoff and the per-slot width cooldown tick here — this IS
-        the scan site in fused mode, mirroring ``_collect_drafts``."""
+        the drafting scan's one site."""
         k = self.speculative_tokens
         self._spec_tick += 1
         if self._spec_tick < self._spec_stride:
@@ -2554,10 +2497,18 @@ class DecodeEngine:
                 # (or already resolved): it will not verify next round.
                 continue
             if entry["spec_k"] <= 0:
+                # Backed off: tick the cooldown, then re-probe at a
+                # width that can clear the draft-mass floor on its own
+                # (a width-1 probe from a lone drafting slot would be
+                # mass-gated forever), so a tail that TURNS repetitive
+                # recovers.
                 entry["spec_cool"] -= 1
                 if entry["spec_cool"] <= 0:
                     entry["spec_k"] = max(1, k // 2)
                 continue
+            # Never draft past the budget: the final budgeted token is
+            # the verify call's free token, so a request with <= 1
+            # token of room gains nothing from drafting.
             room = entry["new"] - len(entry["emitted"]) - 1
             if room <= 0:
                 continue
@@ -2575,8 +2526,8 @@ class DecodeEngine:
 
     def _harvest_ahead_drafts(self):
         """Boundary-side half of overlapped drafting (see
-        ``_draft_ahead``): rebuild ``_collect_drafts``'s
-        (snapshot, draft, draft_len) contract from the ahead-proposals
+        ``_draft_ahead``): build ``_verify_round``'s
+        (snapshot, draft, draft_len) arguments from the ahead-proposals
         whose heads matched the tokens the fused round actually
         delivered, clipped to the verify window at the NEW frontier.
         Returns None when nothing survived — the loop then runs a
@@ -2617,7 +2568,7 @@ class DecodeEngine:
         return snapshot, draft, draft_len
 
     def _fused_round(self, live: int) -> None:
-        """One fused decode round (decode_rounds > 1): a single
+        """One decode round: a single
         ``decode_rounds`` dispatch advances every live slot up to
         ``width`` steps with device-side early exit the moment all are
         done, and the host work for the NEXT round — cover growth, the
@@ -2626,10 +2577,9 @@ class DecodeEngine:
         synchronously at the round boundary: admissions and expiries
         join between rounds, and deadline expiry granularity becomes
         the round (``_round_width`` clamps the width under the
-        tightest live deadline).  Greedy tokens are bit-identical to
-        the k=1 loop: the device math is ``decode_step``'s body and
-        slot math is per-row independent, so scheduling granularity
-        cannot change any slot's token stream."""
+        tightest live deadline).  Greedy tokens do not depend on the
+        width: slot math is per-row independent, so scheduling
+        granularity cannot change any slot's token stream."""
         from kubeflow_tpu.models.generate import decode_rounds
 
         kmax = self.decode_rounds
@@ -2664,9 +2614,10 @@ class DecodeEngine:
                 self._refresh_tables_dev()
             tables = (self._tables_dev if self._tables_dev is not None
                       else self._tables)
-            # Chaos hook: the same site as the unfused step — injected
-            # stalls/deaths hit fused rounds identically (deadlines
-            # expire mid-round, _abort resolves waiters).
+            # Chaos hook: sleep = slow/wedged round (deadlines expire
+            # mid-round); raise = device death (_abort resolves every
+            # waiter).  Outside the timed window so the injected stall
+            # does not masquerade as device latency.
             faults.fire("engine.step")
             tok_before = self._counters["tokens"]
         t0 = time.perf_counter()
@@ -2752,55 +2703,6 @@ class DecodeEngine:
                 self._fused_wasted_ctr.inc(wasted,
                                            engine=self._metric_name)
 
-    def _collect_drafts(self):
-        """Host-side n-gram drafting pass over the live slots.
-
-        Returns (snapshot, draft [slots, k], draft_len [slots]) when at
-        least one slot proposed tokens, else None — the loop then runs
-        the plain decode program, so traffic the drafter cannot
-        predict (and slots whose adaptive width backed off to zero)
-        never pays the k+1-wide verify window.  Histories are exact:
-        speculation forces sync_lag 0, so every emitted token is
-        already materialized when the drafter reads it."""
-        k = self.speculative_tokens
-        # Draft buffers allocate lazily: most rounds on unrepetitive
-        # traffic propose nothing, and this runs once per decode round
-        # — its no-draft path must cost microseconds.
-        draft = draft_len = None
-        snapshot: List[tuple] = []
-        for i, entry in enumerate(self._slot_req):
-            if entry is None or entry["prefilling"]:
-                continue
-            snapshot.append((i, entry))
-            if entry["spec_k"] <= 0:
-                # Backed off: tick the cooldown, then re-probe at a
-                # width that can clear the draft-mass floor on its own
-                # (a width-1 probe from a lone drafting slot would be
-                # mass-gated forever), so a tail that TURNS repetitive
-                # recovers.
-                entry["spec_cool"] -= 1
-                if entry["spec_cool"] <= 0:
-                    entry["spec_k"] = max(1, k // 2)
-                continue
-            # Never draft past the budget: the final budgeted token is
-            # the verify call's free token, so a request with <= 1
-            # token of room gains nothing from drafting.
-            room = entry["new"] - len(entry["emitted"]) - 1
-            width = min(k, entry["spec_k"], room)
-            if width <= 0:
-                continue
-            proposal = _ngram_propose(
-                entry["hist"][:entry["hist_len"]], width)
-            if proposal.size:
-                if draft is None:
-                    draft = np.zeros((self.slots, k), np.int32)
-                    draft_len = np.zeros((self.slots,), np.int32)
-                draft[i, :proposal.size] = proposal
-                draft_len[i] = proposal.size
-        if draft is None:
-            return None
-        return snapshot, draft, draft_len
-
     def _spec_gates_pass(self, draft_len) -> bool:
         """Should this round's proposals actually dispatch verify?
 
@@ -2879,7 +2781,7 @@ class DecodeEngine:
             del toks, counts  # freed here, inside a phase
         with self._phase("drain"):
             self._pending.append((toks_np, snapshot, counts_np))
-            while len(self._pending) > self.sync_lag:  # sync: drains all
+            while self._pending:
                 self._drain_one()
         end = time.perf_counter()
         with self._phase("account"):
@@ -2954,8 +2856,8 @@ class DecodeEngine:
     def _run(self) -> None:
         """The loop thread.  Every statement of an iteration lies in
         one ``_phase`` (admit with wait_work inside it, housekeeping,
-        prefill_dispatch, round_prepare, then one of the three step
-        paths' round_dispatch / overlap / round_wait / drain, account),
+        prefill_dispatch, round_prepare, then the decode or the verify
+        round's round_dispatch / overlap / round_wait / drain, account),
         so the ``loop_*_s`` sums tile the thread's wall time and every
         idle gap of the device falls into a named phase."""
         try:
@@ -3094,16 +2996,17 @@ class DecodeEngine:
         with self._phase("round_prepare"):
             self._set_occ_gauge(
                 sum(r is not None for r in self._slot_req))
-            live = sum(1 for r in self._slot_req
-                       if r is not None and not r["prefilling"])
-            drafts = self._drafts_for_round(live, admissions) \
+            # Kept on the engine for _abort: _fused_round retires a
+            # slot at dispatch, before the round's tokens are read.
+            self._advancing = [r for r in self._slot_req
+                               if r is not None and not r["prefilling"]]
+            live = len(self._advancing)
+            drafts = self._drafts_for_round(admissions) \
                 if live and self.speculative_tokens else None
         if drafts is not None:
             self._verify_round(*drafts, live)
-        elif live and self.decode_rounds > 1:
-            self._fused_round(live)
         elif live:
-            self._step_round(live)
+            self._fused_round(live)
         else:
             with self._phase("drain"):
                 self._last_step_end = None
@@ -3123,36 +3026,18 @@ class DecodeEngine:
             self._push_loop_seconds()
         return True
 
-    def _drafts_for_round(self, live: int, admissions):
+    def _drafts_for_round(self, admissions):
         """Speculation's verdict for this round: (snapshot, draft,
-        draft_len) when a verify call should replace the decode step,
+        draft_len) when a verify call should replace the decode round,
         else None (the plain decode program runs — the adaptive
         backoff's no-regression guarantee for low-acceptance
-        traffic)."""
-        reseed = any(e.get("spec_seed") for e, _ in admissions)
-        if self.decode_rounds > 1:
-            # Fused mode: the drafting scan already ran in the PREVIOUS
-            # round's overlap window (_draft_ahead owns the stride
-            # backoff there); harvest the proposals that survived the
-            # in-flight round and dispatch verify with no drafting
-            # stall on the critical path.  Nothing harvested => plain
-            # fused round, which re-drafts while it computes.
-            if reseed:
-                self._spec_stride = 1
-                self._spec_tick = self._spec_stride
-                self._spec_probe = _SPEC_PROBE_EVERY
-            drafts = self._harvest_ahead_drafts()
-            if drafts is not None and self._spec_gates_pass(drafts[2]):
-                return drafts
-            return None
-        # Draft host-side; when at least one slot proposed, one verify
-        # call replaces this round's decode step (undrafted slots ride
-        # along at draft_len 0 and still net their one token).  No
-        # drafts => the plain decode program, and the scan stride
-        # stretches so persistent unrepetitive traffic stops paying
-        # even the scan.
-        self._spec_tick += 1
-        if reseed:
+        traffic).  The drafting scan already ran in the PREVIOUS
+        round's overlap window (_draft_ahead owns the stride backoff
+        there); harvest the proposals that survived the in-flight
+        round and dispatch verify with no drafting stall on the
+        critical path.  Nothing harvested => plain decode round,
+        which re-drafts while it computes."""
+        if any(e.get("spec_seed") for e, _ in admissions):
             # A draftable prompt arrived: scan next round and let the
             # first drafted round probe even if earlier traffic
             # measured speculation unprofitable — a new request is a
@@ -3160,102 +3045,10 @@ class DecodeEngine:
             self._spec_stride = 1
             self._spec_tick = self._spec_stride
             self._spec_probe = _SPEC_PROBE_EVERY
-        if self._spec_tick < self._spec_stride:
-            return None
-        self._spec_tick = 0
-        drafts = self._collect_drafts()
-        if drafts is None:
-            # Truly EMPTY scan (nothing proposed): stretch the scan
-            # period.  Gate-blocked rounds below do NOT — proposals
-            # exist, so the scan stays productive and the probe
-            # cadence stays honest.
-            self._spec_stride = min(self._spec_stride * 2,
-                                    _SPEC_SCAN_STRIDE_MAX)
-            return None
-        self._spec_stride = 1
-        return drafts if self._spec_gates_pass(drafts[2]) else None
-
-    def _step_round(self, live: int) -> None:
-        """One unfused decode call (decode_rounds == 1): every live
-        slot advances ``steps_per_call`` tokens; the host may run
-        ``sync_lag`` calls ahead of reading them."""
-        from kubeflow_tpu.models.generate import decode_step
-
-        k = self.steps_per_call
-        with self._phase("round_prepare"):
-            # Cover every advancing slot's next k write positions with
-            # pages from its admission reservation BEFORE dispatch (the
-            # reservation guarantees them, so this can never block);
-            # slots already done on device write nothing, and the cover
-            # cap at res_blocks bounds what an EOS-lagged slot can take
-            # to pages it had reserved anyway.
-            for r in self._slot_req:
-                if r is None or r["prefilling"]:
-                    continue
-                self._ensure_cover(
-                    r, r["tokens"].shape[1] + r["scheduled"] + k - 1)
-            # Build (one-time) OUTSIDE the timed window: the first
-            # per-token latency sample must not carry seconds of XLA
-            # compile into the p50/p95 stats and the step histogram.
-            if self._step_exec is None:
-                self._step_exec = self._aot(
-                    decode_step, self.cfg, self.params, self._state,
-                    self.decode, k, self._tables,
-                    paged_kernel=self._paged_kernel)
-            # Chaos hook: sleep = slow/wedged step (deadlines expire
-            # mid-generation); raise = device death.  Outside the timed
-            # window so the injected stall does not masquerade as
-            # device latency in the step histogram.
-            faults.fire("engine.step")
-            # Counter read is loop-thread-local (the sync drain below
-            # merges into it on this same thread): the delta across the
-            # drain is the tokens this round actually DELIVERED —
-            # post-EOS/post-budget fused steps emit nothing, so live*k
-            # would overstate the decode rate and the throughput gate
-            # would suppress profitable speculation.
-            tok_before = (self._counters["tokens"]
-                          if self.speculative_tokens else 0)
-        t0 = time.perf_counter()
-        with self._phase("round_dispatch", width=k, live=live):
-            # A COPY of the host tables: the call returns at enqueue,
-            # and on a backend that reads a numpy argument in place
-            # (XLA:CPU) the next admission's row reset would otherwise
-            # reach a step that has not run yet — the retired slot's
-            # last token then attends through a parked table.
-            self._state, sampled = self._step_exec(
-                self.params, self._state, self._tables.copy())
-        with self._phase("overlap"):
-            self._pending.append((sampled, [
-                (i, r) for i, r in enumerate(self._slot_req)
-                if r is not None and not r["prefilling"]], None))
-            # Deterministic retirement: with no EOS in play a request's
-            # completion step is known at dispatch — free the slot NOW
-            # so the next admission overlaps the lagged read instead of
-            # waiting for it.  The request stays visible in in_flight
-            # until its lagged emission is delivered.
-            for i, r in enumerate(self._slot_req):
-                if r is None or r["prefilling"]:
-                    continue
-                r["scheduled"] = min(r["new"], r["scheduled"] + k)
-                if not self._eos and r["scheduled"] >= r["new"]:
-                    # Loop-thread-owned (see _drain_one).
-                    # kft: allow=lock-guard
-                    self._slot_req[i] = None
-        with self._phase("drain"):
-            while len(self._pending) > self.sync_lag:
-                self._drain_one()
-        end = time.perf_counter()
-        with self._phase("account"):
-            # Per-call latency and gap normalized by fused steps: what
-            # a client streaming tokens would see between tokens,
-            # including interleaved admission/prefill work.  The
-            # delivered-token delta feeds the speculation throughput
-            # gate its decode-side comparison rate (same currency as
-            # the verify side's counts sum).
-            self._record_step_timing(
-                t0, end, k, steps=k, occupancy=live * k,
-                delivered=(self._counters["tokens"] - tok_before
-                           if self.speculative_tokens else None))
+        drafts = self._harvest_ahead_drafts()
+        if drafts is not None and self._spec_gates_pass(drafts[2]):
+            return drafts
+        return None
 
     def _fail_queue(self, exc: Exception) -> None:
         with self._lock:
@@ -3275,10 +3068,10 @@ class DecodeEngine:
             RuntimeError(f"engine loop died: {exc!r}")
         self._fail_queue(err)
         # Fail live slots AND requests whose slots were already
-        # deterministically retired but whose lagged emissions still sit
-        # in _pending — those entries are in neither the queue nor the
-        # slot table, and clearing _pending without resolving them would
-        # leave their clients parked in submit() forever.
+        # deterministically retired at the dispatch of the round that
+        # died — those entries are in neither the queue nor the slot
+        # table, and leaving them unresolved would park their clients
+        # in submit() forever.
         for i, entry in enumerate(self._slot_req):
             if entry is not None and not entry["event"].is_set():
                 self._unpin_adapter(entry)
@@ -3288,12 +3081,14 @@ class DecodeEngine:
             # exists (see _drain_one).
             # kft: allow=lock-guard
             self._slot_req[i] = None
-        for _, snapshot, _ in self._pending:
-            for _, entry in snapshot:
-                if not entry["event"].is_set():
-                    self._unpin_adapter(entry)
-                    entry["err"] = err
-                    entry["event"].set()
+        for entry in self._advancing + [
+                e for _, snapshot, _ in self._pending
+                for _, e in snapshot]:
+            if not entry["event"].is_set():
+                self._unpin_adapter(entry)
+                entry["err"] = err
+                entry["event"].set()
+        self._advancing = []
         self._pending.clear()
         self._prefilling.clear()
         self._set_occ_gauge(0)

@@ -30,10 +30,8 @@ def batcher_factory(*, micro_batch_size: int, batch_timeout_s: float,
                     lm_engine: bool = True,
                     lm_engine_slots: int = 8,
                     lm_engine_prefill_len: int = 0,
-                    lm_engine_sync_lag: int = 2,
-                    lm_engine_steps_per_call: int = 1,
                     lm_engine_admit_width: int = 4,
-                    decode_rounds: int = 1,
+                    decode_rounds: int = 8,
                     prefill_chunk_tokens: int = 64,
                     kv_block_tokens: int = 16,
                     kv_pool_blocks: int = 0,
@@ -119,8 +117,6 @@ def batcher_factory(*, micro_batch_size: int, batch_timeout_s: float,
                 return DecodeEngine(
                     spec["cfg"], spec["params"], spec["decode"],
                     slots=lm_engine_slots, prefill_len=prefill,
-                    sync_lag=lm_engine_sync_lag,
-                    steps_per_call=lm_engine_steps_per_call,
                     decode_rounds=decode_rounds,
                     admit_width=lm_engine_admit_width,
                     prefill_chunk_tokens=prefill_chunk_tokens,
@@ -210,25 +206,15 @@ def main(argv=None) -> int:
                          "the persistent KV cache is sized by it — set "
                          "it near your real prompt lengths on long-"
                          "context models")
-    ap.add_argument("--lm_engine_sync_lag", type=int, default=2,
-                    help="DecodeEngine host-read lag in steps (host "
-                         "dispatches ahead of token materialization; "
-                         "0 = synchronous loop)")
-    ap.add_argument("--lm_engine_steps_per_call", type=int, default=1,
-                    help="DecodeEngine decode steps fused per step-"
-                         "program call: amortizes per-dispatch overhead "
-                         "k-fold at k-step admission granularity")
     ap.add_argument("--decode_rounds", type=int, default=8,
-                    help="DecodeEngine fused decode rounds: up to k "
+                    help="DecodeEngine decode rounds: up to k "
                          "steps run device-resident per dispatch in a "
                          "while_loop with early exit when every slot "
                          "finishes, host uploads double-buffered "
                          "behind device compute (docs §5.2e).  The "
                          "width adapts between 1 and k on early-exit "
                          "waste and queued admissions, and is clamped "
-                         "under the tightest live deadline; 1 restores "
-                         "the classic per-step dispatch loop "
-                         "bit-for-bit")
+                         "under the tightest live deadline")
     ap.add_argument("--lm_engine_admit_width", type=int, default=4,
                     help="DecodeEngine concurrent mid-prefill "
                          "admissions: further queued requests wait "
@@ -395,8 +381,6 @@ def main(argv=None) -> int:
                 lm_engine=not args.lm_static_batcher,
                 lm_engine_slots=args.lm_engine_slots,
                 lm_engine_prefill_len=args.lm_engine_prefill_len,
-                lm_engine_sync_lag=args.lm_engine_sync_lag,
-                lm_engine_steps_per_call=args.lm_engine_steps_per_call,
                 lm_engine_admit_width=args.lm_engine_admit_width,
                 decode_rounds=args.decode_rounds,
                 prefill_chunk_tokens=args.prefill_chunk_tokens,

@@ -1429,7 +1429,7 @@ class BucketedLMBatcher:
     Promotion is BOUNDED (VERDICT r4 item 7): unbounded promotion is a
     cliff on a wide length spread — a 128-token prompt co-batched with
     a 4096-token one pays the 4096 bucket's KV span on every decode
-    step (measured on-chip: see bench.py's promotion-cost probe).
+    step.
     ``max_promotion_factor`` partitions the buckets into bands whose
     largest/smallest ratio stays <= the factor; only requests in the
     same band share a queue, so a request's worst-case padded bucket is

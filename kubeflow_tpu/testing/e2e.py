@@ -270,8 +270,7 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
             rebuild(4)
             # Pick burst prompts the DRAFTER itself would succeed on,
             # by simulating it host-side against the reference greedy
-            # continuations (the same selection bench.py's
-            # speculation probe uses): the spec_accepted assert below
+            # continuations: the spec_accepted assert below
             # must hold by construction, independent of the measured-
             # throughput gate's scheduling-sensitive timing on a
             # loaded box.
@@ -341,11 +340,11 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
             assert 0 < stats["spec_acceptance_rate"] <= 1
             # The three-program guarantee, end to end over :stats —
             # verify exists exactly once; a purely-drafted burst may
-            # never need the plain step program, so it is 0 or 1.
+            # never need the plain decode program, so it is 0 or 1.
             # There is no copy_prefix key: prefix reuse is host-side
             # block-table aliasing, not a device program.
             programs = stats["compiled_programs"]
-            assert set(programs) == {"chunked_prefill", "step",
+            assert set(programs) == {"chunked_prefill", "decode_rounds",
                                      "verify"}, programs
             assert programs["verify"] == 1, programs
             assert programs["chunked_prefill"] == 1, programs
@@ -381,7 +380,7 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
             assert stats["spec_drafted"] == 0
             assert stats["compiled_programs"]["verify"] == 0
             assert set(stats["compiled_programs"]) \
-                == {"chunked_prefill", "step", "verify"}
+                == {"chunked_prefill", "decode_rounds", "verify"}
 
             # --- block-exhaustion burst: a deliberately tiny pool (8
             # pages of 4 tokens against 12-token prompts + 16-token
@@ -477,14 +476,13 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
             assert evict_after > evict_before, (
                 evict_before, evict_after)
 
-            # --- fused-decode burst: rebuild with decode_rounds=8 —
-            # the fused while_loop program replaces the per-step
-            # dispatch loop (docs §5.2e) — and drive mixed-length
-            # concurrent prompts.  The engine must dispatch fused
-            # rounds (kft_engine_fused_rounds_total delta > 0), report
-            # the fused program in compiled_programs, and produce
+            # --- decode-rounds burst: rebuild with decode_rounds=8
+            # (docs §5.2e) and drive mixed-length concurrent prompts.
+            # The engine must dispatch rounds
+            # (kft_engine_fused_rounds_total delta > 0), report the
+            # program in compiled_programs, and produce
             # token-IDENTICAL output to a decode_rounds=1 control
-            # rebuild (the k=1 path compiles no fused program).
+            # rebuild (the same program, one step a dispatch).
             with urllib.request.urlopen(
                     f"http://127.0.0.1:{port}/metrics",
                     timeout=30) as resp:
@@ -517,10 +515,9 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
                 f"fused burst dispatched no fused rounds: {stats}")
             assert stats["steps_per_round_p50"] >= 1, stats
             programs = stats["compiled_programs"]
-            # The fused program joins the guarantee exactly once; the
-            # per-step program is never needed on this path (0), and
+            # The program compiles exactly once for every width, and
             # verify stays 0 (spec off).
-            assert programs.get("decode_rounds") == 1, programs
+            assert programs["decode_rounds"] == 1, programs
             assert programs["chunked_prefill"] == 1, programs
             assert programs["verify"] == 0, programs
             with urllib.request.urlopen(
@@ -532,11 +529,11 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
                 engine="lm-v1") or 0
             assert fused_after - fused_before > 0, (
                 fused_before, fused_after)
-            # k=1 control rebuild: identical tokens, no fused program.
-            # Same concurrent shape as the fused burst — greedy decode
+            # Cap-1 control rebuild: identical tokens.
+            # Same concurrent shape as the burst above — greedy decode
             # is order-independent per slot, and the threads halve the
             # control's wall time.
-            rebuild(0)
+            rebuild(0, decode_rounds=1)
             outs.clear()
             threads = [threading.Thread(target=client, args=(i, p))
                        for i, p in enumerate(fused_prompts)]
@@ -552,9 +549,8 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
                     f"http://127.0.0.1:{port}/model/lm:stats",
                     timeout=30) as resp:
                 stats = json.loads(resp.read())["batcher"]
-            assert stats["fused_rounds"] == 0, stats
-            assert "decode_rounds" not in stats["compiled_programs"], \
-                stats["compiled_programs"]
+            assert stats["decode_rounds"] == 1, stats
+            assert stats["steps_per_round_p99"] == 1, stats
         finally:
             httpd.shutdown()
             server.stop()
@@ -633,10 +629,13 @@ def fault_injection_smoke(namespace: str = "kubeflow-test") -> None:
                        "temperature": 0.0})
         server = ModelServer(reload_backoff_s=0.5)
         server.add_model("lm", f"{tmp}/lm")
+        # One step a round: the scenario sleeps once a DISPATCH, and
+        # the expiry in step 2 counts on a sleep before every token.
         server.enable_batching("lm", batcher_factory(
             micro_batch_size=0, batch_timeout_s=0.005,
             lm_engine=True, lm_engine_slots=1,
-            lm_engine_prefill_len=16, max_queue_depth=1))
+            lm_engine_prefill_len=16, max_queue_depth=1,
+            decode_rounds=1))
         httpd, _ = make_http_server(server, port=0, host="127.0.0.1")
         port = httpd.server_address[1]
         try:
@@ -847,10 +846,14 @@ def fleet_smoke(namespace: str = "kubeflow-test") -> None:
     def make_replica(base, port=0):
         server = ModelServer()
         server.add_model("lm", base)
+        # One step a round: the scenario's sleep comes once a
+        # DISPATCH, and the in-flight load is observable only while a
+        # sleep stands before every token.
         server.enable_batching("lm", batcher_factory(
             micro_batch_size=0, batch_timeout_s=0.005,
             lm_engine=True, lm_engine_slots=2,
-            lm_engine_prefill_len=16, max_queue_depth=8))
+            lm_engine_prefill_len=16, max_queue_depth=8,
+            decode_rounds=1))
         httpd, _ = make_http_server(server, port=port,
                                     host="127.0.0.1")
         return server, httpd
@@ -1277,10 +1280,14 @@ def survivable_smoke(namespace: str = "kubeflow-test") -> None:
     def make_replica(base, port=0):
         server = ModelServer()
         server.add_model("lm", base)
+        # One step a round: the sleep paces generation token by token
+        # (it comes once a DISPATCH), so the kill at the 3rd token
+        # lands mid-generation on the replica that serves it.
         server.enable_batching("lm", batcher_factory(
             micro_batch_size=0, batch_timeout_s=0.005,
             lm_engine=True, lm_engine_slots=2,
-            lm_engine_prefill_len=32, max_queue_depth=16))
+            lm_engine_prefill_len=32, max_queue_depth=16,
+            decode_rounds=1))
         httpd, _ = make_http_server(server, port=port, host="127.0.0.1",
                                     server_cls=KillableServer)
         return server, httpd
@@ -1578,12 +1585,16 @@ def kv_spill_smoke(namespace: str = "kubeflow-test") -> None:
     def make_replica(base, port=0):
         server = ModelServer()
         server.add_model("lm", base)
+        # One step a round: step 4 kills a replica once 3 tokens have
+        # streamed, and the 25-token turn-2 prompt plus what was
+        # delivered must still fit the 32-token prefill width for the
+        # survivor to take the resume (a round of 8 delivers 9).
         server.enable_batching("lm", batcher_factory(
             micro_batch_size=0, batch_timeout_s=0.005,
             lm_engine=True, lm_engine_slots=2,
             lm_engine_prefill_len=32, max_queue_depth=16,
             kv_block_tokens=4, kv_pool_blocks=12,
-            host_spill_blocks=60))
+            host_spill_blocks=60, decode_rounds=1))
         httpd, _ = make_http_server(server, port=port, host="127.0.0.1",
                                     server_cls=KillableServer)
         return server, httpd
@@ -2132,7 +2143,10 @@ def adapter_serving_smoke(namespace: str = "kubeflow-test") -> None:
             lm_engine=True, lm_engine_slots=3,
             lm_engine_prefill_len=16, kv_block_tokens=4,
             max_queue_depth=16, adapters_dir=adir,
-            adapter_slots=2, adapter_rank=rank))
+            adapter_slots=2, adapter_rank=rank,
+            # One step a round: a sleep before every token keeps the
+            # pinned generation of step 3 in flight long enough to see.
+            decode_rounds=1))
         httpd, _ = make_http_server(server, port=0, host="127.0.0.1")
         return server, httpd
 
@@ -2274,7 +2288,7 @@ def adapter_serving_smoke(namespace: str = "kubeflow-test") -> None:
                 stats = srv.batcher_stats("lm") or {}
                 programs = stats.get("compiled_programs") or {}
                 assert set(k for k, v in programs.items() if v) <= \
-                    {"chunked_prefill", "step"}, (
+                    {"chunked_prefill", "decode_rounds"}, (
                     f"replica {i} grew extra programs under mixed "
                     f"adapter traffic: {programs}")
 

@@ -4,8 +4,8 @@ The reference could not test its multi-worker GPU paths without renting
 hardware (SURVEY.md §4 — it created GCE VMs per CI run).  We do better:
 every test runs on a virtual 8-device CPU "slice" via
 ``--xla_force_host_platform_device_count``, so SPMD sharding, collectives,
-and gang logic are exercised hermetically.  bench.py and chip_smoke.py
-intentionally do NOT import this — they run on the attached TPU chip.
+and gang logic are exercised hermetically.  chip_smoke.py
+intentionally does NOT import this — it runs on the attached TPU chip.
 """
 
 import os

@@ -139,7 +139,7 @@ class TestLoaderThroughput:
         """The native core exists to out-feed the chip; this smoke pins
         that it at least sustains multi-shard reads at a sane rate and
         does not regress below the single-thread python fallback on a
-        parallel read (bench.py --model=data reports the real numbers)."""
+        parallel read."""
         import time
 
         payload = b"x" * 65536

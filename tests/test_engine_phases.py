@@ -66,15 +66,16 @@ def _programs(lm):
     return {
         "decode_rounds": (g.decode_rounds, (
             cfg, params, state, decode, 4, tables, i32(4))),
-        "decode_step": (g.decode_step, (
-            cfg, params, state, decode, 1, tables)),
+        "verify_step": (g.verify_step, (
+            cfg, params, state, decode, 3, np.zeros((4, 3), np.int32),
+            np.zeros((4,), np.int32), tables)),
         "prefill_chunk_into_slot": (g.prefill_chunk_into_slot, (
             cfg, params, state, decode, chunk, i32(0), i32(1), i32(1),
             i32(0), i32(0), tables[:1])),
     }
 
 
-PROGRAMS = ["decode_rounds", "decode_step", "prefill_chunk_into_slot"]
+PROGRAMS = ["decode_rounds", "verify_step", "prefill_chunk_into_slot"]
 
 
 def _scopes(lm):
@@ -90,16 +91,15 @@ def test_scope_names_are_in_the_lowered_text(lm, program):
     assert set(re.findall(r"kft\.[a-z_]+", text)) == _scopes(lm)
 
 
-@pytest.mark.parametrize("program", ["decode_rounds", "decode_step"])
-def test_kernel_step_programs_keep_their_scopes(
-        lm, program, interpreted_paged_kernel):
-    """With the paged attention kernel chosen the step programs gather
+def test_kernel_step_program_keeps_its_scopes(
+        lm, interpreted_paged_kernel):
+    """With the paged attention kernel chosen ``decode_rounds`` gathers
     no view (no ``kft.kv_view``) and the kernel's own operations lie
     under ``kft.attention``, which ``programs.decode_attention_share``
     reads."""
     import jax
 
-    fn, args = _programs(lm)[program]
+    fn, args = _programs(lm)["decode_rounds"]
     text = fn.lower(*args, paged_kernel=True).as_text(debug_info=True)
     assert set(re.findall(r"kft\.[a-z_]+", text)) \
         == _scopes(lm) - {"kft.kv_view"}
@@ -136,7 +136,7 @@ def test_every_dot_gather_and_scatter_of_the_layer_body_has_a_scope(
     import jax
 
     fn, args = _programs(lm)[program]
-    static = {"decode_rounds": (0, 3, 4), "decode_step": (0, 3, 4),
+    static = {"decode_rounds": (0, 3, 4), "verify_step": (0, 3, 4),
               "prefill_chunk_into_slot": (0, 3)}[program]
     jaxpr = jax.make_jaxpr(fn, static_argnums=static)(*args)
     counted = [e.primitive.name for e in _all_eqns(jaxpr.jaxpr)]
@@ -209,8 +209,8 @@ def _prompts(n, length=9, seed=SEED):
 
 
 PATHS = {
-    "fused": ({"decode_rounds": 4}, "decode_rounds"),
-    "unfused": ({"decode_rounds": 1}, "step"),
+    "cap4": ({"decode_rounds": 4}, "decode_rounds"),
+    "cap1": ({"decode_rounds": 1}, "decode_rounds"),
     # A prompt that repeats itself, so the n-gram drafter proposes.
     "verify": ({"decode_rounds": 1, "speculative_tokens": 3}, "verify"),
 }
@@ -431,7 +431,7 @@ def test_compile_counters_are_set_once(lm):
     assert second["compile_s"] == first["compile_s"]
     assert second["compiled_peak_bytes"] == first["compiled_peak_bytes"]
     assert second["compiled_programs"] == first["compiled_programs"] == {
-        "chunked_prefill": 1, "step": 0, "verify": 0, "decode_rounds": 1}
+        "chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
 
 
 def test_pool_hand_off_and_stats_are_sized_by_planes(lm):

@@ -279,9 +279,12 @@ class TestEngineDeadlines:
         prompt_a = rng.randint(1, VOCAB, size=(5,)).tolist()
         prompt_b = rng.randint(1, VOCAB, size=(7,)).tolist()
         with faults.injected("seed=1;engine.step:sleep=0.05"):
+            # One step a round: the fault sleeps once a DISPATCH, and
+            # A's expiry below counts on a sleep before every token.
             engine = DecodeEngine(spec["cfg"], spec["params"],
                                   spec["decode"], slots=2,
-                                  prefill_len=16, name="ft-reclaim")
+                                  prefill_len=16, decode_rounds=1,
+                                  name="ft-reclaim")
             outs: dict = {}
 
             def client(key, prompt, deadline=None):
@@ -320,56 +323,68 @@ class TestEngineDeadlines:
             assert got == _reference_row(spec, prompt, NEW_TOKENS), (
                 f"request {key!r} drifted after mid-generation abort")
 
-    def test_retired_lagged_request_still_honors_deadline(
+    def test_request_retired_at_dispatch_is_delivered_at_its_rounds_end(
             self, engine_model):
-        """A deterministically-retired request whose lagged emissions
-        are still pending (slot freed at dispatch, delivery waiting on
-        sync_lag while another slot keeps stepping) must fail at its
-        deadline — under wedged steps that lag is unbounded, and the
-        client gets its 504, not a late 200."""
+        """A request whose remaining budget fits the round is retired
+        at that round's DISPATCH (its slot is free while the device
+        computes) and its tokens arrive at that round's end: under
+        wedged rounds it is not kept waiting on the co-resident
+        request's later rounds, and a deadline it holds is met, not
+        swept (expiry runs between rounds and finds it delivered)."""
         from kubeflow_tpu.serving.engine import DecodeEngine
 
         spec, _ = engine_model
-        with faults.injected("seed=1;engine.step:sleep=0.08"):
-            engine = DecodeEngine(spec["cfg"], spec["params"],
-                                  spec["decode"], slots=2,
-                                  prefill_len=16, sync_lag=8,
-                                  name="ft-lag-dl")
-            outs: dict = {}
+        prompt = np.arange(1, 5, dtype=np.int32)
+        engine = DecodeEngine(spec["cfg"], spec["params"],
+                              spec["decode"], slots=2, prefill_len=16,
+                              decode_rounds=2, name="ft-retired")
+        outs: dict = {}
 
-            def client(key, new, deadline=None):
-                try:
-                    outs[key] = engine.submit(
-                        {"tokens": np.arange(1, 5, dtype=np.int32),
-                         "max_new_tokens": new}, deadline=deadline)
-                except Exception as exc:  # noqa: BLE001 — the point
-                    outs[key] = exc
-
+        def client(key, new, deadline=None):
             try:
-                # B (12 slow steps) keeps the loop busy so A's lagged
-                # emissions stay parked well past A's deadline.
+                outs[key] = engine.submit(
+                    {"tokens": prompt, "max_new_tokens": new},
+                    deadline=deadline)
+            except Exception as exc:  # noqa: BLE001 — the point
+                outs[key] = exc
+
+        try:
+            client("warm", 3)  # compiles both programs, unwedged
+            with faults.injected("seed=1;engine.step:sleep=0.1"):
+                # B: 11 steps after its first token, two a round, each
+                # round wedged 0.1 s.  A: one step after its first
+                # token, so the round that runs it retires it.
                 t_b = threading.Thread(target=client, args=("b", 12))
                 t_b.start()
                 t_a = threading.Thread(
                     target=client,
-                    args=("a", 2, faults.monotonic() + 0.35))
+                    args=("a", 2, faults.monotonic() + 30.0))
                 t_a.start()
                 t_a.join(timeout=60)
-                assert isinstance(outs["a"], DeadlineExceeded), outs["a"]
+                assert t_b.is_alive(), "A waited for B's later rounds"
                 t_b.join(timeout=60)
-                assert not isinstance(outs["b"], Exception), outs["b"]
-                assert engine.stats()["in_flight_requests"] == 0
-            finally:
-                engine.close()
+            want = _reference_row(spec, prompt.tolist(), NEW_TOKENS)
+            for key, new in (("a", 2), ("b", 12)):
+                assert not isinstance(outs[key], Exception), outs[key]
+                assert np.asarray(outs[key]["tokens"])[0].tolist() \
+                    == want[:len(prompt) + new]
+            stats = engine.stats()
+            assert stats["deadline_expired"] == 0
+            assert stats["in_flight_requests"] == 0
+        finally:
+            engine.close()
 
     def test_queued_request_expires_while_slots_busy(self, engine_model):
         from kubeflow_tpu.serving.engine import DecodeEngine
 
         spec, _ = engine_model
         with faults.injected("seed=1;engine.step:sleep=0.04"):
+            # One step a round: a sleep before every token keeps the
+            # occupant in its slot well past the queued deadline.
             engine = DecodeEngine(spec["cfg"], spec["params"],
                                   spec["decode"], slots=1,
-                                  prefill_len=16, name="ft-queue-exp")
+                                  prefill_len=16, decode_rounds=1,
+                                  name="ft-queue-exp")
             holder: dict = {}
 
             def occupant():
@@ -399,11 +414,13 @@ class TestEngineOverload:
 
         spec, _ = engine_model
         with faults.injected("seed=1;engine.step:sleep=0.04"):
+            # One step a round: a sleep before every token holds the
+            # slot while the queue fills and the third submit sheds.
             engine = DecodeEngine(spec["cfg"], spec["params"],
                                   spec["decode"], slots=1,
                                   prefill_len=16, max_queue_depth=1,
                                   overload_retry_after_s=3.0,
-                                  name="ft-eng-shed")
+                                  decode_rounds=1, name="ft-eng-shed")
             results: dict = {}
 
             def client(i):
@@ -941,9 +958,12 @@ class TestEngineDrainDeadlineSkew:
         prompt = rng.randint(1, VOCAB, size=(6,)).tolist()
         with faults.injected(
                 "seed=1;engine.step:sleep=0.05;engine.step:skew=500"):
+            # One step a round: "the step AFTER close()" must exist,
+            # and 12 tokens in rounds of 8 are over in two dispatches.
             engine = DecodeEngine(spec["cfg"], spec["params"],
                                   spec["decode"], slots=1,
-                                  prefill_len=16, name="ft-drain-skew")
+                                  prefill_len=16, decode_rounds=1,
+                                  name="ft-drain-skew")
             outs: dict = {}
 
             def client():

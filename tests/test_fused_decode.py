@@ -109,17 +109,15 @@ class TestFusedDecode:
             self, engine_model, monkeypatch):
         """The tentpole identity: 9 mixed-length requests through 3
         slots (every slot reused, multi-chunk prefill, mid-round
-        admission waves) are token-identical across fused(k=8),
-        unfused(k=1), and generate() — and across BOTH engines the
-        only programs compiled are one chunked prefill each, one step
-        (the k=1 engine), and one fused round program (the k=8 engine,
-        whose adaptive widths all ride the same executable)."""
+        admission waves) are token-identical across a round cap of 8,
+        a cap of 1, and generate() — and each engine compiles one
+        chunked prefill and one round program (whose adaptive widths
+        all ride the same executable)."""
         from kubeflow_tpu.models import generate as gen_mod
 
-        compiles = {"chunked_prefill": 0, "step": 0, "verify": 0,
+        compiles = {"chunked_prefill": 0, "verify": 0,
                     "decode_rounds": 0}
         for attr, key in (("prefill_chunk_into_slot", "chunked_prefill"),
-                          ("decode_step", "step"),
                           ("verify_step", "verify"),
                           ("decode_rounds", "decode_rounds")):
             monkeypatch.setattr(gen_mod, attr, _counting_proxy(
@@ -135,7 +133,7 @@ class TestFusedDecode:
 
         fused_outs, fused_stats, fused_programs = _run_engine(
             spec, prompts, news, decode_rounds=8)
-        plain_outs, _, plain_programs = _run_engine(
+        plain_outs, plain_stats, plain_programs = _run_engine(
             spec, prompts, news, decode_rounds=1)
         for i in range(len(prompts)):
             got_f = np.asarray(fused_outs[i]["tokens"])[0].tolist()
@@ -158,14 +156,14 @@ class TestFusedDecode:
         assert fused_stats["active_slots"] == 0
         assert fused_stats["in_flight_requests"] == 0
 
-        # Compile counts: the fused engine never builds the per-step
-        # program; the k=1 engine never builds the fused one.
-        assert compiles == {"chunked_prefill": 2, "step": 1,
-                            "verify": 0, "decode_rounds": 1}
-        assert fused_programs == {"chunked_prefill": 1, "step": 0,
-                                  "verify": 0, "decode_rounds": 1}
-        assert plain_programs == {"chunked_prefill": 1, "step": 1,
-                                  "verify": 0}
+        # A cap of 1 is the same program run one step a dispatch.
+        assert plain_stats["steps_per_round_p99"] == 1
+        assert plain_stats["fused_rounds"] == plain_stats["steps"]
+
+        assert compiles == {"chunked_prefill": 2, "verify": 0,
+                            "decode_rounds": 2}
+        assert fused_programs == plain_programs == {
+            "chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
 
     def test_eos_inside_round_matches_generate(self, engine_model):
         """A slot whose EOS lands mid-round freezes on device; the
@@ -325,8 +323,8 @@ class TestFusedDecode:
 
     @pytest.mark.parametrize("tensor", [2])
     def test_mesh_fused_identity(self, engine_model, tensor):
-        """Fused rounds compile SPMD under the serving mesh exactly
-        like decode_step: greedy identity holds at mesh 2 (the
+        """Decode rounds compile SPMD under the serving mesh:
+        greedy identity holds at mesh 2 (the
         conftest forces an 8-device CPU host platform; the mesh-1 /
         single-device fused path is every other test in this file)."""
         from kubeflow_tpu.serving import sharding
@@ -371,17 +369,21 @@ class TestFusedDecode:
             def lower(self, *a, **kw):
                 lowered = real.lower(*a, **kw)
 
+                class _Compiled:
+                    def __init__(self):
+                        self.exe = lowered.compile()
+
+                    def __getattr__(self, name):  # memory_analysis
+                        return getattr(self.exe, name)
+
+                    def __call__(self, *ra, **rkw):
+                        calls["n"] += 1
+                        if calls["n"] >= 2:
+                            raise RuntimeError("device died")
+                        return self.exe(*ra, **rkw)
+
                 class _Lowered:
-                    def compile(self_l):
-                        exe = lowered.compile()
-
-                        def run(*ra, **rkw):
-                            calls["n"] += 1
-                            if calls["n"] >= 2:
-                                raise RuntimeError("device died")
-                            return exe(*ra, **rkw)
-
-                        return run
+                    compile = staticmethod(_Compiled)
 
                 return _Lowered()
 
@@ -410,7 +412,8 @@ class TestFusedDecode:
         assert not any(t.is_alive() for t in threads), (
             "a client hung after the fused loop died")
         assert len(outs) == 2  # every waiter resolved (result or error)
-        assert any(isinstance(v, Exception) for v in outs.values())
+        assert calls["n"] == 2
+        assert [type(outs[i]) for i in (0, 1)] == [RuntimeError] * 2
         engine.close()
 
 
@@ -431,7 +434,7 @@ def _with_config(spec, **over):
 
 class TestPagedKernelChoice:
     """serving/engine.py decides ONCE, from the platform its pool lives
-    on, whether decode_step / decode_rounds attend through
+    on, whether decode_rounds attends through
     ops/paged_attention.py; ``decode_kernel_steps`` over ``steps`` is
     the share of decode steps the kernel served."""
 
@@ -478,8 +481,7 @@ class TestPagedKernelChoice:
             "kft_engine_decode_kernel_steps_total",
             engine=f"{name}-k{decode_rounds}") == stats["steps"]
         assert programs["chunked_prefill"] == 1
-        assert programs["step"] == int(decode_rounds == 1)
-        assert programs.get("decode_rounds", 0) == int(decode_rounds > 1)
+        assert programs["decode_rounds"] == 1
         for i in range(len(prompts)):
             got = np.asarray(outs[i]["tokens"])[0].tolist()
             assert got == np.asarray(
@@ -589,8 +591,8 @@ class TestPoolCarriedInPlace:
     """models/generate.py carries the STACKED paged pool through the
     layer scan: a layer scatters its columns at (plane, block, offset)
     and reads its pages by plane.  Invisible in the tokens, for every
-    stack that runs the paged programs: plain and int8 pools, single
-    and fused steps, a verify round, a looped stack, a mesh."""
+    stack that runs the paged programs: plain and int8 pools, rounds
+    of one step and of eight, a verify round, a looped stack, a mesh."""
 
     CASES = {
         "plain-k1": {"decode_rounds": 1},
@@ -691,11 +693,7 @@ class TestPoolCarriedInPlace:
 
         tables = jnp.asarray(
             rng.permutation(nb)[:slots * mb].reshape(slots, mb), jnp.int32)
-        # Every slot retired: each step parks its write.
-        s = state()
-        before = pool(s)
-        s, _ = gen.decode_step(cfg, params, s, decode, 2, tables)
-        unchanged(before, s)
+        # Every slot retired: a verify window parks its writes.
         s = state()
         before = pool(s)
         s, _, _ = gen.verify_step(
@@ -703,15 +701,23 @@ class TestPoolCarriedInPlace:
             jnp.full((slots,), 2, jnp.int32), tables)
         unchanged(before, s)
         # Live slots whose next position falls on a page the table does
-        # not hold (the sentinel), single and fused steps.
+        # not hold (the sentinel): rounds of one step and of three, and
+        # a round of two in which only slot 0 lives, so that each of
+        # its steps also parks the retired slots' writes (a round of
+        # retired slots alone runs no step).
         live = {"done": np.zeros(slots, bool),
                 "lengths": np.full(slots, bt, np.int32),
                 "stop_len": np.full(slots, 2 * bt, np.int32)}
         holes = tables.at[:, 1:].set(nb)
-        s = state(**live)
-        before = pool(s)
-        s, _ = gen.decode_step(cfg, params, s, decode, 1, holes)
-        unchanged(before, s)
+        for alive, cap in ((live, 1), ({**live, "done": np.arange(
+                slots) > 0}, 2)):
+            s = state(**alive)
+            before = pool(s)
+            s, _, counts, steps = gen.decode_rounds(
+                cfg, params, s, decode, 4, holes, jnp.int32(cap))
+            assert int(steps) == cap
+            assert counts.tolist() == (~alive["done"] * cap).tolist()
+            unchanged(before, s)
         s = state(**live)
         before = pool(s)
         s, _, _, steps = gen.decode_rounds(

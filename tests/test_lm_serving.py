@@ -124,12 +124,17 @@ def engine_model(tmp_path_factory):
     server.stop()
 
 
+# The round cap must not change a token: the identity tests below run
+# at one step a dispatch and at the default cap.
+ROUND_CAPS = pytest.mark.parametrize("decode_rounds", [1, 8])
+
+
 def _counting_proxy(fn, compiles, key):
     """Wrap a slot entry point so each .lower() call — exactly one XLA
     compilation in the engine, which AOT-compiles then only invokes
     the executables — bumps ``compiles[key]``.  Shared by the
-    three-program and four-program compile-count tests so the two
-    assertions can never silently diverge."""
+    compile-count tests so their assertions can never silently
+    diverge."""
     class _Proxy:
         def lower(self, *a, **kw):
             compiles[key] += 1
@@ -165,16 +170,17 @@ class TestDecodeEngine:
     ``speculative_tokens`` — see TestSpeculativeDecoding; prefix reuse
     is zero-copy block-table aliasing, never a device program)."""
 
+    @ROUND_CAPS
     def test_matches_generate_mixed_lengths_slot_reuse_three_programs(
-            self, engine_model, monkeypatch):
+            self, engine_model, monkeypatch, decode_rounds):
         import threading
 
         from kubeflow_tpu.models import generate as gen_mod
         from kubeflow_tpu.serving.engine import DecodeEngine
 
-        compiles = {"chunked_prefill": 0, "step": 0, "verify": 0}
+        compiles = {"chunked_prefill": 0, "decode_rounds": 0, "verify": 0}
         for attr, key in (("prefill_chunk_into_slot", "chunked_prefill"),
-                          ("decode_step", "step"),
+                          ("decode_rounds", "decode_rounds"),
                           ("verify_step", "verify")):
             monkeypatch.setattr(gen_mod, attr, _counting_proxy(
                 getattr(gen_mod, attr), compiles, key))
@@ -195,7 +201,9 @@ class TestDecodeEngine:
         engine = DecodeEngine(spec["cfg"], spec["params"],
                               spec["decode"], slots=3, prefill_len=16,
                               admit_width=2, prefill_chunk_tokens=8,
-                              kv_block_tokens=4, name="test-equiv")
+                              kv_block_tokens=4,
+                              decode_rounds=decode_rounds,
+                              name=f"test-equiv-{decode_rounds}")
         try:
             outs = [None] * len(prompts)
 
@@ -229,11 +237,13 @@ class TestDecodeEngine:
         # aliasing — compiled exactly two programs (no speculative
         # verify: this engine runs with speculation off; no prefix
         # copy program EXISTS — a cache hit is a block-table edit).
-        two = {"chunked_prefill": 1, "step": 1, "verify": 0}
+        two = {"chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
         assert compiles == two
         assert engine.compiled_programs() == two
 
-    def test_eos_retirement_matches_generate(self, engine_model):
+    @ROUND_CAPS
+    def test_eos_retirement_matches_generate(self, engine_model,
+                                             decode_rounds):
         """With EOS configured, a slot frozen by the device `done` flag
         must emit exactly generate()'s tokens up to and including EOS,
         and its slot must come back (occupancy drains to zero)."""
@@ -248,7 +258,9 @@ class TestDecodeEngine:
         prompts = [rng.randint(1, VOCAB, size=(n,)).tolist()
                    for n in (3, 9, 16)]
         engine = DecodeEngine(spec["cfg"], spec["params"], decode,
-                              slots=2, prefill_len=16, name="test-eos")
+                              slots=2, prefill_len=16,
+                              decode_rounds=decode_rounds,
+                              name=f"test-eos-{decode_rounds}")
         try:
             for prompt in prompts:
                 out = engine.submit(
@@ -268,42 +280,55 @@ class TestDecodeEngine:
     def test_abort_resolves_retired_requests(self, engine_model,
                                              monkeypatch):
         """Engine death must error EVERY waiter — including a request
-        whose slot was deterministically retired at dispatch while its
-        lagged emission still sat in the pending stream (it is in
-        neither the queue nor the slot table when _abort walks them)."""
+        whose slot was deterministically retired at the dispatch of
+        the round that dies (it is in neither the queue nor the slot
+        table when _abort walks them).  A device's death shows where
+        its results are read, so the second round's tokens raise
+        there, after the dispatch retired the short request."""
         import threading
 
         from kubeflow_tpu.models import generate as gen_mod
         from kubeflow_tpu.serving.engine import DecodeEngine
 
-        real = gen_mod.decode_step
+        real = gen_mod.decode_rounds
         calls = {"n": 0}
 
-        class _DiesOnSecondStep:
+        class _Dead:
+            def __array__(self, *a, **kw):
+                raise RuntimeError("device died")
+
+        class _DiesInSecondRound:
             def lower(self, *a, **kw):
                 lowered = real.lower(*a, **kw)
 
+                class _Compiled:
+                    def __init__(self):
+                        self.exe = lowered.compile()
+
+                    def __getattr__(self, name):  # memory_analysis
+                        return getattr(self.exe, name)
+
+                    def __call__(self, *ra, **rkw):
+                        calls["n"] += 1
+                        state, toks, counts, steps = self.exe(*ra, **rkw)
+                        if calls["n"] >= 2:
+                            toks = _Dead()
+                        return state, toks, counts, steps
+
                 class _Lowered:
-                    def compile(self_l):
-                        exe = lowered.compile()
-
-                        def run(*ra, **rkw):
-                            calls["n"] += 1
-                            if calls["n"] >= 2:
-                                raise RuntimeError("device died")
-                            return exe(*ra, **rkw)
-
-                        return run
+                    compile = staticmethod(_Compiled)
 
                 return _Lowered()
 
-        monkeypatch.setattr(gen_mod, "decode_step", _DiesOnSecondStep())
+        monkeypatch.setattr(gen_mod, "decode_rounds",
+                            _DiesInSecondRound())
         spec, _ = engine_model
-        # sync_lag larger than the steps the workload survives: the
-        # short request's tokens are never drained before the blow-up.
+        # Rounds of 4: the first token comes from the prefill, so a
+        # budget of 6 is scheduled to its end by the SECOND round and
+        # retired at that round's dispatch; 12 stays in its slot.
         engine = DecodeEngine(spec["cfg"], spec["params"],
                               spec["decode"], slots=2, prefill_len=16,
-                              sync_lag=4, name="test-abort")
+                              decode_rounds=4, name="test-abort")
         outs: dict = {}
 
         def client(i, new):
@@ -315,18 +340,21 @@ class TestDecodeEngine:
                 outs[i] = exc
 
         threads = [threading.Thread(target=client, args=a)
-                   for a in ((0, 2), (1, 12))]
+                   for a in ((0, 6), (1, 12))]
         for t in threads:
             t.start()
         for t in threads:
             t.join(timeout=30)
         assert not any(t.is_alive() for t in threads), (
             "a client hung after the engine loop died")
-        assert len(outs) == 2  # every waiter resolved (result or error)
+        assert calls["n"] == 2
+        # Every waiter resolved, both with the engine's death.
+        assert [type(outs[i]) for i in (0, 1)] == [RuntimeError] * 2
         engine.close()
 
+    @ROUND_CAPS
     def test_prefix_cache_identity_on_off_with_eviction(
-            self, engine_model):
+            self, engine_model, decode_rounds):
         """Shared-prefix aliasing must be invisible in the tokens:
         engine output with the prefix cache ON equals single-request
         generate() equals cache OFF — including LRU eviction forced
@@ -360,8 +388,8 @@ class TestDecodeEngine:
                 spec["cfg"], spec["params"], spec["decode"], slots=2,
                 prefill_len=16, prefill_chunk_tokens=4,
                 kv_block_tokens=4, kv_pool_blocks=10,
-                prefix_caching=caching,
-                name=f"test-prefix-{int(caching)}")
+                prefix_caching=caching, decode_rounds=decode_rounds,
+                name=f"test-prefix-{int(caching)}-{decode_rounds}")
             try:
                 outs = [None] * len(prompts)
 
@@ -404,8 +432,9 @@ class TestDecodeEngine:
         assert on_engine._mgr.used_blocks() == 0
         assert off_engine._mgr.used_blocks() == 0
 
+    @ROUND_CAPS
     def test_shared_prefix_zero_copy_aliasing_identity(
-            self, engine_model):
+            self, engine_model, decode_rounds):
         """Two requests sharing a block-aligned prefix must produce
         bit-identical tokens to unshared runs while the engine copies
         ZERO prefix tokens: the hit is a refcounted block-table alias
@@ -423,7 +452,8 @@ class TestDecodeEngine:
         engine = DecodeEngine(
             spec["cfg"], spec["params"], spec["decode"], slots=2,
             prefill_len=16, prefill_chunk_tokens=8, kv_block_tokens=4,
-            name="test-zero-copy")
+            decode_rounds=decode_rounds,
+            name=f"test-zero-copy-{decode_rounds}")
         try:
             o1 = engine.submit({"tokens": np.asarray(p1, np.int32),
                                 "max_new_tokens": 6})
@@ -447,7 +477,7 @@ class TestDecodeEngine:
             assert stats["prefix_hits"] == 1
             assert stats["cached_prompt_tokens"] == 8
             assert set(stats["compiled_programs"]) == {
-                "chunked_prefill", "step", "verify"}
+                "chunked_prefill", "decode_rounds", "verify"}
             # White-box: the alias really is the SAME physical pages —
             # p2's own published record leads with p1's block ids (its
             # prefill never wrote new pages for the shared prefix; a
@@ -575,7 +605,9 @@ class TestDecodeEngine:
         finally:
             server.enable_batching("lm", lambda model: None)
 
-    def test_padded_prompt_counts_true_tokens(self, engine_model):
+    @ROUND_CAPS
+    def test_padded_prompt_counts_true_tokens(self, engine_model,
+                                              decode_rounds):
         """accepts()/submit() must validate the REAL token count, not
         the padded width: a 5-token prompt right-padded to 24 (beyond
         the 16-wide prefill window) is admitted, prefilled at its true
@@ -591,7 +623,8 @@ class TestDecodeEngine:
         want = _reference_rows(spec, [real], [6])[0]
         engine = DecodeEngine(spec["cfg"], spec["params"],
                               spec["decode"], slots=1, prefill_len=16,
-                              name="test-padded")
+                              decode_rounds=decode_rounds,
+                              name=f"test-padded-{decode_rounds}")
         try:
             assert engine.accepts({"tokens": padded})
             out = engine.submit({"tokens": padded, "max_new_tokens": 6})
@@ -610,8 +643,9 @@ class TestDecodeEngine:
         finally:
             engine.close()
 
+    @ROUND_CAPS
     def test_final_chunk_near_cache_end_stays_in_bounds(
-            self, engine_model):
+            self, engine_model, decode_rounds):
         """A cached-prefix resume whose final chunk window runs past
         the slot's max_len must not corrupt the cache: the paged
         scatter parks positions beyond the block table's real pages on
@@ -630,7 +664,8 @@ class TestDecodeEngine:
         engine = DecodeEngine(
             spec["cfg"], spec["params"], spec["decode"], slots=1,
             prefill_len=16, max_len=18, prefill_chunk_tokens=8,
-            kv_block_tokens=4, name="test-chunk-bounds")
+            kv_block_tokens=4, decode_rounds=decode_rounds,
+            name=f"test-chunk-bounds-{decode_rounds}")
         try:
             for i in range(2):  # second run resumes from 12 cached cols
                 out = engine.submit({
@@ -643,7 +678,8 @@ class TestDecodeEngine:
         finally:
             engine.close()
 
-    def test_budget_clamped_to_config(self, engine_model):
+    @ROUND_CAPS
+    def test_budget_clamped_to_config(self, engine_model, decode_rounds):
         """A request asking for more than the export config's
         max_new_tokens gets the config budget — the model's advertised
         ceiling, same as the direct path's trim — not the engine's
@@ -653,7 +689,8 @@ class TestDecodeEngine:
         spec, _ = engine_model
         engine = DecodeEngine(spec["cfg"], spec["params"],
                               spec["decode"], slots=1, prefill_len=16,
-                              name="test-clamp")
+                              decode_rounds=decode_rounds,
+                              name=f"test-clamp-{decode_rounds}")
         try:
             out = engine.submit({
                 "tokens": np.arange(1, 4, dtype=np.int32),
@@ -703,6 +740,56 @@ class TestDecodeEngine:
         factory = batcher_factory(micro_batch_size=0,
                                   batch_timeout_s=0.01)
         assert factory(model) is None  # direct path, no crash
+
+    def test_one_default_round_cap_and_the_programs_it_compiles(
+            self, engine_model, monkeypatch):
+        """The serving binary's parser, ``batcher_factory`` and
+        ``DecodeEngine`` agree on ``decode_rounds``, so an engine built
+        with none of them set is the engine the binary (and a cell of
+        the benchmark) serves; under plain traffic it compiles the
+        chunked prefill and the decode rounds, once each."""
+        import argparse
+        import inspect
+
+        from kubeflow_tpu.serving import main as serving_main
+        from kubeflow_tpu.serving.engine import DecodeEngine
+
+        parsed = {}
+        real = argparse.ArgumentParser.parse_args
+
+        def capture(self, args=None, namespace=None):
+            parsed.update(vars(real(self, args, namespace)))
+            raise SystemExit(0)
+
+        monkeypatch.setattr(argparse.ArgumentParser, "parse_args", capture)
+        with pytest.raises(SystemExit):
+            serving_main.main(["--model_name", "lm",
+                               "--model_base_path", "unused"])
+        monkeypatch.undo()
+        defaults = {
+            parsed["decode_rounds"],
+            inspect.signature(serving_main.batcher_factory)
+            .parameters["decode_rounds"].default,
+            inspect.signature(DecodeEngine.__init__)
+            .parameters["decode_rounds"].default}
+        assert defaults == {8}
+
+        spec, _ = engine_model
+        engine = DecodeEngine(spec["cfg"], spec["params"], spec["decode"],
+                              prefill_len=16, name="test-defaults")
+        try:
+            assert engine.compiled_programs() == {
+                "chunked_prefill": 0, "decode_rounds": 0, "verify": 0}
+            out = engine.submit({"tokens": np.asarray(_prompt(), np.int32)})
+            want = _reference_rows(spec, [_prompt()], [NEW_TOKENS])[0]
+            assert np.asarray(out["tokens"])[0].tolist() == want
+            stats = engine.stats()
+        finally:
+            engine.close()
+        assert stats["decode_rounds"] == 8
+        assert stats["steps_per_round_p99"] > 1
+        assert stats["compiled_programs"] == {
+            "chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
 
     def test_rest_routing_and_stats_route(self, engine_model):
         """Wired behind ModelServer via the serving entrypoint's
@@ -780,7 +867,7 @@ class TestDecodeEngine:
                 {"tokens": prompts[i][None], "max_new_tokens": news[i]}))
             assert engine.stats()["tokens"] == sum(news)
             assert engine.compiled_programs() == {
-                "chunked_prefill": 1, "step": 1, "verify": 0}
+                "chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
         finally:
             engine.close()
         # The static path bakes the export's budget into its programs:
@@ -816,7 +903,7 @@ class TestSpeculativeDecoding:
         (repetitive — greedy continuations of the tiny model collapse
         into cycles the n-gram drafter proposes) interleaved with
         random ones (the drafter finds no suffix match early on, so
-        plain decode rounds run too — both the step AND verify
+        plain decode rounds run too — both the decode AND verify
         programs must compile)."""
         rng = np.random.RandomState(SEED + 21)
         prompts, news = [], []
@@ -831,7 +918,7 @@ class TestSpeculativeDecoding:
         return prompts, news
 
     def _run_engine(self, spec, prompts, news, *, speculative_tokens,
-                    slots=2, decode=None, name="test-spec"):
+                    slots=2, decode=None, name="test-spec", **kw):
         import threading
 
         from kubeflow_tpu.serving.engine import DecodeEngine
@@ -841,7 +928,7 @@ class TestSpeculativeDecoding:
             slots=slots, prefill_len=16, prefill_chunk_tokens=8,
             kv_block_tokens=4,
             speculative_tokens=speculative_tokens,
-            name=f"{name}-{speculative_tokens}")
+            name=f"{name}-{speculative_tokens}", **kw)
         try:
             outs = [None] * len(prompts)
 
@@ -878,9 +965,9 @@ class TestSpeculativeDecoding:
         # here, and it must hold regardless of gating.
         monkeypatch.setattr(eng_mod, "_SPEC_RATE_MARGIN", 0.0)
 
-        compiles = {"chunked_prefill": 0, "step": 0, "verify": 0}
+        compiles = {"chunked_prefill": 0, "decode_rounds": 0, "verify": 0}
         for attr, key in (("prefill_chunk_into_slot", "chunked_prefill"),
-                          ("decode_step", "step"),
+                          ("decode_rounds", "decode_rounds"),
                           ("verify_step", "verify")):
             monkeypatch.setattr(gen_mod, attr, _counting_proxy(
                 getattr(gen_mod, attr), compiles, key))
@@ -888,10 +975,13 @@ class TestSpeculativeDecoding:
         spec, _ = engine_model
         prompts, news = self._mixed_workload()
         want = _reference_rows(spec, prompts, news)
+        # Rounds of 2 on both sides: the budgets are 6 to 12 tokens,
+        # and a round of 8 that runs while the first draft is made
+        # leaves a verify window no room.
         on_outs, on_stats = self._run_engine(
-            spec, prompts, news, speculative_tokens=4)
+            spec, prompts, news, speculative_tokens=4, decode_rounds=2)
         off_outs, off_stats = self._run_engine(
-            spec, prompts, news, speculative_tokens=0)
+            spec, prompts, news, speculative_tokens=0, decode_rounds=2)
         for i in range(len(prompts)):
             got_on = np.asarray(on_outs[i]["tokens"])[0].tolist()
             got_off = np.asarray(off_outs[i]["tokens"])[0].tolist()
@@ -910,10 +1000,10 @@ class TestSpeculativeDecoding:
         # Three programs, each compiled once across BOTH engines (the
         # spec-OFF engine reuses two of the same .lower sites and
         # never lowers verify).
-        assert compiles == {"chunked_prefill": 2, "step": 2,
+        assert compiles == {"chunked_prefill": 2, "decode_rounds": 2,
                             "verify": 1}
         assert on_stats["compiled_programs"] == {
-            "chunked_prefill": 1, "step": 1, "verify": 1}
+            "chunked_prefill": 1, "decode_rounds": 1, "verify": 1}
         assert off_stats["compiled_programs"]["verify"] == 0
 
     def test_forced_full_rejection_rollback_and_slot_reuse(
@@ -943,21 +1033,27 @@ class TestSpeculativeDecoding:
             # the real drafter's proposal instead would not guarantee
             # a mismatch: a proposal already one below the target
             # would shift ONTO it.)
+            # The drafter runs while a round computes and its proposal
+            # is kept only if its head is what that round delivers:
+            # at a round cap of 1 (pinned below) the head is ONE
+            # token, so that one is the reference's and every token
+            # after it, the whole verify window, is wrong.
             hist = history.tolist()
             for prompt, ref in zip(prompts, want):
                 if len(hist) >= len(prompt) \
                         and hist[:len(prompt)] == prompt:
                     emitted = len(hist) - len(prompt)
-                    nxt = ref[len(prompt) + emitted:
-                              len(prompt) + emitted + k]
-                    return ((np.asarray(nxt, np.int64) + 1)
-                            % VOCAB).astype(np.int32)
+                    nxt = np.asarray(ref[len(prompt) + emitted:
+                                         len(prompt) + emitted + k],
+                                     np.int64)
+                    nxt[1:] = (nxt[1:] + 1) % VOCAB
+                    return nxt.astype(np.int32)
             return np.empty((0,), np.int32)  # unknown prompt: no draft
 
         monkeypatch.setattr(eng_mod, "_ngram_propose", always_wrong)
         outs, stats = self._run_engine(
             spec, prompts, news, speculative_tokens=4, slots=1,
-            name="test-reject")
+            decode_rounds=1, name="test-reject")
         for i in range(len(prompts)):
             got = np.asarray(outs[i]["tokens"])[0].tolist()
             assert got == want[i], (
@@ -1013,10 +1109,13 @@ class TestSpeculativeDecoding:
             return np.asarray(nxt, np.int32)
 
         monkeypatch.setattr(eng_mod, "_ngram_propose", oracle)
+        # One step a round: at a wider cap the plain round that runs
+        # while the first draft is made reaches the EOS itself, and no
+        # verify window is ever dispatched.
         outs, stats = self._run_engine(
             spec, [prompt, prompt], [NEW_TOKENS, NEW_TOKENS],
             speculative_tokens=4, slots=1, decode=decode,
-            name="test-eos-window")
+            decode_rounds=1, name="test-eos-window")
         for i in range(2):  # second request = slot reuse after EOS
             got = np.asarray(outs[i]["tokens"])[0, len(prompt):].tolist()
             assert got == want, (
@@ -1110,12 +1209,15 @@ class TestResumeAndStreaming:
         return DecodeEngine(spec["cfg"], spec["params"],
                             decode or spec["decode"], name=name, **kw)
 
-    def test_resume_matches_generate_at_every_cut(self, engine_model):
+    @ROUND_CAPS
+    def test_resume_matches_generate_at_every_cut(self, engine_model,
+                                                  decode_rounds):
         spec, _ = engine_model
         prompt = _prompt()
         want = _reference_rows(spec, [prompt], [NEW_TOKENS])[0]
         suffix = want[len(prompt):]
-        engine = self._engine(spec, name="test-resume-cuts")
+        engine = self._engine(spec, decode_rounds=decode_rounds,
+                              name=f"test-resume-cuts-{decode_rounds}")
         try:
             for cut in range(NEW_TOKENS):
                 out = engine.submit({
@@ -1187,7 +1289,8 @@ class TestResumeAndStreaming:
         finally:
             engine.close()
 
-    def test_resume_under_tight_kv_pool(self, engine_model):
+    @ROUND_CAPS
+    def test_resume_under_tight_kv_pool(self, engine_model, decode_rounds):
         """Resume admissions reserve worst-case pages like any other:
         under a pool barely covering one worst case they serialize
         (never deadlock) and stay token-identical."""
@@ -1200,7 +1303,8 @@ class TestResumeAndStreaming:
         # Worst case: ceil((8 prompt + 6 resume + 6 new) / 4) = 5
         # pages; pool of 6 fits ONE resumed request plus scraps.
         engine = self._engine(spec, kv_pool_blocks=6,
-                              name="test-resume-tight")
+                              decode_rounds=decode_rounds,
+                              name=f"test-resume-tight-{decode_rounds}")
         try:
             outs = [None] * 3
 
@@ -1223,11 +1327,14 @@ class TestResumeAndStreaming:
             engine.close()
         assert engine.stats()["kv_blocks_used"] == 0
 
-    def test_submit_stream_yields_exact_suffix(self, engine_model):
+    @ROUND_CAPS
+    def test_submit_stream_yields_exact_suffix(self, engine_model,
+                                               decode_rounds):
         spec, _ = engine_model
         prompt = _prompt()
         want = _reference_rows(spec, [prompt], [NEW_TOKENS])[0]
-        engine = self._engine(spec, name="test-stream")
+        engine = self._engine(spec, decode_rounds=decode_rounds,
+                              name=f"test-stream-{decode_rounds}")
         try:
             meta, it = engine.submit_stream(
                 {"tokens": np.asarray(prompt, np.int32),

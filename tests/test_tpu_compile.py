@@ -1,4 +1,4 @@
-"""Compile the main path's kernels and two engine programs for a v5e chip
+"""Compile the main path's kernels and the engine's programs for a v5e chip
 that is described, not attached (the TPU compiler ships with jaxlib).
 
 Interpret-mode tests (tests/test_ops.py) prove the kernels' arithmetic;
@@ -24,7 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from kubeflow_tpu.ops import flash
 
-# The 188M LM (chip_smoke.py, bench.py's lm preset).
+# The 188M LM (chip_smoke.py).
 LM = {"vocab_size": 32_000, "d_model": 1024, "n_layers": 12, "n_heads": 8,
       "n_kv_heads": 8, "d_ff": 2816, "head_dim": 128, "max_seq_len": 2048,
       "dtype": "bfloat16"}
@@ -155,16 +155,6 @@ def _fits(compiled, gib=16):
     live = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     return live < gib * 2 ** 30
-
-
-def test_engine_decode_step_compiles(engine_shapes):
-    from kubeflow_tpu.models.generate import decode_step
-
-    e = engine_shapes
-    compiled = decode_step.lower(
-        e["cfg"], e["params"], e["state"], e["decode"], 1,
-        e["arg"](e["slots"], e["table_blocks"])).compile()
-    assert _fits(compiled)
 
 
 def test_engine_prefill_chunk_compiles(engine_shapes):
