@@ -389,14 +389,43 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
 
     # ONE scan over the cfg.kv_planes cache planes, step-major: plane p
     # is layer p % n_layers of loop step p // n_layers, and the body
-    # indexes the stacked weights (and adapters) by that layer, which is
-    # what riding them as xs compiles to.  A scan per loop step would
-    # slice a quarter of the pool out and write it back on top.
+    # indexes the stacked weights (and adapters) by that layer.  A scan
+    # per loop step would slice a quarter of the pool out and write it
+    # back on top.
+    #
+    # A leaf with ONE matrix a layer (attn/wq, attn/wo, mlp/wo) is read
+    # by its matmul where it lies in the stack.  A leaf with a PAIR a
+    # layer (mlp/wi [L, 2, e, f]: gate, up; attn/wkv [L, 2, e, hkv, d])
+    # was not while the body took w[layer] and _layer_step [0] / [1] of
+    # it: the compiler copied the pair out once a layer, before the
+    # matmuls (Mistral-7B's 235 MB out to HBM and back, smaller pairs
+    # into the chip's fast memory: PERF.md section 6, PR 31).  So the
+    # body hands over each matrix of a pair as its own read, at
+    # 2 * layer + c of the leaf's first two axes taken as one: a bitcast
+    # and a slice with one consumer, as for the single-matrix leaves
+    # (_layer_step takes [0] / [1] of the tuple as it did of the array).
+    # Storage and the dots are unchanged.
     def step(x, cache_kv, plane):
         layer = plane % cfg.n_layers
+
+        def at(w, i):
+            return jax.lax.dynamic_index_in_dim(w, i, keepdims=False)
+
+        def pair(w):  # [L, 2, ...] array or QTensor -> (w[l, 0], w[l, 1])
+            return tuple(
+                jax.tree_util.tree_map(
+                    lambda a: at(a.reshape((-1,) + a.shape[2:]),
+                                 2 * layer + c), w)
+                for c in range(2))
+
         layer_params, ad = jax.tree_util.tree_map(
-            lambda w: jax.lax.dynamic_index_in_dim(w, layer, keepdims=False),
-            (layer_stack, adapter_stack))
+            lambda w: at(w, layer), (layer_stack, adapter_stack))
+        layer_params = dict(
+            layer_params,
+            attn=dict(layer_params["attn"],
+                      wkv=pair(layer_stack["attn"]["wkv"])),
+            mlp=dict(layer_params["mlp"],
+                     wi=pair(layer_stack["mlp"]["wi"])))
         x, cache_kv = _layer_step(
             cfg, layer_params, x, cache_kv, cache_len, positions,
             pad_amount=pad_amount, write_cols=write_cols,

@@ -746,3 +746,136 @@ class TestPoolCarriedInPlace:
                 (a != b).reshape(a.shape[0], a.shape[1], -1), axis=-1)
             assert changed[:, row].all() and changed.sum() \
                 == cfg.kv_planes * 2
+
+
+# What the PARENT's programs gave for TestStackedPairsReadInPlace (commit
+# a2e38cf: the layer scan sliced one layer's ``mlp/wi`` [2, e, f] and
+# ``attn/wkv`` [2, e, hkv, d] out of the stack and took [0] / [1] of the
+# copy), on this suite's CPU backend.  Reading each matrix of the pair
+# where it lies feeds the same dots the same operands.
+_PARENT_PAIRS = {
+    "int8-weights": {
+        "first": [62, 127], "round": [[62, 62, 64, 64], [127, 127, 127, 127]],
+        "chunk_logits": [
+            -0.09711797, 0.01449957, -0.007245621,
+            -0.04465917, -0.0009560251, 0.06132012],
+        "step_logits": [
+            [-0.08817986, -0.05842119, -0.1803328,
+             -0.08834793, 0.07048687, 0.1008183],
+            [-0.01559237, 0.02670428, 0.118,
+             0.2855819, -0.01710831, 0.04240373]],
+    },
+    "adapters": {
+        "first": [62, 127], "round": [[62, 62, 62, 62], [127, 127, 127, 127]],
+        "chunk_logits": [
+            -0.09201603, -0.002648324, -0.03161668,
+            -0.05473974, 0.01275305, 0.05538537],
+        "step_logits": [
+            [-0.0824753, -0.06748873, -0.1865508,
+             -0.09866641, 0.07318876, 0.09117322],
+            [-0.01818722, 0.01004753, 0.1023682,
+             0.2816063, -0.01172643, 0.03893422]],
+    },
+    "looped-3x2": {
+        "first": [36, 127], "round": [[36, 36, 36, 36], [127, 127, 42, 42]],
+        "chunk_logits": [
+            0.2088994, -0.1669596, -0.0337302,
+            -0.26163, -0.02251597, -0.04253511],
+        "step_logits": [
+            [0.1727304, -0.1478264, -0.02805489,
+             -0.261128, -0.002452213, -0.01993862],
+            [-0.1085904, 0.1173044, 0.08726891,
+             0.2103384, 0.002443834, -0.08984765]],
+    },
+}
+
+
+class TestStackedPairsReadInPlace:
+    """models/generate.py hands a layer's ``mlp/wi`` and ``attn/wkv`` to
+    their matmuls as two single-matrix reads of the stacked leaf, at
+    ``2 * layer + c`` of its first two axes taken as one.  Invisible in
+    tokens and logits where no benchmark cell looks: int8 weights (a
+    ``QTensor``'s values and scale are indexed in step), an adapter
+    stack (its factors keep their own [:, 0] / [:, 1]), and a looped
+    stack whose planes outnumber its layers (the pair's index comes from
+    ``plane % n_layers``, never from the plane)."""
+
+    CASES = ("int8-weights", "adapters", "looped-3x2")
+
+    @staticmethod
+    def build(spec, case):
+        from kubeflow_tpu.ops.quantize import quantize_params
+        from kubeflow_tpu.serving import adapters
+
+        if case == "looped-3x2":
+            spec = _with_config(spec, n_layers=3, loop_steps=2,
+                                sandwich_norm=True)
+        cfg, params = spec["cfg"], dict(spec["params"])
+        if case == "int8-weights":
+            params = quantize_params(params)
+        if case == "adapters":
+            stack = adapters.init_adapter_stack(cfg, 3, 2)  # row 0: base
+            for row in (1, 2):
+                factors = adapters.random_adapter_factors(cfg, 2, SEED + row)
+                for grp, leaves in factors.items():
+                    for k, v in leaves.items():
+                        stack[grp][k][row] = v
+            params["adapters"] = stack
+        return cfg, params, spec["decode"]
+
+    @staticmethod
+    def run(cfg, params, decode):
+        """Two slots by hand through both engine programs: a chunk of 8
+        columns each (adapter rows 1 and 2), then a round of 4 steps;
+        the logits of a chunk and of a decode step come from the same
+        forward the programs wrap."""
+        import jax
+        import jax.numpy as jnp
+
+        from kubeflow_tpu.models import generate as gen
+
+        slots, nb, bt = 2, 8, 4
+        rng = np.random.RandomState(SEED + 31)
+        prompts = jnp.asarray(rng.randint(1, VOCAB, size=(slots, 8)),
+                              jnp.int32)
+        tables = jnp.arange(nb, dtype=jnp.int32).reshape(slots, -1)
+        ids = jnp.arange(1, slots + 1, dtype=jnp.int32)
+        forward = jax.jit(gen._forward_with_cache, static_argnums=0)
+
+        state = gen.init_paged_state(cfg, slots, nb, bt)
+        chunk_logits, _ = forward(
+            cfg, params, prompts[:1], (state["cache_k"], state["cache_v"]),
+            jnp.int32(0), tables=tables[:1], adapter_ids=ids[:1])
+        first = []
+        for slot in range(slots):
+            state, tok = gen.prefill_chunk_into_slot(
+                cfg, params, state, decode, prompts[slot:slot + 1],
+                jnp.int32(0), jnp.int32(8 - 2 * slot), jnp.int32(6),
+                jnp.int32(slot), jnp.int32(7), tables[slot:slot + 1],
+                ids[slot])
+            first.append(int(tok[0]))
+        step_logits, _ = forward(
+            cfg, params, state["last_token"][:, None],
+            (state["cache_k"], state["cache_v"]), state["lengths"],
+            write_cols=state["lengths"], tables=tables,
+            adapter_ids=state["adapter_ids"])
+        state, toks, counts, steps = gen.decode_rounds(
+            cfg, params, state, decode, 4, tables, jnp.int32(4))
+        assert int(steps) == 4
+        return {
+            "first": first,
+            "round": [np.asarray(toks)[s, :int(counts[s])].tolist()
+                      for s in range(slots)],
+            "chunk_logits": np.asarray(chunk_logits)[0, -1, :6].tolist(),
+            "step_logits": np.asarray(step_logits)[:, 0, :6].tolist(),
+        }
+
+    @pytest.mark.parametrize("case", CASES)
+    def test_tokens_and_logits_are_the_parents(self, engine_model, case):
+        got = self.run(*self.build(engine_model[0], case))
+        want = _PARENT_PAIRS[case]
+        assert got["first"] == want["first"]
+        assert got["round"] == want["round"]
+        for key in ("chunk_logits", "step_logits"):
+            np.testing.assert_allclose(got[key], want[key], rtol=2e-5,
+                                       atol=2e-5, err_msg=key)

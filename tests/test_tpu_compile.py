@@ -299,3 +299,91 @@ def test_engine_programs_update_the_pool_in_place(cell_program, name,
     # Donated and aliased: the pool that comes in is the pool that goes out.
     assert m.alias_size_in_bytes >= 2 * side
     assert _fits(compiled)
+
+
+def _layer_pair_shapes(widths):
+    """One layer's ``mlp/wi`` and ``attn/wkv``, whole and one of the pair."""
+    e, f = widths["d_model"], widths["d_ff"]
+    hkv, d = widths["n_kv_heads"], widths["head_dim"]
+    return [(2, e, f), (e, f), (2, e, hkv, d), (e, hkv, d)]
+
+
+def _weight_moves(text, shapes):
+    """Instructions of an HLO module, outside its fused computations,
+    whose result has one of ``shapes`` (in any layout and memory space):
+    an array of a layer's weight that the program PRODUCES, in HBM or in
+    the chip's fast memory (``S(1)``), before a matmul reads it.  The
+    loops' own tuple elements and parameters move nothing."""
+    import re
+
+    dims = "|".join(",".join(str(n) for n in s) for s in shapes)
+    shaped = re.compile(
+        rf"%(\S+) = \(?\w+\[(?:{dims})\](?:\{{[^}}]*\}})? ([\w-]+)\(")
+    found, fused = [], False
+    for line in text.splitlines():
+        if line.startswith("%fused_computation"):
+            fused = True
+        elif line.startswith("}"):
+            fused = False
+        elif not fused:
+            m = shaped.search(line)
+            if m and m.group(2) not in ("get-tuple-element", "parameter"):
+                found.append(m.group(1))
+    return found
+
+
+def test_weight_moves_finds_the_scans_slices_of_a_pair():
+    """The texts are the parent's (PR 30's programs, compiled for the
+    described v5e; operands shortened): the slice of Mistral's ``mlp/wi``
+    went to HBM, those of InternLM2's and of every ``attn/wkv`` to the fast
+    memory; a matmul's ``[slots, f]`` result, the relayout of a whole
+    stack and what a fusion holds inside are not such moves."""
+    tile = "T(8,128)(2,1)"
+    hbm, fast = f"{{2,1,0:{tile}}}", f"{{2,1,0:{tile}S(1)}}"
+    text = f"""
+%fused_computation.4.clone (p0: bf16[16,2,4096,14336]) -> bf16[2,4096,14336] {{
+  %dynamic_slice.88 = bf16[1,2,4096,14336]{{3,2,1,0:{tile}}} dynamic-slice(%p0, %p1)
+  ROOT %bitcast.178 = bf16[2,4096,14336]{hbm} bitcast(%dynamic_slice.88)
+}}
+
+%wide.region_3.28.clone (arg: (s32[], bf16[6,1,4096])) -> (s32[], bf16[6,1,4096]) {{
+  %get-tuple-element.9 = bf16[2,4096,14336]{hbm} get-tuple-element(%w), index=2
+  %dynamic-slice_bitcast_fusion.18 = bf16[2,4096,8,128]{{3,1,2,0:{tile}S(1)}} fusion(
+  %fusion.138 = bf16[6,14336]{{1,0:{tile}S(1)}} fusion(%get-tuple-element.1392, %i)
+  %dynamic-slice_bitcast_fusion.19 = bf16[2,4096,14336]{hbm} fusion(%gte.1392, %i)
+  %fusion.137 = bf16[6,14336]{{1,0:{tile}S(1)}} fusion(%dynamic-slice_bitcast_fusion.19)
+  %copy.16 = bf16[16,2,4096,8,128]{{4,2,3,1,0:{tile}}} copy(%wkv)
+  %slice_bitcast_fusion.9 = bf16[4096,14336]{{1,0:{tile}}} fusion(%fusion.19)
+}}
+"""
+    assert _weight_moves(text, _layer_pair_shapes(
+        CELLS["mistral-7b-v0.3-l16"][0])) == [
+        "dynamic-slice_bitcast_fusion.18", "dynamic-slice_bitcast_fusion.19",
+        "slice_bitcast_fusion.9"]
+    text = f"""
+  %dynamic-slice_bitcast_fusion.19 = bf16[2,2048,8192]{fast} fusion(%gte.1410, %i)
+  %fusion.136 = bf16[16,8192]{{1,0:{tile}S(1)}} fusion(%fusion.19, %bitcast.9)
+"""
+    assert _weight_moves(text, _layer_pair_shapes(
+        CELLS["internlm2-1.8b"][0])) == ["dynamic-slice_bitcast_fusion.19"]
+
+
+@pytest.mark.parametrize("program", ["decode_rounds",
+                                     "prefill_chunk_into_slot"])
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_engine_programs_read_stacked_weights_in_place(cell_program, name,
+                                                       program):
+    """A stacked leaf that holds a PAIR a layer (``mlp/wi``: gate, up;
+    ``attn/wkv``: keys, values) reaches its matmuls as their own operand:
+    neither engine program produces an array of the shape of one layer's
+    pair or of one matrix of it.  Sliced out as ``w[layer]`` first, the
+    pair was copied once a layer: Mistral's 235 MB to HBM and back, the
+    smaller ones into ``S(1)`` (``PERF.md`` section 6, PR 31)."""
+    widths = CELLS[name][0]
+    _, compiled = cell_program(name, program)
+    assert _weight_moves(compiled.as_text(),
+                         _layer_pair_shapes(widths)) == []
+    if (name, program) == ("mistral-7b-v0.3-l16", "decode_rounds"):
+        # What stays is the relayout of the stacked wq / wkv once a call
+        # (0.537 + 0.268 GB); the parent's 1.041 GB held the slice too.
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.85e9
