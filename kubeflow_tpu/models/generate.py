@@ -89,10 +89,12 @@ def _rms_norm(x, scale, eps, dtype):
     return (normed * scale).astype(dtype)
 
 
-def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
-                cache_len, positions, pad_amount=None, write_cols=None,
-                tables=None, adapters=None, paged_kernel=False, plane=None):
-    """One decoder block against the KV cache.
+def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
+                     cache_len, positions, pad_amount=None, write_cols=None,
+                     tables=None, adapters=None, paged_kernel=False,
+                     plane=None):
+    """The attention half of a decoder block against the KV cache: norm,
+    projections, cache write, attention, output projection, residual.
 
     x: [b, t, e] new activations (t = prompt len at prefill, 1 at decode);
     cache_kv: (k, v) each [b, max_len, hkv, d], this layer's own — or,
@@ -134,7 +136,8 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
     Wider steps (the prefill chunk, speculative verify), an int8
     ``QTensor`` pool and every other backend keep the view and
     ``dot_product_attention``.
-    Mirrors models/transformer.py Block but with explicit cache state.
+    cache_kv None (a forward without a cache, the flax module's): plain
+    causal attention over the call's own q, k, v.
     """
     attn = layer_params["attn"]
     dt = cfg.dtype
@@ -162,13 +165,19 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                           "bse,ber->bsr", "bsr,brhd->bshd")
             v = v + _lora(y, ad["wkv_a"][:, 1], ad["wkv_b"][:, 1],
                           "bse,ber->bsr", "bsr,brhd->bshd")
+        if cfg.qk_norm:
+            q = norm(q, attn["q_norm"]["scale"])
+            k = norm(k, attn["k_norm"]["scale"])
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
 
-    ck, cv = cache_kv
+    ck, cv = cache_kv if cache_kv is not None else (None, None)
     t = x.shape[1]
     per_row = not isinstance(cache_len, int) and cache_len.ndim == 1
-    if tables is not None:
+    if cache_kv is None:
+        with jax.named_scope("kft.attention"):
+            out = dot_product_attention(q, k, v, causal=True)
+    elif tables is not None:
         vals = ck.values if isinstance(ck, QTensor) else ck
         nb, bt = vals.shape[1], vals.shape[2]
         mb = tables.shape[1]
@@ -273,8 +282,8 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
     # freshly quantized cache, and serving goldens pin that rounding).
     # cache_len is a static python 0 at prefill and a TRACED scalar in
     # the decode scan — the gate must only ever inspect the static case.
-    static_prefill = (tables is None and isinstance(cache_len, int)
-                      and cache_len == 0)
+    static_prefill = (cache_kv is not None and tables is None
+                      and isinstance(cache_len, int) and cache_len == 0)
     if (cfg.attention == "flash" and t > 1 and static_prefill
             and not isinstance(ck, QTensor)):
         from kubeflow_tpu.ops.flash import flash_attention
@@ -285,7 +294,7 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
                 block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
                 kv_valid_start=pad_amount,
             )
-    elif tables is None:
+    elif tables is None and cache_kv is not None:
         with jax.named_scope("kft.attention"):
             out = dot_product_attention(
                 q, ck, cv, causal=True, kv_offset=cache_len,
@@ -301,6 +310,16 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
             with jax.named_scope("kft.loop_norm"):
                 y = norm(y, layer_params["attn_out_norm"]["scale"])
         x = x + y
+    return x, (None if cache_kv is None else (ck, cv))
+
+
+def _dense_ff(cfg: TransformerConfig, layer_params, x, adapters=None):
+    """The block's SwiGLU feed-forward with its norm and residual."""
+    dt = cfg.dtype
+
+    def norm(x, scale):
+        return _rms_norm(x, scale, cfg.norm_eps, dt)
+
     with jax.named_scope("kft.mlp"):
         y = norm(x, layer_params["mlp_norm"]["scale"])
         mlp = layer_params["mlp"]
@@ -322,7 +341,209 @@ def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
             with jax.named_scope("kft.loop_norm"):
                 y = norm(y, layer_params["mlp_out_norm"]["scale"])
         x = x + y
-    return x, (ck, cv)
+    return x
+
+
+def _layer_step(cfg: TransformerConfig, layer_params, x, cache_kv,
+                cache_len, positions, pad_amount=None, write_cols=None,
+                tables=None, adapters=None, paged_kernel=False, plane=None):
+    """One decoder block against the KV cache: ``_attention_block``,
+    then ``_dense_ff`` (models/transformer.py Block with explicit cache
+    state)."""
+    x, cache_kv = _attention_block(
+        cfg, layer_params, x, cache_kv, cache_len, positions,
+        pad_amount=pad_amount, write_cols=write_cols, tables=tables,
+        adapters=adapters, paged_kernel=paged_kernel, plane=plane)
+    return _dense_ff(cfg, layer_params, x, adapters), cache_kv
+
+
+def _conv_block(cfg: TransformerConfig, layer_params, x, state=None,
+                rows=None, fresh=None, n_new=None):
+    """The gated short convolution of a ``conv`` layer (TransformerConfig.
+    layer_types), with its norm and residual, against its per-sequence
+    state.
+
+    x: [b, t, e].  state: this layer's [S, K - 1, e], the last K - 1
+    columns of u = B * h of every slot's sequence, or None (a forward
+    without a cache: every row starts from zeros and nothing is kept).
+    rows [b]: the slot of each row of x; None = row i is slot i (S = b).
+    fresh [b] bool: the row's sequence starts in this call, from zeros,
+    whatever its slot held.  n_new [b]: how many of the row's t columns
+    are real; the state kept is that of the last real one, so 0 leaves a
+    row's state as it was (a slot that is parked, or in mid-prefill
+    while the others decode) and a right-padded final chunk keeps the
+    state of the prompt's last token.  Returns (x, state).
+    """
+    dt, taps = cfg.dtype, cfg.conv_kernel
+    conv = layer_params["conv"]
+    t = x.shape[1]
+    with jax.named_scope("kft.short_conv"):
+        y = _rms_norm(x, layer_params["conv_norm"]["scale"], cfg.norm_eps,
+                      dt)
+        bch = qeinsum("bse,ecf->bscf", y, conv["w_in"], dt)
+        u = bch[:, :, 0] * bch[:, :, 2]
+    with jax.named_scope("kft.conv_state"):
+        if state is None:
+            prev = jnp.zeros((x.shape[0], taps - 1, x.shape[2]), dt)
+        else:
+            prev = state if rows is None else state[rows]
+            if fresh is not None:
+                prev = jnp.where(fresh[:, None, None], 0, prev)
+        ext = jnp.concatenate([prev.astype(dt), u], axis=1)
+        if state is not None:
+            keep = n_new[:, None] + jnp.arange(taps - 1)[None, :]
+            kept = jnp.take_along_axis(
+                ext, keep[:, :, None], axis=1).astype(state.dtype)
+            state = kept if rows is None else state.at[rows].set(kept)
+    with jax.named_scope("kft.short_conv"):
+        w = conv["w_conv"].astype(jnp.float32)
+        c = sum(w[i] * ext[:, i:i + t].astype(jnp.float32)
+                for i in range(taps)).astype(dt)
+        x = x + qeinsum("bse,ef->bsf", bch[:, :, 1] * c, conv["w_out"], dt)
+    return x, state
+
+
+def _sparse_ff(cfg: TransformerConfig, layer_params, x, live=None):
+    """Sparse experts in the feed-forward's place (TransformerConfig.
+    layer_types), with norm and residual; nothing is dropped.
+
+    Every (row, chosen expert) pair is sorted by expert and the experts'
+    SwiGLUs run as two grouped products (``jax.lax.ragged_dot``) over
+    the stacked expert matrices where they lie: a row meets only the
+    experts it chose, and an expert no row chose is not read.
+    ``moe/wi`` is [experts, e, 2 f], gate then up along the last axis.
+    live [b, t] bool (None: all): rows that are no token (a parked slot,
+    a final chunk's padding) choose nothing and come back unchanged.
+    Returns (x, distinct experts with at least one row).
+    """
+    dt, n, k, f = cfg.dtype, cfg.moe_experts, cfg.moe_top_k, cfg.moe_d_ff
+    moe = layer_params["moe"]
+    b, t, e = x.shape
+    with jax.named_scope("kft.mlp"):
+        y = _rms_norm(x, layer_params["mlp_norm"]["scale"], cfg.norm_eps,
+                      dt).reshape(b * t, e)
+        with jax.named_scope("kft.moe_route"):
+            # In float32 whatever the model computes in: a score that
+            # rounding moves past another changes a whole expert.
+            scores = jax.nn.sigmoid(jnp.einsum(
+                "me,en->mn", y.astype(jnp.float32),
+                moe["router"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            # The bias selects and does not weigh.
+            _, chosen = jax.lax.top_k(scores + moe["bias"], k)
+            picked = jnp.take_along_axis(scores, chosen, axis=1)
+            gates = picked / (picked.sum(axis=1, keepdims=True) + 1e-6)
+            pairs = chosen.reshape(-1)
+            if live is not None:
+                # Past every expert: sorted last, in no group.
+                pairs = jnp.where(jnp.repeat(live.reshape(-1), k), pairs, n)
+            order = jnp.argsort(pairs)
+            sizes = jnp.zeros((n,), jnp.int32).at[pairs].add(1, mode="drop")
+            rows = y[order // k]
+        with jax.named_scope("kft.moe_experts"):
+            h = jax.lax.ragged_dot(rows, moe["wi"].astype(dt), sizes)
+            h = jax.nn.silu(h[:, :f]) * h[:, f:]
+            out = jax.lax.ragged_dot(h, moe["wo"].astype(dt), sizes)
+        with jax.named_scope("kft.moe_route"):
+            out = out[jnp.argsort(order)].reshape(b * t, k, e)
+            if live is not None:
+                # A row in no group is not written by the grouped product.
+                out = jnp.where(live.reshape(-1, 1, 1), out, 0)
+            y = jnp.sum(out.astype(jnp.float32) * gates[:, :, None], axis=1)
+        x = x + y.astype(dt).reshape(b, t, e)
+    return x, jnp.sum(sizes > 0).astype(jnp.int32)
+
+
+def _embed_tokens(cfg: TransformerConfig, params, tokens, cache_len,
+                  pad_amount=None):
+    """tokens [b, t] -> (x [b, t, e], rope positions [b, t]); see
+    ``_forward_with_cache`` for ``cache_len`` and ``pad_amount``."""
+    with jax.named_scope("kft.embed"):
+        x = embed_lookup(params["embed"], tokens, cfg.dtype)  # int8-aware
+    per_row = not isinstance(cache_len, int) and cache_len.ndim == 1
+    if per_row:
+        positions = (cache_len[:, None]
+                     + jnp.arange(tokens.shape[1])[None, :])
+    else:
+        positions = cache_len + jnp.arange(tokens.shape[1])[None, :]
+        positions = jnp.broadcast_to(positions, tokens.shape)
+    if pad_amount is not None:
+        # Left-padded rows: real token i of a row sits at buffer column
+        # pad + i but must see rope position i.  Pad columns clamp to 0
+        # — their keys are masked from every attention anyway.
+        positions = jnp.maximum(positions - pad_amount[:, None], 0)
+    return x, positions
+
+
+def _final_norm(cfg: TransformerConfig, params, x):
+    return _rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps,
+                     cfg.dtype)
+
+
+def _logits(cfg: TransformerConfig, params, x):
+    dt = cfg.dtype
+    with jax.named_scope("kft.logits"):
+        x = _final_norm(cfg, params, x)
+        if cfg.tied_embeddings:
+            logits = qeinsum("bse,ve->bsv", x, params["embed"], dt)
+        else:
+            logits = qeinsum("bse,ev->bsv", x, params["w_out"], dt)
+        return logits.astype(jnp.float32)
+
+
+def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
+                        cache_len=0, write_cols=None, tables=None,
+                        paged_kernel=False, conv=None, rows=None,
+                        fresh=None, n_new=None):
+    """The forward of a stack that states its ``layer_types``
+    (TransformerConfig): tokens [b, t] -> (logits [b, t, v], cache,
+    conv, experts touched).
+
+    The layers are walked one by one over ``params["layers"][str(i)]``:
+    no two need have the same leaves, every matrix is an array of its
+    own that its product reads where it lies, and the attention layers
+    alone own planes of the paged pool (plane j is the j-th of them).
+    ``cache`` is the stacked pool (k, v) that the serving programs carry
+    and donate, ``tables`` their block tables, ``cache_len`` /
+    ``write_cols`` / ``paged_kernel`` as in ``_forward_with_cache``;
+    ``conv`` is the [conv layers, slots, K - 1, e] state of the
+    convolution layers with ``rows`` / ``fresh`` / ``n_new`` as in
+    ``_conv_block``.  Rows with ``n_new`` 0 and columns at or past
+    ``n_new`` are no tokens: they choose no expert.  Without ``cache``
+    and ``conv`` this is the plain forward of the whole sequence (the
+    flax module's).  The last value counts, over the sparse layers, the
+    distinct experts that got a row.
+    """
+    from flax import linen as nn
+
+    params = nn.unbox(params)
+    x, positions = _embed_tokens(cfg, params, tokens, cache_len)
+    live = None if n_new is None else (
+        jnp.arange(tokens.shape[1])[None, :] < n_new[:, None])
+    plane = plane_c = 0
+    touched = jnp.zeros((), jnp.int32)
+    for i, kind in enumerate(cfg.layer_types):
+        layer_params = params["layers"][str(i)]
+        if kind == "conv":
+            x, state = _conv_block(
+                cfg, layer_params, x,
+                None if conv is None else conv[plane_c], rows, fresh, n_new)
+            if conv is not None:
+                with jax.named_scope("kft.conv_state"):
+                    conv = conv.at[plane_c].set(state)
+            plane_c += 1
+        else:
+            x, cache = _attention_block(
+                cfg, layer_params, x, cache, cache_len, positions,
+                write_cols=write_cols, tables=tables,
+                paged_kernel=paged_kernel, plane=plane)
+            plane += 1
+        if cfg.layer_is_sparse(i):
+            x, n = _sparse_ff(cfg, layer_params, x, live)
+            touched = touched + n
+        else:
+            x = _dense_ff(cfg, layer_params, x)
+    return _logits(cfg, params, x), cache, conv, touched
 
 
 def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
@@ -351,23 +572,13 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
     """
     from flax import linen as nn
 
+    if cfg.layer_types:
+        raise ValueError(
+            "a stack with layer_types runs in the serving engine's "
+            "programs (forward_layer_types), not here")
     params = nn.unbox(params)  # accept raw model.init output
     dt = cfg.dtype
-    embed = params["embed"]
-    with jax.named_scope("kft.embed"):
-        x = embed_lookup(embed, tokens, dt)  # int8-aware row gather
-    per_row = not isinstance(cache_len, int) and cache_len.ndim == 1
-    if per_row:
-        positions = (cache_len[:, None]
-                     + jnp.arange(tokens.shape[1])[None, :])
-    else:
-        positions = cache_len + jnp.arange(tokens.shape[1])[None, :]
-        positions = jnp.broadcast_to(positions, tokens.shape)
-    if pad_amount is not None:
-        # Left-padded rows: real token i of a row sits at buffer column
-        # pad + i but must see rope position i.  Pad columns clamp to 0
-        # — their keys are masked from every attention anyway.
-        positions = jnp.maximum(positions - pad_amount[:, None], 0)
+    x, positions = _embed_tokens(cfg, params, tokens, cache_len, pad_amount)
 
     layer_stack = params["layers"]
     adapter_stack = None
@@ -385,7 +596,7 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
                 dict(params["adapters"]))
 
     def final_norm(x):
-        return _rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps, dt)
+        return _final_norm(cfg, params, x)
 
     # ONE scan over the cfg.kv_planes cache planes, step-major: plane p
     # is layer p % n_layers of loop step p // n_layers, and the body
@@ -471,14 +682,7 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
 
         x, cache = jax.lax.scan(body, x, (planes, *cache))
 
-    with jax.named_scope("kft.logits"):
-        x = final_norm(x)
-        if cfg.tied_embeddings:
-            logits = qeinsum("bse,ve->bsv", x, embed, dt)
-        else:
-            logits = qeinsum("bse,ev->bsv", x, params["w_out"], dt)
-        logits = logits.astype(jnp.float32)
-    return logits, tuple(cache)
+    return _logits(cfg, params, x), tuple(cache)
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
@@ -690,10 +894,26 @@ def init_paged_state(cfg: TransformerConfig, slots: int,
     the params tree carries no adapter stack).  Block tables are NOT
     device state: the host owns them and passes the current snapshot
     into every program call.
+
+    A stack with ``layer_types`` (TransformerConfig) adds what its
+    layers keep per SLOT, of fixed size, beside the pool: ``conv``
+    [conv layers, slots, conv_kernel - 1, e], the last columns of each
+    convolution layer's gated input (a slot's first chunk starts from
+    zeros whatever is there), and, with sparse experts, the scalar
+    ``moe_touched``: the distinct experts that got a row, summed over
+    the sparse layers and the steps of the LAST decode_rounds call.
     """
     cache_k, cache_v = init_cache(cfg, num_blocks, block_tokens,
                                   kv_cache_dtype)
+    extra = {}
+    if cfg.conv_planes:
+        extra["conv"] = jnp.zeros(
+            (cfg.conv_planes, slots, cfg.conv_kernel - 1, cfg.d_model),
+            cfg.dtype)
+    if cfg.layer_types and cfg.moe_experts:
+        extra["moe_touched"] = jnp.zeros((), jnp.int32)
     return {
+        **extra,
         "cache_k": cache_k,
         "cache_v": cache_v,
         "lengths": jnp.zeros((slots,), jnp.int32),
@@ -787,11 +1007,27 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
     # Retired slots park their write past the table span; the
     # block scatter drops it.
     write_cols = jnp.where(advance, lengths, park)
-    logits, (ck, cv) = _forward_with_cache(
-        cfg, params, state["last_token"][:, None],
-        (state["cache_k"], state["cache_v"]), lengths,
-        write_cols=write_cols, tables=tables,
-        adapter_ids=state.get("adapter_ids"), paged_kernel=paged_kernel)
+    if cfg.layer_types:
+        # Only live rows are tokens: a parked slot, or one in
+        # mid-prefill, keeps its convolution state and chooses no expert.
+        logits, (ck, cv), conv, touched = forward_layer_types(
+            cfg, params, state["last_token"][:, None],
+            (state["cache_k"], state["cache_v"]), lengths,
+            write_cols=write_cols, tables=tables,
+            paged_kernel=paged_kernel, conv=state.get("conv"),
+            n_new=advance.astype(jnp.int32))
+        state = dict(state)
+        if conv is not None:
+            state["conv"] = conv
+        if "moe_touched" in state:
+            state["moe_touched"] = state["moe_touched"] + touched
+    else:
+        logits, (ck, cv) = _forward_with_cache(
+            cfg, params, state["last_token"][:, None],
+            (state["cache_k"], state["cache_v"]), lengths,
+            write_cols=write_cols, tables=tables,
+            adapter_ids=state.get("adapter_ids"),
+            paged_kernel=paged_kernel)
     with jax.named_scope("kft.sample"):
         last = logits[:, -1]
         if decode.temperature <= 0.0:
@@ -853,6 +1089,8 @@ def decode_rounds(cfg: TransformerConfig, params, state,
     park = tables.shape[1] * _pool_block_tokens(state["cache_k"])
     slots = state["done"].shape[0]
     len0 = state["lengths"]
+    if "moe_touched" in state:
+        state = dict(state, moe_touched=jnp.zeros((), jnp.int32))
     cap = jnp.minimum(jnp.asarray(max_steps, jnp.int32),
                       jnp.int32(k))
 
@@ -913,6 +1151,11 @@ def verify_step(cfg: TransformerConfig, params, state,
     park their writes out of range and emit 0 tokens, exactly like
     a decode step.
     """
+    if cfg.layer_types:
+        raise ValueError(
+            "verify_step rolls a rejected draft back by not moving a "
+            "frontier; a convolution state has no frontier to leave "
+            "behind (speculation over a slot state: not built)")
     lengths, done = state["lengths"], state["done"]
     park = tables.shape[1] * _pool_block_tokens(state["cache_k"])
     advance = ~done
@@ -1026,9 +1269,21 @@ def prefill_chunk_into_slot(
     w = tokens.shape[1]
     aid = (jnp.zeros((), jnp.int32) if adapter_id is None
            else jnp.reshape(jnp.asarray(adapter_id, jnp.int32), ()))
-    logits, (ck, cv) = _forward_with_cache(
-        cfg, params, tokens, (state["cache_k"], state["cache_v"]),
-        start, tables=table_row, adapter_ids=aid[None])
+    conv = None
+    if cfg.layer_types:
+        # The slot's first chunk starts its convolution layers from
+        # zeros; a later one goes on from the state the last left, and
+        # each leaves the state of its last real token.
+        first = jnp.reshape(start == 0, (1,))
+        real = jnp.reshape(jnp.clip(prompt_len - start, 0, w), (1,))
+        logits, (ck, cv), conv, _ = forward_layer_types(
+            cfg, params, tokens, (state["cache_k"], state["cache_v"]),
+            start, tables=table_row, conv=state.get("conv"),
+            rows=jnp.reshape(slot, (1,)), fresh=first, n_new=real)
+    else:
+        logits, (ck, cv) = _forward_with_cache(
+            cfg, params, tokens, (state["cache_k"], state["cache_v"]),
+            start, tables=table_row, adapter_ids=aid[None])
     with jax.named_scope("kft.sample"):
         # First-token sampling from the last REAL prompt position of this
         # chunk (only meaningful on the final chunk; clamped otherwise).
@@ -1055,6 +1310,8 @@ def prefill_chunk_into_slot(
 
         state = dict(state)
         state["cache_k"], state["cache_v"] = ck, cv
+        if conv is not None:
+            state["conv"] = conv
         if "adapter_ids" in state:
             state["adapter_ids"] = state["adapter_ids"].at[slot].set(aid)
         state["done"] = state["done"].at[slot].set(True)

@@ -145,9 +145,52 @@ class TransformerConfig:
     # Sandwich norms: a second RMSNorm on the OUTPUT of each branch
     # (``attn_out_norm``, ``mlp_out_norm``), before the residual add.
     sandwich_norm: bool = False
+    # A stack whose layers differ (LFM2's ``layer_types``): one entry a
+    # layer, "full_attention" or "conv"; empty = every layer is the
+    # attention block above.  A stack that states its layer types is
+    # walked layer by layer over a tree with one entry a layer
+    # (``params["layers"][str(i)]``, shapes in
+    # ``layer_tree_shapes`` below) by the serving programs,
+    # and only by them: training and generate() do not take it.
+    #   "conv": a gated short convolution in the attention's place,
+    #     [B, C, h] = y W_in; u = B * h; c_t = sum_i w_i u_{t-K+1+i}
+    #     (depthwise, causal, ``conv_kernel`` = K taps, no bias);
+    #     out = (C * c) W_out.  Its cache is the last K - 1 columns of u
+    #     per sequence, of fixed size beside the paged KV pool, and only
+    #     the attention layers own a KV plane (``kv_planes``).
+    #   The feed-forward of such a stack: layers before
+    #     ``moe_dense_layers`` keep the SwiGLU of ``d_ff``; with
+    #     ``moe_experts`` > 0 the others hold that many SwiGLU experts of
+    #     ``moe_d_ff`` and send each token to ``moe_top_k`` of them,
+    #     nothing dropped: scores s = sigmoid(y W_r) in float32, the
+    #     choice by s + bias (``moe/bias`` selects and does not weigh),
+    #     weights s_i / (sum of the chosen s + 1e-6).
+    #   ``qk_norm``: an RMSNorm over each head of q and of k, scales of
+    #     their own, before the rotary positions.
+    layer_types: Tuple[str, ...] = ()
+    conv_kernel: int = 3
+    qk_norm: bool = False
+    moe_dense_layers: int = 0
+    moe_d_ff: int = 0
 
     def __post_init__(self):
         assert self.n_heads % self.n_kv_heads == 0
+        # JSON hands a list over; the config is a static (hashed) argument.
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            unknown = set(self.layer_types) - {"full_attention", "conv"}
+            if unknown or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types must name n_layers={self.n_layers} "
+                    f"layers as 'full_attention' or 'conv', got "
+                    f"{self.layer_types}")
+            if self.loop_steps > 1 or self.sandwich_norm:
+                raise ValueError(
+                    "layer_types with loop_steps > 1 or sandwich_norm: "
+                    "not built")
+            if self.conv_kernel < 2:
+                raise ValueError(
+                    f"conv_kernel={self.conv_kernel} must be >= 2")
         if self.loop_steps < 1:
             raise ValueError(f"loop_steps={self.loop_steps} must be >= 1")
         if self.loop_steps > 1 and self.pipeline_microbatches:
@@ -186,8 +229,21 @@ class TransformerConfig:
 
     @property
     def kv_planes(self) -> int:
-        """Leading axis of a KV cache: one plane per (loop step, layer)."""
+        """Leading axis of a KV cache: one plane per (loop step, layer),
+        of the layers that attend."""
+        if self.layer_types:
+            return self.layer_types.count("full_attention")
         return self.loop_steps * self.n_layers
+
+    @property
+    def conv_planes(self) -> int:
+        """Layers that keep a convolution state per sequence."""
+        return self.layer_types.count("conv")
+
+    def layer_is_sparse(self, layer: int) -> bool:
+        """Whether ``layer`` of a stack with ``layer_types`` holds
+        experts (else the dense SwiGLU)."""
+        return self.moe_experts > 0 and layer >= self.moe_dense_layers
 
     def flops_per_token(self) -> float:
         """Forward useful FLOPs per token (2*params matmul convention +
@@ -206,6 +262,63 @@ class TransformerConfig:
         attn = 2 * 2 * self.kv_planes * self.n_heads * self.head_dim \
             * self.max_seq_len  # qk^T + av, causal halving ignored
         return float(matmul + attn)
+
+
+def layer_tree_shapes(cfg: TransformerConfig):
+    """The parameter tree of a stack with ``layer_types``, as nested
+    {name: shape}: one entry a layer under ``layers`` (no two need agree,
+    so nothing is stacked), each matrix a leaf of its own."""
+    e, d, n = cfg.d_model, cfg.head_dim, cfg.moe_experts
+    f = cfg.moe_d_ff or cfg.d_ff
+    norm = {"scale": (e,)}
+    tree = {"embed": (cfg.vocab_size, e), "final_norm": norm, "layers": {}}
+    if not cfg.tied_embeddings:
+        tree["w_out"] = (e, cfg.vocab_size)
+    for i, kind in enumerate(cfg.layer_types):
+        layer = {"mlp_norm": norm}
+        if kind == "conv":
+            layer["conv_norm"] = norm
+            layer["conv"] = {"w_in": (e, 3, e),
+                             "w_conv": (cfg.conv_kernel, e),
+                             "w_out": (e, e)}
+        else:
+            layer["attn_norm"] = norm
+            layer["attn"] = {"wq": (e, cfg.n_heads, d),
+                             "wkv": (2, e, cfg.n_kv_heads, d),
+                             "wo": (cfg.n_heads, d, e)}
+            if cfg.qk_norm:
+                layer["attn"].update(q_norm={"scale": (d,)},
+                                     k_norm={"scale": (d,)})
+        if cfg.layer_is_sparse(i):
+            layer["moe"] = {"router": (e, n), "bias": (n,),
+                            "wi": (n, e, 2 * f), "wo": (n, f, e)}
+        else:
+            layer["mlp"] = {"wi": (2, e, cfg.d_ff), "wo": (cfg.d_ff, e)}
+        tree["layers"][str(i)] = layer
+    return tree
+
+
+def _hold(module: nn.Module, shapes):
+    """Declare a nested {name: shape} of parameters on ``module`` (from
+    inside its compact call) and hand them back as the same nesting of
+    arrays: matrices lecun-normal, ``scale`` ones, ``bias`` zeros."""
+    out = {}
+    for name, shape in shapes.items():
+        if isinstance(shape, tuple):
+            make = {"scale": init.ones_init(),
+                    "bias": init.zeros_init()}.get(name, kernel_init)
+            out[name] = module.param(name, make, shape, jnp.float32)
+        else:
+            out[name] = _Leaves(shape, name=name)()
+    return out
+
+
+class _Leaves(nn.Module):
+    shapes: Any
+
+    @nn.compact
+    def __call__(self):
+        return _hold(self, self.shapes)
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -443,6 +556,21 @@ class Transformer(nn.Module):
         return_hidden: bool = False,
     ) -> "jax.Array | Tuple[jax.Array, jax.Array]":
         cfg = self.cfg
+        if cfg.layer_types:
+            # The tree the serving programs walk, and their forward over
+            # the whole sequence without a cache.  Held here so that an
+            # export of such a stack initialises and restores like any
+            # other; the trainer's block (sharding names, remat, the
+            # experts' balance loss) is not built.
+            if positions is not None or segment_ids is not None \
+                    or return_hidden:
+                raise ValueError(
+                    "a stack with layer_types takes default positions, "
+                    "no segment_ids and no chunked loss")
+            from kubeflow_tpu.models.generate import forward_layer_types
+
+            params = _hold(self, layer_tree_shapes(cfg))
+            return forward_layer_types(cfg, params, tokens)[0]
         embed = self.param(
             "embed",
             nn.with_logical_partitioning(embed_init, ("vocab", "embed")),

@@ -29,6 +29,16 @@ is loaded once for all ``h / hkv`` query heads of a group, nothing is
 transposed or repeated, and the MXU does ``hkv`` times the needed work
 on a step that memory bounds.
 
+Narrow heads.  The chip copies whole 128-lane rows, so a head of 64
+(or 32) lanes cannot be a row of its own.  ``128 // d`` neighbouring kv
+heads then share a row (the same bitcast, ``[bt * hkv * d / 128, 128]``),
+each query head is laid into the lanes of ITS kv head with zeros in the
+others' (the zeros take the neighbours out of the score), the mask keeps
+the rows of its head's group, and of the ``[h, 128]`` product with the
+values each head keeps its own lanes.  The kernel is the same; only what
+it is told about rows and groups differs (``supports`` says which head
+sizes can be laid out so).
+
 Arithmetic: operands in the pool's dtype (bf16 on the chip) with
 float32 accumulation, float32 running max / sum / output (online
 softmax), weights cast to the pool's dtype before the value product, as
@@ -131,6 +141,18 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
+_LANES = 128
+
+
+def supports(head_dim: int, n_kv_heads: int) -> bool:
+    """Whether a page of ``n_kv_heads`` heads of ``head_dim`` can be read
+    as whole 128-lane rows: heads of a multiple of 128, or narrower heads
+    that fill a row between them."""
+    if head_dim % _LANES == 0:
+        return True
+    return _LANES % head_dim == 0 and n_kv_heads % (_LANES // head_dim) == 0
+
+
 @functools.partial(jax.jit, static_argnames=("pages_per_block",
                                              "interpret"))
 def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
@@ -150,16 +172,28 @@ def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
     0 does no page and returns zeros (a retired slot).  Slots may share
     physical pages (the prefix cache's aliasing).
     """
-    S, h, d = q.shape
+    S, h, head_dim = q.shape
     planes, nb, bt, hkv, _ = k_pool.shape
     mb = tables.shape[1]
     assert h % hkv == 0, (h, hkv)
+    g, scale = h // hkv, head_dim ** -0.5
+    # Narrow heads: ``pack`` kv heads a row (module docstring).  A head
+    # size that cannot be laid out so keeps a row a head, which only the
+    # interpreter takes (``supports`` is what a caller on the chip asks).
+    pack = _LANES // head_dim if head_dim < _LANES \
+        and supports(head_dim, hkv) else 1
+    lane = None
+    if pack > 1:
+        lane = jax.nn.one_hot((jnp.arange(h) // g) % pack, pack,
+                              dtype=q.dtype)[None, :, :, None]
+        q = (q[:, :, None, :] * lane).reshape(S, h, pack * head_dim)
+    hkv, g, d = hkv // pack, g * pack, pack * head_dim
     pages = max(1, min(pages_per_block, mb))
     rows = bt * hkv
     kernel = functools.partial(
-        _kernel, mb=mb, bt=bt, hkv=hkv, g=h // hkv, pages=pages, nb=nb,
-        scale=d ** -0.5)
-    return pl.pallas_call(
+        _kernel, mb=mb, bt=bt, hkv=hkv, g=g, pages=pages, nb=nb,
+        scale=scale)
+    out = pl.pallas_call(
         kernel,
         name="paged_decode_attention",
         out_shape=jax.ShapeDtypeStruct((S, h, d), q.dtype),
@@ -185,3 +219,6 @@ def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
       jnp.reshape(plane, (1,)).astype(jnp.int32), q,
       k_pool.reshape(planes * nb, rows, d),
       v_pool.reshape(planes * nb, rows, d))
+    if pack > 1:
+        out = jnp.sum(out.reshape(S, h, pack, head_dim) * lane, axis=2)
+    return out

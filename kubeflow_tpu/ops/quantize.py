@@ -86,6 +86,13 @@ CONTRACTIONS: Dict[Tuple[str, ...], Tuple[int, ...]] = {
     ("attn", "wo"): (-3, -2),      # [h, d, e] contract h, d
     ("mlp", "wi"): (-2,),          # [2, e, f] contract e
     ("mlp", "wo"): (-2,),          # [f, e] contract f
+    # A stack with ``layer_types`` (models/transformer.py).  The
+    # convolution's taps and the router are not here: a few thousand
+    # numbers a layer, kept in float32.
+    ("conv", "w_in"): (-3,),       # [e, 3, e] contract e
+    ("conv", "w_out"): (-2,),      # [e, e] contract e
+    ("moe", "wi"): (-2,),          # [n, e, 2f] contract e
+    ("moe", "wo"): (-2,),          # [n, f, e] contract f
 }
 
 

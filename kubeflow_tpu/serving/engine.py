@@ -507,6 +507,26 @@ class DecodeEngine:
             raise ValueError(f"slots must be >= 1, got {slots}")
         self.cfg = cfg
         self.mesh = mesh
+        # State of fixed size per SLOT beside the paged pool (the
+        # convolution layers of a stack with ``layer_types``): a page
+        # alias no longer restores a prefix, a frontier moved back no
+        # longer rolls a draft back, and pages alone no longer carry a
+        # sequence to the host tier or to another replica.  Until the
+        # state is snapshotted per page such a model prefills every
+        # prompt whole, and what rests on pages alone is refused by
+        # name before any token.
+        self._slot_state = cfg.conv_planes > 0
+        if self._slot_state:
+            for flag, value in (
+                    ("speculative_tokens", speculative_tokens),
+                    ("host_spill_blocks", host_spill_blocks),
+                    ("adapters", adapters), ("mesh", mesh)):
+                if value:
+                    raise ValueError(
+                        f"engine {name!r}: {flag} is not built for a "
+                        f"model with per-slot state "
+                        f"({cfg.conv_planes} convolution layers)")
+            prefix_caching = False
         self._registry = adapters
         self._adapter_version = None
         if adapters is not None:
@@ -562,6 +582,13 @@ class DecodeEngine:
             raise ValueError(
                 f"kv_pool_blocks must be >= 1, got {self.kv_pool_blocks}")
         self.prefix_caching = bool(prefix_caching)
+        self._prefix_reuse = (
+            "off: a page alias does not restore per-slot state, every "
+            "prompt is prefilled whole" if self._slot_state
+            else "on" if self.prefix_caching else "off: disabled")
+        self._moe_layers = sum(
+            map(cfg.layer_is_sparse, range(cfg.n_layers))) \
+            if cfg.layer_types else 0
         # Host-RAM spill tier capacity in pages (§5.10): 0 disables.
         # The tier rides the prefix index (spilled records are looked
         # up by the same chained digests), so it requires caching.
@@ -609,12 +636,15 @@ class DecodeEngine:
         # program; stats()["decode_kernel_steps"] over "steps" is the
         # share of decode steps the kernel served.
         self._paged_kernel = (
-            mesh is None and cfg.head_dim % 128 == 0
+            mesh is None
             and _plain_pool_platform(self._state["cache_k"]) == "tpu")
         if self._paged_kernel:
             # Load Pallas here, not inside the first trace: its import
             # took ~3 s on the chip's host and read as compile_s.
-            from kubeflow_tpu.ops import paged_attention  # noqa: F401
+            from kubeflow_tpu.ops import paged_attention
+
+            self._paged_kernel = paged_attention.supports(
+                cfg.head_dim, cfg.n_kv_heads)
         # Host-owned per-slot block tables, passed into every program
         # call; the sentinel value (== pool size) parks writes and
         # reads of unallocated logical pages.  Loop-thread-owned.
@@ -708,7 +738,7 @@ class DecodeEngine:
             "fused_rounds": 0, "fused_steps_wasted": 0,
             "decode_kernel_steps": 0,
             "spill_pages_out": 0, "spill_pages_in": 0,
-            "parked_sessions": 0, "fetches": 0,
+            "parked_sessions": 0, "fetches": 0, "experts_touched": 0,
             **dict.fromkeys(_SUM_KEYS, 0),
         }
         import jax
@@ -998,6 +1028,12 @@ class DecodeEngine:
         # (:prefill route); ``kv_handoff`` is the decode-tier import
         # payload those pages arrive as.  Both validated HERE so a
         # malformed payload answers 400 before any device work.
+        if self._slot_state:
+            for key in ("kv_export", "kv_handoff", "park_kv"):
+                if inputs.get(key):
+                    raise ValueError(
+                        f"{key} is not built for a model with per-slot "
+                        f"state: pages alone do not carry its sequences")
         export = bool(inputs.get("kv_export"))
         handoff = self._parse_handoff(inputs.get("kv_handoff"), length)
         if deadline is not None and faults.monotonic() >= deadline:
@@ -1268,6 +1304,22 @@ class DecodeEngine:
             "loop_steps": self.cfg.loop_steps,
             "kv_planes": self.cfg.kv_planes,
             "kv_bytes_per_token": self.kv_bytes_per_token,
+            # What a stack with ``layer_types`` holds beside the pool
+            # (zeros for every other model): the layers with a
+            # convolution state per slot and its bytes over all slots;
+            # the sparse layers, their experts and experts a token; and
+            # the device's own count of distinct experts that got a row,
+            # summed over sparse layers and decode steps (over
+            # moe_layers x moe_experts x steps: the share of the
+            # experts' weights a step reads).
+            "conv_planes": self.cfg.conv_planes,
+            "conv_state_bytes": int(self._state["conv"].nbytes)
+            if "conv" in self._state else 0,
+            "moe_layers": self._moe_layers,
+            "moe_experts": self.cfg.moe_experts if self._moe_layers else 0,
+            "moe_top_k": self.cfg.moe_top_k if self._moe_layers else 0,
+            "experts_touched": c["experts_touched"],
+            "prefix_reuse": self._prefix_reuse,
             "kv_block_evictions": c["kv_evictions"],
             "kv_shed_no_blocks": c["kv_shed_no_blocks"],
             "tokens_resident": extra["kv_used"] * self.kv_block_tokens,
@@ -2624,6 +2676,11 @@ class DecodeEngine:
         with self._phase("round_dispatch", width=width, live=live):
             self._state, toks, counts, steps_run = self._rounds_exec(
                 self.params, self._state, tables, np.int32(width))
+            touched = self._state.get("moe_touched")
+            if touched is not None:
+                # Its copy to the host rides behind the round, so the
+                # read at the boundary costs no round trip of its own.
+                touched.copy_to_host_async()
         # ---- overlap window: the dispatch returned as soon as the
         # round was enqueued; everything until the np.asarray below
         # runs while the device computes.
@@ -2674,7 +2731,16 @@ class DecodeEngine:
             for (i, _), at in zip(snapshot, lengths):
                 n = int(counts_np[i])
                 attended += n * at + n * (n - 1) // 2
-            phase.facts(steps=steps, attended=attended)
+            facts = {"steps": steps, "attended": attended}
+            if touched is not None:
+                # The device's own count for this round (decode_rounds
+                # starts it at zero), read before the state is donated
+                # to the next dispatch.
+                facts["experts_touched"] = int(touched)
+                with self._lock:
+                    self._counters["experts_touched"] += \
+                        facts["experts_touched"]
+            phase.facts(**facts)
             del toks, counts, steps_run  # freed here, inside a phase
         with self._phase("drain"):
             self._pending.append((toks_np, snapshot, counts_np))

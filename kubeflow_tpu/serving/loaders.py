@@ -137,6 +137,10 @@ def lm_generate(config: Dict[str, Any]) -> Callable:
     quantize = config.get("quantize")
     if quantize not in (None, "int8"):
         raise ValueError(f"unknown quantize mode {quantize!r}")
+    if quantize and cfg.layer_types:
+        raise ValueError(
+            "quantize is not built for a model with layer_types (its "
+            "experts' grouped products take plain arrays)")
 
     def make_predict(variables):
         # Stage weights into HBM ONCE at load.  They are an argument to

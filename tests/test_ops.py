@@ -447,3 +447,34 @@ class TestPagedDecodeKernel:
             np.asarray(out)[live], np.asarray(ref)[live], atol=2e-5)
         # A slot with nothing to attend reads no page and returns zeros.
         assert not np.asarray(out)[~live].any()
+
+    @pytest.mark.parametrize("d,hkv,group", [
+        (64, 2, 1), (64, 8, 4), (64, 4, 2), (32, 4, 2)])
+    def test_narrow_heads_share_a_row(self, d, hkv, group):
+        """Heads under 128 lanes: ``128 // d`` kv heads a row, each query
+        head in its own kv head's lanes (LFM2's 32 heads on 8 of 64)."""
+        from kubeflow_tpu.ops.paged_attention import (
+            paged_decode_attention,
+            supports,
+        )
+
+        assert supports(d, hkv) and not supports(64, 3) \
+            and not supports(96, 4)
+        rng = np.random.RandomState(7 * d + hkv)
+        slots, h = 4, hkv * group
+        nb = slots * _TABLE + 3
+        q = jnp.asarray(rng.randn(slots, h, d), jnp.float32)
+        k_pools = jnp.asarray(rng.randn(2, nb, _PAGE, hkv, d), jnp.float32)
+        v_pools = jnp.asarray(rng.randn(2, nb, _PAGE, hkv, d), jnp.float32)
+        tables = jnp.asarray(rng.permutation(nb)[:slots * _TABLE].reshape(
+            slots, _TABLE).astype(np.int32))
+        n = jnp.asarray(np.array([13, 2 * _PAGE, 0, _TABLE * _PAGE],
+                                 np.int32))
+        out = paged_decode_attention(
+            q, k_pools, v_pools, jnp.int32(1), tables, n,
+            pages_per_block=2, interpret=True)
+        ref = self._reference(q, k_pools[1], v_pools[1], tables, n)
+        live = np.asarray(n) > 0
+        np.testing.assert_allclose(
+            np.asarray(out)[live], np.asarray(ref)[live], atol=2e-5)
+        assert not np.asarray(out)[~live].any()

@@ -313,7 +313,7 @@ def _weight_moves(text, shapes):
     whose result has one of ``shapes`` (in any layout and memory space):
     an array of a layer's weight that the program PRODUCES, in HBM or in
     the chip's fast memory (``S(1)``), before a matmul reads it.  The
-    loops' own tuple elements and parameters move nothing."""
+    loops' own tuple elements, parameters and bitcasts move nothing."""
     import re
 
     dims = "|".join(",".join(str(n) for n in s) for s in shapes)
@@ -327,7 +327,8 @@ def _weight_moves(text, shapes):
             fused = False
         elif not fused:
             m = shaped.search(line)
-            if m and m.group(2) not in ("get-tuple-element", "parameter"):
+            if m and m.group(2) not in ("get-tuple-element", "parameter",
+                                        "bitcast"):
                 found.append(m.group(1))
     return found
 
@@ -387,3 +388,84 @@ def test_engine_programs_read_stacked_weights_in_place(cell_program, name,
         # What stays is the relayout of the stacked wq / wkv once a call
         # (0.537 + 0.268 GB); the parent's 1.041 GB held the slice too.
         assert compiled.memory_analysis().temp_size_in_bytes < 0.85e9
+
+
+# A stack with layer_types at the cell's sizes
+# (benchmark/configs/lfm2-24b-a2b-l10.json, cells/lfm2-24b-a2b-l10.workers):
+# 8 convolution layers with a per-slot state, 2 attention layers that own
+# the pool's 2 planes (heads of 64: two kv heads a 128-lane row), 2 dense
+# feed-forwards and 8 x 64 experts walked layer by layer.
+LFM2 = ({"vocab_size": 65_536, "d_model": 2048, "n_layers": 10,
+         "n_heads": 32, "n_kv_heads": 8, "d_ff": 11_776, "head_dim": 64,
+         "max_seq_len": 128_000, "rope_theta": 1e6, "tied_embeddings": True,
+         "norm_eps": 1e-5, "qk_norm": True, "conv_kernel": 3,
+         "layer_types": ["conv", "conv", "full_attention", "conv", "conv",
+                         "conv", "full_attention", "conv", "conv", "conv"],
+         "moe_experts": 64, "moe_top_k": 4, "moe_dense_layers": 2,
+         "moe_d_ff": 1536, "dtype": "bfloat16"}, 16, 704)
+
+
+@pytest.fixture(scope="module")
+def lfm2_program(chip):
+    import functools
+
+    from kubeflow_tpu.models import generate
+
+    widths, slots, max_len = LFM2
+    e = _engine_shapes(chip, widths, slots, max_len, max_new_tokens=384)
+
+    @functools.cache
+    def compiled(program):
+        if program == "decode_rounds":
+            return e, generate.decode_rounds.lower(
+                e["cfg"], e["params"], e["state"], e["decode"], 8,
+                e["arg"](slots, e["table_blocks"]), e["arg"](),
+                paged_kernel=True).compile()
+        scalar = e["arg"]()
+        return e, generate.prefill_chunk_into_slot.lower(
+            e["cfg"], e["params"], e["state"], e["decode"], e["arg"](1, 64),
+            scalar, scalar, scalar, scalar, scalar,
+            e["arg"](1, e["table_blocks"])).compile()
+
+    return compiled
+
+
+@pytest.mark.parametrize("program", ["decode_rounds",
+                                     "prefill_chunk_into_slot"])
+def test_layer_types_programs_hold_both_states_and_the_experts_in_place(
+        lfm2_program, program):
+    """Both engine programs of the LFM2 cut: the pool AND the convolution
+    state come in donated and go out aliased, no array of a layer's
+    experts (1.2 GB) is produced outside a fusion, the grouped products
+    are the chip's own kernel, and a call's temporaries stay under 0.5 GB
+    beside 10.53 GB of weights.  (``_pool_moves`` is not held to nothing
+    here: a side of this pool is 23 MB, and the compiler parks it in the
+    chip's fast memory between a step's scatters and brings it back for
+    the kernel: 5 % of the programs' time on the chip, PERF.md section 5.)"""
+    e, compiled = lfm2_program(program)
+    text = compiled.as_text()
+    pool, conv = e["state"]["cache_k"], e["state"]["conv"]
+    assert pool.shape == (2, 16 * 44, 16, 8, 64)
+    assert conv.shape == (8, 16, 2, 2048)
+    assert _weight_moves(text, [(64, 2048, 3072), (64, 1536, 2048),
+                                (2048, 3072), (1536, 2048),
+                                (2, 2048, 11_776)]) == []
+    assert text.count('op_name="ragged-dot-metadata"') == 8
+    m = compiled.memory_analysis()
+    held = 2 * int(np.prod(pool.shape)) * 2 + int(np.prod(conv.shape)) * 2
+    assert m.alias_size_in_bytes >= held
+    assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+    assert 10.53e9 < m.argument_size_in_bytes < 10.7e9
+    assert _fits(compiled, gib=11.65)  # 12.5 GB
+
+
+def test_layer_types_decode_rounds_keeps_the_paged_kernel(lfm2_program):
+    """Heads of 64 lanes: the decode program still attends through
+    ``ops/paged_attention.py`` (two kv heads a row), once a plane."""
+    from kubeflow_tpu.ops.paged_attention import supports
+
+    assert supports(64, 8)
+    _, compiled = lfm2_program("decode_rounds")
+    assert "paged_decode_attention" in compiled.as_text()
+    _, chunk = lfm2_program("prefill_chunk_into_slot")
+    assert "paged_decode_attention" not in chunk.as_text()
