@@ -89,6 +89,40 @@ def _rms_norm(x, scale, eps, dtype):
     return (normed * scale).astype(dtype)
 
 
+# Query rows a tile of the view's attention (``_view_attention``): the
+# width the chunk program ran at until PR 36, whose fusions the chip's
+# compiler is known to make well.
+_VIEW_QUERY_TILE = 64
+
+
+def _view_attention(q, view_k, view_v, cache_len, pad_amount):
+    """Attention of the call's q [b, t, h, d] over a gathered view of the
+    pool, in tiles of ``_VIEW_QUERY_TILE`` query rows where t holds
+    several.  A row's scores and softmax are its own, so a tile's rows
+    come out as they would from one call over all of t; what changes is
+    the float32 score array a layer holds at once, ``[h, tile, view]`` in
+    place of ``[h, t, view]``: at 256 rows over Mistral's 6,400-long view
+    the whole array is 210 MB, and the chip's compiler made the softmax's
+    reduction over it 14 ms a layer where a tile's takes 0.1 (PERF.md
+    section 6, PR 36)."""
+    t = q.shape[1]
+    tiles, rest = divmod(t, _VIEW_QUERY_TILE)
+    if tiles < 2 or rest:
+        return dot_product_attention(
+            q, view_k, view_v, causal=True,
+            kv_offset=cache_len, kv_valid_start=pad_amount)
+
+    def tile(i):
+        first = i * _VIEW_QUERY_TILE
+        return dot_product_attention(
+            jax.lax.dynamic_slice_in_dim(q, first, _VIEW_QUERY_TILE, axis=1),
+            view_k, view_v, causal=True,
+            kv_offset=cache_len + first, kv_valid_start=pad_amount)
+
+    out = jax.lax.map(tile, jnp.arange(tiles))      # [tiles, b, tile, h, d]
+    return jnp.moveaxis(out, 0, 1).reshape(q.shape)
+
+
 def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
                      cache_len, positions, pad_amount=None, write_cols=None,
                      tables=None, adapters=None, paged_kernel=False,
@@ -241,10 +275,8 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
             with jax.named_scope("kft.kv_view"):
                 view_k, view_v = paged_view(ck), paged_view(cv)
             with jax.named_scope("kft.attention"):
-                out = dot_product_attention(
-                    q, view_k, view_v, causal=True,
-                    kv_offset=cache_len, kv_valid_start=pad_amount,
-                )
+                out = _view_attention(q, view_k, view_v, cache_len,
+                                      pad_amount)
     elif isinstance(ck, QTensor):
         def store(c, new):
             vals, s = quantize_array(new, (-1,))    # [b, t, hk, d]

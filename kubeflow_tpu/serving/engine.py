@@ -25,12 +25,17 @@ over ONE persistent PAGED KV block pool shared by ``slots`` sequences:
     ``decode_rounds`` call runs up to ``decode_rounds`` steps on the
     device (the width adapts under that cap, see ``_round_width``) and
     stops early once every slot is done;
-  - new requests are admitted into free slots BETWEEN steps, and their
-    prompts prefill in **static-width chunks scheduled between decode
-    steps** under a per-step token budget (``prefill_chunk_tokens``) —
-    a long arriving prompt can never stall in-flight decode for longer
-    than one chunk's compute, where a one-shot full-width prefill
-    stalls every active slot for the whole prompt;
+  - new requests are admitted into free slots BETWEEN rounds, and their
+    prompts prefill in **static-width chunks** (``prefill_chunk_tokens``
+    columns, ``PREFILL_CHUNK_TOKENS`` = 256 by default, clamped to
+    ``prefill_len``): an admission's first chunk at its claim, then at
+    most ONE chunk of the oldest admission between two decode rounds,
+    once the rounds since the last have saved up its width at
+    ``PREFILL_ROUND_TOKENS`` = 64 a round (every round with nothing
+    live) — a long arriving prompt holds a live slot's next token back
+    by one chunk's compute every 4th round (its longest gap is one
+    round plus one chunk), where a one-shot full-width prefill stalls
+    every active slot for the whole prompt;
   - admission first resumes from the **longest cached shared prefix**:
     the block-hashed index finds the longest token-block prefix a
     previous prompt already computed and the new slot's table ALIASES
@@ -111,6 +116,30 @@ from kubeflow_tpu.serving.model_server import (
 from kubeflow_tpu.serving.adapters import AdapterNotFound
 from kubeflow_tpu.serving.prefix_cache import BlockManager
 from kubeflow_tpu.testing import faults
+
+
+# The chunk program's width where nobody states one.  A call reads every
+# weight once for all of its columns, and a bf16 weight gives one FLOP a
+# byte a column, so the read bounds the call until the columns reach the
+# chip's ridge: peak bf16 FLOP/s over HBM bytes/s, 197e12 / 819e9 = ~240
+# on a TPU v5e.  Rounded up to a multiple of the MXU's 128-row tile: 256
+# (at 64 the prompt paid the read four times over).  A property of the
+# chip and of the weights' width, not of a model.
+PREFILL_CHUNK_TOKENS = 256
+
+# The prompt tokens a decode round's live slots wait for: what one chunk
+# between two rounds held until the chunk grew wider than this (the value
+# dates from PR 4).  A wider chunk runs once the rounds since the last
+# have saved up its width — every 4th round at 256 columns — so prefill
+# keeps the tokens a round it had and costs the live slots a quarter of a
+# 23 ms chunk where it cost a whole 15 ms one; with no slot live nothing
+# is held back.  This is the dial of the prefill / decode trade: at one
+# 256-wide chunk EVERY round docqa's closed loop served 82 % more tokens a
+# second at 57 % less time to first token and 8 % MORE time per output
+# token, the one judged number (PERF.md section 6, PR 36; section 7 row
+# 10).
+PREFILL_ROUND_TOKENS = 64
+
 
 class _SpillShed(Exception):
     """Internal: a spill-tier fault struck mid-admission (the
@@ -412,11 +441,14 @@ class DecodeEngine:
         state.  Chunk scheduling among the admitted set is FIFO (the
         oldest admission takes the whole budget until it finishes —
         best TTFT for the head of the line).
-      prefill_chunk_tokens: per-step prefill token budget AND the
-        static chunk program width (clamped to prefill_len): between
-        two decode steps the loop spends at most this many prompt
-        tokens on chunked prefill, which bounds the inter-token latency
-        of in-flight slots regardless of arriving prompt length.
+      prefill_chunk_tokens: the static chunk program width (clamped to
+        prefill_len: ``chunk_w``), ``PREFILL_CHUNK_TOKENS`` by default.
+        Between two decode rounds the loop runs at most ONE chunk of
+        the oldest mid-prefill admission (a new admission's first chunk
+        runs at its claim), and while slots are live only once the
+        rounds have saved up its width at ``PREFILL_ROUND_TOKENS`` a
+        round: an in-flight slot's longest gap is one round plus one
+        chunk regardless of arriving prompt length.
       kv_block_tokens: paged-KV page size in cache positions — also
         the prefix hash/share granularity (prefixes are cached and
         aliased in multiples of this many tokens).
@@ -485,7 +517,7 @@ class DecodeEngine:
         max_len: Optional[int] = None,
         decode_rounds: int = 8,
         admit_width: int = 4,
-        prefill_chunk_tokens: int = 64,
+        prefill_chunk_tokens: int = PREFILL_CHUNK_TOKENS,
         kv_block_tokens: int = 16,
         kv_pool_blocks: int = 0,
         prefix_caching: bool = True,
@@ -570,8 +602,8 @@ class DecodeEngine:
                 f"{cfg.max_seq_len}")
         self.decode_rounds = max(1, int(decode_rounds))
         self.admit_width = max(1, min(int(admit_width), slots))
-        self.prefill_chunk_tokens = max(1, int(prefill_chunk_tokens))
-        self.chunk_w = min(self.prefill_chunk_tokens, self.prefill_len)
+        self.chunk_w = min(max(1, int(prefill_chunk_tokens)),
+                           self.prefill_len)
         self.kv_block_tokens = max(1, int(kv_block_tokens))
         # Per-slot block-table span: enough logical pages to cover
         # max_len positions (a static program shape).
@@ -720,6 +752,9 @@ class DecodeEngine:
         # (FIFO — the oldest admission finishes first, best TTFT).
         # Loop-thread-owned; the admission pop reads only its length.
         self._prefilling: List[dict] = []
+        # Prompt tokens of prefill the rounds since the last chunk have
+        # saved up (PREFILL_ROUND_TOKENS a round; _iterate).
+        self._prefill_credit = 0
         # (tokens_array, [(slot, entry), ...], counts) emissions not
         # yet delivered, and the entries the current round advances.
         self._pending: List[tuple] = []
@@ -1405,8 +1440,8 @@ class DecodeEngine:
             # Wall time between consecutive step-call completions while
             # slots were live — the client-visible inter-token gap,
             # INCLUDING whatever admission/prefill work ran in between.
-            # Bounded by the chunk budget; a full-prefill stall would
-            # spike the max.
+            # Bounded by one round plus one chunk; a full-prefill stall
+            # would spike the max.
             "inter_token_gap_p50_ms": pct(gaps, 0.50),
             "inter_token_gap_p99_ms": pct(gaps, 0.99),
             "inter_token_gap_max_ms": round(gaps[-1] * 1e3, 3)
@@ -3032,8 +3067,8 @@ class DecodeEngine:
             if self.host_spill_blocks:
                 # Spill-then-admit (§5.10): evacuate LRU-cold idle
                 # records to the host tier BEFORE this round's take()
-                # calls (admission prefills below, chunk budget, decode
-                # covers) can destroy-evict them — pool pressure
+                # calls (admission prefills below, the round's chunk,
+                # decode covers) can destroy-evict them — pool pressure
                 # degrades to a host copy, not to recompute.
                 self._spill_tick()
         with self._phase("prefill_dispatch") as phase:
@@ -3043,19 +3078,23 @@ class DecodeEngine:
                     self._begin_prefill(entry, slot)
                 except _SpillShed as exc:
                     self._shed_admitted(entry, slot, str(exc))
-            # Chunked prefill BETWEEN decode steps, under the per-step
-            # token budget: the head admission (FIFO — oldest finishes
-            # first, best TTFT) gets chunks until the budget is spent,
-            # then the loop returns to decoding.  In-flight slots
-            # therefore stall at most ~budget prompt-tokens of prefill
-            # per step, no matter how long the arriving prompts are.
-            budget = self.prefill_chunk_tokens
-            while budget > 0 and self._prefilling:
-                entry = self._prefilling[0]
-                self._prefill_chunk(entry)
-                budget -= self.chunk_w
-                if not entry["prefilling"]:
-                    self._prefilling.pop(0)
+            # Chunked prefill BETWEEN decode rounds: the head admission
+            # (FIFO — oldest finishes first, best TTFT) gets at most ONE
+            # chunk of chunk_w columns, then the loop returns to
+            # decoding.  While slots are live a round saves up
+            # PREFILL_ROUND_TOKENS of it, so in-flight slots stall one
+            # chunk's compute every chunk_w / PREFILL_ROUND_TOKENS
+            # rounds, no matter how long the arriving prompts are.
+            if self._prefilling:
+                self._prefill_credit += PREFILL_ROUND_TOKENS
+                if self._prefill_credit >= self.chunk_w or not any(
+                        r is not None and not r["prefilling"]
+                        for r in self._slot_req):
+                    self._prefill_credit = 0
+                    entry = self._prefilling[0]
+                    self._prefill_chunk(entry)
+                    if not entry["prefilling"]:
+                        self._prefilling.pop(0)
             phase.facts(
                 admitted=len(admissions),
                 chunks=self._counters["prefill_chunks"] - chunks_before)

@@ -19,6 +19,7 @@ import sys
 import threading
 import time
 
+from kubeflow_tpu.serving.engine import PREFILL_CHUNK_TOKENS
 from kubeflow_tpu.serving.http import make_http_server
 from kubeflow_tpu.serving.model_server import ModelServer
 from kubeflow_tpu.testing import faults
@@ -32,7 +33,7 @@ def batcher_factory(*, micro_batch_size: int, batch_timeout_s: float,
                     lm_engine_prefill_len: int = 0,
                     lm_engine_admit_width: int = 4,
                     decode_rounds: int = 8,
-                    prefill_chunk_tokens: int = 64,
+                    prefill_chunk_tokens: int = PREFILL_CHUNK_TOKENS,
                     kv_block_tokens: int = 16,
                     kv_pool_blocks: int = 0,
                     host_spill_blocks: int = 0,
@@ -220,13 +221,22 @@ def main(argv=None) -> int:
                          "admissions: further queued requests wait "
                          "even when slots are free, so a burst of long "
                          "prompts cannot hoard every slot half-filled")
-    ap.add_argument("--prefill_chunk_tokens", type=int, default=64,
-                    help="DecodeEngine per-step prefill token budget "
-                         "(and the static chunk width): arriving "
-                         "prompts prefill in chunks scheduled between "
-                         "decode steps, so in-flight inter-token "
-                         "latency is bounded by one chunk regardless "
-                         "of prompt length")
+    ap.add_argument("--prefill_chunk_tokens", type=int,
+                    default=PREFILL_CHUNK_TOKENS,
+                    help="DecodeEngine prefill chunk width in prompt "
+                         "tokens (clamped to the prefill width).  The "
+                         "default, 256, is the chip's ridge: a call "
+                         "reads every weight once, and a bf16 weight "
+                         "gives one FLOP a byte a token, so under "
+                         "197e12 / 819e9 = ~240 tokens (TPU v5e) the "
+                         "read bounds the call; rounded up to the "
+                         "128-row tile.  At most one chunk runs "
+                         "between two decode rounds (while requests "
+                         "decode, once the rounds have saved up its "
+                         "width at 64 prompt tokens a round), so an "
+                         "in-flight request's longest inter-token gap "
+                         "is one round plus one chunk regardless of "
+                         "prompt length")
     ap.add_argument("--kv_block_tokens", type=int, default=16,
                     help="DecodeEngine paged-KV page size in cache "
                          "positions — also the prefix hash/share "

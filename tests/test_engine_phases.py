@@ -30,8 +30,9 @@ NESTED = {"wait_work": "admit", "round_wait": "drain"}
 LOOPED = {"loop_steps": 2, "sandwich_norm": True}
 
 
-@pytest.fixture(scope="module", params=["dense", "looped"])
-def lm(request):
+def _stack(**widths):
+    """``(cfg, params, decode)`` of the tiny LM, with ``widths`` over its
+    own."""
     import jax
     from flax import linen as nn
 
@@ -42,15 +43,24 @@ def lm(request):
     cfg = _model_config({
         "vocab_size": VOCAB, "d_model": 32, "n_layers": 2, "n_heads": 4,
         "n_kv_heads": 2, "d_ff": 64, "head_dim": 8, "max_seq_len": 64,
-        "dtype": "float32", **(LOOPED if request.param == "looped" else {})})
+        "dtype": "float32", **widths})
     params = nn.unbox(Transformer(cfg).init(
         jax.random.key(SEED), np.zeros((1, 8), np.int32))["params"])
+    return cfg, params, DecodeConfig(max_new_tokens=NEW_TOKENS,
+                                     temperature=0.0)
+
+
+@pytest.fixture(scope="module", params=["dense", "looped"])
+def lm(request):
+    import jax
+
+    cfg, params, decode = _stack(
+        **(LOOPED if request.param == "looped" else {}))
     # Norm scales away from 1: a norm read with the wrong scale shows.
     params = jax.tree_util.tree_map_with_path(
         lambda path, a: a * (1.0 + 0.1 * np.sin(np.arange(a.size)).reshape(
             a.shape)) if path[-1].key == "scale" else a, params)
-    return cfg, params, DecodeConfig(max_new_tokens=NEW_TOKENS,
-                                     temperature=0.0)
+    return cfg, params, decode
 
 
 def _programs(lm):
@@ -470,3 +480,198 @@ def test_pool_hand_off_and_stats_are_sized_by_planes(lm):
     finally:
         pre.close()
         dec.close()
+
+
+# -- the chunk's width (PR 36) -------------------------------------------------
+
+@pytest.mark.parametrize("prefill_len", [16, 40, 300])
+def test_an_engine_built_without_a_width_takes_the_constants(lm, prefill_len):
+    """One constant in the tree: the engine, ``batcher_factory`` and the
+    serving binary's flag default to it, and ``prefill_len`` clamps it."""
+    import dataclasses
+    import inspect
+
+    from kubeflow_tpu.serving import engine as engine_module
+    from kubeflow_tpu.serving import main as serving_main
+
+    assert engine_module.PREFILL_CHUNK_TOKENS == 256
+    for fn in (engine_module.DecodeEngine.__init__,
+               serving_main.batcher_factory):
+        assert inspect.signature(fn).parameters[
+            "prefill_chunk_tokens"].default == 256
+    cfg, params, decode = lm
+    engine = engine_module.DecodeEngine(
+        dataclasses.replace(cfg, max_seq_len=512), params, decode,
+        slots=2, prefill_len=prefill_len, name=f"width-{prefill_len}")
+    try:
+        assert engine.chunk_w == min(256, prefill_len)
+    finally:
+        engine.close()
+
+
+@pytest.mark.parametrize("width", [None, 8])
+def test_one_chunk_of_the_oldest_admission_between_two_rounds(
+        lm, width, monkeypatch):
+    """The ``chunks`` fact of ``prefill_dispatch``: a claim dispatches the
+    admission's first chunk, and beyond the claims an iteration runs at
+    most ONE chunk, of the oldest admission, before its decode round.
+    Also where nobody states a width and ``prefill_len`` clamps the
+    default: every prompt is then one chunk, dispatched at its claim, and
+    the loop adds none (``_prefilling`` stays empty)."""
+    import jax
+
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    Recorder.events = []
+    kw = {"prefill_chunk_tokens": width} if width else {}
+    cfg, params, decode = lm
+    engine = DecodeEngine(cfg, params, decode, slots=3, prefill_len=32,
+                          kv_block_tokens=4, decode_rounds=4,
+                          name=f"one-chunk-{width}", **kw)
+    try:
+        assert engine.chunk_w == (width or 32)
+        prompts = _prompts(6, length=29)
+        _serve(engine, prompts)
+        stats = engine.stats()
+    finally:
+        engine.close()
+    per_prompt = -(-29 // engine.chunk_w)
+    assert stats["prefill_chunks"] == 6 * per_prompt
+    facts = [e["facts"] for e in Recorder.events
+             if e["name"] == "kft.engine.prefill_dispatch" and "t1" in e]
+    assert sum(f["chunks"] for f in facts) == stats["prefill_chunks"]
+    assert sum(f["admitted"] for f in facts) == 6
+    for f in facts:
+        assert f["admitted"] <= f["chunks"] <= f["admitted"] + 1
+    if width is None:
+        assert all(f["chunks"] == f["admitted"] for f in facts)
+    else:
+        # Mid-prefill prompts do get their chunk where nothing is claimed.
+        assert any(f["chunks"] == 1 and f["admitted"] == 0 for f in facts)
+
+
+@pytest.mark.parametrize("beside", ["a_live_slot", "nothing"])
+def test_a_wide_chunk_waits_for_the_rounds_to_save_it_up(beside,
+                                                         monkeypatch):
+    """A chunk wider than ``PREFILL_ROUND_TOKENS``: while a slot is live
+    a round saves up 64 of its 128 columns, so a prompt in mid-prefill
+    gets its next chunk every SECOND round; with nothing live it gets
+    one every iteration."""
+    import jax
+
+    from kubeflow_tpu.serving import engine as engine_module
+
+    assert engine_module.PREFILL_ROUND_TOKENS == 64
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    Recorder.events = []
+    engine = _engine(_stack(max_seq_len=512), slots=2, prefill_len=448,
+                     prefill_chunk_tokens=128, decode_rounds=1,
+                     name=f"saved-up-{beside}")
+    long_prompt = _prompts(1, length=440, seed=7)[0]
+    try:
+        if beside == "a_live_slot":
+            live = threading.Thread(target=_serve, args=(
+                engine, _prompts(1, length=5), 60))
+            live.start()
+            while engine.stats()["first_tokens"] < 1:
+                time.sleep(0.001)
+        _serve(engine, [long_prompt], new=2)
+        if beside == "a_live_slot":
+            live.join()
+    finally:
+        engine.close()
+    dispatched = {e["facts"]["round"] for e in Recorder.events
+                  if e["name"] == "kft.engine.round_dispatch"}
+    chunks = sorted(
+        e["facts"]["round"] for e in Recorder.events
+        if e["name"] == "kft.engine.prefill_dispatch" and "t1" in e
+        and e["facts"]["chunks"] == 1 and e["facts"]["admitted"] == 0)
+    apart = {b - a for a, b in zip(chunks, chunks[1:])}
+    # 440 columns are four chunks, the first at the claim.
+    if beside == "a_live_slot":
+        assert len(chunks) == 3 and apart == {2}
+        assert set(range(chunks[0], chunks[-1])) <= dispatched
+    else:  # the claim's iteration runs the second chunk too
+        assert len(chunks) == 2 and apart == {1}
+        assert not dispatched & set(chunks[:-1])
+
+
+def _conv_stack():
+    """A stack with ``layer_types``: convolution layers with a per-slot
+    state beside the pool, a dense feed-forward and sparse experts."""
+    return _stack(
+        n_layers=3, layer_types=["conv", "full_attention", "conv"],
+        conv_kernel=3, qk_norm=True, tied_embeddings=True, moe_experts=4,
+        moe_top_k=2, moe_dense_layers=1, moe_d_ff=24)
+
+
+_LONG, _TAIL = _prompts(1, length=22)[0], _prompts(1, length=9, seed=3)[0]
+# name -> (stack, chunk widths, prompts served one after the other, prompt
+# tokens the LAST of them finds cached).  Pages are 4 positions: the
+# second prompt shares 14 tokens with the first and resumes at 12, inside
+# a chunk of 8 or 16 columns.  At 128 and 192 columns the view's attention
+# runs in 2 and 3 tiles of query rows (``generate._VIEW_QUERY_TILE``), at
+# 64 in one call.
+WIDTH_CASES = {
+    "cold": ("dense", (4, 8, 16), [_LONG], 0),
+    "prefix_hit_mid_page": (
+        "dense", (4, 8, 16), [_LONG, _LONG[:14] + _TAIL], 12),
+    "shorter_than_a_chunk": ("dense", (4, 8, 16), [_LONG[:3]], 0),
+    "conv_state": ("conv", (4, 8, 16), [_LONG, _LONG[:3]], 0),
+    "query_tiles": ("long", (64, 128, 192),
+                    [_prompts(1, length=150, seed=5)[0], _LONG], 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WIDTH_CASES))
+def test_the_chunks_width_changes_no_token(case):
+    """The same prompts at three chunk widths: the same answers, in the
+    chunks each width makes of what the cache did not hold."""
+    stack, widths, prompts, cached = WIDTH_CASES[case]
+    model = {"dense": _stack, "conv": _conv_stack,
+             "long": lambda: _stack(max_seq_len=256)}[stack]()
+    prefill_len = 192 if stack == "long" else 32
+    fresh = [len(p) for p in prompts]
+    fresh[-1] -= cached
+    answers = []
+    for width in widths:
+        engine = _engine(model, prefill_chunk_tokens=width, decode_rounds=4,
+                         prefill_len=prefill_len,
+                         name=f"width-{case}-{width}")
+        try:
+            assert engine.chunk_w == width
+            outs = [_serve(engine, [p])[0] for p in prompts]
+            stats = engine.stats()
+        finally:
+            engine.close()
+        assert stats["cached_prompt_tokens"] == cached
+        assert stats["prompt_tokens"] - cached == sum(fresh)
+        assert stats["prefill_chunks"] == sum(-(-n // width) for n in fresh)
+        answers.append([o["tokens"][0].tolist() for o in outs])
+        assert [len(a) for a in answers[-1]] == [
+            len(p) + NEW_TOKENS for p in prompts]
+    assert answers[0] == answers[1] == answers[2]
+
+
+@pytest.mark.parametrize("rows", [128, 192])
+@pytest.mark.parametrize("per_row", [False, True])
+def test_view_attention_in_query_tiles_is_the_whole_calls(rows, per_row):
+    """``_view_attention`` over several tiles of query rows against ONE
+    ``dot_product_attention`` over all of them: grouped heads, a view
+    longer than the rows, a scalar frontier and one per row."""
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models import generate
+    from kubeflow_tpu.ops.attention import dot_product_attention
+
+    assert rows % generate._VIEW_QUERY_TILE == 0
+    rng = np.random.default_rng(SEED)
+    q = jnp.asarray(rng.normal(size=(2, rows, 4, 8)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, 256, 2, 8)), jnp.float32)
+            for _ in range(2))
+    start = jnp.asarray([40, 7], jnp.int32) if per_row else jnp.int32(40)
+    got = generate._view_attention(q, k, v, start, None)
+    want = dot_product_attention(q, k, v, causal=True, kv_offset=start)
+    assert got.shape == want.shape == q.shape
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)

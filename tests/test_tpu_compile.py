@@ -145,8 +145,8 @@ def _engine_shapes(chip, widths, slots, max_len, block=16,
 
 @pytest.fixture(scope="module")
 def engine_shapes(chip):
-    """The 188M engine: 16 slots, chunk 64, 16-token pages, 256-token
-    prompts + 128 new."""
+    """The 188M engine: 16 slots, 16-token pages, 256-token prompts (one
+    chunk each) + 128 new."""
     return _engine_shapes(chip, LM, 16, 256 + 128)
 
 
@@ -157,32 +157,41 @@ def _fits(compiled, gib=16):
     return live < gib * 2 ** 30
 
 
-def test_engine_prefill_chunk_compiles(engine_shapes):
+def _compile_chunk(e, max_len):
+    """``prefill_chunk_into_slot`` of the engine ``e`` describes, as wide
+    as ``DecodeEngine`` makes it where nobody states a width: its
+    constant, clamped to ``prefill_len``."""
     from kubeflow_tpu.models.generate import prefill_chunk_into_slot
+    from kubeflow_tpu.serving.engine import PREFILL_CHUNK_TOKENS
 
-    e = engine_shapes
+    width = min(PREFILL_CHUNK_TOKENS, max_len - e["decode"].max_new_tokens)
     scalar = e["arg"]()
-    compiled = prefill_chunk_into_slot.lower(
-        e["cfg"], e["params"], e["state"], e["decode"], e["arg"](1, 64),
+    return prefill_chunk_into_slot.lower(
+        e["cfg"], e["params"], e["state"], e["decode"], e["arg"](1, width),
         scalar, scalar, scalar, scalar, scalar,
         e["arg"](1, e["table_blocks"])).compile()
-    assert _fits(compiled)
+
+
+def test_engine_prefill_chunk_compiles(engine_shapes):
+    assert _fits(_compile_chunk(engine_shapes, 256 + 128))
 
 
 # The serving cells' widths (benchmark/configs/, benchmark/cells/): the
 # engine's decode program has to come out WITH the paged attention kernel
 # when its pool lives on a TPU, and without the gathered float32 view.
+# ``(widths, slots, max_len, max_new_tokens)``: ``prefill_len`` is the
+# difference of the last two (2048, 6272, 128).
 CELLS = {
     "internlm2-1.8b": (
         {"vocab_size": 92_544, "d_model": 2048, "n_layers": 24,
          "n_heads": 16, "n_kv_heads": 8, "d_ff": 8192, "head_dim": 128,
          "max_seq_len": 32_768, "tied_embeddings": False,
-         "dtype": "bfloat16"}, 16, 2560),
+         "dtype": "bfloat16"}, 16, 2560, 512),
     "mistral-7b-v0.3-l16": (
         {"vocab_size": 32_768, "d_model": 4096, "n_layers": 16,
          "n_heads": 32, "n_kv_heads": 8, "d_ff": 14_336, "head_dim": 128,
          "max_seq_len": 32_768, "tied_embeddings": False,
-         "dtype": "bfloat16"}, 6, 6400),
+         "dtype": "bfloat16"}, 6, 6400, 128),
     # A looped stack: 192 planes over 48 layers' weights, kv heads = heads
     # (a page is 256 rows of the kernel's block, twice the others').
     "ouro-2.6b": (
@@ -190,7 +199,7 @@ CELLS = {
          "n_heads": 16, "n_kv_heads": 16, "d_ff": 5632, "head_dim": 128,
          "max_seq_len": 65_536, "rope_theta": 1e6, "tied_embeddings": False,
          "loop_steps": 4, "sandwich_norm": True, "dtype": "bfloat16"},
-        5, 512),
+        5, 512, 384),
 }
 
 
@@ -198,26 +207,23 @@ CELLS = {
 def cell_program(chip):
     """``(engine shapes, compiled program)`` of a cell's engine, each
     program compiled once for the tests below: ``decode_rounds`` (8 steps
-    wide, with the paged kernel) or ``prefill_chunk_into_slot`` (64
-    columns)."""
+    wide, with the paged kernel) or ``prefill_chunk_into_slot`` (as wide
+    as the engine makes it for the cell: 256 columns, 128 in
+    ``ouro-2.6b``)."""
     import functools
 
     from kubeflow_tpu.models import generate
 
     @functools.cache
     def compiled(name, program):
-        widths, slots, max_len = CELLS[name]
-        e = _engine_shapes(chip, widths, slots, max_len)
+        widths, slots, max_len, new = CELLS[name]
+        e = _engine_shapes(chip, widths, slots, max_len, max_new_tokens=new)
         if program == "decode_rounds":
             return e, generate.decode_rounds.lower(
                 e["cfg"], e["params"], e["state"], e["decode"], 8,
                 e["arg"](slots, e["table_blocks"]), e["arg"](),
                 paged_kernel=True).compile()
-        scalar = e["arg"]()
-        return e, generate.prefill_chunk_into_slot.lower(
-            e["cfg"], e["params"], e["state"], e["decode"], e["arg"](1, 64),
-            scalar, scalar, scalar, scalar, scalar,
-            e["arg"](1, e["table_blocks"])).compile()
+        return e, _compile_chunk(e, max_len)
 
     return compiled
 
@@ -228,7 +234,7 @@ def test_engine_decode_rounds_compiles_with_paged_kernel(cell_program, name):
 
     from kubeflow_tpu.serving.engine import _plain_pool_platform
 
-    widths, slots, max_len = CELLS[name]
+    widths, slots, max_len, _ = CELLS[name]
     e, compiled = cell_program(name, "decode_rounds")
     # What DecodeEngine decides from: the platform of the pool's device.
     assert _plain_pool_platform(e["state"]["cache_k"]) == "tpu"
@@ -299,6 +305,41 @@ def test_engine_programs_update_the_pool_in_place(cell_program, name,
     # Donated and aliased: the pool that comes in is the pool that goes out.
     assert m.alias_size_in_bytes >= 2 * side
     assert _fits(compiled)
+
+
+def _fusions_given_up(text):
+    """Fusions of a compiled module for which the chip's compiler found no
+    cost (``estimated_cycles`` at the largest int64) after retrying: the
+    fallback it then emits ran 90 times slower than the same fusion a
+    width below (the softmax's reduction over ``f32[32,256,6400]`` of a
+    256-wide chunk, 14 ms a layer; PERF.md section 6, PR 36)."""
+    import re
+
+    return re.findall(
+        r"%(\S+) = [^\n]*\"estimated_cycles\":\"9223372036854775807\"", text)
+
+
+def test_fusions_given_up_finds_the_untiled_softmax():
+    """The line is the 256-wide chunk's, compiled without query tiles
+    (PR 36, operands and the window's bounds shortened)."""
+    scores = "f32[32,256,6400]{2,1,0:T(8,128)}"
+    text = f"""
+  %fusion.180 = {scores} fusion(%copy.44, %copy.43), kind=kOutput, \
+backend_config={{"estimated_cycles":"120934","retry_count":"0"}}
+  %fusion.181 = (f32[32,256]{{1,0:T(8,128)S(1)}}, {scores}) \
+fusion(%fusion.180), kind=kOutput, backend_config={{"window_config":\
+{{"estimated_cycles":"9223372036854775807"}},"retry_count":"6"}}
+"""
+    assert _fusions_given_up(text) == ["fusion.181"]
+
+
+@pytest.mark.parametrize("program", ["decode_rounds",
+                                     "prefill_chunk_into_slot"])
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_engine_programs_hold_no_fusion_the_compiler_gave_up_on(
+        cell_program, name, program):
+    _, compiled = cell_program(name, program)
+    assert _fusions_given_up(compiled.as_text()) == []
 
 
 def _layer_pair_shapes(widths):
@@ -402,7 +443,7 @@ LFM2 = ({"vocab_size": 65_536, "d_model": 2048, "n_layers": 10,
          "layer_types": ["conv", "conv", "full_attention", "conv", "conv",
                          "conv", "full_attention", "conv", "conv", "conv"],
          "moe_experts": 64, "moe_top_k": 4, "moe_dense_layers": 2,
-         "moe_d_ff": 1536, "dtype": "bfloat16"}, 16, 704)
+         "moe_d_ff": 1536, "dtype": "bfloat16"}, 16, 704, 384)
 
 
 @pytest.fixture(scope="module")
@@ -411,8 +452,8 @@ def lfm2_program(chip):
 
     from kubeflow_tpu.models import generate
 
-    widths, slots, max_len = LFM2
-    e = _engine_shapes(chip, widths, slots, max_len, max_new_tokens=384)
+    widths, slots, max_len, new = LFM2
+    e = _engine_shapes(chip, widths, slots, max_len, max_new_tokens=new)
 
     @functools.cache
     def compiled(program):
@@ -421,11 +462,7 @@ def lfm2_program(chip):
                 e["cfg"], e["params"], e["state"], e["decode"], 8,
                 e["arg"](slots, e["table_blocks"]), e["arg"](),
                 paged_kernel=True).compile()
-        scalar = e["arg"]()
-        return e, generate.prefill_chunk_into_slot.lower(
-            e["cfg"], e["params"], e["state"], e["decode"], e["arg"](1, 64),
-            scalar, scalar, scalar, scalar, scalar,
-            e["arg"](1, e["table_blocks"])).compile()
+        return e, _compile_chunk(e, max_len)
 
     return compiled
 
@@ -451,6 +488,7 @@ def test_layer_types_programs_hold_both_states_and_the_experts_in_place(
                                 (2048, 3072), (1536, 2048),
                                 (2, 2048, 11_776)]) == []
     assert text.count('op_name="ragged-dot-metadata"') == 8
+    assert _fusions_given_up(text) == []
     m = compiled.memory_analysis()
     held = 2 * int(np.prod(pool.shape)) * 2 + int(np.prod(conv.shape)) * 2
     assert m.alias_size_in_bytes >= held
