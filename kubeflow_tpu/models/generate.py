@@ -123,6 +123,28 @@ def _view_attention(q, view_k, view_v, cache_len, pad_amount):
     return jnp.moveaxis(out, 0, 1).reshape(q.shape)
 
 
+def _page_coordinates(tables, cache_len, write_cols, b, t, nb, bt):
+    """Where a call's ``t`` new columns a row land in a paged pool of
+    ``nb`` blocks of ``bt`` positions: (physical block [b, t], offset in
+    it [b, t], the rows' first column [b] or None where ``cache_len`` is
+    one scalar for the batch).  Sentinel table entries (== nb) and
+    logical indices past the table both park the write out of the
+    pool's range: the scatter drops them."""
+    mb = tables.shape[1]
+    base = None
+    if not isinstance(cache_len, int) and cache_len.ndim == 1:
+        base = cache_len if write_cols is None else write_cols
+        pos = base[:, None] + jnp.arange(t)[None, :]
+    else:
+        pos = cache_len + jnp.arange(t)[None, :]
+        pos = jnp.broadcast_to(pos, (b, t))
+    blk_slot = pos // bt
+    blk = jnp.take_along_axis(
+        tables, jnp.clip(blk_slot, 0, mb - 1), axis=1)
+    blk = jnp.where(blk_slot < mb, blk, nb)
+    return blk, pos % bt, base
+
+
 def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
                      cache_len, positions, pad_amount=None, write_cols=None,
                      tables=None, adapters=None, paged_kernel=False,
@@ -173,6 +195,15 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
     cache_kv None (a forward without a cache, the flax module's): plain
     causal attention over the call's own q, k, v.
     """
+    if cfg.latent:
+        if pad_amount is not None or adapters is not None:
+            raise ValueError(
+                "latent attention with left-padded rows or adapters: "
+                "not built")
+        return _latent_attention_block(
+            cfg, layer_params, x, cache_kv, cache_len, positions,
+            write_cols=write_cols, tables=tables,
+            paged_kernel=paged_kernel, plane=plane)
     attn = layer_params["attn"]
     dt = cfg.dtype
 
@@ -228,20 +259,8 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
                                              mode="drop")
 
         with jax.named_scope("kft.kv_write"):
-            if per_row:
-                base = cache_len if write_cols is None else write_cols
-                pos = base[:, None] + jnp.arange(t)[None, :]
-            else:
-                pos = cache_len + jnp.arange(t)[None, :]
-                pos = jnp.broadcast_to(pos, (x.shape[0], t))
-            blk_slot = pos // bt
-            # Physical block per position: sentinel table entries
-            # (== nb) and logical indices past the table both park the
-            # write out of the pool's range — the scatter drops them.
-            blk = jnp.take_along_axis(
-                tables, jnp.clip(blk_slot, 0, mb - 1), axis=1)
-            blk = jnp.where(blk_slot < mb, blk, nb)
-            off = pos % bt
+            blk, off, base = _page_coordinates(
+                tables, cache_len, write_cols, x.shape[0], t, nb, bt)
             ck = store(ck, k)
             cv = store(cv, v)
 
@@ -345,6 +364,180 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
     return x, (None if cache_kv is None else (ck, cv))
 
 
+def _rope_pairs(x, positions, theta):
+    """Rotary positions over interleaved pairs (2i, 2i + 1) of the last
+    axis (``transformer.rope`` pairs i with i + d / 2).  x [b, s, h, d]."""
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _latent_view_attention(q_row, view, value_lanes, kv_offset, scale):
+    """Attention of absorbed queries q_row [b, t, h, row] over a slot's
+    gathered view of the latent pool [b, s, row] -> [b, t, h,
+    value_lanes], in the latent space: a position's row is key (whole)
+    and value (its first ``value_lanes`` lanes) for every head, so the
+    heads fold into the rows of ONE product against the view.
+    ``kv_offset`` (a scalar, or [b] per row): position of query column 0
+    among the view's.  Softmax in float32; in tiles of
+    ``_VIEW_QUERY_TILE`` query rows where t holds several, as
+    ``_view_attention`` (the float32 scores are [h, tile, s])."""
+    dt = q_row.dtype
+    b, t, h, _ = q_row.shape
+    values = view[..., :value_lanes]
+
+    def attend(q, offset):
+        n = q.shape[1]
+        # (query, head) pairs are the rows of one product with the view;
+        # row m is query m // h, which sees the view up to its position.
+        sc = jnp.einsum("bmr,bkr->bmk", q.reshape(b, n * h, -1), view,
+                        preferred_element_type=jnp.float32) * scale
+        q_pos = jnp.asarray(offset)[..., None] + jnp.arange(n * h) // h
+        keep = jnp.arange(view.shape[1]) <= q_pos[..., None]
+        w = jax.nn.softmax(
+            jnp.where(keep, sc, jnp.finfo(jnp.float32).min), axis=-1)
+        out = jnp.einsum("bmk,bkc->bmc", w.astype(dt), values,
+                         preferred_element_type=jnp.float32)
+        return out.astype(dt).reshape(b, n, h, value_lanes)
+
+    tiles, rest = divmod(t, _VIEW_QUERY_TILE)
+    if tiles < 2 or rest:
+        return attend(q_row, kv_offset)
+
+    def tile(i):
+        first = i * _VIEW_QUERY_TILE
+        return attend(jax.lax.dynamic_slice_in_dim(
+            q_row, first, _VIEW_QUERY_TILE, axis=1), kv_offset + first)
+
+    out = jax.lax.map(tile, jnp.arange(tiles))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, h, value_lanes)
+
+
+def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
+                            cache_len, positions, write_cols=None,
+                            tables=None, paged_kernel=False, plane=None):
+    """Latent attention (MLA, TransformerConfig.attention_kind) in the
+    attention block's place: norm, projections, the write of the token's
+    latent row, attention, output projection, residual.
+
+    ``cache`` is ``(pool,)``, the ONE stacked latent pool
+    [kv_planes, num_blocks, block_tokens, cfg.latent_row] (a row: the
+    normed, scaled latent, then the shared rotary key, then zeros up to
+    whole 128-lane rows), with ``tables`` / ``cache_len`` / ``write_cols``
+    / ``plane`` / ``paged_kernel`` as in ``_attention_block``; or None (a
+    forward without a cache).  Two forms of one arithmetic:
+
+    - against the pool the key expansion is ABSORBED into the query
+      (``qt_j = q_nope_j W_uk_j^T``): all heads score the latent rows as
+      they lie, the weights sum the rows (``ot_j``), and the result is
+      expanded (``ot_j W_uv_j``), so a position is read once for every
+      head and both products, and the heads fold into the rows of one
+      matmul.  A decode step (ONE query position a row over per-row
+      lengths) with ``paged_kernel`` reads the pages in place through
+      ops/paged_attention.py; a prefill chunk, and a decode step on any
+      other backend, attend over the slot's gathered view
+      (``_latent_view_attention``);
+    - the forward without a cache EXPANDS keys and values from the latent
+      (``c W_uk``, ``c W_uv``) and attends as any other model does, as
+      the plain reference does everywhere.
+    """
+    attn = layer_params["attn"]
+    dt = cfg.dtype
+    e, rkv = cfg.d_model, cfg.mla_kv_rank
+    dn, dr = cfg.mla_nope_dim, cfg.mla_rope_dim
+    up_q, up_kv = (e / cfg.mla_q_rank) ** 0.5, (e / rkv) ** 0.5
+    b, t = x.shape[:2]
+    scale = (dn + dr) ** -0.5
+    wk_b, wv_b = attn["wk_b"], attn["wv_b"]
+
+    with jax.named_scope("kft.mla_q"):
+        y = _rms_norm(x, layer_params["attn_norm"]["scale"], cfg.norm_eps,
+                      dt)
+        qa = qeinsum("bse,er->bsr", y, attn["wq_a"], dt)
+        qa = _rms_norm(qa, attn["q_norm"]["scale"] * up_q, cfg.norm_eps, dt)
+        q = qeinsum("bsr,rhd->bshd", qa, attn["wq_b"], dt)
+        q_nope = q[..., :dn]
+        q_rope = _rope_pairs(q[..., dn:], positions, cfg.rope_theta)
+    with jax.named_scope("kft.mla_latent_write"):
+        kva = qeinsum("bse,ec->bsc", y, attn["wkv_a"], dt)
+        c = _rms_norm(kva[..., :rkv], attn["kv_norm"]["scale"] * up_kv,
+                      cfg.norm_eps, dt)
+        k_r = _rope_pairs(kva[:, :, None, rkv:], positions,
+                          cfg.rope_theta)[:, :, 0]
+        if cache is not None:
+            pool, = cache
+            nb, bt, width = pool.shape[1:]
+            mb, pad = tables.shape[1], width - rkv - dr
+            row = jnp.concatenate(
+                [c, k_r, jnp.zeros((b, t, pad), dt)], axis=-1)
+            blk, off, base = _page_coordinates(
+                tables, cache_len, write_cols, b, t, nb, bt)
+            pool = pool.at[plane, blk, off].set(
+                row.astype(pool.dtype), mode="drop")
+            cache = (pool,)
+
+    if cache is None:
+        with jax.named_scope("kft.mla_prefill"):
+            k = jnp.concatenate(
+                [qeinsum("bkc,hdc->bkhd", c, wk_b, dt), jnp.broadcast_to(
+                    k_r[:, :, None], (b, t, cfg.n_heads, dr))], axis=-1)
+            out = dot_product_attention(
+                jnp.concatenate([q_nope, q_rope], axis=-1), k,
+                qeinsum("bkc,chd->bkhd", c, wv_b, dt), causal=True)
+    else:
+        decode = t == 1 and base is not None
+        with jax.named_scope(
+                "kft.mla_decode" if decode else "kft.mla_prefill"):
+            qt = qeinsum("bshd,hdc->bshc", q_nope, wk_b, dt)
+            q_row = jnp.concatenate(
+                [qt, q_rope, jnp.zeros((b, t, cfg.n_heads, pad), dt)],
+                axis=-1)                                # [b, t, h, width]
+            if decode and paged_kernel:
+                from kubeflow_tpu.ops import paged_attention
+
+                # The step's own row is in the pool already; a parked
+                # write marks a retired row, which reads nothing.
+                attend = jnp.where(base < mb * bt, cache_len + 1, 0)
+                ot = paged_attention.paged_latent_decode_attention(
+                    q_row[:, 0], pool, plane, tables, attend, rkv,
+                    scale)[:, None]
+            else:
+                ot = _latent_view_attention(
+                    q_row, pool[plane, tables].reshape(b, mb * bt, width),
+                    rkv, cache_len, scale)
+            out = qeinsum("bshc,chd->bshd", ot, wv_b, dt)
+    with jax.named_scope("kft.attn_out"):
+        x = x + qeinsum("bshd,hde->bse", out, attn["wo"], dt)
+    return x, cache
+
+
+def _dense_mlp(cfg: TransformerConfig, mlp, y, adapters=None):
+    """A SwiGLU over the normed stream y [b, s, e] (no norm, no
+    residual)."""
+    dt = cfg.dtype
+    gate = qeinsum("bse,ef->bsf", y, mlp["wi"][0], dt)
+    up = qeinsum("bse,ef->bsf", y, mlp["wi"][1], dt)
+    if adapters is not None:
+        ad = adapters["mlp"]
+        gate = gate + _lora(y, ad["wi_a"][:, 0], ad["wi_b"][:, 0],
+                            "bse,ber->bsr", "bsr,brf->bsf")
+        up = up + _lora(y, ad["wi_a"][:, 1], ad["wi_b"][:, 1],
+                        "bse,ber->bsr", "bsr,brf->bsf")
+    h = jax.nn.silu(gate) * up
+    y = qeinsum("bsf,fe->bse", h, mlp["wo"], dt)
+    if adapters is not None:
+        ad = adapters["mlp"]
+        y = y + _lora(h, ad["wo_a"], ad["wo_b"],
+                      "bsf,bfr->bsr", "bsr,bre->bse")
+    return y
+
+
 def _dense_ff(cfg: TransformerConfig, layer_params, x, adapters=None):
     """The block's SwiGLU feed-forward with its norm and residual."""
     dt = cfg.dtype
@@ -354,21 +547,7 @@ def _dense_ff(cfg: TransformerConfig, layer_params, x, adapters=None):
 
     with jax.named_scope("kft.mlp"):
         y = norm(x, layer_params["mlp_norm"]["scale"])
-        mlp = layer_params["mlp"]
-        gate = qeinsum("bse,ef->bsf", y, mlp["wi"][0], dt)
-        up = qeinsum("bse,ef->bsf", y, mlp["wi"][1], dt)
-        if adapters is not None:
-            ad = adapters["mlp"]
-            gate = gate + _lora(y, ad["wi_a"][:, 0], ad["wi_b"][:, 0],
-                                "bse,ber->bsr", "bsr,brf->bsf")
-            up = up + _lora(y, ad["wi_a"][:, 1], ad["wi_b"][:, 1],
-                            "bse,ber->bsr", "bsr,brf->bsf")
-        h = jax.nn.silu(gate) * up
-        y = qeinsum("bsf,fe->bse", h, mlp["wo"], dt)
-        if adapters is not None:
-            ad = adapters["mlp"]
-            y = y + _lora(h, ad["wo_a"], ad["wo_b"],
-                          "bsf,bfr->bsr", "bsr,bre->bse")
+        y = _dense_mlp(cfg, layer_params["mlp"], y, adapters)
         if cfg.sandwich_norm:
             with jax.named_scope("kft.loop_norm"):
                 y = norm(y, layer_params["mlp_out_norm"]["scale"])
@@ -435,55 +614,130 @@ def _conv_block(cfg: TransformerConfig, layer_params, x, state=None,
     return x, state
 
 
-def _sparse_ff(cfg: TransformerConfig, layer_params, x, live=None):
-    """Sparse experts in the feed-forward's place (TransformerConfig.
-    layer_types), with norm and residual; nothing is dropped.
+def _experts(cfg: TransformerConfig, moe, y, live=None):
+    """The expert layer over normed rows y [m, e]: ``sum over the chosen
+    i of w_i E_i(y)`` -> ([m, e] float32, counts); nothing is dropped.
 
-    Every (row, chosen expert) pair is sorted by expert and the experts'
-    SwiGLUs run as two grouped products (``jax.lax.ragged_dot``) over
-    the stacked expert matrices where they lie: a row meets only the
-    experts it chose, and an expert no row chose is not read.
-    ``moe/wi`` is [experts, e, 2 f], gate then up along the last axis.
-    live [b, t] bool (None: all): rows that are no token (a parked slot,
-    a final chunk's padding) choose nothing and come back unchanged.
-    Returns (x, distinct experts with at least one row).
+    The router (TransformerConfig.moe_score / moe_normalize / moe_scale)
+    scores every output in float32, ``moe/bias`` selects and does not
+    weigh.  Every (row, chosen expert) pair whose expert this program
+    HOLDS (``moe_experts_offset``, ``moe_held``) is sorted by expert and
+    the experts' SwiGLUs run as two grouped products
+    (``jax.lax.ragged_dot``) over the stacked expert matrices where they
+    lie: a row meets only the experts it chose, and an expert no row
+    chose is not read.  ``moe/wi`` is [held, e, 2 f], gate then up along
+    the last axis.  A pair whose expert lies on another chip is in no
+    group and adds nothing here; a pair that chose a zero-compute expert
+    (an output at or past ``moe_experts``) is in no group either and adds
+    its weight times the row itself.
+    live [m] bool (None: all): rows that are no token (a parked slot, a
+    final chunk's padding) choose nothing and come back as zeros.
+    counts: int32 scalars over the live rows: ``touched`` (distinct held
+    experts with at least one row), and the pairs by where they fell,
+    ``held``, ``zero``, ``absent``.
     """
     dt, n, k, f = cfg.dtype, cfg.moe_experts, cfg.moe_top_k, cfg.moe_d_ff
-    moe = layer_params["moe"]
+    held, first, zero = cfg.moe_held, cfg.moe_experts_offset, \
+        cfg.moe_zero_experts
+    m, e = y.shape
+    with jax.named_scope("kft.moe_route"):
+        # In float32 whatever the model computes in: a score that
+        # rounding moves past another changes a whole expert.
+        scores = jnp.einsum(
+            "me,en->mn", y.astype(jnp.float32),
+            moe["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(scores) if cfg.moe_score == "sigmoid" \
+            else jax.nn.softmax(scores, axis=-1)
+        # The bias selects and does not weigh.
+        _, chosen = jax.lax.top_k(scores + moe["bias"], k)
+        gates = jnp.take_along_axis(scores, chosen, axis=1)
+        if cfg.moe_normalize:
+            gates = gates / (gates.sum(axis=1, keepdims=True) + 1e-6)
+        if cfg.moe_scale != 1.0:
+            gates = gates * cfg.moe_scale
+        pairs = chosen.reshape(-1)
+        if held != n or zero:
+            # Another chip's expert, or one without weights: past every
+            # expert held here.
+            pairs = jnp.where((pairs >= first) & (pairs < first + held),
+                              pairs - first, held)
+        if live is not None:
+            # Past every expert: sorted last, in no group.
+            pairs = jnp.where(jnp.repeat(live, k), pairs, held)
+        order = jnp.argsort(pairs)
+        sizes = jnp.zeros((held,), jnp.int32).at[pairs].add(1, mode="drop")
+        rows = y[order // k]
+    with jax.named_scope("kft.moe_experts"):
+        h = jax.lax.ragged_dot(rows, moe["wi"].astype(dt), sizes)
+        h = jax.nn.silu(h[:, :f]) * h[:, f:]
+        out = jax.lax.ragged_dot(h, moe["wo"].astype(dt), sizes)
+    with jax.named_scope("kft.moe_route"):
+        out = out[jnp.argsort(order)].reshape(m, k, e)
+        in_group = (pairs < held).reshape(m, k)
+        # A row in no group is not written by the grouped product.
+        if held != n or zero:
+            out = jnp.where(in_group[:, :, None], out, 0)
+        elif live is not None:
+            out = jnp.where(live[:, None, None], out, 0)
+        out = jnp.sum(out.astype(jnp.float32) * gates[:, :, None], axis=1)
+    alive = jnp.ones((m,), bool) if live is None else live
+    is_zero = (chosen >= n) & alive[:, None]
+    if zero:
+        with jax.named_scope("kft.moe_zero"):
+            # A weighted copy of the row, no product.
+            out = out + jnp.sum(jnp.where(is_zero, gates, 0), axis=1,
+                                keepdims=True) * y.astype(jnp.float32)
+    n_held = jnp.sum(in_group).astype(jnp.int32)
+    n_zero = jnp.sum(is_zero).astype(jnp.int32)
+    return out, {
+        "touched": jnp.sum(sizes > 0).astype(jnp.int32),
+        "held": n_held, "zero": n_zero,
+        "absent": jnp.sum(alive).astype(jnp.int32) * k - n_held - n_zero}
+
+
+def _sparse_ff(cfg: TransformerConfig, layer_params, x, live=None):
+    """Sparse experts in the feed-forward's place (TransformerConfig.
+    layer_types), with norm and residual: ``_experts`` over the normed
+    stream.  live [b, t] bool (None: all): rows that are no token come
+    back unchanged.  Returns (x, ``_experts``'s counts)."""
     b, t, e = x.shape
     with jax.named_scope("kft.mlp"):
         y = _rms_norm(x, layer_params["mlp_norm"]["scale"], cfg.norm_eps,
-                      dt).reshape(b * t, e)
-        with jax.named_scope("kft.moe_route"):
-            # In float32 whatever the model computes in: a score that
-            # rounding moves past another changes a whole expert.
-            scores = jax.nn.sigmoid(jnp.einsum(
-                "me,en->mn", y.astype(jnp.float32),
-                moe["router"].astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))
-            # The bias selects and does not weigh.
-            _, chosen = jax.lax.top_k(scores + moe["bias"], k)
-            picked = jnp.take_along_axis(scores, chosen, axis=1)
-            gates = picked / (picked.sum(axis=1, keepdims=True) + 1e-6)
-            pairs = chosen.reshape(-1)
-            if live is not None:
-                # Past every expert: sorted last, in no group.
-                pairs = jnp.where(jnp.repeat(live.reshape(-1), k), pairs, n)
-            order = jnp.argsort(pairs)
-            sizes = jnp.zeros((n,), jnp.int32).at[pairs].add(1, mode="drop")
-            rows = y[order // k]
-        with jax.named_scope("kft.moe_experts"):
-            h = jax.lax.ragged_dot(rows, moe["wi"].astype(dt), sizes)
-            h = jax.nn.silu(h[:, :f]) * h[:, f:]
-            out = jax.lax.ragged_dot(h, moe["wo"].astype(dt), sizes)
-        with jax.named_scope("kft.moe_route"):
-            out = out[jnp.argsort(order)].reshape(b * t, k, e)
-            if live is not None:
-                # A row in no group is not written by the grouped product.
-                out = jnp.where(live.reshape(-1, 1, 1), out, 0)
-            y = jnp.sum(out.astype(jnp.float32) * gates[:, :, None], axis=1)
-        x = x + y.astype(dt).reshape(b, t, e)
-    return x, jnp.sum(sizes > 0).astype(jnp.int32)
+                      cfg.dtype).reshape(b * t, e)
+        y, counts = _experts(cfg, layer_params["moe"], y,
+                             None if live is None else live.reshape(-1))
+        x = x + y.astype(cfg.dtype).reshape(b, t, e)
+    return x, counts
+
+
+def _shortcut_double(cfg: TransformerConfig, layer_params, x, cache,
+                     cache_len, positions, live=None, write_cols=None,
+                     tables=None, paged_kernel=False, plane=None):
+    """A ``shortcut_double`` layer (TransformerConfig.layer_types): two
+    attention sublayers on planes ``plane`` and ``plane + 1``, two dense
+    SwiGLUs, and the expert layer that reads the first half's normed
+    stream and is added at the layer's end.  Returns (x, cache,
+    ``_experts``'s counts)."""
+    dt = cfg.dtype
+    b, t, e = x.shape
+    first, second = layer_params["half_0"], layer_params["half_1"]
+    x, cache = _attention_block(
+        cfg, first, x, cache, cache_len, positions, write_cols=write_cols,
+        tables=tables, paged_kernel=paged_kernel, plane=plane)
+    with jax.named_scope("kft.mlp"):
+        m = _rms_norm(x, first["mlp_norm"]["scale"], cfg.norm_eps, dt)
+        s, counts = _experts(
+            cfg, layer_params["moe"], m.reshape(b * t, e),
+            None if live is None else live.reshape(-1))
+        x = x + _dense_mlp(cfg, first["mlp"], m)
+    x, cache = _attention_block(
+        cfg, second, x, cache, cache_len, positions, write_cols=write_cols,
+        tables=tables, paged_kernel=paged_kernel, plane=plane + 1)
+    x = _dense_ff(cfg, second, x)
+    with jax.named_scope("kft.scmoe_shortcut"):
+        x = x + s.astype(dt).reshape(b, t, e)
+    return x, cache, counts
 
 
 def _embed_tokens(cfg: TransformerConfig, params, tokens, cache_len,
@@ -529,22 +783,22 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
                         fresh=None, n_new=None):
     """The forward of a stack that states its ``layer_types``
     (TransformerConfig): tokens [b, t] -> (logits [b, t, v], cache,
-    conv, experts touched).
+    conv, the expert layers' counts).
 
     The layers are walked one by one over ``params["layers"][str(i)]``:
     no two need have the same leaves, every matrix is an array of its
     own that its product reads where it lies, and the attention layers
     alone own planes of the paged pool (plane j is the j-th of them).
-    ``cache`` is the stacked pool (k, v) that the serving programs carry
-    and donate, ``tables`` their block tables, ``cache_len`` /
+    ``cache`` is the stacked pool, (k, v) or (latent,), that the serving
+    programs carry and donate, ``tables`` their block tables, ``cache_len`` /
     ``write_cols`` / ``paged_kernel`` as in ``_forward_with_cache``;
     ``conv`` is the [conv layers, slots, K - 1, e] state of the
     convolution layers with ``rows`` / ``fresh`` / ``n_new`` as in
     ``_conv_block``.  Rows with ``n_new`` 0 and columns at or past
     ``n_new`` are no tokens: they choose no expert.  Without ``cache``
     and ``conv`` this is the plain forward of the whole sequence (the
-    flax module's).  The last value counts, over the sparse layers, the
-    distinct experts that got a row.
+    flax module's).  The last value sums ``_experts``'s counts over the
+    sparse layers (None without any).
     """
     from flax import linen as nn
 
@@ -553,9 +807,22 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
     live = None if n_new is None else (
         jnp.arange(tokens.shape[1])[None, :] < n_new[:, None])
     plane = plane_c = 0
-    touched = jnp.zeros((), jnp.int32)
+    counts = None
+
+    def count(n):
+        return n if counts is None else jax.tree_util.tree_map(
+            jnp.add, counts, n)
+
     for i, kind in enumerate(cfg.layer_types):
         layer_params = params["layers"][str(i)]
+        if kind == "shortcut_double":
+            x, cache, n = _shortcut_double(
+                cfg, layer_params, x, cache, cache_len, positions, live,
+                write_cols=write_cols, tables=tables,
+                paged_kernel=paged_kernel, plane=plane)
+            counts = count(n)
+            plane += 2
+            continue
         if kind == "conv":
             x, state = _conv_block(
                 cfg, layer_params, x,
@@ -572,10 +839,10 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
             plane += 1
         if cfg.layer_is_sparse(i):
             x, n = _sparse_ff(cfg, layer_params, x, live)
-            touched = touched + n
+            counts = count(n)
         else:
             x = _dense_ff(cfg, layer_params, x)
-    return _logits(cfg, params, x), cache, conv, touched
+    return _logits(cfg, params, x), cache, conv, counts
 
 
 def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
@@ -933,11 +1200,29 @@ def init_paged_state(cfg: TransformerConfig, slots: int,
     convolution layer's gated input (a slot's first chunk starts from
     zeros whatever is there), and, with sparse experts, the scalar
     ``moe_touched``: the distinct experts that got a row, summed over
-    the sparse layers and the steps of the LAST decode_rounds call.
+    the sparse layers and the steps of the LAST decode_rounds call; where
+    the experts held are a share, or some need no weights
+    (``cfg.moe_partial``), also ``moe_pairs`` [3]: that call's (row,
+    choice) pairs that fell on an expert held here, on a zero-compute
+    expert, and on an expert of another chip.
+
+    With latent attention (``cfg.latent``) the pool is ONE array,
+    ``cache_latent`` [cfg.kv_planes, num_blocks, block_tokens,
+    cfg.latent_row], key and value at once, in place of ``cache_k`` and
+    ``cache_v`` (``pool_sides`` names what a state holds).
     """
-    cache_k, cache_v = init_cache(cfg, num_blocks, block_tokens,
-                                  kv_cache_dtype)
+    if cfg.latent:
+        if kv_cache_dtype != "model":
+            raise ValueError("an int8 latent pool: not built")
+        pool = {"cache_latent": jnp.zeros(
+            (cfg.kv_planes, num_blocks, block_tokens, cfg.latent_row),
+            cfg.dtype)}
+    else:
+        pool = dict(zip(("cache_k", "cache_v"), init_cache(
+            cfg, num_blocks, block_tokens, kv_cache_dtype)))
     extra = {}
+    if cfg.moe_partial:
+        extra["moe_pairs"] = jnp.zeros((3,), jnp.int32)
     if cfg.conv_planes:
         extra["conv"] = jnp.zeros(
             (cfg.conv_planes, slots, cfg.conv_kernel - 1, cfg.d_model),
@@ -946,8 +1231,7 @@ def init_paged_state(cfg: TransformerConfig, slots: int,
         extra["moe_touched"] = jnp.zeros((), jnp.int32)
     return {
         **extra,
-        "cache_k": cache_k,
-        "cache_v": cache_v,
+        **pool,
         "lengths": jnp.zeros((slots,), jnp.int32),
         "stop_len": jnp.zeros((slots,), jnp.int32),
         "last_token": jnp.zeros((slots,), jnp.int32),
@@ -957,8 +1241,16 @@ def init_paged_state(cfg: TransformerConfig, slots: int,
     }
 
 
-def _pool_block_tokens(cache) -> int:
-    """Static block width of a paged pool array ([L, NB, bt, ...])."""
+def pool_sides(state) -> Tuple[str, ...]:
+    """The keys under which a paged state holds its pool: keys and values,
+    or the one latent pool."""
+    return ("cache_latent",) if "cache_latent" in state \
+        else ("cache_k", "cache_v")
+
+
+def _pool_block_tokens(state) -> int:
+    """Static block width of a paged state's pool ([L, NB, bt, ...])."""
+    cache = state[pool_sides(state)[0]]
     vals = cache.values if isinstance(cache, QTensor) else cache
     return vals.shape[2]
 
@@ -1035,6 +1327,7 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
     attention reads the pool in place through ops/paged_attention.py
     instead of the gathered view."""
     lengths, done = state["lengths"], state["done"]
+    sides = pool_sides(state)
     advance = ~done
     # Retired slots park their write past the table span; the
     # block scatter drops it.
@@ -1042,9 +1335,9 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
     if cfg.layer_types:
         # Only live rows are tokens: a parked slot, or one in
         # mid-prefill, keeps its convolution state and chooses no expert.
-        logits, (ck, cv), conv, touched = forward_layer_types(
+        logits, cache, conv, counts = forward_layer_types(
             cfg, params, state["last_token"][:, None],
-            (state["cache_k"], state["cache_v"]), lengths,
+            tuple(state[side] for side in sides), lengths,
             write_cols=write_cols, tables=tables,
             paged_kernel=paged_kernel, conv=state.get("conv"),
             n_new=advance.astype(jnp.int32))
@@ -1052,11 +1345,14 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
         if conv is not None:
             state["conv"] = conv
         if "moe_touched" in state:
-            state["moe_touched"] = state["moe_touched"] + touched
+            state["moe_touched"] = state["moe_touched"] + counts["touched"]
+        if "moe_pairs" in state:
+            state["moe_pairs"] = state["moe_pairs"] + jnp.stack(
+                [counts["held"], counts["zero"], counts["absent"]])
     else:
-        logits, (ck, cv) = _forward_with_cache(
+        logits, cache = _forward_with_cache(
             cfg, params, state["last_token"][:, None],
-            (state["cache_k"], state["cache_v"]), lengths,
+            tuple(state[side] for side in sides), lengths,
             write_cols=write_cols, tables=tables,
             adapter_ids=state.get("adapter_ids"),
             paged_kernel=paged_kernel)
@@ -1078,8 +1374,7 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
         new_done = done | (new_lengths >= state["stop_len"])
         if decode.eos_token >= 0:
             new_done = new_done | (advance & (nxt == decode.eos_token))
-    state = dict(state)
-    state["cache_k"], state["cache_v"] = ck, cv
+    state = dict(state, **dict(zip(sides, cache)))
     state["lengths"] = new_lengths
     state["last_token"] = nxt
     state["done"] = new_done
@@ -1118,11 +1413,13 @@ def decode_rounds(cfg: TransformerConfig, params, state,
     tokens do not depend on how the steps are cut into rounds.
     ``paged_kernel``: as there.
     """
-    park = tables.shape[1] * _pool_block_tokens(state["cache_k"])
+    park = tables.shape[1] * _pool_block_tokens(state)
     slots = state["done"].shape[0]
     len0 = state["lengths"]
     if "moe_touched" in state:
         state = dict(state, moe_touched=jnp.zeros((), jnp.int32))
+    if "moe_pairs" in state:
+        state = dict(state, moe_pairs=jnp.zeros((3,), jnp.int32))
     cap = jnp.minimum(jnp.asarray(max_steps, jnp.int32),
                       jnp.int32(k))
 
@@ -1187,9 +1484,10 @@ def verify_step(cfg: TransformerConfig, params, state,
         raise ValueError(
             "verify_step rolls a rejected draft back by not moving a "
             "frontier; a convolution state has no frontier to leave "
-            "behind (speculation over a slot state: not built)")
+            "behind, and a stack with layer_types has no verify forward "
+            "(speculation there: not built)")
     lengths, done = state["lengths"], state["done"]
-    park = tables.shape[1] * _pool_block_tokens(state["cache_k"])
+    park = tables.shape[1] * _pool_block_tokens(state)
     advance = ~done
     write_cols = jnp.where(advance, lengths, park)
     tokens = jnp.concatenate(
@@ -1302,19 +1600,20 @@ def prefill_chunk_into_slot(
     aid = (jnp.zeros((), jnp.int32) if adapter_id is None
            else jnp.reshape(jnp.asarray(adapter_id, jnp.int32), ()))
     conv = None
+    sides = pool_sides(state)
     if cfg.layer_types:
         # The slot's first chunk starts its convolution layers from
         # zeros; a later one goes on from the state the last left, and
         # each leaves the state of its last real token.
         first = jnp.reshape(start == 0, (1,))
         real = jnp.reshape(jnp.clip(prompt_len - start, 0, w), (1,))
-        logits, (ck, cv), conv, _ = forward_layer_types(
-            cfg, params, tokens, (state["cache_k"], state["cache_v"]),
+        logits, cache, conv, _ = forward_layer_types(
+            cfg, params, tokens, tuple(state[side] for side in sides),
             start, tables=table_row, conv=state.get("conv"),
             rows=jnp.reshape(slot, (1,)), fresh=first, n_new=real)
     else:
-        logits, (ck, cv) = _forward_with_cache(
-            cfg, params, tokens, (state["cache_k"], state["cache_v"]),
+        logits, cache = _forward_with_cache(
+            cfg, params, tokens, tuple(state[side] for side in sides),
             start, tables=table_row, adapter_ids=aid[None])
     with jax.named_scope("kft.sample"):
         # First-token sampling from the last REAL prompt position of this
@@ -1340,8 +1639,7 @@ def prefill_chunk_into_slot(
         if decode.eos_token >= 0:
             done_final = done_final | (tok[0] == decode.eos_token)
 
-        state = dict(state)
-        state["cache_k"], state["cache_v"] = ck, cv
+        state = dict(state, **dict(zip(sides, cache)))
         if conv is not None:
             state["conv"] = conv
         if "adapter_ids" in state:

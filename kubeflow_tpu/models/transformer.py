@@ -167,23 +167,112 @@ class TransformerConfig:
     #     weights s_i / (sum of the chosen s + 1e-6).
     #   ``qk_norm``: an RMSNorm over each head of q and of k, scales of
     #     their own, before the rotary positions.
+    #   "shortcut_double" (LongCat-Flash's double layer): TWO attention
+    #     sublayers and TWO dense SwiGLUs of ``d_ff`` a layer, and one
+    #     expert layer that reads the first half's normed stream and is
+    #     added at the layer's end (the shortcut):
+    #       a = x + Attn_0(N_0(x));  m = N'_0(a);  s = Experts(m)
+    #       b = a + Dense_0(m);      c = b + Attn_1(N_1(b))
+    #       y = c + Dense_1(N'_1(c)) + s
+    #     It owns two planes of the pool.  Every such layer holds
+    #     experts (``moe_dense_layers`` must be 0).
     layer_types: Tuple[str, ...] = ()
     conv_kernel: int = 3
     qk_norm: bool = False
     moe_dense_layers: int = 0
     moe_d_ff: int = 0
+    # The router of a stack with ``layer_types``, as fields (the
+    # defaults are LFM2's): scores ``sigmoid`` or ``softmax`` of y W_r
+    # in float32; the chosen scores divided by their sum or not; a
+    # factor on the weights.  ``moe/bias`` always selects and never
+    # weighs.
+    moe_score: str = "sigmoid"
+    moe_normalize: bool = True
+    moe_scale: float = 1.0
+    # Experts that need no weights: the router has ``moe_experts`` +
+    # ``moe_zero_experts`` outputs, and a chosen output at or past
+    # ``moe_experts`` returns its input (times its weight).
+    moe_zero_experts: int = 0
+    # The chip's share of the experts (expert parallelism without its
+    # exchange): this program holds the weights of experts
+    # [moe_experts_offset, moe_experts_offset + moe_experts_held) of
+    # ``moe_experts`` (0 held = all of them), routes over every output,
+    # and computes the part of the result that its own experts give,
+    # plus the zero-compute experts' part, which belongs to the chip
+    # that owns the token.  What an absent expert would add is left out.
+    moe_experts_held: int = 0
+    moe_experts_offset: int = 0
+    # Attention of every attending layer: "gqa" (the block above) or
+    # "latent" (MLA): queries through a low-rank pair with a norm
+    # between (``mla_q_rank``), keys and values expanded from ONE
+    # normed latent of ``mla_kv_rank`` a token, beside a rotary key of
+    # ``mla_rope_dim`` that all heads share; a query / key head is
+    # ``mla_nope_dim`` + ``mla_rope_dim`` wide, a value head
+    # ``mla_v_dim``; rotary pairs are interleaved (2i, 2i + 1); the two
+    # low-rank activations are scaled by sqrt(d_model / rank) after
+    # their norms (the one form built).  The cache of a token and plane is
+    # ``(latent, rotary key)``, key and value at once: ONE pool
+    # ``cache_latent`` [planes, blocks, block_tokens, latent_row] in
+    # place of a k and a v pool (``latent_row``: the two parts, padded
+    # to whole 128-lane rows).  ``n_kv_heads`` / ``head_dim`` are unread.
+    attention_kind: str = "gqa"
+    mla_q_rank: int = 0
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
 
     def __post_init__(self):
-        assert self.n_heads % self.n_kv_heads == 0
+        # Latent attention reads neither n_kv_heads nor head_dim.
+        assert self.latent or self.n_heads % self.n_kv_heads == 0
         # JSON hands a list over; the config is a static (hashed) argument.
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.attention_kind not in ("gqa", "latent"):
+            raise ValueError(
+                f"attention_kind={self.attention_kind!r} not in "
+                "('gqa', 'latent')")
+        if self.moe_score not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"moe_score={self.moe_score!r} not in "
+                "('sigmoid', 'softmax')")
+        if self.latent:
+            sizes = (self.mla_q_rank, self.mla_kv_rank, self.mla_nope_dim,
+                     self.mla_rope_dim, self.mla_v_dim)
+            if min(sizes) < 1 or self.mla_rope_dim % 2:
+                raise ValueError(
+                    "attention_kind='latent' needs mla_q_rank, "
+                    "mla_kv_rank, mla_nope_dim, mla_rope_dim (even) and "
+                    f"mla_v_dim, got {sizes}")
+            if not self.layer_types or self.qk_norm:
+                raise ValueError(
+                    "attention_kind='latent' runs in a stack that states "
+                    "its layer_types, without qk_norm (the rest: not "
+                    "built)")
+        if self.moe_zero_experts or self.moe_experts_held \
+                or self.moe_experts_offset:
+            held, first = self.moe_experts_held, self.moe_experts_offset
+            if not self.layer_types or min(
+                    self.moe_zero_experts, held, first) < 0 \
+                    or first + (held or self.moe_experts) \
+                    > self.moe_experts:
+                raise ValueError(
+                    f"experts [{first}, {first} + {held}) of "
+                    f"{self.moe_experts} with {self.moe_zero_experts} "
+                    "zero-compute experts: not a share of a stack with "
+                    "layer_types")
         if self.layer_types:
-            unknown = set(self.layer_types) - {"full_attention", "conv"}
+            kinds = {"full_attention", "conv", "shortcut_double"}
+            unknown = set(self.layer_types) - kinds
             if unknown or len(self.layer_types) != self.n_layers:
                 raise ValueError(
                     f"layer_types must name n_layers={self.n_layers} "
-                    f"layers as 'full_attention' or 'conv', got "
+                    f"layers as one of {sorted(kinds)}, got "
                     f"{self.layer_types}")
+            if "shortcut_double" in self.layer_types and (
+                    not self.moe_experts or self.moe_dense_layers):
+                raise ValueError(
+                    "a shortcut_double layer holds experts: moe_experts "
+                    "> 0 and moe_dense_layers == 0")
             if self.loop_steps > 1 or self.sandwich_norm:
                 raise ValueError(
                     "layer_types with loop_steps > 1 or sandwich_norm: "
@@ -232,8 +321,36 @@ class TransformerConfig:
         """Leading axis of a KV cache: one plane per (loop step, layer),
         of the layers that attend."""
         if self.layer_types:
-            return self.layer_types.count("full_attention")
+            return self.layer_types.count("full_attention") \
+                + 2 * self.layer_types.count("shortcut_double")
         return self.loop_steps * self.n_layers
+
+    @property
+    def latent(self) -> bool:
+        return self.attention_kind == "latent"
+
+    @property
+    def latent_row(self) -> int:
+        """Values a token and plane of the latent pool holds: the normed
+        latent, then the rotary key, each padded to whole 128-lane rows
+        (the chip copies whole rows: 512 + 64 -> 512 + 128)."""
+        def rows(n):
+            return -(-n // 128) * 128
+
+        return rows(self.mla_kv_rank) + rows(self.mla_rope_dim)
+
+    @property
+    def moe_partial(self) -> bool:
+        """Whether an expert layer's result is this chip's part of it, or
+        holds pairs that need no weights: its pairs are then counted by
+        where they fell (``init_paged_state``'s ``moe_pairs``)."""
+        return bool(self.layer_types and self.moe_experts and (
+            self.moe_zero_experts or self.moe_held != self.moe_experts))
+
+    @property
+    def moe_held(self) -> int:
+        """Experts whose weights this program holds."""
+        return self.moe_experts_held or self.moe_experts
 
     @property
     def conv_planes(self) -> int:
@@ -274,7 +391,40 @@ def layer_tree_shapes(cfg: TransformerConfig):
     tree = {"embed": (cfg.vocab_size, e), "final_norm": norm, "layers": {}}
     if not cfg.tied_embeddings:
         tree["w_out"] = (e, cfg.vocab_size)
+    dense = {"wi": (2, e, cfg.d_ff), "wo": (cfg.d_ff, e)}
+    outputs, held = n + cfg.moe_zero_experts, cfg.moe_held
+    experts = {"router": (e, outputs), "bias": (outputs,),
+               "wi": (held, e, 2 * f), "wo": (held, f, e)}
+    if cfg.latent:
+        # An expanded key and value head lie in leaves of their own
+        # (``wk_b``, ``wv_b``): a decode step absorbs the first into the
+        # query and applies the second to the latent-space output, and a
+        # slice of one leaf would be copied out a layer.  ``wk_b`` lies
+        # head-major, [h, d_nope, r_kv]: the absorbing product is a
+        # batch over heads that contracts d_nope, and from [r_kv, h,
+        # d_nope] the chip's compiler relaid the matrix once a step and
+        # sublayer.
+        h, rq, rkv = cfg.n_heads, cfg.mla_q_rank, cfg.mla_kv_rank
+        attn = {"wq_a": (e, rq), "q_norm": {"scale": (rq,)},
+                "wq_b": (rq, h, cfg.mla_nope_dim + cfg.mla_rope_dim),
+                "wkv_a": (e, rkv + cfg.mla_rope_dim),
+                "kv_norm": {"scale": (rkv,)},
+                "wk_b": (h, cfg.mla_nope_dim, rkv),
+                "wv_b": (rkv, h, cfg.mla_v_dim),
+                "wo": (h, cfg.mla_v_dim, e)}
+    else:
+        attn = {"wq": (e, cfg.n_heads, d),
+                "wkv": (2, e, cfg.n_kv_heads, d),
+                "wo": (cfg.n_heads, d, e)}
+        if cfg.qk_norm:
+            attn.update(q_norm={"scale": (d,)}, k_norm={"scale": (d,)})
     for i, kind in enumerate(cfg.layer_types):
+        if kind == "shortcut_double":
+            half = {"attn_norm": norm, "attn": attn, "mlp_norm": norm,
+                    "mlp": dense}
+            tree["layers"][str(i)] = {"half_0": half, "half_1": half,
+                                      "moe": experts}
+            continue
         layer = {"mlp_norm": norm}
         if kind == "conv":
             layer["conv_norm"] = norm
@@ -283,17 +433,11 @@ def layer_tree_shapes(cfg: TransformerConfig):
                              "w_out": (e, e)}
         else:
             layer["attn_norm"] = norm
-            layer["attn"] = {"wq": (e, cfg.n_heads, d),
-                             "wkv": (2, e, cfg.n_kv_heads, d),
-                             "wo": (cfg.n_heads, d, e)}
-            if cfg.qk_norm:
-                layer["attn"].update(q_norm={"scale": (d,)},
-                                     k_norm={"scale": (d,)})
+            layer["attn"] = attn
         if cfg.layer_is_sparse(i):
-            layer["moe"] = {"router": (e, n), "bias": (n,),
-                            "wi": (n, e, 2 * f), "wo": (n, f, e)}
+            layer["moe"] = experts
         else:
-            layer["mlp"] = {"wi": (2, e, cfg.d_ff), "wo": (cfg.d_ff, e)}
+            layer["mlp"] = dense
         tree["layers"][str(i)] = layer
     return tree
 

@@ -39,6 +39,16 @@ values each head keeps its own lanes.  The kernel is the same; only what
 it is told about rows and groups differs (``supports`` says which head
 sizes can be laid out so).
 
+Latent pages (``paged_latent_decode_attention``).  A model with latent
+attention keeps ONE pool, ``[kv_planes, num_blocks, block_tokens, row]``:
+a position's row (the normed latent, the rotary key all heads share,
+zeros up to whole 128-lane rows) is key and value at once.  The same
+kernel walks it with one side in place of two: ``hkv`` is 1, so every
+query head (its nope part absorbed into the latent space, then its rotary
+part) scores every row, and the value product takes the rows' first
+``value_lanes`` lanes (the latent) of the SAME buffer; the output stays in
+the latent space for the caller to expand.
+
 Arithmetic: operands in the pool's dtype (bf16 on the chip) with
 float32 accumulation, float32 running max / sum / output (online
 softmax), weights cast to the pool's dtype before the value product, as
@@ -59,9 +69,17 @@ from jax.experimental.pallas import tpu as pltpu
 _MASKED = -1e30
 
 
-def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sems, *, mb, bt, hkv, g, pages, nb, scale):
-    """One slot: walk its resident pages ``pages`` at a time."""
+def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
+            pages, nb, scale, value_lanes=None):
+    """One slot: walk its resident pages ``pages`` at a time.
+
+    ``refs``: the pool's sides in HBM (keys and values; or, with
+    ``value_lanes``, the ONE latent pool, whose rows are keys whole and
+    values in their first ``value_lanes`` lanes), the output, a buffer a
+    side, the semaphores."""
+    sides = (len(refs) - 2) // 2
+    hbm, o_ref = refs[:sides], refs[sides]
+    bufs, sems = refs[sides + 1:-1], refs[-1]
     s = pl.program_id(0)
     first = plane_ref[0] * nb             # the plane's page 0 in the view
     n = ntok_ref[s]                       # positions to attend (0: none)
@@ -73,8 +91,8 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, k_hbm, v_hbm, o_ref,
     def _():
         # A page the walk never copied is multiplied by a zero weight:
         # it has to hold numbers, and fresh VMEM need not.
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
 
     def copies(blk, buf, p):
         # Entries below the frontier are real pages; the clamp only
@@ -83,12 +101,11 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, k_hbm, v_hbm, o_ref,
         page = first + jnp.minimum(
             tables_ref[s * mb + blk * pages + p], nb - 1)
         dst = pl.ds(p * rows, rows)
-        return (
+        return tuple(
             pltpu.make_async_copy(
-                k_hbm.at[page], kbuf.at[buf, dst], sems.at[0, buf]),
-            pltpu.make_async_copy(
-                v_hbm.at[page], vbuf.at[buf, dst], sems.at[1, buf]),
-        )
+                hbm[side].at[page], bufs[side].at[buf, dst],
+                sems.at[side, buf])
+            for side in range(sides))
 
     def for_pages(blk, buf, act):
         for p in range(pages):
@@ -118,8 +135,10 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, k_hbm, v_hbm, o_ref,
             for_pages(i + 1, 1 - buf, lambda c: c.start())
 
         for_pages(i, buf, lambda c: c.wait())
-        k = kbuf[buf]                                       # [rows*, d]
-        v = vbuf[buf]
+        k = bufs[0][buf]                                    # [rows*, d]
+        v = bufs[-1][buf]
+        if value_lanes is not None:
+            v = v[:, :value_lanes]
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # [h, rows*]
@@ -135,7 +154,7 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     m0 = jnp.full((h, 1), _MASKED, jnp.float32)
     l0 = jnp.zeros((h, 1), jnp.float32)
-    acc0 = jnp.zeros((h, q.shape[1]), jnp.float32)
+    acc0 = jnp.zeros((h, value_lanes or q.shape[1]), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
     # A slot with nothing to attend (retired) returns zeros.
     o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
@@ -222,3 +241,56 @@ def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
     if pack > 1:
         out = jnp.sum(out.reshape(S, h, pack, head_dim) * lane, axis=2)
     return out
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "value_lanes", "scale", "pages_per_block", "interpret"))
+def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
+                                  value_lanes: int, scale: float, *,
+                                  pages_per_block: int = 32,
+                                  interpret: bool = False):
+    """The latent (MLA) form: ``q [S, h, row]`` against each slot's
+    resident LATENT pages -> ``[S, h, value_lanes]``, in the latent space.
+
+    pool: ``[kv_planes, num_blocks, block_tokens, row]``, the one stacked
+    latent pool, left in HBM.  A position's row is key and value at once:
+    every query head scores against the whole row (the query absorbed
+    into the latent space, then its rotary part; lanes past those hold
+    zeros on both sides), and the weights sum the row's first
+    ``value_lanes`` lanes (the latent), so a page is copied once for all
+    heads and once for both products.  ``row`` is a multiple of 128 (the
+    chip copies whole lane rows: ``TransformerConfig.latent_row``).
+    plane / tables / n_tokens: as ``paged_decode_attention``; ``scale``
+    multiplies the scores (the expanded head's, not the row's).
+    """
+    S, h, row = q.shape
+    planes, nb, bt, _ = pool.shape
+    mb = tables.shape[1]
+    pages = max(1, min(pages_per_block, mb))
+    kernel = functools.partial(
+        _kernel, mb=mb, bt=bt, hkv=1, g=h, pages=pages, nb=nb,
+        scale=scale, value_lanes=value_lanes)
+    return pl.pallas_call(
+        kernel,
+        name="paged_latent_decode_attention",
+        out_shape=jax.ShapeDtypeStruct((S, h, value_lanes), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[
+                pl.BlockSpec((1, h, row), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, h, value_lanes),
+                                   lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bt, row), pool.dtype),
+                pltpu.SemaphoreType.DMA((1, 2)),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(tables.reshape(-1).astype(jnp.int32), n_tokens.astype(jnp.int32),
+      jnp.reshape(plane, (1,)).astype(jnp.int32), q,
+      pool.reshape(planes * nb, bt, row))

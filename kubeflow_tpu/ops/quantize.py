@@ -93,6 +93,12 @@ CONTRACTIONS: Dict[Tuple[str, ...], Tuple[int, ...]] = {
     ("conv", "w_out"): (-2,),      # [e, e] contract e
     ("moe", "wi"): (-2,),          # [n, e, 2f] contract e
     ("moe", "wo"): (-2,),          # [n, f, e] contract f
+    # Latent attention (TransformerConfig.attention_kind).
+    ("attn", "wq_a"): (-2,),       # [e, r_q] contract e
+    ("attn", "wq_b"): (-3,),       # [r_q, h, d] contract r_q
+    ("attn", "wkv_a"): (-2,),      # [e, r_kv + d_rope] contract e
+    ("attn", "wk_b"): (-1,),       # [h, d, r_kv] contract r_kv (a chunk)
+    ("attn", "wv_b"): (-3,),       # [r_kv, h, d] contract r_kv
 }
 
 

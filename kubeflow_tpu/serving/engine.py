@@ -295,6 +295,9 @@ _SUM_KEYS = ("loop_rounds", *_PHASE_KEY.values(),
              "prefill_span_s_sum", "first_tokens",
              "prefill_span_hit_s_sum", "first_tokens_hit",
              "compile_s", "compiled_peak_bytes")
+# The decode steps' (row, choice) pairs by where they fell, in the order
+# of ``state["moe_pairs"]`` (models/generate.py init_paged_state).
+_PAIR_KEYS = ("pairs_held", "pairs_zero", "pairs_absent")
 
 
 class _Phase:
@@ -532,7 +535,10 @@ class DecodeEngine:
     ):
         import jax
 
-        from kubeflow_tpu.models.generate import init_paged_state
+        from kubeflow_tpu.models.generate import (
+            init_paged_state,
+            pool_sides,
+        )
         from kubeflow_tpu.runtime.prom import REGISTRY
 
         if slots < 1:
@@ -548,7 +554,18 @@ class DecodeEngine:
         # prompt whole, and what rests on pages alone is refused by
         # name before any token.
         self._slot_state = cfg.conv_planes > 0
-        if self._slot_state:
+        # A latent pool (``cfg.latent``: ONE array, key and value at
+        # once) has no per-slot state, so pages alias and prefixes are
+        # reused as for any other model; what MOVES pages between tiers
+        # or replicas (two-sided page stacks), the verify forward, the
+        # adapters' deltas and the mesh's sharding of kv heads are not
+        # built for it, and are refused by name likewise.
+        self._pages_stay = (
+            f"per-slot state ({cfg.conv_planes} convolution layers)"
+            if self._slot_state
+            else "a latent pool (key and value in one page)"
+            if cfg.latent else None)
+        if self._pages_stay:
             for flag, value in (
                     ("speculative_tokens", speculative_tokens),
                     ("host_spill_blocks", host_spill_blocks),
@@ -556,8 +573,8 @@ class DecodeEngine:
                 if value:
                     raise ValueError(
                         f"engine {name!r}: {flag} is not built for a "
-                        f"model with per-slot state "
-                        f"({cfg.conv_planes} convolution layers)")
+                        f"model with {self._pages_stay}")
+        if self._slot_state:
             prefix_caching = False
         self._registry = adapters
         self._adapter_version = None
@@ -650,8 +667,9 @@ class DecodeEngine:
                                        decode.kv_cache_dtype)
         # What one resident position costs: keys and values over every
         # plane of the pool (an int8 pool's scales included).
+        sides = pool_sides(self._state)
         self.kv_bytes_per_token = sum(
-            leaf.nbytes for side in ("cache_k", "cache_v")
+            leaf.nbytes for side in sides
             for leaf in jax.tree_util.tree_leaves(self._state[side])
         ) // (self.kv_pool_blocks * self.kv_block_tokens)
         if mesh is not None:
@@ -669,13 +687,15 @@ class DecodeEngine:
         # share of decode steps the kernel served.
         self._paged_kernel = (
             mesh is None
-            and _plain_pool_platform(self._state["cache_k"]) == "tpu")
+            and _plain_pool_platform(self._state[sides[0]]) == "tpu")
         if self._paged_kernel:
             # Load Pallas here, not inside the first trace: its import
             # took ~3 s on the chip's host and read as compile_s.
             from kubeflow_tpu.ops import paged_attention
 
-            self._paged_kernel = paged_attention.supports(
+            # A latent row is whole 128-lane rows by construction
+            # (TransformerConfig.latent_row).
+            self._paged_kernel = cfg.latent or paged_attention.supports(
                 cfg.head_dim, cfg.n_kv_heads)
         # Host-owned per-slot block tables, passed into every program
         # call; the sentinel value (== pool size) parks writes and
@@ -774,6 +794,7 @@ class DecodeEngine:
             "decode_kernel_steps": 0,
             "spill_pages_out": 0, "spill_pages_in": 0,
             "parked_sessions": 0, "fetches": 0, "experts_touched": 0,
+            **dict.fromkeys(_PAIR_KEYS, 0),
             **dict.fromkeys(_SUM_KEYS, 0),
         }
         import jax
@@ -1063,12 +1084,12 @@ class DecodeEngine:
         # (:prefill route); ``kv_handoff`` is the decode-tier import
         # payload those pages arrive as.  Both validated HERE so a
         # malformed payload answers 400 before any device work.
-        if self._slot_state:
+        if self._pages_stay:
             for key in ("kv_export", "kv_handoff", "park_kv"):
                 if inputs.get(key):
                     raise ValueError(
-                        f"{key} is not built for a model with per-slot "
-                        f"state: pages alone do not carry its sequences")
+                        f"{key} is not built for a model with "
+                        f"{self._pages_stay}: its pages are not moved")
         export = bool(inputs.get("kv_export"))
         handoff = self._parse_handoff(inputs.get("kv_handoff"), length)
         if deadline is not None and faults.monotonic() >= deadline:
@@ -1351,9 +1372,27 @@ class DecodeEngine:
             "conv_state_bytes": int(self._state["conv"].nbytes)
             if "conv" in self._state else 0,
             "moe_layers": self._moe_layers,
-            "moe_experts": self.cfg.moe_experts if self._moe_layers else 0,
+            # The experts whose weights this engine HOLDS (a share's 16
+            # of 512 routed ones; every expert where none is cut): what a
+            # step can touch.
+            "moe_experts": self.cfg.moe_held if self._moe_layers else 0,
             "moe_top_k": self.cfg.moe_top_k if self._moe_layers else 0,
             "experts_touched": c["experts_touched"],
+            # A latent pool's one row a token and plane, key and value
+            # at once (0 for a k / v pool); the chip's share of the
+            # experts and those that need no weights; and the device's
+            # own counts of the (row, choice) pairs of the decode
+            # steps, by where they fell (zeros where every expert is
+            # held and has weights: all pairs are held then).
+            "latent_bytes_per_token": self.kv_bytes_per_token
+            if self.cfg.latent else 0,
+            "moe_experts_held": self.cfg.moe_held
+            if self._moe_layers else 0,
+            "moe_routed_experts": self.cfg.moe_experts
+            if self._moe_layers else 0,
+            "moe_zero_experts": self.cfg.moe_zero_experts
+            if self._moe_layers else 0,
+            **{key: c[key] for key in _PAIR_KEYS},
             "prefix_reuse": self._prefix_reuse,
             "kv_block_evictions": c["kv_evictions"],
             "kv_shed_no_blocks": c["kv_shed_no_blocks"],
@@ -2712,10 +2751,13 @@ class DecodeEngine:
             self._state, toks, counts, steps_run = self._rounds_exec(
                 self.params, self._state, tables, np.int32(width))
             touched = self._state.get("moe_touched")
-            if touched is not None:
-                # Its copy to the host rides behind the round, so the
-                # read at the boundary costs no round trip of its own.
-                touched.copy_to_host_async()
+            pairs = self._state.get("moe_pairs")
+            for count in (touched, pairs):
+                if count is not None:
+                    # Its copy to the host rides behind the round, so
+                    # the read at the boundary costs no round trip of
+                    # its own.
+                    count.copy_to_host_async()
         # ---- overlap window: the dispatch returned as soon as the
         # round was enqueued; everything until the np.asarray below
         # runs while the device computes.
@@ -2775,6 +2817,12 @@ class DecodeEngine:
                 with self._lock:
                     self._counters["experts_touched"] += \
                         facts["experts_touched"]
+            if pairs is not None:
+                fell = dict(zip(_PAIR_KEYS, map(int, np.asarray(pairs))))
+                facts.update(fell)
+                with self._lock:
+                    for key, n in fell.items():
+                        self._counters[key] += n
             phase.facts(**facts)
             del toks, counts, steps_run  # freed here, inside a phase
         with self._phase("drain"):

@@ -184,10 +184,12 @@ def test_flax_forward_matches_the_reference(lfm2, n):
 class Served:
     """A paged state with SLOTS slots, driven as the engine drives it."""
 
-    def __init__(self, cfg, params, new=4):
+    def __init__(self, cfg, params, new=4, reference=None):
         from kubeflow_tpu.models import generate as g
 
         self.g, self.cfg, self.params = g, cfg, params
+        # (params, tokens, published) -> the plain reference's logits.
+        self.reference = reference or _reference
         self.decode = g.DecodeConfig(max_new_tokens=new, temperature=0.0)
         self.state = g.init_paged_state(cfg, SLOTS, SLOTS * TABLE, BLOCK)
         self.tables = np.full((SLOTS, TABLE), SLOTS * TABLE, np.int32)
@@ -229,8 +231,8 @@ class Served:
         s = self.state
         logits = self.g.forward_layer_types(
             self.cfg, self.params, s["last_token"][:, None],
-            (s["cache_k"], s["cache_v"]), s["lengths"],
-            tables=jnp.asarray(self.tables), conv=s["conv"],
+            tuple(s[side] for side in self.g.pool_sides(s)), s["lengths"],
+            tables=jnp.asarray(self.tables), conv=s.get("conv"),
             n_new=jnp.ones((SLOTS,), jnp.int32))[0]
         return np.asarray(logits)[:, 0]
 
@@ -239,8 +241,8 @@ class Served:
         position's full row, and how far each served token's reference
         logit lies under the row's best."""
         served = self.served[slot]
-        want = _reference(self.params if params is None else params,
-                          np.concatenate([prompt, served]), published)
+        want = self.reference(self.params if params is None else params,
+                              np.concatenate([prompt, served]), published)
         rows = want[len(prompt) - 1:len(prompt) - 1 + len(served)]
         gaps = rows.max(-1) - rows[np.arange(len(served)), served]
         return max(np.abs(last[slot] - want[-1]).max(), gaps.max())
@@ -361,7 +363,8 @@ def _sparse_layer(cfg, params, bias):
     lp["moe"] = dict(lp["moe"], bias=jnp.asarray(bias, jnp.float32))
     x = jnp.asarray(np.random.default_rng(9).normal(0, 1, (2, 6, 32)),
                     jnp.float32)
-    out, touched = _sparse_ff(cfg, lp, x)
+    out, counts = _sparse_ff(cfg, lp, x)
+    touched = counts["touched"]
     y = reference_lfm2.rms_norm(x.reshape(12, 32), lp["mlp_norm"]["scale"],
                                 cfg.norm_eps)
     published = dict(PUBLISHED, num_experts_per_tok=cfg.moe_top_k)
@@ -415,7 +418,8 @@ def test_rows_that_are_no_tokens_choose_nothing(lfm2):
     x = jnp.asarray(np.random.default_rng(10).normal(0, 1, (3, 1, 32)),
                     jnp.float32)
     live = jnp.asarray([[True], [False], [True]])
-    out, touched = _sparse_ff(cfg, lp, x, live)
+    out, counts = _sparse_ff(cfg, lp, x, live)
+    touched = counts["touched"]
     alone, _ = _sparse_ff(cfg, lp, x[:1])
     assert np.array_equal(np.asarray(out[1]), np.asarray(x[1]))
     assert np.abs(np.asarray(out[0] - alone[0])).max() < 1e-6

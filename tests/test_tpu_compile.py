@@ -507,3 +507,114 @@ def test_layer_types_decode_rounds_keeps_the_paged_kernel(lfm2_program):
     assert "paged_decode_attention" in compiled.as_text()
     _, chunk = lfm2_program("prefill_chunk_into_slot")
     assert "paged_decode_attention" not in chunk.as_text()
+
+
+# LongCat-Flash's language model at the cell's sizes
+# (benchmark/configs/longcat-flash-omni-l4.json,
+# cells/longcat-flash-omni-l4.agents): 4 double layers, each two latent
+# attention sublayers (8 planes of ONE latent pool, a row of 512 + 64
+# values padded to 640 lanes), two dense SwiGLUs of 12,288 and 16 of 512
+# routed experts beside 256 zero-compute ones, walked layer by layer; 64
+# slots of 6,240 + 384 positions.
+LONGCAT = ({"vocab_size": 16_384, "d_model": 6144, "n_layers": 4,
+            "n_heads": 64, "n_kv_heads": 64, "d_ff": 12_288,
+            "max_seq_len": 131_072, "rope_theta": 1e7,
+            "tied_embeddings": False, "norm_eps": 1e-5,
+            "layer_types": ["shortcut_double"] * 4,
+            "attention_kind": "latent", "mla_q_rank": 1536,
+            "mla_kv_rank": 512, "mla_nope_dim": 128, "mla_rope_dim": 64,
+            "mla_v_dim": 128, "moe_experts": 512, "moe_experts_held": 16,
+            "moe_zero_experts": 256, "moe_top_k": 12, "moe_d_ff": 2048,
+            "moe_score": "softmax", "moe_normalize": False,
+            "moe_scale": 6.0, "dtype": "bfloat16"}, 64, 6624, 384)
+
+
+@pytest.fixture(scope="module")
+def longcat_program(chip):
+    import functools
+
+    from kubeflow_tpu.models import generate
+
+    widths, slots, max_len, new = LONGCAT
+    e = _engine_shapes(chip, widths, slots, max_len, max_new_tokens=new)
+
+    @functools.cache
+    def compiled(program):
+        if program == "decode_rounds":
+            return e, generate.decode_rounds.lower(
+                e["cfg"], e["params"], e["state"], e["decode"], 8,
+                e["arg"](slots, e["table_blocks"]), e["arg"](),
+                paged_kernel=True).compile()
+        return e, _compile_chunk(e, max_len)
+
+    return compiled
+
+
+@pytest.mark.parametrize("program", ["decode_rounds",
+                                     "prefill_chunk_into_slot"])
+def test_latent_programs_hold_the_pool_and_the_weights_in_place(
+        longcat_program, program):
+    """Both engine programs of the LongCat-Flash cut at the cell's sizes: the
+    ONE latent pool comes in donated and goes out aliased with no copy,
+    slice or restack of it or of a plane (PR 29's guard); no array of a
+    double layer's experts, of one expert's matrix or of a dense SwiGLU's
+    pair is produced outside a fusion (PR 31's), and what the program does
+    produce of the shape of an attention matrix is the compiler's own
+    sliced prefetch into the fast memory (``ConcatBitcast`` of
+    ``slice-done``s, ``copy-done`` of the cross-program prefetch) and one
+    relayout a sublayer and CALL, named below; the grouped products are
+    the chip's own kernel, once a layer; no fusion the compiler gave up
+    on (PR 36's); and the whole fits
+    under 15.2 GB, which is where ISSUE 37's fallback to 48 slots would
+    have been taken (it is not: 14.81 and 14.97 GB)."""
+    e, compiled = longcat_program(program)
+    text = compiled.as_text()
+    pool = e["state"]["cache_latent"]
+    assert pool.shape == (8, 64 * 414, 16, 640)
+    assert "cache_k" not in e["state"]
+    assert _pool_moves(text, pool.shape) == []
+    assert _weight_moves(text, [
+        (16, 6144, 4096), (16, 2048, 6144), (6144, 4096), (2048, 6144),
+        (2, 6144, 12_288), (6144, 12_288), (12_288, 6144)]) == []
+    prefetched = _weight_moves(text, [
+        (6144, 1536), (1536, 64, 192), (6144, 576), (64, 128, 512),
+        (512, 64, 128), (64, 128, 6144), (6144, 768)])
+    relaid = [name for name in prefetched
+              if not name.startswith(("custom-call", "copy-done"))]
+    # What stays: decode_rounds relays each sublayer's ``wv_b`` (8.4 MB,
+    # head-major for the product that leaves the latent space) ONCE A
+    # CALL, before its loop of steps; the chunk program nothing.
+    assert len(relaid) == (8 if program == "decode_rounds" else 0), relaid
+    assert all(name.startswith("copy.") for name in relaid)
+    assert text.count('op_name="ragged-dot-metadata"') == 4
+    assert _fusions_given_up(text) == []
+    m = compiled.memory_analysis()
+    side = int(np.prod(pool.shape)) * 2
+    assert side == 4_341_104_640
+    assert m.alias_size_in_bytes >= side
+    assert m.temp_size_in_bytes < (0.45e9 if program.startswith("prefill")
+                                   else 0.15e9), m.temp_size_in_bytes
+    # Weights 10.38 GB (the routers in float32) beside the pool.
+    assert 14.70e9 < m.argument_size_in_bytes < 14.75e9
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert live < 15.2e9, live
+
+
+def test_latent_decode_rounds_attends_through_the_latent_kernel(
+        longcat_program):
+    """The decode program reads the latent pages in place, once a plane
+    (8 kernel calls a step); the chunk program attends the slot's
+    gathered view and holds no kernel."""
+    _, compiled = longcat_program("decode_rounds")
+    text = compiled.as_text()
+    assert text.count(
+        "paged_latent_decode_attention/pallas_call\" ") >= 1
+    assert len([line for line in text.splitlines()
+                if "custom_call_target=\"tpu_custom_call\"" in line
+                and "%paged_latent_decode_attention" in
+                line.split(" = ")[0]]) == 8
+    assert "paged_decode_attention" not in text.replace(
+        "paged_latent_decode_attention", "")
+    _, chunk = longcat_program("prefill_chunk_into_slot")
+    assert "paged_latent_decode_attention" not in chunk.as_text()
