@@ -94,6 +94,70 @@ def _rms_norm(x, scale, eps, dtype):
 # compiler is known to make well.
 _VIEW_QUERY_TILE = 64
 
+# Key positions a tile of a slot's view at most (``view_key_tiles``): a
+# call of several query tiles visits the view tile by tile and stops after
+# the one that holds its last visible position.
+_VIEW_KEY_TILE = 512
+
+
+def view_key_tiles(max_blocks: int, block_tokens: int, t: int):
+    """How a call of ``t`` query columns a row visits a slot's view of
+    ``max_blocks`` pages of ``block_tokens`` positions: ``(tile, tiles)``,
+    the positions of a key tile (whole pages; at most ``_VIEW_KEY_TILE``
+    and an eighth of the table) and how many the table holds, the last
+    one perhaps short.  ``(view, 1)``, ONE pass over the whole view,
+    where the call holds no two tiles of query rows (a decode or verify
+    step) or the table no more than two key tiles: nothing to leave out
+    that is worth a branch."""
+    view = max_blocks * block_tokens
+    if (t < 2 * _VIEW_QUERY_TILE or t % _VIEW_QUERY_TILE
+            or view <= 2 * _VIEW_KEY_TILE):
+        return view, 1
+    pages = max(1, min(_VIEW_KEY_TILE, view // 8) // block_tokens)
+    return pages * block_tokens, -(-max_blocks // pages)
+
+
+def _tiles_visited(held, tile: int, tiles: int):
+    """Key tiles that hold positions ``[0, held)``, of a view's ``tiles``:
+    ``held`` a Python int (the engine's counter) or a traced scalar (the
+    program's own bound)."""
+    lib = jnp if isinstance(held, jax.Array) else np
+    return lib.clip((held + tile - 1) // tile, 1, tiles)
+
+
+def view_positions_scored(max_blocks: int, block_tokens: int, t: int,
+                          held: int) -> int:
+    """View positions a call of ``t`` columns whose last row sees
+    ``held`` positions gathers and scores: ``held`` rounded up to whole
+    key tiles, the whole view at most (and always where
+    ``view_key_tiles`` says one pass)."""
+    tile, tiles = view_key_tiles(max_blocks, block_tokens, t)
+    return min(int(_tiles_visited(held, tile, tiles)) * tile,
+               max_blocks * block_tokens)
+
+
+def _held_key_tiles(tables, block_tokens: int, t: int, cache_len):
+    """The key tiles a call of ``t`` columns a row visits of its rows'
+    views (``tables`` [b, max_blocks]): None where ``view_key_tiles``
+    says one pass, else ``(tile, visited, pages_of)``: the positions of a
+    tile, the (traced) tiles that hold every position the call's last
+    column may see (``cache_len + t`` of them, over the rows), and
+    ``pages_of(i)`` [b, tile pages], tile i's entries of the tables.  A
+    position past the visited tiles is masked for every query of the
+    call, so its weight is exactly 0 in a pass over the whole view too:
+    what is left out is its gather, its score and the sums of zeros."""
+    max_blocks = tables.shape[1]
+    tile, tiles = view_key_tiles(max_blocks, block_tokens, t)
+    if tiles == 1:
+        return None
+    pages = tile // block_tokens
+    # The last tile may overhang the table: its entries past it lie past
+    # the view's length, which the forms below mask.
+    padded = jnp.pad(tables, ((0, 0), (0, tiles * pages - max_blocks)))
+    return tile, _tiles_visited(jnp.max(cache_len) + t, tile, tiles), (
+        lambda i: jax.lax.dynamic_slice_in_dim(
+            padded, i * pages, pages, axis=1))
+
 
 def _view_attention(q, view_k, view_v, cache_len, pad_amount):
     """Attention of the call's q [b, t, h, d] over a gathered view of the
@@ -121,6 +185,79 @@ def _view_attention(q, view_k, view_v, cache_len, pad_amount):
 
     out = jax.lax.map(tile, jnp.arange(tiles))      # [tiles, b, tile, h, d]
     return jnp.moveaxis(out, 0, 1).reshape(q.shape)
+
+
+def _online_softmax(tile_of, scores_of, sums_of, rows, lanes, q_pos, tile,
+                    visited, view, first=None):
+    """Softmax-weighted sums over ``visited`` (traced) key tiles of
+    ``tile`` positions of a view ``view`` long, with a running float32
+    max / sum / accumulator of shapes ``rows`` / ``rows`` / ``rows +
+    (lanes,)``: ``tile_of(i)`` gathers key tile i, ``scores_of(held)``
+    gives its float32 scores ``rows + (tile,)`` and ``sums_of(held, w)``
+    the float32 sums ``rows + (lanes,)`` of its values under weights w.
+    A row attends the positions up to its own ``q_pos`` (and from
+    ``first`` on), both broadcastable to ``rows``.  A masked score is the
+    least float32, which is also where the running max starts: once a
+    row has met a position it may see, a masked one weighs exp(least -
+    max) = 0 exactly, as in one pass over the whole view."""
+    least = jnp.finfo(jnp.float32).min
+
+    def body(i, carry):
+        m, l, acc = carry
+        held = tile_of(i)
+        k_pos = i * tile + jnp.arange(tile)
+        keep = (k_pos <= q_pos[..., None]) & (k_pos < view)
+        if first is not None:
+            keep = keep & (k_pos >= first[..., None])
+        sc = jnp.where(keep, scores_of(held), least)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        w = jnp.exp(sc - m_new[..., None])
+        fade = jnp.exp(m - m_new)
+        return (m_new, l * fade + w.sum(axis=-1),
+                acc * fade[..., None] + sums_of(held, w))
+
+    m, l, acc = jax.lax.fori_loop(0, visited, body, (
+        jnp.full(rows, least, jnp.float32), jnp.zeros(rows, jnp.float32),
+        jnp.zeros(rows + (lanes,), jnp.float32)))
+    return acc / l[..., None]
+
+
+def _tiled_view_attention(q, tile_of, hkv, cache_len, pad_amount, tile,
+                          visited, view):
+    """Attention of q [b, t, h, d] over ``visited`` (traced) key tiles of
+    ``tile`` positions of a view ``view`` long: ``tile_of(i)`` gathers
+    the keys and values of tile i, each [b, tile, hkv, d] (arrays or int8
+    ``QTensor``s, whose scales fold into the scores and the weights as in
+    ``dot_product_attention``).  The group's queries share a key head's
+    tile as rows of one product: nothing is repeated over the group."""
+    b, t, h, d = q.shape
+    dt = q.dtype
+    grouped = q.reshape(b, t, hkv, h // hkv, d)
+
+    def split(c):
+        return (c.values, c.scale) if isinstance(c, QTensor) else (c, None)
+
+    def scores_of(held):
+        k, k_scale = split(held[0])
+        sc = jnp.einsum("bqngd,bknd->bngqk", grouped, k.astype(dt),
+                        preferred_element_type=jnp.float32) * d ** -0.5
+        if k_scale is not None:
+            sc = sc * k_scale.transpose(0, 2, 1)[:, :, None, None, :]
+        return sc
+
+    def sums_of(held, w):
+        v, v_scale = split(held[1])
+        if v_scale is not None:
+            w = w * v_scale.transpose(0, 2, 1)[:, :, None, None, :]
+        return jnp.einsum("bngqk,bknd->bngqd", w.astype(dt), v.astype(dt),
+                          preferred_element_type=jnp.float32)
+
+    out = _online_softmax(
+        tile_of, scores_of, sums_of, (b, hkv, h // hkv, t), d,
+        jnp.reshape(cache_len, (-1, 1, 1, 1)) + jnp.arange(t), tile,
+        visited, view,
+        None if pad_amount is None else pad_amount.reshape(-1, 1, 1, 1))
+    return out.transpose(0, 3, 1, 2, 4).reshape(q.shape).astype(dt)
 
 
 def _page_coordinates(tables, cache_len, write_cols, b, t, nb, bt):
@@ -179,10 +316,15 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
     plane sliced out and put back — a logical index past the table
     span, or a table entry holding the sentinel ``num_blocks``
     (unallocated), drops the write — and attention runs over the row's
-    [max_blocks * block_tokens] view, ONE gather ``pool[plane, tables]``
-    of the row's own pages (sentinel entries clamp onto an arbitrary
-    block whose columns all sit beyond the causal frontier, so the
-    garbage they contribute is masked).
+    own pages of the plane: ONE gather ``pool[plane, tables]`` of the
+    [max_blocks * block_tokens] view for a decode or verify step and
+    for a table of no more than two key tiles; for a call of several
+    query tiles (the prefill chunk) over a longer table, a loop over
+    key tiles that stops after the one holding the call's last visible
+    position, ``cache_len + t - 1`` (``_held_key_tiles``: pages past it
+    are not gathered, scored or summed).  Sentinel entries clamp onto
+    an arbitrary block whose columns all sit beyond the causal
+    frontier, so the garbage they contribute is masked.
     paged_kernel (static; the serving engine sets it when its pool
     lives on a TPU): a step with ONE query position per row (t == 1:
     decode_rounds) over a plain-array pool gathers no
@@ -190,8 +332,9 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
     plane and reads each row's resident pages in place (a row whose
     write is parked attends nothing).
     Wider steps (the prefill chunk, speculative verify), an int8
-    ``QTensor`` pool and every other backend keep the view and
-    ``dot_product_attention``.
+    ``QTensor`` pool and every other backend gather their pages and
+    attend them with plain products (``_view_attention``,
+    ``_tiled_view_attention``).
     cache_kv None (a forward without a cache, the flax module's): plain
     causal attention over the call's own q, k, v.
     """
@@ -264,15 +407,15 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
             ck = store(ck, k)
             cv = store(cv, v)
 
-        def paged_view(c):
+        def paged_view(c, pages):
             # Row view of the (just-updated) pool, the row's pages of
             # this plane in ONE gather (p[plane] first would copy the
             # plane): OOB sentinel entries clamp, contributing finite
             # garbage that the kv_offset mask discards.
             def gather(p):
-                g = p[plane, tables]
+                g = p[plane, pages]
                 return g.reshape(
-                    (tables.shape[0], mb * bt) + p.shape[3:])
+                    (pages.shape[0], pages.shape[1] * bt) + p.shape[3:])
 
             if isinstance(c, QTensor):
                 return QTensor(gather(c.values), gather(c.scale),
@@ -291,11 +434,26 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
                 out = paged_attention.paged_decode_attention(
                     q[:, 0], ck, cv, plane, tables, attend)[:, None]
         else:
-            with jax.named_scope("kft.kv_view"):
-                view_k, view_v = paged_view(ck), paged_view(cv)
-            with jax.named_scope("kft.attention"):
-                out = _view_attention(q, view_k, view_v, cache_len,
-                                      pad_amount)
+            held = _held_key_tiles(tables, bt, t, cache_len)
+            if held is None:
+                with jax.named_scope("kft.kv_view"):
+                    view_k = paged_view(ck, tables)
+                    view_v = paged_view(cv, tables)
+                with jax.named_scope("kft.attention"):
+                    out = _view_attention(q, view_k, view_v, cache_len,
+                                          pad_amount)
+            else:
+                tile, visited, pages_of = held
+
+                def tile_of(i):
+                    with jax.named_scope("kft.kv_view"):
+                        return (paged_view(ck, pages_of(i)),
+                                paged_view(cv, pages_of(i)))
+
+                with jax.named_scope("kft.attention"):
+                    out = _tiled_view_attention(
+                        q, tile_of, vals.shape[3], cache_len, pad_amount,
+                        tile, visited, mb * bt)
     elif isinstance(ck, QTensor):
         def store(c, new):
             vals, s = quantize_array(new, (-1,))    # [b, t, hk, d]
@@ -419,6 +577,31 @@ def _latent_view_attention(q_row, view, value_lanes, kv_offset, scale):
     return jnp.moveaxis(out, 0, 1).reshape(b, t, h, value_lanes)
 
 
+def _tiled_latent_view_attention(q_row, rows_of, value_lanes, kv_offset,
+                                 scale, tile, visited, view):
+    """``_latent_view_attention`` over ``visited`` (traced) key tiles of
+    ``tile`` positions of a view ``view`` long; ``rows_of(i)`` gathers
+    tile i [b, tile, row]."""
+    dt = q_row.dtype
+    b, t, h, _ = q_row.shape
+    q = q_row.reshape(b, t * h, -1)
+
+    def scores_of(held):
+        return jnp.einsum("bmr,bkr->bmk", q, held,
+                          preferred_element_type=jnp.float32) * scale
+
+    def sums_of(held, w):
+        return jnp.einsum("bmk,bkc->bmc", w.astype(dt),
+                          held[..., :value_lanes],
+                          preferred_element_type=jnp.float32)
+
+    out = _online_softmax(
+        rows_of, scores_of, sums_of, (b, t * h), value_lanes,
+        jnp.asarray(kv_offset)[..., None] + jnp.arange(t * h) // h, tile,
+        visited, view)
+    return out.astype(dt).reshape(b, t, h, value_lanes)
+
+
 def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
                             cache_len, positions, write_cols=None,
                             tables=None, paged_kernel=False, plane=None):
@@ -440,9 +623,12 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
       head and both products, and the heads fold into the rows of one
       matmul.  A decode step (ONE query position a row over per-row
       lengths) with ``paged_kernel`` reads the pages in place through
-      ops/paged_attention.py; a prefill chunk, and a decode step on any
-      other backend, attend over the slot's gathered view
-      (``_latent_view_attention``);
+      ops/paged_attention.py; a decode step on any other backend
+      attends over the slot's gathered view
+      (``_latent_view_attention``), and a prefill chunk over the key
+      tiles of it that the slot holds (``_held_key_tiles``,
+      ``_tiled_latent_view_attention``; the whole view in one pass
+      where the table is short);
     - the forward without a cache EXPANDS keys and values from the latent
       (``c W_uk``, ``c W_uv``) and attends as any other model does, as
       the plain reference does everywhere.
@@ -508,9 +694,19 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
                     q_row[:, 0], pool, plane, tables, attend, rkv,
                     scale)[:, None]
             else:
-                ot = _latent_view_attention(
-                    q_row, pool[plane, tables].reshape(b, mb * bt, width),
-                    rkv, cache_len, scale)
+                held = _held_key_tiles(tables, bt, t, cache_len)
+                if held is None:
+                    ot = _latent_view_attention(
+                        q_row,
+                        pool[plane, tables].reshape(b, mb * bt, width),
+                        rkv, cache_len, scale)
+                else:
+                    tile, visited, pages_of = held
+                    ot = _tiled_latent_view_attention(
+                        q_row,
+                        lambda i: pool[plane, pages_of(i)].reshape(
+                            b, tile, width),
+                        rkv, cache_len, scale, tile, visited, mb * bt)
             out = qeinsum("bshc,chd->bshd", ot, wv_b, dt)
     with jax.named_scope("kft.attn_out"):
         x = x + qeinsum("bshd,hde->bse", out, attn["wo"], dt)

@@ -787,6 +787,7 @@ class DecodeEngine:
             "shed": 0, "expired": 0,
             "prefix_hits": 0, "prefix_misses": 0, "prefix_evictions": 0,
             "prefill_chunks": 0, "cached_tokens": 0, "prompt_tokens": 0,
+            "prefill_positions_held": 0, "prefill_positions_scored": 0,
             "spec_drafted": 0, "spec_accepted": 0, "spec_steps": 0,
             "kv_evictions": 0, "kv_shed_no_blocks": 0,
             "handoff_pages_out": 0, "handoff_pages_in": 0,
@@ -1466,6 +1467,14 @@ class DecodeEngine:
             # scheduling turn.
             "prefill_chunks": c["prefill_chunks"],
             "prefill_chunk_p95_ms": pct(chunks, 0.95),
+            # What the chunks' attention cost, summed over chunks: the
+            # positions a chunk's last real row may see (its start + its
+            # real tokens) and the positions of the slot's view the
+            # program visited for it (that bound in whole key tiles,
+            # the table's length where the program makes one pass:
+            # generate.view_positions_scored, no device read).
+            "prefill_positions_held": c["prefill_positions_held"],
+            "prefill_positions_scored": c["prefill_positions_scored"],
             # Where the time goes, cumulative (see _SUM_KEYS): the
             # loop's phases, queue wait, prefill span, compiles.
             **{key: c[key] for key in _SUM_KEYS},
@@ -2282,7 +2291,10 @@ class DecodeEngine:
         (``round_wait``, and ``busy_s`` through the round's timing)
         covers it, and ``prefill_span_s_sum`` holds a request's whole
         prefill from slot claim to first token."""
-        from kubeflow_tpu.models.generate import prefill_chunk_into_slot
+        from kubeflow_tpu.models.generate import (
+            prefill_chunk_into_slot,
+            view_positions_scored,
+        )
 
         w = self.chunk_w
         prompt = entry["tokens"][0]
@@ -2335,8 +2347,13 @@ class DecodeEngine:
                     self._mgr.publish(
                         prompt, true_len, entry["blocks"],
                         salt=entry.get("adapter_salt", b""))
+        scored = view_positions_scored(
+            self._tables.shape[1], self.kv_block_tokens, w, start + w)
         with self._lock:
             self._counters["prefill_chunks"] += 1
+            self._counters["prefill_positions_held"] += min(
+                start + w, true_len)
+            self._counters["prefill_positions_scored"] += scored
             # NOT added to busy_s: dt is a dispatch.  The chunk's
             # compute is inside the next round's timed wait, so
             # tokens_per_sec still pays for it.

@@ -675,3 +675,203 @@ def test_view_attention_in_query_tiles_is_the_whole_calls(rows, per_row):
     want = dot_product_attention(q, k, v, causal=True, kv_offset=start)
     assert got.shape == want.shape == q.shape
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+
+
+def _paged(rng, view, hkv, d, planes=2, spare=7):
+    """A stacked pool side and ONE row's table over it, pages in a
+    shuffled order: ``(pool [planes, nb, 16, hkv, d], tables [1, view /
+    16])``."""
+    import jax.numpy as jnp
+
+    pages = view // 16
+    pool = jnp.asarray(
+        rng.normal(size=(planes, pages + spare, 16, hkv, d)), jnp.float32)
+    order = rng.permutation(pages + spare)[:pages]
+    return pool, jnp.asarray(order[None], jnp.int32)
+
+
+# A table of 1,280 positions in pages of 16 under a call of 128 columns:
+# key tiles of 160 positions (an eighth), 8 of them.  ``start`` so that
+# the call's last position (start + 127) lies in the first tile, on both
+# sides of a tile's edge, and in the table's last chunk; and so that the
+# call itself starts on both sides of an edge.
+HELD_STARTS = [0, 159 - 127, 160 - 127, 161 - 127, 159, 160, 161,
+               2 * 160 - 128, 1280 - 128]
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["array", "int8"])
+@pytest.mark.parametrize("start", HELD_STARTS)
+def test_view_attention_over_held_tiles_is_the_whole_views(start, int8):
+    """The chunk program's attention over the key tiles the call can see
+    (``_held_key_tiles`` + ``_tiled_view_attention``) against ONE
+    ``dot_product_attention`` over the row's whole gathered view: grouped
+    heads, an int8 ``QTensor`` pool, and pages past the visited tiles
+    that hold NaN (never gathered, so the result stays finite; the one
+    pass is given the clean pool)."""
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models import generate
+    from kubeflow_tpu.ops.attention import dot_product_attention
+    from kubeflow_tpu.ops.quantize import QTensor, quantize_array
+
+    view, t, h, hkv, d, plane = 1280, 128, 4, 2, 8, 1
+    assert generate.view_key_tiles(view // 16, 16, t) == (160, 8)
+    rng = np.random.default_rng(SEED + start)
+    q = jnp.asarray(rng.normal(size=(1, t, h, d)), jnp.float32)
+    (pool_k, tables), (pool_v, _) = (
+        _paged(rng, view, hkv, d), _paged(rng, view, hkv, d))
+    scored = generate.view_positions_scored(view // 16, 16, t, start + t)
+    assert start + t <= scored <= view and scored % 160 == 0
+    assert scored - (start + t) < 160
+
+    def spoiled(pool):
+        unseen = tables[0, scored // 16:]
+        return pool.at[:, unseen].set(jnp.nan)
+
+    def side(pool):
+        if not int8:
+            return pool
+        values, scale = quantize_array(pool, (-1,))
+        return QTensor(values, scale, (-1,))
+
+    def row_view(c, pages):
+        def gather(p):
+            return p[plane, pages].reshape((1, -1) + p.shape[3:])
+        if int8:
+            return QTensor(gather(c.values), gather(c.scale), c.axes)
+        return gather(c)
+
+    want = dot_product_attention(
+        q, row_view(side(pool_k), tables), row_view(side(pool_v), tables),
+        causal=True, kv_offset=jnp.int32(start))
+    tile, visited, pages_of = generate._held_key_tiles(
+        tables, 16, t, jnp.int32(start))
+    assert (tile, int(visited)) == (160, scored // 160)
+
+    ck, cv = side(pool_k), side(pool_v)
+    if not int8:
+        ck, cv = spoiled(ck), spoiled(cv)
+    got = generate._tiled_view_attention(
+        q, lambda i: (row_view(ck, pages_of(i)), row_view(cv, pages_of(i))),
+        hkv, jnp.int32(start), None, tile, visited, view)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(got, want, rtol=0, atol=5e-6)
+
+
+@pytest.mark.parametrize("t, view, one_pass", [
+    (1, 6624, True), (5, 6624, True), (64, 6624, True), (96, 6624, True),
+    (128, 512, True), (256, 704, True), (256, 1024, True),
+    (128, 1040, False), (256, 2560, False), (256, 6400, False),
+    (256, 6624, False)])
+def test_which_calls_visit_a_view_by_key_tiles(t, view, one_pass):
+    """A decode or verify step, a call of one query tile and a table of
+    one or two key tiles (reason's 512, workers' 704 positions) run one
+    pass; the cells' long tables are visited in tiles no coarser than an
+    eighth of the table or 512 positions, in whole pages."""
+    from kubeflow_tpu.models import generate
+
+    tile, tiles = generate.view_key_tiles(view // 16, 16, t)
+    if one_pass:
+        assert (tile, tiles) == (view, 1)
+        assert generate.view_positions_scored(view // 16, 16, t, 1) == view
+        return
+    assert tile % 16 == 0 and tile <= min(view // 8, 512)
+    assert (tiles - 1) * tile < view <= tiles * tile
+    held = [1, tile - 1, tile, tile + 1, view - 1, view, view + 200]
+    scored = [generate.view_positions_scored(view // 16, 16, t, n)
+              for n in held]
+    assert scored == [tile, tile, tile, 2 * tile, view, view, view]
+
+
+def test_left_padded_rows_over_held_tiles_mask_their_pad():
+    """Rows with a ``pad_amount`` and a frontier of their own through the
+    tile loop: the pad's keys get no weight, the rows' own bound is the
+    furthest row's."""
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models import generate
+    from kubeflow_tpu.ops.attention import dot_product_attention
+
+    view, t, h, hkv, d = 1280, 128, 4, 2, 8
+    rng = np.random.default_rng(SEED)
+    q = jnp.asarray(rng.normal(size=(2, t, h, d)), jnp.float32)
+    pool_k, row = _paged(rng, view, hkv, d, planes=1, spare=80)
+    pool_v, _ = _paged(rng, view, hkv, d, planes=1, spare=80)
+    tables = jnp.concatenate([row, (row + 3) % pool_k.shape[1]])
+    start = jnp.asarray([300, 40], jnp.int32)
+    pad = jnp.asarray([170, 3], jnp.int32)
+
+    def row_view(p, pages):
+        return p[0, pages].reshape((2, -1) + p.shape[3:])
+
+    want = dot_product_attention(
+        q, row_view(pool_k, tables), row_view(pool_v, tables), causal=True,
+        kv_offset=start, kv_valid_start=pad)
+    tile, visited, pages_of = generate._held_key_tiles(tables, 16, t, start)
+    assert int(visited) == 3                 # 300 + 128 positions of 160
+    got = generate._tiled_view_attention(
+        q, lambda i: (row_view(pool_k, pages_of(i)),
+                      row_view(pool_v, pages_of(i))), hkv, start, pad, tile,
+        visited, view)
+    np.testing.assert_allclose(got, want, rtol=0, atol=5e-6)
+
+
+@pytest.mark.parametrize("kv_cache_dtype", ["model", "int8"])
+@pytest.mark.parametrize("start", [0, 160 - 128, 161 - 128, 700,
+                                   1280 - 128, 1280 - 64])
+def test_a_chunk_over_a_long_table_is_the_one_pass_chunk(
+        start, kv_cache_dtype, monkeypatch):
+    """``_forward_with_cache`` of a 128-column chunk at ``start`` against
+    a slot whose table holds 1,280 positions (the attention block takes
+    the key-tile loop) and the same call made to pass over the whole
+    view (a key tile as long as the table): the same logits and the same
+    pool, grouped heads, a bfloat16-free float32 stack, a plain and an
+    int8 pool; the last chunk overhangs the table."""
+    import jax
+    import jax.numpy as jnp
+    from flax import linen as nn
+
+    from kubeflow_tpu.models import generate
+    from kubeflow_tpu.models.transformer import (
+        Transformer,
+        TransformerConfig,
+    )
+    from kubeflow_tpu.ops.quantize import QTensor
+
+    cfg = TransformerConfig(
+        vocab_size=VOCAB, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=64, head_dim=8, max_seq_len=2048, dtype=jnp.float32)
+    params = nn.unbox(Transformer(cfg).init(
+        jax.random.key(SEED), jnp.zeros((1, 8), jnp.int32))["params"])
+    rng = np.random.default_rng(SEED + start)
+    state = generate.init_paged_state(cfg, 2, 2 * 80, 16,
+                                      kv_cache_dtype=kv_cache_dtype)
+
+    def fill(c):
+        if isinstance(c, QTensor):
+            return QTensor(
+                jnp.asarray(rng.integers(-120, 120, c.values.shape),
+                            c.values.dtype),
+                jnp.asarray(rng.uniform(0.004, 0.02, c.scale.shape),
+                            c.scale.dtype), c.axes)
+        return jnp.asarray(rng.normal(size=c.shape), c.dtype)
+
+    cache = fill(state["cache_k"]), fill(state["cache_v"])
+    tables = jnp.asarray(rng.permutation(160)[None, :80], jnp.int32)
+    chunk = jnp.asarray(rng.integers(1, VOCAB, (1, 128)), jnp.int32)
+
+    def forward():
+        logits, (ck, _) = generate._forward_with_cache(
+            cfg, params, chunk, cache, jnp.int32(start), tables=tables)
+        return np.asarray(logits), np.asarray(
+            ck.values if isinstance(ck, QTensor) else ck)
+
+    assert generate.view_key_tiles(80, 16, 128) == (160, 8)
+    tiled = forward()
+    monkeypatch.setattr(generate, "_VIEW_KEY_TILE", 1280)
+    assert generate.view_key_tiles(80, 16, 128) == (1280, 1)
+    whole = forward()
+    assert np.ptp(whole[0]) > 0.3
+    assert np.abs(tiled[0] - whole[0]).max() < 2e-4
+    if kv_cache_dtype == "model":
+        assert np.abs(tiled[1] - whole[1]).max() < 2e-4

@@ -678,6 +678,93 @@ class TestDecodeEngine:
         finally:
             engine.close()
 
+    def test_long_table_chunks_visit_the_held_key_tiles(self, engine_model):
+        """A table long enough for the chunk program to visit it by key
+        tiles (1,200 positions: 9 tiles of 144 under chunks of 128): a
+        cold prompt in 3 chunks, then one that shares 200 tokens with it
+        (12 whole pages cached, the hit ends mid-page) in 3 more, both
+        token-identical to ``generate()``; ``stats()`` counts, chunk by
+        chunk, the positions the chunk's last real row may see and the
+        view positions the program visited for it."""
+        import dataclasses
+
+        from kubeflow_tpu.models.generate import view_key_tiles
+        from kubeflow_tpu.serving.engine import DecodeEngine
+
+        spec, _ = engine_model
+        spec = dict(spec, cfg=dataclasses.replace(spec["cfg"],
+                                                  max_seq_len=2048))
+        rng = np.random.RandomState(SEED + 38)
+        common = rng.randint(1, VOCAB, size=(200,)).tolist()
+        p1 = common + rng.randint(1, VOCAB, size=(100,)).tolist()
+        p2 = common + rng.randint(1, VOCAB, size=(250,)).tolist()
+        want = _reference_rows(spec, [p1, p2], [6, 6])
+        engine = DecodeEngine(
+            spec["cfg"], spec["params"], spec["decode"], slots=2,
+            prefill_len=1200 - NEW_TOKENS, prefill_chunk_tokens=128,
+            kv_block_tokens=16, name="test-held-tiles")
+        seen = []
+        inner = engine._prefill_chunk
+
+        def spy(entry):
+            start = entry["pos"]
+            inner(entry)
+            seen.append((start, engine._counters["prefill_positions_held"],
+                         engine._counters["prefill_positions_scored"]))
+
+        engine._prefill_chunk = spy
+        try:
+            table = engine._tables.shape[1] * 16
+            assert table == 1200
+            assert view_key_tiles(table // 16, 16, 128) == (144, 9)
+            for prompt, row in ((p1, want[0]), (p2, want[1])):
+                out = engine.submit({"tokens": np.asarray(prompt, np.int32),
+                                     "max_new_tokens": 6})
+                assert np.asarray(out["tokens"])[0].tolist() == row
+            stats = engine.stats()
+        finally:
+            engine.close()
+        assert stats["prefix_hits"] == 1
+        assert stats["cached_prompt_tokens"] == 192
+        assert set(stats["compiled_programs"]) == {
+            "chunked_prefill", "decode_rounds", "verify"}
+        starts = [start for start, _, _ in seen]
+        assert starts == [0, 128, 256, 192, 320, 448]
+        held = np.diff([0] + [h for _, h, _ in seen]).tolist()
+        scored = np.diff([0] + [s for _, _, s in seen]).tolist()
+        # start + the chunk's real tokens; that bound + the pad columns,
+        # in whole tiles of 144.
+        assert held == [128, 256, 300, 320, 448, 450]
+        assert scored == [144, 288, 432, 432, 576, 576]
+        assert all(h <= s <= table for h, s in zip(held, scored))
+        assert stats["prefill_positions_held"] == sum(held)
+        assert stats["prefill_positions_scored"] == sum(scored)
+        assert stats["prefill_chunks"] == 6
+
+    def test_short_table_chunks_score_the_whole_table(self, engine_model):
+        """Where the program makes one pass over the view (a table of no
+        more than two key tiles), every chunk scores the table's
+        length."""
+        from kubeflow_tpu.serving.engine import DecodeEngine
+
+        spec, _ = engine_model
+        rng = np.random.RandomState(SEED + 39)
+        prompt = rng.randint(1, VOCAB, size=(13,)).tolist()
+        engine = DecodeEngine(
+            spec["cfg"], spec["params"], spec["decode"], slots=1,
+            prefill_len=16, prefill_chunk_tokens=8, kv_block_tokens=4,
+            name="test-one-pass-counters")
+        try:
+            engine.submit({"tokens": np.asarray(prompt, np.int32),
+                           "max_new_tokens": 2})
+            stats = engine.stats()
+            table = engine._tables.shape[1] * 4
+        finally:
+            engine.close()
+        assert stats["prefill_chunks"] == 2
+        assert stats["prefill_positions_held"] == 8 + 13
+        assert stats["prefill_positions_scored"] == 2 * table
+
     @ROUND_CAPS
     def test_budget_clamped_to_config(self, engine_model, decode_rounds):
         """A request asking for more than the export config's

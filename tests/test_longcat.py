@@ -335,6 +335,76 @@ def test_the_views_attention_in_tiles_is_the_untiled_one():
     assert np.abs(np.asarray(tiled - want)).max() < 1e-5
 
 
+@pytest.mark.parametrize("start", [0, 159 - 127, 160 - 127, 161 - 127,
+                                   160, 2 * 160 - 128, 1280 - 128])
+def test_the_views_attention_over_held_tiles_is_the_whole_views(start):
+    """A chunk of 128 columns over a table of 1,280 positions visits key
+    tiles of 160 and stops after the one that holds its last position (in
+    the first tile, on both sides of an edge, in the table's last chunk):
+    the result is ONE pass's over the whole gathered view, and the pages
+    past the visited tiles, which hold NaN here, are never read."""
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models import generate
+
+    view, t, h, row, lanes, plane = 1280, 128, 4, 32, 24, 1
+    rng = np.random.default_rng(15 + start)
+    q = jnp.asarray(rng.normal(0, 1, (1, t, h, row)), jnp.float32)
+    pool = jnp.asarray(rng.normal(0, 1, (2, 90, 16, row)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(90)[None, :view // 16], jnp.int32)
+    want = generate._latent_view_attention(
+        q, pool[plane, tables].reshape(1, view, row), lanes,
+        jnp.int32(start), 0.25)
+    tile, visited, pages_of = generate._held_key_tiles(
+        tables, 16, t, jnp.int32(start))
+    scored = generate.view_positions_scored(view // 16, 16, t, start + t)
+    assert (tile, int(visited) * tile) == (160, scored)
+    assert start + t <= scored < start + t + 160
+    spoiled = pool.at[:, tables[0, scored // 16:]].set(jnp.nan)
+    got = generate._tiled_latent_view_attention(
+        q, lambda i: spoiled[plane, pages_of(i)].reshape(1, tile, row),
+        lanes, jnp.int32(start), 0.25, tile, visited, view)
+    assert got.shape == want.shape == (1, t, h, lanes)
+    assert np.abs(np.asarray(got - want)).max() < 1e-5
+
+
+@pytest.mark.parametrize("start", [0, 160 - 128, 161 - 128, 700,
+                                   1280 - 128])
+def test_a_chunk_over_a_long_table_is_the_one_pass_chunk(longcat, start,
+                                                         monkeypatch):
+    """``forward_layer_types`` of a 128-column chunk at ``start`` against
+    a slot whose table holds 1,280 positions (the latent block takes the
+    key-tile loop) and the same call made to pass over the whole view
+    (a key tile as long as the table): the same logits and the same
+    rows written, on a pool that holds other rows everywhere."""
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models import generate
+
+    cfg, params = longcat
+    rng = np.random.default_rng(start)
+    state = generate.init_paged_state(cfg, 2, 2 * 80, 16)
+    pool = jnp.asarray(rng.normal(
+        0, 1, state["cache_latent"].shape), jnp.float32)
+    tables = jnp.asarray(rng.permutation(160)[None, :80], jnp.int32)
+    chunk = jnp.asarray(_tokens(128, seed=start)[None])
+
+    def forward():
+        logits, cache, _, _ = generate.forward_layer_types(
+            cfg, params, chunk, (pool,), jnp.int32(start), tables=tables)
+        return np.asarray(logits), np.asarray(cache[0])
+
+    assert generate.view_key_tiles(80, 16, 128) == (160, 8)
+    tiled = forward()
+    monkeypatch.setattr(generate, "_VIEW_KEY_TILE", 1280)
+    assert generate.view_key_tiles(80, 16, 128) == (1280, 1)
+    whole = forward()
+    assert np.abs(tiled[0] - whole[0]).max() < TOL
+    assert np.ptp(whole[0]) > 1.0
+    np.testing.assert_array_equal(tiled[1][0], whole[1][0])
+    assert np.abs(tiled[1] - whole[1]).max() < TOL
+
+
 def _spoil(params, change):
     """``params`` with ``change(path, leaf)`` applied to every leaf."""
     import jax

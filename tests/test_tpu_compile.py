@@ -333,13 +333,66 @@ fusion(%fusion.180), kind=kOutput, backend_config={{"window_config":\
     assert _fusions_given_up(text) == ["fusion.181"]
 
 
+def _program_of(request, name, program):
+    """``(engine shapes, compiled program)`` of any of the five cells'
+    configurations, from the fixture that compiles it."""
+    if name in CELLS:
+        return request.getfixturevalue("cell_program")(name, program)
+    return request.getfixturevalue(
+        {"lfm2-24b-a2b-l10": "lfm2_program",
+         "longcat-flash-omni-l4": "longcat_program"}[name])(program)
+
+
+FIVE = sorted(CELLS) + ["lfm2-24b-a2b-l10", "longcat-flash-omni-l4"]
+
+
 @pytest.mark.parametrize("program", ["decode_rounds",
                                      "prefill_chunk_into_slot"])
-@pytest.mark.parametrize("name", sorted(CELLS))
+@pytest.mark.parametrize("name", FIVE)
 def test_engine_programs_hold_no_fusion_the_compiler_gave_up_on(
-        cell_program, name, program):
-    _, compiled = cell_program(name, program)
+        request, name, program):
+    _, compiled = _program_of(request, name, program)
     assert _fusions_given_up(compiled.as_text()) == []
+
+
+# The chunk programs whose table is long enough to be visited by key
+# tiles (generate.view_key_tiles): (query heads, table positions, key
+# tile).  Reason's 512 and workers' 704 positions run one pass.
+TILED = {"internlm2-1.8b": (16, 2560, 320),
+         "mistral-7b-v0.3-l16": (32, 6400, 512),
+         "longcat-flash-omni-l4": (64, 6624, 512)}
+
+
+@pytest.mark.parametrize("name", FIVE)
+def test_chunk_programs_score_a_key_tile_at_a_time(request, name):
+    """A chunk's attention loops over key tiles up to the slot's held
+    length (PR 38): the program holds no float32 array as long as the
+    table in any axis and none as large as ONE query tile's scores over
+    the whole view (``[h, 64, view]`` / ``[64 x h, view]``, which the
+    one pass held four times a layer); the two short tables keep the
+    one pass."""
+    import re
+
+    from kubeflow_tpu.models import generate
+    from kubeflow_tpu.serving.engine import PREFILL_CHUNK_TOKENS
+
+    e, compiled = _program_of(request, name, "prefill_chunk_into_slot")
+    text = compiled.as_text()
+    view = e["table_blocks"] * 16
+    width = min(PREFILL_CHUNK_TOKENS, {"ouro-2.6b": 128}.get(name, 256))
+    tile, tiles = generate.view_key_tiles(e["table_blocks"], 16, width)
+    shapes = [[int(n) for n in dims.split(",")]
+              for dims in re.findall(r"f32\[([\d,]+)\]", text)]
+    if name not in TILED:
+        assert (tile, tiles) == (view, 1)
+        assert any(view in dims for dims in shapes)
+        return
+    heads, positions, key_tile = TILED[name]
+    assert (view, tile) == (positions, key_tile)
+    assert tiles == -(-view // tile) > 2
+    assert not [dims for dims in shapes if view in dims]
+    assert max(int(np.prod(dims)) for dims in shapes) < heads * 64 * view
+    assert any(tile in dims for dims in shapes)
 
 
 def _layer_pair_shapes(widths):
@@ -592,7 +645,9 @@ def test_latent_programs_hold_the_pool_and_the_weights_in_place(
     side = int(np.prod(pool.shape)) * 2
     assert side == 4_341_104_640
     assert m.alias_size_in_bytes >= side
-    assert m.temp_size_in_bytes < (0.45e9 if program.startswith("prefill")
+    # The chunk's 0.249 GB fell to 0.148 with the key-tile loop (PR 38:
+    # no float32 scores of a query tile over the whole view).
+    assert m.temp_size_in_bytes < (0.2e9 if program.startswith("prefill")
                                    else 0.15e9), m.temp_size_in_bytes
     # Weights 10.38 GB (the routers in float32) beside the pool.
     assert 14.70e9 < m.argument_size_in_bytes < 14.75e9
