@@ -94,6 +94,7 @@ unchanged.
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -116,6 +117,8 @@ from kubeflow_tpu.serving.model_server import (
 from kubeflow_tpu.serving.adapters import AdapterNotFound
 from kubeflow_tpu.serving.prefix_cache import BlockManager
 from kubeflow_tpu.testing import faults
+
+log = logging.getLogger(__name__)
 
 
 # The chunk program's width where nobody states one.  A call reads every
@@ -213,7 +216,8 @@ LOOP_SECONDS_TOTAL = "kft_engine_loop_seconds_total"
 LOOP_SECONDS_HELP = \
     "wall seconds of the engine's loop thread, by engine and phase " \
     "of an iteration (the phases tile it; round_wait is the host " \
-    "blocked on the device)"
+    "blocked on the device, round_read the round's other reads " \
+    "once its first result has landed)"
 QUEUE_WAIT_TOTAL = "kft_engine_queue_wait_seconds_total"
 QUEUE_WAIT_HELP = \
     "seconds admitted requests waited from submit to slot claim, " \
@@ -279,7 +283,7 @@ _NO_DRAFT = np.empty((0,), np.int32)
 # The phases that tile one iteration of DecodeEngine._run (see _Phase).
 _PHASES = ("wait_work", "admit", "housekeeping", "prefill_dispatch",
            "round_prepare", "round_dispatch", "overlap", "round_wait",
-           "drain", "account")
+           "round_read", "drain", "account")
 _PHASE_KEY = {p: f"loop_{p}_s" for p in _PHASES}
 _PHASE_NOTE = {p: f"kft.engine.{p}" for p in _PHASES}
 # Cumulative counters that stats() hands out under their own names (a
@@ -290,7 +294,17 @@ _PHASE_NOTE = {p: f"kft.engine.{p}" for p in _PHASES}
 # that resumed a cached prefix; wall seconds of every AOT compile and
 # the largest program by the compiler's own account (arguments + outputs
 # + temporaries - aliased: memory_stats() leaves temporaries out).
+# The turnaround between two rounds (_ReadPhase, _device_has_work): from
+# a round's first result on the host to the return of the next call that
+# hands the device work, summed and counted.  The loop thread's CPU
+# seconds (time.thread_time(), stored once an iteration): over the
+# phases in which it is not blocked (all but wait_work and round_wait)
+# they say whether its work ran or waited for a core or the interpreter
+# lock.  The iterations that took _SLOW_ROUND_FACTOR times the running
+# mean, and the seconds they took over it (_note_iteration).
 _SUM_KEYS = ("loop_rounds", *_PHASE_KEY.values(),
+             "turnaround_s_sum", "turnarounds", "loop_cpu_s",
+             "slow_rounds", "slow_round_s_sum",
              "queue_wait_s_sum", "admitted",
              "prefill_span_s_sum", "first_tokens",
              "prefill_span_hit_s_sum", "first_tokens_hit",
@@ -298,6 +312,13 @@ _SUM_KEYS = ("loop_rounds", *_PHASE_KEY.values(),
 # The decode steps' (row, choice) pairs by where they fell, in the order
 # of ``state["moe_pairs"]`` (models/generate.py init_paged_state).
 _PAIR_KEYS = ("pairs_held", "pairs_zero", "pairs_absent")
+# An iteration that takes this many times the running mean of the
+# wall time (wait_work left out) of the iterations that waited for the
+# device is counted and logged with its own phase times; the mean
+# follows at _ITER_MEAN_ALPHA an iteration, a slow one counted as the
+# limit it passed.
+_SLOW_ROUND_FACTOR = 8
+_ITER_MEAN_ALPHA = 1 / 64
 
 
 class _Phase:
@@ -306,8 +327,10 @@ class _Phase:
     no trace records; it puts the phase on the device trace's clock, so
     an idle gap of the chip has an owner) and, on exit, the phase's OWN
     wall time added to ``stats()``'s cumulative ``loop_<name>_s``.  A
-    phase entered inside another is subtracted from it, so the ten sums
-    tile the thread's wall time; a window is two readings subtracted."""
+    phase entered inside another is subtracted from it, so the eleven
+    sums tile the thread's wall time; a window is two readings
+    subtracted.  The facts of an iteration's phases are kept until the
+    next iteration for the record of a slow one (``_note_iteration``)."""
 
     __slots__ = ("_engine", "_key", "_note", "_t0", "_inner")
 
@@ -317,6 +340,8 @@ class _Phase:
         self._note = engine._annotate(
             _PHASE_NOTE[name], round=engine._counters["loop_rounds"],
             **facts)
+        if facts:
+            engine._iter_facts.update(facts)
 
     def __enter__(self):
         self._note.__enter__()
@@ -328,6 +353,7 @@ class _Phase:
     def facts(self, **facts):
         """Facts known only at the phase's end."""
         self._note.set_metadata(**facts)
+        self._engine._iter_facts.update(facts)
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self._t0
@@ -339,6 +365,23 @@ class _Phase:
         self._engine._counters[self._key] += dt - self._inner
         self._note.__exit__(*exc)
         return False
+
+
+class _ReadPhase(_Phase):
+    """``round_read``, entered inside ``round_wait`` the moment the read
+    a round makes first has returned: ``round_wait``'s own stretch, the
+    host blocked on the device, ends here, and what the round reads and
+    counts after it is this phase's.  The first one entered since the
+    device was last handed work opens the turnaround
+    (``_device_has_work``): nothing is queued on the device from here."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        super().__enter__()
+        if self._engine._ready_at is None:
+            self._engine._ready_at = self._t0
+        return self
 
 
 def _ngram_propose(history: np.ndarray, k: int,
@@ -803,10 +846,19 @@ class DecodeEngine:
         self._annotate = jax.profiler.TraceAnnotation
         self._phases: List[_Phase] = []  # loop-thread-owned stack
         self._loop_pushed = dict.fromkeys(_PHASE_KEY.values(), 0.0)
+        # Loop-thread-owned, as the stack: when the open turnaround's
+        # first result landed (None: the device has work, or the loop
+        # went idle), the facts of this iteration's phases, the running
+        # mean of an iteration's wall time, the compile seconds it has
+        # seen and when it last logged a slow iteration.
+        self._ready_at: Optional[float] = None
+        self._iter_facts: Dict[str, Any] = {}
+        self._iter_mean: Optional[float] = None
+        self._compile_seen = 0.0
+        self._slow_logged_at = float("-inf")
         self._step_times: List[float] = []   # bounded reservoirs
         self._chunk_times: List[float] = []
         self._gap_times: List[float] = []
-        self._ttft_times: List[float] = []
         self._last_step_end: Optional[float] = None
         self._metric_name = name
         self._occ_gauge = REGISTRY.gauge(
@@ -1287,20 +1339,18 @@ class DecodeEngine:
                 "step_times": list(self._step_times),
                 "chunk_times": list(self._chunk_times),
                 "gap_times": list(self._gap_times),
-                "ttft_times": list(self._ttft_times),
                 "round_steps": list(self._round_steps),
             })
         steps = c["steps"]
 
         # Sort each reservoir ONCE, outside the lock: the lock only
-        # pays the four list copies, and every percentile below reads
+        # pays the list copies, and every percentile below reads
         # the one sorted copy — the old shape re-sorted the same
         # 4096-entry reservoir per pct() call while a hot /stats +
         # /metrics scrape pattern held the decode loop's lock.
         times = sorted(extra["step_times"])
         gaps = sorted(extra["gap_times"])
         chunks = sorted(extra["chunk_times"])
-        ttfts = sorted(extra["ttft_times"])
         rounds = sorted(extra["round_steps"])
 
         def pct(sorted_values, q):
@@ -1476,7 +1526,9 @@ class DecodeEngine:
             "prefill_positions_held": c["prefill_positions_held"],
             "prefill_positions_scored": c["prefill_positions_scored"],
             # Where the time goes, cumulative (see _SUM_KEYS): the
-            # loop's phases, queue wait, prefill span, compiles.
+            # loop's phases, the turnaround between two rounds, the
+            # loop thread's CPU seconds, the slow iterations, queue
+            # wait, prefill span, compiles.
             **{key: c[key] for key in _SUM_KEYS},
             "mean_occupancy": round(c["occupancy_sum"] / steps, 2)
             if steps else 0.0,
@@ -1484,18 +1536,14 @@ class DecodeEngine:
             if c["busy_s"] else 0.0,
             "token_latency_p50_ms": pct(times, 0.50),
             "token_latency_p95_ms": pct(times, 0.95),
-            "token_latency_p99_ms": pct(times, 0.99),
             # Wall time between consecutive step-call completions while
             # slots were live — the client-visible inter-token gap,
             # INCLUDING whatever admission/prefill work ran in between.
             # Bounded by one round plus one chunk; a full-prefill stall
             # would spike the max.
             "inter_token_gap_p50_ms": pct(gaps, 0.50),
-            "inter_token_gap_p99_ms": pct(gaps, 0.99),
             "inter_token_gap_max_ms": round(gaps[-1] * 1e3, 3)
             if gaps else 0.0,
-            "ttft_p50_ms": pct(ttfts, 0.50),
-            "ttft_p99_ms": pct(ttfts, 0.99),
         }
         if self._registry is not None:
             # Adapter-array serving (§5.11): registry occupancy plus
@@ -1550,6 +1598,33 @@ class DecodeEngine:
         """``with self._phase("drain"):`` — loop thread only."""
         return _Phase(self, name, facts)
 
+    def _round_read(self) -> _ReadPhase:
+        """``with self._round_read():`` inside ``round_wait``, right
+        after the read the round makes first."""
+        return _ReadPhase(self, "round_read", {})
+
+    def _device_has_work(self, phase: _Phase) -> None:
+        """A call that hands the device work (a round's, a verify
+        window's or a chunk's program) has just returned: the open
+        turnaround, if any, ends here.  It began when the first result of
+        the last round landed (``_ReadPhase``), the earliest moment the
+        loop could know the device had nothing left to do: the stretch
+        is the host's critical path of a round.  Summed and counted in
+        ``stats()``, and a fact of the dispatching ``phase`` so that a
+        traced run shows it beside the device's gap."""
+        ready = self._ready_at
+        if ready is None:
+            return
+        # Loop-thread-owned; wait_work forgets it under the lock only
+        # because that is where the loop finds out it has gone idle.
+        # kft: allow=lock-guard
+        self._ready_at = None
+        took = time.perf_counter() - ready
+        counters = self._counters  # loop-thread-owned keys, one writer
+        counters["turnaround_s_sum"] += took
+        counters["turnarounds"] += 1
+        phase.facts(since_ready_us=int(took * 1e6))
+
     def _aot(self, fn, *args, **static):
         """``fn.lower(*args).compile()`` for every AOT program of the
         engine, with the wall time it took added to ``compile_s`` and
@@ -1571,13 +1646,65 @@ class DecodeEngine:
 
     def _push_loop_seconds(self) -> None:
         """The phase sums, to the registry ``/metrics`` serves: once
-        an iteration, each phase's growth since the last push."""
+        an iteration, in its closing ``account``, each phase's growth
+        since the last push.  Together they are the iteration's own
+        phase times (that closing ``account`` itself counts with the
+        next iteration), which ``_note_iteration`` holds against the
+        running mean."""
+        own = {}
         for phase, key in _PHASE_KEY.items():
             total = self._counters[key]
-            if total > self._loop_pushed[key]:
-                self._loop_ctr.inc(total - self._loop_pushed[key],
+            own[phase] = total - self._loop_pushed[key]
+            if own[phase] > 0:
+                self._loop_ctr.inc(own[phase],
                                    engine=self._metric_name, phase=phase)
                 self._loop_pushed[key] = total
+        self._note_iteration(own)
+
+    def _note_iteration(self, own: Dict[str, float]) -> None:
+        """The slow iteration, kept: one whose wall time (``wait_work``
+        left out) passes ``_SLOW_ROUND_FACTOR`` times the running mean
+        adds 1 to ``slow_rounds`` and what it took over the mean to
+        ``slow_round_s_sum``, and is logged, at most once a second, with
+        its own time in every phase and the facts of its phases: a 2 s
+        round among 5 ms ones names its phase in the run it happens in.
+        Neither held against the mean nor part of it: an iteration that
+        compiled a program, and one that never waited for the device (a
+        chunk dispatched with no slot live costs the host's 2 ms, and the
+        iteration that then reads the last chunk's token waits for all of
+        them: on the chip every prefilled context of a set-up read as a
+        slow iteration while those counted)."""
+        counters = self._counters  # loop-thread-owned keys, one writer
+        if counters["compile_s"] != self._compile_seen:
+            self._compile_seen = counters["compile_s"]
+            return
+        if not own["round_wait"]:
+            return
+        took = sum(own.values()) - own["wait_work"]
+        mean = self._iter_mean
+        if mean is None:
+            self._iter_mean = took
+            return
+        limit = _SLOW_ROUND_FACTOR * mean
+        if took > limit:
+            counters["slow_rounds"] += 1
+            counters["slow_round_s_sum"] += took - mean
+            now = time.perf_counter()
+            if now - self._slow_logged_at >= 1.0:
+                self._slow_logged_at = now
+                facts = self._iter_facts
+                log.warning(
+                    "engine %r: slow iteration %d took %.4f s against a "
+                    "mean of %.4f s, most of it in %s; own seconds by "
+                    "phase: %s; width=%s steps=%s live=%s admitted=%s "
+                    "chunks=%s", self._metric_name,
+                    counters["loop_rounds"], took, mean,
+                    max((p for p in own if p != "wait_work"), key=own.get),
+                    " ".join(f"{p}={own[p]:.4f}" for p in _PHASES),
+                    *(facts.get(k, "-") for k in (
+                        "width", "steps", "live", "admitted", "chunks")))
+            took = limit
+        self._iter_mean = mean + _ITER_MEAN_ALPHA * (took - mean)
 
     def _free_slots_locked(self) -> List[int]:
         return [i for i, r in enumerate(self._slot_req) if r is None]
@@ -2333,6 +2460,7 @@ class DecodeEngine:
         t0 = time.perf_counter()
         self._state, tok = self._chunk_exec(*call_args)
         dt = time.perf_counter() - t0
+        self._device_has_work(self._phases[-1])
         entry["pos"] = start + w
         finished = entry["pos"] >= true_len
         if finished:
@@ -2423,13 +2551,15 @@ class DecodeEngine:
             host = arr  # the round already waited for it
         else:
             # The blocking read of a prefill's first token: the host
-            # waits on the chip here, not in the drain around it.
+            # waits on the chip here, not in the drain around it, and
+            # the chunk has nothing else to read.
             with self._phase("round_wait"):
                 host = np.asarray(arr)
+                with self._round_read():
+                    pass
         emitted = 0
         finished = 0
         finished_entries: List[dict] = []
-        ttfts: List[float] = []
         span_s = span_hit_s = 0.0
         firsts = firsts_hit = 0
         for col, entry in snapshot:
@@ -2472,7 +2602,6 @@ class DecodeEngine:
                         self._slot_req[entry["slot"]] = None
                     self._finish(entry)
                     finished_entries.append(entry)
-                    ttfts.append(entry["t_first"] - entry["t"])
                     finished += 1
                     break
         with self._lock:
@@ -2489,9 +2618,6 @@ class DecodeEngine:
             # cache until LRU eviction needs them.
             for e in finished_entries:
                 self._release_entry_locked(e)
-            self._ttft_times.extend(ttfts)
-            if len(self._ttft_times) > 4096:
-                del self._ttft_times[:2048]
             # Wake streaming readers: their tokens materialized above.
             self._emit.notify_all()
         if emitted:
@@ -2764,9 +2890,11 @@ class DecodeEngine:
             faults.fire("engine.step")
             tok_before = self._counters["tokens"]
         t0 = time.perf_counter()
-        with self._phase("round_dispatch", width=width, live=live):
+        with self._phase("round_dispatch", width=width,
+                         live=live) as phase:
             self._state, toks, counts, steps_run = self._rounds_exec(
                 self.params, self._state, tables, np.int32(width))
+            self._device_has_work(phase)
             touched = self._state.get("moe_touched")
             pairs = self._state.get("moe_pairs")
             for count in (touched, pairs):
@@ -2812,36 +2940,41 @@ class DecodeEngine:
             if self.host_spill_blocks:
                 self._spill_tick(1)
         # ---- round boundary: materialize ONCE, deliver, account.
+        # ``round_wait``'s own stretch is the wait for the first result;
+        # the other reads and the counts made of them are ``round_read``,
+        # and the facts stay on ``round_wait``, which ends where they do.
         with self._phase("round_wait") as phase:
             toks_np = np.asarray(toks)
-            counts_np = np.asarray(counts)
-            steps = int(steps_run)
-            # The device's own counts: the steps it ran of ``width``, and
-            # the cache positions those steps attended, summed over slots
-            # and steps (a slot that emitted n tokens from length l
-            # attended l, l + 1, ... l + n - 1): the round's least cache
-            # traffic, whatever stopped a slot.
-            attended = 0
-            for (i, _), at in zip(snapshot, lengths):
-                n = int(counts_np[i])
-                attended += n * at + n * (n - 1) // 2
-            facts = {"steps": steps, "attended": attended}
-            if touched is not None:
-                # The device's own count for this round (decode_rounds
-                # starts it at zero), read before the state is donated
-                # to the next dispatch.
-                facts["experts_touched"] = int(touched)
-                with self._lock:
-                    self._counters["experts_touched"] += \
-                        facts["experts_touched"]
-            if pairs is not None:
-                fell = dict(zip(_PAIR_KEYS, map(int, np.asarray(pairs))))
-                facts.update(fell)
-                with self._lock:
-                    for key, n in fell.items():
-                        self._counters[key] += n
-            phase.facts(**facts)
-            del toks, counts, steps_run  # freed here, inside a phase
+            with self._round_read():
+                counts_np = np.asarray(counts)
+                steps = int(steps_run)
+                # The device's own counts: the steps it ran of ``width``,
+                # and the cache positions those steps attended, summed
+                # over slots and steps (a slot that emitted n tokens from
+                # length l attended l, l + 1, ... l + n - 1): the round's
+                # least cache traffic, whatever stopped a slot.
+                attended = 0
+                for (i, _), at in zip(snapshot, lengths):
+                    n = int(counts_np[i])
+                    attended += n * at + n * (n - 1) // 2
+                facts = {"steps": steps, "attended": attended}
+                if touched is not None:
+                    # The device's own count for this round
+                    # (decode_rounds starts it at zero), read before the
+                    # state is donated to the next dispatch.
+                    facts["experts_touched"] = int(touched)
+                    with self._lock:
+                        self._counters["experts_touched"] += \
+                            facts["experts_touched"]
+                if pairs is not None:
+                    fell = dict(zip(_PAIR_KEYS,
+                                    map(int, np.asarray(pairs))))
+                    facts.update(fell)
+                    with self._lock:
+                        for key, n in fell.items():
+                            self._counters[key] += n
+                phase.facts(**facts)
+                del toks, counts, steps_run  # freed here, in a phase
         with self._phase("drain"):
             self._pending.append((toks_np, snapshot, counts_np))
             while self._pending:
@@ -2935,16 +3068,19 @@ class DecodeEngine:
             faults.fire("engine.step")
         t0 = time.perf_counter()
         with self._phase("round_dispatch",
-                         width=self.speculative_tokens + 1, live=live):
+                         width=self.speculative_tokens + 1,
+                         live=live) as phase:
             self._state, toks, counts = self._verify_exec(
                 self.params, self._state, draft, draft_len, self._tables)
+            self._device_has_work(phase)
         # Materialize ONCE and share the host copies with the drain —
         # a second device->host transfer per round would show up at
         # this call rate.
         with self._phase("round_wait"):
             toks_np = np.asarray(toks)
-            counts_np = np.asarray(counts)
-            del toks, counts  # freed here, inside a phase
+            with self._round_read():
+                counts_np = np.asarray(counts)
+                del toks, counts  # freed here, inside a phase
         with self._phase("drain"):
             self._pending.append((toks_np, snapshot, counts_np))
             while self._pending:
@@ -3023,9 +3159,10 @@ class DecodeEngine:
         """The loop thread.  Every statement of an iteration lies in
         one ``_phase`` (admit with wait_work inside it, housekeeping,
         prefill_dispatch, round_prepare, then the decode or the verify
-        round's round_dispatch / overlap / round_wait / drain, account),
-        so the ``loop_*_s`` sums tile the thread's wall time and every
-        idle gap of the device falls into a named phase."""
+        round's round_dispatch / overlap / round_wait with round_read
+        inside it / drain, account), so the ``loop_*_s`` sums tile the
+        thread's wall time and every idle gap of the device falls into a
+        named phase."""
         try:
             while True:
                 if not self._iterate():
@@ -3040,11 +3177,16 @@ class DecodeEngine:
         # bumped before the first phase so that it can name the round.
         # kft: allow=lock-guard
         self._counters["loop_rounds"] += 1
+        self._iter_facts.clear()
         with self._phase("admit"), self._lock:
             with self._phase("wait_work"):
                 while (not self._queue
                        and all(r is None for r in self._slot_req)
                        and not self._pending and not self._stopped):
+                    # Nothing queued and no live slot: the device waits
+                    # for a client, not for the loop, and the open
+                    # turnaround is closed without being counted.
+                    self._ready_at = None
                     self._work.wait()
             if self._stopped and not self._queue \
                     and all(r is None for r in self._slot_req) \
@@ -3193,6 +3335,10 @@ class DecodeEngine:
             if self.host_spill_blocks:
                 self._set_kv_spilled_gauge(
                     self._mgr.host_used_blocks())
+            # Loop-thread-owned key (see loop_rounds): cumulative by
+            # nature, so one clock read an iteration is enough.
+            # kft: allow=lock-guard
+            self._counters["loop_cpu_s"] = time.thread_time()
             self._push_loop_seconds()
         return True
 

@@ -19,12 +19,14 @@ SCOPES = {"kft.embed", "kft.qkv_proj", "kft.kv_write", "kft.kv_view",
           "kft.attention", "kft.attn_out", "kft.mlp", "kft.logits",
           "kft.sample"}
 # Top-level phases of one iteration in the order they may be entered;
-# wait_work lies inside admit, and a blocking token read inside drain is
-# a round_wait of its own.
+# wait_work lies inside admit, a blocking token read inside drain is a
+# round_wait of its own, and every round_wait holds one round_read (PR 39).
 RANK = {"admit": 0, "housekeeping": 1, "prefill_dispatch": 2,
         "round_prepare": 3, "round_dispatch": 4, "overlap": 5,
         "round_wait": 6, "drain": 7, "account": 8}
-NESTED = {"wait_work": "admit", "round_wait": "drain"}
+NESTED = {"wait_work": "admit", "round_wait": "drain",
+          "round_read": "round_wait"}
+PREFIX = "kft.engine."
 
 
 LOOPED = {"loop_steps": 2, "sandwich_norm": True}
@@ -254,16 +256,19 @@ def test_phases_tile_every_iteration_in_order(lm, path, monkeypatch):
     for number, group in sorted(rounds.items()):
         # Enter order is time order, and the round number never falls.
         assert [e["t0"] for e in group] == sorted(e["t0"] for e in group)
-        top, rank = [], -1
+        top, rank, opened = [], -1, []
         for e in group:
-            name = e["name"][len("kft.engine."):]
-            if top and e["t0"] < top[-1]["t1"]:  # inside the one before
-                assert NESTED[name] == top[-1]["name"][len("kft.engine."):]
-                assert e["t1"] <= top[-1]["t1"]
-                continue
-            assert RANK[name] >= rank, (number, name)
-            rank = RANK[name]
-            top.append(e)
+            name = e["name"][len(PREFIX):]
+            while opened and opened[-1]["t1"] <= e["t0"]:
+                opened.pop()
+            if opened:  # inside the one that is still open
+                assert NESTED[name] == opened[-1]["name"][len(PREFIX):]
+                assert e["t1"] <= opened[-1]["t1"]
+            else:
+                assert RANK[name] >= rank, (number, name)
+                rank = RANK[name]
+                top.append(e)
+            opened.append(e)
         names = [e["name"][len("kft.engine."):] for e in top]
         if number == max(rounds):  # closed and drained: the loop left
             assert names == ["admit"]
@@ -332,8 +337,16 @@ def test_round_wait_states_the_steps_and_positions_the_device_ran(
 
 
 def _loop_sums(stats):
-    return {k: v for k, v in stats.items()
-            if k.startswith("loop_") and k.endswith("_s")}
+    """The phase sums; ``loop_cpu_s`` is the thread's CPU clock."""
+    return {k: v for k, v in stats.items() if k != "loop_cpu_s"
+            and k.startswith("loop_") and k.endswith("_s")}
+
+
+def _unblocked(stats):
+    """Wall seconds of the phases in which the loop thread waits for
+    nothing: all but ``wait_work`` and ``round_wait``."""
+    return sum(v for k, v in _loop_sums(stats).items()
+               if k not in ("loop_wait_work_s", "loop_round_wait_s"))
 
 
 def test_loop_sums_are_monotone_and_add_up_to_the_wall_time(lm):
@@ -351,17 +364,201 @@ def test_loop_sums_are_monotone_and_add_up_to_the_wall_time(lm):
         readings.append(b)
     finally:
         engine.close()
-    assert len(_loop_sums(a)) == 10
+    assert len(_loop_sums(a)) == 11
     for before, after in zip(readings, readings[1:]):
         for key, value in _loop_sums(before).items():
             assert after[key] >= value
-        assert after["loop_rounds"] >= before["loop_rounds"]
+        for key in ("loop_rounds", "loop_cpu_s", "turnarounds",
+                    "turnaround_s_sum", "slow_rounds", "slow_round_s_sum"):
+            assert after[key] >= before[key]
     assert b["loop_rounds"] > a["loop_rounds"]
     grown = sum(_loop_sums(b).values()) - sum(_loop_sums(a).values())
     # The idle stretch before ``a`` is added when its wait ends (inside
     # the window) and the one before ``b`` is still open: 50 ms each way.
     assert grown == pytest.approx(t_b - t_a, rel=0.10, abs=0.06)
     assert b["loop_round_wait_s"] > a["loop_round_wait_s"]
+    assert b["loop_round_read_s"] > a["loop_round_read_s"]
+    # The thread's CPU seconds: it cannot have run for longer than the
+    # phases in which it is not blocked lasted (10 ms for the reads of
+    # two clocks an iteration apart and what a wake-up costs).
+    assert 0 < b["loop_cpu_s"] - a["loop_cpu_s"] \
+        <= _unblocked(b) - _unblocked(a) + 0.01
+    assert b["turnarounds"] > a["turnarounds"]
+
+
+def _loop_events(engine):
+    """The finished annotations of the engine's loop thread, bare phase
+    names, in the order they were entered."""
+    return [dict(e, name=e["name"][len(PREFIX):]) for e in Recorder.events
+            if "t1" in e and e["thread"] == engine._thread.ident]
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_round_read_lies_inside_round_wait_in_every_round(
+        lm, path, monkeypatch):
+    """Every ``round_wait`` (a decode round's, a verify round's, the
+    blocking read of a prefill's first token inside ``drain``) holds ONE
+    ``round_read``, entered after the wait's own stretch and ending with
+    it; the round's facts stay on ``round_wait``, which a traced run's
+    readers match to the device's calls."""
+    import jax
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    Recorder.events = []
+    kw, _ = PATHS[path]
+    engine = _engine(lm, name=f"read-{path}", **kw)
+    try:
+        prompts = [[7, 8, 9] * 5] * 4 if path == "verify" else _prompts(4)
+        _serve(engine, prompts)
+        stats = engine.stats()
+    finally:
+        engine.close()
+    events = _loop_events(engine)
+    waits = [e for e in events if e["name"] == "round_wait"]
+    reads = [e for e in events if e["name"] == "round_read"]
+    drains = [e for e in events if e["name"] == "drain"]
+    assert len(waits) == len(reads) >= 6
+    for wait, read in zip(waits, reads):
+        assert wait["facts"]["round"] == read["facts"]["round"]
+        assert wait["t0"] <= read["t0"] <= read["t1"] <= wait["t1"]
+        assert set(read["facts"]) == {"round"}
+    in_drain = [w for w in waits if any(
+        d["t0"] <= w["t0"] and w["t1"] <= d["t1"] for d in drains)]
+    # One blocking read a prompt (its first token), the rest are rounds.
+    assert len(in_drain) == len(prompts)
+    rounds = [w for w in waits if not any(w is d for d in in_drain)]
+    assert len(rounds) == stats["fused_rounds"] + stats["spec_steps"]
+    fused = [w for w in rounds if "steps" in w["facts"]]
+    assert len(fused) == stats["fused_rounds"]
+    assert all("attended" in w["facts"] for w in fused)
+    if path == "verify":
+        assert stats["spec_steps"] >= 1
+    # What the reads took is in stats() under its own name, and the
+    # wait's own stretch no longer holds it.
+    read_s = sum(e["t1"] - e["t0"] for e in reads)
+    wait_s = sum(e["t1"] - e["t0"] for e in waits)
+    assert 0 < stats["loop_round_read_s"] <= read_s
+    assert stats["loop_round_wait_s"] <= wait_s - stats["loop_round_read_s"]
+
+
+def _hands_work(event):
+    """Does this annotation hold a call that hands the device work?"""
+    return event["name"] == "round_dispatch" or (
+        event["name"] == "prefill_dispatch"
+        and event["facts"].get("chunks", 0) > 0)
+
+
+def test_turnaround_is_first_result_to_the_next_dispatchs_return(
+        lm, monkeypatch):
+    """``turnaround_s_sum`` / ``turnarounds`` against the recorded
+    annotations: a counted stretch starts where the first ``round_read``
+    since the last hand-over was entered and ends inside the next
+    annotation that hands the device work, a chunk's where that comes
+    before the round's; the dispatching annotation states it
+    (``since_ready_us``); nothing is counted across a ``wait_work`` in
+    which the loop had nothing to do."""
+    import jax
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    Recorder.events = []
+    engine = _engine(lm, decode_rounds=4, name="turnaround")
+    try:
+        _serve(engine, _prompts(2))
+        idle_from = time.perf_counter()
+        time.sleep(0.08)  # the loop finds nothing to do and waits
+        idle_to = time.perf_counter()
+        # A chunk between two rounds: a prompt of four chunks beside one
+        # of a single chunk, which decodes while the other prefills.
+        _serve(engine, _prompts(1, length=5) + _prompts(1, length=29, seed=3),
+               new=30)
+        stats = engine.stats()
+    finally:
+        engine.close()
+    events = _loop_events(engine)
+    ready, counted, by_chunk, stated = None, [], 0, {}
+    for e in events:
+        if e["name"] == "round_read" and ready is None:
+            ready = e["t0"]
+        if not _hands_work(e):
+            continue
+        if "since_ready_us" in e["facts"]:
+            took = e["facts"]["since_ready_us"] / 1e6
+            # The stretch ends at the call's return, inside e.
+            assert ready is not None
+            assert e["t0"] - ready - 1e-4 <= took <= e["t1"] - ready
+            counted.append((ready, took))
+            by_chunk += e["name"] == "prefill_dispatch"
+            stated.setdefault(e["facts"]["round"], []).append(e["name"])
+        ready = None
+    assert len(counted) == stats["turnarounds"]
+    assert stats["turnaround_s_sum"] == pytest.approx(
+        sum(took for _, took in counted), abs=2e-6 * len(counted))
+    assert stats["turnarounds"] >= stats["fused_rounds"] // 2 >= 4
+    # The chunk's call ended the turnaround where it came first, and
+    # that iteration's round found none open.
+    assert by_chunk >= 1
+    assert all(len(names) == 1 for names in stated.values())
+    # The idle stretch: no counted turnaround spans its middle, and the
+    # first hand-over after it closes none.
+    middle = (idle_from + idle_to) / 2
+    assert not any(start < middle < start + took
+                   for start, took in counted)
+    first = next(e for e in events if _hands_work(e) and e["t0"] > idle_to)
+    assert "since_ready_us" not in first["facts"]
+    last = max(e["t1"] for e in events if e["t1"] < middle)
+    assert any(e["name"] == "round_read" and e["t0"] <= last
+               for e in events)  # a turnaround WAS open when it went idle
+
+
+def test_a_slow_iteration_is_counted_and_logged_once(
+        lm, monkeypatch, caplog):
+    """A ``faults`` sleep at ``engine.step`` (in ``round_prepare``) makes
+    one iteration many times the running mean: ``slow_rounds`` + 1, its
+    excess in ``slow_round_s_sum``, ONE warning that names the phase and
+    carries the iteration's eleven own phase times, and none for the
+    rounds beside it."""
+    import logging
+
+    from kubeflow_tpu.serving import engine as engine_module
+    from kubeflow_tpu.testing import faults
+
+    assert engine_module._SLOW_ROUND_FACTOR == 8
+    # A test box under load hiccups by 8 times a 2 ms iteration; the
+    # sleep below is 100 times one.
+    monkeypatch.setattr(engine_module, "_SLOW_ROUND_FACTOR", 40)
+    engine = _engine(lm, decode_rounds=4, name="slow")
+    try:
+        for batch in range(3):  # compiles, then a mean to hold against
+            _serve(engine, _prompts(3, seed=batch))
+        before = engine.stats()
+        with caplog.at_level(logging.WARNING,
+                             logger="kubeflow_tpu.serving.engine"):
+            with faults.injected("seed=1;engine.step:sleep=0.4*1"):
+                _serve(engine, _prompts(3, seed=7))
+            _serve(engine, _prompts(3, seed=8))
+        after = engine.stats()
+    finally:
+        engine.close()
+    assert before["slow_rounds"] == 0 and before["slow_round_s_sum"] == 0
+    assert after["slow_rounds"] == 1
+    assert 0.35 < after["slow_round_s_sum"] < 0.6
+    logged = [r.getMessage() for r in caplog.records
+              if "slow iteration" in r.getMessage()]
+    assert len(logged) == 1
+    text = logged[0]
+    assert "engine 'slow'" in text and "most of it in round_prepare" in text
+    times = dict(re.findall(r"(\w+)=(\d+\.\d+)", text))
+    assert set(times) == {
+        "wait_work", "admit", "housekeeping", "prefill_dispatch",
+        "round_prepare", "round_dispatch", "overlap", "round_wait",
+        "round_read", "drain", "account"}
+    assert float(times["round_prepare"]) >= 0.4
+    assert max(float(v) for k, v in times.items()
+               if k != "round_prepare") < 0.1
+    facts = dict(re.findall(r"(width|steps|live|admitted|chunks)=(\S+)",
+                            text))
+    assert set(facts) == {"width", "steps", "live", "admitted", "chunks"}
+    assert int(facts["live"]) >= 1 and 1 <= int(facts["width"]) <= 4
 
 
 @pytest.mark.parametrize("prefix_hit", [False, True])
