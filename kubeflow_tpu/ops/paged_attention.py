@@ -11,8 +11,28 @@ and its plane, never a slice.  The plain path gathers each slot's whole
 multiplies in float32: tens of gigabytes of HBM traffic a step for a
 gigabyte of resident keys and values.  This kernel leaves the pool in
 HBM and, per slot, copies only the pages below the slot's frontier into
-VMEM, several pages a block, the next block's copies in flight while
-this one is computed.
+VMEM, about a MiB of pages a block and side (``_BLOCK_BYTES``), into one
+of two buffers.
+
+The walk.  The grid is one slot a step, in order, and the copies stay in
+flight from the first slot's first block to the last slot's last.  While
+a block is computed, the NEXT block's copies run into the other buffer:
+the same slot's next block or, under a slot's last block, the first
+block of the next slot that attends anything (``n_tokens`` and the
+tables are scalar-prefetched, so a slot can read its successor's).  Two
+words in SMEM ride from one grid step to the next: the buffer that next
+first block went to (a slot of an odd number of blocks flips it), and
+the slot it was started for, so that only a call's first live slot
+starts its own first block, with nothing to compute meanwhile.  A
+retired slot (``n_tokens`` 0) starts and waits for nothing; the search
+for the next live slot walks past it.  Every block but a slot's last is
+whole: its pages are issued and waited for under ONE condition, eight
+in straight-line code a turn of a loop, and only a last block counts
+its pages; no page is a branch.  Every start has its wait, a semaphore
+a buffer and side, whatever ends the walk.
+The compiler's bounds checks on the copies are off (they were two
+thirds of the instructions a page's copy cost): the plane and every
+table entry are clamped into the pool instead.
 
 Layout.  The pool is viewed ``[kv_planes * num_blocks, bt * hkv, d]``
 (a bitcast: the leading axes merged, and a page's two), so physical
@@ -59,6 +79,7 @@ another order of summation.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -70,22 +91,33 @@ _MASKED = -1e30
 
 
 def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
-            pages, nb, scale, value_lanes=None):
-    """One slot: walk its resident pages ``pages`` at a time.
+            pages, nb, planes, scale, value_lanes=None):
+    """One slot: walk its resident pages ``pages`` at a time and, under
+    its last block, start the first block of the next slot that attends
+    anything.
 
     ``refs``: the pool's sides in HBM (keys and values; or, with
     ``value_lanes``, the ONE latent pool, whose rows are keys whole and
     values in their first ``value_lanes`` lanes), the output, a buffer a
-    side, the semaphores."""
-    sides = (len(refs) - 2) // 2
+    side, the semaphores, and ``ride``, the two words that pass from one
+    grid step to the next: the buffer the next first block goes to, and
+    the slot whose first block is in flight (module docstring)."""
+    sides = (len(refs) - 3) // 2
     hbm, o_ref = refs[:sides], refs[sides]
-    bufs, sems = refs[sides + 1:-1], refs[-1]
-    s = pl.program_id(0)
-    first = plane_ref[0] * nb             # the plane's page 0 in the view
-    n = ntok_ref[s]                       # positions to attend (0: none)
-    n_pages = (n + bt - 1) // bt
-    n_blocks = (n_pages + pages - 1) // pages
+    bufs, sems, ride = refs[sides + 1:-2], refs[-2], refs[-1]
+    s, slots = pl.program_id(0), pl.num_programs(0)
+    # The plane's page 0 in the view.  This clamp and the one on a
+    # table's entry keep every copy inside the pool; the compiler's own
+    # checks are off (``_COMPILER_PARAMS``).
+    first = jnp.clip(plane_ref[0], 0, planes - 1) * nb
     rows = bt * hkv                       # (position, kv head) rows a page
+
+    def pages_of(slot):
+        return (ntok_ref[slot] + bt - 1) // bt
+
+    n = ntok_ref[s]                       # positions to attend (0: none)
+    n_pages = pages_of(s)
+    n_blocks = (n_pages + pages - 1) // pages
 
     @pl.when(s == 0)
     def _():
@@ -93,30 +125,47 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
         # it has to hold numbers, and fresh VMEM need not.
         for buf in bufs:
             buf[...] = jnp.zeros_like(buf)
+        ride[0] = 0
+        ride[1] = -1
 
-    def copies(blk, buf, p):
-        # Entries below the frontier are real pages; the clamp only
-        # keeps a sentinel (== nb) that a wrong table would hold inside
-        # the plane.
-        page = first + jnp.minimum(
-            tables_ref[s * mb + blk * pages + p], nb - 1)
-        dst = pl.ds(p * rows, rows)
-        return tuple(
-            pltpu.make_async_copy(
-                hbm[side].at[page], bufs[side].at[buf, dst],
-                sems.at[side, buf])
-            for side in range(sides))
+    def block(slot, blk, buf, held, act):
+        """``act`` (start or wait) on the copies of ``slot``'s block
+        ``blk``, of whose pages the slot holds those below ``held``.
+        Every block but a slot's last is whole: its pages go in
+        straight-line code, ``_UNROLL`` at a time, under ONE condition;
+        only a last block counts its pages."""
+        def page(p, _=None):
+            # Entries below the frontier are real pages; the clamp only
+            # keeps a sentinel (== nb) that a wrong table would hold
+            # inside the plane.
+            at = first + jnp.clip(
+                tables_ref[slot * mb + blk * pages + p], 0, nb - 1)
+            dst = pl.ds(pl.multiple_of(p * rows, rows), rows)
+            for side in range(sides):
+                act(pltpu.make_async_copy(
+                    hbm[side].at[at], bufs[side].at[buf, dst],
+                    sems.at[side, buf]))
 
-    def for_pages(blk, buf, act):
-        for p in range(pages):
-            @pl.when(blk * pages + p < n_pages)
-            def _(p=p):
-                for c in copies(blk, buf, p):
-                    act(c)
+        here = held - blk * pages             # of this block's pages
+        unroll = math.gcd(pages, _UNROLL)
 
-    @pl.when(n_blocks > 0)
-    def _():
-        for_pages(0, 0, lambda c: c.start())
+        def some_pages(i, _):
+            for j in range(unroll):
+                page(i * unroll + j)
+
+        @pl.when(here >= pages)
+        def _():
+            jax.lax.fori_loop(0, pages // unroll, some_pages, None)
+
+        @pl.when(here < pages)
+        def _():
+            jax.lax.fori_loop(0, here, page, None)
+
+    def start(copy):
+        copy.start()
+
+    def wait(copy):
+        copy.wait()
 
     q = q_ref[0]                                            # [h, d]
     h = q.shape[0]
@@ -126,15 +175,9 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
                           // g)
     pos = col // hkv
 
-    def body(i, carry):
+    def attend(i, buf, carry):
         m, l, acc = carry
-        buf = i % 2
-
-        @pl.when(i + 1 < n_blocks)
-        def _():
-            for_pages(i + 1, 1 - buf, lambda c: c.start())
-
-        for_pages(i, buf, lambda c: c.wait())
+        block(s, i, buf, n_pages, wait)
         k = bufs[0][buf]                                    # [rows*, d]
         v = bufs[-1][buf]
         if value_lanes is not None:
@@ -152,15 +195,94 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    m0 = jnp.full((h, 1), _MASKED, jnp.float32)
-    l0 = jnp.zeros((h, 1), jnp.float32)
-    acc0 = jnp.zeros((h, value_lanes or q.shape[1]), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-    # A slot with nothing to attend (retired) returns zeros.
-    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+    @pl.when(n_blocks == 0)
+    def _():
+        # A slot with nothing to attend (retired) starts and waits for
+        # nothing, and returns zeros.
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(n_blocks > 0)
+    def _():
+        buf0 = ride[0]
+
+        @pl.when(ride[1] != s)
+        def _():
+            # Nobody fetched this slot's first block: the call's first
+            # slot that attends anything.
+            block(s, 0, buf0, n_pages, start)
+
+        def body(i, carry):
+            buf = (buf0 + i) % 2
+            block(s, i + 1, 1 - buf, n_pages, start)
+            return attend(i, buf, carry)
+
+        h_rows = (h, 1)
+        carry = jax.lax.fori_loop(0, n_blocks - 1, body, (
+            jnp.full(h_rows, _MASKED, jnp.float32),
+            jnp.zeros(h_rows, jnp.float32),
+            jnp.zeros((h, value_lanes or q.shape[1]), jnp.float32)))
+        # The last block: the buffer beside it is free, and takes the
+        # first block of the next slot that attends anything.
+        buf = (buf0 + n_blocks - 1) % 2
+        nxt = jax.lax.while_loop(
+            lambda j: (j < slots)
+            & (ntok_ref[jnp.minimum(j, slots - 1)] == 0),
+            lambda j: j + 1, s + 1)
+
+        @pl.when(nxt < slots)
+        def _():
+            block(nxt, 0, 1 - buf, pages_of(nxt), start)
+
+        ride[0] = 1 - buf
+        ride[1] = nxt
+        _, l, acc = attend(n_blocks - 1, buf, carry)
+        o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 _LANES = 128
+
+# Pages of a whole block issued between two turns of their loop: the
+# loop's own instructions are a tenth of a page's; unrolled whole (64)
+# the kernel's text tripled and every program that holds it took three
+# times as long to lower (agents' ``setup_s`` +5 s).
+_UNROLL = 8
+
+# A block of copies is about a MiB a side: read alone on the chip
+# (PERF.md section 5, PR 41), latent pages of 20 KB are fastest 64 a
+# block (32: +12 to +19 %, 128: +2 to +7 %, 16: +44 %), and k / v pages
+# of 32 KB a side read within 4 % at 16, 32 and 64 a block (8: +7 %).
+_BLOCK_BYTES = 1 << 20
+
+# Each page's copy carried two bounds checks that halt the chip (one on
+# the HBM address, one on the VMEM address): 12 of the ~18 instruction
+# bundles a page's copy cost, with no vector work beside them, a third
+# of a latent block's time.  Every address the kernel forms is clamped
+# (the plane, a table's entry) or static.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary",), disable_bounds_checks=True)
+
+
+def _block_pages(page_bytes: int, table_pages: int) -> int:
+    """Pages a block: the fewest, in powers of two, that fill
+    ``_BLOCK_BYTES`` a side; never more than a table holds."""
+    pages = 1
+    while pages * page_bytes < _BLOCK_BYTES:
+        pages *= 2
+    return min(pages, table_pages)
+
+
+def _walk_arguments(tables, n_tokens, plane):
+    """The kernel's three scalar-prefetched arguments."""
+    return (tables.reshape(-1).astype(jnp.int32),
+            n_tokens.astype(jnp.int32),
+            jnp.reshape(plane, (1,)).astype(jnp.int32))
+
+
+def _walk_scratch(buffers):
+    """Two buffers a side, a semaphore a buffer, and the two words that
+    ride from one grid step to the next."""
+    return [*buffers, pltpu.SemaphoreType.DMA((len(buffers), 2)),
+            pltpu.SMEM((2,), jnp.int32)]
 
 
 def supports(head_dim: int, n_kv_heads: int) -> bool:
@@ -175,7 +297,7 @@ def supports(head_dim: int, n_kv_heads: int) -> bool:
 @functools.partial(jax.jit, static_argnames=("pages_per_block",
                                              "interpret"))
 def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
-                           pages_per_block: int = 16,
+                           pages_per_block: int | None = None,
                            interpret: bool = False):
     """``q [S, h, d]`` against each slot's resident pages -> ``[S, h, d]``.
 
@@ -190,6 +312,8 @@ def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
     from 0 and INCLUDING the step's own (already written to the pool);
     0 does no page and returns zeros (a retired slot).  Slots may share
     physical pages (the prefix cache's aliasing).
+    pages_per_block: the walk's block; None takes it from a page's bytes
+    (``_block_pages``).
     """
     S, h, head_dim = q.shape
     planes, nb, bt, hkv, _ = k_pool.shape
@@ -207,11 +331,12 @@ def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
                               dtype=q.dtype)[None, :, :, None]
         q = (q[:, :, None, :] * lane).reshape(S, h, pack * head_dim)
     hkv, g, d = hkv // pack, g * pack, pack * head_dim
-    pages = max(1, min(pages_per_block, mb))
     rows = bt * hkv
+    pages = min(pages_per_block, mb) if pages_per_block else _block_pages(
+        rows * d * k_pool.dtype.itemsize, mb)
     kernel = functools.partial(
         _kernel, mb=mb, bt=bt, hkv=hkv, g=g, pages=pages, nb=nb,
-        scale=scale)
+        planes=planes, scale=scale)
     out = pl.pallas_call(
         kernel,
         name="paged_decode_attention",
@@ -225,17 +350,13 @@ def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((1, h, d), lambda s, *_: (s, 0, 0)),
-            scratch_shapes=[
+            scratch_shapes=_walk_scratch([
                 pltpu.VMEM((2, pages * rows, d), k_pool.dtype),
-                pltpu.VMEM((2, pages * rows, d), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ],
+                pltpu.VMEM((2, pages * rows, d), v_pool.dtype)]),
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(tables.reshape(-1).astype(jnp.int32), n_tokens.astype(jnp.int32),
-      jnp.reshape(plane, (1,)).astype(jnp.int32), q,
+    )(*_walk_arguments(tables, n_tokens, plane), q,
       k_pool.reshape(planes * nb, rows, d),
       v_pool.reshape(planes * nb, rows, d))
     if pack > 1:
@@ -247,7 +368,7 @@ def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
     "value_lanes", "scale", "pages_per_block", "interpret"))
 def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
                                   value_lanes: int, scale: float, *,
-                                  pages_per_block: int = 32,
+                                  pages_per_block: int | None = None,
                                   interpret: bool = False):
     """The latent (MLA) form: ``q [S, h, row]`` against each slot's
     resident LATENT pages -> ``[S, h, value_lanes]``, in the latent space.
@@ -266,10 +387,11 @@ def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
     S, h, row = q.shape
     planes, nb, bt, _ = pool.shape
     mb = tables.shape[1]
-    pages = max(1, min(pages_per_block, mb))
+    pages = min(pages_per_block, mb) if pages_per_block else _block_pages(
+        bt * row * pool.dtype.itemsize, mb)
     kernel = functools.partial(
         _kernel, mb=mb, bt=bt, hkv=1, g=h, pages=pages, nb=nb,
-        scale=scale, value_lanes=value_lanes)
+        planes=planes, scale=scale, value_lanes=value_lanes)
     return pl.pallas_call(
         kernel,
         name="paged_latent_decode_attention",
@@ -283,14 +405,10 @@ def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
             ],
             out_specs=pl.BlockSpec((1, h, value_lanes),
                                    lambda s, *_: (s, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((2, pages * bt, row), pool.dtype),
-                pltpu.SemaphoreType.DMA((1, 2)),
-            ],
+            scratch_shapes=_walk_scratch([
+                pltpu.VMEM((2, pages * bt, row), pool.dtype)]),
         ),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(tables.reshape(-1).astype(jnp.int32), n_tokens.astype(jnp.int32),
-      jnp.reshape(plane, (1,)).astype(jnp.int32), q,
+    )(*_walk_arguments(tables, n_tokens, plane), q,
       pool.reshape(planes * nb, bt, row))

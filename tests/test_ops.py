@@ -1,5 +1,7 @@
 """Attention op tests: XLA reference vs Pallas flash kernel (interpreter)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -478,3 +480,132 @@ class TestPagedDecodeKernel:
         np.testing.assert_allclose(
             np.asarray(out)[live], np.asarray(ref)[live], atol=2e-5)
         assert not np.asarray(out)[~live].any()
+
+    # What the walk across slots can get wrong (PR 41): a slot's first
+    # block is fetched under the slot before it, into the buffer that is
+    # free, and a whole block's pages are issued in straight-line code.
+    # Six slots, tables of 12 pages, blocks of 2 pages (16 positions):
+    # positions a slot, and what the case is about.
+    _WALK = {
+        "retired_between_live": (40, 0, 23, 0, 0, 50),
+        "first_and_last_retired": (0, 33, 48, 7, 17, 0),
+        "one_live_slot": (0, 0, 0, 37, 0, 0),
+        "n_of_one": (1, 1, 40, 1, 0, 1),
+        "exact_block_multiples": (16, 32, 48, 64, 96, 16),
+        # 3, 2, 1, 4, 5, 2 blocks: the buffer a slot starts in flips or
+        # not from one slot to the next.
+        "odd_and_even_block_counts": (41, 32, 9, 57, 70, 20),
+        "shared_pages": (52, 52, 36, 20, 60, 44),
+        "sentinel_past_the_frontier": (16, 5, 96, 31, 0, 24),
+    }
+
+    @pytest.mark.parametrize("form", ["k_v", "latent"])
+    @pytest.mark.parametrize("case", sorted(_WALK))
+    def test_walk_across_slots_matches_gathered_view(self, case, form):
+        """Both forms in the TPU interpreter, which a plain
+        ``interpret=True`` is not: scratch starts as NaN, a copy lands
+        only where it is WAITED for, a semaphore left with a count and a
+        read that races a copy are reported."""
+        from jax._src.pallas.mosaic.interpret import (
+            interpret_pallas_call as tpu_interpreter,
+        )
+        from jax.experimental.pallas import tpu as pltpu
+
+        from kubeflow_tpu.ops.paged_attention import (
+            paged_decode_attention,
+            paged_latent_decode_attention,
+        )
+
+        rng = np.random.RandomState(41)
+        slots, table, pages = 6, 12, 2
+        nb = slots * table + 5
+        n = np.array(self._WALK[case], np.int32)
+        tables = rng.permutation(nb)[:slots * table].reshape(
+            slots, table).astype(np.int32)
+        if case == "shared_pages":
+            # A cached prefix of three pages under slots 0, 1 and 4: a
+            # block and a half, so a shared page is one slot's block 1
+            # and stays resident while the next slot fetches it again.
+            tables[1, :3] = tables[4, :3] = tables[0, :3]
+        if case == "sentinel_past_the_frontier":
+            for s in range(slots):
+                tables[s, -(-int(n[s]) // _PAGE):] = nb
+        tables, n_tokens = jnp.asarray(tables), jnp.asarray(n)
+        mode = pltpu.InterpretParams(detect_races=True)
+        if form == "k_v":
+            hkv, d, h = 2, 128, 4
+            q = jnp.asarray(rng.randn(slots, h, d), jnp.float32)
+            k_pools = jnp.asarray(
+                rng.randn(2, nb, _PAGE, hkv, d), jnp.float32)
+            v_pools = jnp.asarray(
+                rng.randn(2, nb, _PAGE, hkv, d), jnp.float32)
+            ref = self._reference(q, k_pools[1], v_pools[1], tables,
+                                  n_tokens)
+            run = functools.partial(
+                paged_decode_attention, q, k_pools, v_pools, jnp.int32(1),
+                tables, n_tokens, pages_per_block=pages, interpret=mode)
+        else:
+            row, latent, h = 256, 128, 8
+            q = jnp.asarray(rng.randn(slots, h, row), jnp.float32)
+            pools = jnp.asarray(rng.randn(2, nb, _PAGE, row), jnp.float32)
+            view = pools[1][jnp.minimum(tables, nb - 1)].reshape(
+                slots, table * _PAGE, row)
+            with jax.default_matmul_precision("highest"):
+                sc = jnp.einsum("shr,skr->shk", q, view) * 0.25
+                sc = jnp.where(
+                    jnp.arange(table * _PAGE)[None, None, :]
+                    < n_tokens[:, None, None], sc, -jnp.inf)
+                ref = jnp.einsum(
+                    "shk,skc->shc", jax.nn.softmax(sc, -1),
+                    view[..., :latent])
+            run = functools.partial(
+                paged_latent_decode_attention, q, pools, jnp.int32(1),
+                tables, n_tokens, latent, 0.25, pages_per_block=pages,
+                interpret=mode)
+        # The interpreter's callbacks run jax operations of their own and
+        # can deadlock against another dispatch: the reference is on the
+        # host before the kernel starts, and nothing follows it.
+        ref = np.asarray(ref)
+        out = np.asarray(run())
+        assert not tpu_interpreter.races.races_found
+        live = n > 0
+        np.testing.assert_allclose(out[live], ref[live], atol=2e-5)
+        assert not out[~live].any()
+
+    def test_walk_leaves_no_copy_unwaited(self, capfd):
+        """A slot's first block is started by the slot before it: every
+        start has its wait, whatever ends the walk (a live last slot,
+        retired ones after it).  The interpreter names a semaphore that
+        keeps a count at the kernel's end."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        from kubeflow_tpu.ops.paged_attention import (
+            paged_latent_decode_attention,
+        )
+
+        rng = np.random.RandomState(42)
+        slots, table, row = 5, 6, 128
+        nb = slots * table
+        pools = jnp.asarray(rng.randn(1, nb, _PAGE, row), jnp.float32)
+        q = jnp.asarray(rng.randn(slots, 8, row), jnp.float32)
+        tables = jnp.asarray(rng.permutation(nb).reshape(
+            slots, table).astype(np.int32))
+        for n in [(20, 0, 48, 0, 0), (0, 9, 0, 33, 17)]:
+            out = paged_latent_decode_attention(
+                q, pools, jnp.int32(0), tables, jnp.asarray(n, jnp.int32),
+                128, 0.25, pages_per_block=2,
+                interpret=pltpu.InterpretParams())
+            assert np.isfinite(np.asarray(out)).all()
+        assert "non-zero count" not in capfd.readouterr().out
+
+    @pytest.mark.parametrize("page_bytes,table,pages", [
+        (16 * 640 * 2, 414, 64),        # agents: latent rows of 640 lanes
+        (16 * 8 * 128 * 2, 400, 32),    # docqa, chat: 8 kv heads of 128
+        (16 * 16 * 128 * 2, 32, 16),    # reason: 16 kv heads of 128
+        (16 * 8 * 64 * 2, 44, 44),      # workers: the whole table
+        (8 * 128 * 4, 5, 5)])           # these tests' pools
+    def test_a_block_is_a_mib_of_pages_a_side(self, page_bytes, table,
+                                              pages):
+        from kubeflow_tpu.ops.paged_attention import _block_pages
+
+        assert _block_pages(page_bytes, table) == pages

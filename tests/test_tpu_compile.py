@@ -673,3 +673,43 @@ def test_latent_decode_rounds_attends_through_the_latent_kernel(
         "paged_latent_decode_attention", "")
     _, chunk = longcat_program("prefill_chunk_into_slot")
     assert "paged_latent_decode_attention" not in chunk.as_text()
+
+
+# ``decode_rounds``'s live bytes (arguments + outputs + temporaries -
+# aliased) at the five cells' sizes before PR 41 changed the kernel's page
+# walk.  What the walk added rides in VMEM and SMEM scratch, and its tables
+# are clamped inside the kernel: the compiled peak does not rise with it.
+_DECODE_PEAK_BEFORE_THE_WALK = {
+    "internlm2-1.8b": 8_208_373_760,
+    "mistral-7b-v0.3-l16": 10_839_322_624,
+    "ouro-2.6b": 10_571_918_336,
+    "lfm2-24b-a2b-l10": 10_693_747_200,
+    "longcat-flash-omni-l4": 14_813_549_056,
+}
+
+
+@pytest.mark.parametrize("name", sorted(_DECODE_PEAK_BEFORE_THE_WALK))
+def test_decode_rounds_peak_is_no_higher_than_before_the_walk(
+        request, name):
+    """Both forms of the kernel, in every cell's decode program: the
+    kernel by its name in the text (the benchmark's readers find it so),
+    the pool aliased, the peak where it was."""
+    if name in CELLS:
+        e, compiled = request.getfixturevalue("cell_program")(
+            name, "decode_rounds")
+    else:
+        e, compiled = request.getfixturevalue(
+            "lfm2_program" if name.startswith("lfm2")
+            else "longcat_program")("decode_rounds")
+    latent = "cache_latent" in e["state"]
+    kernel = "paged_latent_decode_attention" if latent \
+        else "paged_decode_attention"
+    assert f"%{kernel}" in compiled.as_text()
+    m = compiled.memory_analysis()
+    sides = [e["state"][k] for k in (
+        ["cache_latent"] if latent else ["cache_k", "cache_v"])]
+    assert m.alias_size_in_bytes >= sum(
+        int(np.prod(p.shape)) * p.dtype.itemsize for p in sides)
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert live <= _DECODE_PEAK_BEFORE_THE_WALK[name], live
