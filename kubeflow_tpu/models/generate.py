@@ -602,19 +602,228 @@ def _tiled_latent_view_attention(q_row, rows_of, value_lanes, kv_offset,
     return out.astype(dt).reshape(b, t, h, value_lanes)
 
 
+def _layer_norm(x, scale, bias, eps, dtype):
+    """LayerNorm (mean and variance, a scale and a bias) in float32."""
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    normed = x32 * jax.lax.rsqrt(
+        jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (normed * scale + bias).astype(dtype)
+
+
+def _rope_leading(x, positions, theta, dr):
+    """Rotary pairs on the first ``dr`` values of x [b, s, h, d]."""
+    return jnp.concatenate(
+        [_rope_pairs(x[..., :dr], positions, theta), x[..., dr:]], axis=-1)
+
+
+# Float32 scores an index key tile may hold at once (``_index_scores``):
+# 32 MB over the call's rows and index heads.
+_INDEX_TILE_SCORES = 1 << 23
+
+# Key positions an index key tile at most.
+_INDEX_KEY_TILE = 2048
+
+
+def index_key_tiles(max_blocks: int, block_tokens: int, rows: int):
+    """How ``_index_scores`` visits a slot's index keys for ``rows``
+    (query, index head) pairs a call: ``(pages a tile, tiles)``."""
+    positions = min(_INDEX_KEY_TILE, max(1, _INDEX_TILE_SCORES // rows))
+    pages = max(1, min(positions // block_tokens, max_blocks))
+    return pages, -(-max_blocks // pages)
+
+
+def index_positions_scored(max_blocks: int, block_tokens: int, rows: int,
+                            held: int) -> int:
+    """Index keys ``_index_scores`` scores for a call of ``rows`` (query,
+    index head) pairs whose last query sees ``held`` positions: ``held``
+    rounded up to whole index key tiles."""
+    pages, tiles = index_key_tiles(max_blocks, block_tokens, rows)
+    tile = pages * block_tokens
+    return int(_tiles_visited(held, tile, tiles)) * tile
+
+
+def _index_scores(q_idx, w_idx, keys_of, tile, tiles, visited, q_pos):
+    """The indexer's scores ``I(t, s) = sum_h w_h(t) relu(qI_h(t) .
+    kI(s))`` in float32, [b, t, tiles * tile]: ``keys_of(i)`` gathers key
+    tile i [b, tile, d] of ``tiles``, of which the first ``visited``
+    (traced) are scored; a position past a row's own ``q_pos`` [b, t], or
+    in a tile not visited, reads -inf."""
+    b, t = q_idx.shape[:2]
+
+    def body(i, out):
+        keys = keys_of(i)
+        sc = jnp.einsum("bthd,bkd->bthk", q_idx, keys,
+                        preferred_element_type=jnp.float32)
+        sc = jnp.einsum("bthk,bth->btk", jax.nn.relu(sc), w_idx)
+        k_pos = i * tile + jnp.arange(tile)
+        sc = jnp.where(k_pos <= q_pos[..., None], sc, -jnp.inf)
+        return jax.lax.dynamic_update_slice_in_dim(out, sc, i * tile, 2)
+
+    return jax.lax.fori_loop(
+        0, visited, body, jnp.full((b, t, tiles * tile), -jnp.inf))
+
+
+# Positions a block of ``_choose``'s second level: a row of 128 lanes.
+_CHOICE_BLOCK = 128
+
+
+def _choose(scores, q_pos, topk: int):
+    """The ``topk`` positions of largest score a row (ties to the lower
+    position), in the order of the positions: ``(positions [b, t, k], real
+    [b, t, k])``, ``real`` false past the row's last choice where it sees
+    fewer than k positions (``scores`` reads -inf past ``q_pos``).
+
+    The set is ``jax.lax.top_k``'s without its sort (which the chip runs
+    as a full sort of the row: 4.8 ms for 16 rows of 34,816, PERF.md
+    section 6, PR 44), in three passes of plain vector work:
+    - the k-th largest score, bit by bit: a float's bits, flipped so that
+      they order as the floats do, and 32 counts of the scores at or above
+      a candidate;
+    - the chosen: every score above it, and of those equal to it the
+      first by position that fill the k;
+    - their positions, without a scatter: by blocks of ``_CHOICE_BLOCK``
+      positions, choice j lies in the block whose running count passes j,
+      and is that block's (j - count before it)-th chosen position.
+    """
+    b, t, n = scores.shape
+    k = min(topk, n)
+    blocks = -(-n // _CHOICE_BLOCK)
+    pad = blocks * _CHOICE_BLOCK - n
+    bits = jax.lax.bitcast_convert_type(
+        jnp.pad(scores, ((0, 0), (0, 0), (0, pad)),
+                constant_values=-jnp.inf), jnp.uint32)
+    # Unsigned keys in the floats' order: a negative float's bits all
+    # flip, a positive one's sign bit.
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    want = jnp.minimum(q_pos + 1, k)[..., None]          # [b, t, 1]
+
+    def bit(i, low):
+        cand = low | (jnp.uint32(1) << jnp.asarray(31 - i, jnp.uint32))
+        enough = jnp.sum(keys >= cand, axis=-1, keepdims=True) >= want
+        return jnp.where(enough, cand, low)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros((b, t, 1), jnp.uint32))
+    above = keys > kth
+    level = keys == kth
+    spare = want - jnp.sum(above, axis=-1, keepdims=True)
+    picked = above | (level & (jnp.cumsum(level, axis=-1) <= spare))
+    picked = picked.reshape(b, t, blocks, _CHOICE_BLOCK)
+    ends = jnp.cumsum(jnp.sum(picked, axis=-1), axis=-1)  # [b, t, blocks]
+    slot = jnp.arange(k)
+    block = jnp.sum(ends[:, :, None, :] <= slot[:, None], axis=-1)
+    block = jnp.minimum(block, blocks - 1)                # [b, t, k]
+    before = jnp.take_along_axis(
+        jnp.pad(ends, ((0, 0), (0, 0), (1, 0))), block, axis=-1)
+    lanes = jnp.take_along_axis(picked, block[..., None], axis=2)
+    rank = jnp.cumsum(lanes, axis=-1) - 1                 # [b, t, k, lanes]
+    lane = jnp.argmax(lanes & (rank == (slot - before)[..., None]), axis=-1)
+    return block * _CHOICE_BLOCK + lane, slot < want
+
+
+def _rows_attention(q_row, rows, keep, value_lanes, scale):
+    """Attention of absorbed queries q_row [b, t, h, row] over latent
+    rows of the query's own, rows [b, t, k, row] (``keep`` [b, t, k]), or
+    that the call's queries share, rows [b, k, row] (``keep`` [b, t, k]
+    too) -> [b, t, h, value_lanes] in the latent space.  Softmax in
+    float32 over the kept rows only."""
+    dt = q_row.dtype
+    own = rows.ndim == 4
+    sc = jnp.einsum("bthr,btkr->bthk" if own else "bthr,bkr->bthk", q_row,
+                    rows, preferred_element_type=jnp.float32) * scale
+    w = jax.nn.softmax(jnp.where(keep[:, :, None, :], sc,
+                                 jnp.finfo(jnp.float32).min), axis=-1)
+    return jnp.einsum("bthk,btkc->bthc" if own else "bthk,bkc->bthc",
+                      w.astype(dt), rows[..., :value_lanes],
+                      preferred_element_type=jnp.float32).astype(dt)
+
+
+def _by_query_tiles(attend, q_row, *per_query):
+    """``attend(q_row, *per_query)`` in tiles of ``_VIEW_QUERY_TILE``
+    query columns where the call holds several (axis 1 of every
+    argument)."""
+    t = q_row.shape[1]
+    tiles, rest = divmod(t, _VIEW_QUERY_TILE)
+    if tiles < 2 or rest:
+        return attend(q_row, *per_query)
+
+    def tile(i):
+        return attend(*(jax.lax.dynamic_slice_in_dim(
+            a, i * _VIEW_QUERY_TILE, _VIEW_QUERY_TILE, axis=1)
+            for a in (q_row, *per_query)))
+
+    out = jax.lax.map(tile, jnp.arange(tiles))      # [tiles, b, tile, ...]
+    return jnp.moveaxis(out, 0, 1).reshape(
+        (q_row.shape[0], t) + out.shape[3:])
+
+
+def latent_sides(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The pools of a latent stack, in the order its programs carry
+    them: the full layers' latent rows, their index keys (with an
+    indexer), the sliding layers' rows (with such layers)."""
+    return ("cache_latent",) + (("cache_index",) if cfg.indexed else ()) \
+        + (("cache_window",) if cfg.window_planes else ())
+
+
+def window_pages(window: int, block_tokens: int, t: int) -> int:
+    """Pages that can meet the positions ``t`` neighbouring queries see
+    through a window of ``window``: t + window - 1 positions from
+    anywhere in the first page."""
+    return (t + window - 2 + block_tokens - 1) // block_tokens + 1
+
+
+def _table_entries(tables, first, n: int):
+    """``tables[i, first[i]:first[i] + n]`` a slot, [b, n].
+
+    As one gather of single entries, NOT ``jax.vmap`` of
+    ``dynamic_slice``: where the table and the positions are constants of
+    the program (a closure, not arguments), the chip's compiler folds that
+    form at compile time to the slice's FIRST entry and zeros after it
+    (libtpu 0.0.34; tests/test_tpu_compile.py holds this form to the right
+    pages there), and every page but one then reads page 0.  The engine
+    hands both as arguments, where either form runs right (PERF.md section
+    6, PR 44)."""
+    return jnp.take_along_axis(
+        tables, first[:, None] + jnp.arange(n), axis=1)
+
+
+def _window_view_attention(q_row, pool, plane, tables, q_pos, window,
+                           value_lanes, scale):
+    """Attention of absorbed queries q_row [b, t, h, row] at positions
+    ``q_pos`` [b, t] (neighbours, a row) over the last ``window``
+    positions each sees, read from the pages of ``pool``'s ``plane`` that
+    can meet them (``window_pages``) and no others."""
+    b, t = q_row.shape[:2]
+    bt, width = pool.shape[2:]
+    mb = tables.shape[1]
+    n_pages = min(mb, window_pages(window, bt, t))
+    first = jnp.maximum(q_pos[:, 0] - window + 1, 0) // bt
+    first = jnp.minimum(first, mb - n_pages)
+    view = pool[plane, _table_entries(tables, first, n_pages)].reshape(
+        b, n_pages * bt, width)
+    k_pos = (first * bt)[:, None, None] + jnp.arange(n_pages * bt)
+    return _rows_attention(
+        q_row, view,
+        (k_pos <= q_pos[..., None]) & (k_pos > q_pos[..., None] - window),
+        value_lanes, scale)
+
+
 def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
                             cache_len, positions, write_cols=None,
-                            tables=None, paged_kernel=False, plane=None):
+                            tables=None, paged_kernel=False, plane=None,
+                            kind="full_attention"):
     """Latent attention (MLA, TransformerConfig.attention_kind) in the
     attention block's place: norm, projections, the write of the token's
     latent row, attention, output projection, residual.
 
-    ``cache`` is ``(pool,)``, the ONE stacked latent pool
-    [kv_planes, num_blocks, block_tokens, cfg.latent_row] (a row: the
+    ``cache`` holds the stack's pools in ``latent_sides`` order, each
+    [its planes, num_blocks, block_tokens, row] (a latent row: the
     normed, scaled latent, then the shared rotary key, then zeros up to
     whole 128-lane rows), with ``tables`` / ``cache_len`` / ``write_cols``
     / ``plane`` / ``paged_kernel`` as in ``_attention_block``; or None (a
-    forward without a cache).  Two forms of one arithmetic:
+    forward without a cache).  ``kind`` names the layer's sizes and its
+    pool (``TransformerConfig.latent_sizes``); ``plane`` counts within
+    that pool.  Two forms of one arithmetic:
 
     - against the pool the key expansion is ABSORBED into the query
       (``qt_j = q_nope_j W_uk_j^T``): all heads score the latent rows as
@@ -632,15 +841,32 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
     - the forward without a cache EXPANDS keys and values from the latent
       (``c W_uk``, ``c W_uv``) and attends as any other model does, as
       the plain reference does everywhere.
+
+    With an indexer (``cfg.indexed``, full_attention layers) a token
+    also writes its index key to the index pool's plane, the call scores
+    the index keys its slots hold by key tiles (``_index_scores``),
+    chooses ``index_topk`` positions a query (``_choose``) and attends
+    the chosen rows and no others, gathered a query by (page, offset).
+    A sliding_attention layer gathers (the kernel:
+    copies) only the pages that meet its queries' windows
+    (``window_pages``), whatever the slot holds below them.  Both read
+    what the whole-view forms would read, masked: the cost is what
+    differs.  ``cfg.attn_gate`` scales each head's output by the sigmoid
+    of a projection of the layer's normed input.
     """
     attn = layer_params["attn"]
     dt = cfg.dtype
-    e, rkv = cfg.d_model, cfg.mla_kv_rank
-    dn, dr = cfg.mla_nope_dim, cfg.mla_rope_dim
-    up_q, up_kv = (e / cfg.mla_q_rank) ** 0.5, (e / rkv) ** 0.5
+    z = cfg.latent_sizes(kind)
+    e, rkv, dn, dr, heads = (cfg.d_model, z.kv_rank, z.nope_dim,
+                             z.rope_dim, z.heads)
+    up_q, up_kv = (e / z.q_rank) ** 0.5, (e / rkv) ** 0.5
     b, t = x.shape[:2]
     scale = (dn + dr) ** -0.5
     wk_b, wv_b = attn["wk_b"], attn["wv_b"]
+    indexed = cfg.indexed and kind == "full_attention"
+    if cache is not None:
+        cache, sides = list(cache), latent_sides(cfg)
+        side = sides.index("cache_window" if z.window else "cache_latent")
 
     with jax.named_scope("kft.mla_q"):
         y = _rms_norm(x, layer_params["attn_norm"]["scale"], cfg.norm_eps,
@@ -649,68 +875,173 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
         qa = _rms_norm(qa, attn["q_norm"]["scale"] * up_q, cfg.norm_eps, dt)
         q = qeinsum("bsr,rhd->bshd", qa, attn["wq_b"], dt)
         q_nope = q[..., :dn]
-        q_rope = _rope_pairs(q[..., dn:], positions, cfg.rope_theta)
+        q_rope = _rope_pairs(q[..., dn:], positions, z.rope_theta)
     with jax.named_scope("kft.mla_latent_write"):
         kva = qeinsum("bse,ec->bsc", y, attn["wkv_a"], dt)
         c = _rms_norm(kva[..., :rkv], attn["kv_norm"]["scale"] * up_kv,
                       cfg.norm_eps, dt)
         k_r = _rope_pairs(kva[:, :, None, rkv:], positions,
-                          cfg.rope_theta)[:, :, 0]
+                          z.rope_theta)[:, :, 0]
         if cache is not None:
-            pool, = cache
+            pool = cache[side]
             nb, bt, width = pool.shape[1:]
             mb, pad = tables.shape[1], width - rkv - dr
             row = jnp.concatenate(
                 [c, k_r, jnp.zeros((b, t, pad), dt)], axis=-1)
             blk, off, base = _page_coordinates(
                 tables, cache_len, write_cols, b, t, nb, bt)
-            pool = pool.at[plane, blk, off].set(
+            pool = cache[side] = pool.at[plane, blk, off].set(
                 row.astype(pool.dtype), mode="drop")
-            cache = (pool,)
+    if indexed:
+        with jax.named_scope("kft.dsa_index"):
+            q_idx = _rope_leading(
+                qeinsum("bsr,rhd->bshd", qa, attn["wq_idx"], dt),
+                positions, z.rope_theta, dr)
+            k_idx = _layer_norm(
+                qeinsum("bse,ed->bsd", y, attn["wk_idx"], dt),
+                attn["k_idx_norm"]["scale"], attn["k_idx_norm"]["bias"],
+                cfg.norm_eps, dt)
+            k_idx = _rope_leading(k_idx[:, :, None], positions,
+                                  z.rope_theta, dr)[:, :, 0]
+            # In float32, as the router's scores: a weight that rounding
+            # moves changes which positions a query attends.
+            w_idx = jnp.einsum(
+                "bse,eh->bsh", y.astype(jnp.float32),
+                attn["w_idx"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST) \
+                * (cfg.index_heads * cfg.index_dim) ** -0.5
+            if cache is not None:
+                at = sides.index("cache_index")
+                keys = cache[at] = cache[at].at[plane, blk, off].set(
+                    k_idx.astype(cache[at].dtype), mode="drop")
+
+    gate = None
+    if cfg.attn_gate:
+        with jax.named_scope("kft.attn_gate"):
+            gate = jax.nn.sigmoid(qeinsum(
+                "bse,eh->bsh", y, attn["wg"], dt).astype(jnp.float32))
 
     if cache is None:
+        rows_q = jnp.arange(t)
+        keep = jnp.broadcast_to(rows_q[None, :] <= rows_q[:, None],
+                                (b, t, t))
+        if z.window:
+            keep = keep & (rows_q[None, :] > rows_q[:, None] - z.window)
+        if indexed:
+            with jax.named_scope("kft.dsa_index"):
+                scores = _index_scores(
+                    q_idx, w_idx, lambda i: k_idx, t, 1, 1, positions)
+            with jax.named_scope("kft.dsa_select"):
+                chosen, real = _choose(scores, positions, cfg.index_topk)
+                picked = jnp.zeros((b, t, t), jnp.int32).at[
+                    jnp.arange(b)[:, None, None],
+                    jnp.arange(t)[None, :, None], chosen].add(real)
+                keep = keep & (picked > 0)
         with jax.named_scope("kft.mla_prefill"):
             k = jnp.concatenate(
                 [qeinsum("bkc,hdc->bkhd", c, wk_b, dt), jnp.broadcast_to(
-                    k_r[:, :, None], (b, t, cfg.n_heads, dr))], axis=-1)
-            out = dot_product_attention(
-                jnp.concatenate([q_nope, q_rope], axis=-1), k,
-                qeinsum("bkc,chd->bkhd", c, wv_b, dt), causal=True)
+                    k_r[:, :, None], (b, t, heads, dr))], axis=-1)
+            full = jnp.concatenate([q_nope, q_rope], axis=-1)
+            if not (z.window or indexed):
+                out = dot_product_attention(
+                    full, k, qeinsum("bkc,chd->bkhd", c, wv_b, dt),
+                    causal=True)
+            else:
+                sc = jnp.einsum("bqhd,bkhd->bhqk", full, k,
+                                preferred_element_type=jnp.float32) * scale
+                w = jax.nn.softmax(jnp.where(
+                    keep[:, None], sc, jnp.finfo(jnp.float32).min), axis=-1)
+                out = jnp.einsum(
+                    "bhqk,bkhd->bqhd", w.astype(dt),
+                    qeinsum("bkc,chd->bkhd", c, wv_b, dt),
+                    preferred_element_type=jnp.float32).astype(dt)
     else:
         decode = t == 1 and base is not None
+        # Position of the call's query columns among its slots' own.
+        q_pos = jnp.reshape(cache_len, (-1, 1)) + jnp.arange(t)[None, :]
+        q_pos = jnp.broadcast_to(q_pos, (b, t))
         with jax.named_scope(
                 "kft.mla_decode" if decode else "kft.mla_prefill"):
             qt = qeinsum("bshd,hdc->bshc", q_nope, wk_b, dt)
             q_row = jnp.concatenate(
-                [qt, q_rope, jnp.zeros((b, t, cfg.n_heads, pad), dt)],
+                [qt, q_rope, jnp.zeros((b, t, heads, pad), dt)],
                 axis=-1)                                # [b, t, h, width]
-            if decode and paged_kernel:
-                from kubeflow_tpu.ops import paged_attention
+        if decode and paged_kernel and not indexed:
+            # The step's own row is in the pool already; a parked write
+            # marks a retired row, which reads nothing.
+            attend = jnp.where(base < mb * bt, cache_len + 1, 0)
+        if indexed:
+            with jax.named_scope("kft.dsa_index"):
+                pages, tiles = index_key_tiles(
+                    mb, bt, b * t * cfg.index_heads)
+                padded = jnp.pad(tables,
+                                 ((0, 0), (0, tiles * pages - mb)))
+                scores = _index_scores(
+                    q_idx, w_idx,
+                    lambda i: keys[plane, jax.lax.dynamic_slice_in_dim(
+                        padded, i * pages, pages, axis=1)].reshape(
+                            b, pages * bt, -1),
+                    pages * bt, tiles,
+                    _tiles_visited(jnp.max(cache_len) + t, pages * bt,
+                                   tiles), q_pos)
+            with jax.named_scope("kft.dsa_select"):
+                chosen, real = _choose(scores, q_pos, cfg.index_topk)
+            with jax.named_scope("kft.mla_sparse"):
+                # Row by row through the compiler's gather, on every
+                # backend: the chip's compiler refuses a kernel's copy
+                # of ONE row out of a tiled page
+                # (ops/paged_attention.py).  A parked row's choices lie
+                # past its own position: it attends nothing it keeps.
+                at_page = jnp.take_along_axis(
+                    padded[:, None, :], chosen // bt, axis=2)
+                ot = _by_query_tiles(
+                    lambda q, pg, ch, ok: _rows_attention(
+                        q, pool[plane, pg, ch % bt], ok, rkv, scale),
+                    q_row, at_page, chosen, real)
+        elif z.window:
+            with jax.named_scope("kft.mla_window"):
+                if decode and paged_kernel:
+                    from kubeflow_tpu.ops import paged_attention
 
-                # The step's own row is in the pool already; a parked
-                # write marks a retired row, which reads nothing.
-                attend = jnp.where(base < mb * bt, cache_len + 1, 0)
-                ot = paged_attention.paged_latent_decode_attention(
-                    q_row[:, 0], pool, plane, tables, attend, rkv,
-                    scale)[:, None]
-            else:
-                held = _held_key_tiles(tables, bt, t, cache_len)
-                if held is None:
-                    ot = _latent_view_attention(
-                        q_row,
-                        pool[plane, tables].reshape(b, mb * bt, width),
-                        rkv, cache_len, scale)
+                    ot = paged_attention.paged_latent_decode_attention(
+                        q_row[:, 0], pool, plane, tables, attend, rkv,
+                        scale, window=z.window)[:, None]
                 else:
-                    tile, visited, pages_of = held
-                    ot = _tiled_latent_view_attention(
-                        q_row,
-                        lambda i: pool[plane, pages_of(i)].reshape(
-                            b, tile, width),
-                        rkv, cache_len, scale, tile, visited, mb * bt)
+                    ot = _window_view_attention(
+                        q_row, pool, plane, tables, q_pos, z.window, rkv,
+                        scale)
+        else:
+            with jax.named_scope(
+                    "kft.mla_decode" if decode else "kft.mla_prefill"):
+                if decode and paged_kernel:
+                    from kubeflow_tpu.ops import paged_attention
+
+                    ot = paged_attention.paged_latent_decode_attention(
+                        q_row[:, 0], pool, plane, tables, attend, rkv,
+                        scale)[:, None]
+                else:
+                    held = _held_key_tiles(tables, bt, t, cache_len)
+                    if held is None:
+                        ot = _latent_view_attention(
+                            q_row,
+                            pool[plane, tables].reshape(b, mb * bt, width),
+                            rkv, cache_len, scale)
+                    else:
+                        tile, visited, pages_of = held
+                        ot = _tiled_latent_view_attention(
+                            q_row,
+                            lambda i: pool[plane, pages_of(i)].reshape(
+                                b, tile, width),
+                            rkv, cache_len, scale, tile, visited, mb * bt)
+        with jax.named_scope(
+                "kft.mla_decode" if decode else "kft.mla_prefill"):
             out = qeinsum("bshc,chd->bshd", ot, wv_b, dt)
+    if gate is not None:
+        with jax.named_scope("kft.attn_gate"):
+            out = (out * gate[..., None]).astype(dt)
     with jax.named_scope("kft.attn_out"):
         x = x + qeinsum("bshd,hde->bse", out, attn["wo"], dt)
-    return x, cache
+    return x, (None if cache is None else tuple(cache))
 
 
 def _dense_mlp(cfg: TransformerConfig, mlp, y, adapters=None):
@@ -898,12 +1229,20 @@ def _sparse_ff(cfg: TransformerConfig, layer_params, x, live=None):
     stream.  live [b, t] bool (None: all): rows that are no token come
     back unchanged.  Returns (x, ``_experts``'s counts)."""
     b, t, e = x.shape
+    moe = layer_params["moe"]
     with jax.named_scope("kft.mlp"):
-        y = _rms_norm(x, layer_params["mlp_norm"]["scale"], cfg.norm_eps,
-                      cfg.dtype).reshape(b * t, e)
-        y, counts = _experts(cfg, layer_params["moe"], y,
+        normed = _rms_norm(x, layer_params["mlp_norm"]["scale"],
+                           cfg.norm_eps, cfg.dtype)
+        y, counts = _experts(cfg, moe, normed.reshape(b * t, e),
                              None if live is None else live.reshape(-1))
-        x = x + y.astype(cfg.dtype).reshape(b, t, e)
+        y = y.astype(cfg.dtype).reshape(b, t, e)
+        if cfg.moe_shared_d_ff:
+            # The shared expert: every token, unweighted, once.
+            with jax.named_scope("kft.moe_shared"):
+                shared = _dense_mlp(cfg, moe["shared"], normed)
+                y = y + (shared if live is None
+                         else jnp.where(live[..., None], shared, 0))
+        x = x + y
     return x, counts
 
 
@@ -984,8 +1323,9 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
     The layers are walked one by one over ``params["layers"][str(i)]``:
     no two need have the same leaves, every matrix is an array of its
     own that its product reads where it lies, and the attention layers
-    alone own planes of the paged pool (plane j is the j-th of them).
-    ``cache`` is the stacked pool, (k, v) or (latent,), that the serving
+    alone own planes of the paged pool (plane j is the j-th of them; the
+    sliding_attention layers count their own, in a pool of their own).
+    ``cache`` is the stacked pool, (k, v) or ``latent_sides``', that the serving
     programs carry and donate, ``tables`` their block tables, ``cache_len`` /
     ``write_cols`` / ``paged_kernel`` as in ``_forward_with_cache``;
     ``conv`` is the [conv layers, slots, K - 1, e] state of the
@@ -1002,7 +1342,7 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
     x, positions = _embed_tokens(cfg, params, tokens, cache_len)
     live = None if n_new is None else (
         jnp.arange(tokens.shape[1])[None, :] < n_new[:, None])
-    plane = plane_c = 0
+    plane = plane_c = plane_w = 0
     counts = None
 
     def count(n):
@@ -1027,6 +1367,12 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
                 with jax.named_scope("kft.conv_state"):
                     conv = conv.at[plane_c].set(state)
             plane_c += 1
+        elif kind == "sliding_attention":
+            x, cache = _latent_attention_block(
+                cfg, layer_params, x, cache, cache_len, positions,
+                write_cols=write_cols, tables=tables,
+                paged_kernel=paged_kernel, plane=plane_w, kind=kind)
+            plane_w += 1
         else:
             x, cache = _attention_block(
                 cfg, layer_params, x, cache, cache_len, positions,
@@ -1405,14 +1751,24 @@ def init_paged_state(cfg: TransformerConfig, slots: int,
     With latent attention (``cfg.latent``) the pool is ONE array,
     ``cache_latent`` [cfg.kv_planes, num_blocks, block_tokens,
     cfg.latent_row], key and value at once, in place of ``cache_k`` and
-    ``cache_v`` (``pool_sides`` names what a state holds).
+    ``cache_v`` (``pool_sides`` names what a state holds).  An indexer
+    adds ``cache_index`` (an index key a token and full plane) and
+    sliding_attention layers ``cache_window`` (their planes, of their own
+    row width); ``cache_latent`` then holds the full layers' planes
+    only.  Every pool has the same blocks of the same positions: ONE
+    block table a slot serves them all, so a page is allocated, aliased
+    and freed in all of them at once.
     """
     if cfg.latent:
         if kv_cache_dtype != "model":
             raise ValueError("an int8 latent pool: not built")
-        pool = {"cache_latent": jnp.zeros(
-            (cfg.kv_planes, num_blocks, block_tokens, cfg.latent_row),
-            cfg.dtype)}
+        full = cfg.kv_planes - cfg.window_planes
+        widths = {"cache_latent": (full, cfg.latent_row),
+                  "cache_index": (full, cfg.index_dim),
+                  "cache_window": (cfg.window_planes, cfg.window_row)}
+        pool = {side: jnp.zeros(
+            (widths[side][0], num_blocks, block_tokens, widths[side][1]),
+            cfg.dtype) for side in latent_sides(cfg)}
     else:
         pool = dict(zip(("cache_k", "cache_v"), init_cache(
             cfg, num_blocks, block_tokens, kv_cache_dtype)))
@@ -1439,9 +1795,10 @@ def init_paged_state(cfg: TransformerConfig, slots: int,
 
 def pool_sides(state) -> Tuple[str, ...]:
     """The keys under which a paged state holds its pool: keys and values,
-    or the one latent pool."""
-    return ("cache_latent",) if "cache_latent" in state \
-        else ("cache_k", "cache_v")
+    or a latent stack's pools (``latent_sides``)."""
+    latent = tuple(side for side in (
+        "cache_latent", "cache_index", "cache_window") if side in state)
+    return latent or ("cache_k", "cache_v")
 
 
 def _pool_block_tokens(state) -> int:

@@ -176,6 +176,7 @@ class TransformerConfig:
     #       y = c + Dense_1(N'_1(c)) + s
     #     It owns two planes of the pool.  Every such layer holds
     #     experts (``moe_dense_layers`` must be 0).
+    #   "sliding_attention": see ``window`` below.
     layer_types: Tuple[str, ...] = ()
     conv_kernel: int = 3
     qk_norm: bool = False
@@ -221,6 +222,42 @@ class TransformerConfig:
     mla_nope_dim: int = 0
     mla_rope_dim: int = 0
     mla_v_dim: int = 0
+    # A learned choice of the positions a "full_attention" layer of a
+    # latent stack attends (DeepSeek-V3.2's indexer): ``index_topk`` > 0
+    # gives every such layer ``index_heads`` index queries of
+    # ``index_dim`` from the query's low-rank activation, ONE index key
+    # of ``index_dim`` a token (a LayerNorm with scale and bias, cached
+    # per token in a pool of its own, ``cache_index``), rotary pairs on
+    # the first ``mla_rope_dim`` values of both, and a weight a head
+    # ``w = u W_w * index_heads^-0.5 * index_dim^-0.5``:
+    #   I(t, s) = sum_h w_h(t) relu(qI_h(t) . kI(s))  in float32, s <= t
+    # The softmax of the layer's attention runs over the ``index_topk``
+    # positions of largest I(t, .) (all of them while t < index_topk; ties
+    # to the lower position) and over no other.
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    # "sliding_attention": a latent layer with head count, ranks and head
+    # widths of its own (``window_*``; the rows of its planes lie in a
+    # third pool, ``cache_window``, ``window_row`` wide) whose query at t
+    # sees the positions (t - ``window``, t], its own among them, and
+    # carries no indexer.
+    window: int = 0
+    window_heads: int = 0
+    window_q_rank: int = 0
+    window_kv_rank: int = 0
+    window_nope_dim: int = 0
+    window_rope_dim: int = 0
+    window_v_dim: int = 0
+    window_rope_theta: float = 10_000.0
+    # A headwise gate on every latent layer's attention, before the
+    # output projection: out_j = sigmoid(u W_g)_j * o_j, u the layer's
+    # normed input, W_g [d_model, heads] (``attn/wg``), no bias.
+    attn_gate: bool = False
+    # ONE shared SwiGLU of this width beside the routed experts of every
+    # sparse layer (``moe/shared``), added unweighted; it belongs to the
+    # chip that owns the token (in a sum over shares it counts once).
+    moe_shared_d_ff: int = 0
 
     def __post_init__(self):
         # Latent attention reads neither n_kv_heads nor head_dim.
@@ -248,6 +285,35 @@ class TransformerConfig:
                     "attention_kind='latent' runs in a stack that states "
                     "its layer_types, without qk_norm (the rest: not "
                     "built)")
+        indexer = (self.index_heads, self.index_dim, self.index_topk)
+        if any(indexer) and (
+                not self.latent or min(indexer) < 1
+                or self.index_dim < self.mla_rope_dim):
+            raise ValueError(
+                "an indexer needs attention_kind='latent' and index_heads, "
+                f"index_dim (>= mla_rope_dim) and index_topk, got {indexer}")
+        if "sliding_attention" in self.layer_types:
+            sizes = (self.window, self.window_heads, self.window_q_rank,
+                     self.window_kv_rank, self.window_nope_dim,
+                     self.window_rope_dim, self.window_v_dim)
+            if not self.latent or min(sizes) < 1 \
+                    or self.window_rope_dim % 2:
+                raise ValueError(
+                    "a sliding_attention layer is a latent layer with "
+                    "window, window_heads, window_q_rank, window_kv_rank, "
+                    "window_nope_dim, window_rope_dim (even) and "
+                    f"window_v_dim, got {sizes}")
+        if self.attn_gate and not self.latent:
+            raise ValueError("attn_gate gates latent layers: "
+                             "attention_kind='latent'")
+        if self.moe_shared_d_ff and not self.layer_types:
+            raise ValueError("moe_shared_d_ff belongs to the sparse layers "
+                             "of a stack that states its layer_types")
+        if "shortcut_double" in self.layer_types and (
+                self.indexed or self.attn_gate or self.moe_shared_d_ff):
+            raise ValueError(
+                "a shortcut_double layer with an indexer, a gate or a "
+                "shared expert: not built")
         if self.moe_zero_experts or self.moe_experts_held \
                 or self.moe_experts_offset:
             held, first = self.moe_experts_held, self.moe_experts_offset
@@ -261,7 +327,8 @@ class TransformerConfig:
                     "zero-compute experts: not a share of a stack with "
                     "layer_types")
         if self.layer_types:
-            kinds = {"full_attention", "conv", "shortcut_double"}
+            kinds = {"full_attention", "conv", "shortcut_double",
+                     "sliding_attention"}
             unknown = set(self.layer_types) - kinds
             if unknown or len(self.layer_types) != self.n_layers:
                 raise ValueError(
@@ -322,22 +389,49 @@ class TransformerConfig:
         of the layers that attend."""
         if self.layer_types:
             return self.layer_types.count("full_attention") \
-                + 2 * self.layer_types.count("shortcut_double")
+                + 2 * self.layer_types.count("shortcut_double") \
+                + self.window_planes
         return self.loop_steps * self.n_layers
+
+    @property
+    def window_planes(self) -> int:
+        """The planes of ``kv_planes`` that sliding_attention layers own,
+        in the pool of their own row width."""
+        return self.layer_types.count("sliding_attention")
 
     @property
     def latent(self) -> bool:
         return self.attention_kind == "latent"
+
+    def latent_sizes(self, kind: str = "full_attention") -> "LatentSizes":
+        """The sizes of a latent layer of ``kind``: the model's own, or a
+        sliding_attention layer's (``window_*``)."""
+        if kind == "sliding_attention":
+            return LatentSizes(
+                self.window_heads, self.window_q_rank, self.window_kv_rank,
+                self.window_nope_dim, self.window_rope_dim,
+                self.window_v_dim, self.window_rope_theta, self.window)
+        return LatentSizes(
+            self.n_heads, self.mla_q_rank, self.mla_kv_rank,
+            self.mla_nope_dim, self.mla_rope_dim, self.mla_v_dim,
+            self.rope_theta, 0)
 
     @property
     def latent_row(self) -> int:
         """Values a token and plane of the latent pool holds: the normed
         latent, then the rotary key, each padded to whole 128-lane rows
         (the chip copies whole rows: 512 + 64 -> 512 + 128)."""
-        def rows(n):
-            return -(-n // 128) * 128
+        return self.latent_sizes().row
 
-        return rows(self.mla_kv_rank) + rows(self.mla_rope_dim)
+    @property
+    def window_row(self) -> int:
+        """``latent_row`` of a sliding_attention layer's planes."""
+        return self.latent_sizes("sliding_attention").row
+
+    @property
+    def indexed(self) -> bool:
+        """Whether the full_attention layers choose their positions."""
+        return self.index_topk > 0
 
     @property
     def moe_partial(self) -> bool:
@@ -381,6 +475,48 @@ class TransformerConfig:
         return float(matmul + attn)
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentSizes:
+    """What ``_latent_attention_block`` reads of a latent layer."""
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float
+    window: int        # 0: every position up to the query's
+
+    @property
+    def row(self) -> int:
+        def rows(n):
+            return -(-n // 128) * 128
+
+        return rows(self.kv_rank) + rows(self.rope_dim)
+
+
+def _latent_tree(cfg: TransformerConfig, kind: str):
+    """The ``attn`` leaves of a latent layer of ``kind``."""
+    e, z = cfg.d_model, cfg.latent_sizes(kind)
+    h, rq, rkv = z.heads, z.q_rank, z.kv_rank
+    attn = {"wq_a": (e, rq), "q_norm": {"scale": (rq,)},
+            "wq_b": (rq, h, z.nope_dim + z.rope_dim),
+            "wkv_a": (e, rkv + z.rope_dim),
+            "kv_norm": {"scale": (rkv,)},
+            "wk_b": (h, z.nope_dim, rkv),
+            "wv_b": (rkv, h, z.v_dim),
+            "wo": (h, z.v_dim, e)}
+    if cfg.attn_gate:
+        attn["wg"] = (e, h)
+    if cfg.indexed and kind == "full_attention":
+        attn.update(wq_idx=(rq, cfg.index_heads, cfg.index_dim),
+                    wk_idx=(e, cfg.index_dim),
+                    k_idx_norm={"scale": (cfg.index_dim,),
+                                "bias": (cfg.index_dim,)},
+                    w_idx=(e, cfg.index_heads))
+    return attn
+
+
 def layer_tree_shapes(cfg: TransformerConfig):
     """The parameter tree of a stack with ``layer_types``, as nested
     {name: shape}: one entry a layer under ``layers`` (no two need agree,
@@ -395,6 +531,9 @@ def layer_tree_shapes(cfg: TransformerConfig):
     outputs, held = n + cfg.moe_zero_experts, cfg.moe_held
     experts = {"router": (e, outputs), "bias": (outputs,),
                "wi": (held, e, 2 * f), "wo": (held, f, e)}
+    if cfg.moe_shared_d_ff:
+        experts["shared"] = {"wi": (2, e, cfg.moe_shared_d_ff),
+                             "wo": (cfg.moe_shared_d_ff, e)}
     if cfg.latent:
         # An expanded key and value head lie in leaves of their own
         # (``wk_b``, ``wv_b``): a decode step absorbs the first into the
@@ -404,14 +543,7 @@ def layer_tree_shapes(cfg: TransformerConfig):
         # batch over heads that contracts d_nope, and from [r_kv, h,
         # d_nope] the chip's compiler relaid the matrix once a step and
         # sublayer.
-        h, rq, rkv = cfg.n_heads, cfg.mla_q_rank, cfg.mla_kv_rank
-        attn = {"wq_a": (e, rq), "q_norm": {"scale": (rq,)},
-                "wq_b": (rq, h, cfg.mla_nope_dim + cfg.mla_rope_dim),
-                "wkv_a": (e, rkv + cfg.mla_rope_dim),
-                "kv_norm": {"scale": (rkv,)},
-                "wk_b": (h, cfg.mla_nope_dim, rkv),
-                "wv_b": (rkv, h, cfg.mla_v_dim),
-                "wo": (h, cfg.mla_v_dim, e)}
+        attn = _latent_tree(cfg, "full_attention")
     else:
         attn = {"wq": (e, cfg.n_heads, d),
                 "wkv": (2, e, cfg.n_kv_heads, d),
@@ -433,7 +565,8 @@ def layer_tree_shapes(cfg: TransformerConfig):
                              "w_out": (e, e)}
         else:
             layer["attn_norm"] = norm
-            layer["attn"] = attn
+            layer["attn"] = _latent_tree(cfg, kind) \
+                if kind == "sliding_attention" else attn
         if cfg.layer_is_sparse(i):
             layer["moe"] = experts
         else:

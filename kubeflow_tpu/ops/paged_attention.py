@@ -67,7 +67,19 @@ kernel walks it with one side in place of two: ``hkv`` is 1, so every
 query head (its nope part absorbed into the latent space, then its rotary
 part) scores every row, and the value product takes the rows' first
 ``value_lanes`` lanes (the latent) of the SAME buffer; the output stays in
-the latent space for the caller to expand.
+the latent space for the caller to expand.  With ``window`` a slot's
+walk starts at the page that holds the first position of its window and
+the mask keeps ``[n_tokens - window, n_tokens)``: a sliding layer copies
+the pages that meet its window, whatever the slot holds below them.
+
+What is NOT here: a form that copies CHOSEN rows (an indexer's
+``index_topk`` positions).  A latent row lies in HBM inside a tile of
+(8, 128)(2, 1): the chip's compiler refuses a copy of one row of it
+("slice shape along dimension 0 must be aligned to tiling (8)"; compiled
+for a described v5e, PR 44), and no bitcast of the pool gives a row a
+tile of its own.  ``models/generate.py`` gathers the chosen rows with the
+compiler's own gather (``pool[plane, page, offset]``) and attends them
+with plain products.
 
 Arithmetic: operands in the pool's dtype (bf16 on the chip) with
 float32 accumulation, float32 running max / sum / output (online
@@ -91,7 +103,7 @@ _MASKED = -1e30
 
 
 def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
-            pages, nb, planes, scale, value_lanes=None):
+            pages, nb, planes, scale, value_lanes=None, window=None):
     """One slot: walk its resident pages ``pages`` at a time and, under
     its last block, start the first block of the next slot that attends
     anything.
@@ -101,7 +113,9 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
     values in their first ``value_lanes`` lanes), the output, a buffer a
     side, the semaphores, and ``ride``, the two words that pass from one
     grid step to the next: the buffer the next first block goes to, and
-    the slot whose first block is in flight (module docstring)."""
+    the slot whose first block is in flight (module docstring).
+    ``window``: a slot attends its last ``window`` positions only, and
+    its walk starts at the page that holds the first of them."""
     sides = (len(refs) - 3) // 2
     hbm, o_ref = refs[:sides], refs[sides]
     bufs, sems, ride = refs[sides + 1:-2], refs[-2], refs[-1]
@@ -112,12 +126,21 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
     first = jnp.clip(plane_ref[0], 0, planes - 1) * nb
     rows = bt * hkv                       # (position, kv head) rows a page
 
+    def first_page(slot):
+        """The first page of ``slot``'s walk: 0, or the one that holds
+        the first position of its window."""
+        if window is None:
+            return 0
+        return jnp.maximum(ntok_ref[slot] - window, 0) // bt
+
     def pages_of(slot):
-        return (ntok_ref[slot] + bt - 1) // bt
+        """Pages ``slot``'s walk holds, from its first page on."""
+        return (ntok_ref[slot] + bt - 1) // bt - first_page(slot)
 
     n = ntok_ref[s]                       # positions to attend (0: none)
     n_pages = pages_of(s)
     n_blocks = (n_pages + pages - 1) // pages
+    page0 = first_page(s)
 
     @pl.when(s == 0)
     def _():
@@ -139,7 +162,8 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
             # keeps a sentinel (== nb) that a wrong table would hold
             # inside the plane.
             at = first + jnp.clip(
-                tables_ref[slot * mb + blk * pages + p], 0, nb - 1)
+                tables_ref[slot * mb + first_page(slot) + blk * pages + p],
+                0, nb - 1)
             dst = pl.ds(pl.multiple_of(p * rows, rows), rows)
             for side in range(sides):
                 act(pltpu.make_async_copy(
@@ -185,7 +209,11 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # [h, rows*]
-        keep = own & (pos + i * (pages * bt) < n)
+        if window is None:
+            keep = own & (pos + i * (pages * bt) < n)
+        else:
+            at = pos + (page0 + i * pages) * bt
+            keep = own & (at < n) & (at >= n - window)
         sc = jnp.where(keep, sc, _MASKED)
         m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
         p = jnp.exp(sc - m_new)
@@ -365,9 +393,10 @@ def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "value_lanes", "scale", "pages_per_block", "interpret"))
+    "value_lanes", "scale", "window", "pages_per_block", "interpret"))
 def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
                                   value_lanes: int, scale: float, *,
+                                  window: int | None = None,
                                   pages_per_block: int | None = None,
                                   interpret: bool = False):
     """The latent (MLA) form: ``q [S, h, row]`` against each slot's
@@ -383,6 +412,9 @@ def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
     chip copies whole lane rows: ``TransformerConfig.latent_row``).
     plane / tables / n_tokens: as ``paged_decode_attention``; ``scale``
     multiplies the scores (the expanded head's, not the row's).
+    window: a slot attends its last ``window`` positions
+    ``[n_tokens - window, n_tokens)`` and the walk copies only the pages
+    that hold them, whatever lies below: None attends all.
     """
     S, h, row = q.shape
     planes, nb, bt, _ = pool.shape
@@ -391,7 +423,7 @@ def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
         bt * row * pool.dtype.itemsize, mb)
     kernel = functools.partial(
         _kernel, mb=mb, bt=bt, hkv=1, g=h, pages=pages, nb=nb,
-        planes=planes, scale=scale, value_lanes=value_lanes)
+        planes=planes, scale=scale, value_lanes=value_lanes, window=window)
     return pl.pallas_call(
         kernel,
         name="paged_latent_decode_attention",
@@ -412,3 +444,4 @@ def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
         interpret=interpret,
     )(*_walk_arguments(tables, n_tokens, plane), q,
       pool.reshape(planes * nb, bt, row))
+

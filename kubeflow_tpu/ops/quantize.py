@@ -99,6 +99,13 @@ CONTRACTIONS: Dict[Tuple[str, ...], Tuple[int, ...]] = {
     ("attn", "wkv_a"): (-2,),      # [e, r_kv + d_rope] contract e
     ("attn", "wk_b"): (-1,),       # [h, d, r_kv] contract r_kv (a chunk)
     ("attn", "wv_b"): (-3,),       # [r_kv, h, d] contract r_kv
+    # The indexer (its per-head weights ``w_idx`` stay float32, as the
+    # router), the headwise gate, the shared expert.
+    ("attn", "wq_idx"): (-3,),     # [r_q, h_i, d_i] contract r_q
+    ("attn", "wk_idx"): (-2,),     # [e, d_i] contract e
+    ("attn", "wg"): (-2,),         # [e, h] contract e
+    ("shared", "wi"): (-2,),       # [2, e, f] contract e
+    ("shared", "wo"): (-2,),       # [f, e] contract f
 }
 
 

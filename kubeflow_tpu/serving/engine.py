@@ -195,6 +195,11 @@ FUSED_WASTED_TOTAL = "kft_engine_fused_steps_wasted_total"
 FUSED_WASTED_HELP = \
     "fused-round slot-steps dispatched but not delivered (early-exit " \
     "waste past a slot's EOS/budget/deadline), by engine"
+SPARSE_READS_TOTAL = "kft_engine_sparse_positions_total"
+SPARSE_READS_HELP = \
+    "positions the decode steps of a stack with an indexer or sliding " \
+    "layers read, by engine and kind (index_scored, index_chosen, " \
+    "window_read), summed over slots, steps and planes"
 DECODE_KERNEL_STEPS_TOTAL = "kft_engine_decode_kernel_steps_total"
 DECODE_KERNEL_STEPS_HELP = \
     "decode steps run by a step program that holds the paged " \
@@ -312,6 +317,10 @@ _SUM_KEYS = ("loop_rounds", *_PHASE_KEY.values(),
 # The decode steps' (row, choice) pairs by where they fell, in the order
 # of ``state["moe_pairs"]`` (models/generate.py init_paged_state).
 _PAIR_KEYS = ("pairs_held", "pairs_zero", "pairs_absent")
+# What the decode steps of a stack with an indexer or sliding layers read,
+# by (slot, step, plane): index keys scored, positions chosen of them,
+# window positions read (``DecodeEngine._sparse_reads``).
+_SPARSE_KEYS = ("index_scored", "index_chosen", "window_read")
 # An iteration that takes this many times the running mean of the
 # wall time (wait_work left out) of the iterations that waited for the
 # device is counted and logged with its own phase times; the mean
@@ -681,6 +690,8 @@ class DecodeEngine:
         self._moe_layers = sum(
             map(cfg.layer_is_sparse, range(cfg.n_layers))) \
             if cfg.layer_types else 0
+        self._index_planes = cfg.kv_planes - cfg.window_planes \
+            if cfg.indexed else 0
         # Host-RAM spill tier capacity in pages (§5.10): 0 disables.
         # The tier rides the prefix index (spilled records are looked
         # up by the same chained digests), so it requires caching.
@@ -839,6 +850,7 @@ class DecodeEngine:
             "spill_pages_out": 0, "spill_pages_in": 0,
             "parked_sessions": 0, "fetches": 0, "experts_touched": 0,
             **dict.fromkeys(_PAIR_KEYS, 0),
+            **dict.fromkeys(_SPARSE_KEYS, 0),
             **dict.fromkeys(_SUM_KEYS, 0),
         }
         import jax
@@ -905,6 +917,8 @@ class DecodeEngine:
             FUSED_WASTED_TOTAL, FUSED_WASTED_HELP)
         self._kernel_steps_ctr = REGISTRY.counter(
             DECODE_KERNEL_STEPS_TOTAL, DECODE_KERNEL_STEPS_HELP)
+        self._sparse_reads_ctr = REGISTRY.counter(
+            SPARSE_READS_TOTAL, SPARSE_READS_HELP)
         self._kv_spilled_gauge = REGISTRY.gauge(
             KV_SPILLED_GAUGE, KV_SPILLED_HELP)
         self._host_tier_gauge = REGISTRY.gauge(
@@ -1444,6 +1458,9 @@ class DecodeEngine:
             "moe_zero_experts": self.cfg.moe_zero_experts
             if self._moe_layers else 0,
             **{key: c[key] for key in _PAIR_KEYS},
+            # What the decode steps of a stack with an indexer or sliding
+            # layers read of those planes (zeros for every other model).
+            **{key: c[key] for key in _SPARSE_KEYS},
             "prefix_reuse": self._prefix_reuse,
             "kv_block_evictions": c["kv_evictions"],
             "kv_shed_no_blocks": c["kv_shed_no_blocks"],
@@ -2407,6 +2424,23 @@ class DecodeEngine:
         if entry["prefilling"]:
             self._prefilling.append(entry)
 
+    def _sparse_reads(self, slots):
+        """What a round's decode steps read of the index and window
+        planes, from ``(positions its first step sees, tokens emitted)``
+        a slot (the step's own position counted, as in ``attended``): a
+        step that sees l positions scores l index keys a full plane,
+        attends ``index_topk`` of them at most, and reads the last
+        ``window`` positions a sliding plane."""
+        scored = chosen = window = 0
+        topk, span = self.cfg.index_topk, self.cfg.window
+        for at, n in slots:
+            scored += n * at + n * (n - 1) // 2
+            chosen += sum(min(at + j, topk) for j in range(n))
+            window += sum(min(at + j, span) for j in range(n))
+        return dict(zip(_SPARSE_KEYS, (
+            scored * self._index_planes, chosen * self._index_planes,
+            window * self.cfg.window_planes)))
+
     def _prefill_chunk(self, entry: dict) -> None:
         """One static-width chunk of one entry's prompt into its slot
         (dispatch only — the final chunk's first sampled token joins
@@ -2419,6 +2453,7 @@ class DecodeEngine:
         covers it, and ``prefill_span_s_sum`` holds a request's whole
         prefill from slot claim to first token."""
         from kubeflow_tpu.models.generate import (
+            index_positions_scored,
             prefill_chunk_into_slot,
             view_positions_scored,
         )
@@ -2475,8 +2510,13 @@ class DecodeEngine:
                     self._mgr.publish(
                         prompt, true_len, entry["blocks"],
                         salt=entry.get("adapter_salt", b""))
-        scored = view_positions_scored(
-            self._tables.shape[1], self.kv_block_tokens, w, start + w)
+        # An indexer's chunk scores its slot's index keys by tiles of
+        # their own; the attention then reads the chosen rows alone.
+        scored = index_positions_scored(
+            self._tables.shape[1], self.kv_block_tokens,
+            w * self.cfg.index_heads, start + w) if self.cfg.indexed \
+            else view_positions_scored(
+                self._tables.shape[1], self.kv_block_tokens, w, start + w)
         with self._lock:
             self._counters["prefill_chunks"] += 1
             self._counters["prefill_positions_held"] += min(
@@ -2958,6 +2998,17 @@ class DecodeEngine:
                     n = int(counts_np[i])
                     attended += n * at + n * (n - 1) // 2
                 facts = {"steps": steps, "attended": attended}
+                if self._index_planes or self.cfg.window_planes:
+                    reads = self._sparse_reads(
+                        (at, int(counts_np[i]))
+                        for (i, _), at in zip(snapshot, lengths))
+                    facts.update(reads)
+                    with self._lock:
+                        for key, n in reads.items():
+                            self._counters[key] += n
+                    for key, n in reads.items():
+                        self._sparse_reads_ctr.inc(
+                            n, engine=self._metric_name, kind=key)
                 if touched is not None:
                     # The device's own count for this round
                     # (decode_rounds starts it at zero), read before the

@@ -713,3 +713,164 @@ def test_decode_rounds_peak_is_no_higher_than_before_the_walk(
     live = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert live <= _DECODE_PEAK_BEFORE_THE_WALK[name], live
+
+
+# dots3-note-prev's language model at the cell's sizes
+# (benchmark/configs/dots3-note-prev-l5.json,
+# cells/dots3-note-prev-l5.longctx): layers 0-4 (full, full, sliding x 3),
+# an indexer of 64 heads of 128 choosing 2,048 positions in the full
+# layers, window layers of 64 heads over rows of 1,088 values, 32 of 256
+# routed experts beside one shared; THREE pools on one table, 16 slots of
+# 32,864 + 384 positions.
+DOTS3 = ({"vocab_size": 19_008, "d_model": 5120, "n_layers": 5,
+          "n_heads": 128, "n_kv_heads": 128, "d_ff": 13_824,
+          "max_seq_len": 524_288, "rope_theta": 8e7,
+          "tied_embeddings": False, "norm_eps": 1e-5,
+          "layer_types": ["full_attention"] * 2
+          + ["sliding_attention"] * 3,
+          "attention_kind": "latent", "mla_q_rank": 1024,
+          "mla_kv_rank": 512, "mla_nope_dim": 128, "mla_rope_dim": 64,
+          "mla_v_dim": 128, "index_heads": 64, "index_dim": 128,
+          "index_topk": 2048, "window": 513, "window_heads": 64,
+          "window_q_rank": 1024, "window_kv_rank": 1024,
+          "window_nope_dim": 192, "window_rope_dim": 64,
+          "window_v_dim": 128, "window_rope_theta": 5e4,
+          "attn_gate": True, "moe_experts": 256, "moe_experts_held": 32,
+          "moe_top_k": 8, "moe_d_ff": 1536, "moe_dense_layers": 1,
+          "moe_shared_d_ff": 1536, "dtype": "bfloat16"}, 16, 33_248, 384)
+
+
+@pytest.fixture(scope="module")
+def dots3_program(chip):
+    import functools
+
+    from kubeflow_tpu.models import generate
+
+    widths, slots, max_len, new = DOTS3
+    e = _engine_shapes(chip, widths, slots, max_len, max_new_tokens=new)
+
+    @functools.cache
+    def compiled(program):
+        if program == "decode_rounds":
+            return e, generate.decode_rounds.lower(
+                e["cfg"], e["params"], e["state"], e["decode"], 8,
+                e["arg"](slots, e["table_blocks"]), e["arg"](),
+                paged_kernel=True).compile()
+        return e, _compile_chunk(e, max_len)
+
+    return compiled
+
+
+@pytest.mark.parametrize("program", ["decode_rounds",
+                                     "prefill_chunk_into_slot"])
+def test_three_pools_come_in_donated_and_go_out_aliased(dots3_program,
+                                                        program):
+    """Both engine programs of the dots3-note-prev cut at the cell's sizes:
+    the three pools (full planes' latent rows, their index keys, window
+    planes' rows) come in donated and go out aliased with no copy, slice
+    or restack of one or of a plane; no array of a layer's experts is
+    produced outside a fusion; the grouped products are the chip's own
+    kernel, once a sparse layer; and the whole fits the chip."""
+    e, compiled = dots3_program(program)
+    text = compiled.as_text()
+    blocks = 16 * 2078
+    shapes = {"cache_latent": (2, blocks, 16, 640),
+              "cache_index": (2, blocks, 16, 128),
+              "cache_window": (3, blocks, 16, 1152)}
+    assert {k: e["state"][k].shape for k in shapes} == shapes
+    for shape in shapes.values():
+        assert _pool_moves(text, shape) == []
+    assert _weight_moves(text, [
+        (32, 5120, 3072), (32, 1536, 5120), (5120, 3072),
+        (2, 5120, 13_824), (5120, 13_824), (13_824, 5120)]) == []
+    # The shared expert's matrices are leaves of their own (its down
+    # matrix has the shape of a routed expert's): what is produced of
+    # their shapes is the compiler's prefetch of a leaf into the fast
+    # memory.
+    assert all(name.startswith(("copy-done", "custom-call"))
+               for name in _weight_moves(text, [
+                   (1536, 5120), (2, 5120, 1536), (5120, 1536)]))
+    assert text.count('op_name="ragged-dot-metadata"') == 4
+    m = compiled.memory_analysis()
+    print(program, "temp", m.temp_size_in_bytes, "args",
+          m.argument_size_in_bytes)
+    pools = sum(int(np.prod(s)) * 2 for s in shapes.values())
+    assert pools == 16 * 33_248 * 9_984 == 5_311_168_512
+    assert m.alias_size_in_bytes >= pools
+    # Weights 8.18 GB (routers and index weights in float32) beside them.
+    assert 13.45e9 < m.argument_size_in_bytes < 13.55e9, \
+        m.argument_size_in_bytes
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert live < 15.5e9, (live, m.temp_size_in_bytes)
+
+
+def test_window_planes_decode_through_the_kernel_from_the_windows_first_page(
+        dots3_program):
+    """The decode program reads a window plane's pages in place through the
+    latent kernel's window form (3 calls a step); a full plane's chosen
+    rows come through the compiler's gather, and no program gathers a
+    slot's whole view of a latent pool."""
+    e, compiled = dots3_program("decode_rounds")
+    text = compiled.as_text()
+    assert len([line for line in text.splitlines()
+                if "custom_call_target=\"tpu_custom_call\"" in line
+                and "%paged_latent_decode_attention" in
+                line.split(" = ")[0]]) == 3
+    view = 2078 * 16
+    for program in ("decode_rounds", "prefill_chunk_into_slot"):
+        text = dots3_program(program)[1].as_text()
+        for rows, width in ((view, 640), (view, 1152)):
+            assert f"bf16[16,{rows},{width}]" not in text
+            assert f"bf16[1,{rows},{width}]" not in text
+
+
+def _folded_table_entries(chip, entries, n):
+    """``entries(tables, first, n)`` over a table and positions that are
+    CONSTANTS of the program, compiled for the chip: (the s32 constants of
+    the result's size that the compiler folded it to, the right entries)."""
+    import re
+
+    from jax._src.lib import xla_client
+
+    slots, table_blocks = 4, 2078
+    rng = np.random.default_rng(0)
+    tables = rng.permutation(slots * table_blocks).reshape(
+        slots, table_blocks).astype(np.int32)
+    first = np.asarray([1798, 1467, 1272, 898], np.int32)
+    compiled = jax.jit(lambda x: entries(
+        jnp.asarray(tables), jnp.asarray(first), n) + x).lower(
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)).compile()
+    options = xla_client._xla.HloPrintOptions()
+    options.print_large_constants = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(options)
+    folded = [np.asarray(re.findall(r"-?\d+", values), np.int64)
+              for values in re.findall(
+                  r"= s32\[[0-9,]*\]\S* constant\(\{([^\n]*)\}\)", text)]
+    want = np.stack([tables[i, p:p + n] for i, p in enumerate(first)])
+    return [c for c in folded if c.size == want.size], want
+
+
+def test_a_windows_pages_of_a_constant_table_are_the_right_pages(chip):
+    """PR 44's fault, found in a scratch script and not in the engine: a
+    slot's window pages taken as ``jax.vmap`` of ``dynamic_slice`` over a
+    table that is a constant of the program fold, in this compiler, to the
+    slice's first entry and zeros, and the window then reads page 0 for
+    every page but one (0.37-0.41 off at an output scale of 0.25 on the
+    chip).  ``generate._table_entries`` is one gather of single entries,
+    which the compiler leaves to run time (or folds right).  The first
+    half holds the program's form; the second says when the other form
+    may come back."""
+    from kubeflow_tpu.models import generate
+
+    folded, want = _folded_table_entries(chip, generate._table_entries, 33)
+    assert all((c == want.reshape(-1)).all() for c in folded)
+    folded, want = _folded_table_entries(
+        chip, lambda tables, first, n: jax.vmap(
+            lambda row, p: jax.lax.dynamic_slice_in_dim(row, p, n))(
+                tables, first), 33)
+    if all((c == want.reshape(-1)).all() for c in folded):
+        return  # this compiler folds the sliced form right
+    wrong, = folded
+    assert (wrong.reshape(4, 33)[:, 0] == want[:, 0]).all()
+    assert (wrong.reshape(4, 33)[:, 1:] == 0).all()
