@@ -643,6 +643,18 @@ def index_positions_scored(max_blocks: int, block_tokens: int, rows: int,
     return int(_tiles_visited(held, tile, tiles)) * tile
 
 
+def index_keys_walked(index_pool) -> bool:
+    """Whether a decode step that reads its pools in place
+    (``paged_kernel``) also scores its index keys so, page by page
+    (ops/paged_attention.py ``paged_index_scores``), from what the index
+    pool [planes, num_blocks, block_tokens, index_dim] is: the layout the
+    kernel can copy.  Else the key tiles of ``_index_scores``."""
+    from kubeflow_tpu.ops import paged_attention
+
+    return paged_attention.supports_index(
+        index_pool.shape[3], index_pool.shape[2], index_pool.dtype)
+
+
 def _index_scores(q_idx, w_idx, keys_of, tile, tiles, visited, q_pos):
     """The indexer's scores ``I(t, s) = sum_h w_h(t) relu(qI_h(t) .
     kI(s))`` in float32, [b, t, tiles * tile]: ``keys_of(i)`` gathers key
@@ -844,9 +856,16 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
 
     With an indexer (``cfg.indexed``, full_attention layers) a token
     also writes its index key to the index pool's plane, the call scores
-    the index keys its slots hold by key tiles (``_index_scores``),
-    chooses ``index_topk`` positions a query (``_choose``) and attends
-    the chosen rows and no others, gathered a query by (page, offset).
+    the index keys its slots hold, chooses ``index_topk`` positions a
+    query (``_choose``) and attends the chosen rows and no others,
+    gathered a query by (page, offset).  The scores come by key tiles
+    gathered for every row up to the call's longest slot
+    (``_index_scores``: a prefill chunk, whose 16,384 (query, head) rows
+    a tile are bound by the products; every backend but the chip) or,
+    in a decode step with ``paged_kernel`` over a pool the kernel can
+    copy (``index_keys_walked``), page by page from each slot's OWN pages
+    in place (``paged_index_scores``): one arithmetic, chosen by the
+    call's shape and the pool's layout.
     A sliding_attention layer gathers (the kernel:
     copies) only the pages that meet its queries' windows
     (``window_pages``), whatever the slot holds below them.  Both read
@@ -966,24 +985,32 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
             q_row = jnp.concatenate(
                 [qt, q_rope, jnp.zeros((b, t, heads, pad), dt)],
                 axis=-1)                                # [b, t, h, width]
-        if decode and paged_kernel and not indexed:
-            # The step's own row is in the pool already; a parked write
-            # marks a retired row, which reads nothing.
+        if decode and paged_kernel:
+            from kubeflow_tpu.ops import paged_attention
+
+            # The step's own row (and index key) is in the pool already;
+            # a parked write marks a retired row, which reads nothing.
             attend = jnp.where(base < mb * bt, cache_len + 1, 0)
         if indexed:
             with jax.named_scope("kft.dsa_index"):
-                pages, tiles = index_key_tiles(
-                    mb, bt, b * t * cfg.index_heads)
-                padded = jnp.pad(tables,
-                                 ((0, 0), (0, tiles * pages - mb)))
-                scores = _index_scores(
-                    q_idx, w_idx,
-                    lambda i: keys[plane, jax.lax.dynamic_slice_in_dim(
-                        padded, i * pages, pages, axis=1)].reshape(
-                            b, pages * bt, -1),
-                    pages * bt, tiles,
-                    _tiles_visited(jnp.max(cache_len) + t, pages * bt,
-                                   tiles), q_pos)
+                if decode and paged_kernel and index_keys_walked(keys):
+                    padded = tables
+                    scores = paged_attention.paged_index_scores(
+                        q_idx[:, 0], w_idx[:, 0], keys, plane, tables,
+                        attend)[:, None]
+                else:
+                    pages, tiles = index_key_tiles(
+                        mb, bt, b * t * cfg.index_heads)
+                    padded = jnp.pad(tables,
+                                     ((0, 0), (0, tiles * pages - mb)))
+                    scores = _index_scores(
+                        q_idx, w_idx,
+                        lambda i: keys[plane, jax.lax.dynamic_slice_in_dim(
+                            padded, i * pages, pages, axis=1)].reshape(
+                                b, pages * bt, -1),
+                        pages * bt, tiles,
+                        _tiles_visited(jnp.max(cache_len) + t, pages * bt,
+                                       tiles), q_pos)
             with jax.named_scope("kft.dsa_select"):
                 chosen, real = _choose(scores, q_pos, cfg.index_topk)
             with jax.named_scope("kft.mla_sparse"):
@@ -1001,8 +1028,6 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
         elif z.window:
             with jax.named_scope("kft.mla_window"):
                 if decode and paged_kernel:
-                    from kubeflow_tpu.ops import paged_attention
-
                     ot = paged_attention.paged_latent_decode_attention(
                         q_row[:, 0], pool, plane, tables, attend, rkv,
                         scale, window=z.window)[:, None]
@@ -1014,8 +1039,6 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
             with jax.named_scope(
                     "kft.mla_decode" if decode else "kft.mla_prefill"):
                 if decode and paged_kernel:
-                    from kubeflow_tpu.ops import paged_attention
-
                     ot = paged_attention.paged_latent_decode_attention(
                         q_row[:, 0], pool, plane, tables, attend, rkv,
                         scale)[:, None]

@@ -1,4 +1,5 @@
-"""Decode attention over the paged KV pool, read in place (Pallas TPU).
+"""Decode attention over the paged KV pool, and an indexer's scores over
+its paged index keys, read in place (Pallas TPU).
 
 The decode program of the serving engine (``models/generate.py``
 ``decode_rounds``) attends ONE query position per slot
@@ -72,6 +73,24 @@ walk starts at the page that holds the first position of its window and
 the mask keeps ``[n_tokens - window, n_tokens)``: a sliding layer copies
 the pages that meet its window, whatever the slot holds below them.
 
+Index keys (``paged_index_scores``).  A stack with an indexer keeps a
+second pool beside its latent one, ``[planes, num_blocks, block_tokens,
+index_dim]``: a position's index key, one for all index heads, a page of
+16 keys of 128 bfloat16 exactly one 4 KB tile.  The walk is the same
+(``_walk``: one side, the same two buffers, blocks in flight from the
+call's first live slot to its last); what is computed on a block is not
+attention: ``q_idx [hI, dI] @ keys^T`` in the pool's dtype with float32
+accumulation, relu, times the head's float32 weight, summed over the
+index heads: one float32 score a position, written to the block's row
+of the output.  No softmax and no carry between blocks; a position at or
+past the slot's ``n_tokens`` reads -inf, a retired slot -inf everywhere,
+which is what ``models/generate.py`` ``_index_scores`` gives and what its
+``_choose`` expects.  At 4 KB a descriptor the walk is bound by the
+issue of its copies and their waits (~11 ns a page: 335 GB/s alone on
+the chip, the same with 8 index heads as with 64; PERF.md section 6,
+PR 45), not by HBM; ``supports_index`` says which pools can be laid out
+so.
+
 What is NOT here: a form that copies CHOSEN rows (an indexer's
 ``index_topk`` positions).  A latent row lies in HBM inside a tile of
 (8, 128)(2, 1): the chip's compiler refuses a copy of one row of it
@@ -102,50 +121,47 @@ from jax.experimental.pallas import tpu as pltpu
 _MASKED = -1e30
 
 
-def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
-            pages, nb, planes, scale, value_lanes=None, window=None):
-    """One slot: walk its resident pages ``pages`` at a time and, under
-    its last block, start the first block of the next slot that attends
-    anything.
+def _first_page(ntok_ref, slot, window, bt):
+    """The first page of ``slot``'s walk: 0, or the one that holds the
+    first position of its window."""
+    if window is None:
+        return 0
+    return jnp.maximum(ntok_ref[slot] - window, 0) // bt
 
-    ``refs``: the pool's sides in HBM (keys and values; or, with
-    ``value_lanes``, the ONE latent pool, whose rows are keys whole and
-    values in their first ``value_lanes`` lanes), the output, a buffer a
-    side, the semaphores, and ``ride``, the two words that pass from one
-    grid step to the next: the buffer the next first block goes to, and
-    the slot whose first block is in flight (module docstring).
-    ``window``: a slot attends its last ``window`` positions only, and
-    its walk starts at the page that holds the first of them."""
-    sides = (len(refs) - 3) // 2
-    hbm, o_ref = refs[:sides], refs[sides]
-    bufs, sems, ride = refs[sides + 1:-2], refs[-2], refs[-1]
+
+def _walk(tables_ref, ntok_ref, plane_ref, hbm, bufs, sems, ride, *, mb,
+          bt, rows, pages, nb, planes, window, idle, visit, carry, finish):
+    """One slot's walk (module docstring), whatever is computed on a
+    block: its resident pages ``pages`` at a time into ``bufs`` (one a
+    side of ``hbm``, ``rows`` rows a page) and, under its last block, the
+    first block of the next slot that holds anything.
+
+    ``visit(i, buf, carry) -> carry`` computes block ``i`` of the slot,
+    landed in buffer ``buf``; ``finish(carry)`` takes what the last block
+    returns; ``idle()`` stands for both in a slot that holds nothing
+    (retired), which starts and waits for nothing.  ``ride``: the two
+    words that pass from one grid step to the next, the buffer the next
+    first block goes to and the slot whose first block is in flight."""
     s, slots = pl.program_id(0), pl.num_programs(0)
     # The plane's page 0 in the view.  This clamp and the one on a
     # table's entry keep every copy inside the pool; the compiler's own
     # checks are off (``_COMPILER_PARAMS``).
     first = jnp.clip(plane_ref[0], 0, planes - 1) * nb
-    rows = bt * hkv                       # (position, kv head) rows a page
 
     def first_page(slot):
-        """The first page of ``slot``'s walk: 0, or the one that holds
-        the first position of its window."""
-        if window is None:
-            return 0
-        return jnp.maximum(ntok_ref[slot] - window, 0) // bt
+        return _first_page(ntok_ref, slot, window, bt)
 
     def pages_of(slot):
         """Pages ``slot``'s walk holds, from its first page on."""
         return (ntok_ref[slot] + bt - 1) // bt - first_page(slot)
 
-    n = ntok_ref[s]                       # positions to attend (0: none)
     n_pages = pages_of(s)
     n_blocks = (n_pages + pages - 1) // pages
-    page0 = first_page(s)
 
     @pl.when(s == 0)
     def _():
-        # A page the walk never copied is multiplied by a zero weight:
-        # it has to hold numbers, and fresh VMEM need not.
+        # A page the walk never copied is multiplied by a zero weight
+        # (or masked): it has to hold numbers, and fresh VMEM need not.
         for buf in bufs:
             buf[...] = jnp.zeros_like(buf)
         ride[0] = 0
@@ -165,9 +181,9 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
                 tables_ref[slot * mb + first_page(slot) + blk * pages + p],
                 0, nb - 1)
             dst = pl.ds(pl.multiple_of(p * rows, rows), rows)
-            for side in range(sides):
+            for side, buffer in enumerate(bufs):
                 act(pltpu.make_async_copy(
-                    hbm[side].at[at], bufs[side].at[buf, dst],
+                    hbm[side].at[at], buffer.at[buf, dst],
                     sems.at[side, buf]))
 
         here = held - blk * pages             # of this block's pages
@@ -191,6 +207,65 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
     def wait(copy):
         copy.wait()
 
+    def landed(i, buf, state):
+        block(s, i, buf, n_pages, wait)
+        return visit(i, buf, state)
+
+    @pl.when(n_blocks == 0)
+    def _():
+        idle()
+
+    @pl.when(n_blocks > 0)
+    def _():
+        buf0 = ride[0]
+
+        @pl.when(ride[1] != s)
+        def _():
+            # Nobody fetched this slot's first block: the call's first
+            # slot that holds anything.
+            block(s, 0, buf0, n_pages, start)
+
+        def body(i, state):
+            buf = (buf0 + i) % 2
+            block(s, i + 1, 1 - buf, n_pages, start)
+            return landed(i, buf, state)
+
+        state = jax.lax.fori_loop(0, n_blocks - 1, body, carry)
+        # The last block: the buffer beside it is free, and takes the
+        # first block of the next slot that holds anything.
+        buf = (buf0 + n_blocks - 1) % 2
+        nxt = jax.lax.while_loop(
+            lambda j: (j < slots)
+            & (ntok_ref[jnp.minimum(j, slots - 1)] == 0),
+            lambda j: j + 1, s + 1)
+
+        @pl.when(nxt < slots)
+        def _():
+            block(nxt, 0, 1 - buf, pages_of(nxt), start)
+
+        ride[0] = 1 - buf
+        ride[1] = nxt
+        finish(landed(n_blocks - 1, buf, state))
+
+
+def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
+            pages, nb, planes, scale, value_lanes=None, window=None):
+    """One slot's attention over the pages ``_walk`` brings.
+
+    ``refs``: the pool's sides in HBM (keys and values; or, with
+    ``value_lanes``, the ONE latent pool, whose rows are keys whole and
+    values in their first ``value_lanes`` lanes), the output, a buffer a
+    side, the semaphores, and the walk's ``ride``.
+    ``window``: a slot attends its last ``window`` positions only, and
+    its walk starts at the page that holds the first of them."""
+    sides = (len(refs) - 3) // 2
+    hbm, o_ref = refs[:sides], refs[sides]
+    bufs, sems, ride = refs[sides + 1:-2], refs[-2], refs[-1]
+    s = pl.program_id(0)
+    rows = bt * hkv                       # (position, kv head) rows a page
+    n = ntok_ref[s]                       # positions to attend (0: none)
+    page0 = _first_page(ntok_ref, s, window, bt)
+
     q = q_ref[0]                                            # [h, d]
     h = q.shape[0]
     shape = (h, pages * rows)
@@ -201,7 +276,6 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
 
     def attend(i, buf, carry):
         m, l, acc = carry
-        block(s, i, buf, n_pages, wait)
         k = bufs[0][buf]                                    # [rows*, d]
         v = bufs[-1][buf]
         if value_lanes is not None:
@@ -223,48 +297,48 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    @pl.when(n_blocks == 0)
-    def _():
-        # A slot with nothing to attend (retired) starts and waits for
-        # nothing, and returns zeros.
+    def idle():
+        # A slot with nothing to attend returns zeros.
         o_ref[0] = jnp.zeros_like(o_ref[0])
 
-    @pl.when(n_blocks > 0)
-    def _():
-        buf0 = ride[0]
-
-        @pl.when(ride[1] != s)
-        def _():
-            # Nobody fetched this slot's first block: the call's first
-            # slot that attends anything.
-            block(s, 0, buf0, n_pages, start)
-
-        def body(i, carry):
-            buf = (buf0 + i) % 2
-            block(s, i + 1, 1 - buf, n_pages, start)
-            return attend(i, buf, carry)
-
-        h_rows = (h, 1)
-        carry = jax.lax.fori_loop(0, n_blocks - 1, body, (
-            jnp.full(h_rows, _MASKED, jnp.float32),
-            jnp.zeros(h_rows, jnp.float32),
-            jnp.zeros((h, value_lanes or q.shape[1]), jnp.float32)))
-        # The last block: the buffer beside it is free, and takes the
-        # first block of the next slot that attends anything.
-        buf = (buf0 + n_blocks - 1) % 2
-        nxt = jax.lax.while_loop(
-            lambda j: (j < slots)
-            & (ntok_ref[jnp.minimum(j, slots - 1)] == 0),
-            lambda j: j + 1, s + 1)
-
-        @pl.when(nxt < slots)
-        def _():
-            block(nxt, 0, 1 - buf, pages_of(nxt), start)
-
-        ride[0] = 1 - buf
-        ride[1] = nxt
-        _, l, acc = attend(n_blocks - 1, buf, carry)
+    def finish(carry):
+        _, l, acc = carry
         o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+    h_rows = (h, 1)
+    _walk(tables_ref, ntok_ref, plane_ref, hbm, bufs, sems, ride, mb=mb,
+          bt=bt, rows=rows, pages=pages, nb=nb, planes=planes,
+          window=window, idle=idle, visit=attend, finish=finish, carry=(
+              jnp.full(h_rows, _MASKED, jnp.float32),
+              jnp.zeros(h_rows, jnp.float32),
+              jnp.zeros((h, value_lanes or q.shape[1]), jnp.float32)))
+
+
+def _index_kernel(tables_ref, ntok_ref, plane_ref, q_ref, w_ref, pool_ref,
+                  o_ref, buf_ref, sems, ride, *, mb, bt, pages, nb, planes):
+    """One slot's indexer scores over the index key pages ``_walk``
+    brings: block i's ``sum_h w_h relu(q_h . k)`` is row i of the
+    output, and every position at or past the slot's ``n_tokens`` (all
+    of a retired slot's) reads -inf.  Nothing passes from one block to
+    the next."""
+    n = ntok_ref[pl.program_id(0)]
+    q = q_ref[0]                                            # [hI, dI]
+    w = w_ref[0]                                            # [hI, 1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, pages * bt), 1)
+    o_ref[0] = jnp.full(o_ref.shape[1:], -jnp.inf, o_ref.dtype)
+
+    def score(i, buf, _):
+        sc = jax.lax.dot_general(
+            q, buf_ref[buf], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [hI, keys]
+        sc = jnp.sum(jax.nn.relu(sc) * w, axis=0, keepdims=True)
+        o_ref[0, pl.ds(i, 1)] = jnp.where(
+            pos + i * (pages * bt) < n, sc, -jnp.inf)
+
+    _walk(tables_ref, ntok_ref, plane_ref, (pool_ref,), (buf_ref,), sems,
+          ride, mb=mb, bt=bt, rows=bt, pages=pages, nb=nb, planes=planes,
+          window=None, idle=lambda: None, visit=score, carry=None,
+          finish=lambda _: None)
 
 
 _LANES = 128
@@ -278,7 +352,9 @@ _UNROLL = 8
 # A block of copies is about a MiB a side: read alone on the chip
 # (PERF.md section 5, PR 41), latent pages of 20 KB are fastest 64 a
 # block (32: +12 to +19 %, 128: +2 to +7 %, 16: +44 %), and k / v pages
-# of 32 KB a side read within 4 % at 16, 32 and 64 a block (8: +7 %).
+# of 32 KB a side read within 4 % at 16, 32 and 64 a block (8: +7 %);
+# index key pages of 4 KB are fastest 256 a block (64: +11 %, 128: +2 %,
+# 512: +5 %; PR 45).
 _BLOCK_BYTES = 1 << 20
 
 # Each page's copy carried two bounds checks that halt the chip (one on
@@ -445,3 +521,65 @@ def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
     )(*_walk_arguments(tables, n_tokens, plane), q,
       pool.reshape(planes * nb, bt, row))
 
+
+def supports_index(index_dim: int, block_tokens: int, dtype) -> bool:
+    """Whether a page of the index pool, ``[block_tokens, index_dim]`` of
+    ``dtype``, can be copied as it lies: keys of whole 128-lane rows, and
+    a page of whole tiles of the dtype (8 rows of 32 bits: 16 of
+    bfloat16)."""
+    return index_dim % _LANES == 0 \
+        and block_tokens % (32 // jnp.dtype(dtype).itemsize) == 0
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_block",
+                                             "interpret"))
+def paged_index_scores(q_idx, w_idx, index_pool, plane, tables, n_tokens, *,
+                       pages_per_block: int | None = None,
+                       interpret: bool = False):
+    """An indexer's scores of ONE query position a slot against the
+    index keys the slot holds: ``I(s) = sum_h w_h relu(q_h . k(s))`` ->
+    float32 ``[S, max_blocks * block_tokens]``, -inf at and past a slot's
+    ``n_tokens`` (everywhere for a retired slot), as
+    ``models/generate.py`` ``_index_scores`` gives them.
+
+    q_idx ``[S, hI, dI]`` (the pool's dtype on the chip), w_idx ``[S,
+    hI]`` float32; index_pool: ``[planes, num_blocks, block_tokens, dI]``,
+    the stacked index pool, left in HBM: a position's index key, which
+    all index heads share.  plane / tables / n_tokens /
+    pages_per_block: as ``paged_decode_attention``.  The products run in
+    the pool's dtype with float32 accumulation; relu, the weights and the
+    sum over the index heads in float32.
+    """
+    S, hI, dI = q_idx.shape
+    planes, nb, bt, _ = index_pool.shape
+    mb = tables.shape[1]
+    pages = min(pages_per_block, mb) if pages_per_block else _block_pages(
+        bt * dI * index_pool.dtype.itemsize, mb)
+    blocks = -(-mb // pages)
+    kernel = functools.partial(
+        _index_kernel, mb=mb, bt=bt, pages=pages, nb=nb, planes=planes)
+    out = pl.pallas_call(
+        kernel,
+        name="paged_index_scores",
+        # A block's scores a row; the last block may overhang the table.
+        out_shape=jax.ShapeDtypeStruct((S, blocks, pages * bt),
+                                       jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S,),
+            in_specs=[
+                pl.BlockSpec((1, hI, dI), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec((1, hI, 1), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, blocks, pages * bt),
+                                   lambda s, *_: (s, 0, 0)),
+            scratch_shapes=_walk_scratch([
+                pltpu.VMEM((2, pages * bt, dI), index_pool.dtype)]),
+        ),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(*_walk_arguments(tables, n_tokens, plane), q_idx,
+      w_idx.astype(jnp.float32)[..., None],
+      index_pool.reshape(planes * nb, bt, dI))
+    return out.reshape(S, blocks * pages * bt)[:, :mb * bt]

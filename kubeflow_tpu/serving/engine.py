@@ -199,7 +199,8 @@ SPARSE_READS_TOTAL = "kft_engine_sparse_positions_total"
 SPARSE_READS_HELP = \
     "positions the decode steps of a stack with an indexer or sliding " \
     "layers read, by engine and kind (index_scored, index_chosen, " \
-    "window_read), summed over slots, steps and planes"
+    "window_read; index_read: the index keys the program read to " \
+    "score those), summed over slots, steps and planes"
 DECODE_KERNEL_STEPS_TOTAL = "kft_engine_decode_kernel_steps_total"
 DECODE_KERNEL_STEPS_HELP = \
     "decode steps run by a step program that holds the paged " \
@@ -319,8 +320,10 @@ _SUM_KEYS = ("loop_rounds", *_PHASE_KEY.values(),
 _PAIR_KEYS = ("pairs_held", "pairs_zero", "pairs_absent")
 # What the decode steps of a stack with an indexer or sliding layers read,
 # by (slot, step, plane): index keys scored, positions chosen of them,
-# window positions read (``DecodeEngine._sparse_reads``).
-_SPARSE_KEYS = ("index_scored", "index_chosen", "window_read")
+# window positions read, and the index keys the program READ to score
+# the first (``DecodeEngine._sparse_reads``).
+_SPARSE_KEYS = ("index_scored", "index_chosen", "window_read",
+                "index_read")
 # An iteration that takes this many times the running mean of the
 # wall time (wait_work left out) of the iterations that waited for the
 # device is counted and logged with its own phase times; the mean
@@ -588,6 +591,7 @@ class DecodeEngine:
         import jax
 
         from kubeflow_tpu.models.generate import (
+            index_keys_walked,
             init_paged_state,
             pool_sides,
         )
@@ -751,6 +755,10 @@ class DecodeEngine:
             # (TransformerConfig.latent_row).
             self._paged_kernel = cfg.latent or paged_attention.supports(
                 cfg.head_dim, cfg.n_kv_heads)
+        # Whether those decode steps also score their index keys page by
+        # page in place, as the program itself decides from the pool.
+        self._index_walk = self._paged_kernel and cfg.indexed \
+            and index_keys_walked(self._state["cache_index"])
         # Host-owned per-slot block tables, passed into every program
         # call; the sentinel value (== pool size) parks writes and
         # reads of unallocated logical pages.  Loop-thread-owned.
@@ -2424,22 +2432,38 @@ class DecodeEngine:
         if entry["prefilling"]:
             self._prefilling.append(entry)
 
-    def _sparse_reads(self, slots):
-        """What a round's decode steps read of the index and window
-        planes, from ``(positions its first step sees, tokens emitted)``
-        a slot (the step's own position counted, as in ``attended``): a
-        step that sees l positions scores l index keys a full plane,
-        attends ``index_topk`` of them at most, and reads the last
-        ``window`` positions a sliding plane."""
-        scored = chosen = window = 0
+    def _sparse_reads(self, slots, steps):
+        """What a round's ``steps`` decode steps read of the index and
+        window planes, from ``(positions its first step sees, tokens
+        emitted)`` a slot (the step's own position counted, as in
+        ``attended``): a step that sees l positions scores l index keys
+        a full plane, attends ``index_topk`` of them at most, and reads
+        the last ``window`` positions a sliding plane.  To score the l
+        keys the program READS l rounded up to whole pages where it
+        walks the slot's own pages (``_index_walk``), else, for every
+        row of the call, the key tiles up to the longest slot's."""
+        from kubeflow_tpu.models.generate import index_positions_scored
+
+        slots = list(slots)
+        scored = chosen = window = read = 0
         topk, span = self.cfg.index_topk, self.cfg.window
+        pages, bt = self._tables.shape[1], self.kv_block_tokens
         for at, n in slots:
             scored += n * at + n * (n - 1) // 2
             chosen += sum(min(at + j, topk) for j in range(n))
             window += sum(min(at + j, span) for j in range(n))
+            if self._index_walk:
+                read += sum(-(-(at + j) // bt) * bt for j in range(n))
+        if self._index_planes and not self._index_walk:
+            # A slot that stopped keeps its length and rides along.
+            read = self.slots * sum(
+                index_positions_scored(
+                    pages, bt, self.slots * self.cfg.index_heads,
+                    max(at + min(j, n) for at, n in slots))
+                for j in range(steps))
         return dict(zip(_SPARSE_KEYS, (
             scored * self._index_planes, chosen * self._index_planes,
-            window * self.cfg.window_planes)))
+            window * self.cfg.window_planes, read * self._index_planes)))
 
     def _prefill_chunk(self, entry: dict) -> None:
         """One static-width chunk of one entry's prompt into its slot
@@ -2999,9 +3023,9 @@ class DecodeEngine:
                     attended += n * at + n * (n - 1) // 2
                 facts = {"steps": steps, "attended": attended}
                 if self._index_planes or self.cfg.window_planes:
-                    reads = self._sparse_reads(
+                    reads = self._sparse_reads((
                         (at, int(counts_np[i]))
-                        for (i, _), at in zip(snapshot, lengths))
+                        for (i, _), at in zip(snapshot, lengths)), steps)
                     facts.update(reads)
                     with self._lock:
                         for key, n in reads.items():

@@ -22,6 +22,7 @@ products, and the seeded scores lie ~1e-2 apart where rounding moves them by
 from 1 +- 0.3, the indexer's LayerNorm bias and the router's bias at 0.03.
 """
 
+import collections
 import dataclasses
 import functools
 import json
@@ -154,6 +155,14 @@ def _tokens(n, seed=0):
 @pytest.fixture(scope="module")
 def dots3():
     cfg = _config()
+    return cfg, _params(cfg)
+
+
+@pytest.fixture(scope="module")
+def dots3_wide_keys():
+    """Index keys of 128, which the walk kernel can copy (the 8 of
+    ``dots3`` cannot, and keep the key tiles under ``paged_kernel``)."""
+    cfg = _config(dict(PUBLISHED, index_head_dim=128))
     return cfg, _params(cfg)
 
 
@@ -552,6 +561,69 @@ def test_decode_rounds_through_the_kernel_matches_the_reference(
     assert run.worst(1, prompt, run.next_logits()) < TOL
 
 
+def test_decode_rounds_scores_index_keys_through_the_walk_kernel(
+        dots3_wide_keys, monkeypatch):
+    """``decode_rounds`` with the kernels interpreted against the tile
+    form, float32 on both sides.  Two slots of unequal lengths and a
+    third retired; the same positions chosen a step and full plane, the
+    same tokens."""
+    import jax
+
+    from kubeflow_tpu.models import generate
+    from kubeflow_tpu.ops import paged_attention
+
+    cfg, params = dots3_wide_keys
+    calls = collections.Counter()
+    for name in ("paged_latent_decode_attention", "paged_index_scores"):
+        def interpreted(*args, _real=getattr(paged_attention, name),
+                        _name=name, **kw):
+            calls[_name] += 1
+            return _real(*args, interpret=True, **kw)
+
+        monkeypatch.setattr(paged_attention, name, interpreted)
+    choose, seen = generate._choose, []
+
+    def recorded(scores, q_pos, topk):
+        chosen, real = choose(scores, q_pos, topk)
+        jax.debug.callback(
+            lambda *a: seen.append([np.asarray(x) for x in a]), scores,
+            chosen, real, ordered=True)
+        return chosen, real
+
+    monkeypatch.setattr(generate, "_choose", recorded)
+    prompts = {0: _tokens(70, seed=14), 2: _tokens(33, seed=15)}
+    served = {}
+    for kernel in (False, True):
+        run = _served(cfg, params, new=5)
+        for slot, prompt in prompts.items():
+            run.prefill(slot, prompt, new=5)
+        del seen[:]                      # the chunks' choices
+        run.state, toks, counts, ran = run.g.decode_rounds(
+            cfg, params, run.state, run.decode, 4, run.tables, np.int32(4),
+            paged_kernel=kernel)
+        jax.effects_barrier()
+        assert int(ran) == 4 and len(seen) == 4 * 2
+        served[kernel] = (np.asarray(toks), np.asarray(counts), list(seen))
+    assert calls == {"paged_index_scores": 2,
+                     "paged_latent_decode_attention": cfg.window_planes}
+    (toks, counts, tiles), (k_toks, k_counts, walked) = \
+        served[False], served[True]
+    assert counts.tolist() == [4, 0, 4]
+    np.testing.assert_array_equal(k_counts, counts)
+    np.testing.assert_array_equal(k_toks, toks)
+    live = [0, 2]
+    for (sc, chosen, real), (k_sc, k_chosen, k_real) in zip(tiles, walked):
+        # The table's own length, not whole key tiles of it.
+        assert k_sc.shape[-1] == TABLE * BLOCK <= sc.shape[-1]
+        assert np.isneginf(k_sc[1]).all()
+        np.testing.assert_allclose(k_sc[live], sc[live, :, :TABLE * BLOCK],
+                                   atol=1e-5)
+        np.testing.assert_array_equal(k_real[live], real[live])
+        assert real[live].sum() == 2 * TOPK
+        np.testing.assert_array_equal(k_chosen[live][k_real[live]],
+                                      chosen[live][real[live]])
+
+
 # -- the engine ---------------------------------------------------------------
 
 def _engine(cfg, params, **kw):
@@ -609,6 +681,61 @@ def test_engine_reuses_a_prefix_on_three_pools_and_says_what_it_read(dots3):
     assert stats["index_chosen"] == 2 * TOPK * 8
     assert stats["window_read"] == 2 * WINDOW * 8
     assert stats["pairs_held"] > 0 and stats["pairs_absent"] > 0
+
+
+@pytest.mark.parametrize("form", ["walk", "tiles"])
+def test_engine_counts_the_index_keys_its_program_reads(
+        dots3_wide_keys, monkeypatch, form):
+    """``index_read``: with the walk kernel (an engine told its pools live
+    on a TPU, index keys 128 wide; the kernels interpreted) a step's l
+    rounded up to whole pages; with the key tiles, what
+    ``index_positions_scored`` says of the round's longest slot, for
+    every row of the call.  ``index_scored`` is the l keys a step needs
+    either way."""
+    from kubeflow_tpu.models import generate
+    from kubeflow_tpu.ops import paged_attention
+    from kubeflow_tpu.serving import engine as engine_mod
+
+    cfg, params = dots3_wide_keys
+    if form == "walk":
+        monkeypatch.setattr(engine_mod, "_plain_pool_platform",
+                            lambda pool: "tpu")
+        for name in ("paged_latent_decode_attention", "paged_index_scores"):
+            monkeypatch.setattr(paged_attention, name, functools.partial(
+                getattr(paged_attention, name), interpret=True))
+    engine = _engine(cfg, params)
+    try:
+        prompts = [_tokens(n, seed=30 + n) for n in (75, 33)]
+        for p in prompts:
+            engine.submit({"tokens": p, "max_new_tokens": 5})
+        stats = engine.stats()
+    finally:
+        engine.close(drain_s=0.0)
+    # Each request: 4 decode steps after the prompt's own first token, a
+    # round a request, in 2 full planes; tables of 11 pages of 16.
+    held = [np.arange(len(p) + 1, len(p) + 5) for p in prompts]
+    assert stats["steps"] == 8
+    assert stats["index_scored"] == 2 * sum(h.sum() for h in held)
+    assert stats["index_chosen"] == 2 * TOPK * 8
+    if form == "walk":
+        assert stats["decode_kernel_steps"] == 8
+        assert stats["index_read"] == 2 * sum(
+            (-(-h // 16) * 16).sum() for h in held) == 2 * (4 * 80 + 4 * 48)
+    else:
+        assert stats["decode_kernel_steps"] == 0
+        # One key tile holds the whole table: 176 keys for each of the
+        # call's 3 rows, whatever the one live slot holds.
+        assert generate.index_positions_scored(11, 16, 3 * 3, 80) == 176
+        assert stats["index_read"] == 2 * 3 * 176 * 8
+    # A round of 3 steps over two unequal slots, one stopping after its
+    # first step, with key tiles of 2 pages: from the loop's own lengths.
+    monkeypatch.setattr(generate, "_INDEX_KEY_TILE", 32)
+    reads = engine._sparse_reads([(40, 3), (75, 1)], 3)
+    assert reads["index_scored"] == 2 * (40 + 41 + 42 + 75)
+    assert reads["index_read"] == (
+        2 * (3 * 48 + 80) if form == "walk"
+        # Tiles up to the longest slot (75, then 76 where it stopped).
+        else 2 * 3 * (96 + 96 + 96))
 
 
 def test_engine_stats_of_another_stack_count_no_sparse_reads():

@@ -609,3 +609,81 @@ class TestPagedDecodeKernel:
         from kubeflow_tpu.ops.paged_attention import _block_pages
 
         assert _block_pages(page_bytes, table) == pages
+
+
+class TestPagedIndexScores:
+    """``paged_index_scores`` in the TPU interpreter (scratch starts as
+    NaN, a copy lands only where it is waited for) against
+    ``generate._index_scores`` over each slot's gathered index keys."""
+
+    # Tables of 12 pages of 8, blocks of 2 pages (16 positions):
+    # (positions a slot, the plane read), and what the case is about.
+    _CASES = {
+        "mixed_lengths": ((41, 9, 70, 20, 57, 3), 1),
+        "ends_on_a_page": ((24, 40, 8, 56, 72, 88), 1),
+        "ends_on_a_block": ((16, 32, 48, 64, 96, 16), 1),
+        "retired_between_live": ((40, 0, 23, 0, 0, 50), 1),
+        "first_and_last_retired": ((0, 33, 48, 7, 17, 0), 1),
+        "one_live_slot": ((0, 0, 0, 37, 0, 0), 1),
+        "one_slot_only": ((45,), 1),
+        "first_plane": ((41, 32, 9, 57, 70, 20), 0),
+        "last_plane": ((52, 1, 36, 20, 60, 96), 2),
+        "sentinel_past_the_frontier": ((16, 5, 96, 31, 0, 24), 1),
+        "all_retired": ((0, 0, 0, 0, 0, 0), 1),
+    }
+
+    @pytest.mark.parametrize("case", sorted(_CASES))
+    def test_matches_index_scores(self, case):
+        from jax._src.pallas.mosaic.interpret import (
+            interpret_pallas_call as tpu_interpreter,
+        )
+        from jax.experimental.pallas import tpu as pltpu
+
+        from kubeflow_tpu.models.generate import _choose, _index_scores
+        from kubeflow_tpu.ops.paged_attention import (
+            paged_index_scores,
+            supports_index,
+        )
+
+        n, plane = self._CASES[case]
+        n = np.array(n, np.int32)
+        rng = np.random.RandomState(45)
+        slots, table, heads, dim, topk = len(n), 12, 4, 128, 10
+        assert supports_index(dim, _PAGE, jnp.float32) \
+            and supports_index(dim, 16, jnp.bfloat16) \
+            and not supports_index(32, 16, jnp.bfloat16) \
+            and not supports_index(dim, 8, jnp.bfloat16)
+        nb = slots * table + 5
+        tables = rng.permutation(nb)[:slots * table].reshape(
+            slots, table).astype(np.int32)
+        if case == "sentinel_past_the_frontier":
+            for s in range(slots):
+                tables[s, -(-int(n[s]) // _PAGE):] = nb
+        q = jnp.asarray(rng.randn(slots, heads, dim), jnp.float32)
+        w = jnp.asarray(rng.randn(slots, heads), jnp.float32)
+        # The planes beside the one read hold other numbers.
+        pools = jnp.asarray(rng.randn(3, nb, _PAGE, dim), jnp.float32)
+        view = pools[plane][np.minimum(tables, nb - 1)].reshape(
+            slots, table * _PAGE, dim)
+        q_pos = jnp.asarray(n[:, None] - 1)
+        ref = _index_scores(q[:, None], w[:, None], lambda i: view,
+                            table * _PAGE, 1, 1, q_pos)
+        want = [np.asarray(a) for a in _choose(ref, q_pos, topk)]
+        # The interpreter's callbacks can deadlock against another
+        # dispatch: the reference is on the host before the kernel
+        # starts, and nothing is dispatched until its result is too.
+        ref = np.asarray(ref)[:, 0]
+        out = np.asarray(paged_index_scores(
+            q, w, pools, jnp.int32(plane), jnp.asarray(tables),
+            jnp.asarray(n), pages_per_block=2,
+            interpret=pltpu.InterpretParams(detect_races=True)))
+        assert not tpu_interpreter.races.races_found
+        assert out.shape == ref.shape and out.dtype == np.float32
+        past = np.arange(table * _PAGE)[None, :] >= n[:, None]
+        assert np.isneginf(out[past]).all()
+        np.testing.assert_allclose(out[~past], ref[~past], atol=1e-5,
+                                   rtol=1e-5)
+        got = [np.asarray(a) for a in _choose(
+            jnp.asarray(out)[:, None], q_pos, topk)]
+        np.testing.assert_array_equal(got[1], want[1])
+        np.testing.assert_array_equal(got[0][got[1]], want[0][want[1]])

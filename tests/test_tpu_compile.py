@@ -825,6 +825,33 @@ def test_window_planes_decode_through_the_kernel_from_the_windows_first_page(
             assert f"bf16[1,{rows},{width}]" not in text
 
 
+def test_full_planes_score_their_index_keys_through_the_walk_kernel(
+        dots3_program):
+    """The decode program scores a full plane's index keys page by page in
+    place: ``paged_index_scores`` under ``kft.dsa_index``, once a full
+    plane, and no gather of a key tile of 2,048 positions (128 pages) for
+    the call's 16 rows; the chunk program (256 query columns a tile,
+    compute-bound) keeps the key tiles and holds no such kernel."""
+    _, compiled = dots3_program("decode_rounds")
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line
+             and "%paged_index_scores" in line.split(" = ")[0]]
+    assert len(calls) == 2
+    assert all("/kft.dsa_index/" in line.split("op_name=\"")[1].split(
+        "\"")[0] for line in calls)
+    # The stacked index pool, viewed a page a row, left where it is.
+    assert all("bf16[66496,16,128]" in line for line in calls)
+    for tile in ("bf16[2048,16,128]", "bf16[16,2048,128]",
+                 "bf16[16,128,16,128]", "f32[16,1,64,2048]",
+                 "f32[16,64,2048]"):
+        assert tile not in text, tile
+    # Its 16,384 (query, head) rows a call make a key tile 512 positions.
+    chunk = dots3_program("prefill_chunk_into_slot")[1].as_text()
+    assert "paged_index_scores" not in chunk
+    assert "bf16[32,16,128]" in chunk and "f32[1,256,64,512]" in chunk
+
+
 def _folded_table_entries(chip, entries, n):
     """``entries(tables, first, n)`` over a table and positions that are
     CONSTANTS of the program, compiled for the chip: (the s32 constants of
