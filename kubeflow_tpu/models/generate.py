@@ -30,6 +30,7 @@ from kubeflow_tpu.models.transformer import (
     Transformer,
     TransformerConfig,
     rope,
+    yarn_frequencies,
 )
 from kubeflow_tpu.ops.attention import dot_product_attention
 from kubeflow_tpu.ops.quantize import (
@@ -285,7 +286,7 @@ def _page_coordinates(tables, cache_len, write_cols, b, t, nb, bt):
 def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
                      cache_len, positions, pad_amount=None, write_cols=None,
                      tables=None, adapters=None, paged_kernel=False,
-                     plane=None):
+                     plane=None, store=True):
     """The attention half of a decoder block against the KV cache: norm,
     projections, cache write, attention, output projection, residual.
 
@@ -346,7 +347,9 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
         return _latent_attention_block(
             cfg, layer_params, x, cache_kv, cache_len, positions,
             write_cols=write_cols, tables=tables,
-            paged_kernel=paged_kernel, plane=plane)
+            paged_kernel=paged_kernel, plane=plane, store=store)
+    if not store:
+        raise ValueError("store=False: a latent layer's (see there)")
     attn = layer_params["attn"]
     dt = cfg.dtype
 
@@ -522,11 +525,16 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
     return x, (None if cache_kv is None else (ck, cv))
 
 
-def _rope_pairs(x, positions, theta):
+def _rope_pairs(x, positions, theta, yarn=None):
     """Rotary positions over interleaved pairs (2i, 2i + 1) of the last
-    axis (``transformer.rope`` pairs i with i + d / 2).  x [b, s, h, d]."""
+    axis (``transformer.rope`` pairs i with i + d / 2).  x [b, s, h, d].
+    ``yarn`` (``LatentSizes.yarn``): YaRN's frequencies, a constant of the
+    program computed in float64 (``transformer.yarn_frequencies``)."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    else:
+        freqs = jnp.asarray(yarn_frequencies(d, theta, yarn), jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -536,14 +544,16 @@ def _rope_pairs(x, positions, theta):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _latent_view_attention(q_row, view, value_lanes, kv_offset, scale):
+def _latent_view_attention(q_row, view, value_lanes, kv_offset, scale,
+                           first=0):
     """Attention of absorbed queries q_row [b, t, h, row] over a slot's
     gathered view of the latent pool [b, s, row] -> [b, t, h,
     value_lanes], in the latent space: a position's row is key (whole)
     and value (its first ``value_lanes`` lanes) for every head, so the
     heads fold into the rows of ONE product against the view.
     ``kv_offset`` (a scalar, or [b] per row): position of query column 0
-    among the view's.  Softmax in float32; in tiles of
+    among the view's; positions below ``first`` (static) are attended by
+    nobody.  Softmax in float32; in tiles of
     ``_VIEW_QUERY_TILE`` query rows where t holds several, as
     ``_view_attention`` (the float32 scores are [h, tile, s])."""
     dt = q_row.dtype
@@ -557,7 +567,8 @@ def _latent_view_attention(q_row, view, value_lanes, kv_offset, scale):
         sc = jnp.einsum("bmr,bkr->bmk", q.reshape(b, n * h, -1), view,
                         preferred_element_type=jnp.float32) * scale
         q_pos = jnp.asarray(offset)[..., None] + jnp.arange(n * h) // h
-        keep = jnp.arange(view.shape[1]) <= q_pos[..., None]
+        k_pos = jnp.arange(view.shape[1])
+        keep = (k_pos <= q_pos[..., None]) & (k_pos >= first)
         w = jax.nn.softmax(
             jnp.where(keep, sc, jnp.finfo(jnp.float32).min), axis=-1)
         out = jnp.einsum("bmk,bkc->bmc", w.astype(dt), values,
@@ -578,7 +589,7 @@ def _latent_view_attention(q_row, view, value_lanes, kv_offset, scale):
 
 
 def _tiled_latent_view_attention(q_row, rows_of, value_lanes, kv_offset,
-                                 scale, tile, visited, view):
+                                 scale, tile, visited, view, first=0):
     """``_latent_view_attention`` over ``visited`` (traced) key tiles of
     ``tile`` positions of a view ``view`` long; ``rows_of(i)`` gathers
     tile i [b, tile, row]."""
@@ -598,7 +609,8 @@ def _tiled_latent_view_attention(q_row, rows_of, value_lanes, kv_offset,
     out = _online_softmax(
         rows_of, scores_of, sums_of, (b, t * h), value_lanes,
         jnp.asarray(kv_offset)[..., None] + jnp.arange(t * h) // h, tile,
-        visited, view)
+        visited, view,
+        jnp.full((b, t * h), first, jnp.int32) if first else None)
     return out.astype(dt).reshape(b, t, h, value_lanes)
 
 
@@ -823,7 +835,7 @@ def _window_view_attention(q_row, pool, plane, tables, q_pos, window,
 def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
                             cache_len, positions, write_cols=None,
                             tables=None, paged_kernel=False, plane=None,
-                            kind="full_attention"):
+                            kind="full_attention", first_pos=0, store=True):
     """Latent attention (MLA, TransformerConfig.attention_kind) in the
     attention block's place: norm, projections, the write of the token's
     latent row, attention, output projection, residual.
@@ -872,16 +884,29 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
     what the whole-view forms would read, masked: the cost is what
     differs.  ``cfg.attn_gate`` scales each head's output by the sigmoid
     of a projection of the layer's normed input.
+
+    A decode step may hold TWO query positions a row (t == 2 over per-row
+    lengths: a drafting stack's last token and its draft,
+    ``TransformerConfig.mtp_layers``), each under its own frontier; the
+    kernel then takes both as rows of one call.  ``first_pos`` (static):
+    positions below it are attended by nobody (the draft layer's plane
+    holds nothing at index 0).  ``store`` False: the call's rows lie in
+    the pool already and the pool is not written (a position recomputed
+    over a shared page).  The rotary positions are ``positions``, which
+    need not be the rows' indices ``cache_len`` + column.
     """
     attn = layer_params["attn"]
     dt = cfg.dtype
     z = cfg.latent_sizes(kind)
     e, rkv, dn, dr, heads = (cfg.d_model, z.kv_rank, z.nope_dim,
                              z.rope_dim, z.heads)
-    up_q, up_kv = (e / z.q_rank) ** 0.5, (e / rkv) ** 0.5
+    up_q, up_kv = ((e / z.q_rank) ** 0.5, (e / rkv) ** 0.5) \
+        if cfg.mla_rescale else (1.0, 1.0)
     b, t = x.shape[:2]
-    scale = (dn + dr) ** -0.5
+    scale = (dn + dr) ** -0.5 * cfg.mla_softmax_mult
     wk_b, wv_b = attn["wk_b"], attn["wv_b"]
+    # What ``_rope_pairs`` takes after the positions.
+    turns = (z.rope_theta,) if z.yarn is None else (z.rope_theta, z.yarn)
     indexed = cfg.indexed and kind == "full_attention"
     if cache is not None:
         cache, sides = list(cache), latent_sides(cfg)
@@ -894,23 +919,24 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
         qa = _rms_norm(qa, attn["q_norm"]["scale"] * up_q, cfg.norm_eps, dt)
         q = qeinsum("bsr,rhd->bshd", qa, attn["wq_b"], dt)
         q_nope = q[..., :dn]
-        q_rope = _rope_pairs(q[..., dn:], positions, z.rope_theta)
+        q_rope = _rope_pairs(q[..., dn:], positions, *turns)
     with jax.named_scope("kft.mla_latent_write"):
         kva = qeinsum("bse,ec->bsc", y, attn["wkv_a"], dt)
         c = _rms_norm(kva[..., :rkv], attn["kv_norm"]["scale"] * up_kv,
                       cfg.norm_eps, dt)
         k_r = _rope_pairs(kva[:, :, None, rkv:], positions,
-                          z.rope_theta)[:, :, 0]
+                          *turns)[:, :, 0]
         if cache is not None:
             pool = cache[side]
             nb, bt, width = pool.shape[1:]
             mb, pad = tables.shape[1], width - rkv - dr
-            row = jnp.concatenate(
-                [c, k_r, jnp.zeros((b, t, pad), dt)], axis=-1)
             blk, off, base = _page_coordinates(
                 tables, cache_len, write_cols, b, t, nb, bt)
-            pool = cache[side] = pool.at[plane, blk, off].set(
-                row.astype(pool.dtype), mode="drop")
+            if store:
+                row = jnp.concatenate(
+                    [c, k_r, jnp.zeros((b, t, pad), dt)], axis=-1)
+                pool = cache[side] = pool.at[plane, blk, off].set(
+                    row.astype(pool.dtype), mode="drop")
     if indexed:
         with jax.named_scope("kft.dsa_index"):
             q_idx = _rope_leading(
@@ -961,6 +987,9 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
                 [qeinsum("bkc,hdc->bkhd", c, wk_b, dt), jnp.broadcast_to(
                     k_r[:, :, None], (b, t, heads, dr))], axis=-1)
             full = jnp.concatenate([q_nope, q_rope], axis=-1)
+            if cfg.mla_softmax_mult != 1.0 and not (z.window or indexed):
+                # dot_product_attention scales by the head's width alone.
+                full = (full * cfg.mla_softmax_mult).astype(dt)
             if not (z.window or indexed):
                 out = dot_product_attention(
                     full, k, qeinsum("bkc,chd->bkhd", c, wv_b, dt),
@@ -975,7 +1004,8 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
                     qeinsum("bkc,chd->bkhd", c, wv_b, dt),
                     preferred_element_type=jnp.float32).astype(dt)
     else:
-        decode = t == 1 and base is not None
+        decode = base is not None and (
+            t == 1 or (t == 2 and not indexed and not z.window))
         # Position of the call's query columns among its slots' own.
         q_pos = jnp.reshape(cache_len, (-1, 1)) + jnp.arange(t)[None, :]
         q_pos = jnp.broadcast_to(q_pos, (b, t))
@@ -988,9 +1018,10 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
         if decode and paged_kernel:
             from kubeflow_tpu.ops import paged_attention
 
-            # The step's own row (and index key) is in the pool already;
-            # a parked write marks a retired row, which reads nothing.
-            attend = jnp.where(base < mb * bt, cache_len + 1, 0)
+            # The step's own rows (and index key) are in the pool
+            # already: its last query sees cache_len + t positions; a
+            # parked write marks a retired row, which reads nothing.
+            attend = jnp.where(base < mb * bt, cache_len + t, 0)
         if indexed:
             with jax.named_scope("kft.dsa_index"):
                 if decode and paged_kernel and index_keys_walked(keys):
@@ -1039,23 +1070,27 @@ def _latent_attention_block(cfg: TransformerConfig, layer_params, x, cache,
             with jax.named_scope(
                     "kft.mla_decode" if decode else "kft.mla_prefill"):
                 if decode and paged_kernel:
+                    # Both positions of a drafting step as rows of one
+                    # call: a page is copied once for the two.
                     ot = paged_attention.paged_latent_decode_attention(
-                        q_row[:, 0], pool, plane, tables, attend, rkv,
-                        scale)[:, None]
+                        q_row.reshape(b, t * heads, width), pool, plane,
+                        tables, attend, rkv, scale, queries=t,
+                        first=first_pos).reshape(b, t, heads, rkv)
                 else:
                     held = _held_key_tiles(tables, bt, t, cache_len)
                     if held is None:
                         ot = _latent_view_attention(
                             q_row,
                             pool[plane, tables].reshape(b, mb * bt, width),
-                            rkv, cache_len, scale)
+                            rkv, cache_len, scale, first_pos)
                     else:
                         tile, visited, pages_of = held
                         ot = _tiled_latent_view_attention(
                             q_row,
                             lambda i: pool[plane, pages_of(i)].reshape(
                                 b, tile, width),
-                            rkv, cache_len, scale, tile, visited, mb * bt)
+                            rkv, cache_len, scale, tile, visited, mb * bt,
+                            first_pos)
         with jax.named_scope(
                 "kft.mla_decode" if decode else "kft.mla_prefill"):
             out = qeinsum("bshc,chd->bshd", ot, wv_b, dt)
@@ -1170,7 +1205,9 @@ def _experts(cfg: TransformerConfig, moe, y, live=None):
 
     The router (TransformerConfig.moe_score / moe_normalize / moe_scale)
     scores every output in float32, ``moe/bias`` selects and does not
-    weigh.  Every (row, chosen expert) pair whose expert this program
+    weigh; with ``moe_groups`` the choice falls inside the
+    ``moe_groups_kept`` groups of largest score, whichever chip holds
+    their experts.  Every (row, chosen expert) pair whose expert this program
     HOLDS (``moe_experts_offset``, ``moe_held``) is sorted by expert and
     the experts' SwiGLUs run as two grouped products
     (``jax.lax.ragged_dot``) over the stacked expert matrices where they
@@ -1200,10 +1237,25 @@ def _experts(cfg: TransformerConfig, moe, y, live=None):
         scores = jax.nn.sigmoid(scores) if cfg.moe_score == "sigmoid" \
             else jax.nn.softmax(scores, axis=-1)
         # The bias selects and does not weigh.
-        _, chosen = jax.lax.top_k(scores + moe["bias"], k)
+        select = scores + moe["bias"]
+        if cfg.moe_groups:
+            with jax.named_scope("kft.moe_groups"):
+                # A group scores the sum of its 2 largest; the choice
+                # falls inside the groups kept (ties to the lower group,
+                # as top_k's to the lower index).
+                groups = cfg.moe_groups
+                of_group = jax.lax.top_k(
+                    select.reshape(m, groups, n // groups), 2)[0].sum(-1)
+                _, kept = jax.lax.top_k(of_group, cfg.moe_groups_kept)
+                kept = jnp.zeros((m, groups), bool).at[
+                    jnp.arange(m)[:, None], kept].set(True)
+                select = jnp.where(
+                    jnp.repeat(kept, n // groups, axis=1), select, -jnp.inf)
+        _, chosen = jax.lax.top_k(select, k)
         gates = jnp.take_along_axis(scores, chosen, axis=1)
         if cfg.moe_normalize:
-            gates = gates / (gates.sum(axis=1, keepdims=True) + 1e-6)
+            gates = gates / (gates.sum(axis=1, keepdims=True)
+                             + cfg.moe_norm_eps)
         if cfg.moe_scale != 1.0:
             gates = gates * cfg.moe_scale
         pairs = chosen.reshape(-1)
@@ -1324,24 +1376,31 @@ def _final_norm(cfg: TransformerConfig, params, x):
                      cfg.dtype)
 
 
-def _logits(cfg: TransformerConfig, params, x):
+def _head(cfg: TransformerConfig, params, normed):
+    """The head over a normed stream -> float32 logits."""
     dt = cfg.dtype
+    if cfg.tied_embeddings:
+        logits = qeinsum("bse,ve->bsv", normed, params["embed"], dt)
+    else:
+        logits = qeinsum("bse,ev->bsv", normed, params["w_out"], dt)
+    return logits.astype(jnp.float32)
+
+
+def _logits(cfg: TransformerConfig, params, x):
     with jax.named_scope("kft.logits"):
-        x = _final_norm(cfg, params, x)
-        if cfg.tied_embeddings:
-            logits = qeinsum("bse,ve->bsv", x, params["embed"], dt)
-        else:
-            logits = qeinsum("bse,ev->bsv", x, params["w_out"], dt)
-        return logits.astype(jnp.float32)
+        return _head(cfg, params, _final_norm(cfg, params, x))
 
 
 def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
                         cache_len=0, write_cols=None, tables=None,
                         paged_kernel=False, conv=None, rows=None,
-                        fresh=None, n_new=None):
+                        fresh=None, n_new=None, hidden=False, store=True):
     """The forward of a stack that states its ``layer_types``
     (TransformerConfig): tokens [b, t] -> (logits [b, t, v], cache,
-    conv, the expert layers' counts).
+    conv, the expert layers' counts).  ``hidden`` (static): the stream
+    after the final norm [b, t, e] in the logits' place (what a draft
+    module reads, and the head's input: ``_head``).  ``store``: as
+    ``_latent_attention_block``'s.
 
     The layers are walked one by one over ``params["layers"][str(i)]``:
     no two need have the same leaves, every matrix is an array of its
@@ -1400,14 +1459,59 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
             x, cache = _attention_block(
                 cfg, layer_params, x, cache, cache_len, positions,
                 write_cols=write_cols, tables=tables,
-                paged_kernel=paged_kernel, plane=plane)
+                paged_kernel=paged_kernel, plane=plane, store=store)
             plane += 1
         if cfg.layer_is_sparse(i):
             x, n = _sparse_ff(cfg, layer_params, x, live)
             counts = count(n)
         else:
             x = _dense_ff(cfg, layer_params, x)
+    if hidden:
+        with jax.named_scope("kft.logits"):
+            return _final_norm(cfg, params, x), cache, conv, counts
     return _logits(cfg, params, x), cache, conv, counts
+
+
+def mtp_logits(cfg: TransformerConfig, params, hidden, tokens, cache,
+               cache_len, positions, live=None, write_cols=None,
+               tables=None, paged_kernel=False):
+    """The multi-token-prediction module (TransformerConfig.mtp_layers)
+    over rows ``(h_i, t_{i+1})``: ``hidden`` [b, t, e], the main stack's
+    stream after its final norm at positions i, and ``tokens`` [b, t],
+    the tokens AFTER them -> (float32 logits [b, t, v] that predict
+    t_{i+2}, cache, the expert layer's counts).
+
+        z = [N_e(Emb(t_{i+1})); N_h(h_i)] W_eh
+        z' = Block(z)   (latent attention over the rows before, experts)
+        logits = Head(N_s(z'))      (embedding and head the main model's)
+
+    The layer's latent rows lie in the LAST plane of ``cache``'s latent
+    pool at index i + 1: ``cache_len`` (a scalar, or [b]) is the index of
+    column 0, ``positions`` [b, t] its rotary positions (i, not the
+    index: the shift is the layout's, not the model's), and index 0 is
+    attended by nobody.  ``live`` [b, t] bool: rows that are no token
+    choose no expert.  ``cache`` None: rows over the call's own columns
+    alone, no pool (the plain forward of a whole sequence)."""
+    from flax import linen as nn
+
+    params = nn.unbox(params)
+    mtp, dt = params["mtp"], cfg.dtype
+    with jax.named_scope("kft.embed"):
+        emb = embed_lookup(params["embed"], tokens, dt)
+    with jax.named_scope("kft.mtp_proj"):
+        z = jnp.concatenate([
+            _rms_norm(emb, mtp["enorm"]["scale"], cfg.norm_eps, dt),
+            _rms_norm(hidden.astype(dt), mtp["hnorm"]["scale"],
+                      cfg.norm_eps, dt)], axis=-1)
+        z = qeinsum("bsf,fe->bse", z, mtp["eh_proj"], dt)
+    z, cache = _latent_attention_block(
+        cfg, mtp["layer"], z, cache, cache_len, positions,
+        write_cols=write_cols, tables=tables, paged_kernel=paged_kernel,
+        plane=cfg.kv_planes - 1, first_pos=1)
+    z, counts = _sparse_ff(cfg, mtp["layer"], z, live)
+    with jax.named_scope("kft.logits"):
+        return _head(cfg, params, _rms_norm(
+            z, mtp["norm"]["scale"], cfg.norm_eps, dt)), cache, counts
 
 
 def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
@@ -1781,6 +1885,10 @@ def init_paged_state(cfg: TransformerConfig, slots: int,
     only.  Every pool has the same blocks of the same positions: ONE
     block table a slot serves them all, so a page is allocated, aliased
     and freed in all of them at once.
+
+    A drafting stack (``cfg.mtp_layers``) adds ``mtp_draft`` [slots],
+    ``mtp_hidden`` [slots, e] and ``mtp_counts`` [3] (see there); its
+    draft layer's rows are the last plane of ``cache_latent``.
     """
     if cfg.latent:
         if kv_cache_dtype != "model":
@@ -1804,6 +1912,15 @@ def init_paged_state(cfg: TransformerConfig, slots: int,
             cfg.dtype)
     if cfg.layer_types and cfg.moe_experts:
         extra["moe_touched"] = jnp.zeros((), jnp.int32)
+    if cfg.mtp_layers:
+        # A slot's draft of the token after ``last_token``, the main
+        # stack's normed stream at the last position a chunk filled
+        # (what the next chunk's first draft row reads), and the LAST
+        # decode_rounds call's (drafts verified, drafts taken, positions
+        # the later of a step's two rows saw, summed over live slots).
+        extra["mtp_draft"] = jnp.zeros((slots,), jnp.int32)
+        extra["mtp_hidden"] = jnp.zeros((slots, cfg.d_model), cfg.dtype)
+        extra["mtp_counts"] = jnp.zeros((3,), jnp.int32)
     return {
         **extra,
         **pool,
@@ -1887,6 +2004,99 @@ def gather_kv_pages(state, ids):
     return gather(state["cache_k"]), gather(state["cache_v"])
 
 
+def _count_experts(state, counts):
+    """``state`` with an expert layer's ``counts`` added to what the
+    call has counted so far."""
+    state = dict(state)
+    if "moe_touched" in state:
+        state["moe_touched"] = state["moe_touched"] + counts["touched"]
+    if "moe_pairs" in state:
+        state["moe_pairs"] = state["moe_pairs"] + jnp.stack(
+            [counts["held"], counts["zero"], counts["absent"]])
+    return state
+
+
+def _advance_slots_drafting(cfg: TransformerConfig, params,
+                            decode: DecodeConfig, tables: jax.Array, park,
+                            state, paged_kernel=False):
+    """``_advance_slots`` of a stack whose multi-token-prediction module
+    drafts (TransformerConfig.mtp_layers), greedy: one step that
+    VERIFIES a draft and makes the next, and yields one token or two.
+
+    A live slot holds its last token t_n (frontier n = ``lengths``) and
+    a draft d of t_{n+1}.  The stack runs the rows [t_n, d] at positions
+    n, n + 1, each under its own frontier; g = argmax of the first.  If
+    g == d the second row stood on the right token: the step emits g
+    and g' = argmax of the second and the frontier moves by 2; else it
+    emits g and the frontier moves by 1 (row n + 1 of every plane is
+    overwritten by the next step before anything attends it: a length
+    reset, as ``verify_step``'s).  A budget or an EOS met by the first
+    token of a pair cuts the second.  The module then runs the rows
+    (h_n, g) and, if taken, (h_{n+1}, g') at indices n + 1, n + 2 of its
+    plane, and the draft the slot keeps is the argmax of the last real
+    one.  Nothing goes to the host between verifying and drafting.
+
+    Returns (state, first [S], second [S], emitted [S]): the tokens and
+    how many of the two are real (0 for a retired slot)."""
+    if decode.temperature > 0.0:
+        raise ValueError(
+            "a stack whose multi-token-prediction module drafts "
+            "(mtp_layers) decodes greedily: a draft is taken where it is "
+            "the stack's own first choice (sampling with a draft: not "
+            "built)")
+    lengths, done = state["lengths"], state["done"]
+    sides = pool_sides(state)
+    advance = ~done
+    write_cols = jnp.where(advance, lengths, park)
+    rows = jnp.stack([state["last_token"], state["mtp_draft"]], axis=1)
+    hidden, cache, _, counts = forward_layer_types(
+        cfg, params, rows, tuple(state[side] for side in sides), lengths,
+        write_cols=write_cols, tables=tables, paged_kernel=paged_kernel,
+        n_new=2 * advance.astype(jnp.int32), hidden=True)
+    state = _count_experts(state, counts)
+    with jax.named_scope("kft.logits"):
+        logits = _head(cfg, params, hidden)
+    with jax.named_scope("kft.mtp_accept"):
+        targets = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, 2]
+        first, second = targets[:, 0], targets[:, 1]
+        taken = advance & (first == state["mtp_draft"])
+        emit = jnp.minimum(jnp.where(taken, 2, 1),
+                           jnp.maximum(state["stop_len"] - lengths, 0))
+        stop = jnp.zeros_like(done)
+        if decode.eos_token >= 0:
+            emit = jnp.where(first == decode.eos_token,
+                             jnp.minimum(emit, 1), emit)
+            stop = (first == decode.eos_token) | (
+                (emit == 2) & (second == decode.eos_token))
+        emit = jnp.where(advance, emit, 0)
+        pair = emit == 2
+        new_lengths = lengths + emit
+        new_done = done | (advance & (stop | (
+            new_lengths >= state["stop_len"])))
+        first = jnp.where(advance, first, 0)
+        second = jnp.where(pair, second, 0)
+    with jax.named_scope("kft.mtp_draft"):
+        # Row (h_i, t_{i+1}) lies at index i + 1 of the draft plane.
+        drafts, cache, counts = mtp_logits(
+            cfg, params, hidden, jnp.stack([first, second], axis=1), cache,
+            lengths + 1, lengths[:, None] + jnp.arange(2)[None, :],
+            live=jnp.stack([advance, pair], axis=1),
+            write_cols=jnp.where(advance, lengths + 1, park),
+            tables=tables, paged_kernel=paged_kernel)
+        drafts = jnp.argmax(drafts, axis=-1).astype(jnp.int32)
+    state = _count_experts(state, counts)
+    state.update(zip(sides, cache))
+    state["lengths"] = new_lengths
+    state["last_token"] = jnp.where(
+        advance, jnp.where(pair, second, first), state["last_token"])
+    state["done"] = new_done
+    state["mtp_draft"] = jnp.where(pair, drafts[:, 1], drafts[:, 0])
+    state["mtp_counts"] = state["mtp_counts"] + jnp.stack(
+        [jnp.sum(advance), jnp.sum(taken),
+         jnp.sum(jnp.where(advance, lengths + 2, 0))]).astype(jnp.int32)
+    return state, first, second, emit
+
+
 def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
                    tables: jax.Array, park, state, paged_kernel=False):
     """One batched decode step over every slot, ``decode_rounds``'s
@@ -1917,14 +2127,10 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
             write_cols=write_cols, tables=tables,
             paged_kernel=paged_kernel, conv=state.get("conv"),
             n_new=advance.astype(jnp.int32))
-        state = dict(state)
+        state = dict(state) if counts is None \
+            else _count_experts(state, counts)
         if conv is not None:
             state["conv"] = conv
-        if "moe_touched" in state:
-            state["moe_touched"] = state["moe_touched"] + counts["touched"]
-        if "moe_pairs" in state:
-            state["moe_pairs"] = state["moe_pairs"] + jnp.stack(
-                [counts["held"], counts["zero"], counts["absent"]])
     else:
         logits, cache = _forward_with_cache(
             cfg, params, state["last_token"][:, None],
@@ -1969,7 +2175,8 @@ def decode_rounds(cfg: TransformerConfig, params, state,
     per-step dispatch, and a round that finishes all slots at step 3
     stops at step 3 instead of burning k-3 dead forwards.
 
-    Returns ``(state, toks, counts, steps_run)``:
+    Returns ``(state, toks, counts, steps_run)`` and, for a drafting
+    stack (below), ``drafts`` after them:
 
     - ``toks`` [S, k] int32, slot-major: slot s's tokens for this
       round occupy ``toks[s, :counts[s]]`` contiguously (a live slot
@@ -1988,10 +2195,21 @@ def decode_rounds(cfg: TransformerConfig, params, state,
     before dispatch.  Per-step math is ``_advance_slots``, so greedy
     tokens do not depend on how the steps are cut into rounds.
     ``paged_kernel``: as there.
+
+    A stack whose multi-token-prediction module drafts
+    (``cfg.mtp_layers``) takes ``_advance_slots_drafting`` as its step: a
+    slot then emits one token or two a step, ``toks`` is [S, 2 k] (slot
+    s's tokens still contiguous in ``toks[s, :counts[s]]``), the host
+    covers 2 k + 1 positions a slot, ``state["mtp_counts"]`` holds the
+    call's drafts verified and taken, and ``drafts`` [S, 2 k] the draft
+    each token of ``toks`` was held against (-1 for the second of a
+    pair, which was held against none).
     """
     park = tables.shape[1] * _pool_block_tokens(state)
     slots = state["done"].shape[0]
     len0 = state["lengths"]
+    if cfg.mtp_layers:
+        state = dict(state, mtp_counts=jnp.zeros((3,), jnp.int32))
     if "moe_touched" in state:
         state = dict(state, moe_touched=jnp.zeros((), jnp.int32))
     if "moe_pairs" in state:
@@ -2005,15 +2223,32 @@ def decode_rounds(cfg: TransformerConfig, params, state,
 
     def body(carry):
         i, state, out = carry
+        if cfg.mtp_layers:
+            at = state["lengths"] - len0     # emitted so far, a slot
+            held = state["mtp_draft"]
+            state, first, second, emit = _advance_slots_drafting(
+                cfg, params, decode, tables, park, state, paged_kernel)
+            row = jnp.arange(slots)
+            at = jnp.where(emit > 0, at, 2 * k)
+            out = out.at[0, row, at].set(first, mode="drop")
+            out = out.at[1, row, at].set(held, mode="drop")
+            out = out.at[0, row, jnp.where(emit > 1, at + 1, 2 * k)].set(
+                second, mode="drop")
+            return i + 1, state, out
         state, nxt = _advance_slots(cfg, params, decode, tables, park,
                                     state, paged_kernel)
         return i + 1, state, out.at[:, i].set(nxt)
 
+    # A drafting stack's second plane: the draft each token was held
+    # against, -1 where none was (the second of a pair).
+    out0 = jnp.zeros((slots, k), jnp.int32) if not cfg.mtp_layers \
+        else jnp.stack([jnp.zeros((slots, 2 * k), jnp.int32),
+                        jnp.full((slots, 2 * k), -1, jnp.int32)])
     steps_run, state, toks = jax.lax.while_loop(
-        cond, body,
-        (jnp.zeros((), jnp.int32), state,
-         jnp.zeros((slots, k), jnp.int32)))
+        cond, body, (jnp.zeros((), jnp.int32), state, out0))
     counts = state["lengths"] - len0
+    if cfg.mtp_layers:
+        return state, toks[0], counts, steps_run, toks[1]
     return state, toks, counts, steps_run
 
 
@@ -2110,6 +2345,70 @@ def verify_step(cfg: TransformerConfig, params, state,
     return state, out, emit.astype(jnp.int32)
 
 
+def _drafting_chunk(cfg, params, state, decode, tokens, start, prompt_len,
+                    new_tokens, slot, table_row, prev_token):
+    """``prefill_chunk_into_slot`` of a stack whose multi-token-
+    prediction module drafts (see there: ``prev_token``), greedy."""
+    if decode.temperature > 0.0:
+        raise ValueError("mtp_layers with a temperature: not built")
+    slots_n = state["done"].shape[0]
+    w = tokens.shape[1]
+    sides = pool_sides(state)
+    cache = tuple(state[side] for side in sides)
+    real = jnp.reshape(jnp.clip(prompt_len - start, 0, w), (1,))
+
+    def recomputed():
+        return forward_layer_types(
+            cfg, params, jnp.reshape(prev_token, (1, 1)), cache, start - 1,
+            tables=table_row, hidden=True, store=False)[0]
+
+    before = jax.lax.cond(
+        prev_token >= 0, recomputed,
+        lambda: state["mtp_hidden"][slot][None, None])
+    hidden, cache, _, _ = forward_layer_types(
+        cfg, params, tokens, cache, start, tables=table_row, n_new=real,
+        hidden=True)
+    with jax.named_scope("kft.sample"):
+        idx = jnp.clip(prompt_len - 1 - start, 0, w - 1)
+        last = jax.lax.dynamic_slice_in_dim(hidden, idx, 1, axis=1)
+        with jax.named_scope("kft.logits"):
+            tok = jnp.argmax(_head(cfg, params, last)[:, 0],
+                             axis=-1).astype(jnp.int32)          # [1]
+    with jax.named_scope("kft.mtp_fill"):
+        # Index i of the draft plane: token i, the stream at i - 1.
+        index = start + jnp.arange(w)
+        _, cache, _ = mtp_logits(
+            cfg, params,
+            jnp.concatenate([before.astype(hidden.dtype), hidden[:, :-1]],
+                            axis=1),
+            tokens, cache, start, (index - 1)[None],
+            live=((index >= 1) & (index < prompt_len))[None],
+            tables=table_row)
+        # The row of (h_{p-1}, first token) at index p, whose argmax is
+        # the slot's first draft; its write is past a chunk that is not
+        # the last and is overwritten by the one that is.
+        drafts, cache, _ = mtp_logits(
+            cfg, params, last, tok[:, None], cache, prompt_len,
+            jnp.reshape(prompt_len - 1, (1, 1)), tables=table_row)
+        draft = jnp.argmax(drafts[0, 0]).astype(jnp.int32)
+    with jax.named_scope("kft.sample"):
+        is_last = (start + w) >= prompt_len
+        final_slot = jnp.where(is_last, slot, slots_n)
+        done_final = new_tokens <= 1
+        if decode.eos_token >= 0:
+            done_final = done_final | (tok[0] == decode.eos_token)
+        state = dict(state, **dict(zip(sides, cache)))
+        state["mtp_hidden"] = state["mtp_hidden"].at[slot].set(
+            hidden[0, -1].astype(state["mtp_hidden"].dtype))
+        state["done"] = state["done"].at[slot].set(True)
+        for key, value in (
+                ("done", done_final), ("lengths", prompt_len),
+                ("stop_len", prompt_len + jnp.maximum(new_tokens, 1) - 1),
+                ("last_token", tok[0]), ("mtp_draft", draft)):
+            state[key] = state[key].at[final_slot].set(value, mode="drop")
+    return state, tok
+
+
 @partial(jax.jit, static_argnums=(0, 3), donate_argnums=(2,))
 def prefill_chunk_into_slot(
     cfg: TransformerConfig,
@@ -2124,6 +2423,7 @@ def prefill_chunk_into_slot(
     seed: jax.Array,
     table_row: jax.Array,
     adapter_id: Optional[jax.Array] = None,
+    prev_token: Optional[jax.Array] = None,
 ):
     """Extend slot ``slot``'s KV by one static-width chunk of prompt
     starting at traced cache offset ``start``; returns
@@ -2138,6 +2438,19 @@ def prefill_chunk_into_slot(
     slot, so an interleaved step reads a harmless id from a frozen
     row.  Omitted/None means base (0) and traces a separate program —
     engines without an adapter stack never pay the operand.
+
+    prev_token (traced int32 scalar; a drafting stack's,
+    ``cfg.mtp_layers``): the chunk also fills the draft layer's plane
+    (``kft.mtp_fill``): the row at index i embeds token i and reads the
+    main stack's normed stream at position i - 1, so the chunk's first
+    row needs the stream ONE POSITION BACK, which no pool holds.  A
+    slot's chunks hand it on in ``state["mtp_hidden"]``; where nothing
+    ran before this chunk in this slot (the first chunk after a prefix
+    hit) ``prev_token`` >= 0 is the prompt's token at ``start`` - 1 and
+    that one position is recomputed over the slot's pages WITHOUT
+    writing a row (a shared page is never written); -1 takes the
+    state's.  The final chunk also runs the row (h_{p-1}, first token)
+    at index p and arms the slot's first draft with its argmax.
 
     tokens [1, chunk_w]: the prompt's tokens [start, start + chunk_w),
     right-padded past ``prompt_len`` on the final chunk.  table_row
@@ -2171,6 +2484,10 @@ def prefill_chunk_into_slot(
     chunk of every admission at claim time, before any step program
     can run.
     """
+    if cfg.mtp_layers:
+        return _drafting_chunk(cfg, params, state, decode, tokens, start,
+                               prompt_len, new_tokens, slot, table_row,
+                               prev_token)
     slots_n = state["done"].shape[0]
     w = tokens.shape[1]
     aid = (jnp.zeros((), jnp.int32) if adapter_id is None

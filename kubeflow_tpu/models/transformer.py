@@ -23,6 +23,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from kubeflow_tpu.ops.attention import dot_product_attention
@@ -211,7 +212,7 @@ class TransformerConfig:
     # ``mla_nope_dim`` + ``mla_rope_dim`` wide, a value head
     # ``mla_v_dim``; rotary pairs are interleaved (2i, 2i + 1); the two
     # low-rank activations are scaled by sqrt(d_model / rank) after
-    # their norms (the one form built).  The cache of a token and plane is
+    # their norms (``mla_rescale``).  The cache of a token and plane is
     # ``(latent, rotary key)``, key and value at once: ONE pool
     # ``cache_latent`` [planes, blocks, block_tokens, latent_row] in
     # place of a k and a v pool (``latent_row``: the two parts, padded
@@ -258,6 +259,46 @@ class TransformerConfig:
     # sparse layer (``moe/shared``), added unweighted; it belongs to the
     # chip that owns the token (in a sum over shares it counts once).
     moe_shared_d_ff: int = 0
+    # The factor sqrt(d_model / rank) on both low-rank activations of a
+    # latent layer, after their norms (LongCat-Flash, dots3); False:
+    # the norms alone (DeepSeek-V3).
+    mla_rescale: bool = True
+    # YaRN on a latent layer's rotary frequencies (``yarn_factor`` > 1):
+    # pair i of ``mla_rope_dim`` / 2 turns at f_i = rope_theta^(-2i/d)
+    # below ``low`` and at f_i / yarn_factor above ``high``, a linear
+    # ramp between them, where low / high are the floor / ceiling of
+    # d ln(yarn_original_len / (2 pi beta)) / (2 ln rope_theta) at
+    # beta = yarn_beta_fast / yarn_beta_slow (``yarn_frequencies``).
+    # ``mla_softmax_mult`` multiplies the softmax scale of a latent
+    # layer (YaRN's (0.1 mscale_all_dim ln(factor) + 1)^2).
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    mla_softmax_mult: float = 1.0
+    # Expert groups (DeepSeek-V3's ``n_group`` / ``topk_group``): the
+    # router's ``moe_experts`` outputs are ``moe_groups`` runs of
+    # consecutive experts; a group scores the sum of its 2 largest
+    # (score + bias), the ``moe_groups_kept`` groups of largest score are
+    # kept (ties to the lower group), and the ``moe_top_k`` are chosen
+    # inside them.  0: no groups.  ``moe_norm_eps`` is what the sum of the
+    # chosen scores gains before it divides them (``moe_normalize``).
+    moe_groups: int = 0
+    moe_groups_kept: int = 0
+    moe_norm_eps: float = 1e-6
+    # Multi-token-prediction modules (DeepSeek-V3's
+    # ``num_nextn_predict_layers``; 0 or 1) that DRAFT in the serving
+    # engine: ``mtp`` in the tree holds two norms, a projection of
+    # [N_e(Emb(t_{i+1})); N_h(h_i)] (h_i after the final norm), one more
+    # latent + expert layer and a norm before the main model's head; its
+    # logits at i predict t_{i+2}.  The layer owns the LAST plane of the
+    # latent pool, and keeps the row of (h_i, t_{i+1}) at index i + 1,
+    # the position of the token it embeds (index 0 holds nothing and is
+    # never attended), so that a page of it is a function of the page's
+    # tokens and those before, as every other plane's.  A decode step
+    # then runs the stack over TWO positions a slot, the last token and
+    # the draft, and yields one token or two (models/generate.py).
+    mtp_layers: int = 0
 
     def __post_init__(self):
         # Latent attention reads neither n_kv_heads nor head_dim.
@@ -314,6 +355,48 @@ class TransformerConfig:
             raise ValueError(
                 "a shortcut_double layer with an indexer, a gate or a "
                 "shared expert: not built")
+        if not self.mla_rescale and not self.latent:
+            raise ValueError("mla_rescale belongs to latent layers: "
+                             "attention_kind='latent'")
+        yarn = self.yarn_factor != 1.0 or self.mla_softmax_mult != 1.0
+        if yarn and (
+                not self.latent or self.indexed or self.window_planes
+                or "shortcut_double" in self.layer_types
+                or self.yarn_factor < 1.0 or self.yarn_original_len < 1
+                or not 0 < self.yarn_beta_slow < self.yarn_beta_fast):
+            raise ValueError(
+                "YaRN frequencies (yarn_factor >= 1, yarn_original_len, "
+                "yarn_beta_fast > yarn_beta_slow > 0) and mla_softmax_mult "
+                "belong to the full_attention layers of a latent stack; "
+                "with gqa attention, an indexer, sliding_attention or "
+                "shortcut_double layers: not built")
+        if self.moe_groups or self.moe_groups_kept:
+            groups, kept = self.moe_groups, self.moe_groups_kept
+            if not self.layer_types or self.moe_zero_experts \
+                    or groups < 1 or not 1 <= kept <= groups \
+                    or self.moe_experts % groups \
+                    or self.moe_experts // groups < 2 \
+                    or kept * (self.moe_experts // groups) < self.moe_top_k:
+                raise ValueError(
+                    f"moe_groups={groups} with moe_groups_kept={kept}: "
+                    f"groups divide the {self.moe_experts} experts of a "
+                    "stack with layer_types into runs of 2 or more, the "
+                    f"kept ones hold moe_top_k={self.moe_top_k} or more; "
+                    "with zero-compute experts: not built")
+        if self.mtp_layers:
+            other = [name for name, on in (
+                ("an indexer", self.indexed),
+                ("sliding_attention layers", self.window_planes),
+                ("shortcut_double layers",
+                 "shortcut_double" in self.layer_types),
+                ("a convolution state", "conv" in self.layer_types),
+                ("an attention gate", self.attn_gate)) if on]
+            if self.mtp_layers != 1 or not self.latent \
+                    or not self.moe_experts or other:
+                raise ValueError(
+                    f"mtp_layers={self.mtp_layers}: ONE multi-token-"
+                    "prediction module drafts for a latent stack with "
+                    f"experts; with {other or 'anything else'}: not built")
         if self.moe_zero_experts or self.moe_experts_held \
                 or self.moe_experts_offset:
             held, first = self.moe_experts_held, self.moe_experts_offset
@@ -386,11 +469,12 @@ class TransformerConfig:
     @property
     def kv_planes(self) -> int:
         """Leading axis of a KV cache: one plane per (loop step, layer),
-        of the layers that attend."""
+        of the layers that attend; the draft layer's (``mtp_layers``) is
+        the last."""
         if self.layer_types:
             return self.layer_types.count("full_attention") \
                 + 2 * self.layer_types.count("shortcut_double") \
-                + self.window_planes
+                + self.window_planes + self.mtp_layers
         return self.loop_steps * self.n_layers
 
     @property
@@ -411,10 +495,13 @@ class TransformerConfig:
                 self.window_heads, self.window_q_rank, self.window_kv_rank,
                 self.window_nope_dim, self.window_rope_dim,
                 self.window_v_dim, self.window_rope_theta, self.window)
+        yarn = None if self.yarn_factor == 1.0 else (
+            self.yarn_factor, self.yarn_original_len, self.yarn_beta_fast,
+            self.yarn_beta_slow)
         return LatentSizes(
             self.n_heads, self.mla_q_rank, self.mla_kv_rank,
             self.mla_nope_dim, self.mla_rope_dim, self.mla_v_dim,
-            self.rope_theta, 0)
+            self.rope_theta, 0, yarn)
 
     @property
     def latent_row(self) -> int:
@@ -486,6 +573,8 @@ class LatentSizes:
     v_dim: int
     rope_theta: float
     window: int        # 0: every position up to the query's
+    # None, or YaRN's (factor, original length, beta_fast, beta_slow).
+    yarn: Optional[Tuple[float, int, float, float]] = None
 
     @property
     def row(self) -> int:
@@ -572,7 +661,40 @@ def layer_tree_shapes(cfg: TransformerConfig):
         else:
             layer["mlp"] = dense
         tree["layers"][str(i)] = layer
+    if cfg.mtp_layers:
+        # Embedding and head are the main model's.
+        tree["mtp"] = {
+            "enorm": norm, "hnorm": norm, "eh_proj": (2 * e, e),
+            "layer": {"attn_norm": norm, "attn": attn, "mlp_norm": norm,
+                      "moe": experts},
+            "norm": norm}
     return tree
+
+
+def yarn_softmax_mult(factor: float, mscale_all_dim: float) -> float:
+    """``TransformerConfig.mla_softmax_mult`` under YaRN: the square of
+    0.1 mscale_all_dim ln(factor) + 1 (1 without a factor)."""
+    if factor <= 1.0:
+        return 1.0
+    return float(0.1 * mscale_all_dim * np.log(factor) + 1.0) ** 2
+
+
+def yarn_frequencies(dim: int, theta: float, yarn) -> np.ndarray:
+    """The ``dim`` / 2 rotary frequencies of a latent layer under YaRN
+    (``TransformerConfig.yarn_factor``), float64: ``yarn`` is
+    ``LatentSizes.yarn``."""
+    factor, original, beta_fast, beta_slow = yarn
+    freqs = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction(turns):
+        return dim * np.log(original / (2 * np.pi * turns)) \
+            / (2 * np.log(theta))
+
+    low = max(np.floor(correction(beta_fast)), 0)
+    high = min(np.ceil(correction(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
+                   0, 1)
+    return freqs * (1 - ramp) + freqs / factor * ramp
 
 
 def _hold(module: nn.Module, shapes):

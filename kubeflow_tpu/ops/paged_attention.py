@@ -249,7 +249,8 @@ def _walk(tables_ref, ntok_ref, plane_ref, hbm, bufs, sems, ride, *, mb,
 
 
 def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
-            pages, nb, planes, scale, value_lanes=None, window=None):
+            pages, nb, planes, scale, value_lanes=None, window=None,
+            queries=1, first=0):
     """One slot's attention over the pages ``_walk`` brings.
 
     ``refs``: the pool's sides in HBM (keys and values; or, with
@@ -257,7 +258,12 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
     values in their first ``value_lanes`` lanes), the output, a buffer a
     side, the semaphores, and the walk's ``ride``.
     ``window``: a slot attends its last ``window`` positions only, and
-    its walk starts at the page that holds the first of them."""
+    its walk starts at the page that holds the first of them.
+    ``queries``: the query rows hold that many neighbouring positions of
+    the slot, position j in rows ``[j * h / queries, (j + 1) * h /
+    queries)``; ``n_tokens`` is what the LAST of them sees, the one
+    before it one position fewer.  ``first``: positions below it are
+    masked for every row."""
     sides = (len(refs) - 3) // 2
     hbm, o_ref = refs[:sides], refs[sides]
     bufs, sems, ride = refs[sides + 1:-2], refs[-2], refs[-1]
@@ -273,6 +279,9 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
     own = (col % hkv) == (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
                           // g)
     pos = col // hkv
+    # What a query row sees fewer than the slot's last position does.
+    behind = (queries - 1) - jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0) // (h // queries) if queries > 1 else 0
 
     def attend(i, buf, carry):
         m, l, acc = carry
@@ -284,7 +293,10 @@ def _kernel(tables_ref, ntok_ref, plane_ref, q_ref, *refs, mb, bt, hkv, g,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # [h, rows*]
         if window is None:
-            keep = own & (pos + i * (pages * bt) < n)
+            at = pos + i * (pages * bt)
+            keep = own & (at < n - behind)
+            if first:
+                keep = keep & (at >= first)
         else:
             at = pos + (page0 + i * pages) * bt
             keep = own & (at < n) & (at >= n - window)
@@ -469,10 +481,12 @@ def paged_decode_attention(q, k_pool, v_pool, plane, tables, n_tokens, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "value_lanes", "scale", "window", "pages_per_block", "interpret"))
+    "value_lanes", "scale", "window", "queries", "first",
+    "pages_per_block", "interpret"))
 def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
                                   value_lanes: int, scale: float, *,
                                   window: int | None = None,
+                                  queries: int = 1, first: int = 0,
                                   pages_per_block: int | None = None,
                                   interpret: bool = False):
     """The latent (MLA) form: ``q [S, h, row]`` against each slot's
@@ -491,7 +505,15 @@ def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
     window: a slot attends its last ``window`` positions
     ``[n_tokens - window, n_tokens)`` and the walk copies only the pages
     that hold them, whatever lies below: None attends all.
+    queries: ``q`` holds that many neighbouring positions a slot (a
+    drafting stack's last token and its draft), ``[S, queries * heads,
+    row]`` with position j in rows ``[j * heads, (j + 1) * heads)``;
+    ``n_tokens`` counts what the LAST position sees and position j sees
+    ``queries - 1 - j`` fewer: the pages are copied once for all of
+    them.  first: positions below it are attended by no row (without
+    ``window``).
     """
+    assert window is None or (queries == 1 and not first)
     S, h, row = q.shape
     planes, nb, bt, _ = pool.shape
     mb = tables.shape[1]
@@ -499,7 +521,8 @@ def paged_latent_decode_attention(q, pool, plane, tables, n_tokens,
         bt * row * pool.dtype.itemsize, mb)
     kernel = functools.partial(
         _kernel, mb=mb, bt=bt, hkv=1, g=h, pages=pages, nb=nb,
-        planes=planes, scale=scale, value_lanes=value_lanes, window=window)
+        planes=planes, scale=scale, value_lanes=value_lanes, window=window,
+        queries=queries, first=first)
     return pl.pallas_call(
         kernel,
         name="paged_latent_decode_attention",

@@ -106,6 +106,8 @@ CONTRACTIONS: Dict[Tuple[str, ...], Tuple[int, ...]] = {
     ("attn", "wg"): (-2,),         # [e, h] contract e
     ("shared", "wi"): (-2,),       # [2, e, f] contract e
     ("shared", "wo"): (-2,),       # [f, e] contract f
+    # The draft module's projection (TransformerConfig.mtp_layers).
+    ("mtp", "eh_proj"): (-2,),     # [2e, e] contract 2e
 }
 
 

@@ -180,6 +180,14 @@ SPEC_DRAFTED_TOTAL = "kft_engine_spec_drafted_total"
 SPEC_DRAFTED_HELP = "draft tokens proposed to verify_step, by engine"
 SPEC_ACCEPTED_TOTAL = "kft_engine_spec_accepted_total"
 SPEC_ACCEPTED_HELP = "draft tokens accepted by verify_step, by engine"
+MTP_DRAFTED_TOTAL = "kft_engine_mtp_drafted_total"
+MTP_DRAFTED_HELP = \
+    "drafts of a model's own multi-token-prediction module verified by " \
+    "a decode step, by engine"
+MTP_ACCEPTED_TOTAL = "kft_engine_mtp_accepted_total"
+MTP_ACCEPTED_HELP = \
+    "drafts of a model's own multi-token-prediction module taken (the " \
+    "step yielded two tokens), by engine"
 MESH_DEVICES_GAUGE = "kft_engine_mesh_devices"
 MESH_DEVICES_HELP = \
     "devices the engine's serving mesh spans (1 = single-device), " \
@@ -632,6 +640,18 @@ class DecodeEngine:
                         f"model with {self._pages_stay}")
         if self._slot_state:
             prefix_caching = False
+        # A model whose multi-token-prediction module drafts
+        # (TransformerConfig.mtp_layers): every decode step verifies a
+        # draft made on the device and may yield two tokens; drafting
+        # is part of the model, not a dial of the engine (no gate turns
+        # it off), and it is greedy.
+        self._mtp = int(getattr(cfg, "mtp_layers", 0))
+        if self._mtp and decode.temperature > 0:
+            raise ValueError(
+                f"engine {name!r}: temperature {decode.temperature:g} is "
+                "not built for a model whose multi-token-prediction "
+                "module drafts (mtp_layers): a draft is taken where it is "
+                "the stack's own first choice")
         self._registry = adapters
         self._adapter_version = None
         if adapters is not None:
@@ -680,7 +700,9 @@ class DecodeEngine:
         self.kv_block_tokens = max(1, int(kv_block_tokens))
         # Per-slot block-table span: enough logical pages to cover
         # max_len positions (a static program shape).
-        self._table_blocks = -(-self.max_len // self.kv_block_tokens)
+        # The draft layer keeps a token's row one index on (+ 1).
+        self._table_blocks = -(-(self.max_len + self._mtp)
+                               // self.kv_block_tokens)
         self.kv_pool_blocks = int(kv_pool_blocks) \
             or slots * self._table_blocks
         if self.kv_pool_blocks < 1:
@@ -851,6 +873,7 @@ class DecodeEngine:
             "prefill_chunks": 0, "cached_tokens": 0, "prompt_tokens": 0,
             "prefill_positions_held": 0, "prefill_positions_scored": 0,
             "spec_drafted": 0, "spec_accepted": 0, "spec_steps": 0,
+            "mtp_drafted": 0, "mtp_accepted": 0, "mtp_steps": 0,
             "kv_evictions": 0, "kv_shed_no_blocks": 0,
             "handoff_pages_out": 0, "handoff_pages_in": 0,
             "fused_rounds": 0, "fused_steps_wasted": 0,
@@ -915,6 +938,10 @@ class DecodeEngine:
             SPEC_DRAFTED_TOTAL, SPEC_DRAFTED_HELP)
         self._spec_accepted_ctr = REGISTRY.counter(
             SPEC_ACCEPTED_TOTAL, SPEC_ACCEPTED_HELP)
+        self._mtp_drafted_ctr = REGISTRY.counter(
+            MTP_DRAFTED_TOTAL, MTP_DRAFTED_HELP)
+        self._mtp_accepted_ctr = REGISTRY.counter(
+            MTP_ACCEPTED_TOTAL, MTP_ACCEPTED_HELP)
         self._mesh_gauge = REGISTRY.gauge(
             MESH_DEVICES_GAUGE, MESH_DEVICES_HELP)
         self._handoff_ctr = REGISTRY.counter(
@@ -1066,6 +1093,12 @@ class DecodeEngine:
             "prompt_tokens": int(entry["tokens"].shape[1]),
             "max_new_tokens": entry["new"],
         }
+        if self._mtp:
+            # What the model's own module drafted for this request, as
+            # the rounds hand it over: (index of the emitted token a
+            # round began at, the draft each of the round's tokens was
+            # held against, -1 where none was: the second of a pair).
+            meta["mtp_drafts"] = entry["mtp_drafts"]
 
         def stream():
             sent = 0
@@ -1212,7 +1245,7 @@ class DecodeEngine:
         # could ever write (prompt + full budget) in whole pages.
         # Reserving it at admission is what makes block exhaustion a
         # typed shed instead of a mid-flight deadlock.
-        res_blocks = -(-(length + new) // self.kv_block_tokens)
+        res_blocks = -(-(length + new + self._mtp) // self.kv_block_tokens)
         trace_ctx = tracing.current_ctx()
         entry = {
             "tokens": tokens, "new": new, "seed": seed,
@@ -1233,7 +1266,7 @@ class DecodeEngine:
             # Drafting history (prompt + emitted), maintained
             # incrementally by the drain — rebuilding it per round
             # costs more than the draft search itself at step rates.
-            "hist": None, "hist_len": 0,
+            "hist": None, "hist_len": 0, "mtp_drafts": [],
             "deadline": deadline,
             "want_timing": bool(inputs.get("return_timing")),
             "event": threading.Event(), "out": None, "err": None,
@@ -1511,6 +1544,13 @@ class DecodeEngine:
             "spec_drafted": c["spec_drafted"],
             "spec_accepted": c["spec_accepted"],
             "spec_steps": c["spec_steps"],
+            # The model's own multi-token-prediction module
+            # (TransformerConfig.mtp_layers): drafts a decode step
+            # verified, drafts taken (steps that yielded two tokens) and
+            # the decode steps that drafted.
+            "mtp_drafted": c["mtp_drafted"],
+            "mtp_accepted": c["mtp_accepted"],
+            "mtp_steps": c["mtp_steps"],
             "spec_acceptance_rate": round(
                 c["spec_accepted"] / c["spec_drafted"], 4)
             if c["spec_drafted"] else 0.0,
@@ -2495,7 +2535,9 @@ class DecodeEngine:
         chunk = np.zeros((1, w), np.int32)
         seg = prompt[start:start + w]
         chunk[0, :seg.shape[0]] = seg
-        self._ensure_cover(entry, start + w - 1)
+        # A drafting model's final chunk also writes its draft layer's
+        # row at the prompt's length, one past the chunk at most.
+        self._ensure_cover(entry, start + w - 1 + self._mtp)
         if self._chunk_exec is None:
             lower_args = [
                 self.cfg, self.params, self._state, self.decode,
@@ -2507,6 +2549,8 @@ class DecodeEngine:
                 # = base), so compiled_programs() never grows a
                 # per-adapter entry.
                 lower_args.append(np.int32(0))
+            if self._mtp:
+                lower_args += [None, np.int32(-1)]
             self._chunk_exec = self._aot(
                 prefill_chunk_into_slot, *lower_args)
         call_args = [
@@ -2516,6 +2560,13 @@ class DecodeEngine:
             self._tables[entry["slot"]:entry["slot"] + 1].copy()]
         if self._registry is not None:
             call_args.append(np.int32(entry.get("adapter", 0)))
+        if self._mtp:
+            # The slot's first chunk after a prefix hit: nothing ran in
+            # this slot before it, and the draft layer's first row reads
+            # the stream one position back (prefill_chunk_into_slot).
+            call_args += [None, np.int32(
+                prompt[start - 1] if start and start == entry["cached"]
+                else -1)]
         t0 = time.perf_counter()
         self._state, tok = self._chunk_exec(*call_args)
         dt = time.perf_counter() - t0
@@ -2916,6 +2967,9 @@ class DecodeEngine:
         from kubeflow_tpu.models.generate import decode_rounds
 
         kmax = self.decode_rounds
+        # A drafting model's step yields one token or two, and its
+        # draft layer writes one index past them.
+        per = 2 if self._mtp else 1
         with self._phase("round_prepare"):
             width = self._round_width()
             snapshot = [(i, r) for i, r in enumerate(self._slot_req)
@@ -2928,7 +2982,8 @@ class DecodeEngine:
             lengths = []  # per snapshot row: cache positions before the round
             for _, r in snapshot:
                 lengths.append(r["tokens"].shape[1] + r["scheduled"])
-                self._ensure_cover(r, lengths[-1] + width - 1)
+                self._ensure_cover(
+                    r, lengths[-1] + per * width - 1 + self._mtp)
             if self._rounds_exec is None:
                 # One executable serves EVERY adaptive width: the buffer
                 # size k is static, the per-round step cap is a traced
@@ -2956,12 +3011,14 @@ class DecodeEngine:
         t0 = time.perf_counter()
         with self._phase("round_dispatch", width=width,
                          live=live) as phase:
-            self._state, toks, counts, steps_run = self._rounds_exec(
-                self.params, self._state, tables, np.int32(width))
+            self._state, toks, counts, steps_run, *drafts = \
+                self._rounds_exec(
+                    self.params, self._state, tables, np.int32(width))
             self._device_has_work(phase)
             touched = self._state.get("moe_touched")
             pairs = self._state.get("moe_pairs")
-            for count in (touched, pairs):
+            drafted = self._state.get("mtp_counts")
+            for count in (touched, pairs, drafted):
                 if count is not None:
                     # Its copy to the host rides behind the round, so
                     # the read at the boundary costs no round trip of
@@ -2975,6 +3032,8 @@ class DecodeEngine:
             # whose remaining budget fits this round is KNOWN to finish
             # — the loop early-exits only when EVERY slot is done, so it
             # can never stop short of a still-advancing slot's budget.
+            # (A drafting model's slot emits ``width`` tokens at least:
+            # what it emitted is read at the boundary.)
             for i, r in snapshot:
                 r["scheduled"] = min(r["new"], r["scheduled"] + width)
                 if not self._eos and r["scheduled"] >= r["new"]:
@@ -2989,7 +3048,7 @@ class DecodeEngine:
                 if self._slot_req[i] is r:
                     self._ensure_cover(
                         r, r["tokens"].shape[1] + r["scheduled"]
-                        + kmax - 1)
+                        + (per - 1) * width + per * kmax - 1 + self._mtp)
             if self._tables_dirty:
                 self._refresh_tables_dev()
             # Overlapped drafting for the next boundary's verify round.
@@ -3022,6 +3081,22 @@ class DecodeEngine:
                     n = int(counts_np[i])
                     attended += n * at + n * (n - 1) // 2
                 facts = {"steps": steps, "attended": attended}
+                if drafted is not None:
+                    # A drafting step's two rows read a plane's pages
+                    # once: ``attended`` is the device's own sum, over
+                    # live slots and steps, of what the later row saw.
+                    made, taken, seen = map(int, np.asarray(drafted))
+                    facts.update(attended=seen, mtp_drafted=made,
+                                 mtp_accepted=taken)
+                    drafts_np = np.asarray(drafts[0])
+                    with self._lock:
+                        self._counters["mtp_drafted"] += made
+                        self._counters["mtp_accepted"] += taken
+                        self._counters["mtp_steps"] += steps
+                    self._mtp_drafted_ctr.inc(
+                        made, engine=self._metric_name)
+                    self._mtp_accepted_ctr.inc(
+                        taken, engine=self._metric_name)
                 if self._index_planes or self.cfg.window_planes:
                     reads = self._sparse_reads((
                         (at, int(counts_np[i]))
@@ -3049,11 +3124,21 @@ class DecodeEngine:
                         for key, n in fell.items():
                             self._counters[key] += n
                 phase.facts(**facts)
-                del toks, counts, steps_run  # freed here, in a phase
+                del toks, counts, steps_run, drafts  # freed in a phase
         with self._phase("drain"):
             self._pending.append((toks_np, snapshot, counts_np))
             while self._pending:
                 self._drain_one()
+            if self._mtp:
+                for i, r in snapshot:
+                    # What the slot emitted is known here, not at
+                    # dispatch; the next round's cover counts from it.
+                    n = int(counts_np[i])
+                    r["scheduled"] = max(r["scheduled"],
+                                         len(r["emitted"]))
+                    if n and len(r["emitted"]) >= n:
+                        r["mtp_drafts"].append(
+                            (len(r["emitted"]) - n, drafts_np[i, :n]))
         end = time.perf_counter()
         with self._phase("account"):
             delivered = self._counters["tokens"] - tok_before

@@ -901,3 +901,112 @@ def test_a_windows_pages_of_a_constant_table_are_the_right_pages(chip):
     wrong, = folded
     assert (wrong.reshape(4, 33)[:, 0] == want[:, 0]).all()
     assert (wrong.reshape(4, 33)[:, 1:] == 0).all()
+
+
+# dots.vlm1.inst's language model with its multi-token-prediction module
+# drafting, at the cell's sizes (benchmark/configs/dots.vlm1.inst-l5.json,
+# cells/dots.vlm1.inst-l5.longform): a dense layer and four expert layers
+# of 16 of 256 experts in 8 groups, the module's layer with 16 more, 128
+# heads, YaRN; 6 planes of ONE latent pool (the draft layer's the last);
+# 32 slots of 8,288 + 768 positions and the draft plane's one more.
+DOTSVLM = ({"vocab_size": 16_160, "d_model": 7168, "n_layers": 5,
+            "n_heads": 128, "n_kv_heads": 128, "d_ff": 18_432,
+            "max_seq_len": 163_840, "rope_theta": 10_000.0,
+            "tied_embeddings": False, "norm_eps": 1e-6,
+            "layer_types": ["full_attention"] * 5,
+            "attention_kind": "latent", "mla_q_rank": 1536,
+            "mla_kv_rank": 512, "mla_nope_dim": 128, "mla_rope_dim": 64,
+            "mla_v_dim": 128, "mla_rescale": False, "yarn_factor": 40.0,
+            "yarn_original_len": 4096, "yarn_beta_fast": 32.0,
+            "yarn_beta_slow": 1.0, "mla_softmax_mult": 1.8738526,
+            "moe_experts": 256, "moe_experts_held": 16, "moe_top_k": 8,
+            "moe_d_ff": 2048, "moe_dense_layers": 1, "moe_scale": 2.5,
+            "moe_groups": 8, "moe_groups_kept": 4, "moe_norm_eps": 1e-20,
+            "moe_shared_d_ff": 2048, "mtp_layers": 1,
+            "dtype": "bfloat16"}, 32, 8288 + 768 + 1, 768)
+
+
+@pytest.fixture(scope="module")
+def dotsvlm_program(chip):
+    import functools
+
+    from kubeflow_tpu.models import generate
+
+    widths, slots, max_len, new = DOTSVLM
+    e = _engine_shapes(chip, widths, slots, max_len, max_new_tokens=new)
+
+    @functools.cache
+    def compiled(program):
+        if program == "decode_rounds":
+            return e, generate.decode_rounds.lower(
+                e["cfg"], e["params"], e["state"], e["decode"], 8,
+                e["arg"](slots, e["table_blocks"]), e["arg"](),
+                paged_kernel=True).compile()
+        scalar = e["arg"]()
+        return e, generate.prefill_chunk_into_slot.lower(
+            e["cfg"], e["params"], e["state"], e["decode"],
+            e["arg"](1, 256), scalar, scalar, scalar, scalar, scalar,
+            e["arg"](1, e["table_blocks"]), None, scalar).compile()
+
+    return compiled
+
+
+@pytest.mark.parametrize("program", ["decode_rounds",
+                                     "prefill_chunk_into_slot"])
+def test_drafting_programs_hold_six_planes_in_place_under_the_chips_memory(
+        dotsvlm_program, program):
+    """Both engine programs of the drafting stack at the cell's sizes:
+    5,606,143,232 parameters (ISSUE 47's count), the six-plane pool
+    donated and aliased with nothing of its shape copied (the chunk's ONE
+    row at the prompt's length is a dynamic-update-slice in place, named
+    below), the position a chunk recomputes after a hit under a
+    conditional that writes no plane, no fusion the compiler gave up on,
+    and 32 slots fit: 14.04 / 13.96 GB live."""
+    e, compiled = dotsvlm_program(program)
+    text = compiled.as_text()
+    pool = e["state"]["cache_latent"]
+    assert pool.shape == (6, 32 * 567, 16, 640)
+    assert sum(int(np.prod(a.shape)) for a in
+               jax.tree_util.tree_leaves(e["params"])) == 5_606_143_232
+    moves = _pool_moves(text, pool.shape)
+    lines = {name: line for line in text.splitlines()
+             for name in moves if f"%{name} = " in line}
+    assert all("kft.mtp_fill/kft.mla_latent_write/scatter" in line
+               and "dynamic-update-slice(" in line
+               for line in lines.values()), moves
+    assert len(moves) == (0 if program == "decode_rounds" else 1)
+    assert _fusions_given_up(text) == []
+    # (An expert's [2048, 7168] is also the shape of a chunk's sorted
+    # rows and of the shared expert's prefetch: not asked here.)
+    assert _weight_moves(text, [
+        (16, 7168, 4096), (16, 2048, 7168), (7168, 4096),
+        (2, 7168, 18_432), (7168, 18_432), (18_432, 7168),
+        (14_336, 7168)]) == []
+    m = compiled.memory_analysis()
+    side = int(np.prod(pool.shape)) * 2
+    assert side == 2_229_534_720 and m.alias_size_in_bytes >= side
+    assert m.temp_size_in_bytes < 0.65e9, m.temp_size_in_bytes
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert live < 14.3e9, live
+
+
+def test_drafting_decode_rounds_reads_each_plane_once_for_both_rows(
+        dotsvlm_program):
+    """A step's two rows a slot go through the latent kernel as rows of
+    ONE call a plane (256 query rows of 640 lanes a slot): six calls a
+    step, the draft plane's among them; the chunk holds no kernel; the
+    grouped products are the chip's own kernel, once an expert layer."""
+    _, compiled = dotsvlm_program("decode_rounds")
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line
+             and "%paged_latent_decode_attention" in line.split(" = ")[0]]
+    assert len(calls) == 6
+    assert all("bf16[32,256,640]" in line for line in calls)
+    assert text.count('op_name="ragged-dot-metadata"') == 5
+    for scope in ("kft.mtp_draft", "kft.mtp_accept", "kft.moe_groups"):
+        assert scope in text
+    _, chunk = dotsvlm_program("prefill_chunk_into_slot")
+    assert "paged_latent_decode_attention" not in chunk.as_text()
+    assert "kft.mtp_fill" in chunk.as_text()
