@@ -1199,7 +1199,8 @@ def _conv_block(cfg: TransformerConfig, layer_params, x, state=None,
     return x, state
 
 
-def _experts(cfg: TransformerConfig, moe, y, live=None):
+def _experts(cfg: TransformerConfig, moe, y, live=None,
+             grouped_kernel=False):
     """The expert layer over normed rows y [m, e]: ``sum over the chosen
     i of w_i E_i(y)`` -> ([m, e] float32, counts); nothing is dropped.
 
@@ -1209,14 +1210,17 @@ def _experts(cfg: TransformerConfig, moe, y, live=None):
     ``moe_groups_kept`` groups of largest score, whichever chip holds
     their experts.  Every (row, chosen expert) pair whose expert this program
     HOLDS (``moe_experts_offset``, ``moe_held``) is sorted by expert and
-    the experts' SwiGLUs run as two grouped products
-    (``jax.lax.ragged_dot``) over the stacked expert matrices where they
-    lie: a row meets only the experts it chose, and an expert no row
-    chose is not read.  ``moe/wi`` is [held, e, 2 f], gate then up along
-    the last axis.  A pair whose expert lies on another chip is in no
-    group and adds nothing here; a pair that chose a zero-compute expert
-    (an output at or past ``moe_experts``) is in no group either and adds
-    its weight times the row itself.
+    the experts' SwiGLUs run as two grouped products over the stacked
+    expert matrices where they lie: a row meets only the experts it chose,
+    and an expert no row chose is not read.  ``grouped_kernel`` (static,
+    chosen once by the engine from where the expert matrices live):
+    ops/grouped_matmul.py, each touched expert's matrix read once at
+    HBM's rate whatever the rows in no group; else
+    ``jax.lax.ragged_dot``, the same products.  ``moe/wi`` is [held, e,
+    2 f], gate then up along the last axis.  A pair whose expert lies on
+    another chip is in no group and adds nothing here; a pair that chose
+    a zero-compute expert (an output at or past ``moe_experts``) is in no
+    group either and adds its weight times the row itself.
     live [m] bool (None: all): rows that are no token (a parked slot, a
     final chunk's padding) choose nothing and come back as zeros.
     counts: int32 scalars over the live rows: ``touched`` (distinct held
@@ -1271,13 +1275,20 @@ def _experts(cfg: TransformerConfig, moe, y, live=None):
         sizes = jnp.zeros((held,), jnp.int32).at[pairs].add(1, mode="drop")
         rows = y[order // k]
     with jax.named_scope("kft.moe_experts"):
-        h = jax.lax.ragged_dot(rows, moe["wi"].astype(dt), sizes)
+        product = jax.lax.ragged_dot
+        if grouped_kernel:
+            from kubeflow_tpu.ops import grouped_matmul
+
+            product = grouped_matmul.grouped_matmul
+        h = product(rows, moe["wi"].astype(dt), sizes)
         h = jax.nn.silu(h[:, :f]) * h[:, f:]
-        out = jax.lax.ragged_dot(h, moe["wo"].astype(dt), sizes)
+        out = product(h, moe["wo"].astype(dt), sizes)
     with jax.named_scope("kft.moe_route"):
         out = out[jnp.argsort(order)].reshape(m, k, e)
         in_group = (pairs < held).reshape(m, k)
-        # A row in no group is not written by the grouped product.
+        # A row in no group is not written by the grouped product
+        # (ragged_dot leaves zeros, the kernel whatever was there): a
+        # select, never a product.
         if held != n or zero:
             out = jnp.where(in_group[:, :, None], out, 0)
         elif live is not None:
@@ -1298,18 +1309,21 @@ def _experts(cfg: TransformerConfig, moe, y, live=None):
         "absent": jnp.sum(alive).astype(jnp.int32) * k - n_held - n_zero}
 
 
-def _sparse_ff(cfg: TransformerConfig, layer_params, x, live=None):
+def _sparse_ff(cfg: TransformerConfig, layer_params, x, live=None,
+               grouped_kernel=False):
     """Sparse experts in the feed-forward's place (TransformerConfig.
     layer_types), with norm and residual: ``_experts`` over the normed
     stream.  live [b, t] bool (None: all): rows that are no token come
-    back unchanged.  Returns (x, ``_experts``'s counts)."""
+    back unchanged.  ``grouped_kernel``: as ``_experts``'s.  Returns (x,
+    ``_experts``'s counts)."""
     b, t, e = x.shape
     moe = layer_params["moe"]
     with jax.named_scope("kft.mlp"):
         normed = _rms_norm(x, layer_params["mlp_norm"]["scale"],
                            cfg.norm_eps, cfg.dtype)
         y, counts = _experts(cfg, moe, normed.reshape(b * t, e),
-                             None if live is None else live.reshape(-1))
+                             None if live is None else live.reshape(-1),
+                             grouped_kernel)
         y = y.astype(cfg.dtype).reshape(b, t, e)
         if cfg.moe_shared_d_ff:
             # The shared expert: every token, unweighted, once.
@@ -1323,7 +1337,8 @@ def _sparse_ff(cfg: TransformerConfig, layer_params, x, live=None):
 
 def _shortcut_double(cfg: TransformerConfig, layer_params, x, cache,
                      cache_len, positions, live=None, write_cols=None,
-                     tables=None, paged_kernel=False, plane=None):
+                     tables=None, paged_kernel=False, plane=None,
+                     grouped_kernel=False):
     """A ``shortcut_double`` layer (TransformerConfig.layer_types): two
     attention sublayers on planes ``plane`` and ``plane + 1``, two dense
     SwiGLUs, and the expert layer that reads the first half's normed
@@ -1339,7 +1354,7 @@ def _shortcut_double(cfg: TransformerConfig, layer_params, x, cache,
         m = _rms_norm(x, first["mlp_norm"]["scale"], cfg.norm_eps, dt)
         s, counts = _experts(
             cfg, layer_params["moe"], m.reshape(b * t, e),
-            None if live is None else live.reshape(-1))
+            None if live is None else live.reshape(-1), grouped_kernel)
         x = x + _dense_mlp(cfg, first["mlp"], m)
     x, cache = _attention_block(
         cfg, second, x, cache, cache_len, positions, write_cols=write_cols,
@@ -1394,7 +1409,8 @@ def _logits(cfg: TransformerConfig, params, x):
 def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
                         cache_len=0, write_cols=None, tables=None,
                         paged_kernel=False, conv=None, rows=None,
-                        fresh=None, n_new=None, hidden=False, store=True):
+                        fresh=None, n_new=None, hidden=False, store=True,
+                        grouped_kernel=False):
     """The forward of a stack that states its ``layer_types``
     (TransformerConfig): tokens [b, t] -> (logits [b, t, v], cache,
     conv, the expert layers' counts).  ``hidden`` (static): the stream
@@ -1409,7 +1425,8 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
     sliding_attention layers count their own, in a pool of their own).
     ``cache`` is the stacked pool, (k, v) or ``latent_sides``', that the serving
     programs carry and donate, ``tables`` their block tables, ``cache_len`` /
-    ``write_cols`` / ``paged_kernel`` as in ``_forward_with_cache``;
+    ``write_cols`` / ``paged_kernel`` as in ``_forward_with_cache``,
+    ``grouped_kernel`` as in ``_experts``;
     ``conv`` is the [conv layers, slots, K - 1, e] state of the
     convolution layers with ``rows`` / ``fresh`` / ``n_new`` as in
     ``_conv_block``.  Rows with ``n_new`` 0 and columns at or past
@@ -1437,7 +1454,8 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
             x, cache, n = _shortcut_double(
                 cfg, layer_params, x, cache, cache_len, positions, live,
                 write_cols=write_cols, tables=tables,
-                paged_kernel=paged_kernel, plane=plane)
+                paged_kernel=paged_kernel, plane=plane,
+                grouped_kernel=grouped_kernel)
             counts = count(n)
             plane += 2
             continue
@@ -1462,7 +1480,7 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
                 paged_kernel=paged_kernel, plane=plane, store=store)
             plane += 1
         if cfg.layer_is_sparse(i):
-            x, n = _sparse_ff(cfg, layer_params, x, live)
+            x, n = _sparse_ff(cfg, layer_params, x, live, grouped_kernel)
             counts = count(n)
         else:
             x = _dense_ff(cfg, layer_params, x)
@@ -1474,7 +1492,7 @@ def forward_layer_types(cfg: TransformerConfig, params, tokens, cache=None,
 
 def mtp_logits(cfg: TransformerConfig, params, hidden, tokens, cache,
                cache_len, positions, live=None, write_cols=None,
-               tables=None, paged_kernel=False):
+               tables=None, paged_kernel=False, grouped_kernel=False):
     """The multi-token-prediction module (TransformerConfig.mtp_layers)
     over rows ``(h_i, t_{i+1})``: ``hidden`` [b, t, e], the main stack's
     stream after its final norm at positions i, and ``tokens`` [b, t],
@@ -1508,7 +1526,7 @@ def mtp_logits(cfg: TransformerConfig, params, hidden, tokens, cache,
         cfg, mtp["layer"], z, cache, cache_len, positions,
         write_cols=write_cols, tables=tables, paged_kernel=paged_kernel,
         plane=cfg.kv_planes - 1, first_pos=1)
-    z, counts = _sparse_ff(cfg, mtp["layer"], z, live)
+    z, counts = _sparse_ff(cfg, mtp["layer"], z, live, grouped_kernel)
     with jax.named_scope("kft.logits"):
         return _head(cfg, params, _rms_norm(
             z, mtp["norm"]["scale"], cfg.norm_eps, dt)), cache, counts
@@ -2018,7 +2036,7 @@ def _count_experts(state, counts):
 
 def _advance_slots_drafting(cfg: TransformerConfig, params,
                             decode: DecodeConfig, tables: jax.Array, park,
-                            state, paged_kernel=False):
+                            state, paged_kernel=False, grouped_kernel=False):
     """``_advance_slots`` of a stack whose multi-token-prediction module
     drafts (TransformerConfig.mtp_layers), greedy: one step that
     VERIFIES a draft and makes the next, and yields one token or two.
@@ -2052,7 +2070,8 @@ def _advance_slots_drafting(cfg: TransformerConfig, params,
     hidden, cache, _, counts = forward_layer_types(
         cfg, params, rows, tuple(state[side] for side in sides), lengths,
         write_cols=write_cols, tables=tables, paged_kernel=paged_kernel,
-        n_new=2 * advance.astype(jnp.int32), hidden=True)
+        n_new=2 * advance.astype(jnp.int32), hidden=True,
+        grouped_kernel=grouped_kernel)
     state = _count_experts(state, counts)
     with jax.named_scope("kft.logits"):
         logits = _head(cfg, params, hidden)
@@ -2082,7 +2101,8 @@ def _advance_slots_drafting(cfg: TransformerConfig, params,
             lengths + 1, lengths[:, None] + jnp.arange(2)[None, :],
             live=jnp.stack([advance, pair], axis=1),
             write_cols=jnp.where(advance, lengths + 1, park),
-            tables=tables, paged_kernel=paged_kernel)
+            tables=tables, paged_kernel=paged_kernel,
+            grouped_kernel=grouped_kernel)
         drafts = jnp.argmax(drafts, axis=-1).astype(jnp.int32)
     state = _count_experts(state, counts)
     state.update(zip(sides, cache))
@@ -2098,7 +2118,8 @@ def _advance_slots_drafting(cfg: TransformerConfig, params,
 
 
 def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
-                   tables: jax.Array, park, state, paged_kernel=False):
+                   tables: jax.Array, park, state, paged_kernel=False,
+                   grouped_kernel=False):
     """One batched decode step over every slot, ``decode_rounds``'s
     loop body: one forward at t=1 in which each slot ropes at its own
     length, attends under its own causal frontier over its pages of
@@ -2111,7 +2132,9 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
     aim their dropped cache writes.  ``paged_kernel`` (static, chosen
     once by the engine from the platform its pool lives on):
     attention reads the pool in place through ops/paged_attention.py
-    instead of the gathered view."""
+    instead of the gathered view.  ``grouped_kernel`` (static, chosen
+    the same way from where the expert matrices live): as
+    ``_experts``'s."""
     lengths, done = state["lengths"], state["done"]
     sides = pool_sides(state)
     advance = ~done
@@ -2126,7 +2149,7 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
             tuple(state[side] for side in sides), lengths,
             write_cols=write_cols, tables=tables,
             paged_kernel=paged_kernel, conv=state.get("conv"),
-            n_new=advance.astype(jnp.int32))
+            n_new=advance.astype(jnp.int32), grouped_kernel=grouped_kernel)
         state = dict(state) if counts is None \
             else _count_experts(state, counts)
         if conv is not None:
@@ -2165,10 +2188,12 @@ def _advance_slots(cfg: TransformerConfig, params, decode: DecodeConfig,
 
 
 @partial(jax.jit, static_argnums=(0, 3, 4),
-         static_argnames=("paged_kernel",), donate_argnums=(2,))
+         static_argnames=("paged_kernel", "grouped_kernel"),
+         donate_argnums=(2,))
 def decode_rounds(cfg: TransformerConfig, params, state,
                   decode: DecodeConfig, k: int, tables: jax.Array,
-                  max_steps: jax.Array, *, paged_kernel: bool = False):
+                  max_steps: jax.Array, *, paged_kernel: bool = False,
+                  grouped_kernel: bool = False):
     """Device-resident multi-step decode: up to ``k`` decode steps in
     ONE dispatch via ``lax.while_loop``, with device-side early exit
     the moment every slot is done (EOS/budget) — the host never pays
@@ -2194,7 +2219,7 @@ def decode_rounds(cfg: TransformerConfig, params, state,
     must pre-cover every slot for the worst case (``k`` new positions)
     before dispatch.  Per-step math is ``_advance_slots``, so greedy
     tokens do not depend on how the steps are cut into rounds.
-    ``paged_kernel``: as there.
+    ``paged_kernel`` / ``grouped_kernel``: as there.
 
     A stack whose multi-token-prediction module drafts
     (``cfg.mtp_layers``) takes ``_advance_slots_drafting`` as its step: a
@@ -2227,7 +2252,8 @@ def decode_rounds(cfg: TransformerConfig, params, state,
             at = state["lengths"] - len0     # emitted so far, a slot
             held = state["mtp_draft"]
             state, first, second, emit = _advance_slots_drafting(
-                cfg, params, decode, tables, park, state, paged_kernel)
+                cfg, params, decode, tables, park, state, paged_kernel,
+                grouped_kernel)
             row = jnp.arange(slots)
             at = jnp.where(emit > 0, at, 2 * k)
             out = out.at[0, row, at].set(first, mode="drop")
@@ -2236,7 +2262,7 @@ def decode_rounds(cfg: TransformerConfig, params, state,
                 second, mode="drop")
             return i + 1, state, out
         state, nxt = _advance_slots(cfg, params, decode, tables, park,
-                                    state, paged_kernel)
+                                    state, paged_kernel, grouped_kernel)
         return i + 1, state, out.at[:, i].set(nxt)
 
     # A drafting stack's second plane: the draft each token was held
@@ -2346,7 +2372,8 @@ def verify_step(cfg: TransformerConfig, params, state,
 
 
 def _drafting_chunk(cfg, params, state, decode, tokens, start, prompt_len,
-                    new_tokens, slot, table_row, prev_token):
+                    new_tokens, slot, table_row, prev_token,
+                    grouped_kernel=False):
     """``prefill_chunk_into_slot`` of a stack whose multi-token-
     prediction module drafts (see there: ``prev_token``), greedy."""
     if decode.temperature > 0.0:
@@ -2360,14 +2387,15 @@ def _drafting_chunk(cfg, params, state, decode, tokens, start, prompt_len,
     def recomputed():
         return forward_layer_types(
             cfg, params, jnp.reshape(prev_token, (1, 1)), cache, start - 1,
-            tables=table_row, hidden=True, store=False)[0]
+            tables=table_row, hidden=True, store=False,
+            grouped_kernel=grouped_kernel)[0]
 
     before = jax.lax.cond(
         prev_token >= 0, recomputed,
         lambda: state["mtp_hidden"][slot][None, None])
     hidden, cache, _, _ = forward_layer_types(
         cfg, params, tokens, cache, start, tables=table_row, n_new=real,
-        hidden=True)
+        hidden=True, grouped_kernel=grouped_kernel)
     with jax.named_scope("kft.sample"):
         idx = jnp.clip(prompt_len - 1 - start, 0, w - 1)
         last = jax.lax.dynamic_slice_in_dim(hidden, idx, 1, axis=1)
@@ -2383,13 +2411,14 @@ def _drafting_chunk(cfg, params, state, decode, tokens, start, prompt_len,
                             axis=1),
             tokens, cache, start, (index - 1)[None],
             live=((index >= 1) & (index < prompt_len))[None],
-            tables=table_row)
+            tables=table_row, grouped_kernel=grouped_kernel)
         # The row of (h_{p-1}, first token) at index p, whose argmax is
         # the slot's first draft; its write is past a chunk that is not
         # the last and is overwritten by the one that is.
         drafts, cache, _ = mtp_logits(
             cfg, params, last, tok[:, None], cache, prompt_len,
-            jnp.reshape(prompt_len - 1, (1, 1)), tables=table_row)
+            jnp.reshape(prompt_len - 1, (1, 1)), tables=table_row,
+            grouped_kernel=grouped_kernel)
         draft = jnp.argmax(drafts[0, 0]).astype(jnp.int32)
     with jax.named_scope("kft.sample"):
         is_last = (start + w) >= prompt_len
@@ -2409,7 +2438,8 @@ def _drafting_chunk(cfg, params, state, decode, tokens, start, prompt_len,
     return state, tok
 
 
-@partial(jax.jit, static_argnums=(0, 3), donate_argnums=(2,))
+@partial(jax.jit, static_argnums=(0, 3),
+         static_argnames=("grouped_kernel",), donate_argnums=(2,))
 def prefill_chunk_into_slot(
     cfg: TransformerConfig,
     params,
@@ -2424,6 +2454,8 @@ def prefill_chunk_into_slot(
     table_row: jax.Array,
     adapter_id: Optional[jax.Array] = None,
     prev_token: Optional[jax.Array] = None,
+    *,
+    grouped_kernel: bool = False,
 ):
     """Extend slot ``slot``'s KV by one static-width chunk of prompt
     starting at traced cache offset ``start``; returns
@@ -2451,6 +2483,8 @@ def prefill_chunk_into_slot(
     writing a row (a shared page is never written); -1 takes the
     state's.  The final chunk also runs the row (h_{p-1}, first token)
     at index p and arms the slot's first draft with its argmax.
+
+    grouped_kernel (static): as ``decode_rounds``'s.
 
     tokens [1, chunk_w]: the prompt's tokens [start, start + chunk_w),
     right-padded past ``prompt_len`` on the final chunk.  table_row
@@ -2487,7 +2521,7 @@ def prefill_chunk_into_slot(
     if cfg.mtp_layers:
         return _drafting_chunk(cfg, params, state, decode, tokens, start,
                                prompt_len, new_tokens, slot, table_row,
-                               prev_token)
+                               prev_token, grouped_kernel)
     slots_n = state["done"].shape[0]
     w = tokens.shape[1]
     aid = (jnp.zeros((), jnp.int32) if adapter_id is None
@@ -2503,7 +2537,8 @@ def prefill_chunk_into_slot(
         logits, cache, conv, _ = forward_layer_types(
             cfg, params, tokens, tuple(state[side] for side in sides),
             start, tables=table_row, conv=state.get("conv"),
-            rows=jnp.reshape(slot, (1,)), fresh=first, n_new=real)
+            rows=jnp.reshape(slot, (1,)), fresh=first, n_new=real,
+            grouped_kernel=grouped_kernel)
     else:
         logits, cache = _forward_with_cache(
             cfg, params, tokens, tuple(state[side] for side in sides),

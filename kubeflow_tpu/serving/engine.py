@@ -484,6 +484,37 @@ def _plain_pool_platform(pool) -> Optional[str]:
     return next(iter(pool.sharding.device_set)).platform
 
 
+def _expert_matrices_platform(cfg, params) -> Optional[str]:
+    """Platform of the device the expert layers' stacked matrices
+    (``moe/wi``, ``moe/wo``) live on; None where the stack holds none, or
+    one of them is not what ops/grouped_matmul.py reads where it lies: a
+    plain array in the model's bfloat16 (no cast is a copy) whose columns
+    fill whole 128-lane tiles.  Reads the sharding, as
+    ``_plain_pool_platform``."""
+    import jax
+    import jax.numpy as jnp
+    from flax import linen as nn
+
+    from kubeflow_tpu.ops import grouped_matmul
+    from kubeflow_tpu.ops.quantize import QTensor
+
+    if cfg.dtype != jnp.bfloat16:
+        return None
+    platforms = set()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            nn.unbox(params), is_leaf=lambda x: isinstance(x, QTensor)):
+        keys = [getattr(k, "key", None) for k in path]
+        if "moe" not in keys or "shared" in keys \
+                or keys[-1] not in ("wi", "wo"):
+            continue
+        if isinstance(leaf, QTensor) or leaf.ndim != 3 \
+                or leaf.dtype != jnp.bfloat16 \
+                or not grouped_matmul.supports(*leaf.shape[1:]):
+            return None
+        platforms.add(next(iter(leaf.sharding.device_set)).platform)
+    return platforms.pop() if len(platforms) == 1 else None
+
+
 class DecodeEngine:
     """Continuous-batching decode over a persistent slot-based KV cache.
 
@@ -781,6 +812,17 @@ class DecodeEngine:
         # page in place, as the program itself decides from the pool.
         self._index_walk = self._paged_kernel and cfg.indexed \
             and index_keys_walked(self._state["cache_index"])
+        # Decided once the same way, for BOTH programs: the expert
+        # layers' grouped products run through ops/grouped_matmul.py
+        # when the expert matrices are plain bfloat16 arrays on a TPU
+        # and no mesh shards them (under one the compiler's own
+        # ragged_dot is what gets partitioned).  Static for the
+        # programs; stats()["grouped_kernel_steps"] over "steps" and
+        # "grouped_kernel_chunks" over "prefill_chunks" say what it
+        # served.
+        self._grouped_kernel = bool(
+            mesh is None and cfg.layer_types and cfg.moe_experts
+            and _expert_matrices_platform(cfg, params) == "tpu")
         # Host-owned per-slot block tables, passed into every program
         # call; the sentinel value (== pool size) parks writes and
         # reads of unallocated logical pages.  Loop-thread-owned.
@@ -878,6 +920,7 @@ class DecodeEngine:
             "handoff_pages_out": 0, "handoff_pages_in": 0,
             "fused_rounds": 0, "fused_steps_wasted": 0,
             "decode_kernel_steps": 0,
+            "grouped_kernel_steps": 0, "grouped_kernel_chunks": 0,
             "spill_pages_out": 0, "spill_pages_in": 0,
             "parked_sessions": 0, "fetches": 0, "experts_touched": 0,
             **dict.fromkeys(_PAIR_KEYS, 0),
@@ -1569,6 +1612,11 @@ class DecodeEngine:
             # (decode_rounds on a TPU pool): over "steps"
             # it is the share the kernel served, 0 off the chip.
             "decode_kernel_steps": c["decode_kernel_steps"],
+            # Decode steps and prefill chunks whose expert layers ran
+            # their grouped products through ops/grouped_matmul.py:
+            # over "steps" / "prefill_chunks" the share it served.
+            "grouped_kernel_steps": c["grouped_kernel_steps"],
+            "grouped_kernel_chunks": c["grouped_kernel_chunks"],
             "steps_per_round_p50": pct_raw(rounds, 0.50),
             "steps_per_round_p99": pct_raw(rounds, 0.99),
             # Which AOT programs exist — the four-program guarantee,
@@ -2552,7 +2600,8 @@ class DecodeEngine:
             if self._mtp:
                 lower_args += [None, np.int32(-1)]
             self._chunk_exec = self._aot(
-                prefill_chunk_into_slot, *lower_args)
+                prefill_chunk_into_slot, *lower_args,
+                grouped_kernel=self._grouped_kernel)
         call_args = [
             self.params, self._state, chunk,
             np.int32(start), np.int32(true_len), np.int32(entry["new"]),
@@ -2594,6 +2643,7 @@ class DecodeEngine:
                 self._tables.shape[1], self.kv_block_tokens, w, start + w)
         with self._lock:
             self._counters["prefill_chunks"] += 1
+            self._counters["grouped_kernel_chunks"] += self._grouped_kernel
             self._counters["prefill_positions_held"] += min(
                 start + w, true_len)
             self._counters["prefill_positions_scored"] += scored
@@ -2780,6 +2830,8 @@ class DecodeEngine:
         with self._lock:
             self._counters["steps"] += steps
             self._counters["decode_kernel_steps"] += kernel_steps
+            if self._grouped_kernel and program == "decode":
+                self._counters["grouped_kernel_steps"] += steps
             self._counters["occupancy_sum"] += occupancy
             self._counters["busy_s"] += dt
             if extra:
@@ -2992,7 +3044,8 @@ class DecodeEngine:
                 self._rounds_exec = self._aot(
                     decode_rounds, self.cfg, self.params, self._state,
                     self.decode, kmax, self._tables, np.int32(kmax),
-                    paged_kernel=self._paged_kernel)
+                    paged_kernel=self._paged_kernel,
+                    grouped_kernel=self._grouped_kernel)
                 if self.mesh is not None:
                     # The double-buffered upload must land the tables
                     # exactly where the SPMD executable expects them.

@@ -92,3 +92,21 @@ def interpreted_paged_kernel(monkeypatch):
         paged_attention, "paged_decode_attention",
         functools.partial(paged_attention.paged_decode_attention,
                           interpret=True))
+
+
+@pytest.fixture()
+def interpreted_grouped_kernel(monkeypatch):
+    """ops/grouped_matmul.py in the Pallas interpreter (product code
+    reaches it through the module attribute, models/generate.py
+    ``_experts``); yields the list of its calls' (rows, matrices) shapes."""
+    from kubeflow_tpu.ops import grouped_matmul
+
+    calls = []
+    real = grouped_matmul.grouped_matmul
+
+    def interpreted(rows, weights, sizes):
+        calls.append((rows.shape, weights.shape))
+        return real(rows, weights, sizes, interpret=True)
+
+    monkeypatch.setattr(grouped_matmul, "grouped_matmul", interpreted)
+    return calls

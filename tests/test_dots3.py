@@ -624,6 +624,27 @@ def test_decode_rounds_scores_index_keys_through_the_walk_kernel(
                                       chosen[live][real[live]])
 
 
+# -- ops/grouped_matmul.py in the grouped products' place ----------------------
+
+@pytest.mark.parametrize("live", sorted(test_lfm2.LIVE))
+def test_the_grouped_kernel_is_the_expert_layers_ragged_dot(
+        dots3, interpreted_grouped_kernel, live):
+    cfg, params = dots3
+    layer = next(i for i in range(cfg.n_layers) if cfg.layer_is_sparse(i))
+    test_lfm2.kernel_against_ragged_dot(
+        cfg, params["layers"][str(layer)]["moe"],
+        interpreted_grouped_kernel, test_lfm2.LIVE[live])
+
+
+def test_both_programs_serve_the_same_through_the_grouped_kernel(
+        dots3, interpreted_grouped_kernel):
+    cfg, params = dots3
+    test_lfm2.kernel_serves_what_ragged_dot_serves(
+        lambda: _served(cfg, params, new=6), _tokens(70, seed=21), 6)
+    sparse = sum(cfg.layer_is_sparse(i) for i in range(cfg.n_layers))
+    assert len(interpreted_grouped_kernel) == 2 * 2 * sparse
+
+
 # -- the engine ---------------------------------------------------------------
 
 def _engine(cfg, params, **kw):

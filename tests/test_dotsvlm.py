@@ -157,7 +157,8 @@ class Drafting(Served):
             self.cfg, self.params, self.state, self.decode, chunk,
             np.int32(start), np.int32(len(prompt)), np.int32(new),
             np.int32(slot), np.int32(7), self.tables[slot][None], None,
-            np.int32(prompt[start - 1] if after_hit else -1))
+            np.int32(prompt[start - 1] if after_hit else -1),
+            grouped_kernel=self.grouped_kernel)
         if start + CHUNK >= len(prompt):
             self.served[slot] = [int(first[0])]
             self.drafts[slot] = []
@@ -165,7 +166,8 @@ class Drafting(Served):
     def rounds(self, steps, k=4):
         self.state, toks, counts, ran, drafts = self.g.decode_rounds(
             self.cfg, self.params, self.state, self.decode, k, self.tables,
-            np.int32(steps), paged_kernel=self.kernel)
+            np.int32(steps), paged_kernel=self.kernel,
+            grouped_kernel=self.grouped_kernel)
         self.taken += int(self.state["mtp_counts"][1])
         for slot in self.served:
             n, at = int(counts[slot]), len(self.served[slot])
@@ -720,6 +722,33 @@ def test_the_shares_add_up_to_the_whole_layer(dots):
     whole = _reference_experts(moe, normed)
     assert np.abs(total - 3 * normed_shared - whole).max() < TOL
     assert np.abs(shared).max() > 100 * TOL and pairs == 72
+
+
+# -- ops/grouped_matmul.py in the grouped products' place ----------------------
+
+@pytest.mark.parametrize("live", sorted(test_lfm2.LIVE))
+@pytest.mark.parametrize("where", ["stack", "module"])
+def test_the_grouped_kernel_is_the_expert_layers_ragged_dot(
+        dots, interpreted_grouped_kernel, where, live):
+    """An expert layer of the main stack and the draft module's, under
+    the expert groups' choice."""
+    cfg, params = dots
+    layer = next(i for i in range(cfg.n_layers) if cfg.layer_is_sparse(i))
+    moe = params["mtp"]["layer"]["moe"] if where == "module" \
+        else params["layers"][str(layer)]["moe"]
+    test_lfm2.kernel_against_ragged_dot(
+        cfg, moe, interpreted_grouped_kernel, test_lfm2.LIVE[live])
+
+
+def test_both_programs_serve_the_same_through_the_grouped_kernel(
+        dots, interpreted_grouped_kernel):
+    """Verified pairs, drafts and the draft plane's fill: every expert
+    layer of both programs, the module's among them."""
+    cfg, params = dots
+    test_lfm2.kernel_serves_what_ragged_dot_serves(
+        lambda: Drafting(cfg, params, new=6), _tokens(70, seed=21), 6)
+    assert len(interpreted_grouped_kernel) >= 2 * 2 * (
+        1 + sum(cfg.layer_is_sparse(i) for i in range(cfg.n_layers)))
 
 
 # -- the engine: (b) again, (c) a hit is a cold request, (d) stops, (g) -------

@@ -182,7 +182,10 @@ def test_flax_forward_matches_the_reference(lfm2, n):
 # -- the serving path ---------------------------------------------------------
 
 class Served:
-    """A paged state with SLOTS slots, driven as the engine drives it."""
+    """A paged state with SLOTS slots, driven as the engine drives it
+    (``grouped_kernel``: what the engine would hand both programs)."""
+
+    grouped_kernel = False
 
     def __init__(self, cfg, params, new=4, reference=None):
         from kubeflow_tpu.models import generate as g
@@ -206,7 +209,8 @@ class Served:
         self.state, first = self.g.prefill_chunk_into_slot(
             self.cfg, self.params, self.state, self.decode, chunk,
             np.int32(start), np.int32(len(prompt)), np.int32(new),
-            np.int32(slot), np.int32(7), self.tables[slot][None])
+            np.int32(slot), np.int32(7), self.tables[slot][None],
+            grouped_kernel=self.grouped_kernel)
         if start + CHUNK >= len(prompt):
             self.served[slot] = [int(first[0])]
 
@@ -217,7 +221,7 @@ class Served:
     def rounds(self, steps):
         self.state, toks, counts, ran = self.g.decode_rounds(
             self.cfg, self.params, self.state, self.decode, 4, self.tables,
-            np.int32(steps))
+            np.int32(steps), grouped_kernel=self.grouped_kernel)
         for slot in self.served:
             self.served[slot] += [int(t) for t in
                                   toks[slot, :int(counts[slot])]]
@@ -426,6 +430,91 @@ def test_rows_that_are_no_tokens_choose_nothing(lfm2):
     assert 2 <= int(touched) <= 4
 
 
+# -- ops/grouped_matmul.py in the grouped products' place ----------------------
+
+# Which of an expert layer's rows are tokens: all, all but a parked slot
+# and a final chunk's padding, none.
+LIVE = {"all": None,
+        "some": [True, False, True, True, False, False, True, False, True,
+                 True, False, False],
+        "none": [False] * 12}
+
+
+def kernel_against_ragged_dot(cfg, moe, calls, live, tol=1e-5):
+    """``_experts`` over 12 seeded rows with its grouped products through
+    the kernel (interpreted: ``calls`` is the fixture's) against the same
+    through ``jax.lax.ragged_dot``: two calls a layer, the same output,
+    the same counts, and exact zeros for a row that is no token (the
+    kernel does not write it: the select covers what the buffer held)."""
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.models.generate import _experts
+
+    y = jnp.asarray(np.random.default_rng(11).normal(0, 1, (12, cfg.d_model)),
+                    jnp.float32)
+    live = None if live is None else jnp.asarray(live)
+    plain, counted = _experts(cfg, moe, y, live)
+    before = len(calls)
+    got, counts = _experts(cfg, moe, y, live, grouped_kernel=True)
+    assert len(calls) == before + 2
+    assert {k: int(v) for k, v in counts.items()} \
+        == {k: int(v) for k, v in counted.items()}
+    got, plain = np.asarray(got), np.asarray(plain)
+    assert np.isfinite(got).all()
+    assert np.abs(got - plain).max() < tol
+    if live is not None:
+        parked = ~np.asarray(live)
+        assert np.array_equal(got[parked], np.zeros_like(got[parked]))
+        if parked.all():
+            assert int(counts["touched"]) == 0
+    return counts
+
+
+def kernel_serves_what_ragged_dot_serves(make, prompt, new, tol=TOL):
+    """Slot 1 of a ``Served`` that ``make`` builds, through BOTH programs
+    with ``grouped_kernel`` (a final chunk of padding, then rounds beside
+    two parked slots), against the same without: the same tokens, the
+    same counts of the last call, the next position's logits."""
+    import jax
+
+    runs = []
+    for kernel in (False, True):
+        run = make()
+        run.grouped_kernel = kernel
+        run.prefill(1, prompt, new)
+        while len(run.served[1]) < new:
+            run.rounds(2)
+        runs.append(run)
+    plain, kernel = runs
+    assert kernel.served == plain.served and len(kernel.served[1]) == new
+    for key in ("moe_touched", "moe_pairs"):
+        if key in plain.state:
+            assert np.array_equal(np.asarray(kernel.state[key]),
+                                  np.asarray(plain.state[key])), key
+    for got, want in zip(jax.tree_util.tree_leaves(kernel.next_logits()),
+                         jax.tree_util.tree_leaves(plain.next_logits())):
+        assert np.abs(got - want).max() < tol
+
+
+@pytest.mark.parametrize("live", sorted(LIVE))
+def test_the_grouped_kernel_is_the_expert_layers_ragged_dot(
+        lfm2, interpreted_grouped_kernel, live):
+    cfg, params = lfm2
+    counts = kernel_against_ragged_dot(
+        cfg, params["layers"]["1"]["moe"], interpreted_grouped_kernel,
+        LIVE[live])
+    assert int(counts["absent"]) == int(counts["zero"]) == 0
+
+
+def test_both_programs_serve_the_same_through_the_grouped_kernel(
+        lfm2, interpreted_grouped_kernel):
+    cfg, params = lfm2
+    kernel_serves_what_ragged_dot_serves(
+        lambda: Served(cfg, params, new=6), _tokens(70, seed=21), 6)
+    # Both programs were traced with it: 4 sparse layers, two products.
+    assert len(interpreted_grouped_kernel) == 2 * 2 * 4
+
+
 # -- the engine ---------------------------------------------------------------
 
 def _engine(cfg, params, **kw):
@@ -474,6 +563,73 @@ def test_engine_serves_whole_prefills_and_refuses_page_features(lfm2):
             stats["moe_top_k"]) == (4, 8, 2)
     steps = stats["steps"]
     assert 4 * steps <= stats["experts_touched"] <= 4 * 2 * 3 * steps
+
+
+def test_engine_hands_both_programs_the_grouped_kernel_and_counts_it(
+        lfm2, monkeypatch, interpreted_grouped_kernel):
+    """Where the expert matrices are what ops/grouped_matmul.py reads
+    (here: said so for the CPU's float32 ones, the kernel interpreted),
+    the engine decides ONCE, both programs hold the kernel, and
+    ``grouped_kernel_steps`` / ``grouped_kernel_chunks`` count every
+    decode step and chunk; the tokens are the plain engine's."""
+    from kubeflow_tpu.serving import engine as engine_mod
+
+    cfg, params = lfm2
+    prompt = _tokens(70, seed=12)
+    served = {}
+    for kernel in (False, True):
+        if kernel:
+            monkeypatch.setattr(engine_mod, "_expert_matrices_platform",
+                                lambda cfg, params: "tpu")
+        engine = _engine(cfg, params)
+        try:
+            out = engine.submit({"tokens": prompt, "max_new_tokens": 5})
+            served[kernel] = (np.asarray(out["tokens"])[0], engine.stats())
+        finally:
+            engine.close(drain_s=0.0)
+    (plain, off), (tokens, on) = served[False], served[True]
+    assert np.array_equal(tokens, plain) and tokens.shape == (75,)
+    assert off["grouped_kernel_steps"] == off["grouped_kernel_chunks"] == 0
+    assert on["steps"] > 0 and on["grouped_kernel_steps"] == on["steps"]
+    assert on["grouped_kernel_chunks"] == on["prefill_chunks"] > 0
+    # Two programs, 4 sparse layers, two products a layer.
+    assert len(interpreted_grouped_kernel) == 2 * 4 * 2
+    assert on["experts_touched"] == off["experts_touched"]
+
+
+def test_the_kernel_is_for_plain_bfloat16_expert_matrices(lfm2):
+    """What ``DecodeEngine`` decides from: the platform of the expert
+    matrices' device, and None for what the kernel does not read where
+    it lies (another dtype than the model's bfloat16, a quantised leaf,
+    columns that fill no whole 128-lane tile, a stack without experts)."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubeflow_tpu.ops.quantize import QTensor
+    from kubeflow_tpu.serving.engine import _expert_matrices_platform
+
+    cfg, params = lfm2
+    assert _expert_matrices_platform(cfg, params) is None   # float32
+    low = dataclasses.replace(cfg, dtype=jnp.bfloat16)
+    # Narrow: 32 and 48 columns are no whole lane tile.
+    cast = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), params)
+    assert _expert_matrices_platform(low, cast) is None
+    moe = {"router": jnp.zeros((128, 4)), "bias": jnp.zeros((4,)),
+           "wi": jnp.zeros((4, 128, 256), jnp.bfloat16),
+           "wo": jnp.zeros((4, 128, 128), jnp.bfloat16),
+           "shared": {"wi": jnp.zeros((2, 128, 64), jnp.float32)}}
+    tree = {"layers": {"0": {"mlp": {"wi": jnp.zeros((2, 8, 8))}},
+                       "1": {"moe": moe}}}
+    assert _expert_matrices_platform(low, tree) == "cpu"
+    assert _expert_matrices_platform(cfg, tree) is None     # a cast copies
+    assert _expert_matrices_platform(low, {"layers": {"0": {}}}) is None
+    wide = dict(moe, wo=jnp.zeros((4, 128, 128), jnp.float32))
+    assert _expert_matrices_platform(
+        low, {"layers": {"1": {"moe": wide}}}) is None
+    quantised = dict(moe, wi=QTensor(
+        jnp.zeros((4, 128, 256), jnp.int8), jnp.ones((4, 1, 256))))
+    assert _expert_matrices_platform(
+        low, {"layers": {"1": {"moe": quantised}}}) is None
 
 
 def test_engine_stats_of_a_dense_stack_say_so():

@@ -715,6 +715,43 @@ def test_decode_rounds_through_the_latent_kernel_matches_the_reference(
     assert run.worst(1, prompt, run.next_logits()) < TOL
 
 
+# -- ops/grouped_matmul.py in the grouped products' place ----------------------
+
+@pytest.mark.parametrize("live", sorted(test_lfm2.LIVE))
+def test_the_grouped_kernel_is_the_expert_layers_ragged_dot(
+        longcat, interpreted_grouped_kernel, live):
+    """With zero-compute experts beside the held ones: their pairs are in
+    no group, and counted where they fell all the same."""
+    cfg, params = longcat
+    counts = test_lfm2.kernel_against_ragged_dot(
+        cfg, params["layers"]["0"]["moe"], interpreted_grouped_kernel,
+        test_lfm2.LIVE[live])
+    if live != "none":
+        assert int(counts["zero"]) > 0 and int(counts["held"]) > 0
+
+
+def test_the_grouped_kernel_over_a_share_of_the_experts(
+        longcat, interpreted_grouped_kernel):
+    """Two of the eight routed experts held: most pairs are another
+    chip's, sorted past every group, and the kernel visits none of them."""
+    cfg, params = longcat
+    share = dataclasses.replace(cfg, moe_experts_held=2,
+                                moe_experts_offset=4)
+    moe = params["layers"]["0"]["moe"]
+    moe = dict(moe, wi=moe["wi"][4:6], wo=moe["wo"][4:6])
+    counts = test_lfm2.kernel_against_ragged_dot(
+        share, moe, interpreted_grouped_kernel, None)
+    assert int(counts["absent"]) > int(counts["held"]) > 0
+
+
+def test_both_programs_serve_the_same_through_the_grouped_kernel(
+        longcat, interpreted_grouped_kernel):
+    cfg, params = longcat
+    test_lfm2.kernel_serves_what_ragged_dot_serves(
+        lambda: _served(cfg, params, new=6), _tokens(70, seed=21), 6)
+    assert len(interpreted_grouped_kernel) == 2 * 2 * len(cfg.layer_types)
+
+
 # -- the engine ---------------------------------------------------------------
 
 def _engine(cfg, params, **kw):

@@ -157,10 +157,11 @@ def _fits(compiled, gib=16):
     return live < gib * 2 ** 30
 
 
-def _compile_chunk(e, max_len):
+def _compile_chunk(e, max_len, **static):
     """``prefill_chunk_into_slot`` of the engine ``e`` describes, as wide
     as ``DecodeEngine`` makes it where nobody states a width: its
-    constant, clamped to ``prefill_len``."""
+    constant, clamped to ``prefill_len``; ``static``: what the engine
+    would decide for it (``grouped_kernel``)."""
     from kubeflow_tpu.models.generate import prefill_chunk_into_slot
     from kubeflow_tpu.serving.engine import PREFILL_CHUNK_TOKENS
 
@@ -169,7 +170,24 @@ def _compile_chunk(e, max_len):
     return prefill_chunk_into_slot.lower(
         e["cfg"], e["params"], e["state"], e["decode"], e["arg"](1, width),
         scalar, scalar, scalar, scalar, scalar,
-        e["arg"](1, e["table_blocks"])).compile()
+        e["arg"](1, e["table_blocks"]), **static).compile()
+
+
+def _grouped_calls(text):
+    """The lines of ops/grouped_matmul.py's custom calls in a program's
+    text (what ``DecodeEngine`` hands both programs of a stack whose
+    expert matrices are plain bfloat16 arrays on a TPU)."""
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and "%grouped_matmul" in line.split(" = ")[0]]
+
+
+def _holds_the_grouped_kernel(text, layers):
+    """Two calls an expert layer, each under ``kft.moe_experts``, and
+    nothing left of the compiler's ``ragged-dot``."""
+    calls = _grouped_calls(text)
+    return (len(calls) == 2 * layers and "ragged-dot" not in text
+            and all("kft.moe_experts/" in line for line in calls))
 
 
 def test_engine_prefill_chunk_compiles(engine_shapes):
@@ -484,6 +502,21 @@ def test_engine_programs_read_stacked_weights_in_place(cell_program, name,
         assert compiled.memory_analysis().temp_size_in_bytes < 0.85e9
 
 
+@pytest.mark.parametrize("program", ["decode_rounds",
+                                     "prefill_chunk_into_slot"])
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_dense_programs_hold_no_grouped_product(cell_program, name, program):
+    """The three configurations without ``layer_types`` never trace
+    ``_experts``: neither ops/grouped_matmul.py's call nor the compiler's
+    ``ragged-dot`` is in their programs, whatever an engine decides for
+    the stacks that have experts."""
+    _, compiled = cell_program(name, program)
+    text = compiled.as_text()
+    assert _grouped_calls(text) == []
+    assert "grouped_matmul" not in text and "ragged-dot" not in text
+    assert "kft.moe_experts" not in text
+
+
 # A stack with layer_types at the cell's sizes
 # (benchmark/configs/lfm2-24b-a2b-l10.json, cells/lfm2-24b-a2b-l10.workers):
 # 8 convolution layers with a per-slot state, 2 attention layers that own
@@ -514,8 +547,8 @@ def lfm2_program(chip):
             return e, generate.decode_rounds.lower(
                 e["cfg"], e["params"], e["state"], e["decode"], 8,
                 e["arg"](slots, e["table_blocks"]), e["arg"](),
-                paged_kernel=True).compile()
-        return e, _compile_chunk(e, max_len)
+                paged_kernel=True, grouped_kernel=True).compile()
+        return e, _compile_chunk(e, max_len, grouped_kernel=True)
 
     return compiled
 
@@ -527,7 +560,8 @@ def test_layer_types_programs_hold_both_states_and_the_experts_in_place(
     """Both engine programs of the LFM2 cut: the pool AND the convolution
     state come in donated and go out aliased, no array of a layer's
     experts (1.2 GB) is produced outside a fusion, the grouped products
-    are the chip's own kernel, and a call's temporaries stay under 0.5 GB
+    are ops/grouped_matmul.py's calls under ``kft.moe_experts`` (two an
+    expert layer), and a call's temporaries stay under 0.5 GB
     beside 10.53 GB of weights.  (``_pool_moves`` is not held to nothing
     here: a side of this pool is 23 MB, and the compiler parks it in the
     chip's fast memory between a step's scatters and brings it back for
@@ -540,7 +574,7 @@ def test_layer_types_programs_hold_both_states_and_the_experts_in_place(
     assert _weight_moves(text, [(64, 2048, 3072), (64, 1536, 2048),
                                 (2048, 3072), (1536, 2048),
                                 (2, 2048, 11_776)]) == []
-    assert text.count('op_name="ragged-dot-metadata"') == 8
+    assert _holds_the_grouped_kernel(text, 8)
     assert _fusions_given_up(text) == []
     m = compiled.memory_analysis()
     held = 2 * int(np.prod(pool.shape)) * 2 + int(np.prod(conv.shape)) * 2
@@ -597,8 +631,8 @@ def longcat_program(chip):
             return e, generate.decode_rounds.lower(
                 e["cfg"], e["params"], e["state"], e["decode"], 8,
                 e["arg"](slots, e["table_blocks"]), e["arg"](),
-                paged_kernel=True).compile()
-        return e, _compile_chunk(e, max_len)
+                paged_kernel=True, grouped_kernel=True).compile()
+        return e, _compile_chunk(e, max_len, grouped_kernel=True)
 
     return compiled
 
@@ -616,8 +650,8 @@ def test_latent_programs_hold_the_pool_and_the_weights_in_place(
     sliced prefetch into the fast memory (``ConcatBitcast`` of
     ``slice-done``s, ``copy-done`` of the cross-program prefetch) and one
     relayout a sublayer and CALL, named below; the grouped products are
-    the chip's own kernel, once a layer; no fusion the compiler gave up
-    on (PR 36's); and the whole fits
+    ops/grouped_matmul.py's calls, two a layer; no fusion the compiler
+    gave up on (PR 36's); and the whole fits
     under 15.2 GB, which is where ISSUE 37's fallback to 48 slots would
     have been taken (it is not: 14.81 and 14.97 GB)."""
     e, compiled = longcat_program(program)
@@ -639,7 +673,7 @@ def test_latent_programs_hold_the_pool_and_the_weights_in_place(
     # CALL, before its loop of steps; the chunk program nothing.
     assert len(relaid) == (8 if program == "decode_rounds" else 0), relaid
     assert all(name.startswith("copy.") for name in relaid)
-    assert text.count('op_name="ragged-dot-metadata"') == 4
+    assert _holds_the_grouped_kernel(text, 4)
     assert _fusions_given_up(text) == []
     m = compiled.memory_analysis()
     side = int(np.prod(pool.shape)) * 2
@@ -755,8 +789,8 @@ def dots3_program(chip):
             return e, generate.decode_rounds.lower(
                 e["cfg"], e["params"], e["state"], e["decode"], 8,
                 e["arg"](slots, e["table_blocks"]), e["arg"](),
-                paged_kernel=True).compile()
-        return e, _compile_chunk(e, max_len)
+                paged_kernel=True, grouped_kernel=True).compile()
+        return e, _compile_chunk(e, max_len, grouped_kernel=True)
 
     return compiled
 
@@ -790,7 +824,7 @@ def test_three_pools_come_in_donated_and_go_out_aliased(dots3_program,
     assert all(name.startswith(("copy-done", "custom-call"))
                for name in _weight_moves(text, [
                    (1536, 5120), (2, 5120, 1536), (5120, 1536)]))
-    assert text.count('op_name="ragged-dot-metadata"') == 4
+    assert _holds_the_grouped_kernel(text, 4)
     m = compiled.memory_analysis()
     print(program, "temp", m.temp_size_in_bytes, "args",
           m.argument_size_in_bytes)
@@ -941,12 +975,13 @@ def dotsvlm_program(chip):
             return e, generate.decode_rounds.lower(
                 e["cfg"], e["params"], e["state"], e["decode"], 8,
                 e["arg"](slots, e["table_blocks"]), e["arg"](),
-                paged_kernel=True).compile()
+                paged_kernel=True, grouped_kernel=True).compile()
         scalar = e["arg"]()
         return e, generate.prefill_chunk_into_slot.lower(
             e["cfg"], e["params"], e["state"], e["decode"],
             e["arg"](1, 256), scalar, scalar, scalar, scalar, scalar,
-            e["arg"](1, e["table_blocks"]), None, scalar).compile()
+            e["arg"](1, e["table_blocks"]), None, scalar,
+            grouped_kernel=True).compile()
 
     return compiled
 
@@ -995,8 +1030,9 @@ def test_drafting_decode_rounds_reads_each_plane_once_for_both_rows(
         dotsvlm_program):
     """A step's two rows a slot go through the latent kernel as rows of
     ONE call a plane (256 query rows of 640 lanes a slot): six calls a
-    step, the draft plane's among them; the chunk holds no kernel; the
-    grouped products are the chip's own kernel, once an expert layer."""
+    step, the draft plane's among them; the chunk holds no such kernel;
+    the grouped products are ops/grouped_matmul.py's calls, two an expert
+    layer (the module's among the five)."""
     _, compiled = dotsvlm_program("decode_rounds")
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
@@ -1004,9 +1040,17 @@ def test_drafting_decode_rounds_reads_each_plane_once_for_both_rows(
              and "%paged_latent_decode_attention" in line.split(" = ")[0]]
     assert len(calls) == 6
     assert all("bf16[32,256,640]" in line for line in calls)
-    assert text.count('op_name="ragged-dot-metadata"') == 5
+    assert _holds_the_grouped_kernel(text, 5)
     for scope in ("kft.mtp_draft", "kft.mtp_accept", "kft.moe_groups"):
         assert scope in text
     _, chunk = dotsvlm_program("prefill_chunk_into_slot")
     assert "paged_latent_decode_attention" not in chunk.as_text()
     assert "kft.mtp_fill" in chunk.as_text()
+    # The chunk: four expert layers, the same four for the position it
+    # recomputes after a hit, and the module's over the row that arms
+    # the first draft (8 sorted rows).  The module's pass over the
+    # chunk's rows only fills its plane: its experts feed nothing and the
+    # compiler drops them.
+    assert _holds_the_grouped_kernel(chunk.as_text(), 9)
+    assert sum("bf16[8,4096]" in line
+               for line in _grouped_calls(chunk.as_text())) == 1 + 4
