@@ -82,10 +82,15 @@ over ONE persistent PAGED KV block pool shared by ``slots`` sequences:
 
 The host's work for the NEXT round (page covers, the block-table
 upload, drafting, spill) runs while the device computes the current
-one, and the round's tokens are read once, at its end.  Completion is
-detected deterministically from the per-request budget (and, when EOS
-is configured, from the round's tokens — the device flag has already
-frozen the slot by then).
+one, and the round's tokens are read once.  Where another decode round
+follows, the loop keeps ONE round in flight: it dispatches round N+1
+(and the chunk before it) before it reads round N, so the read, the
+drain to the clients, the accounting and the next admission run beside
+a round on the device, not after one (``_dispatch_round``; the depth
+is the design, not a dial; a speculating or a stopping engine reads
+first).  Completion is detected deterministically from the per-request
+budget (and, when EOS is configured, from the round's tokens — the
+device flag has already frozen the slot by then).
 
 Interface-compatible with the batchers (submit/accepts/stats/close), so
 ModelServer.enable_batching wires it behind the REST and gRPC surfaces
@@ -94,6 +99,7 @@ unchanged.
 
 from __future__ import annotations
 
+import bisect
 import logging
 import threading
 import time
@@ -309,14 +315,18 @@ _PHASE_NOTE = {p: f"kft.engine.{p}" for p in _PHASES}
 # the largest program by the compiler's own account (arguments + outputs
 # + temporaries - aliased: memory_stats() leaves temporaries out).
 # The turnaround between two rounds (_ReadPhase, _device_has_work): from
-# a round's first result on the host to the return of the next call that
-# hands the device work, summed and counted.  The loop thread's CPU
+# the first result of a round that the loop did not get ahead of (nothing
+# was queued behind it) to the return of the next call that hands the
+# device work, summed and counted.  The loop thread's CPU
 # seconds (time.thread_time(), stored once an iteration): over the
 # phases in which it is not blocked (all but wait_work and round_wait)
 # they say whether its work ran or waited for a core or the interpreter
 # lock.  The iterations that took _SLOW_ROUND_FACTOR times the running
-# mean, and the seconds they took over it (_note_iteration).
-_SUM_KEYS = ("loop_rounds", *_PHASE_KEY.values(),
+# mean, and the seconds they took over it (_note_iteration).  The decode
+# rounds dispatched while the round before them was unread
+# (``_dispatch_round``): over the rounds, how often the loop kept the
+# device one round ahead of itself.
+_SUM_KEYS = ("loop_rounds", "rounds_ahead", *_PHASE_KEY.values(),
              "turnaround_s_sum", "turnarounds", "loop_cpu_s",
              "slow_rounds", "slow_round_s_sum",
              "queue_wait_s_sum", "admitted",
@@ -332,13 +342,21 @@ _PAIR_KEYS = ("pairs_held", "pairs_zero", "pairs_absent")
 # the first (``DecodeEngine._sparse_reads``).
 _SPARSE_KEYS = ("index_scored", "index_chosen", "window_read",
                 "index_read")
+# What ``decode_rounds`` counts of a call into the state it is handed
+# (it starts them at zero): the host reads them a round late, after the
+# state has been donated on, so each round's are taken out of the state
+# at its dispatch and spare arrays ride on in their place
+# (``DecodeEngine._dispatch_round``).
+_COUNT_KEYS = ("moe_touched", "moe_pairs", "mtp_counts")
 # An iteration that takes this many times the running mean of the
-# wall time (wait_work left out) of the iterations that waited for the
-# device is counted and logged with its own phase times; the mean
-# follows at _ITER_MEAN_ALPHA an iteration, a slow one counted as the
-# limit it passed.
+# wall time (wait_work left out) of the iterations that dispatched a
+# round or waited for the device is counted and logged with its own
+# phase times; the mean is the plain one of its first _ITER_MEAN_SAMPLES
+# iterations (the first of all waits for a program's first run) and
+# follows at one in so many from there, a slow one counted as the limit
+# it passed.
 _SLOW_ROUND_FACTOR = 8
-_ITER_MEAN_ALPHA = 1 / 64
+_ITER_MEAN_SAMPLES = 64
 
 
 class _Phase:
@@ -350,7 +368,11 @@ class _Phase:
     phase entered inside another is subtracted from it, so the eleven
     sums tile the thread's wall time; a window is two readings
     subtracted.  The facts of an iteration's phases are kept until the
-    next iteration for the record of a slow one (``_note_iteration``)."""
+    next iteration for the record of a slow one (``_note_iteration``).
+    The fact ``round`` is the iteration's number, and in the half that
+    reads and drains a decode round (``_finish_round``) the number of
+    the iteration that dispatched it, whichever iteration reads it: a
+    trace's reader pairs a round's dispatch with its wait by it."""
 
     __slots__ = ("_engine", "_key", "_note", "_t0", "_inner")
 
@@ -358,8 +380,7 @@ class _Phase:
         self._engine = engine
         self._key = _PHASE_KEY[name]
         self._note = engine._annotate(
-            _PHASE_NOTE[name], round=engine._counters["loop_rounds"],
-            **facts)
+            _PHASE_NOTE[name], round=engine._round_no, **facts)
         if facts:
             engine._iter_facts.update(facts)
 
@@ -391,17 +412,38 @@ class _ReadPhase(_Phase):
     """``round_read``, entered inside ``round_wait`` the moment the read
     a round makes first has returned: ``round_wait``'s own stretch, the
     host blocked on the device, ends here, and what the round reads and
-    counts after it is this phase's.  The first one entered since the
-    device was last handed work opens the turnaround
-    (``_device_has_work``): nothing is queued on the device from here."""
+    counts after it is this phase's.  ``handed`` numbers the call whose
+    result was read (``_device_has_work``): where it is the LAST call
+    that handed the device work, nothing is queued on the device from
+    here and the turnaround opens; where the loop got ahead (the next
+    round, or a chunk, was dispatched before this read) none does."""
 
-    __slots__ = ()
+    __slots__ = ("_handed",)
+
+    def __init__(self, engine, handed):
+        super().__init__(engine, "round_read", {})
+        self._handed = handed
 
     def __enter__(self):
         super().__enter__()
-        if self._engine._ready_at is None:
+        if self._handed == self._engine._handed:
             self._engine._ready_at = self._t0
         return self
+
+
+class _Round:
+    """A decode round that has been dispatched and not yet read: what
+    ``_dispatch_round`` hands to ``_finish_round``.  ``number`` is the
+    iteration that dispatched it and ``handed`` its call's number
+    (``_device_has_work``); ``lengths`` and ``adds`` are, a row of
+    ``snapshot``, the cache positions before the round and what its
+    dispatch added to the entry's ``scheduled``; ``toks`` / ``counts`` /
+    ``steps_run`` / ``drafts`` are the program's results on the device,
+    and ``held`` its counts taken out of the state (``_COUNT_KEYS``)."""
+
+    __slots__ = ("number", "handed", "width", "live", "snapshot",
+                 "lengths", "adds", "t0", "toks", "counts", "steps_run",
+                 "drafts", "held")
 
 
 def _ngram_propose(history: np.ndarray, k: int,
@@ -901,10 +943,26 @@ class DecodeEngine:
         # Prompt tokens of prefill the rounds since the last chunk have
         # saved up (PREFILL_ROUND_TOKENS a round; _iterate).
         self._prefill_credit = 0
-        # (tokens_array, [(slot, entry), ...], counts) emissions not
-        # yet delivered, and the entries the current round advances.
+        # (tokens_array, [(slot, entry), ...], counts, handed) emissions
+        # not yet delivered, in the order their calls were handed to the
+        # device, and the entries the current round advances.
         self._pending: List[tuple] = []
         self._advancing: List[dict] = []
+        # The decode rounds dispatched and not yet read, oldest first:
+        # one between two iterations where the loop runs ahead, two from
+        # the next round's dispatch to the older one's drain.
+        self._unread: List[_Round] = []
+        # Two sets of spare arrays for the counts a round leaves in the
+        # state (_COUNT_KEYS): a dispatch takes the round's own out and
+        # puts a spare set in, the read gives its set back, and one
+        # round in flight needs no third.
+        self._count_keys = tuple(
+            key for key in _COUNT_KEYS if key in self._state)
+        self._count_spares = [
+            {key: jax.device_put(
+                np.zeros(self._state[key].shape, self._state[key].dtype),
+                self._state[key].sharding)
+             for key in self._count_keys} for _ in range(2)]
         # Counters (mutated by the loop thread, snapshotted under the
         # lock — the same locked-snapshot discipline MicroBatcher uses).
         self._counters = {
@@ -932,14 +990,19 @@ class DecodeEngine:
         self._annotate = jax.profiler.TraceAnnotation
         self._phases: List[_Phase] = []  # loop-thread-owned stack
         self._loop_pushed = dict.fromkeys(_PHASE_KEY.values(), 0.0)
-        # Loop-thread-owned, as the stack: when the open turnaround's
-        # first result landed (None: the device has work, or the loop
-        # went idle), the facts of this iteration's phases, the running
-        # mean of an iteration's wall time, the compile seconds it has
-        # seen and when it last logged a slow iteration.
+        # Loop-thread-owned, as the stack: the number its phases are
+        # stamped with (_Phase), how many calls have handed the device
+        # work, when the open turnaround's first result landed (None:
+        # the device has work, or the loop went idle), the facts of this
+        # iteration's phases, the running mean of an iteration's wall
+        # time and how many iterations are in it, the compile seconds
+        # it has seen and when it last logged a slow iteration.
+        self._round_no = 0
+        self._handed = 0
         self._ready_at: Optional[float] = None
         self._iter_facts: Dict[str, Any] = {}
-        self._iter_mean: Optional[float] = None
+        self._iter_mean = 0.0
+        self._iter_samples = 0
         self._compile_seen = 0.0
         self._slow_logged_at = float("-inf")
         self._step_times: List[float] = []   # bounded reservoirs
@@ -1711,32 +1774,40 @@ class DecodeEngine:
         """``with self._phase("drain"):`` — loop thread only."""
         return _Phase(self, name, facts)
 
-    def _round_read(self) -> _ReadPhase:
-        """``with self._round_read():`` inside ``round_wait``, right
-        after the read the round makes first."""
-        return _ReadPhase(self, "round_read", {})
+    def _round_read(self, handed: int) -> _ReadPhase:
+        """``with self._round_read(handed):`` inside ``round_wait``,
+        right after the read the round makes first; ``handed`` is the
+        number ``_device_has_work`` gave the call that was read."""
+        return _ReadPhase(self, handed)
 
-    def _device_has_work(self, phase: _Phase) -> None:
+    def _device_has_work(self, phase: _Phase) -> int:
         """A call that hands the device work (a round's, a verify
-        window's or a chunk's program) has just returned: the open
-        turnaround, if any, ends here.  It began when the first result of
-        the last round landed (``_ReadPhase``), the earliest moment the
-        loop could know the device had nothing left to do: the stretch
-        is the host's critical path of a round.  Summed and counted in
-        ``stats()``, and a fact of the dispatching ``phase`` so that a
-        traced run shows it beside the device's gap."""
-        ready = self._ready_at
-        if ready is None:
-            return
-        # Loop-thread-owned; wait_work forgets it under the lock only
-        # because that is where the loop finds out it has gone idle.
+        window's or a chunk's program) has just returned; the calls are
+        numbered, and this one's number is returned.  The open
+        turnaround, if any, ends here.  It began when the first result
+        of the last such call landed with nothing queued behind it
+        (``_ReadPhase``), the earliest moment the loop could know the
+        device had nothing left to do: the stretch is the host's
+        critical path of a round the loop did not get ahead of.  Summed
+        and counted in ``stats()``, and a fact of the dispatching
+        ``phase`` so that a traced run shows it beside the device's
+        gap."""
+        # Loop-thread-owned, as _ready_at below.
         # kft: allow=lock-guard
-        self._ready_at = None
-        took = time.perf_counter() - ready
-        counters = self._counters  # loop-thread-owned keys, one writer
-        counters["turnaround_s_sum"] += took
-        counters["turnarounds"] += 1
-        phase.facts(since_ready_us=int(took * 1e6))
+        self._handed += 1
+        ready = self._ready_at
+        if ready is not None:
+            # Loop-thread-owned; wait_work forgets it under the lock
+            # only because that is where the loop finds out it has gone
+            # idle.
+            # kft: allow=lock-guard
+            self._ready_at = None
+            took = time.perf_counter() - ready
+            counters = self._counters  # loop-thread-owned keys, one writer
+            counters["turnaround_s_sum"] += took
+            counters["turnarounds"] += 1
+            phase.facts(since_ready_us=int(took * 1e6))
+        return self._handed
 
     def _aot(self, fn, *args, **static):
         """``fn.lower(*args).compile()`` for every AOT program of the
@@ -1782,24 +1853,24 @@ class DecodeEngine:
         its own time in every phase and the facts of its phases: a 2 s
         round among 5 ms ones names its phase in the run it happens in.
         Neither held against the mean nor part of it: an iteration that
-        compiled a program, and one that never waited for the device (a
-        chunk dispatched with no slot live costs the host's 2 ms, and the
-        iteration that then reads the last chunk's token waits for all of
-        them: on the chip every prefilled context of a set-up read as a
-        slow iteration while those counted)."""
+        compiled a program, and one that neither dispatched a round nor
+        waited for the device (a chunk dispatched with no slot live costs
+        the host's 2 ms, and the iteration that then reads the last
+        chunk's token waits for all of them: on the chip every prefilled
+        context of a set-up read as a slow iteration while those
+        counted).  The round that opens a busy stretch is dispatched and
+        left unread (``_dispatch_round``): its iteration waits for
+        nothing and counts."""
         counters = self._counters  # loop-thread-owned keys, one writer
         if counters["compile_s"] != self._compile_seen:
             self._compile_seen = counters["compile_s"]
             return
-        if not own["round_wait"]:
+        if not own["round_wait"] and not own["round_dispatch"]:
             return
         took = sum(own.values()) - own["wait_work"]
         mean = self._iter_mean
-        if mean is None:
-            self._iter_mean = took
-            return
         limit = _SLOW_ROUND_FACTOR * mean
-        if took > limit:
+        if took > limit and self._iter_samples:
             counters["slow_rounds"] += 1
             counters["slow_round_s_sum"] += took - mean
             now = time.perf_counter()
@@ -1817,7 +1888,9 @@ class DecodeEngine:
                     *(facts.get(k, "-") for k in (
                         "width", "steps", "live", "admitted", "chunks")))
             took = limit
-        self._iter_mean = mean + _ITER_MEAN_ALPHA * (took - mean)
+        self._iter_samples = min(self._iter_samples + 1,
+                                 _ITER_MEAN_SAMPLES)
+        self._iter_mean = mean + (took - mean) / self._iter_samples
 
     def _free_slots_locked(self) -> List[int]:
         return [i for i, r in enumerate(self._slot_req) if r is None]
@@ -1884,10 +1957,12 @@ class DecodeEngine:
         first token of the request still in _pending is dropped by
         _drain_one's event-set check.  No other slot's state is
         touched, so co-resident generations are unaffected.  The
-        sweep runs between rounds, so expiry granularity is the round
-        (``_round_width`` keeps a round under the tightest deadline):
+        sweep runs between dispatches, with up to one round unread
+        (``_dispatch_round``), so expiry granularity is two rounds
+        (``_round_width`` keeps each under the tightest deadline):
         tokens that complete a request before the next sweep are
-        delivered."""
+        delivered, and an unread round's tokens for an expired request
+        are dropped at its drain."""
         pnow = faults.monotonic()
         expired: List[dict] = []
         live = []
@@ -2619,13 +2694,13 @@ class DecodeEngine:
         t0 = time.perf_counter()
         self._state, tok = self._chunk_exec(*call_args)
         dt = time.perf_counter() - t0
-        self._device_has_work(self._phases[-1])
+        handed = self._device_has_work(self._phases[-1])
         entry["pos"] = start + w
         finished = entry["pos"] >= true_len
         if finished:
             entry["prefilling"] = False
             entry["scheduled"] = 1
-            self._pending.append((tok, [(0, entry)], None))
+            self._pending.append((tok, [(0, entry)], None, handed))
             if self.prefix_caching:
                 # Publication is free: the full-block prefix pages
                 # this prefill just wrote ARE the cache entry — a
@@ -2711,7 +2786,7 @@ class DecodeEngine:
         accepted prefixes plus free token, and a decode round's
         [slots, k] per-step emissions (both cut at EOS/budget on
         device, so row s carries counts[s] real tokens)."""
-        arr, snapshot, counts = self._pending.pop(0)
+        arr, snapshot, counts, handed = self._pending.pop(0)
         if isinstance(arr, np.ndarray):
             host = arr  # the round already waited for it
         else:
@@ -2720,7 +2795,7 @@ class DecodeEngine:
             # the chunk has nothing else to read.
             with self._phase("round_wait"):
                 host = np.asarray(arr)
-                with self._round_read():
+                with self._round_read(handed):
                     pass
         emitted = 0
         finished = 0
@@ -2814,7 +2889,12 @@ class DecodeEngine:
         is one EMA sample, not k, so the spec gate prices fused decode
         by its delivered rate, not its call rate; ``round_steps``
         appends to the steps-per-round reservoir (fused rounds
-        only)."""
+        only).  A round dispatched while the round before it was unread
+        began, for this clock, where that one ended: the rounds' wall
+        time is counted once (``busy_s``, the pace that clamps a width
+        under a deadline)."""
+        if self._last_step_end is not None:
+            t0 = max(t0, self._last_step_end)
         dt = end - t0
         per_tok = dt / norm
         gap = (end - self._last_step_end
@@ -2865,9 +2945,10 @@ class DecodeEngine:
         """Current fused-round step width: the adaptive value, clamped
         so ``width x pace`` stays under the tightest live deadline's
         remaining tolerance.  Deadline expiry granularity is the ROUND
-        — the sweep only runs between dispatches — so an unclamped
-        width could schedule a whole round past the soonest deadline
-        and deliver nothing but a late 504 (docs §5.2e)."""
+        (two, where the loop runs one ahead) — the sweep only runs
+        between dispatches — so an unclamped width could schedule a
+        whole round past the soonest deadline and deliver nothing but a
+        late 504 (docs §5.2e)."""
         width = self._round_k
         pace = self._step_pace_ema
         if width > 1 and pace and pace > 0:
@@ -3003,39 +3084,64 @@ class DecodeEngine:
             return None
         return snapshot, draft, draft_len
 
-    def _fused_round(self, live: int) -> None:
-        """One decode round: a single
-        ``decode_rounds`` dispatch advances every live slot up to
-        ``width`` steps with device-side early exit the moment all are
-        done, and the host work for the NEXT round — cover growth, the
-        double-buffered block-table upload, the n-gram drafting scan —
-        runs in the overlap window while the device computes.  Drains
-        synchronously at the round boundary: admissions and expiries
-        join between rounds, and deadline expiry granularity becomes
-        the round (``_round_width`` clamps the width under the
-        tightest live deadline).  Greedy tokens do not depend on the
-        width: slot math is per-row independent, so scheduling
-        granularity cannot change any slot's token stream."""
+    def _dispatch_round(self, live: int) -> None:
+        """The first half of a decode round: a single ``decode_rounds``
+        dispatch advances every live slot up to ``width`` steps with
+        device-side early exit the moment all are done, and the host
+        work for the NEXT round — cover growth, the double-buffered
+        block-table upload, the n-gram drafting scan — runs in the
+        overlap window while the device computes.  The round joins
+        ``_unread``; ``_finish_round`` reads, drains and accounts it.
+
+        Where another decode round follows (``_another_round_follows``)
+        the loop runs the next iteration and dispatches THAT round
+        first, so the device finds it queued when this one ends, and the
+        host's read, drain, accounting, admission and chunk dispatch run
+        beside a round instead of after one.  The depth is one round.
+        Nothing a round needs from the host lies in the results of the
+        round before it: last tokens, lengths and ``done`` live on the
+        device and are chained from call to call, ``scheduled`` already
+        stands for every dispatched step, retirement is decided at
+        dispatch, and a slot the device stopped (``done``) rides along
+        and emits nothing.  What the next round cannot know yet it
+        covers for: a drafting stack's cover counts the unread round's
+        worst case too, the adaptive width sees waste and the queue one
+        round late, a freed slot may be claimed while its last round is
+        unread (the drain delivers by the snapshot's request objects, and
+        the claim's chunk is queued behind that round on the device),
+        pages come back at the drain, one round later, and admissions
+        and expiries join between dispatches, so that a deadline's
+        granularity becomes two rounds (``_round_width`` clamps each
+        under the tightest live deadline).  Greedy tokens do not depend
+        on the width nor on when a round is read: slot math is per-row
+        independent, so scheduling cannot change any slot's stream."""
         from kubeflow_tpu.models.generate import decode_rounds
 
         kmax = self.decode_rounds
         # A drafting model's step yields one token or two, and its
         # draft layer writes one index past them.
         per = 2 if self._mtp else 1
+        rnd = _Round()
+        rnd.number, rnd.live = self._round_no, live
         with self._phase("round_prepare"):
-            width = self._round_width()
-            snapshot = [(i, r) for i, r in enumerate(self._slot_req)
-                        if r is not None and not r["prefilling"]]
+            rnd.width = width = self._round_width()
+            rnd.snapshot = snapshot = [
+                (i, r) for i, r in enumerate(self._slot_req)
+                if r is not None and not r["prefilling"]]
             # Worst-case cover for the WHOLE round before dispatch: the
             # device may write `width` new positions per slot and the
             # block tables ride in as one host-owned snapshot.  The
             # admission reservation guarantees the pages, so this never
-            # blocks.
-            lengths = []  # per snapshot row: cache positions before the round
+            # blocks.  ``scheduled`` counts a drafting step as ONE token
+            # until its round is read: an unread round may have written
+            # as many again.
+            slack = (per - 1) * sum(u.width for u in self._unread)
+            # Per snapshot row: cache positions before the round.
+            rnd.lengths = lengths = []
             for _, r in snapshot:
                 lengths.append(r["tokens"].shape[1] + r["scheduled"])
                 self._ensure_cover(
-                    r, lengths[-1] + per * width - 1 + self._mtp)
+                    r, lengths[-1] + slack + per * width - 1 + self._mtp)
             if self._rounds_exec is None:
                 # One executable serves EVERY adaptive width: the buffer
                 # size k is static, the per-round step cap is a traced
@@ -3060,26 +3166,32 @@ class DecodeEngine:
             # waiter).  Outside the timed window so the injected stall
             # does not masquerade as device latency.
             faults.fire("engine.step")
-            tok_before = self._counters["tokens"]
-        t0 = time.perf_counter()
+        rnd.t0 = time.perf_counter()
         with self._phase("round_dispatch", width=width,
                          live=live) as phase:
-            self._state, toks, counts, steps_run, *drafts = \
-                self._rounds_exec(
+            self._state, rnd.toks, rnd.counts, rnd.steps_run, \
+                *rnd.drafts = self._rounds_exec(
                     self.params, self._state, tables, np.int32(width))
-            self._device_has_work(phase)
-            touched = self._state.get("moe_touched")
-            pairs = self._state.get("moe_pairs")
-            drafted = self._state.get("mtp_counts")
-            for count in (touched, pairs, drafted):
-                if count is not None:
-                    # Its copy to the host rides behind the round, so
-                    # the read at the boundary costs no round trip of
-                    # its own.
+            rnd.handed = self._device_has_work(phase)
+            # The round's counts leave the state before the next call
+            # donates it, and spare arrays ride on in their place
+            # (decode_rounds starts them at zero).  Their copies to the
+            # host ride behind the round, so the read at the boundary
+            # costs no round trip of its own.
+            rnd.held = {key: self._state[key] for key in self._count_keys}
+            if rnd.held:
+                self._state = {**self._state, **self._count_spares.pop()}
+                for count in rnd.held.values():
                     count.copy_to_host_async()
+            if self._unread:
+                # Loop-thread-owned key (see loop_rounds).
+                # kft: allow=lock-guard
+                self._counters["rounds_ahead"] += 1
+            # From here _abort finds the entries this round retires.
+            self._unread.append(rnd)
         # ---- overlap window: the dispatch returned as soon as the
-        # round was enqueued; everything until the np.asarray below
-        # runs while the device computes.
+        # round was enqueued; everything until the round before this
+        # one (or this one) is read runs while the device computes.
         with self._phase("overlap"):
             # Deterministic retirement at dispatch: with no EOS a slot
             # whose remaining budget fits this round is KNOWN to finish
@@ -3087,8 +3199,10 @@ class DecodeEngine:
             # can never stop short of a still-advancing slot's budget.
             # (A drafting model's slot emits ``width`` tokens at least:
             # what it emitted is read at the boundary.)
+            rnd.adds = []
             for i, r in snapshot:
-                r["scheduled"] = min(r["new"], r["scheduled"] + width)
+                rnd.adds.append(min(r["new"] - r["scheduled"], width))
+                r["scheduled"] += rnd.adds[-1]
                 if not self._eos and r["scheduled"] >= r["new"]:
                     # Loop-thread-owned (see _drain_one).
                     # kft: allow=lock-guard
@@ -3097,11 +3211,12 @@ class DecodeEngine:
             # their table upload now, so the next dispatch finds the
             # transfer already done (or at least in flight) instead of
             # paying it on the critical path.
+            slack += (per - 1) * width
             for i, r in snapshot:
                 if self._slot_req[i] is r:
                     self._ensure_cover(
                         r, r["tokens"].shape[1] + r["scheduled"]
-                        + (per - 1) * width + per * kmax - 1 + self._mtp)
+                        + slack + per * kmax - 1 + self._mtp)
             if self._tables_dirty:
                 self._refresh_tables_dev()
             # Overlapped drafting for the next boundary's verify round.
@@ -3115,15 +3230,34 @@ class DecodeEngine:
             # the admission path.
             if self.host_spill_blocks:
                 self._spill_tick(1)
-        # ---- round boundary: materialize ONCE, deliver, account.
+
+    def _another_round_follows(self, stopping: bool) -> bool:
+        """May the loop leave the round it has just dispatched unread and
+        go on to dispatch the next?  It may where a slot stays live
+        after that round's retirements, unless the engine is stopping
+        (it drains) or speculates: ``_drafts_for_round`` chooses the
+        next program from this round's tokens."""
+        return not stopping and not self.speculative_tokens and any(
+            r is not None and not r["prefilling"] for r in self._slot_req)
+
+    def _finish_round(self) -> None:
+        """The second half of the oldest unread decode round: wait for
+        its results, materialize them ONCE, deliver, account.  Its
+        phases carry the round's own number, whichever iteration runs
+        them."""
+        rnd = self._unread[0]
+        # Loop-thread-owned (see _device_has_work).
+        # kft: allow=lock-guard
+        self._round_no = rnd.number
+        snapshot, lengths = rnd.snapshot, rnd.lengths
         # ``round_wait``'s own stretch is the wait for the first result;
         # the other reads and the counts made of them are ``round_read``,
         # and the facts stay on ``round_wait``, which ends where they do.
         with self._phase("round_wait") as phase:
-            toks_np = np.asarray(toks)
-            with self._round_read():
-                counts_np = np.asarray(counts)
-                steps = int(steps_run)
+            toks_np = np.asarray(rnd.toks)
+            with self._round_read(rnd.handed):
+                counts_np = np.asarray(rnd.counts)
+                steps = int(rnd.steps_run)
                 # The device's own counts: the steps it ran of ``width``,
                 # and the cache positions those steps attended, summed
                 # over slots and steps (a slot that emitted n tokens from
@@ -3134,14 +3268,16 @@ class DecodeEngine:
                     n = int(counts_np[i])
                     attended += n * at + n * (n - 1) // 2
                 facts = {"steps": steps, "attended": attended}
-                if drafted is not None:
+                held = {key: np.asarray(count)
+                        for key, count in rnd.held.items()}
+                if "mtp_counts" in held:
                     # A drafting step's two rows read a plane's pages
                     # once: ``attended`` is the device's own sum, over
                     # live slots and steps, of what the later row saw.
-                    made, taken, seen = map(int, np.asarray(drafted))
+                    made, taken, seen = map(int, held["mtp_counts"])
                     facts.update(attended=seen, mtp_drafted=made,
                                  mtp_accepted=taken)
-                    drafts_np = np.asarray(drafts[0])
+                    drafts_np = np.asarray(rnd.drafts[0])
                     with self._lock:
                         self._counters["mtp_drafted"] += made
                         self._counters["mtp_accepted"] += taken
@@ -3161,37 +3297,47 @@ class DecodeEngine:
                     for key, n in reads.items():
                         self._sparse_reads_ctr.inc(
                             n, engine=self._metric_name, kind=key)
-                if touched is not None:
+                if "moe_touched" in held:
                     # The device's own count for this round
-                    # (decode_rounds starts it at zero), read before the
-                    # state is donated to the next dispatch.
-                    facts["experts_touched"] = int(touched)
+                    # (decode_rounds starts it at zero).
+                    facts["experts_touched"] = int(held["moe_touched"])
                     with self._lock:
                         self._counters["experts_touched"] += \
                             facts["experts_touched"]
-                if pairs is not None:
+                if "moe_pairs" in held:
                     fell = dict(zip(_PAIR_KEYS,
-                                    map(int, np.asarray(pairs))))
+                                    map(int, held["moe_pairs"])))
                     facts.update(fell)
                     with self._lock:
                         for key, n in fell.items():
                             self._counters[key] += n
                 phase.facts(**facts)
-                del toks, counts, steps_run, drafts  # freed in a phase
+                if rnd.held:
+                    self._count_spares.append(rnd.held)
+                # Freed in a phase.
+                rnd.toks = rnd.counts = rnd.steps_run = rnd.drafts = None
         with self._phase("drain"):
-            self._pending.append((toks_np, snapshot, counts_np))
+            tok_before = self._counters["tokens"]
+            # In the order the device ran them: a chunk dispatched after
+            # this round (the loop was ahead) hands its first token over
+            # after the round's tokens, one that came before it, before.
+            bisect.insort(self._pending,
+                          (toks_np, snapshot, counts_np, rnd.handed),
+                          key=lambda emission: emission[3])
             while self._pending:
                 self._drain_one()
             if self._mtp:
-                for i, r in snapshot:
+                for (i, r), add in zip(snapshot, rnd.adds):
                     # What the slot emitted is known here, not at
-                    # dispatch; the next round's cover counts from it.
+                    # dispatch, which counted ``add``; the covers of the
+                    # rounds not yet dispatched count from it.
                     n = int(counts_np[i])
-                    r["scheduled"] = max(r["scheduled"],
-                                         len(r["emitted"]))
+                    r["scheduled"] += n - add
                     if n and len(r["emitted"]) >= n:
                         r["mtp_drafts"].append(
                             (len(r["emitted"]) - n, drafts_np[i, :n]))
+            # Drained: _abort no longer has to find its entries here.
+            self._unread.pop(0)
         end = time.perf_counter()
         with self._phase("account"):
             delivered = self._counters["tokens"] - tok_before
@@ -3203,17 +3349,22 @@ class DecodeEngine:
             if dispatched and (self._queue
                                or wasted > _ROUND_WASTE_FRAC * dispatched):
                 self._round_k = max(1, self._round_k // 2)
-            elif steps >= width and not wasted:
-                self._round_k = min(kmax, self._round_k + 1)
-            norm = max(1, steps)
+            elif steps >= rnd.width and not wasted:
+                self._round_k = min(self.decode_rounds, self._round_k + 1)
+            # A round may run no step: every slot it was handed had
+            # stopped in the round before it, which was unread (an EOS,
+            # a drafting stack's budget met early).
             self._record_step_timing(
-                t0, end, norm, steps=norm, occupancy=live * norm,
+                rnd.t0, end, max(1, steps), steps=steps,
+                occupancy=rnd.live * steps,
                 extra={"fused_rounds": 1, "fused_steps_wasted": wasted},
                 delivered=delivered, round_steps=steps)
             self._fused_rounds_ctr.inc(1, engine=self._metric_name)
             if wasted:
                 self._fused_wasted_ctr.inc(wasted,
                                            engine=self._metric_name)
+        # kft: allow=lock-guard
+        self._round_no = self._counters["loop_rounds"]
 
     def _spec_gates_pass(self, draft_len) -> bool:
         """Should this round's proposals actually dispatch verify?
@@ -3285,17 +3436,17 @@ class DecodeEngine:
                          live=live) as phase:
             self._state, toks, counts = self._verify_exec(
                 self.params, self._state, draft, draft_len, self._tables)
-            self._device_has_work(phase)
+            handed = self._device_has_work(phase)
         # Materialize ONCE and share the host copies with the drain —
         # a second device->host transfer per round would show up at
         # this call rate.
         with self._phase("round_wait"):
             toks_np = np.asarray(toks)
-            with self._round_read():
+            with self._round_read(handed):
                 counts_np = np.asarray(counts)
                 del toks, counts  # freed here, inside a phase
         with self._phase("drain"):
-            self._pending.append((toks_np, snapshot, counts_np))
+            self._pending.append((toks_np, snapshot, counts_np, handed))
             while self._pending:
                 self._drain_one()
         end = time.perf_counter()
@@ -3372,10 +3523,11 @@ class DecodeEngine:
         """The loop thread.  Every statement of an iteration lies in
         one ``_phase`` (admit with wait_work inside it, housekeeping,
         prefill_dispatch, round_prepare, then the decode or the verify
-        round's round_dispatch / overlap / round_wait with round_read
-        inside it / drain, account), so the ``loop_*_s`` sums tile the
-        thread's wall time and every idle gap of the device falls into a
-        named phase."""
+        round's round_dispatch / overlap, and round_wait with round_read
+        inside it / drain / account for each round the iteration reads:
+        the one before where the loop runs ahead, its own where no round
+        follows), so the ``loop_*_s`` sums tile the thread's wall time
+        and every idle gap of the device falls into a named phase."""
         try:
             while True:
                 if not self._iterate():
@@ -3390,6 +3542,8 @@ class DecodeEngine:
         # bumped before the first phase so that it can name the round.
         # kft: allow=lock-guard
         self._counters["loop_rounds"] += 1
+        # kft: allow=lock-guard
+        self._round_no = self._counters["loop_rounds"]
         self._iter_facts.clear()
         with self._phase("admit"), self._lock:
             with self._phase("wait_work"):
@@ -3521,8 +3675,8 @@ class DecodeEngine:
         with self._phase("round_prepare"):
             self._set_occ_gauge(
                 sum(r is not None for r in self._slot_req))
-            # Kept on the engine for _abort: _fused_round retires a
-            # slot at dispatch, before the round's tokens are read.
+            # Kept on the engine for _abort: a drain frees a slot
+            # before it resolves the slot's request.
             self._advancing = [r for r in self._slot_req
                                if r is not None and not r["prefilling"]]
             live = len(self._advancing)
@@ -3531,8 +3685,17 @@ class DecodeEngine:
         if drafts is not None:
             self._verify_round(*drafts, live)
         elif live:
-            self._fused_round(live)
+            # The round before this one, if the loop left it unread, is
+            # read now that the device has this one queued behind it;
+            # this one stays unread in its turn where another follows.
+            self._dispatch_round(live)
+            if len(self._unread) > 1:
+                self._finish_round()
+            if not self._another_round_follows(stopping):
+                self._finish_round()
         else:
+            if self._unread:  # its last live slot has just expired
+                self._finish_round()
             with self._phase("drain"):
                 self._last_step_end = None
                 if not self._prefilling:
@@ -3597,10 +3760,10 @@ class DecodeEngine:
             RuntimeError(f"engine loop died: {exc!r}")
         self._fail_queue(err)
         # Fail live slots AND requests whose slots were already
-        # deterministically retired at the dispatch of the round that
-        # died — those entries are in neither the queue nor the slot
-        # table, and leaving them unresolved would park their clients
-        # in submit() forever.
+        # deterministically retired at the dispatch of a round that
+        # died or was still unread — those entries are in neither the
+        # queue nor the slot table, and leaving them unresolved would
+        # park their clients in submit() forever.
         for i, entry in enumerate(self._slot_req):
             if entry is not None and not entry["event"].is_set():
                 self._unpin_adapter(entry)
@@ -3611,13 +3774,16 @@ class DecodeEngine:
             # kft: allow=lock-guard
             self._slot_req[i] = None
         for entry in self._advancing + [
-                e for _, snapshot, _ in self._pending
+                e for snapshot in (
+                    *(rnd.snapshot for rnd in self._unread),
+                    *(emission[1] for emission in self._pending))
                 for _, e in snapshot]:
             if not entry["event"].is_set():
                 self._unpin_adapter(entry)
                 entry["err"] = err
                 entry["event"].set()
         self._advancing = []
+        self._unread.clear()
         self._pending.clear()
         self._prefilling.clear()
         self._set_occ_gauge(0)

@@ -228,8 +228,26 @@ PATHS = {
 }
 
 
+def _iterations(top):
+    """The top-level annotations of the loop thread, cut at every
+    ``admit``: one list an iteration."""
+    out = []
+    for e in top:
+        if e["name"] == PREFIX + "admit":
+            out.append([])
+        out[-1].append(e)
+    return out
+
+
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_phases_tile_every_iteration_in_order(lm, path, monkeypatch):
+    """An iteration is its dispatching half (``admit`` ... ``overlap``,
+    in rank order, stamped with its own number) and then the reading
+    halves (``round_wait`` / ``drain`` / ``account``) of the rounds it
+    reads: none where it leaves its round unread, the round before where
+    the loop is ahead (PR 49), its own too where no round follows; each
+    reading half carries the number of the iteration that DISPATCHED the
+    round.  The top-level phases tile the thread's wall time."""
     import jax
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
@@ -242,50 +260,76 @@ def test_phases_tile_every_iteration_in_order(lm, path, monkeypatch):
             prompts = [[7, 8, 9] * 5 for _ in range(4)]
         _serve(engine, prompts)
         assert engine.compiled_programs()[program] == 1
+        stats = engine.stats()
     finally:
         engine.close()
     events = [e for e in Recorder.events if "t1" in e]
     assert {e["thread"] for e in events} == {engine._thread.ident}
     assert {e["name"] for e in events} <= {
         "kft.engine." + p for p in list(RANK) + list(NESTED)}
-    rounds = {}
+    # Enter order is time order.
+    assert [e["t0"] for e in events] == sorted(e["t0"] for e in events)
+    top, opened = [], []
     for e in events:
-        rounds.setdefault(e["facts"]["round"], []).append(e)
-    assert sorted(rounds) == list(range(min(rounds), max(rounds) + 1))
-    stepped, covered, span = 0, 0.0, 0.0
-    for number, group in sorted(rounds.items()):
-        # Enter order is time order, and the round number never falls.
-        assert [e["t0"] for e in group] == sorted(e["t0"] for e in group)
-        top, rank, opened = [], -1, []
-        for e in group:
-            name = e["name"][len(PREFIX):]
-            while opened and opened[-1]["t1"] <= e["t0"]:
-                opened.pop()
-            if opened:  # inside the one that is still open
-                assert NESTED[name] == opened[-1]["name"][len(PREFIX):]
-                assert e["t1"] <= opened[-1]["t1"]
-            else:
-                assert RANK[name] >= rank, (number, name)
-                rank = RANK[name]
-                top.append(e)
-            opened.append(e)
-        names = [e["name"][len("kft.engine."):] for e in top]
-        if number == max(rounds):  # closed and drained: the loop left
+        name = e["name"][len(PREFIX):]
+        while opened and opened[-1]["t1"] <= e["t0"]:
+            opened.pop()
+        if opened:  # inside the one that is still open
+            assert NESTED[name] == opened[-1]["name"][len(PREFIX):]
+            assert e["t1"] <= opened[-1]["t1"]
+            assert e["facts"]["round"] == opened[-1]["facts"]["round"]
+        else:
+            top.append(e)
+        opened.append(e)
+    iterations = _iterations(top)
+    numbers = [group[0]["facts"]["round"] for group in iterations]
+    assert numbers == list(range(numbers[0], numbers[0] + len(numbers)))
+    stepped, ahead, covered, span, dispatched = 0, 0, 0.0, 0.0, set()
+    for number, group in zip(numbers, iterations):
+        names = [e["name"][len(PREFIX):] for e in group]
+        if number == numbers[-1]:  # closed and drained: the loop left
             assert names == ["admit"]
             continue
         assert names[0] == "admit" and names[-1] == "account"
+        # The halves: a new one begins at every top-level round_wait.
+        halves = [[]]
+        for e in group:
+            if e["name"] == PREFIX + "round_wait":
+                halves.append([])
+            halves[-1].append(e)
+        assert len(halves) <= 3
+        for half in halves:
+            ranks = [RANK[e["name"][len(PREFIX):]] for e in half]
+            assert ranks == sorted(ranks), (number, names)
+        # The closing account is the iteration's own again.
+        assert halves[-1][-1]["facts"]["round"] == number
+        assert all(e["facts"]["round"] == number for e in halves[0])
         if "round_dispatch" in names:
             stepped += 1
-            facts = top[names.index("round_dispatch")]["facts"]
+            dispatched.add(number)
+            facts = group[names.index("round_dispatch")]["facts"]
             assert facts["live"] >= 1 and facts["width"] >= 1
+        read = [half[0]["facts"]["round"] for half in halves[1:]]
+        for of, half in zip(read, halves[1:]):
+            # A round is read once, in its own iteration or the next.
+            assert of in dispatched and number - 1 <= of <= number
+            dispatched.remove(of)
+            assert all(e["facts"]["round"] == of for e in half[:-1])
+            ahead += of < number
+        assert read == sorted(read)
         # Tiling: the top-level phases never overlap, and over the run
         # (below) they leave no hole worth a name.
-        for a, b in zip(top, top[1:]):
+        for a, b in zip(group, group[1:]):
             assert a["t1"] <= b["t0"]
-        covered += sum(e["t1"] - e["t0"] for e in top)
-        span += top[-1]["t1"] - top[0]["t0"]
+        covered += sum(e["t1"] - e["t0"] for e in group)
+        span += group[-1]["t1"] - group[0]["t0"]
+    assert not dispatched  # every round that was dispatched was read
     assert 0.9 * span <= covered <= span
     assert stepped >= 3
+    # A round read in the iteration after its own was dispatched ahead
+    # of: the engine counts the same; a speculating engine reads first.
+    assert ahead == stats["rounds_ahead"]
+    assert (ahead == 0) if path == "verify" else (ahead >= 2)
     chunks = sum(e["facts"].get("chunks", 0) for e in events
                  if e["name"].endswith("prefill_dispatch"))
     assert chunks == engine.stats()["prefill_chunks"]
@@ -368,8 +412,9 @@ def test_loop_sums_are_monotone_and_add_up_to_the_wall_time(lm):
     for before, after in zip(readings, readings[1:]):
         for key, value in _loop_sums(before).items():
             assert after[key] >= value
-        for key in ("loop_rounds", "loop_cpu_s", "turnarounds",
-                    "turnaround_s_sum", "slow_rounds", "slow_round_s_sum"):
+        for key in ("loop_rounds", "rounds_ahead", "loop_cpu_s",
+                    "turnarounds", "turnaround_s_sum", "slow_rounds",
+                    "slow_round_s_sum"):
             assert after[key] >= before[key]
     assert b["loop_rounds"] > a["loop_rounds"]
     grown = sum(_loop_sums(b).values()) - sum(_loop_sums(a).values())
@@ -383,7 +428,12 @@ def test_loop_sums_are_monotone_and_add_up_to_the_wall_time(lm):
     # two clocks an iteration apart and what a wake-up costs).
     assert 0 < b["loop_cpu_s"] - a["loop_cpu_s"] \
         <= _unblocked(b) - _unblocked(a) + 0.01
-    assert b["turnarounds"] > a["turnarounds"]
+    # Most rounds were dispatched while the one before was unread, and
+    # those open no turnaround (its own test holds what one counts).
+    ahead = b["rounds_ahead"] - a["rounds_ahead"]
+    assert 0 < ahead < b["fused_rounds"] - a["fused_rounds"]
+    assert b["turnarounds"] - a["turnarounds"] \
+        <= b["fused_rounds"] - a["fused_rounds"] - ahead
 
 
 def _loop_events(engine):
@@ -451,12 +501,15 @@ def _hands_work(event):
 def test_turnaround_is_first_result_to_the_next_dispatchs_return(
         lm, monkeypatch):
     """``turnaround_s_sum`` / ``turnarounds`` against the recorded
-    annotations: a counted stretch starts where the first ``round_read``
-    since the last hand-over was entered and ends inside the next
+    annotations: a counted stretch starts where a ``round_read`` was
+    entered with NOTHING queued on the device (the call it read was the
+    last that handed the device work) and ends inside the next
     annotation that hands the device work, a chunk's where that comes
     before the round's; the dispatching annotation states it
-    (``since_ready_us``); nothing is counted across a ``wait_work`` in
-    which the loop had nothing to do."""
+    (``since_ready_us``).  A round the loop got ahead of (the next was
+    dispatched before it was read, PR 49) opens none, and nothing is
+    counted across a ``wait_work`` in which the loop had nothing to
+    do."""
     import jax
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
@@ -467,20 +520,39 @@ def test_turnaround_is_first_result_to_the_next_dispatchs_return(
         idle_from = time.perf_counter()
         time.sleep(0.08)  # the loop finds nothing to do and waits
         idle_to = time.perf_counter()
-        # A chunk between two rounds: a prompt of four chunks beside one
-        # of a single chunk, which decodes while the other prefills.
-        _serve(engine, _prompts(1, length=5) + _prompts(1, length=29, seed=3),
-               new=30)
+        # Long prompts with one decode step each: a request's only round
+        # retires it at dispatch while the next prompt is still being
+        # prefilled, so no round follows, the round is read at once and
+        # the next CHUNK ends the turnaround.
+        _serve(engine, _prompts(5, length=29, seed=3), new=2)
         stats = engine.stats()
     finally:
         engine.close()
     events = _loop_events(engine)
-    ready, counted, by_chunk, stated = None, [], 0, {}
+    # The calls that hand the device work, numbered as the engine does;
+    # a chunk's first token is taken for the last chunk's of its
+    # annotation.
+    handed, of_round, of_chunk = 0, {}, 0
+    ready, counted, by_chunk, stated, ahead = None, [], 0, {}, 0
+    drains = [e for e in events if e["name"] == "drain"]
     for e in events:
-        if e["name"] == "round_read" and ready is None:
-            ready = e["t0"]
+        if e["name"] == "round_read":
+            in_drain = any(d["t0"] <= e["t0"] and e["t1"] <= d["t1"]
+                           and d["t0"] < e["t0"] for d in drains)
+            was = of_chunk if in_drain else of_round[e["facts"]["round"]]
+            if was == handed:
+                assert ready is None
+                ready = e["t0"]
+            elif not in_drain:
+                ahead += 1
         if not _hands_work(e):
             continue
+        if e["name"] == "round_dispatch":
+            handed += 1
+            of_round[e["facts"]["round"]] = handed
+        else:
+            handed += e["facts"]["chunks"]
+            of_chunk = handed
         if "since_ready_us" in e["facts"]:
             took = e["facts"]["since_ready_us"] / 1e6
             # The stretch ends at the call's return, inside e.
@@ -493,10 +565,13 @@ def test_turnaround_is_first_result_to_the_next_dispatchs_return(
     assert len(counted) == stats["turnarounds"]
     assert stats["turnaround_s_sum"] == pytest.approx(
         sum(took for _, took in counted), abs=2e-6 * len(counted))
-    assert stats["turnarounds"] >= stats["fused_rounds"] // 2 >= 4
+    # The rounds the loop got ahead of opened none: each is the round
+    # BEFORE one that ``rounds_ahead`` counts.
+    assert ahead == stats["rounds_ahead"] >= 2
+    assert stats["turnarounds"] + ahead <= stats["fused_rounds"]
     # The chunk's call ended the turnaround where it came first, and
     # that iteration's round found none open.
-    assert by_chunk >= 1
+    assert by_chunk >= 2
     assert all(len(names) == 1 for names in stated.values())
     # The idle stretch: no counted turnaround spans its middle, and the
     # first hand-over after it closes none.

@@ -357,7 +357,7 @@ class TestFusedDecode:
     def test_fault_inside_fused_round_aborts_cleanly(
             self, engine_model, monkeypatch):
         """A device fault inside a fused round (seeded at the
-        engine.step chaos site, which _fused_round fires per dispatch)
+        engine.step chaos site, which _dispatch_round fires per dispatch)
         must error EVERY waiter — no hung client, no wedged loop."""
         from kubeflow_tpu.models import generate as gen_mod
         from kubeflow_tpu.serving.engine import DecodeEngine
@@ -415,6 +415,355 @@ class TestFusedDecode:
         assert calls["n"] == 2
         assert [type(outs[i]) for i in (0, 1)] == [RuntimeError] * 2
         engine.close()
+
+
+def _synchronous(monkeypatch):
+    """Every engine built from here on reads a round before it
+    dispatches the next: the loop as it was before PR 49, the same code
+    with ``_another_round_follows`` answering no."""
+    from kubeflow_tpu.serving.engine import DecodeEngine
+
+    monkeypatch.setattr(DecodeEngine, "_another_round_follows",
+                        lambda self, stopping: False)
+
+
+def _sparse_stack(kind):
+    """(module of the stack's own tests, cfg, params): ``lfm2`` counts
+    the experts its rounds touch, ``longcat`` also where its (row,
+    choice) pairs fell, ``dotsvlm`` drafts (two tokens a step)."""
+    module = pytest.importorskip(f"test_{kind}")
+    cfg = module._config()
+    return module, cfg, module._params(cfg)
+
+
+COUNTED = {
+    "lfm2": ("experts_touched",),
+    "longcat": ("experts_touched", "pairs_held", "pairs_zero",
+                "pairs_absent"),
+    "dotsvlm": ("experts_touched", "mtp_drafted", "mtp_accepted",
+                "mtp_steps"),
+}
+
+
+class TestOneRoundAhead:
+    """PR 49: the loop dispatches round N+1 before it reads round N
+    wherever another round follows.  Ordering only: no token, counter or
+    waiter may tell."""
+
+    @pytest.mark.parametrize("decode_rounds", [1, 4])
+    def test_mixed_run_ahead_is_generates_tokens(self, engine_model,
+                                                 decode_rounds):
+        """Admissions mid-stream, retirements at dispatch, a prefix hit
+        and two slots for nine requests (a freed slot is claimed while
+        its last round is still unread): every request gets exactly the
+        reference generator's tokens, and most rounds were dispatched
+        ahead."""
+        from kubeflow_tpu.serving.engine import DecodeEngine
+
+        spec, _ = engine_model
+        rng = np.random.RandomState(SEED + 49)
+        shared = rng.randint(1, VOCAB, size=(8,)).tolist()
+        prompts = [rng.randint(1, VOCAB, size=(n,)).tolist()
+                   for n in (3, 9, 16, 2, 11, 5, 7)]
+        prompts[2:2] = [shared + [17, 3], shared + [5, 9, 11]]
+        news = [12, 3, 7, 9, 12, 2, 10, 5, 12]
+        want = _reference_rows(spec, prompts, news)
+        engine = DecodeEngine(
+            spec["cfg"], spec["params"], spec["decode"], slots=2,
+            prefill_len=16, admit_width=2, prefill_chunk_tokens=8,
+            kv_block_tokens=4, decode_rounds=decode_rounds,
+            name=f"ahead-mixed-{decode_rounds}")
+        outs = [None] * len(prompts)
+
+        def client(i):
+            outs[i] = engine.submit({
+                "tokens": np.asarray(prompts[i], np.int32),
+                "max_new_tokens": news[i]})
+
+        try:
+            threads = [threading.Thread(target=client, args=(i,))
+                       for i in range(len(prompts))]
+            for t in threads:
+                t.start()
+                time.sleep(0.004)  # arrivals spread over the rounds
+            for t in threads:
+                t.join(timeout=60)
+            stats = engine.stats()
+        finally:
+            engine.close()
+        for i, ref in enumerate(want):
+            got = np.asarray(outs[i]["tokens"])[0].tolist()
+            assert got == ref, f"request {i} drifted from generate()"
+        assert stats["tokens"] == sum(news)
+        assert stats["prefix_hits"] >= 1
+        assert stats["in_flight_requests"] == stats["active_slots"] == 0
+        assert 0 < stats["rounds_ahead"] < stats["fused_rounds"] \
+            <= stats["loop_rounds"]
+        if decode_rounds == 1:
+            assert stats["rounds_ahead"] >= stats["fused_rounds"] // 2
+
+    @pytest.mark.parametrize("kind", sorted(COUNTED))
+    def test_counts_read_after_the_state_moved_on_add_up(
+            self, kind, monkeypatch):
+        """An expert stack's and a drafting stack's counts are read out
+        of a state that the next call has already been handed: served
+        one request after another (so that what a round holds does not
+        hang on the clients' threads) the tokens and every counter are
+        the synchronous loop's, and three requests at once get the
+        synchronous loop's tokens."""
+        import concurrent.futures as cf
+
+        from kubeflow_tpu.models.generate import DecodeConfig
+        from kubeflow_tpu.serving.engine import DecodeEngine
+
+        module, cfg, params = _sparse_stack(kind)
+        prompts = [module._tokens(n, seed=70 + n) for n in (20, 70, 9)]
+        served = {}
+        for ahead in (True, False):
+            if not ahead:
+                _synchronous(monkeypatch)
+            # Rounds of two steps at most: five or more a request.
+            engine = DecodeEngine(
+                cfg, params, DecodeConfig(max_new_tokens=12,
+                                          temperature=0.0),
+                slots=3, prefill_len=160, max_len=176,
+                prefill_chunk_tokens=64, decode_rounds=2,
+                name=f"ahead-{kind}-{int(ahead)}")
+            try:
+                alone = [np.asarray(engine.submit(
+                    {"tokens": p})["tokens"])[0] for p in prompts]
+                time.sleep(0.05)  # the last round's accounting
+                counted = engine.stats()
+                with cf.ThreadPoolExecutor(3) as pool:
+                    at_once = list(pool.map(
+                        lambda p: np.asarray(engine.submit(
+                            {"tokens": p})["tokens"])[0], prompts))
+                served[ahead] = (alone, counted, at_once, engine.stats())
+            finally:
+                engine.close(drain_s=0.0)
+        (alone, counted, at_once, after), (s_alone, s_counted, s_at_once,
+                                           s_after) = served[True], \
+            served[False]
+        for got, ref in zip(alone + at_once, s_alone + s_at_once):
+            assert np.array_equal(got, ref)
+        for key in COUNTED[kind] + ("tokens", "steps"):
+            assert counted[key] == s_counted[key], key
+            # (The tests' share of longcat's experts holds them all.)
+            assert counted[key] > 0 or key == "pairs_absent", key
+        assert after["tokens"] == s_after["tokens"]
+        assert counted["rounds_ahead"] >= 3 and after["rounds_ahead"] \
+            > counted["rounds_ahead"]
+        assert s_after["rounds_ahead"] == 0
+
+    def test_a_deadline_expires_with_rounds_unread(self, engine_model):
+        """A request whose deadline passes while the loop is a round
+        ahead gets DeadlineExceeded, its neighbour and the successor in
+        its slot get generate()'s tokens."""
+        from kubeflow_tpu.serving.engine import DecodeEngine
+
+        spec, _ = engine_model
+        rng = np.random.RandomState(SEED + 50)
+        prompt_c, prompt_a, prompt_b = (
+            rng.randint(1, VOCAB, size=(n,)).tolist() for n in (6, 5, 7))
+        outs: dict = {}
+        with faults.injected("seed=1;engine.step:sleep=0.04"):
+            engine = DecodeEngine(spec["cfg"], spec["params"],
+                                  spec["decode"], slots=2, prefill_len=16,
+                                  decode_rounds=1, name="ahead-deadline")
+
+            def client(key, prompt, deadline=None):
+                try:
+                    outs[key] = engine.submit(
+                        {"tokens": np.asarray(prompt, np.int32)},
+                        deadline=deadline)
+                except Exception as exc:  # noqa: BLE001 — the point
+                    outs[key] = exc
+
+            try:
+                t_c = threading.Thread(target=client,
+                                       args=("c", prompt_c))
+                t_c.start()
+                # Four or five one-step rounds of the twelve it asks for.
+                client("a", prompt_a, faults.monotonic() + 0.2)
+                assert isinstance(outs["a"], DeadlineExceeded), outs["a"]
+                client("b", prompt_b)
+                t_c.join(timeout=60)
+                stats = engine.stats()
+            finally:
+                engine.close()
+        assert stats["deadline_expired"] == 1
+        assert stats["in_flight_requests"] == 0
+        assert stats["rounds_ahead"] >= 8
+        want = _reference_rows(spec, [prompt_c, prompt_b],
+                               [NEW_TOKENS, NEW_TOKENS])
+        for key, ref in (("c", want[0]), ("b", want[1])):
+            assert np.asarray(outs[key]["tokens"])[0].tolist() == ref
+
+    def test_a_read_that_fails_with_two_rounds_unread_fails_every_waiter(
+            self, engine_model, monkeypatch):
+        """The third round's tokens cannot be read; the loop finds out
+        after it has dispatched the fourth.  The request the third or
+        fourth round retired at dispatch (in no slot any more) and the
+        one still live both get the error."""
+        from kubeflow_tpu.models import generate as gen_mod
+        from kubeflow_tpu.serving.engine import DecodeEngine
+
+        real = gen_mod.decode_rounds
+        calls = {"n": 0}
+
+        class _Unreadable:
+            def __array__(self, *a, **kw):
+                raise RuntimeError("device died")
+
+        class _ThirdRoundUnreadable:
+            def lower(self, *a, **kw):
+                exe = real.lower(*a, **kw).compile()
+
+                class _Compiled:
+                    def __getattr__(self, name):  # memory_analysis
+                        return getattr(exe, name)
+
+                    def __call__(self, *ra, **rkw):
+                        calls["n"] += 1
+                        state, toks, *rest = exe(*ra, **rkw)
+                        if calls["n"] == 3:
+                            toks = _Unreadable()
+                        return (state, toks, *rest)
+
+                class _Lowered:
+                    compile = staticmethod(_Compiled)
+
+                return _Lowered()
+
+        monkeypatch.setattr(gen_mod, "decode_rounds",
+                            _ThirdRoundUnreadable())
+        spec, _ = engine_model
+        engine = DecodeEngine(spec["cfg"], spec["params"],
+                              spec["decode"], slots=2, prefill_len=16,
+                              decode_rounds=1, name="ahead-abort")
+        outs: dict = {}
+
+        def client(i, new):
+            try:
+                outs[i] = engine.submit({
+                    "tokens": np.arange(1, 5, dtype=np.int32),
+                    "max_new_tokens": new})
+            except Exception as exc:  # noqa: BLE001 — the point
+                outs[i] = exc
+
+        threads = [threading.Thread(target=client, args=a)
+                   for a in ((0, 4), (1, 12))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads), (
+            "a client hung after the loop died a round ahead")
+        assert calls["n"] == 4  # the fourth was dispatched before the read
+        assert [type(outs[i]) for i in (0, 1)] == [RuntimeError] * 2
+        assert engine.stats()["in_flight_requests"] == 0
+        engine.close()
+
+    @pytest.mark.parametrize("drain_s", [10.0, 0.0])
+    def test_close_with_rounds_unread_resolves_every_waiter(
+            self, engine_model, drain_s):
+        """``close()`` while the loop is a round ahead: given time it
+        drains both unread rounds and every request gets its whole
+        answer; given none, every waiter is failed at once."""
+        from kubeflow_tpu.serving.engine import DecodeEngine
+
+        spec, _ = engine_model
+        rng = np.random.RandomState(SEED + 51)
+        prompts = [rng.randint(1, VOCAB, size=(n,)).tolist()
+                   for n in (4, 9, 6)]
+        want = _reference_rows(spec, prompts, [NEW_TOKENS] * 3)
+        outs: dict = {}
+        with faults.injected("seed=1;engine.step:sleep=0.03"):
+            engine = DecodeEngine(spec["cfg"], spec["params"],
+                                  spec["decode"], slots=3, prefill_len=16,
+                                  decode_rounds=1, name="ahead-close")
+
+            def client(i):
+                try:
+                    outs[i] = engine.submit(
+                        {"tokens": np.asarray(prompts[i], np.int32)})
+                except Exception as exc:  # noqa: BLE001 — the point
+                    outs[i] = exc
+
+            threads = [threading.Thread(target=client, args=(i,))
+                       for i in range(3)]
+            for t in threads:
+                t.start()
+            while engine.stats()["rounds_ahead"] < 3:
+                time.sleep(0.005)
+            engine.close(drain_s=drain_s)
+            for t in threads:
+                t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+        assert len(outs) == 3
+        stats = engine.stats()
+        assert stats["in_flight_requests"] == 0
+        if drain_s:
+            for i, ref in enumerate(want):
+                assert np.asarray(outs[i]["tokens"])[0].tolist() == ref
+        else:
+            assert all(isinstance(out, Exception) for out in outs.values())
+
+    @pytest.mark.parametrize("decode_rounds", [1, 4])
+    def test_an_eos_engine_runs_ahead_too(self, engine_model,
+                                          decode_rounds):
+        """``done`` is the device's: a slot an EOS stopped in round N
+        rides in N+1 and emits nothing, its request is resolved at N's
+        drain, and every answer is generate()'s up to its EOS."""
+        from kubeflow_tpu.serving.engine import DecodeEngine
+
+        spec, _ = engine_model
+        rng = np.random.RandomState(SEED + 52)
+        prompts = [rng.randint(1, VOCAB, size=(n,)).tolist()
+                   for n in (3, 9, 16, 5, 11)]
+        plain = _reference_rows(spec, prompts, [NEW_TOKENS] * 5)
+        # A token that request 0 emits in the middle of its answer.
+        eos = plain[0][len(prompts[0]) + 5]
+        decode = dataclasses.replace(spec["decode"], eos_token=eos)
+        want = []
+        for prompt, row in zip(prompts, plain):
+            answer = row[len(prompt):]
+            if eos in answer:
+                answer = answer[:answer.index(eos) + 1]
+            want.append(prompt + answer)
+        assert len(want[0]) < len(plain[0])
+        outs, stats, _ = _run_engine(
+            spec, prompts, [NEW_TOKENS] * 5, decode_rounds=decode_rounds,
+            slots=2, decode=decode, name="ahead-eos")
+        for i, ref in enumerate(want):
+            assert np.asarray(outs[i]["tokens"])[0].tolist() == ref
+        assert stats["tokens"] == sum(
+            len(w) - len(p) for w, p in zip(want, prompts))
+        assert stats["rounds_ahead"] >= 3
+        assert stats["in_flight_requests"] == stats["active_slots"] == 0
+
+    def test_a_speculating_engine_reads_before_it_dispatches(
+            self, engine_model, monkeypatch):
+        """``speculative_tokens``: the next program (verify or decode)
+        is chosen from the round's tokens, so no round is dispatched
+        ahead; the tokens are generate()'s."""
+        import kubeflow_tpu.serving.engine as eng_mod
+
+        monkeypatch.setattr(eng_mod, "_SPEC_RATE_MARGIN", 0.0)
+        spec, _ = engine_model
+        rng = np.random.RandomState(SEED + 53)
+        prompts = [np.tile(rng.randint(1, VOCAB, size=(4,)), 3).tolist(),
+                   rng.randint(1, VOCAB, size=(10,)).tolist(),
+                   rng.randint(1, VOCAB, size=(6,)).tolist()]
+        news = [12, 9, 12]
+        want = _reference_rows(spec, prompts, news)
+        outs, stats, _ = _run_engine(
+            spec, prompts, news, decode_rounds=4, slots=2,
+            speculative_tokens=4, name="ahead-spec")
+        for i, ref in enumerate(want):
+            assert np.asarray(outs[i]["tokens"])[0].tolist() == ref
+        assert stats["fused_rounds"] > 0
+        assert stats["rounds_ahead"] == 0
 
 
 def _with_config(spec, **over):

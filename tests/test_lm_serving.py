@@ -284,7 +284,9 @@ class TestDecodeEngine:
         the round that dies (it is in neither the queue nor the slot
         table when _abort walks them).  A device's death shows where
         its results are read, so the second round's tokens raise
-        there, after the dispatch retired the short request."""
+        there, after the dispatch retired the short request and, the
+        long one still live, after the THIRD round was dispatched (the
+        loop keeps one round in flight, PR 49)."""
         import threading
 
         from kubeflow_tpu.models import generate as gen_mod
@@ -347,7 +349,7 @@ class TestDecodeEngine:
             t.join(timeout=30)
         assert not any(t.is_alive() for t in threads), (
             "a client hung after the engine loop died")
-        assert calls["n"] == 2
+        assert calls["n"] == 3
         # Every waiter resolved, both with the engine's death.
         assert [type(outs[i]) for i in (0, 1)] == [RuntimeError] * 2
         engine.close()
