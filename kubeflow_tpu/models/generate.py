@@ -107,9 +107,9 @@ def view_key_tiles(max_blocks: int, block_tokens: int, t: int):
     the positions of a key tile (whole pages; at most ``_VIEW_KEY_TILE``
     and an eighth of the table) and how many the table holds, the last
     one perhaps short.  ``(view, 1)``, ONE pass over the whole view,
-    where the call holds no two tiles of query rows (a decode or verify
-    step) or the table no more than two key tiles: nothing to leave out
-    that is worth a branch."""
+    where the call holds no two tiles of query rows (a decode step, a
+    drafting stack's two rows a slot) or the table no more than two key
+    tiles: nothing to leave out that is worth a branch."""
     view = max_blocks * block_tokens
     if (t < 2 * _VIEW_QUERY_TILE or t % _VIEW_QUERY_TILE
             or view <= 2 * _VIEW_KEY_TILE):
@@ -298,11 +298,11 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
     traced scalar) and hands the whole pool back;
     cache_len: number of valid cache positions before this call — a
     scalar (whole batch at one length, the generate() path) or a [b]
-    array (per-row lengths, the slot-based decode_rounds / verify_step
-    paths, always with ``tables``; each row writes its t new k/v
-    columns starting at its OWN
-    frontier and attends under its own causal mask via the per-row
-    kv_offset — t is 1 at decode and k+1 at speculative verify);
+    array (per-row lengths, the slot-based decode_rounds path, always
+    with ``tables``; each row writes its t new k/v columns starting at
+    its OWN frontier and attends under its own causal mask via the
+    per-row kv_offset — t is 1 at decode, 2 where a drafting stack
+    holds its draft beside the slot's last token);
     pad_amount: per-row [b] left-pad width (bucketed mixed-length
     prompts) — cache columns before it hold pad-token garbage and are
     masked out of every attention.
@@ -318,8 +318,8 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
     span, or a table entry holding the sentinel ``num_blocks``
     (unallocated), drops the write — and attention runs over the row's
     own pages of the plane: ONE gather ``pool[plane, tables]`` of the
-    [max_blocks * block_tokens] view for a decode or verify step and
-    for a table of no more than two key tiles; for a call of several
+    [max_blocks * block_tokens] view for a decode step and for a
+    table of no more than two key tiles; for a call of several
     query tiles (the prefill chunk) over a longer table, a loop over
     key tiles that stops after the one holding the call's last visible
     position, ``cache_len + t - 1`` (``_held_key_tiles``: pages past it
@@ -332,7 +332,7 @@ def _attention_block(cfg: TransformerConfig, layer_params, x, cache_kv,
     view — ops/paged_attention.py is handed the stacked pool and the
     plane and reads each row's resident pages in place (a row whose
     write is parked attends nothing).
-    Wider steps (the prefill chunk, speculative verify), an int8
+    Wider steps (the prefill chunk), an int8
     ``QTensor`` pool and every other backend gather their pages and
     attend them with plain products (``_view_attention``,
     ``_tiled_view_attention``).
@@ -1538,11 +1538,10 @@ def _forward_with_cache(cfg: TransformerConfig, params, tokens, cache,
     """tokens [b, t] -> (logits [b, t, v], new cache).
 
     cache_len scalar: the whole batch sits at one length (generate()).
-    cache_len [b] array: per-row lengths (slot-based decode_rounds /
-    verify_step) — each row ropes its t tokens at its own positions
-    [len, len + t), writes its own cache columns (write_cols,
-    defaulting to cache_len), and attends under its own causal
-    frontier (t = 1 at decode, k+1 at speculative verify).
+    cache_len [b] array: per-row lengths (slot-based decode_rounds)
+    — each row ropes its t tokens at its own positions [len, len + t),
+    writes its own cache columns (write_cols, defaulting to
+    cache_len), and attends under its own causal frontier (t = 1).
     tables: per-row block tables for the paged block-pool cache (the
     serving engine's unified KV store — see _layer_step); None keeps
     the contiguous per-row layout generate() uses.  With tables,
@@ -1840,23 +1839,11 @@ def generate(
 #                            frontier, per-row block-scatter through
 #                            its table), stopping early once every
 #                            slot is done
-#   verify_step              speculative decoding: score k host-drafted
-#                            candidate tokens per slot in ONE forward
-#                            pass at each slot's frontier, accept the
-#                            longest exact greedy prefix (+1 token from
-#                            the verify logits), and roll rejected
-#                            columns back by NOT advancing cache_len
-#                            over them — the cache_len-gated attention
-#                            masks stale columns past the frontier, so
-#                            rollback is a length reset, not a scatter-
-#                            erase (the engine additionally returns the
-#                            rejected tail's blocks to the pool)
 #
-# Static shapes throughout: slot count, chunk width, pool geometry,
-# draft width, and the per-slot table span are fixed at engine
-# construction, so the whole serving lifetime compiles at most THREE
-# programs (chunked prefill, decode rounds, verify — the third only
-# when speculation is enabled).  Retirement is a device-side `done` flag (a
+# Static shapes throughout: slot count, chunk width, pool geometry
+# and the per-slot table span are fixed at engine construction, so the
+# whole serving lifetime compiles TWO step programs (chunked prefill,
+# decode rounds).  Retirement is a device-side `done` flag (a
 # slot that hits its stop length or EOS stops advancing and drops its
 # block writes), so freeing + reusing a slot needs no extra program —
 # the next admission's first chunk freezes and overwrites it.
@@ -2048,7 +2035,7 @@ def _advance_slots_drafting(cfg: TransformerConfig, params,
     and g' = argmax of the second and the frontier moves by 2; else it
     emits g and the frontier moves by 1 (row n + 1 of every plane is
     overwritten by the next step before anything attends it: a length
-    reset, as ``verify_step``'s).  A budget or an EOS met by the first
+    reset, never a scatter-erase).  A budget or an EOS met by the first
     token of a pair cuts the second.  The module then runs the rows
     (h_n, g) and, if taken, (h_{n+1}, g') at indices n + 1, n + 2 of its
     plane, and the draft the slot keeps is the argmax of the last real
@@ -2206,7 +2193,7 @@ def decode_rounds(cfg: TransformerConfig, params, state,
     - ``toks`` [S, k] int32, slot-major: slot s's tokens for this
       round occupy ``toks[s, :counts[s]]`` contiguously (a live slot
       advances every step from round start until it freezes, so its
-      emissions never leave gaps), matching the verify drain's
+      emissions never leave gaps), the engine drain's
       ``(arr, snapshot, counts)`` stream shape.
     - ``counts`` [S] int32: tokens emitted per slot (EOS included).
     - ``steps_run`` scalar int32: loop iterations actually executed.
@@ -2276,99 +2263,6 @@ def decode_rounds(cfg: TransformerConfig, params, state,
     if cfg.mtp_layers:
         return state, toks[0], counts, steps_run, toks[1]
     return state, toks, counts, steps_run
-
-
-@partial(jax.jit, static_argnums=(0, 3, 4), donate_argnums=(2,))
-def verify_step(cfg: TransformerConfig, params, state,
-                decode: DecodeConfig, k: int, draft: jax.Array,
-                draft_len: jax.Array, tables: jax.Array):
-    """Speculative verify: score up to ``k`` host-drafted tokens per
-    slot in ONE forward pass; returns (state, tokens [S, k+1],
-    emitted [S]).
-
-    ``draft`` [S, k] carries each slot's candidate continuation
-    (prompt-lookup / n-gram proposals — serving/engine.py drafts them
-    host-side) and ``draft_len`` [S] how many are real (0 = the slot
-    rides along undrafted, a mixed batch).  The forward runs at t =
-    k+1 — column 0 is the slot's pending ``last_token``, columns 1..k
-    the draft — with per-row rope positions, per-row causal frontiers,
-    and per-row cache-column scatters, i.e. a decode step's math widened
-    to a k+1 window, so position j's logits are bit-for-bit the logits
-    the (j+1)-th sequential decode step would have produced whenever
-    the first j draft tokens match greedy decode.
-
-    Acceptance is exact-match greedy (the engine only speculates at
-    temperature 0, which is what makes speculation token-IDENTICAL to
-    the non-speculative path): with ``a`` = the longest prefix of the
-    draft equal to the argmax targets, the slot emits a+1 tokens —
-    the a accepted drafts plus one free token from the verify logits
-    (the first disagreement, or the bonus continuation after a full
-    accept) — clipped to the slot's remaining budget and cut at EOS.
-
-    Rollback is DEVICE-SIDE and free: the k+1 fresh k/v columns were
-    written at [len, len + k] as the forward ran (through each slot's
-    block table), but ``lengths`` advances only over the emitted
-    prefix.  Columns past the new frontier hold rejected-draft garbage
-    that the cache_len-gated attention masks out of every later call,
-    and the next step's write window starts at the new frontier and
-    overwrites them before its own attention runs — a length reset,
-    never a scatter-erase (the engine additionally trims whole
-    rejected-tail BLOCKS back to the pool host-side).  Retired slots
-    park their writes out of range and emit 0 tokens, exactly like
-    a decode step.
-    """
-    if cfg.layer_types:
-        raise ValueError(
-            "verify_step rolls a rejected draft back by not moving a "
-            "frontier; a convolution state has no frontier to leave "
-            "behind, and a stack with layer_types has no verify forward "
-            "(speculation there: not built)")
-    lengths, done = state["lengths"], state["done"]
-    park = tables.shape[1] * _pool_block_tokens(state)
-    advance = ~done
-    write_cols = jnp.where(advance, lengths, park)
-    tokens = jnp.concatenate(
-        [state["last_token"][:, None], draft.astype(jnp.int32)], axis=1)
-    logits, (ck, cv) = _forward_with_cache(
-        cfg, params, tokens, (state["cache_k"], state["cache_v"]),
-        lengths, write_cols=write_cols, tables=tables,
-        adapter_ids=state.get("adapter_ids"))
-    with jax.named_scope("kft.sample"):
-        targets = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, k+1]
-        # Longest accepted draft prefix (positions beyond draft_len never
-        # match), then +1 free token, clipped to the per-slot budget: a
-        # live slot always has stop_len - lengths >= 1 emission of room,
-        # so every advancing slot nets at least one token per call — a
-        # verify call never delivers less than a decode step would.
-        pos = jnp.arange(k)[None, :]
-        match = (draft.astype(jnp.int32) == targets[:, :k]) \
-            & (pos < draft_len[:, None])
-        accepted = jnp.sum(
-            jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
-        emit = jnp.minimum(accepted + 1,
-                           jnp.maximum(state["stop_len"] - lengths, 0))
-        if decode.eos_token >= 0:
-            is_eos = targets == decode.eos_token
-            eos_cut = jnp.where(jnp.any(is_eos, axis=1),
-                                jnp.argmax(is_eos, axis=1) + 1, k + 2)
-            done_eos = advance & (eos_cut <= emit)
-            emit = jnp.minimum(emit, eos_cut)
-        else:
-            done_eos = jnp.zeros_like(done)
-        emit = jnp.where(advance, emit, 0)
-        out = jnp.where(jnp.arange(k + 1)[None, :] < emit[:, None],
-                        targets, 0)
-        new_lengths = lengths + emit
-        last_tok = jnp.take_along_axis(
-            targets, jnp.maximum(emit - 1, 0)[:, None], axis=1)[:, 0]
-    state = dict(state)
-    state["cache_k"], state["cache_v"] = ck, cv
-    state["lengths"] = new_lengths
-    state["last_token"] = jnp.where(emit > 0, last_tok,
-                                    state["last_token"])
-    state["done"] = done | done_eos \
-        | (advance & (new_lengths >= state["stop_len"]))
-    return state, out, emit.astype(jnp.int32)
 
 
 def _drafting_chunk(cfg, params, state, decode, tokens, start, prompt_len,

@@ -50,23 +50,15 @@ over ONE persistent PAGED KV block pool shared by ``slots`` sequences:
     (published prefix pages stay resident until LRU eviction) — no
     request ever waits for the batch to drain, and per-request
     ``max_new_tokens`` is data, not a compiled constant;
-  - with ``speculative_tokens`` > 0 (greedy exports only), a host-side
-    **n-gram drafter** proposes up to k candidate tokens per slot by
-    longest-suffix match against the slot's own prompt + generated
-    history (no second model), a single ``verify_step`` forward scores
-    the k+1 positions at each slot's frontier, the longest exact
-    greedy prefix is accepted (+1 free token from the verify logits),
-    and rejected columns roll back device-side by NOT advancing the
-    slot's ``cache_len`` over them (rejected-tail BLOCKS return to
-    the pool) — per-slot adaptive k backs off when acceptance drops,
-    and a round in which no slot drafts runs the plain decode
-    program, so low-acceptance traffic never pays the verify window;
+  - the only speculation is the one a model brings: where its
+    multi-token-prediction module drafts (``mtp_layers``), every step
+    of a decode round verifies a draft made on the device and may
+    yield two tokens (models/generate.py ``_advance_slots_drafting``);
   - every shape is static, so the engine's whole lifetime compiles at
-    most THREE programs (chunked prefill, decode rounds, verify — the
-    third only when speculation is enabled; prefix reuse needs no copy
-    program at all; a decode-tier engine that imports disaggregated
-    KV handoffs adds a fourth, ``kv_import``, run once per imported
-    request);
+    most THREE programs (chunked prefill, decode rounds and, on a
+    decode-tier engine that imports disaggregated KV handoffs,
+    ``kv_import``, run once per imported request; prefix reuse needs
+    no copy program at all);
   - with ``mesh`` set (serving/sharding.py) the SAME programs compile
     tensor-parallel: params and the block pool are placed with
     NamedShardings at construction (heads / MLP hidden / vocab split,
@@ -81,16 +73,16 @@ over ONE persistent PAGED KV block pool shared by ``slots`` sequences:
     through the ordinary cached-prefix chunked-prefill path.
 
 The host's work for the NEXT round (page covers, the block-table
-upload, drafting, spill) runs while the device computes the current
-one, and the round's tokens are read once.  Where another decode round
+upload, spill) runs while the device computes the current one, and the
+round's tokens are read once.  Where another decode round
 follows, the loop keeps ONE round in flight: it dispatches round N+1
 (and the chunk before it) before it reads round N, so the read, the
 drain to the clients, the accounting and the next admission run beside
 a round on the device, not after one (``_dispatch_round``; the depth
-is the design, not a dial; a speculating or a stopping engine reads
-first).  Completion is detected deterministically from the per-request
-budget (and, when EOS is configured, from the round's tokens — the
-device flag has already frozen the slot by then).
+is the design, not a dial; a stopping engine reads first).  Completion
+is detected deterministically from the per-request budget (and, when
+EOS is configured, from the round's tokens — the device flag has
+already frozen the slot by then).
 
 Interface-compatible with the batchers (submit/accepts/stats/close), so
 ModelServer.enable_batching wires it behind the REST and gRPC surfaces
@@ -182,10 +174,6 @@ KV_SHED_HELP = \
     "them, by engine"
 PREFILL_CHUNKS_TOTAL = "kft_engine_prefill_chunks_total"
 PREFILL_CHUNKS_HELP = "prefill chunk program calls, by engine"
-SPEC_DRAFTED_TOTAL = "kft_engine_spec_drafted_total"
-SPEC_DRAFTED_HELP = "draft tokens proposed to verify_step, by engine"
-SPEC_ACCEPTED_TOTAL = "kft_engine_spec_accepted_total"
-SPEC_ACCEPTED_HELP = "draft tokens accepted by verify_step, by engine"
 MTP_DRAFTED_TOTAL = "kft_engine_mtp_drafted_total"
 MTP_DRAFTED_HELP = \
     "drafts of a model's own multi-token-prediction module verified by " \
@@ -255,37 +243,6 @@ ADAPTER_REQUESTS_HELP = \
     "requests admitted naming an adapter variant, by engine and " \
     "adapter"
 
-# N-gram drafter bounds: suffixes of up to _SPEC_NGRAM_MAX tokens are
-# matched against the request's own history, down to _SPEC_NGRAM_MIN.
-# The floor is a BIGRAM on purpose: a single repeated token recurs
-# constantly in unrepetitive text (birthday-bound in the vocab) and
-# measured ~25% acceptance — pure wasted verify windows — while every
-# actually-periodic regime (constant runs, alternations, repeated
-# phrases) repeats its bigrams too.  After a slot's adaptive draft
-# width backs off to zero it re-probes with width 1 once
-# _SPEC_COOLDOWN rounds pass, so a tail that TURNS repetitive can
-# recover speculation.
-_SPEC_NGRAM_MAX = 4
-_SPEC_NGRAM_MIN = 2
-_SPEC_COOLDOWN = 8
-# When every live slot keeps proposing nothing, the drafting scan
-# itself is pure per-round overhead — back off to scanning every
-# _SPEC_SCAN_STRIDE_MAX rounds (histories grow one token per round,
-# so draftability changes slowly); any hit or any new admission
-# resets to every round.
-_SPEC_SCAN_STRIDE_MAX = 8
-# Throughput gate: speculation keeps running only while the verify
-# program's MEASURED delivered token rate (EMA) beats the decode
-# program's by this factor — the break-even is model/hardware
-# dependent (a k+1-wide window costs ~constant extra on a
-# bandwidth-bound TPU but ~linear extra on a compute-bound CPU), so
-# the engine measures it instead of assuming it.  While gated off, a
-# probe verify runs every _SPEC_PROBE_EVERY gated rounds to refresh
-# the estimate (traffic that turns repetitive re-enables itself).
-_SPEC_RATE_MARGIN = 0.95
-_SPEC_PROBE_EVERY = 4
-_SPEC_RATE_ALPHA = 0.3
-
 # Decode rounds: shrink the adaptive round
 # width when more than this fraction of a round's dispatched slot-steps
 # delivered nothing (early-exit waste: slots frozen at EOS/budget while
@@ -297,8 +254,6 @@ _SPEC_RATE_ALPHA = 0.3
 _ROUND_WASTE_FRAC = 0.25
 _ROUND_PACE_ALPHA = 0.2
 
-
-_NO_DRAFT = np.empty((0,), np.int32)
 
 # The phases that tile one iteration of DecodeEngine._run (see _Phase).
 _PHASES = ("wait_work", "admit", "housekeeping", "prefill_dispatch",
@@ -446,66 +401,6 @@ class _Round:
                  "drafts", "held")
 
 
-def _ngram_propose(history: np.ndarray, k: int,
-                   nmax: int = _SPEC_NGRAM_MAX,
-                   nmin: int = _SPEC_NGRAM_MIN) -> np.ndarray:
-    """Prompt-lookup drafting: find the most recent earlier occurrence
-    of the history's longest matchable suffix (n-gram, longest n
-    first) and propose the up-to-k tokens that followed it.  Returns
-    an empty array when no suffix recurs — the caller then runs the
-    plain decode program.  Proposals carry NO correctness weight
-    (verify_step accepts only exact greedy matches); they only set the
-    acceptance rate, so a wrong guess costs one verify window, never a
-    wrong token.
-
-    This runs once per live slot per decode round, so the no-repeat
-    common case must be near-free: every matchable suffix ends with
-    the history's last token, and one vectorized scan for its earlier
-    occurrences prunes unrepetitive text to a single compare."""
-    n_hist = int(history.shape[0])
-    if n_hist < nmin + 1 or k <= 0:
-        return _NO_DRAFT
-    # End positions of candidate occurrences: indices e < n_hist - 1
-    # holding the last token (a follower at e + 1 always exists, and
-    # the trivial self-match at the suffix itself is excluded).
-    ends = np.flatnonzero(history[:n_hist - 1] == history[n_hist - 1])
-    if ends.size == 0:
-        return _NO_DRAFT
-    if nmin >= 2:
-        # Fold the bigram floor into the precheck: every matchable
-        # suffix must end with the last TWO tokens, which prunes the
-        # single-repeated-token noise before any n-gram scan runs.
-        ends = ends[ends >= 1]
-        ends = ends[history[ends - 1] == history[n_hist - 2]]
-        if ends.size == 0:
-            return _NO_DRAFT
-    for n in range(min(nmax, n_hist - 1), nmin - 1, -1):
-        cand = ends[ends >= n - 1]
-        if cand.size == 0:
-            continue
-        if n > 1:
-            pattern = history[n_hist - n:]
-            idx = (cand - (n - 1))[:, None] + np.arange(n)[None, :]
-            cand = cand[(history[idx] == pattern[None, :]).all(axis=1)]
-            if cand.size == 0:
-                continue
-        starts = cand + 1  # continuation start per occurrence
-        # Most recent occurrence with a FULL k-token continuation,
-        # else the most recent at all.  A short continuation (the
-        # match sits near the history's end — the steady state of a
-        # periodic tail) extends CYCLICALLY: the tokens between the
-        # match and the history's end are the period, and proposing
-        # them on repeat is exactly the guess that pays off on the
-        # repetitive text speculation targets.
-        full = starts[starts + k <= n_hist]
-        start = int(full[-1] if full.size else starts[-1])
-        proposal = history[start:start + k]
-        if proposal.size < k:
-            proposal = np.resize(history[start:], k)
-        return proposal.astype(np.int32)
-    return _NO_DRAFT
-
-
 def _true_token_len(row: np.ndarray) -> int:
     """Real prompt length of a 1-D token row: trailing pad ids (token
     0, the framework-wide pad convention) do not count.  An all-pad row
@@ -610,19 +505,11 @@ class DecodeEngine:
         by slots + max_queue_depth.
       overload_retry_after_s: the Retry-After hint a shed submission
         carries back to the client.
-      speculative_tokens: self-speculative (prompt-lookup / n-gram)
-        decoding — the static draft width k of the fourth AOT program
-        (``verify_step``): up to k host-drafted candidate tokens per
-        slot verify in ONE forward pass, token-identical to greedy
-        decode (0 disables).  Requires a greedy export (temperature
-        0) — sampling exports silently fall back to plain decode,
-        because drafting would perturb the per-request sample
-        streams.
       mesh: a ``jax.sharding.Mesh`` (serving/sharding.py build_mesh)
         to run tensor-parallel over: params and the paged KV block
         pool are placed with NamedShardings at construction (heads /
         MLP hidden / vocab split under ``partition_rules``; the pool
-        shards its kv-head dim) and the SAME three AOT programs
+        shards its kv-head dim) and the SAME AOT programs
         compile SPMD from the argument shardings — the host-owned
         block tables, the step loop, and every admission path are
         untouched.  None (the default) is the single-device engine,
@@ -663,7 +550,6 @@ class DecodeEngine:
         host_spill_blocks: int = 0,
         max_queue_depth: int = 0,
         overload_retry_after_s: float = 1.0,
-        speculative_tokens: int = 0,
         mesh=None,
         partition_rules=None,
         adapters=None,
@@ -684,9 +570,8 @@ class DecodeEngine:
         self.mesh = mesh
         # State of fixed size per SLOT beside the paged pool (the
         # convolution layers of a stack with ``layer_types``): a page
-        # alias no longer restores a prefix, a frontier moved back no
-        # longer rolls a draft back, and pages alone no longer carry a
-        # sequence to the host tier or to another replica.  Until the
+        # alias no longer restores a prefix, and pages alone no longer
+        # carry a sequence to the host tier or to another replica.  Until the
         # state is snapshotted per page such a model prefills every
         # prompt whole, and what rests on pages alone is refused by
         # name before any token.
@@ -694,9 +579,9 @@ class DecodeEngine:
         # A latent pool (``cfg.latent``: ONE array, key and value at
         # once) has no per-slot state, so pages alias and prefixes are
         # reused as for any other model; what MOVES pages between tiers
-        # or replicas (two-sided page stacks), the verify forward, the
-        # adapters' deltas and the mesh's sharding of kv heads are not
-        # built for it, and are refused by name likewise.
+        # or replicas (two-sided page stacks), the adapters' deltas and
+        # the mesh's sharding of kv heads are not built for it, and are
+        # refused by name likewise.
         self._pages_stay = (
             f"per-slot state ({cfg.conv_planes} convolution layers)"
             if self._slot_state
@@ -704,7 +589,6 @@ class DecodeEngine:
             if cfg.latent else None)
         if self._pages_stay:
             for flag, value in (
-                    ("speculative_tokens", speculative_tokens),
                     ("host_spill_blocks", host_spill_blocks),
                     ("adapters", adapters), ("mesh", mesh)):
                 if value:
@@ -799,22 +683,6 @@ class DecodeEngine:
         self.max_queue_depth = max(0, int(max_queue_depth))
         self.overload_retry_after_s = overload_retry_after_s
         self._eos = decode.eos_token >= 0
-        # Speculative draft width: greedy exports only (verify accepts
-        # exact argmax matches; under sampling, drafting would have to
-        # perturb the per-request sample streams), capped so a draft
-        # can never exceed the largest completion minus its free
-        # verify token.
-        spec = max(0, int(speculative_tokens))
-        spec = min(spec, max(0, int(decode.max_new_tokens) - 1))
-        if spec and decode.temperature > 0:
-            import logging
-
-            logging.warning(
-                "engine %r: speculative_tokens=%d ignored — the export "
-                "samples at temperature %g and speculation is greedy-"
-                "only", name, spec, decode.temperature)
-            spec = 0
-        self.speculative_tokens = spec
         self._state = init_paged_state(cfg, slots, self.kv_pool_blocks,
                                        self.kv_block_tokens,
                                        decode.kv_cache_dtype)
@@ -889,7 +757,6 @@ class DecodeEngine:
         # literal: these fields and _rounds_exec below ARE the
         # engine's compiled programs.
         self._chunk_exec = None
-        self._verify_exec = None
         # Disaggregated-serving KV import program (kv_import): built
         # the first time a handoff payload arrives; runs once per
         # imported request, never in the step loop.
@@ -909,15 +776,6 @@ class DecodeEngine:
         self._round_k = self.decode_rounds
         self._round_steps: List[int] = []
         self._step_pace_ema: Optional[float] = None
-        # Drafting-scan backoff (loop-thread-owned): consecutive empty
-        # scans stretch the scan period toward _SPEC_SCAN_STRIDE_MAX.
-        self._spec_stride = 1
-        self._spec_tick = 0
-        # Measured delivered-rate EMAs of the two step programs (the
-        # throughput gate's inputs) and the gated-round probe counter.
-        self._rate_step_ema = None
-        self._rate_verify_ema = None
-        self._spec_probe = 0
         # Per-tenant fair admission (§5.11): last-admitted sequence
         # per adapter key ("" = base traffic).  Mutated only under
         # self._lock by the admission pop.
@@ -972,7 +830,6 @@ class DecodeEngine:
             "prefix_hits": 0, "prefix_misses": 0, "prefix_evictions": 0,
             "prefill_chunks": 0, "cached_tokens": 0, "prompt_tokens": 0,
             "prefill_positions_held": 0, "prefill_positions_scored": 0,
-            "spec_drafted": 0, "spec_accepted": 0, "spec_steps": 0,
             "mtp_drafted": 0, "mtp_accepted": 0, "mtp_steps": 0,
             "kv_evictions": 0, "kv_shed_no_blocks": 0,
             "handoff_pages_out": 0, "handoff_pages_in": 0,
@@ -1040,10 +897,6 @@ class DecodeEngine:
             KV_EVICTIONS_TOTAL, KV_EVICTIONS_HELP)
         self._kv_shed_ctr = REGISTRY.counter(
             KV_SHED_TOTAL, KV_SHED_HELP)
-        self._spec_drafted_ctr = REGISTRY.counter(
-            SPEC_DRAFTED_TOTAL, SPEC_DRAFTED_HELP)
-        self._spec_accepted_ctr = REGISTRY.counter(
-            SPEC_ACCEPTED_TOTAL, SPEC_ACCEPTED_HELP)
         self._mtp_drafted_ctr = REGISTRY.counter(
             MTP_DRAFTED_TOTAL, MTP_DRAFTED_HELP)
         self._mtp_accepted_ctr = REGISTRY.counter(
@@ -1358,7 +1211,7 @@ class DecodeEngine:
             "emitted": [], "scheduled": 0, "slot": None,
             "trace": trace_ctx,
             "t_perf": time.perf_counter(), "t_claim_perf": None,
-            "t_first_perf": None, "spec_acc": 0,
+            "t_first_perf": None,
             "prefilling": False, "pos": 0, "cached": 0,
             "res_blocks": res_blocks, "res_left": 0, "blocks": [],
             "released": False,
@@ -1366,13 +1219,7 @@ class DecodeEngine:
             "park": bool(inputs.get("park_kv")), "spill_in": None,
             "adapter": adapter_idx, "adapter_salt": adapter_salt,
             "adapter_name": adapter_name,
-            # Adaptive draft width: grows on full accepts, shrinks on
-            # full rejects; 0 = backed off (re-probes after cooldown).
-            "spec_k": self.speculative_tokens, "spec_cool": 0,
-            # Drafting history (prompt + emitted), maintained
-            # incrementally by the drain — rebuilding it per round
-            # costs more than the draft search itself at step rates.
-            "hist": None, "hist_len": 0, "mtp_drafts": [],
+            "mtp_drafts": [],
             "deadline": deadline,
             "want_timing": bool(inputs.get("return_timing")),
             "event": threading.Event(), "out": None, "err": None,
@@ -1380,21 +1227,6 @@ class DecodeEngine:
         }
         if adapter_pin is not None:
             entry["adapter_pin"] = adapter_pin
-        if self.speculative_tokens:
-            hist = np.empty((length + new,), np.int32)
-            hist[:length] = tokens[0]
-            entry["hist"] = hist
-            entry["hist_len"] = length
-            # Does the PROMPT alone carry a repeated bigram?  Only
-            # then can drafting fire at admission, so only then is an
-            # admission worth resetting the scan-stride backoff for.
-            if length >= 3:
-                pairs = (hist[:length - 1].astype(np.int64) << 32) \
-                    | hist[1:length].astype(np.int64)
-                entry["spec_seed"] = bool(
-                    np.unique(pairs).size < length - 1)
-            else:
-                entry["spec_seed"] = False
         with self._lock:
             if self._stopped:
                 self._unpin_adapter(entry)
@@ -1458,22 +1290,20 @@ class DecodeEngine:
 
     def compiled_programs(self) -> Dict[str, int]:
         """How many device programs this engine has compiled — by
-        construction at most one chunked-prefill, one decode-rounds
-        and one speculative-verify executable (the build sites are
-        None-guarded), so a healthy engine reports at most
-        {"chunked_prefill": 1, "decode_rounds": 1, "verify": 1} for
-        its whole lifetime (ONE ``decode_rounds`` executable serves
-        every round width — the per-round step cap is a traced
-        operand; "verify" stays 0 unless speculation is enabled AND a
-        slot actually drafted).  There is no prefix-copy program:
-        shared-prefix reuse is host-side block-table aliasing.  A
-        decode-tier engine that has imported a disaggregated KV
-        handoff additionally reports ``kv_import`` (once compiled) —
-        the one-per-request page-scatter program; engines that never
-        see a handoff keep the exact three-key shape."""
+        construction at most one chunked-prefill and one decode-rounds
+        executable (the build sites are None-guarded), so a healthy
+        engine reports at most {"chunked_prefill": 1,
+        "decode_rounds": 1} for its whole lifetime (ONE
+        ``decode_rounds`` executable serves every round width — the
+        per-round step cap is a traced operand).  There is no
+        prefix-copy program: shared-prefix reuse is host-side
+        block-table aliasing.  A decode-tier engine that has imported
+        a disaggregated KV handoff additionally reports ``kv_import``
+        (once compiled) — the one-per-request page-scatter program;
+        engines that never see a handoff keep the exact two-key
+        shape."""
         out = {"chunked_prefill": int(self._chunk_exec is not None),
-               "decode_rounds": int(self._rounds_exec is not None),
-               "verify": int(self._verify_exec is not None)}
+               "decode_rounds": int(self._rounds_exec is not None)}
         if self._import_exec is not None:
             out["kv_import"] = 1
         return out
@@ -1641,15 +1471,6 @@ class DecodeEngine:
             "mesh_devices": self._mesh_devices(),
             "handoff_pages_out": c["handoff_pages_out"],
             "handoff_pages_in": c["handoff_pages_in"],
-            # Speculative decoding: drafted vs accepted tokens and the
-            # per-verify-call yield.  accepted_per_step is the mean
-            # EXTRA tokens a verify call delivered beyond the one a
-            # plain decode step would have — the speedup signal to
-            # watch (acceptance_rate alone can look high while k is
-            # backed off to 1).
-            "spec_drafted": c["spec_drafted"],
-            "spec_accepted": c["spec_accepted"],
-            "spec_steps": c["spec_steps"],
             # The model's own multi-token-prediction module
             # (TransformerConfig.mtp_layers): drafts a decode step
             # verified, drafts taken (steps that yielded two tokens) and
@@ -1657,12 +1478,6 @@ class DecodeEngine:
             "mtp_drafted": c["mtp_drafted"],
             "mtp_accepted": c["mtp_accepted"],
             "mtp_steps": c["mtp_steps"],
-            "spec_acceptance_rate": round(
-                c["spec_accepted"] / c["spec_drafted"], 4)
-            if c["spec_drafted"] else 0.0,
-            "accepted_per_step": round(
-                c["spec_accepted"] / c["spec_steps"], 3)
-            if c["spec_steps"] else 0.0,
             # Fused decode rounds (docs §5.2e): rounds dispatched,
             # early-exit slot-steps that delivered nothing, and the
             # realized steps-per-round distribution — how much of the
@@ -1781,10 +1596,10 @@ class DecodeEngine:
         return _ReadPhase(self, handed)
 
     def _device_has_work(self, phase: _Phase) -> int:
-        """A call that hands the device work (a round's, a verify
-        window's or a chunk's program) has just returned; the calls are
-        numbered, and this one's number is returned.  The open
-        turnaround, if any, ends here.  It began when the first result
+        """A call that hands the device work (a round's or a chunk's
+        program) has just returned; the calls are numbered, and this
+        one's number is returned.  The open turnaround, if any, ends
+        here.  It began when the first result
         of the last such call landed with nothing queued behind it
         (``_ReadPhase``), the earliest moment the loop could know the
         device had nothing left to do: the stretch is the host's
@@ -2306,25 +2121,6 @@ class DecodeEngine:
         if blk_d:
             self._kv_evict_ctr.inc(blk_d, engine=self._metric_name)
 
-    def _trim_cover(self, entry: dict, next_write_pos: int) -> None:
-        """Speculative rollback, pool side: pages past the one covering
-        ``next_write_pos`` hold only rejected-draft k/v (already behind
-        the attention mask) — return them to the pool and restore the
-        entry's reservation, so a burst of rejected windows never
-        inflates tokens resident."""
-        target = max(1, next_write_pos // self.kv_block_tokens + 1)
-        n = len(entry["blocks"])
-        if n <= target:
-            return
-        row = self._tables[entry["slot"]]
-        row[target:n] = self.kv_pool_blocks
-        with self._lock:
-            self._tables_dirty = True
-            tail = entry["blocks"][target:]
-            del entry["blocks"][target:]
-            entry["res_left"] += len(tail)
-            self._mgr.rollback(tail)
-
     def _flush_evictions_locked(self):
         """Fold the manager's eviction totals into the engine counters;
         returns the (records, blocks) deltas for the prom counters."""
@@ -2761,17 +2557,15 @@ class DecodeEngine:
             entry["out"]["cached_tokens"] = entry["cached"]
         if entry["trace"] is not None:
             # ONE decode span per request, stamped at delivery: first
-            # token -> last token, annotated with the emitted count and
-            # the speculative tokens verify_step accepted on its
-            # behalf.  Per-step spans would cost the hot loop; this
-            # costs one record at drain.
+            # token -> last token, annotated with the emitted count.
+            # Per-step spans would cost the hot loop; this costs one
+            # record at drain.
             end = time.perf_counter()
             tracing.record_span(
                 "engine.decode", entry["trace"],
                 entry["t_first_perf"] or end, end,
                 attrs={"engine": self._metric_name,
-                       "tokens": len(entry["emitted"]),
-                       "spec_accepted": entry["spec_acc"]})
+                       "tokens": len(entry["emitted"])})
         entry["event"].set()
 
     def _drain_one(self) -> None:
@@ -2782,10 +2576,10 @@ class DecodeEngine:
 
         Two emission shapes ride the one stream: a prefill's [1]
         first token (counts None, col 0), and a slot-major grid with
-        a per-slot ``counts`` vector — a verify call's [slots, k+1]
-        accepted prefixes plus free token, and a decode round's
-        [slots, k] per-step emissions (both cut at EOS/budget on
-        device, so row s carries counts[s] real tokens)."""
+        a per-slot ``counts`` vector — a decode round's [slots, k]
+        per-step emissions ([slots, 2 k] where the model drafts: a
+        step may yield two), cut at EOS/budget on device, so row s
+        carries counts[s] real tokens."""
         arr, snapshot, counts, handed = self._pending.pop(0)
         if isinstance(arr, np.ndarray):
             host = arr  # the round already waited for it
@@ -2823,9 +2617,6 @@ class DecodeEngine:
                         span_hit_s += span
                         firsts_hit += 1
                 entry["emitted"].append(tok)
-                if entry["hist"] is not None:
-                    entry["hist"][entry["hist_len"]] = tok
-                    entry["hist_len"] += 1
                 emitted += 1
                 complete = len(entry["emitted"]) >= entry["new"] or (
                     self._eos and tok == self.decode.eos_token)
@@ -2865,34 +2656,21 @@ class DecodeEngine:
         if firsts:
             self._prefill_span_ctr.inc(span_s, engine=self._metric_name)
 
-    @staticmethod
-    def _blend_rate(ema, rate):
-        return rate if ema is None else (
-            (1 - _SPEC_RATE_ALPHA) * ema + _SPEC_RATE_ALPHA * rate)
-
-    def _record_step_timing(self, t0, end, norm, steps, occupancy,
-                            extra=None, delivered=None, program="decode",
-                            round_steps=None):
-        """Shared per-round accounting for BOTH step programs (decode
-        rounds and verify): busy time, step/occupancy
-        counters, the per-token latency and inter-token-gap
-        reservoirs, the step histogram, AND the throughput-gate EMAs —
-        one discipline, so the percentiles the benchmark and e2e read
-        mean the same thing on either path and the speculation gate
-        compares decode and verify in the same currency.  ``norm`` is
-        tokens-per-slot-stream this call (fused steps for decode, mean
-        emissions of advancing slots for verify); ``extra`` merges
-        additional counters under the same lock (a scrape must never
-        see spec_steps ahead of steps); ``delivered`` (tokens the
-        round actually delivered, post-EOS/budget) feeds the
-        ``program``'s rate EMA per ROUND — a fused dispatch of k steps
-        is one EMA sample, not k, so the spec gate prices fused decode
-        by its delivered rate, not its call rate; ``round_steps``
-        appends to the steps-per-round reservoir (fused rounds
-        only).  A round dispatched while the round before it was unread
-        began, for this clock, where that one ended: the rounds' wall
-        time is counted once (``busy_s``, the pace that clamps a width
-        under a deadline)."""
+    def _record_step_timing(self, t0, end, steps, occupancy, wasted):
+        """A decode round's accounting: busy time, step/occupancy
+        counters, the per-token latency and inter-token-gap reservoirs,
+        the step histogram and the steps-per-round reservoir — one
+        discipline for what the benchmark and e2e read as percentiles.
+        The round's own counters merge under the same lock (a scrape
+        must never see ``fused_rounds`` ahead of ``steps``).  A round
+        dispatched while the round before it was unread began, for this
+        clock, where that one ended: the rounds' wall time is counted
+        once (``busy_s``, the pace that clamps a width under a
+        deadline)."""
+        # A round may run no step: every slot it was handed had stopped
+        # in the round before it, which was unread (an EOS, a drafting
+        # stack's budget met early).
+        norm = max(1, steps)
         if self._last_step_end is not None:
             t0 = max(t0, self._last_step_end)
         dt = end - t0
@@ -2905,18 +2683,16 @@ class DecodeEngine:
         self._step_pace_ema = per_tok if self._step_pace_ema is None \
             else ((1 - _ROUND_PACE_ALPHA) * self._step_pace_ema
                   + _ROUND_PACE_ALPHA * per_tok)
-        kernel_steps = steps if (
-            self._paged_kernel and program == "decode") else 0
+        kernel_steps = steps if self._paged_kernel else 0
         with self._lock:
             self._counters["steps"] += steps
             self._counters["decode_kernel_steps"] += kernel_steps
-            if self._grouped_kernel and program == "decode":
+            if self._grouped_kernel:
                 self._counters["grouped_kernel_steps"] += steps
             self._counters["occupancy_sum"] += occupancy
             self._counters["busy_s"] += dt
-            if extra:
-                for key, value in extra.items():
-                    self._counters[key] += value
+            self._counters["fused_rounds"] += 1
+            self._counters["fused_steps_wasted"] += wasted
             self._step_times.append(per_tok)
             if len(self._step_times) > 4096:
                 del self._step_times[:2048]
@@ -2924,22 +2700,13 @@ class DecodeEngine:
                 self._gap_times.append(gap / norm)
                 if len(self._gap_times) > 4096:
                     del self._gap_times[:2048]
-            if round_steps is not None:
-                self._round_steps.append(round_steps)
-                if len(self._round_steps) > 4096:
-                    del self._round_steps[:2048]
+            self._round_steps.append(steps)
+            if len(self._round_steps) > 4096:
+                del self._round_steps[:2048]
         self._step_hist.observe(per_tok, engine=self._metric_name)
         if kernel_steps:
             self._kernel_steps_ctr.inc(kernel_steps,
                                        engine=self._metric_name)
-        if delivered is not None and delivered > 0 and dt > 0:
-            rate = delivered / dt
-            if program == "verify":
-                self._rate_verify_ema = self._blend_rate(
-                    self._rate_verify_ema, rate)
-            else:
-                self._rate_step_ema = self._blend_rate(
-                    self._rate_step_ema, rate)
 
     def _round_width(self) -> int:
         """Current fused-round step width: the adaptive value, clamped
@@ -2969,7 +2736,7 @@ class DecodeEngine:
         Called from the overlap window right after next-round cover
         growth, so the transfer rides alongside the in-flight round's
         compute; a table mutation after that point (admission row
-        reset, expiry parking, speculative trim) re-marks dirty and
+        reset, expiry parking) re-marks dirty and
         the next dispatch re-uploads before launching.  Under a mesh
         whose executable is not compiled yet the table placement is
         unknown: keep passing the host array — the runtime then
@@ -2988,109 +2755,13 @@ class DecodeEngine:
         else:
             self._tables_dev = jax.device_put(tables)
 
-    def _draft_ahead(self, snapshot, width: int) -> None:
-        """Overlapped drafting: while the fused round computes, run
-        the n-gram scan against each slot's DISPATCH-TIME history and
-        stash the proposal on the entry.  The proposal must survive
-        the in-flight round, so it is drafted ``width`` tokens deeper
-        than the verify window; at the next round boundary
-        ``_harvest_ahead_drafts`` checks the round's delivered tokens
-        against the proposal's head — a matching prefix means the tail
-        is still a valid draft at the new frontier, a divergence drops
-        it (the next fused round simply runs undrafted).  Either way a
-        verify dispatch never waits on a drafting scan.  Scan-stride
-        backoff and the per-slot width cooldown tick here — this IS
-        the drafting scan's one site."""
-        k = self.speculative_tokens
-        self._spec_tick += 1
-        if self._spec_tick < self._spec_stride:
-            return
-        self._spec_tick = 0
-        proposed = False
-        for i, entry in snapshot:
-            if self._slot_req[i] is not entry \
-                    or entry["event"].is_set():
-                # Deterministically retired at this round's dispatch
-                # (or already resolved): it will not verify next round.
-                continue
-            if entry["spec_k"] <= 0:
-                # Backed off: tick the cooldown, then re-probe at a
-                # width that can clear the draft-mass floor on its own
-                # (a width-1 probe from a lone drafting slot would be
-                # mass-gated forever), so a tail that TURNS repetitive
-                # recovers.
-                entry["spec_cool"] -= 1
-                if entry["spec_cool"] <= 0:
-                    entry["spec_k"] = max(1, k // 2)
-                continue
-            # Never draft past the budget: the final budgeted token is
-            # the verify call's free token, so a request with <= 1
-            # token of room gains nothing from drafting.
-            room = entry["new"] - len(entry["emitted"]) - 1
-            if room <= 0:
-                continue
-            depth = width + min(k, entry["spec_k"], room)
-            proposal = _ngram_propose(
-                entry["hist"][:entry["hist_len"]], depth)
-            if proposal.size:
-                proposed = True
-                entry["draft_ahead"] = (entry["hist_len"], proposal)
-        if proposed:
-            self._spec_stride = 1
-        else:
-            self._spec_stride = min(self._spec_stride * 2,
-                                    _SPEC_SCAN_STRIDE_MAX)
-
-    def _harvest_ahead_drafts(self):
-        """Boundary-side half of overlapped drafting (see
-        ``_draft_ahead``): build ``_verify_round``'s
-        (snapshot, draft, draft_len) arguments from the ahead-proposals
-        whose heads matched the tokens the fused round actually
-        delivered, clipped to the verify window at the NEW frontier.
-        Returns None when nothing survived — the loop then runs a
-        plain fused round, which re-drafts in its overlap window.
-        Greedy token identity is unaffected either way: verify accepts
-        exact argmax matches only, so a stale-but-lucky draft and a
-        fresh one deliver the same tokens."""
-        k = self.speculative_tokens
-        draft = draft_len = None
-        snapshot: List[tuple] = []
-        for i, entry in enumerate(self._slot_req):
-            if entry is None or entry["prefilling"]:
-                continue
-            snapshot.append((i, entry))
-            ahead = entry.pop("draft_ahead", None)
-            if ahead is None:
-                continue
-            at_len, proposal = ahead
-            grown = entry["hist_len"] - at_len
-            if grown < 0 or grown >= proposal.size:
-                continue
-            if grown and not np.array_equal(
-                    entry["hist"][at_len:entry["hist_len"]],
-                    proposal[:grown]):
-                continue
-            room = entry["new"] - len(entry["emitted"]) - 1
-            width = min(int(proposal.size) - grown, k,
-                        entry["spec_k"], room)
-            if width <= 0:
-                continue
-            if draft is None:
-                draft = np.zeros((self.slots, k), np.int32)
-                draft_len = np.zeros((self.slots,), np.int32)
-            draft[i, :width] = proposal[grown:grown + width]
-            draft_len[i] = width
-        if draft is None:
-            return None
-        return snapshot, draft, draft_len
-
     def _dispatch_round(self, live: int) -> None:
         """The first half of a decode round: a single ``decode_rounds``
         dispatch advances every live slot up to ``width`` steps with
         device-side early exit the moment all are done, and the host
         work for the NEXT round — cover growth, the double-buffered
-        block-table upload, the n-gram drafting scan — runs in the
-        overlap window while the device computes.  The round joins
+        block-table upload — runs in the overlap window while the
+        device computes.  The round joins
         ``_unread``; ``_finish_round`` reads, drains and accounts it.
 
         Where another decode round follows (``_another_round_follows``)
@@ -3219,9 +2890,6 @@ class DecodeEngine:
                         + slack + per * kmax - 1 + self._mtp)
             if self._tables_dirty:
                 self._refresh_tables_dev()
-            # Overlapped drafting for the next boundary's verify round.
-            if self.speculative_tokens:
-                self._draft_ahead(snapshot, width)
             # Overlapped spill (§5.10): evacuate one cold record while
             # the round computes — the gather is enqueued behind the
             # in-flight round, so the host blocks at most where it
@@ -3235,9 +2903,8 @@ class DecodeEngine:
         """May the loop leave the round it has just dispatched unread and
         go on to dispatch the next?  It may where a slot stays live
         after that round's retirements, unless the engine is stopping
-        (it drains) or speculates: ``_drafts_for_round`` chooses the
-        next program from this round's tokens."""
-        return not stopping and not self.speculative_tokens and any(
+        (it drains)."""
+        return not stopping and any(
             r is not None and not r["prefilling"] for r in self._slot_req)
 
     def _finish_round(self) -> None:
@@ -3351,14 +3018,8 @@ class DecodeEngine:
                 self._round_k = max(1, self._round_k // 2)
             elif steps >= rnd.width and not wasted:
                 self._round_k = min(self.decode_rounds, self._round_k + 1)
-            # A round may run no step: every slot it was handed had
-            # stopped in the round before it, which was unread (an EOS,
-            # a drafting stack's budget met early).
             self._record_step_timing(
-                rnd.t0, end, max(1, steps), steps=steps,
-                occupancy=rnd.live * steps,
-                extra={"fused_rounds": 1, "fused_steps_wasted": wasted},
-                delivered=delivered, round_steps=steps)
+                rnd.t0, end, steps, rnd.live * steps, wasted)
             self._fused_rounds_ctr.inc(1, engine=self._metric_name)
             if wasted:
                 self._fused_wasted_ctr.inc(wasted,
@@ -3366,164 +3027,11 @@ class DecodeEngine:
         # kft: allow=lock-guard
         self._round_no = self._counters["loop_rounds"]
 
-    def _spec_gates_pass(self, draft_len) -> bool:
-        """Should this round's proposals actually dispatch verify?
-
-        Mass gate: the verify window is STATICALLY k+1 wide — its
-        device cost does not shrink with the actual draft mass — so a
-        round proposing under half of even ONE window's worth
-        (room-capped request tails) cannot win.
-
-        Throughput gate: dispatch verify only while its MEASURED
-        delivered rate beats the decode program's (EMAs over real
-        calls — break-even is hardware dependent, so it is measured,
-        not assumed).  Persistently mediocre acceptance — drafts that
-        match often enough to pass the mass gate but not often enough
-        to pay for the window — lands here; a probe verify every few
-        gated rounds keeps the estimate fresh so traffic that turns
-        repetitive re-enables itself.  Together with the per-slot
-        width backoff these gates are the no-regression guarantee for
-        low-acceptance traffic."""
-        if int(draft_len.sum()) < max(1, self.speculative_tokens // 2):
-            return False
-        if self._rate_step_ema is not None \
-                and self._rate_verify_ema is not None:
-            if self._rate_verify_ema \
-                    < _SPEC_RATE_MARGIN * self._rate_step_ema:
-                self._spec_probe += 1
-                if self._spec_probe < _SPEC_PROBE_EVERY:
-                    return False
-            self._spec_probe = 0
-        return True
-
-    def _verify_round(self, snapshot, draft, draft_len,
-                      live: int) -> None:
-        """One speculative round: dispatch verify_step over every live
-        slot, drain the variable-count emissions synchronously, and
-        fold the outcome into the adaptive widths + counters.
-
-        Rejected drafts need minimal host-side cleanup: the program
-        only advanced each slot's cache_len over the accepted prefix,
-        so the rejected columns are already behind the attention mask
-        (device-side rollback) and the host just trims whole rejected-
-        tail BLOCKS back to the pool; prefix publication only ever
-        covers full PROMPT blocks written by prefill, so a drafted-
-        but-rejected token can never enter a published prefix page."""
-        from kubeflow_tpu.models.generate import verify_step
-
-        with self._phase("round_prepare"):
-            # Cover every slot's verify window [len, len + k] with
-            # pages from its reservation BEFORE dispatch (accepted
-            # positions must land in real pages; positions past the
-            # reservation can only be rejected/past-budget and park on
-            # the sentinel).
-            for _, entry in snapshot:
-                self._ensure_cover(
-                    entry, entry["tokens"].shape[1]
-                    + len(entry["emitted"]) + self.speculative_tokens)
-            if self._verify_exec is None:
-                self._verify_exec = self._aot(
-                    verify_step, self.cfg, self.params, self._state,
-                    self.decode, self.speculative_tokens, draft,
-                    draft_len, self._tables)
-            # Chaos hook: the same site as the decode step — injected
-            # stalls/deaths must hit speculative rounds identically
-            # (deadlines expire mid-verify, _abort resolves waiters).
-            faults.fire("engine.step")
-        t0 = time.perf_counter()
-        with self._phase("round_dispatch",
-                         width=self.speculative_tokens + 1,
-                         live=live) as phase:
-            self._state, toks, counts = self._verify_exec(
-                self.params, self._state, draft, draft_len, self._tables)
-            handed = self._device_has_work(phase)
-        # Materialize ONCE and share the host copies with the drain —
-        # a second device->host transfer per round would show up at
-        # this call rate.
-        with self._phase("round_wait"):
-            toks_np = np.asarray(toks)
-            with self._round_read(handed):
-                counts_np = np.asarray(counts)
-                del toks, counts  # freed here, inside a phase
-        with self._phase("drain"):
-            self._pending.append((toks_np, snapshot, counts_np, handed))
-            while self._pending:
-                self._drain_one()
-        end = time.perf_counter()
-        with self._phase("account"):
-            self._account_verify(snapshot, draft, draft_len, toks_np,
-                                 counts_np, t0, end, live)
-
-    def _account_verify(self, snapshot, draft, draft_len, toks_np,
-                        counts_np, t0, end, live: int) -> None:
-        """What a verify round's outcome does to the adaptive widths,
-        the page covers and the counters."""
-        drafted = int(draft_len.sum())
-        accepted = 0
-        for col, entry in snapshot:
-            d = int(draft_len[col])
-            if not d:
-                continue
-            lim = min(d, int(counts_np[col]))
-            a = 0
-            while a < lim and toks_np[col, a] == draft[col, a]:
-                a += 1
-            accepted += a
-            if entry["trace"] is not None:
-                # Per-request accepted-token tally for the decode
-                # span's annotation (stamped at delivery).
-                entry["spec_acc"] += a
-            # Adaptive width: additive increase on a full accept,
-            # additive decrease on a full reject; at zero the slot
-            # stops paying drafting until the cooldown re-probe.
-            if a == d:
-                entry["spec_k"] = min(self.speculative_tokens,
-                                      entry["spec_k"] + 1)
-            elif a == 0:
-                entry["spec_k"] -= 1
-                if entry["spec_k"] <= 0:
-                    entry["spec_k"] = 0
-                    entry["spec_cool"] = _SPEC_COOLDOWN
-        # Speculative rollback, pool side: the drain materialized each
-        # slot's true emission count, so pages past the new frontier
-        # hold only rejected-draft garbage — trim them back to the
-        # pool (a delivered/expired entry already released everything).
-        # `scheduled` tracks the delivered count too: the plain decode
-        # rounds that follow a backed-off slot size their page cover
-        # from it, and a stale value would let a later decode write
-        # park on the table sentinel and silently drop its k/v.
-        for _, entry in snapshot:
-            entry["scheduled"] = max(entry["scheduled"],
-                                     len(entry["emitted"]))
-            if not entry["released"]:
-                self._trim_cover(
-                    entry,
-                    entry["tokens"].shape[1] + len(entry["emitted"]))
-        total = int(counts_np.sum())
-        advancing = int(np.count_nonzero(counts_np))
-        # Per-TOKEN latency/gap samples: one verify call delivers a
-        # variable token count, so normalize by the mean emissions of
-        # the slots that advanced — the client-visible stream pace.
-        # The verify-rate EMA rides the shared accounting path
-        # (delivered tokens per round, same currency as fused decode).
-        norm = max(1.0, total / advancing) if advancing else 1.0
-        self._record_step_timing(
-            t0, end, norm, steps=1, occupancy=live,
-            extra={"spec_steps": 1, "spec_drafted": drafted,
-                   "spec_accepted": accepted},
-            delivered=total, program="verify")
-        if drafted:
-            self._spec_drafted_ctr.inc(drafted,
-                                       engine=self._metric_name)
-        if accepted:
-            self._spec_accepted_ctr.inc(accepted,
-                                        engine=self._metric_name)
-
     def _run(self) -> None:
         """The loop thread.  Every statement of an iteration lies in
         one ``_phase`` (admit with wait_work inside it, housekeeping,
-        prefill_dispatch, round_prepare, then the decode or the verify
-        round's round_dispatch / overlap, and round_wait with round_read
+        prefill_dispatch, round_prepare, then the decode round's
+        round_dispatch / overlap, and round_wait with round_read
         inside it / drain / account for each round the iteration reads:
         the one before where the loop runs ahead, its own where no round
         follows), so the ``loop_*_s`` sums tile the thread's wall time
@@ -3680,11 +3188,7 @@ class DecodeEngine:
             self._advancing = [r for r in self._slot_req
                                if r is not None and not r["prefilling"]]
             live = len(self._advancing)
-            drafts = self._drafts_for_round(admissions) \
-                if live and self.speculative_tokens else None
-        if drafts is not None:
-            self._verify_round(*drafts, live)
-        elif live:
+        if live:
             # The round before this one, if the loop left it unread, is
             # read now that the device has this one queued behind it;
             # this one stays unread in its turn where another follows.
@@ -3717,30 +3221,6 @@ class DecodeEngine:
             self._counters["loop_cpu_s"] = time.thread_time()
             self._push_loop_seconds()
         return True
-
-    def _drafts_for_round(self, admissions):
-        """Speculation's verdict for this round: (snapshot, draft,
-        draft_len) when a verify call should replace the decode round,
-        else None (the plain decode program runs — the adaptive
-        backoff's no-regression guarantee for low-acceptance
-        traffic).  The drafting scan already ran in the PREVIOUS
-        round's overlap window (_draft_ahead owns the stride backoff
-        there); harvest the proposals that survived the in-flight
-        round and dispatch verify with no drafting stall on the
-        critical path.  Nothing harvested => plain decode round,
-        which re-drafts while it computes."""
-        if any(e.get("spec_seed") for e, _ in admissions):
-            # A draftable prompt arrived: scan next round and let the
-            # first drafted round probe even if earlier traffic
-            # measured speculation unprofitable — a new request is a
-            # new regime.
-            self._spec_stride = 1
-            self._spec_tick = self._spec_stride
-            self._spec_probe = _SPEC_PROBE_EVERY
-        drafts = self._harvest_ahead_drafts()
-        if drafts is not None and self._spec_gates_pass(drafts[2]):
-            return drafts
-        return None
 
     def _fail_queue(self, exc: Exception) -> None:
         with self._lock:

@@ -40,7 +40,6 @@ def batcher_factory(*, micro_batch_size: int, batch_timeout_s: float,
                     prefix_caching: bool = True,
                     max_queue_depth: int = 0,
                     overload_retry_after_s: float = 1.0,
-                    speculative_tokens: int = 0,
                     adapters_dir: str = "",
                     adapter_slots: int = 8,
                     adapter_rank: int = 4,
@@ -127,7 +126,6 @@ def batcher_factory(*, micro_batch_size: int, batch_timeout_s: float,
                     prefix_caching=prefix_caching,
                     max_queue_depth=max_queue_depth,
                     overload_retry_after_s=overload_retry_after_s,
-                    speculative_tokens=speculative_tokens,
                     adapters=registry,
                     mesh=sharding.build_mesh(mesh_axes),
                     name=f"{model.name}-v{model.version}")
@@ -266,16 +264,6 @@ def main(argv=None) -> int:
                          "(admissions never resume from cached "
                          "prefixes; the paged pool and chunked "
                          "prefill still apply)")
-    ap.add_argument("--speculative_tokens", type=int, default=0,
-                    help="DecodeEngine self-speculative decoding: up "
-                         "to this many n-gram-drafted candidate tokens "
-                         "verify per slot in ONE forward pass "
-                         "(prompt-lookup drafting, no second model), "
-                         "token-identical to greedy decode; per-slot "
-                         "adaptive backoff protects low-acceptance "
-                         "traffic.  Greedy exports only (sampling "
-                         "exports fall back to plain decode); 0 "
-                         "disables")
     ap.add_argument("--adapters_dir", default="",
                     help="directory of per-tenant adapter deltas "
                          "(<name>.npz + digest sidecar, §5.11): enables "
@@ -400,7 +388,6 @@ def main(argv=None) -> int:
                 prefix_caching=not args.no_prefix_cache,
                 max_queue_depth=args.max_queue_depth,
                 overload_retry_after_s=args.overload_retry_after_s,
-                speculative_tokens=args.speculative_tokens,
                 adapters_dir=args.adapters_dir,
                 adapter_slots=args.adapter_slots,
                 adapter_rank=args.adapter_rank,

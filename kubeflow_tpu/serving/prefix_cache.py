@@ -19,9 +19,7 @@ ALL of the host bookkeeping for that pool:
     that reservation as the frontier grows, so a mid-prefill or
     mid-decode slot can never be starved by later admissions
     (deadlock-freedom by construction: ``free + evictable >= reserved``
-    is the invariant every operation preserves), while speculative
-    rollback returns rejected-tail blocks to the pool without losing
-    the guarantee;
+    is the invariant every operation preserves);
 
   - **the block-hashed prefix index** — prompts are hashed in
     ``block_tokens``-token blocks, each digest chained over its
@@ -268,13 +266,6 @@ class BlockManager:
                     self._cached_idle += 1
                 else:
                     self._free.append(b)
-
-    def rollback(self, blocks: Sequence[int]) -> None:
-        """Speculative rollback: return freshly written tail pages to
-        the pool AND restore the owner's reservation (it may regrow
-        over the same positions after the rejected window)."""
-        self.release(blocks)
-        self._reserved += len(blocks)
 
     # -- prefix index ------------------------------------------------------
 

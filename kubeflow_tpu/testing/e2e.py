@@ -99,13 +99,7 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
     prompt) must register prefix-cache hits in
     ``kft_engine_prefix_hits_total`` and keep the max inter-token gap
     of in-flight slots under the chunk-budget bound (no full-prefill
-    stall spike).  Then a speculative burst (--speculative_tokens
-    rebuild, repetitive prompts the n-gram drafter can predict) must
-    register accepted drafts in ``kft_engine_spec_accepted_total``,
-    report all three compiled programs over :stats (chunked prefill,
-    step, verify — prefix reuse is zero-copy block aliasing, no copy
-    program exists), and produce token-IDENTICAL output to a spec-OFF
-    control rebuild.  Finally a block-exhaustion burst against a
+    stall spike).  Then a block-exhaustion burst against a
     deliberately tiny ``kv_pool_blocks`` pool: admission must shed
     typed Overloaded (HTTP 429) while the pool is exhausted,
     retirement must free blocks and restore admission (the queued
@@ -115,9 +109,11 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
     deltas over /metrics.  Finally a fused-decode burst
     (``--decode_rounds 8`` rebuild): the engine must dispatch fused
     while_loop rounds (``kft_engine_fused_rounds_total`` delta > 0),
-    report the ``decode_rounds`` program over :stats, and produce
-    token-IDENTICAL output to a ``decode_rounds=1`` control rebuild
-    that compiles no fused program."""
+    report exactly its two compiled programs over :stats (chunked
+    prefill, decode rounds — prefix reuse is zero-copy block aliasing,
+    no copy program exists), and produce token-IDENTICAL output to a
+    ``decode_rounds=1`` control rebuild (the same program, one step a
+    dispatch)."""
     import json
     import tempfile
     import threading
@@ -150,11 +146,15 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
                        "temperature": 0.0})
         server = ModelServer()
         server.add_model("lm", f"{tmp}/lm")
-        server.enable_batching("lm", batcher_factory(
-            micro_batch_size=0, batch_timeout_s=0.005,
-            lm_engine=True, lm_engine_slots=2,
-            lm_engine_prefill_len=16, prefill_chunk_tokens=8,
-            kv_block_tokens=4))
+
+        def rebuild(**extra):
+            server.enable_batching("lm", batcher_factory(
+                micro_batch_size=0, batch_timeout_s=0.005,
+                lm_engine=True, lm_engine_slots=2,
+                lm_engine_prefill_len=16, prefill_chunk_tokens=8,
+                kv_block_tokens=4, **extra))
+
+        rebuild()
         httpd, _ = make_http_server(server, port=0, host="127.0.0.1")
         try:
             port = httpd.server_address[1]
@@ -252,136 +252,6 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
             assert sample_value(
                 parsed, "kft_serving_cached_token_ratio") is not None
 
-            # --- speculative burst: rebuild the batching plane with
-            # speculation on (fresh engine, third AOT program) and
-            # drive repetitive prompts — tiled patterns whose greedy
-            # continuations collapse into runs the n-gram drafter
-            # predicts.  Speculation must ACCEPT drafts (counted in
-            # kft_engine_spec_accepted_total) while staying token-
-            # identical to a spec-OFF control rebuild.
-            def rebuild(spec_tokens, **extra):
-                server.enable_batching("lm", batcher_factory(
-                    micro_batch_size=0, batch_timeout_s=0.005,
-                    lm_engine=True, lm_engine_slots=2,
-                    lm_engine_prefill_len=16, prefill_chunk_tokens=8,
-                    kv_block_tokens=4,
-                    speculative_tokens=spec_tokens, **extra))
-
-            rebuild(4)
-            # Pick burst prompts the DRAFTER itself would succeed on,
-            # by simulating it host-side against the reference greedy
-            # continuations: the spec_accepted assert below
-            # must hold by construction, independent of the measured-
-            # throughput gate's scheduling-sensitive timing on a
-            # loaded box.
-            from kubeflow_tpu.models.generate import (
-                DecodeConfig,
-                generate,
-            )
-            from kubeflow_tpu.serving.engine import _ngram_propose
-
-            cand = [np.asarray(
-                (rng.randint(1, 128, size=(4,)).tolist() * 3)[:12],
-                np.int32) for _ in range(8)]
-            refs = np.asarray(generate(
-                cfg, variables["params"], np.stack(cand),
-                DecodeConfig(max_new_tokens=max_new,
-                             temperature=0.0))[0])
-
-            def sim_accepts(prompt, cont):
-                hist = list(prompt) + [cont[0]]
-                accepted, i = 0, 1
-                while i < len(cont):
-                    room = len(cont) - i - 1
-                    prop = (_ngram_propose(
-                        np.asarray(hist, np.int32), min(4, room))
-                        if room > 0 else np.empty((0,), np.int32))
-                    a = 0
-                    for j, p in enumerate(prop.tolist()):
-                        if p == cont[i + j]:
-                            a += 1
-                        else:
-                            break
-                    accepted += a
-                    hist.extend(cont[i:i + a + 1])
-                    i += a + 1
-                return accepted
-
-            scores = [sim_accepts(cand[i].tolist(),
-                                  refs[i, 12:].tolist())
-                      for i in range(len(cand))]
-            ranked = sorted(range(len(cand)),
-                            key=lambda i: scores[i], reverse=True)
-            assert scores[ranked[0]] > 0, (
-                "no candidate prompt is draftable under the n-gram "
-                "drafter; widen the candidate pool")
-            spec_prompts = [cand[i].tolist() for i in ranked[:4]]
-            outs.clear()
-            threads = [threading.Thread(target=client, args=(i, p))
-                       for i, p in enumerate(spec_prompts)]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            spec_out = {}
-            for i, prompt in enumerate(spec_prompts):
-                tokens = outs[i]["predictions"][0]["tokens"]
-                assert tokens[:len(prompt)] == prompt
-                assert len(tokens) == len(prompt) + max_new
-                spec_out[i] = tokens
-            with urllib.request.urlopen(
-                    f"http://127.0.0.1:{port}/model/lm:stats",
-                    timeout=30) as resp:
-                stats = json.loads(resp.read())["batcher"]
-            assert stats["spec_drafted"] > 0, (
-                f"speculative burst proposed no drafts: {stats}")
-            assert stats["spec_accepted"] > 0, (
-                f"speculative burst accepted no drafts: {stats}")
-            assert 0 < stats["spec_acceptance_rate"] <= 1
-            # The three-program guarantee, end to end over :stats —
-            # verify exists exactly once; a purely-drafted burst may
-            # never need the plain decode program, so it is 0 or 1.
-            # There is no copy_prefix key: prefix reuse is host-side
-            # block-table aliasing, not a device program.
-            programs = stats["compiled_programs"]
-            assert set(programs) == {"chunked_prefill", "decode_rounds",
-                                     "verify"}, programs
-            assert programs["verify"] == 1, programs
-            assert programs["chunked_prefill"] == 1, programs
-            with urllib.request.urlopen(
-                    f"http://127.0.0.1:{port}/metrics",
-                    timeout=30) as resp:
-                parsed = parse_metrics(resp.read().decode())
-            # Pinned to THIS server's engine label: the prom registry
-            # is process-global, and an unpinned read falls back to
-            # the first series of each family — which other suites'
-            # engines may own (and own DIFFERENTLY per family).
-            accepted = sample_value(
-                parsed, "kft_engine_spec_accepted_total",
-                engine="lm-v1") or 0
-            drafted = sample_value(
-                parsed, "kft_engine_spec_drafted_total",
-                engine="lm-v1") or 0
-            assert accepted > 0, (
-                "kft_engine_spec_accepted_total not exported/zero")
-            assert drafted >= accepted
-            # Spec-OFF control: identical tokens on a fresh engine.
-            rebuild(0)
-            outs.clear()
-            for i, prompt in enumerate(spec_prompts):
-                client(i, prompt)
-                assert outs[i]["predictions"][0]["tokens"] \
-                    == spec_out[i], (
-                    f"speculation changed tokens for prompt {i}")
-            with urllib.request.urlopen(
-                    f"http://127.0.0.1:{port}/model/lm:stats",
-                    timeout=30) as resp:
-                stats = json.loads(resp.read())["batcher"]
-            assert stats["spec_drafted"] == 0
-            assert stats["compiled_programs"]["verify"] == 0
-            assert set(stats["compiled_programs"]) \
-                == {"chunked_prefill", "decode_rounds", "verify"}
-
             # --- block-exhaustion burst: a deliberately tiny pool (8
             # pages of 4 tokens against 12-token prompts + 16-token
             # budgets = 7 reserved pages per request, so exactly ONE
@@ -402,7 +272,7 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
             evict_before = sample_value(
                 parsed, "kft_engine_kv_block_evictions_total",
                 engine="lm-v1") or 0
-            rebuild(0, kv_pool_blocks=8, max_queue_depth=1)
+            rebuild(kv_pool_blocks=8, max_queue_depth=1)
             burst = [rng.randint(1, 128, size=(12,)).tolist()
                      for _ in range(8)]
             outs.clear()
@@ -490,7 +360,7 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
             fused_before = sample_value(
                 parsed, "kft_engine_fused_rounds_total",
                 engine="lm-v1") or 0
-            rebuild(0, decode_rounds=8)
+            rebuild(decode_rounds=8)
             fused_prompts = [rng.randint(1, 128, size=(n,)).tolist()
                              for n in (3, 9, 16)]
             outs.clear()
@@ -514,12 +384,11 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
             assert stats["fused_rounds"] > 0, (
                 f"fused burst dispatched no fused rounds: {stats}")
             assert stats["steps_per_round_p50"] >= 1, stats
-            programs = stats["compiled_programs"]
-            # The program compiles exactly once for every width, and
-            # verify stays 0 (spec off).
-            assert programs["decode_rounds"] == 1, programs
-            assert programs["chunked_prefill"] == 1, programs
-            assert programs["verify"] == 0, programs
+            # Each program compiles exactly once, for every width, and
+            # there is no other: prefix reuse is host-side block-table
+            # aliasing, not a device program.
+            assert stats["compiled_programs"] == {
+                "chunked_prefill": 1, "decode_rounds": 1}, stats
             with urllib.request.urlopen(
                     f"http://127.0.0.1:{port}/metrics",
                     timeout=30) as resp:
@@ -533,7 +402,7 @@ def engine_smoke(namespace: str = "kubeflow-test") -> None:
             # Same concurrent shape as the burst above — greedy decode
             # is order-independent per slot, and the threads halve the
             # control's wall time.
-            rebuild(0, decode_rounds=1)
+            rebuild(decode_rounds=1)
             outs.clear()
             threads = [threading.Thread(target=client, args=(i, p))
                        for i, p in enumerate(fused_prompts)]

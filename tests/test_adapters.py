@@ -9,7 +9,7 @@ The contract under test, layer by layer:
     last-good revision keeps serving, typed 404/429 sheds.
   - ENGINE IDENTITY: a mixed-adapter continuous batch is bit-identical
     to per-adapter sequential runs — through plain decode, adapter-
-    scoped prefix-cache hits, speculative decode, and a tensor mesh —
+    scoped prefix-cache hits and a tensor mesh —
     while ``compiled_programs()`` never grows a per-adapter entry.
   - WIRE: ``model@adapter`` resolves through ModelServer to the engine
     (predict + streaming), unknown adapters shed 404, and a request
@@ -372,10 +372,9 @@ class TestAdapterEngineIdentity:
         want = _sequential_refs(lm, workload)
         # Count compiles only for the co-batched engine under test
         # (the reference engine above did its own, identical, two).
-        compiles = {"chunked_prefill": 0, "decode_rounds": 0, "verify": 0}
+        compiles = {"chunked_prefill": 0, "decode_rounds": 0}
         for attr, key in (("prefill_chunk_into_slot", "chunked_prefill"),
-                          ("decode_rounds", "decode_rounds"),
-                          ("verify_step", "verify")):
+                          ("decode_rounds", "decode_rounds")):
             monkeypatch.setattr(gen_mod, attr, _counting_proxy(
                 getattr(gen_mod, attr), compiles, key))
         eng = _engine(lm, adapters=_registry(lm[0]), name="ad-mixed")
@@ -402,7 +401,7 @@ class TestAdapterEngineIdentity:
             assert stats["adapters"]["adapters_resident"] == 2
         finally:
             eng.close()
-        two = {"chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
+        two = {"chunked_prefill": 1, "decode_rounds": 1}
         assert compiles == two
         assert eng.compiled_programs() == two
 
@@ -433,44 +432,6 @@ class TestAdapterEngineIdentity:
         finally:
             eng.close()
 
-    def test_speculative_identity(self, lm, monkeypatch):
-        """Draft/verify speculation over a mixed-adapter batch stays
-        bit-identical to the non-speculative sequential runs (the
-        verify program gathers the same per-slot delta)."""
-        import kubeflow_tpu.serving.engine as eng_mod
-
-        workload = [(adapter, prompt, NEW_TOKENS)
-                    for adapter, prompt, _ in _mixed_workload(n_each=1)]
-        want = _sequential_refs(lm, workload, name="ad-spec-ref")
-
-        # Random prompts and 10 new tokens never give the n-gram
-        # drafter a repeated bigram (no verify round ran: the test was
-        # red).  An oracle proposes each request's own continuation,
-        # so every verify accepts under ITS adapter's delta, however
-        # the requests interleave; no throughput veto of the rounds.
-        def oracle(history, k):
-            hist = history.tolist()
-            for (_, prompt, _), ref in zip(workload, want):
-                if hist[:len(prompt)] == prompt.tolist():
-                    return np.asarray(ref[len(hist):len(hist) + k],
-                                      np.int32)
-            return np.empty((0,), np.int32)
-
-        monkeypatch.setattr(eng_mod, "_ngram_propose", oracle)
-        monkeypatch.setattr(eng_mod, "_SPEC_RATE_MARGIN", 0.0)
-        # Rounds of 2: the budget is 10 tokens, and a round of 8 that
-        # runs while the first draft is made leaves a verify no room.
-        eng = _engine(lm, adapters=_registry(lm[0]),
-                      speculative_tokens=3, decode_rounds=2,
-                      name="ad-spec")
-        try:
-            outs = _run_concurrent(eng, workload)
-            assert outs == want
-            assert eng.stats()["spec_accepted"] > 0
-            assert eng.compiled_programs()["verify"] == 1
-        finally:
-            eng.close()
-
     def test_mesh2_identity(self, lm):
         """The stacked adapter axis sharded over tensor=2 changes no
         token: mixed traffic equals the unsharded sequential runs."""
@@ -488,19 +449,17 @@ class TestAdapterEngineIdentity:
             eng.close()
 
     @pytest.mark.slow  # ~9s combined sweep; the per-path tests above stay tier-1
-    def test_full_sweep_spec_prefix_mesh(self, lm):
-        """The heavy combination: speculation ON, prefix cache ON,
-        tensor=2 mesh, 12 mixed requests over 3 slots with slot reuse
-        and repeated prompts — every row equals its sequential twin."""
+    def test_full_sweep_prefix_mesh(self, lm):
+        """The heavy combination: prefix cache ON, tensor=2 mesh, 12
+        mixed requests over 3 slots with slot reuse and repeated
+        prompts — every row equals its sequential twin."""
         from kubeflow_tpu.serving import sharding
 
         workload = _mixed_workload(n_each=4)
-        want = _sequential_refs(lm, workload, name="ad-sweep-ref",
-                                speculative_tokens=3)
+        want = _sequential_refs(lm, workload, name="ad-sweep-ref")
         eng = _engine(lm, adapters=_registry(lm[0]),
                       mesh=sharding.build_mesh({"tensor": 2}),
-                      speculative_tokens=3, prefix_caching=True,
-                      name="ad-sweep")
+                      prefix_caching=True, name="ad-sweep")
         try:
             outs = _run_concurrent(eng, workload)
             assert outs == want

@@ -657,7 +657,7 @@ def _engine(cfg, params, **kw):
         name="dots3-test", **kw)
 
 
-@pytest.mark.parametrize("flag", ["speculative_tokens", "host_spill_blocks"])
+@pytest.mark.parametrize("flag", ["host_spill_blocks", "adapters", "mesh"])
 def test_engine_refuses_at_construction_by_name(dots3, flag):
     cfg, params = dots3
     with pytest.raises(ValueError, match=flag):

@@ -17,6 +17,7 @@ is right about once in 16 and both branches of a step are taken.
 """
 
 import dataclasses
+import time
 
 import numpy as np
 import pytest
@@ -775,8 +776,7 @@ def _stream(engine, prompt, new):
 
 
 @pytest.mark.parametrize("flag, value", [
-    ("speculative_tokens", 4), ("host_spill_blocks", 4),
-    ("temperature", 0.7)])
+    ("host_spill_blocks", 4), ("temperature", 0.7)])
 def test_engine_refuses_at_construction_by_name(dots, flag, value):
     cfg, params = dots
     with pytest.raises(ValueError, match=flag):
@@ -942,3 +942,87 @@ def test_a_budget_or_an_eos_cuts_a_pair(dots, shift, new, eos):
     # One token from the prefill, then pairs, the last cut or not.
     assert s["mtp_steps"] == (len(want) // 2 if shift == 1
                               else len(want) - 1)
+
+
+def _drafting_and_undrafted(cfg, params, new, serve):
+    """What ``serve(engine)`` returns on the drafting engine and on the
+    same stack with its module left out, each engine closed after."""
+    out = []
+    for c, p in ((cfg, params), _without_module(cfg, params)):
+        engine = _engine(c, p, new=new)
+        try:
+            out.append(serve(engine))
+        finally:
+            engine.close(drain_s=0.0)
+    return out
+
+
+def _settled_stats(engine):
+    time.sleep(0.05)  # the last round's accounting runs beside the reply
+    return engine.stats()
+
+
+@pytest.mark.parametrize("cut", [1, 2, 3, 4])
+def test_a_stream_cut_inside_a_pair_resumes_as_the_undrafted_engines(
+        dots, cut):
+    """Every draft right, so after the prefill's token every step is a
+    pair: 3 -> 4 (5 6) (7 8) ...  A client that has read ``cut`` tokens
+    of a stream (2 and 4 fall between the two tokens of a pair) and
+    resumes from them gets, streamed and whole, the undrafted engine's
+    tokens, and the resumed steps are pairs again."""
+    cfg, params = dots
+    prompt = np.asarray([9, 1, 2, 3], np.int32)
+    want = list(range(4, 13))
+
+    def serve(engine):
+        _, stream = engine.submit_stream(
+            {"tokens": prompt, "max_new_tokens": 9})
+        streamed = [t for chunk in stream for t in chunk]
+        before = _settled_stats(engine)
+        resumed = np.asarray(engine.submit(
+            {"tokens": prompt, "resume_tokens": streamed[:cut],
+             "max_new_tokens": 9})["tokens"])[0, 4:].tolist()
+        after = _settled_stats(engine)
+        return streamed, resumed, [after[k] - before[k]
+                                   for k in ("mtp_steps", "mtp_accepted")]
+
+    drafting, plain = _drafting_and_undrafted(
+        cfg, _cycle(params, 1), 9, serve)
+    assert drafting[:2] == plain[:2] == (want, want)
+    # One token from the resumed prefill, then pairs, the last cut or
+    # not: no draft was refused for having been resumed.
+    assert drafting[2] == [(9 - cut) // 2] * 2 and plain[2] == [0, 0]
+
+
+@pytest.mark.parametrize("shift", [1, 2])
+def test_a_drafting_engine_runs_a_round_ahead_and_reuses_its_slots(
+        dots, shift):
+    """Five requests through three slots, every draft right (a slot
+    advances by two a step, and the round ahead covers the unread
+    one's worst case) or none: the loop keeps a round in flight, a slot
+    freed by a pair is claimed again, and the tokens are the undrafted
+    engine's."""
+    import concurrent.futures as cf
+
+    cfg, params = dots
+    prompts = [np.asarray([9, 7, 8, last], np.int32)
+               for last in (1, 2, 3, 1, 2)]
+    # The budget of the tests above, so that their programs serve.
+    news = [9, 8, 6, 9, 5]
+
+    def serve(engine):
+        with cf.ThreadPoolExecutor(5) as pool:
+            outs = list(pool.map(
+                lambda i: np.asarray(engine.submit(
+                    {"tokens": prompts[i], "max_new_tokens": news[i]}
+                )["tokens"])[0, 4:].tolist(), range(5)))
+        return outs, _settled_stats(engine)
+
+    (outs, s), (plain, _) = _drafting_and_undrafted(
+        cfg, _cycle(params, shift), 9, serve)
+    assert outs == plain == [list(range(int(p[3]) + 1, int(p[3]) + 1 + n))
+                             for p, n in zip(prompts, news)]
+    assert s["requests"] == 5 and s["active_slots"] == 0
+    assert s["rounds_ahead"] > 0
+    assert s["mtp_accepted"] == (s["mtp_drafted"] if shift == 1 else 0)
+    assert s["tokens"] == sum(news)

@@ -6,6 +6,7 @@ the other engine tests run, and (PR 26) the same LM as a looped stack:
 two loop steps over its two layers, sandwich norms, so that every path the
 engine sizes by the pool's leading axis runs with 4 planes for 2 layers."""
 
+import dataclasses
 import re
 import threading
 import time
@@ -78,16 +79,13 @@ def _programs(lm):
     return {
         "decode_rounds": (g.decode_rounds, (
             cfg, params, state, decode, 4, tables, i32(4))),
-        "verify_step": (g.verify_step, (
-            cfg, params, state, decode, 3, np.zeros((4, 3), np.int32),
-            np.zeros((4,), np.int32), tables)),
         "prefill_chunk_into_slot": (g.prefill_chunk_into_slot, (
             cfg, params, state, decode, chunk, i32(0), i32(1), i32(1),
             i32(0), i32(0), tables[:1])),
     }
 
 
-PROGRAMS = ["decode_rounds", "verify_step", "prefill_chunk_into_slot"]
+PROGRAMS = ["decode_rounds", "prefill_chunk_into_slot"]
 
 
 def _scopes(lm):
@@ -148,7 +146,7 @@ def test_every_dot_gather_and_scatter_of_the_layer_body_has_a_scope(
     import jax
 
     fn, args = _programs(lm)[program]
-    static = {"decode_rounds": (0, 3, 4), "verify_step": (0, 3, 4),
+    static = {"decode_rounds": (0, 3, 4),
               "prefill_chunk_into_slot": (0, 3)}[program]
     jaxpr = jax.make_jaxpr(fn, static_argnums=static)(*args)
     counted = [e.primitive.name for e in _all_eqns(jaxpr.jaxpr)]
@@ -220,12 +218,10 @@ def _prompts(n, length=9, seed=SEED):
     return [rng.randint(1, VOCAB, size=(length,)).tolist() for _ in range(n)]
 
 
-PATHS = {
-    "cap4": ({"decode_rounds": 4}, "decode_rounds"),
-    "cap1": ({"decode_rounds": 1}, "decode_rounds"),
-    # A prompt that repeats itself, so the n-gram drafter proposes.
-    "verify": ({"decode_rounds": 1, "speculative_tokens": 3}, "verify"),
-}
+# The round caps: the engine's own (8: what a bare engine and every
+# cell run), a narrower one and one step a dispatch.
+PATHS = {"cap8": {}, "cap4": {"decode_rounds": 4},
+         "cap1": {"decode_rounds": 1}}
 
 
 def _iterations(top):
@@ -252,14 +248,10 @@ def test_phases_tile_every_iteration_in_order(lm, path, monkeypatch):
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
     Recorder.events = []
-    kw, program = PATHS[path]
-    engine = _engine(lm, name=f"phases-{path}", **kw)
+    engine = _engine(lm, name=f"phases-{path}", **PATHS[path])
     try:
-        prompts = _prompts(4)
-        if path == "verify":
-            prompts = [[7, 8, 9] * 5 for _ in range(4)]
-        _serve(engine, prompts)
-        assert engine.compiled_programs()[program] == 1
+        _serve(engine, _prompts(4))
+        assert engine.compiled_programs()["decode_rounds"] == 1
         stats = engine.stats()
     finally:
         engine.close()
@@ -327,9 +319,8 @@ def test_phases_tile_every_iteration_in_order(lm, path, monkeypatch):
     assert 0.9 * span <= covered <= span
     assert stepped >= 3
     # A round read in the iteration after its own was dispatched ahead
-    # of: the engine counts the same; a speculating engine reads first.
-    assert ahead == stats["rounds_ahead"]
-    assert (ahead == 0) if path == "verify" else (ahead >= 2)
+    # of: the engine counts the same.
+    assert ahead == stats["rounds_ahead"] >= 2
     chunks = sum(e["facts"].get("chunks", 0) for e in events
                  if e["name"].endswith("prefill_dispatch"))
     assert chunks == engine.stats()["prefill_chunks"]
@@ -446,7 +437,7 @@ def _loop_events(engine):
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_round_read_lies_inside_round_wait_in_every_round(
         lm, path, monkeypatch):
-    """Every ``round_wait`` (a decode round's, a verify round's, the
+    """Every ``round_wait`` (a decode round's, the
     blocking read of a prefill's first token inside ``drain``) holds ONE
     ``round_read``, entered after the wait's own stretch and ending with
     it; the round's facts stay on ``round_wait``, which a traced run's
@@ -455,10 +446,9 @@ def test_round_read_lies_inside_round_wait_in_every_round(
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
     Recorder.events = []
-    kw, _ = PATHS[path]
-    engine = _engine(lm, name=f"read-{path}", **kw)
+    engine = _engine(lm, name=f"read-{path}", **PATHS[path])
     try:
-        prompts = [[7, 8, 9] * 5] * 4 if path == "verify" else _prompts(4)
+        prompts = _prompts(4)
         _serve(engine, prompts)
         stats = engine.stats()
     finally:
@@ -477,12 +467,9 @@ def test_round_read_lies_inside_round_wait_in_every_round(
     # One blocking read a prompt (its first token), the rest are rounds.
     assert len(in_drain) == len(prompts)
     rounds = [w for w in waits if not any(w is d for d in in_drain)]
-    assert len(rounds) == stats["fused_rounds"] + stats["spec_steps"]
-    fused = [w for w in rounds if "steps" in w["facts"]]
-    assert len(fused) == stats["fused_rounds"]
-    assert all("attended" in w["facts"] for w in fused)
-    if path == "verify":
-        assert stats["spec_steps"] >= 1
+    assert len(rounds) == stats["fused_rounds"]
+    assert all("steps" in w["facts"] and "attended" in w["facts"]
+               for w in rounds)
     # What the reads took is in stats() under its own name, and the
     # wait's own stretch no longer holds it.
     read_s = sum(e["t1"] - e["t0"] for e in reads)
@@ -713,7 +700,7 @@ def test_compile_counters_are_set_once(lm):
     assert second["compile_s"] == first["compile_s"]
     assert second["compiled_peak_bytes"] == first["compiled_peak_bytes"]
     assert second["compiled_programs"] == first["compiled_programs"] == {
-        "chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
+        "chunked_prefill": 1, "decode_rounds": 1}
 
 
 def test_pool_hand_off_and_stats_are_sized_by_planes(lm):
@@ -837,7 +824,11 @@ def test_a_wide_chunk_waits_for_the_rounds_to_save_it_up(beside,
     assert engine_module.PREFILL_ROUND_TOKENS == 64
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
     Recorder.events = []
-    engine = _engine(_stack(max_seq_len=512), slots=2, prefill_len=448,
+    cfg, params, decode = _stack(max_seq_len=512)
+    # The live slot's 60 tokens are the export's budget too, so that it
+    # outlives the long prompt's chunks however the box is loaded.
+    decode = dataclasses.replace(decode, max_new_tokens=60)
+    engine = _engine((cfg, params, decode), slots=2, prefill_len=448,
                      prefill_chunk_tokens=128, decode_rounds=1,
                      name=f"saved-up-{beside}")
     long_prompt = _prompts(1, length=440, seed=7)[0]
@@ -1036,7 +1027,7 @@ def test_view_attention_over_held_tiles_is_the_whole_views(start, int8):
     (128, 1040, False), (256, 2560, False), (256, 6400, False),
     (256, 6624, False)])
 def test_which_calls_visit_a_view_by_key_tiles(t, view, one_pass):
-    """A decode or verify step, a call of one query tile and a table of
+    """A decode step, a call of one query tile and a table of
     one or two key tiles (reason's 512, workers' 704 positions) run one
     pass; the cells' long tables are visited in tiles no coarser than an
     eighth of the table or 512 positions, in whole pages."""

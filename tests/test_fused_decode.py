@@ -3,7 +3,7 @@ serving/engine.py ``decode_rounds > 1``, docs §5.2e): the while_loop
 round program must be INVISIBLE in the tokens — fused(k=8) ==
 unfused(k=1) == single-request generate() across slot reuse, EOS
 inside a round, deadline expiry at a round boundary, mid-round
-admission, speculation-ON mixed traffic, and SPMD meshes — while the
+admission, and SPMD meshes — while the
 fused engine compiles exactly ONE extra program (and the k=1 path
 compiles none)."""
 
@@ -115,10 +115,8 @@ class TestFusedDecode:
         all ride the same executable)."""
         from kubeflow_tpu.models import generate as gen_mod
 
-        compiles = {"chunked_prefill": 0, "verify": 0,
-                    "decode_rounds": 0}
+        compiles = {"chunked_prefill": 0, "decode_rounds": 0}
         for attr, key in (("prefill_chunk_into_slot", "chunked_prefill"),
-                          ("verify_step", "verify"),
                           ("decode_rounds", "decode_rounds")):
             monkeypatch.setattr(gen_mod, attr, _counting_proxy(
                 getattr(gen_mod, attr), compiles, key))
@@ -160,10 +158,9 @@ class TestFusedDecode:
         assert plain_stats["steps_per_round_p99"] == 1
         assert plain_stats["fused_rounds"] == plain_stats["steps"]
 
-        assert compiles == {"chunked_prefill": 2, "verify": 0,
-                            "decode_rounds": 2}
+        assert compiles == {"chunked_prefill": 2, "decode_rounds": 2}
         assert fused_programs == plain_programs == {
-            "chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
+            "chunked_prefill": 1, "decode_rounds": 1}
 
     def test_eos_inside_round_matches_generate(self, engine_model):
         """A slot whose EOS lands mid-round freezes on device; the
@@ -285,41 +282,6 @@ class TestFusedDecode:
                 assert got == ref, f"request {key!r} drifted"
         finally:
             engine.close()
-
-    def test_spec_on_mixed_traffic_identity(self, engine_model,
-                                            monkeypatch):
-        """Speculation + fused rounds coexist: draft-ahead verify
-        rounds interleave with fused decode rounds and the mixed
-        repetitive/random workload stays token-identical to
-        generate()."""
-        import kubeflow_tpu.serving.engine as eng_mod
-
-        # Zero the measured-throughput margin so gating never vetoes
-        # verify rounds on a loaded box — identity is what is under
-        # test, and it must hold regardless of gating.
-        monkeypatch.setattr(eng_mod, "_SPEC_RATE_MARGIN", 0.0)
-
-        spec, _ = engine_model
-        rng = np.random.RandomState(SEED + 21)
-        prompts, news = [], []
-        for i in range(8):
-            if i % 2 == 0:
-                pat = rng.randint(1, VOCAB, size=(4,))
-                prompts.append(np.tile(pat, 3).tolist())
-            else:
-                prompts.append(
-                    rng.randint(1, VOCAB, size=(10,)).tolist())
-            news.append([12, 8, 10, 6][i % 4])
-        want = _reference_rows(spec, prompts, news)
-        outs, stats, programs = _run_engine(
-            spec, prompts, news, decode_rounds=8, slots=2,
-            speculative_tokens=4, name="fused-spec")
-        for i in range(len(prompts)):
-            got = np.asarray(outs[i]["tokens"])[0].tolist()
-            assert got == want[i], (
-                f"spec-ON fused request {i} drifted from generate()")
-        assert stats["fused_rounds"] > 0
-        assert programs["decode_rounds"] == 1
 
     @pytest.mark.parametrize("tensor", [2])
     def test_mesh_fused_identity(self, engine_model, tensor):
@@ -742,29 +704,6 @@ class TestOneRoundAhead:
         assert stats["rounds_ahead"] >= 3
         assert stats["in_flight_requests"] == stats["active_slots"] == 0
 
-    def test_a_speculating_engine_reads_before_it_dispatches(
-            self, engine_model, monkeypatch):
-        """``speculative_tokens``: the next program (verify or decode)
-        is chosen from the round's tokens, so no round is dispatched
-        ahead; the tokens are generate()'s."""
-        import kubeflow_tpu.serving.engine as eng_mod
-
-        monkeypatch.setattr(eng_mod, "_SPEC_RATE_MARGIN", 0.0)
-        spec, _ = engine_model
-        rng = np.random.RandomState(SEED + 53)
-        prompts = [np.tile(rng.randint(1, VOCAB, size=(4,)), 3).tolist(),
-                   rng.randint(1, VOCAB, size=(10,)).tolist(),
-                   rng.randint(1, VOCAB, size=(6,)).tolist()]
-        news = [12, 9, 12]
-        want = _reference_rows(spec, prompts, news)
-        outs, stats, _ = _run_engine(
-            spec, prompts, news, decode_rounds=4, slots=2,
-            speculative_tokens=4, name="ahead-spec")
-        for i, ref in enumerate(want):
-            assert np.asarray(outs[i]["tokens"])[0].tolist() == ref
-        assert stats["fused_rounds"] > 0
-        assert stats["rounds_ahead"] == 0
-
 
 def _with_config(spec, **over):
     """The tiny LM with some fields of its config changed, and weights
@@ -924,15 +863,6 @@ _PARENT_TOKENS = {
         [123, 123, 123, 123],
         [126, 126, 102, 102, 98, 48, 102, 98, 98, 98],
     ],
-    "verify": [
-        [98, 98, 98, 98, 98, 98, 98, 98, 98, 98, 98, 98],
-        [16, 16, 16, 16, 16, 16],
-        [23, 23, 23],
-        [2, 73, 43, 43, 25, 113, 113, 102],
-        [102, 102, 16, 16, 16, 16, 16, 16, 16, 16, 16, 16],
-        [123, 123, 123, 123],
-        [62, 62, 62, 62, 62, 86, 8, 8, 59, 59],
-    ],
 }
 
 
@@ -941,14 +871,13 @@ class TestPoolCarriedInPlace:
     layer scan: a layer scatters its columns at (plane, block, offset)
     and reads its pages by plane.  Invisible in the tokens, for every
     stack that runs the paged programs: plain and int8 pools, rounds
-    of one step and of eight, a verify round, a looped stack, a mesh."""
+    of one step and of eight, a looped stack, a mesh."""
 
     CASES = {
         "plain-k1": {"decode_rounds": 1},
         "plain-k8": {"decode_rounds": 8},
         "int8-k1": {"decode_rounds": 1, "kv": "int8"},
         "int8-k8": {"decode_rounds": 8, "kv": "int8"},
-        "verify": {"decode_rounds": 1, "speculative_tokens": 4},
         "looped": {"decode_rounds": 8,
                    "cfg": {"loop_steps": 2, "sandwich_norm": True}},
         "mesh4": {"decode_rounds": 8, "tensor": 4,
@@ -956,9 +885,8 @@ class TestPoolCarriedInPlace:
     }
 
     @classmethod
-    def serve(cls, spec, case, monkeypatch):
+    def serve(cls, spec, case):
         """(new tokens per request, generate()'s, stats) of one case."""
-        import kubeflow_tpu.serving.engine as eng_mod
         from kubeflow_tpu.serving import sharding
 
         case = dict(cls.CASES[case])
@@ -970,12 +898,6 @@ class TestPoolCarriedInPlace:
         lens = [3, 9, 16, 2, 12, 16, 5]
         news = [12, 6, 3, 8, 12, 4, 10]
         prompts = [rng.randint(1, VOCAB, size=(n,)).tolist() for n in lens]
-        if "speculative_tokens" in case:
-            # Repeating prompts, so that the drafter has something to
-            # propose, and no throughput veto of the verify rounds.
-            prompts = [np.tile(p[:4], 3).tolist() if i % 2 == 0 else p
-                       for i, p in enumerate(prompts)]
-            monkeypatch.setattr(eng_mod, "_SPEC_RATE_MARGIN", 0.0)
         tensor = case.pop("tensor", 0)
         if tensor:
             case["mesh"] = sharding.build_mesh({"tensor": tensor})
@@ -990,13 +912,11 @@ class TestPoolCarriedInPlace:
 
     @pytest.mark.parametrize("case", sorted(CASES))
     def test_tokens_are_generates_and_the_parents(self, engine_model,
-                                                  monkeypatch, case):
-        got, want, stats = self.serve(engine_model[0], case, monkeypatch)
+                                                  case):
+        got, want, stats = self.serve(engine_model[0], case)
         assert got == want, "drifted from single-request generate()"
         assert got == _PARENT_TOKENS[case], "drifted from the parent's"
         assert stats["steps"] > 0
-        if case == "verify":
-            assert stats["spec_steps"] > 0
         if case == "looped":
             assert stats["kv_planes"] == 4 and stats["fused_rounds"] > 0
         if case == "mesh4":
@@ -1042,13 +962,6 @@ class TestPoolCarriedInPlace:
 
         tables = jnp.asarray(
             rng.permutation(nb)[:slots * mb].reshape(slots, mb), jnp.int32)
-        # Every slot retired: a verify window parks its writes.
-        s = state()
-        before = pool(s)
-        s, _, _ = gen.verify_step(
-            cfg, params, s, decode, 2, jnp.ones((slots, 2), jnp.int32),
-            jnp.full((slots,), 2, jnp.int32), tables)
-        unchanged(before, s)
         # Live slots whose next position falls on a page the table does
         # not hold (the sentinel): rounds of one step and of three, and
         # a round of two in which only slot 0 lives, so that each of

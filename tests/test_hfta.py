@@ -1,12 +1,15 @@
 """Horizontally fused training arrays (runtime/hfta.py).
 
-The HFTA contract is BIT-identity, not allclose: member i of a fused
-run must produce exactly the arrays its width-1 solo run produces —
-across fused widths, across an early-stopped peer, and across a
-preempt/resume boundary.  The solo control is therefore a WIDTH-1
-FusedTrainer run (the same vmapped step): a plain ``Trainer`` step
-differs from the batched-GEMM accumulation order at ~1e-8 and is only
-allclose-comparable.
+The HFTA contract at ONE fused width is BIT-identity, not allclose:
+the same compiled step on the same inputs gives exactly the same
+arrays — across an early-stopped peer and across a preempt/resume
+boundary.  ACROSS widths (member i of a width-4 run against its
+width-1 solo run) the compiler may order a reduction differently, so
+the gradients agree to the last bits only, and the contract is the
+same trajectory within a stated tolerance (``assert_same_trajectory``).
+The solo control is a WIDTH-1 FusedTrainer run (the same vmapped
+step): a plain ``Trainer`` step differs from the batched-GEMM
+accumulation order at ~1e-8 as well.
 
 Same-task FusedTrainers share one compiled step (the process-level
 cache in runtime/hfta.py), so only the first run of each WIDTH pays a
@@ -79,20 +82,45 @@ def assert_bit_identical(a, b):
         assert np.array_equal(x, y)
 
 
+# 64 float32 ulps at the parameters' scale (the norm scales are ~1.0):
+# 7.6e-6, 0.4 % of the smallest lr here.
+ULPS = 64 * float(np.spacing(np.float32(1.0)))
+
+
+def assert_same_trajectory(a, b, lr, steps):
+    """The same member at two fused widths.  ``vmap`` width may change
+    a reduction's order, so a gradient differs in its last bits, and a
+    step moves a parameter by lr * m / (sqrt(v) + eps): the elements
+    agree within ULPS but for a handful whose gradient is of eps's own
+    size (an embedding row the batch barely touches), which may land
+    anywhere within Adam's reach, 2 * lr a step.  Measured over 5
+    steps of 3,632 elements: members 0, 2, 3 differ by under ONE ulp
+    everywhere, member 1 by more than 4 in 205, 16 in 7, 64 in 1 (by
+    1 % of its lr); an lr 10 % off, or one step too many, moves more
+    than 3,600 of them past 128."""
+    assert len(a) == len(b)
+    diff = np.concatenate([np.abs(x - y).ravel() for x, y in zip(a, b)])
+    assert diff.max() <= 2 * lr * steps
+    assert np.mean(diff > ULPS) <= 0.005
+
+
 class TestWidthInvariance:
     def test_member_params_bit_identical_to_solo_control(self, task,
                                                          fused4):
-        """Fused width-4 == width-1 per member: fusion must be
-        invisible to each member's trajectory.  Members 0 and 3
-        bracket the lr/seed spread; 1 and 2 ride the same vmap lane
-        mechanics."""
+        """Fused width-4 == width-1 per member, to the tolerance a
+        reduction's order leaves: fusion must be invisible to each
+        member's trajectory.  Members 0 and 3 bracket the lr/seed
+        spread; 1 and 2 ride the same vmap lane mechanics."""
         ft4, s4 = fused4
         members = specs(4)
         for i in (0, 3):
             ft1 = make(task, [members[i]])
             s1 = ft1.fit(data_factory(), 5, log_every=10)
-            assert_bit_identical(member_leaves(ft1, s1, 0),
-                                 member_leaves(ft4, s4, i))
+            assert int(ft1.member_state(s1, 0).step) \
+                == int(ft4.member_state(s4, i).step) == 5
+            assert_same_trajectory(member_leaves(ft1, s1, 0),
+                                   member_leaves(ft4, s4, i),
+                                   members[i].lr, 5)
 
     def test_member_validation(self, task):
         with pytest.raises(ValueError, match="duplicate"):
@@ -113,12 +141,16 @@ class TestEarlyStopMasking:
         assert ft.last_active == [False, False, False, False]
         steps = [int(ft.member_state(s, i).step) for i in range(4)]
         assert steps == [5, 2, 5, 5]
-        # m1 == its own width-1 control run exactly stop_step steps.
+        # m1 == its own width-1 control run exactly stop_step steps
+        # (another width: the same trajectory, not the same bits).
         ft1 = make(task, [specs(4)[1]])
         s1 = ft1.fit(data_factory(), 2, log_every=10)
-        assert_bit_identical(member_leaves(ft1, s1, 0),
-                             member_leaves(ft, s, 1))
-        # Peers == the reference run with no stop anywhere.
+        assert int(ft1.member_state(s1, 0).step) == 2
+        assert_same_trajectory(member_leaves(ft1, s1, 0),
+                               member_leaves(ft, s, 1),
+                               specs(4)[1].lr, 2)
+        # Peers == the reference run with no stop anywhere (the same
+        # width, the same program: bit for bit).
         ft_full, s_full = fused4
         for i in (0, 2, 3):
             assert_bit_identical(member_leaves(ft_full, s_full, i),

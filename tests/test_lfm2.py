@@ -526,7 +526,7 @@ def _engine(cfg, params, **kw):
         slots=3, prefill_len=160, max_len=176, name="lfm2-test", **kw)
 
 
-@pytest.mark.parametrize("flag", ["speculative_tokens", "host_spill_blocks"])
+@pytest.mark.parametrize("flag", ["host_spill_blocks", "adapters", "mesh"])
 def test_engine_refuses_at_construction_by_name(lfm2, flag):
     cfg, params = lfm2
     with pytest.raises(ValueError, match=flag):

@@ -166,9 +166,8 @@ class TestDecodeEngine:
     be token-identical to single-request generate(), across mixed
     prompt lengths, per-request budgets, and slot reuse — while
     compiling exactly two device programs for the whole workload
-    (the third, speculative verify, only exists under
-    ``speculative_tokens`` — see TestSpeculativeDecoding; prefix reuse
-    is zero-copy block-table aliasing, never a device program)."""
+    (prefix reuse is zero-copy block-table aliasing, never a device
+    program)."""
 
     @ROUND_CAPS
     def test_matches_generate_mixed_lengths_slot_reuse_three_programs(
@@ -178,10 +177,9 @@ class TestDecodeEngine:
         from kubeflow_tpu.models import generate as gen_mod
         from kubeflow_tpu.serving.engine import DecodeEngine
 
-        compiles = {"chunked_prefill": 0, "decode_rounds": 0, "verify": 0}
+        compiles = {"chunked_prefill": 0, "decode_rounds": 0}
         for attr, key in (("prefill_chunk_into_slot", "chunked_prefill"),
-                          ("decode_rounds", "decode_rounds"),
-                          ("verify_step", "verify")):
+                          ("decode_rounds", "decode_rounds")):
             monkeypatch.setattr(gen_mod, attr, _counting_proxy(
                 getattr(gen_mod, attr), compiles, key))
 
@@ -234,10 +232,9 @@ class TestDecodeEngine:
             engine.close()
         # The whole mixed workload — admission waves, slot reuse,
         # varying budgets, multi-chunk prefills, zero-copy prefix
-        # aliasing — compiled exactly two programs (no speculative
-        # verify: this engine runs with speculation off; no prefix
-        # copy program EXISTS — a cache hit is a block-table edit).
-        two = {"chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
+        # aliasing — compiled exactly two programs (no prefix copy
+        # program EXISTS — a cache hit is a block-table edit).
+        two = {"chunked_prefill": 1, "decode_rounds": 1}
         assert compiles == two
         assert engine.compiled_programs() == two
 
@@ -479,7 +476,7 @@ class TestDecodeEngine:
             assert stats["prefix_hits"] == 1
             assert stats["cached_prompt_tokens"] == 8
             assert set(stats["compiled_programs"]) == {
-                "chunked_prefill", "decode_rounds", "verify"}
+                "chunked_prefill", "decode_rounds"}
             # White-box: the alias really is the SAME physical pages —
             # p2's own published record leads with p1's block ids (its
             # prefill never wrote new pages for the shared prefix; a
@@ -729,7 +726,7 @@ class TestDecodeEngine:
         assert stats["prefix_hits"] == 1
         assert stats["cached_prompt_tokens"] == 192
         assert set(stats["compiled_programs"]) == {
-            "chunked_prefill", "decode_rounds", "verify"}
+            "chunked_prefill", "decode_rounds"}
         starts = [start for start, _, _ in seen]
         assert starts == [0, 128, 256, 192, 320, 448]
         held = np.diff([0] + [h for _, h, _ in seen]).tolist()
@@ -868,7 +865,7 @@ class TestDecodeEngine:
                               prefill_len=16, name="test-defaults")
         try:
             assert engine.compiled_programs() == {
-                "chunked_prefill": 0, "decode_rounds": 0, "verify": 0}
+                "chunked_prefill": 0, "decode_rounds": 0}
             out = engine.submit({"tokens": np.asarray(_prompt(), np.int32)})
             want = _reference_rows(spec, [_prompt()], [NEW_TOKENS])[0]
             assert np.asarray(out["tokens"])[0].tolist() == want
@@ -878,7 +875,7 @@ class TestDecodeEngine:
         assert stats["decode_rounds"] == 8
         assert stats["steps_per_round_p99"] > 1
         assert stats["compiled_programs"] == {
-            "chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
+            "chunked_prefill": 1, "decode_rounds": 1}
 
     def test_rest_routing_and_stats_route(self, engine_model):
         """Wired behind ModelServer via the serving entrypoint's
@@ -956,7 +953,7 @@ class TestDecodeEngine:
                 {"tokens": prompts[i][None], "max_new_tokens": news[i]}))
             assert engine.stats()["tokens"] == sum(news)
             assert engine.compiled_programs() == {
-                "chunked_prefill": 1, "decode_rounds": 1, "verify": 0}
+                "chunked_prefill": 1, "decode_rounds": 1}
         finally:
             engine.close()
         # The static path bakes the export's budget into its programs:
@@ -977,278 +974,6 @@ class TestDecodeEngine:
             assert got[i] == static[i][:n + new], (
                 f"request {i} (len {n}, budget {new}): the engine and "
                 "the static batcher decoded different tokens")
-
-
-class TestSpeculativeDecoding:
-    """Token-identity battery for self-speculative decoding
-    (serving/engine.py speculative_tokens + models/generate.py
-    verify_step): speculation must be INVISIBLE in the tokens — spec ON
-    == spec OFF == single-request generate() on every path, including
-    forced full rejection, mid-stream EOS inside an accepted draft
-    window, and device-side rollback followed by slot reuse."""
-
-    def _mixed_workload(self):
-        """Prompts the drafter can and cannot predict: pattern-tiled
-        (repetitive — greedy continuations of the tiny model collapse
-        into cycles the n-gram drafter proposes) interleaved with
-        random ones (the drafter finds no suffix match early on, so
-        plain decode rounds run too — both the decode AND verify
-        programs must compile)."""
-        rng = np.random.RandomState(SEED + 21)
-        prompts, news = [], []
-        for i in range(8):
-            if i % 2 == 0:
-                pat = rng.randint(1, VOCAB, size=(4,))
-                prompts.append(np.tile(pat, 3).tolist())
-            else:
-                prompts.append(
-                    rng.randint(1, VOCAB, size=(10,)).tolist())
-            news.append([12, 8, 10, 6][i % 4])
-        return prompts, news
-
-    def _run_engine(self, spec, prompts, news, *, speculative_tokens,
-                    slots=2, decode=None, name="test-spec", **kw):
-        import threading
-
-        from kubeflow_tpu.serving.engine import DecodeEngine
-
-        engine = DecodeEngine(
-            spec["cfg"], spec["params"], decode or spec["decode"],
-            slots=slots, prefill_len=16, prefill_chunk_tokens=8,
-            kv_block_tokens=4,
-            speculative_tokens=speculative_tokens,
-            name=f"{name}-{speculative_tokens}", **kw)
-        try:
-            outs = [None] * len(prompts)
-
-            def client(i):
-                outs[i] = engine.submit({
-                    "tokens": np.asarray(prompts[i], np.int32),
-                    "max_new_tokens": news[i]})
-
-            threads = [threading.Thread(target=client, args=(i,))
-                       for i in range(len(prompts))]
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            return outs, engine.stats()
-        finally:
-            engine.close()
-
-    def test_spec_on_equals_spec_off_equals_generate_three_programs(
-            self, engine_model, monkeypatch):
-        """The tentpole identity: a mixed repetitive/random workload
-        with slot reuse is token-identical across spec ON, spec OFF,
-        and generate(), real draft acceptance happened, and the spec-ON
-        engine compiled exactly the three programs."""
-        import kubeflow_tpu.serving.engine as eng_mod
-
-        from kubeflow_tpu.models import generate as gen_mod
-
-        # The measured-throughput gate is timing-based (delivered-rate
-        # EMAs of real device calls) — on a loaded CI box it can
-        # legitimately veto verify rounds and starve the acceptance
-        # counters this test asserts on.  Zero the margin so every
-        # proposed round verifies: identity is what is under test
-        # here, and it must hold regardless of gating.
-        monkeypatch.setattr(eng_mod, "_SPEC_RATE_MARGIN", 0.0)
-
-        compiles = {"chunked_prefill": 0, "decode_rounds": 0, "verify": 0}
-        for attr, key in (("prefill_chunk_into_slot", "chunked_prefill"),
-                          ("decode_rounds", "decode_rounds"),
-                          ("verify_step", "verify")):
-            monkeypatch.setattr(gen_mod, attr, _counting_proxy(
-                getattr(gen_mod, attr), compiles, key))
-
-        spec, _ = engine_model
-        prompts, news = self._mixed_workload()
-        want = _reference_rows(spec, prompts, news)
-        # Rounds of 2 on both sides: the budgets are 6 to 12 tokens,
-        # and a round of 8 that runs while the first draft is made
-        # leaves a verify window no room.
-        on_outs, on_stats = self._run_engine(
-            spec, prompts, news, speculative_tokens=4, decode_rounds=2)
-        off_outs, off_stats = self._run_engine(
-            spec, prompts, news, speculative_tokens=0, decode_rounds=2)
-        for i in range(len(prompts)):
-            got_on = np.asarray(on_outs[i]["tokens"])[0].tolist()
-            got_off = np.asarray(off_outs[i]["tokens"])[0].tolist()
-            assert got_on == want[i], f"spec ON drifted on request {i}"
-            assert got_off == want[i], f"spec OFF drifted on request {i}"
-        # Speculation really ran: drafts proposed, some accepted, and
-        # the counters reconcile (accepted <= drafted, both visible in
-        # the acceptance-rate stats).
-        assert on_stats["spec_drafted"] > 0
-        assert 0 < on_stats["spec_accepted"] <= on_stats["spec_drafted"]
-        assert 0 < on_stats["spec_acceptance_rate"] <= 1
-        assert on_stats["accepted_per_step"] > 0
-        assert on_stats["spec_steps"] > 0
-        assert off_stats["spec_drafted"] == 0
-        assert off_stats["spec_steps"] == 0
-        # Three programs, each compiled once across BOTH engines (the
-        # spec-OFF engine reuses two of the same .lower sites and
-        # never lowers verify).
-        assert compiles == {"chunked_prefill": 2, "decode_rounds": 2,
-                            "verify": 1}
-        assert on_stats["compiled_programs"] == {
-            "chunked_prefill": 1, "decode_rounds": 1, "verify": 1}
-        assert off_stats["compiled_programs"]["verify"] == 0
-
-    def test_forced_full_rejection_rollback_and_slot_reuse(
-            self, engine_model, monkeypatch):
-        """An always-wrong drafter forces every draft to reject: the
-        device-side rollback (cache_len reset over the rejected
-        columns) must leave the slot's cache exactly as sequential
-        decode would have, across REPEATED requests through one slot —
-        no stale rejected-draft column may ever leak into a later
-        request's attention."""
-        import kubeflow_tpu.serving.engine as eng_mod
-
-        spec, _ = engine_model
-        rng = np.random.RandomState(SEED + 23)
-        pat = rng.randint(1, VOCAB, size=(4,))
-        prompts = [np.tile(pat, 3).tolist(),
-                   rng.randint(1, VOCAB, size=(9,)).tolist(),
-                   np.tile(pat, 3).tolist()]
-        news = [12, 10, 12]
-        want = _reference_rows(spec, prompts, news)
-
-        def always_wrong(history, k, *a, **kw):
-            # Guaranteed full rejection BY CONSTRUCTION: propose the
-            # reference continuation shifted by one in vocab space —
-            # the greedy target IS the reference token at each
-            # position, and (t + 1) % VOCAB != t always.  (Shifting
-            # the real drafter's proposal instead would not guarantee
-            # a mismatch: a proposal already one below the target
-            # would shift ONTO it.)
-            # The drafter runs while a round computes and its proposal
-            # is kept only if its head is what that round delivers:
-            # at a round cap of 1 (pinned below) the head is ONE
-            # token, so that one is the reference's and every token
-            # after it, the whole verify window, is wrong.
-            hist = history.tolist()
-            for prompt, ref in zip(prompts, want):
-                if len(hist) >= len(prompt) \
-                        and hist[:len(prompt)] == prompt:
-                    emitted = len(hist) - len(prompt)
-                    nxt = np.asarray(ref[len(prompt) + emitted:
-                                         len(prompt) + emitted + k],
-                                     np.int64)
-                    nxt[1:] = (nxt[1:] + 1) % VOCAB
-                    return nxt.astype(np.int32)
-            return np.empty((0,), np.int32)  # unknown prompt: no draft
-
-        monkeypatch.setattr(eng_mod, "_ngram_propose", always_wrong)
-        outs, stats = self._run_engine(
-            spec, prompts, news, speculative_tokens=4, slots=1,
-            decode_rounds=1, name="test-reject")
-        for i in range(len(prompts)):
-            got = np.asarray(outs[i]["tokens"])[0].tolist()
-            assert got == want[i], (
-                f"request {i} drifted after full-rejection rollback")
-        assert stats["spec_drafted"] > 0
-        assert stats["spec_accepted"] == 0
-        assert stats["spec_acceptance_rate"] == 0.0
-        assert stats["active_slots"] == 0
-
-    def test_eos_inside_accepted_draft_window(self, engine_model,
-                                              monkeypatch):
-        """EOS emitted MID-WINDOW: an oracle drafter (proposes the true
-        greedy continuation) guarantees the draft window is fully
-        accepted, so the EOS lands inside it — the device must cut the
-        emission at EOS, freeze the slot, and the next request must
-        reuse it cleanly."""
-        import dataclasses
-
-        import kubeflow_tpu.serving.engine as eng_mod
-
-        from kubeflow_tpu.models.generate import generate
-
-        spec, _ = engine_model
-        rng = np.random.RandomState(SEED + 25)
-        # Pick a prompt whose greedy continuation contains a token
-        # FIRST appearing at index >= 2: configured as EOS, a fully
-        # accepted 4-token draft window emits it mid-window, never as
-        # the window's first token.  (Tiny random-init models collapse
-        # to constant runs fast, so search a few candidate prompts.)
-        prompt = cont = eos = eos_idx = None
-        for _ in range(16):
-            cand = rng.randint(1, VOCAB, size=(10,)).tolist()
-            ref, _ = generate(spec["cfg"], spec["params"],
-                              np.asarray(cand, np.int32)[None],
-                              spec["decode"])
-            cand_cont = np.asarray(ref)[0, len(cand):].tolist()
-            for idx in range(2, len(cand_cont)):
-                if cand_cont[idx] not in cand_cont[:idx]:
-                    prompt, cont = cand, cand_cont
-                    eos, eos_idx = cand_cont[idx], idx
-                    break
-            if eos is not None:
-                break
-        assert eos is not None, (
-            "no candidate prompt produced a usable mid-stream EOS "
-            "token; widen the search")
-        decode = dataclasses.replace(spec["decode"], eos_token=eos)
-        want = cont[:eos_idx + 1]
-
-        def oracle(history, k, *a, **kw):
-            emitted = len(history) - len(prompt)
-            nxt = cont[emitted:emitted + k]
-            return np.asarray(nxt, np.int32)
-
-        monkeypatch.setattr(eng_mod, "_ngram_propose", oracle)
-        # One step a round: at a wider cap the plain round that runs
-        # while the first draft is made reaches the EOS itself, and no
-        # verify window is ever dispatched.
-        outs, stats = self._run_engine(
-            spec, [prompt, prompt], [NEW_TOKENS, NEW_TOKENS],
-            speculative_tokens=4, slots=1, decode=decode,
-            decode_rounds=1, name="test-eos-window")
-        for i in range(2):  # second request = slot reuse after EOS
-            got = np.asarray(outs[i]["tokens"])[0, len(prompt):].tolist()
-            assert got == want, (
-                f"request {i}: EOS-in-window emission {got} != {want}")
-        # The window really was speculative: drafts were accepted
-        # before (and including) the EOS cut.
-        assert stats["spec_accepted"] > 0
-
-    def test_sampling_export_disables_speculation(self, engine_model):
-        """Speculation is greedy-only: a sampling export silently falls
-        back to plain decode (verify would accept argmax tokens the
-        sampler never drew), and the engine still serves."""
-        import dataclasses
-
-        spec, _ = engine_model
-        decode = dataclasses.replace(spec["decode"], temperature=0.7)
-        outs, stats = self._run_engine(
-            spec, [[1, 2, 3, 4]], [6], speculative_tokens=4,
-            slots=1, decode=decode, name="test-sampling")
-        assert np.asarray(outs[0]["tokens"]).shape == (1, 10)
-        assert stats["spec_steps"] == 0
-        assert stats["spec_drafted"] == 0
-        assert stats["compiled_programs"]["verify"] == 0
-
-    def test_ngram_propose_unit(self):
-        """The drafter itself: repeated suffixes propose their
-        historical continuation; unrepetitive histories propose
-        nothing (the engine then runs plain decode)."""
-        from kubeflow_tpu.serving.engine import _ngram_propose
-
-        hist = np.asarray([5, 9, 7, 3, 9, 7], np.int32)
-        # Suffix [9, 7] recurred at positions 1-2 -> propose what
-        # followed it: [3, 9, 7], truncated to k.
-        assert _ngram_propose(hist, 3).tolist() == [3, 9, 7]
-        assert _ngram_propose(hist, 1).tolist() == [3]
-        # No repeated suffix at all -> empty proposal.
-        assert _ngram_propose(
-            np.asarray([1, 2, 3, 4, 5], np.int32), 4).size == 0
-        # Degenerate histories never crash the drafter.
-        assert _ngram_propose(np.asarray([7], np.int32), 4).size == 0
-        # Constant run: suffix matches one step back, proposal
-        # continues the run.
-        run = np.full((6,), 8, np.int32)
-        assert _ngram_propose(run, 2).tolist() == [8, 8]
 
 
 def test_lm_logits_loader_serves_f32_regardless_of_ce_dtype(tmp_path):
@@ -1285,8 +1010,8 @@ class TestResumeAndStreaming:
     """Survivable-inference engine surface (PR 14): a resume admission
     (prompt + tokens a prior attempt delivered) must be token-identical
     to an uninterrupted generate() at EVERY cut point — including cuts
-    landing mid-speculative-window and under a tight paged-KV pool —
-    and the streaming surface must emit exactly the suffix."""
+    under a tight paged-KV pool — and the streaming surface must emit
+    exactly the suffix."""
 
     def _engine(self, spec, decode=None, name="test-resume", **kw):
         from kubeflow_tpu.serving.engine import DecodeEngine
@@ -1350,31 +1075,6 @@ class TestResumeAndStreaming:
                 "max_new_tokens": NEW_TOKENS})
             got = np.asarray(out["tokens"])[0].tolist()
             assert got == prompt + suffix[:4]
-        finally:
-            engine.close()
-
-    def test_resume_mid_speculative_window_identity(self, engine_model):
-        """A resume landing mid-speculative-window: the resumed engine
-        drafts from the identical history (prompt + resume), so the
-        suffix must still be bit-identical to the uninterrupted run."""
-        spec, _ = engine_model
-        rng = np.random.RandomState(SEED + 31)
-        pat = rng.randint(1, VOCAB, size=(4,))
-        prompt = np.tile(pat, 3).tolist()  # repetitive: drafts fire
-        want = _reference_rows(spec, [prompt], [NEW_TOKENS])[0]
-        suffix = want[len(prompt):]
-        engine = self._engine(spec, speculative_tokens=4,
-                              prefill_len=32,
-                              name="test-resume-spec")
-        try:
-            for cut in (2, 5, 9):
-                out = engine.submit({
-                    "tokens": np.asarray(prompt, np.int32),
-                    "resume_tokens": suffix[:cut],
-                    "max_new_tokens": NEW_TOKENS})
-                got = np.asarray(out["tokens"])[0].tolist()
-                assert got == want, (
-                    f"speculative resume at cut {cut} drifted")
         finally:
             engine.close()
 

@@ -217,12 +217,6 @@ def test_what_is_not_built_for_a_latent_pool_is_refused_by_name(longcat):
         generate.init_paged_state(cfg, 2, 8, 4, kv_cache_dtype="int8")
     with pytest.raises(ValueError, match="layer_types"):
         generate.generate(cfg, params, jnp.ones((1, 4), jnp.int32))
-    state = generate.init_paged_state(cfg, 2, 8, 4)
-    with pytest.raises(ValueError, match="verify"):
-        generate.verify_step(
-            cfg, params, state, generate.DecodeConfig(), 2,
-            jnp.zeros((2, 2), jnp.int32), jnp.zeros((2,), jnp.int32),
-            jnp.zeros((2, 4), jnp.int32))
     with pytest.raises(ValueError, match="quantize"):
         loaders.lm_generate({"model": dataclasses.asdict(cfg),
                              "quantize": "int8"})
@@ -764,7 +758,7 @@ def _engine(cfg, params, **kw):
         name="longcat-test", **kw)
 
 
-@pytest.mark.parametrize("flag", ["speculative_tokens", "host_spill_blocks"])
+@pytest.mark.parametrize("flag", ["host_spill_blocks", "adapters", "mesh"])
 def test_engine_refuses_at_construction_by_name(longcat, flag):
     cfg, params = longcat
     with pytest.raises(ValueError, match=flag):

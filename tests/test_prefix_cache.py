@@ -170,17 +170,6 @@ class TestBlockManager:
         assert mgr.used_blocks() == 0  # publish was a no-op
         mgr.check_invariants()
 
-    def test_rollback_restores_reservation(self):
-        mgr = BlockManager(num_blocks=4, block_tokens=2)
-        mgr.admit(toks(1, 2), 1, 3)
-        blocks = [mgr.take() for _ in range(3)]
-        assert mgr.available() == 1
-        mgr.rollback(blocks[2:])  # speculative tail trim
-        assert mgr.available() == 1  # page freed, reservation restored
-        assert mgr.take() == blocks[2]
-        mgr.release(blocks)
-        mgr.check_invariants()
-
     def test_invalidate_forgets_everything(self):
         mgr = BlockManager(num_blocks=4, block_tokens=2)
         blocks, _, res = run_request(mgr, [1, 2, 3, 4], 0)
@@ -203,7 +192,7 @@ class TestBlockManager:
 
 class TestAllocatorInvariantBattery:
     """Seeded randomized mixed workload against a small pool: admit /
-    grow / speculative-rollback / release / publish in arbitrary
+    grow / release / publish in arbitrary
     interleavings.  After EVERY operation the structural invariants
     must hold (no double-free, refcount/free-list agreement,
     reservation coverage), no page may ever be writable by two
@@ -224,7 +213,7 @@ class TestAllocatorInvariantBattery:
             return set(req["blocks"][req["shared_n"]:])
 
         for _ in range(400):
-            op = rng.randint(4)
+            op = rng.randint(3)
             if op == 0 and len(live) < 6:  # admit
                 # Half the prompts share one of two hot prefixes so
                 # aliasing actually happens; suffixes diverge.
@@ -265,15 +254,7 @@ class TestAllocatorInvariantBattery:
                             np.asarray(req["tokens"], np.int32),
                             len(req["tokens"]), req["blocks"])
                         req["published"] = True
-            elif op == 2 and live:  # speculative tail rollback
-                req = live[rng.randint(len(live))]
-                private_n = len(req["blocks"]) - req["shared_n"]
-                if private_n > 1:
-                    tail = req["blocks"][-1:]
-                    del req["blocks"][-1:]
-                    req["res_left"] += 1
-                    mgr.rollback(tail)
-            elif op == 3 and live:  # retire
+            elif op == 2 and live:  # retire
                 req = live.pop(rng.randint(len(live)))
                 mgr.release(req["blocks"], unreserve=req["res_left"])
             # Writable sets of any two live requests stay disjoint.
@@ -312,7 +293,7 @@ class TestAllocatorInvariantBattery:
             return {"marker": digests[-1], "n": len(digests)}
 
         for _ in range(400):
-            op = rng.randint(7)
+            op = rng.randint(6)
             if op == 0 and len(live) < 6:  # admit
                 base = ([1, 2, 3, 4, 5, 6, 7, 8] if rng.randint(2)
                         else [9, 9, 9, 9])
@@ -347,17 +328,10 @@ class TestAllocatorInvariantBattery:
                             np.asarray(req["tokens"], np.int32),
                             len(req["tokens"]), req["blocks"])
                         req["published"] = True
-            elif op == 2 and live:  # speculative tail rollback
-                req = live[rng.randint(len(live))]
-                if len(req["blocks"]) - req["shared_n"] > 1:
-                    tail = req["blocks"][-1:]
-                    del req["blocks"][-1:]
-                    req["res_left"] += 1
-                    mgr.rollback(tail)
-            elif op == 3 and live:  # retire
+            elif op == 2 and live:  # retire
                 req = live.pop(rng.randint(len(live)))
                 mgr.release(req["blocks"], unreserve=req["res_left"])
-            elif op == 4:  # spill an idle LRU record to the host tier
+            elif op == 3:  # spill an idle LRU record to the host tier
                 for rec in mgr.spill_candidates(max_records=2):
                     digests = list(rec.digests)
                     freed = mgr.spill(rec, payload_for(digests))
@@ -368,7 +342,7 @@ class TestAllocatorInvariantBattery:
                     # check_invariants' free-list uniqueness below.
                     assert 0 <= freed <= len(digests)
                     assert digests[-1] in mgr._host_chains
-            elif op == 5:  # park a session's KV straight to host
+            elif op == 4:  # park a session's KV straight to host
                 tokens = rng.randint(1, 90,
                                      size=(rng.randint(4, 17),)).tolist()
                 depth = len(tokens) // mgr.block
@@ -380,7 +354,7 @@ class TestAllocatorInvariantBattery:
                         {"marker": None, "n": depth})
                     if stored:
                         spilled_chains.append((tokens, stored))
-            elif op == 6 and spilled_chains:  # fetch / re-import path
+            elif op == 5 and spilled_chains:  # fetch / re-import path
                 tokens, depth = spilled_chains[
                     rng.randint(len(spilled_chains))]
                 payload, got = mgr.lookup_spilled(

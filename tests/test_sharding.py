@@ -6,7 +6,7 @@ The conftest forces an 8-device CPU host platform, so meshes of 2 and
   - the sharded engine (params + paged KV pool placed over a
     ``tensor`` mesh, serving/sharding.py) is BIT-IDENTICAL to the
     single-device engine for greedy decode — across plain prompts,
-    prefix-cache hits, int8 KV pools, and speculative verify;
+    prefix-cache hits and int8 KV pools;
   - disaggregated handoff (prefill replica exports finished block
     pages, decode replica imports them) equals local prefill at EVERY
     page-coverage cut, i.e. every chunk boundary the import can land
@@ -197,25 +197,6 @@ class TestShardedEngineIdentity:
         finally:
             single.close()
             shard.close()
-
-    def test_speculative_identity(self, lm):
-        """Sharded speculative verify == generate(): the verify
-        program compiles SPMD like the others and exact-match
-        acceptance keeps greedy identity."""
-        _, _, _, reference = lm
-        eng = _engine(lm, mesh=_mesh(2), speculative_tokens=4,
-                      name="spec-mesh2")
-        try:
-            # Repetitive prompt the n-gram drafter can predict, plus a
-            # random one (mixed batch, draft_len 0 rider).
-            rep = np.asarray([7, 9, 7, 9, 7, 9, 7, 9], np.int32)
-            rand = _prompts()[0]
-            for p in (rep, rand, rep):
-                got = eng.submit({"tokens": p})["tokens"][0].tolist()
-                assert got == reference(p)
-            assert eng.stats()["spec_steps"] >= 0  # battery sanity
-        finally:
-            eng.close()
 
     def test_mesh_gauge_zeroed_on_close(self, lm):
         from kubeflow_tpu.runtime.prom import (

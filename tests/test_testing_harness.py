@@ -87,9 +87,9 @@ class TestE2EDrivers:
         # requests through the HTTP surface against the in-process
         # continuous-batching engine (occupancy drains to zero), a
         # shared-prefix burst (kft_engine_prefix_hits_total > 0,
-        # bounded inter-token gap), and a speculative burst
-        # (kft_engine_spec_accepted_total > 0, four compiled
-        # programs, token-identical to a spec-OFF control).
+        # bounded inter-token gap), a block-exhaustion burst and a
+        # decode-rounds burst (two compiled programs, token-identical
+        # to a decode_rounds=1 control).
         engine_smoke()
 
     def test_fault_injection_smoke(self):
